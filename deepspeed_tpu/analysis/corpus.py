@@ -487,10 +487,11 @@ def _paged_decode_program(num_blocks: int, devices=None):
         donation_expected=False, meta={"skip_required": True})
 
 
-# between the two pool sizings: measured modeled peaks ~1.18 MiB (33-block
-# pool, correctly freed) vs ~2.21 MiB (96-block leak) on jax 0.4.37 —
-# re-measure BOTH variants before retuning (same protocol as remat-missing)
-PAGED_LEAK_BUDGET = 1536 << 10   # 1.5 MiB
+# between the two pool sizings: measured modeled peaks 1.90 MiB (33-block
+# pool, correctly freed) vs 4.20 MiB (96-block leak) on jax 0.9.0 (PR 21;
+# 1.18 vs 2.21 MiB on the previous stack, budget 1.5 MiB) — re-measure
+# BOTH variants before retuning (same protocol as remat-missing)
+PAGED_LEAK_BUDGET = 3 << 20      # 3 MiB
 
 
 def paged_cache_leak(devices=None):

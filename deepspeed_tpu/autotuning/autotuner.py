@@ -55,6 +55,9 @@ class Autotuner:
     """In-process grid/random search over engine configurations."""
 
     def __init__(self, model, base_config: Dict[str, Any], devices=None):
+        # in-process by design: this process initializes the backend and
+        # holds the chip(s) for every trial — never pair it with
+        # scheduler.ResourceManager's local children in the same process
         import jax
         self.model = model
         self.base = dict(base_config)

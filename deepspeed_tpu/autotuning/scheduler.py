@@ -77,7 +77,12 @@ class ResourceManager:
                  results_dir: str = "autotuning_exps",
                  launch: Optional[Callable[[Experiment], None]] = None,
                  poll_s: float = 1.0, timeout_s: float = 3600.0):
-        self.hosts = list(hosts) or ["localhost"]
+        # every alias of THIS machine is one host slot: its chips belong to
+        # one process at a time, so two local experiment children must never
+        # run at once (the second would fail or hang waiting for the chip)
+        hosts = list(hosts) or ["localhost"]
+        local = [h for h in hosts if self._is_local([h])]
+        self.hosts = [h for h in hosts if h not in local[1:]]
         self.chips_per_host = chips_per_host
         self.results_dir = results_dir
         self._launch = launch or self._launch_default
@@ -105,6 +110,9 @@ class ResourceManager:
                   cfg_path, out_path]
         local = self._is_local(exp.hosts)
         if local:
+            # NOT chip-safe from a parent that has touched JAX: the child
+            # needs the local chip, and a parent that initialized a backend
+            # (e.g. the in-process Autotuner) already holds it
             self._procs[exp.exp_id] = subprocess.Popen(
                 script, stdout=subprocess.DEVNULL,
                 stderr=subprocess.STDOUT)

@@ -22,7 +22,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 DEFAULT_SIZES = [1 << 14, 1 << 18, 1 << 22, 1 << 24]  # elements (fp32)
 OPS = ("psum", "all_gather", "psum_scatter", "all_to_all", "ppermute",
@@ -64,8 +63,8 @@ def _op_fn(op: str, axis: str, mesh: Mesh):
     else:
         raise ValueError(f"unknown op {op!r}")
 
-    fn = shard_map(body, mesh=mesh, in_specs=in_spec, out_specs=out_spec,
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_spec,
+                       out_specs=out_spec, check_vma=False)
 
     def chained(x, iters):
         # chain iterations through a data dependency so one dispatch times

@@ -46,21 +46,13 @@ FSDP_AXIS = "fsdp"
 
 
 def shard_map_compat(f, mesh, *, in_specs, out_specs, manual_axes):
-    """Partial-auto shard_map across jax versions: `jax.shard_map` with
-    axis_names (>= 0.6 spelling) or the experimental module with
-    `auto=` (the 0.4.x spelling). Only `manual_axes` become manual; every
+    """Partial-auto shard_map: only `manual_axes` become manual; every
     other mesh axis stays auto — GSPMD keeps partitioning the body over
     them (param all-gathers, TP reductions, fsdp constraints)."""
     import jax
-    try:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             axis_names=set(manual_axes), check_vma=False)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm
-        auto = frozenset(mesh.axis_names) - frozenset(manual_axes)
-        return _sm(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False, auto=auto)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs,
+                         axis_names=set(manual_axes), check_vma=False)
 
 
 def _entries(spec: P):

@@ -84,6 +84,8 @@ class LaunchAgent:
         try:
             if self._signaled:
                 return 128 + signal.SIGTERM
+            # the child owns the chip: safe only while this agent process
+            # never initializes a JAX backend itself
             self.proc = subprocess.Popen(
                 self.cmd, env=self.env, start_new_session=True)
             while True:

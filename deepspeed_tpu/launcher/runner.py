@@ -158,6 +158,8 @@ def main(argv=None):
         if args.dry_run:
             print(" ".join(map(shlex.quote, script_cmd)))
             return 0
+        # the child owns the chip: safe only while this launcher process
+        # never initializes a JAX backend itself
         return subprocess.call(script_cmd)
 
     if args.launcher != "ssh":

@@ -104,18 +104,6 @@ def ring_attention(q, k, v, mesh: Mesh, *, axis_name: str = "seq",
     local = functools.partial(ring_attention_local, axis_name=axis_name,
                               causal=causal, sm_scale=sm_scale,
                               axis_size=int(mesh.shape[axis_name]))
-    try:
-        fn = jax.shard_map(
-            local,
-            mesh=mesh,
-            in_specs=(spec, spec, spec),
-            out_specs=spec,
-            check_vma=False)
-    except (AttributeError, TypeError):
-        # older jax: jax.shard_map / check_vma don't exist yet — the
-        # experimental spelling with check_rep is the same full-manual mode
-        from jax.experimental.shard_map import shard_map
-        fn = shard_map(local, mesh,
-                       in_specs=(spec, spec, spec), out_specs=spec,
-                       check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return fn(q, k, v)

@@ -24,8 +24,6 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 from jax.sharding import Mesh
 
-from deepspeed_tpu.utils.logging import logger
-
 # canonical axis order, outermost first — pipe outermost so that PP crosses
 # the slowest links (DCN) and tensor innermost so TP rides fastest ICI links.
 AXIS_ORDER = ("pipe", "data", "fsdp", "expert", "seq", "tensor")
@@ -107,24 +105,22 @@ def plan_from_config(config, world_size: int) -> MeshPlan:
 def build_mesh(plan: MeshPlan, devices: Optional[List] = None) -> Mesh:
     """Build the device mesh.
 
-    Uses `jax.experimental.mesh_utils.create_device_mesh` when it can (it
-    optimizes assignment for the TPU torus so that the innermost axes land on
-    the fastest ICI rings); falls back to a plain reshape.
+    On real TPUs `jax.experimental.mesh_utils.create_device_mesh` assigns
+    devices so the innermost axes land on the fastest ICI rings of the
+    torus; elsewhere (CPU, one device) the order is a plain reshape. A
+    failure of the torus assignment propagates — a mesh silently built in
+    naive device order would run every collective over the wrong links.
     """
     import jax
+    from jax.experimental import mesh_utils
     devices = devices if devices is not None else jax.devices()
     shape = tuple(getattr(plan, ax) for ax in AXIS_ORDER)
     n = int(np.prod(shape))
     if n != len(devices):
         raise ValueError(f"mesh needs {n} devices, have {len(devices)}")
-    try:
-        from jax.experimental import mesh_utils
-        if len(devices) > 1 and devices[0].platform == "tpu":
-            dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-        else:
-            dev_array = np.asarray(devices).reshape(shape)
-    except Exception as e:  # pragma: no cover - defensive
-        logger.warning(f"mesh_utils failed ({e}); using naive device order")
+    if len(devices) > 1 and devices[0].platform == "tpu":
+        dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
+    else:
         dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, AXIS_ORDER)
 

@@ -148,22 +148,13 @@ def _tp_degree_for_overlap():
     mesh, tensor absent or size 1, tensor already manual (nested shard_map
     regions own it), or any partially-manual region (the nested shard_map
     cannot be established from inside another manual region)."""
-    from deepspeed_tpu.parallel.context import physical_mesh_env
-    env_mesh, shape, bound = physical_mesh_env()
+    from deepspeed_tpu.parallel.context import (in_manual_region,
+                                                physical_mesh_env)
+    env_mesh, shape, _ = physical_mesh_env()
     if env_mesh is None:
         return None, 0
     tp = shape.get(TENSOR_AXIS, 1)
-    if tp <= 1:
-        return env_mesh, 0
-    try:
-        from jax.sharding import AxisType, get_abstract_mesh
-        am = get_abstract_mesh()
-        if am.axis_names and any(t is AxisType.Manual
-                                 for t in getattr(am, "axis_types", ())):
-            return env_mesh, 0
-    except Exception:
-        pass
-    if TENSOR_AXIS in bound:
+    if tp <= 1 or in_manual_region():
         return env_mesh, 0
     return env_mesh, tp
 

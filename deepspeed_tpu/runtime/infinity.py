@@ -58,9 +58,8 @@ class LayerStore:
       nvme   — AIO chunk files (the true ZeRO-Infinity tier; local-disk
                fast on a real TPU-VM where NVMe sits next to the chip)
       host   — numpy buffers in this process (tests; CPU)
-      pinned — jax arrays in TPU-host pinned DRAM (the fast tier when the
-               client process is remote from the TPU host, e.g. a relay:
-               bytes move host<->HBM by local DMA and never cross the wire)
+      pinned — jax arrays in TPU-host pinned DRAM (bytes move host<->HBM
+               by local DMA, no file system in between)
     """
 
     def __init__(self, path: Optional[str], n_layers: int, chunk_elems: int,
@@ -1464,10 +1463,8 @@ class InfinityExecutor:
                     # bound in-flight chunk buffers to one layer: at 7B a
                     # layer's (3, C) fp32 opt buffer is 2.4 GB, and letting
                     # the async dispatch run ahead piles up donated+new
-                    # buffers past HBM. (block_until_ready is a no-op through
-                    # the relay transport; a scalar fetch is the reliable
-                    # fence.)
-                    np.asarray(jax.device_get(new_buf[0, 0]))
+                    # buffers past HBM
+                    jax.block_until_ready(new_buf)
                 del opt_dev, new_buf, new_bits
         self._drain_write()
 

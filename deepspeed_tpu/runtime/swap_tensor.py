@@ -606,9 +606,8 @@ class HostAdamSwapper:
     4 bytes/param instead of the 28 the state-streaming tier moves.
 
     Same interface as NVMeOptimizerSwapper (initialize/step/export/import).
-    The right tier on a real TPU-VM where this process runs on the TPU
-    host; through a remote relay the grad/param hop crosses the wire, so it
-    stays opt-in (offload_optimizer.use_cpu_adam)."""
+    Opt-in (offload_optimizer.use_cpu_adam); which optimizer tier wins on
+    the chip is ROADMAP S2's question."""
 
     def __init__(self, param_template, *, mesh, lr=1e-3,
                  betas=(0.9, 0.999), eps: float = 1e-8,

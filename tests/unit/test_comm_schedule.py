@@ -220,10 +220,15 @@ BATCH16 = {"input_ids": np.zeros((16, 16), np.int32)}
 # (measured; re-measure with engine.audit() if a deliberate change shifts
 # them). DEFERRED is the same dict for EVERY gas; the eager per-microbatch
 # grad sync adds exactly EAGER_AR_PER_MB all-reduces per extra microbatch.
-STAGE2_DEFERRED_CENSUS = {"all-reduce": 21, "reduce-scatter": 20,
+# Pinned on jax/jaxlib 0.9.0 (PR 21; the previous stack's values were
+# deferred {ar 21, rs 20, ag 20}, eager gas-1 ar 41, +21 per microbatch):
+# 0.9's CPU pipeline combines each microbatch's per-parameter grad
+# all-reduces into ONE, so the eager path now grows by exactly 1 per extra
+# microbatch (measured 4 / 5 / 7 at gas 1 / 2 / 4).
+STAGE2_DEFERRED_CENSUS = {"all-reduce": 1, "reduce-scatter": 20,
                           "all-gather": 20}
-STAGE2_EAGER_GAS1_AR = 41       # = test_analysis.STAGE2_CENSUS["all-reduce"]
-EAGER_AR_PER_MB = 21            # per-microbatch grad sync all-reduces
+STAGE2_EAGER_GAS1_AR = 4        # = test_analysis.STAGE2_CENSUS["all-reduce"]
+EAGER_AR_PER_MB = 1             # per-microbatch grad sync all-reduces
 
 
 def census_of(stage, axes, devices, gas, *, deferred, unroll=0, hier=False,

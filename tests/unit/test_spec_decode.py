@@ -140,7 +140,12 @@ def test_spec_bit_parity_and_acceptance():
     """spec K=3 == spec K=0 == speculation off == PR-9 generate(), token
     for token, AND the proposer actually accepted something."""
     model = make_model(_cfg())
-    params = model.init(jax.random.PRNGKey(0))
+    # data-dependent pin, re-measured on jax 0.9.0 (PR 21): whether the
+    # n-gram proposer ever hits depends on the random-init weights' greedy
+    # stream. PRNGKey(0)'s model never re-enters a prompt trigram on this
+    # stack (0 of 27 verify steps accepted anything); PRNGKey(1)'s does
+    # (12 accepted over 21 steps).
+    params = model.init(jax.random.PRNGKey(1))
     reqs = _repetitive_load(np.random.default_rng(2))
     off = _serving(model, params).run(list(reqs))          # spec_tokens=0
     spec_srv = _serving(model, params, spec_tokens=3)
