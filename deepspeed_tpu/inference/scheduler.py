@@ -114,6 +114,15 @@ class Request:
     cow_dst: Optional[int] = None
     # wall time the request last received tokens at the host (ITL stats)
     last_token_t: Optional[float] = None
+    # FIRST admission by schedule() (a preemption does not reset it:
+    # re-admissions are counted by `preemptions`). submit_t -> admit_t is
+    # the queue wait, admit_t -> first_token_t the prefill dispatch, the
+    # quantum it rides and the fetch.
+    admit_t: Optional[float] = None
+    # longest interval between two commits that delivered tokens to this
+    # request (the first token starts the clock) — what a streaming client
+    # sees as its worst stall; None until a second delivery
+    max_gap_ms: Optional[float] = None
     # --- multi-tenancy (ISSUE 17) -------------------------------------
     # which registered LoRA adapter serves this request (0 = base model /
     # the null adapter). Pure routing data to the scheduler; the serving
@@ -263,6 +272,7 @@ class RequestScheduler:
         req.prefix_rows = 0
         req.cow_src = req.cow_dst = None
         req.last_token_t = None
+        req.admit_t = None
         req.adapter_slot = None
         req.kv_rows = 0
         self._next_rid = max(self._next_rid, req.rid) + 1
@@ -456,6 +466,7 @@ class RequestScheduler:
         #    the copy-on-write fork, and only the uncovered tail allocates
         #    fresh blocks.
         admitted: List[Request] = []
+        now = time.perf_counter()
         while self.waiting and self._free_slots:
             req = self.waiting[0]
             ctx_arr = req.context
@@ -523,6 +534,7 @@ class RequestScheduler:
             if req.admission_seq is None:      # aging: resumed requests
                 req.admission_seq = self._next_seq  # keep their first seq
                 self._next_seq += 1
+                req.admit_t = now
             self.running.append(req)
             admitted.append(req)
         return {"admitted": admitted, "preempted": preempted,

@@ -93,6 +93,7 @@ from deepspeed_tpu.inference.scheduler import (AdmissionRejected, Request,
 from deepspeed_tpu.robustness import events as rb_events
 from deepspeed_tpu.robustness import faults as rb_faults
 from deepspeed_tpu.robustness.preemption import Preempted
+from deepspeed_tpu.telemetry.tracing import span
 
 
 class DecodeDispatchHang(RuntimeError):
@@ -230,8 +231,8 @@ class ServingConfig:
     # (never silent queue growth — `serving-unbounded-queue` corpus)
     max_queue: Optional[int] = None
     pool_watermark: Optional[float] = None
-    # dispatch watchdog: a scheduling round (quantum dispatch + token
-    # fetch) that exceeds this raises DecodeDispatchHang and recovers by
+    # dispatch watchdog: a round whose quantum dispatch, or whose token
+    # fetch, exceeds this raises DecodeDispatchHang and recovers by
     # rebuilding the batch from host-side cursors. None = no watchdog.
     dispatch_timeout_s: Optional[float] = None
     # round recovery attempts before the failure propagates (a transient
@@ -275,8 +276,8 @@ class ServingConfig:
     lora_rank: int = 0                 # shared by all adapters (one shape)
     lora_targets: tuple = ("q", "k", "v", "o")
     # --- fleet observability (ISSUE 18; default off = PR-17 behavior) ---
-    # per-request distributed tracing: host-wall-clock spans only (two
-    # perf_counter calls + a deque append per span, ZERO added device
+    # per-request distributed tracing: host-wall-clock spans only (one
+    # telemetry.tracing.span + a deque append per span, ZERO added device
     # syncs — tracing on/off is bit-identical, pinned by test_fleet_obs).
     # Arm at runtime with enable_request_trace() to A/B a warm engine.
     request_trace: bool = False
@@ -575,12 +576,16 @@ class ServingEngine:
         self._preemption = None            # attach_preemption()
         self._drain_dir: Optional[str] = None
         # --- fleet observability (ISSUE 18) ----------------------------
-        # round-phase decomposition ring: one entry per _round() with the
-        # host milliseconds each phase took (schedule / housekeeping /
-        # prefill dispatch / decode dispatch / token fetch / commit).
-        # Cheap enough to ALWAYS be on: ~7 perf_counter reads per round.
+        # round-phase decomposition: every _round() times its phases
+        # (schedule / housekeeping / prefill dispatch / decode dispatch /
+        # token fetch / commit) through telemetry.tracing.span — always on,
+        # seven inactive TraceAnnotations a round. The milliseconds add up
+        # in _phase_totals over the stats window; the ring of the last 256
+        # rounds is the stall rule's baseline (_note_phases), nothing else.
         self._phases: "collections.deque[Dict[str, float]]" = \
             collections.deque(maxlen=256)
+        self._phase_totals = dict.fromkeys(self._PHASE_OUT, 0.0)
+        self._rounds = 0                   # rounds in this stats window
         self._round_tokens = 0             # tokens committed this round
         self._phase_stall_events = 0       # serving_phase_stall emissions
         self._tracer = None                # RequestTracer when armed
@@ -670,32 +675,30 @@ class ServingEngine:
             merge_chrome_trace([stream], path=path)
         return stream
 
+    # a round's phase entry (the ring's keys) -> phase_decomposition()'s
+    _PHASE_OUT = {"schedule_ms": "serve_schedule_ms",
+                  "housekeeping_ms": "serve_housekeeping_ms",
+                  "prefill_ms": "serve_prefill_dispatch_ms",
+                  "decode_ms": "serve_decode_dispatch_ms",
+                  "fetch_ms": "serve_fetch_ms",
+                  "commit_ms": "serve_commit_ms",
+                  "round_ms": "serve_round_ms", "tokens": "serve_tokens"}
+
     def phase_decomposition(self) -> Dict[str, float]:
-        """Aggregate the round-phase ring into the decomposition the
-        serving doctor prices (``profiling.doctor.diagnose_serving``):
-        total host ms per phase over the window plus round/token counts
-        and the tracing-overhead evidence (device_syncs self-report)."""
+        """The decomposition the serving doctor prices
+        (``profiling.doctor.diagnose_serving``): total host ms per phase
+        over the WHOLE stats window (since ``reset_stats()``) plus round /
+        token counts and the tracing-overhead evidence (device_syncs
+        self-report)."""
         out: Dict[str, float] = {
-            "serve_rounds": float(len(self._phases)),
-            "serve_schedule_ms": 0.0, "serve_housekeeping_ms": 0.0,
-            "serve_prefill_dispatch_ms": 0.0,
-            "serve_decode_dispatch_ms": 0.0, "serve_fetch_ms": 0.0,
-            "serve_commit_ms": 0.0, "serve_round_ms": 0.0,
-            "serve_tokens": 0.0,
+            "serve_rounds": float(self._rounds),
             "serve_phase_stall_events": float(self._phase_stall_events),
             "trace_armed": float(self._tracer is not None),
             "trace_device_syncs": float(self._tracer.device_syncs
                                         if self._tracer else 0),
         }
-        for entry in self._phases:
-            out["serve_schedule_ms"] += entry["schedule_ms"]
-            out["serve_housekeeping_ms"] += entry["housekeeping_ms"]
-            out["serve_prefill_dispatch_ms"] += entry["prefill_ms"]
-            out["serve_decode_dispatch_ms"] += entry["decode_ms"]
-            out["serve_fetch_ms"] += entry["fetch_ms"]
-            out["serve_commit_ms"] += entry["commit_ms"]
-            out["serve_round_ms"] += entry["round_ms"]
-            out["serve_tokens"] += entry["tokens"]
+        for key, total in self._phase_totals.items():
+            out[self._PHASE_OUT[key]] = total
         return {k: (round(v, 3) if k.endswith("_ms") else v)
                 for k, v in out.items()}
 
@@ -707,7 +710,8 @@ class ServingEngine:
     _STALL_FRACTION = 0.6
 
     def _note_phases(self, entry: Dict[str, float]) -> None:
-        """Append one round's phase decomposition and emit (at most one
+        """Add one round's phase decomposition to the window's totals (and
+        to the ring, the stall rule's baseline) and emit (at most one
         per stats window) a ``serving_phase_stall`` event when a NON-fetch
         phase dominates a round that regressed against the window's own
         steady state (3x the prior-round median, with >= 8 warm rounds of
@@ -717,6 +721,9 @@ class ServingEngine:
         means 'the accelerator is the bottleneck', which is health, not a
         stall."""
         self._phases.append(entry)
+        self._rounds += 1
+        for key in self._phase_totals:
+            self._phase_totals[key] += entry[key]
         if (not self._quantum_warm or self._phase_stall_events
                 or len(self._phases) < 9
                 or entry["round_ms"] < self._STALL_MIN_ROUND_MS):
@@ -844,10 +851,11 @@ class ServingEngine:
         import jax
         import jax.numpy as jnp
         t = self.config.temperature
-        if t and t > 0:
-            return jax.random.categorical(key, logits / t, axis=-1
-                                          ).astype(jnp.int32)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            if t and t > 0:
+                return jax.random.categorical(key, logits / t, axis=-1
+                                              ).astype(jnp.int32)
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     def _get_prefill_fn(self, P: int):
         """One compile per prompt bucket P: prefill + block scatter + first
@@ -917,10 +925,11 @@ class ServingEngine:
                 logits, pools = self.model.decode_span_paged(
                     params, tok_mat, pools, tables, seq_lens, active=active,
                     lora=lora)
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                acc = greedy_accept_len(nxt, tok_mat[:, 1:])      # [S]
-                pend = jnp.take_along_axis(nxt, acc[:, None],
-                                           axis=1)[:, 0]
+                with jax.named_scope("sample"):
+                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    acc = greedy_accept_len(nxt, tok_mat[:, 1:])  # [S]
+                    pend = jnp.take_along_axis(nxt, acc[:, None],
+                                               axis=1)[:, 0]
                 pend = jnp.where(active, pend, tok_mat[:, 0])
                 new_lens = seq_lens + jnp.where(
                     active, acc + 1, 0).astype(jnp.int32)
@@ -1223,7 +1232,13 @@ class ServingEngine:
         last_err: Optional[BaseException] = None
         for _attempt in range(max(0, self.config.round_retries) + 1):
             try:
-                finished = self._round()
+                with span("ds:serve.round", index=self._rounds) as rs:
+                    finished, ph = self._round()
+                    rs.note(running=len(self.scheduler.running),
+                            tokens=self._round_tokens)
+                # only a round that completed counts in the window's totals
+                self._note_phases({**ph, "round_ms": rs.seconds * 1e3,
+                                   "tokens": float(self._round_tokens)})
                 break
             except (Preempted, KeyboardInterrupt):
                 raise
@@ -1243,17 +1258,17 @@ class ServingEngine:
                 f"{self.config.round_retries} recovery retries") from last_err
         return finished
 
-    def _round(self) -> List[Request]:
+    def _round(self):
+        """The round proper, inside ``step()``'s ``ds:serve.round`` span:
+        (requests finished, host milliseconds per phase). Each phase is one
+        ``ds:serve.<phase>`` span (telemetry.tracing.span: on the
+        profiler's clock in any session)."""
         import jax
         import jax.numpy as jnp
 
-        # phase decomposition (ISSUE 18): pure host perf_counter reads —
-        # the ring is always on; the doctor prices it after the fact
-        t_round0 = time.perf_counter()
         self._round_tokens = 0
         ph = {"schedule_ms": 0.0, "housekeeping_ms": 0.0, "prefill_ms": 0.0,
               "decode_ms": 0.0, "fetch_ms": 0.0, "commit_ms": 0.0}
-
         info = rb_faults.serving_round_seam()
         keep = info.get("squeeze")
         if keep is not None:
@@ -1263,10 +1278,10 @@ class ServingEngine:
             self.allocator.set_reserve(
                 max(0, self.allocator.free_blocks - int(keep)))
         try:
-            t0 = time.perf_counter()
-            decisions = self.scheduler.schedule(
-                token_budget=self.config.prefill_token_budget)
-            ph["schedule_ms"] = (time.perf_counter() - t0) * 1e3
+            with span("ds:serve.schedule") as sp:
+                decisions = self.scheduler.schedule(
+                    token_budget=self.config.prefill_token_budget)
+            ph["schedule_ms"] = sp.seconds * 1e3
             if self._tracer is not None:
                 now = time.perf_counter()
                 for req in decisions["preempted"]:
@@ -1278,153 +1293,162 @@ class ServingEngine:
                     # that never passed add_request get their id here
                     self._tracer.begin(req.rid)
                     w0 = getattr(req, "_trace_wait_t0", req.submit_t)
+                    # a first admission's wait ends where admit_t says
+                    w1 = now if req.preemptions else req.admit_t
                     self._tracer.add_span(
                         req.rid, "queue_wait", self._tracer.epoch(w0),
-                        self._tracer.epoch(now),
+                        self._tracer.epoch(w1),
                         preemptions=req.preemptions)
-            t0 = time.perf_counter()
-            if self._lora:
-                # adapter pins track the running set: scheduler-preempted
-                # victims drop theirs first (their slots become LRU
-                # candidates), then each admission pins — if EVERY slot is
-                # held by another in-flight adapter the admission bounces
-                # back to the queue head, exactly the KV-pool-exhaustion
-                # discipline applied to the adapter pool
+            with span("ds:serve.housekeeping") as sp:
+                if self._lora:
+                    # adapter pins track the running set: scheduler-
+                    # preempted victims drop theirs first (their slots
+                    # become LRU candidates), then each admission pins — if
+                    # EVERY slot is held by another in-flight adapter the
+                    # admission bounces back to the queue head, exactly the
+                    # KV-pool-exhaustion discipline applied to the adapter
+                    # pool
+                    for req in decisions["preempted"]:
+                        self._release_adapter(req)
+                    for req in decisions["admitted"]:
+                        if not self._acquire_adapter(req):
+                            self.scheduler.preempt(req)
+                            self._drop_kv_payload(req)
+                            rb_events.emit("adapter_slots_exhausted",
+                                           rid=req.rid,
+                                           adapter=req.adapter_id)
                 for req in decisions["preempted"]:
-                    self._release_adapter(req)
+                    # an eviction consumes an unscattered import payload:
+                    # the re-admission recomputes (scheduler.preempt zeroed
+                    # kv_rows) — stale bytes never outlive their blocks
+                    self._drop_kv_payload(req)
                 for req in decisions["admitted"]:
-                    if not self._acquire_adapter(req):
-                        self.scheduler.preempt(req)
-                        self._drop_kv_payload(req)
-                        rb_events.emit("adapter_slots_exhausted",
-                                       rid=req.rid,
-                                       adapter=req.adapter_id)
-            for req in decisions["preempted"]:
-                # an eviction consumes an unscattered import payload: the
-                # re-admission recomputes (scheduler.preempt zeroed
-                # kv_rows) — stale bytes never outlive their blocks
-                self._drop_kv_payload(req)
-            for req in decisions["admitted"]:
-                if req.cow_src is not None and req.state == "running":
-                    # the copy-on-write fork runs BEFORE any of the
-                    # request's own dispatches can write the boundary block
-                    self._dispatch_fork(req)
-                if req.state == "running" and \
-                        getattr(req, "_kv_payload", None) is not None:
-                    # imported KV bytes scatter into the admission's fresh
-                    # blocks BEFORE the tail prefill span below reads them
-                    with self._rspan(req.rid, "kv_import",
-                                     rows=int(req.kv_rows)):
-                        self._dispatch_kv_import(req)
-            ph["housekeeping_ms"] = (time.perf_counter() - t0) * 1e3
-            t0 = time.perf_counter()
-            for req, start, n in decisions["prefill"]:
-                if req.state != "running":
-                    continue     # bounced by the adapter-slot pin above
-                if start == 0 and n == len(req.context) and not self._lora:
-                    # whole prompt in one go: the PR-9 program (and its
-                    # warm compiles) — chunking/prefix hits take the span.
-                    # LoRA-armed engines route ALL prefills through the
-                    # span program: it carries the adapter delta, and one
-                    # program family keeps the compile count flat
-                    with self._rspan(req.rid, "prefill", tokens=int(n),
-                                     reprefill=req.preemptions > 0):
-                        self._dispatch_prefill(req)
-                else:
-                    with self._rspan(req.rid, "prefill_chunk",
-                                     start=int(start), tokens=int(n)):
-                        self._dispatch_chunk(req, start, n)
-            ph["prefill_ms"] = (time.perf_counter() - t0) * 1e3
+                    if req.cow_src is not None and req.state == "running":
+                        # the copy-on-write fork runs BEFORE any of the
+                        # request's own dispatches can write the boundary
+                        # block
+                        self._dispatch_fork(req)
+                    if req.state == "running" and \
+                            getattr(req, "_kv_payload", None) is not None:
+                        # imported KV bytes scatter into the admission's
+                        # fresh blocks BEFORE the tail prefill span below
+                        # reads them
+                        with self._rspan(req.rid, "kv_import",
+                                         rows=int(req.kv_rows)):
+                            self._dispatch_kv_import(req)
+            ph["housekeeping_ms"] = sp.seconds * 1e3
+            with span("ds:serve.prefill_dispatch") as sp:
+                for req, start, n in decisions["prefill"]:
+                    if req.state != "running":
+                        continue     # bounced by the adapter-slot pin above
+                    if start == 0 and n == len(req.context) \
+                            and not self._lora:
+                        # whole prompt in one go: the PR-9 program (and its
+                        # warm compiles) — chunking/prefix hits take the
+                        # span. LoRA-armed engines route ALL prefills
+                        # through the span program: it carries the adapter
+                        # delta, and one program family keeps the compile
+                        # count flat
+                        with self._rspan(req.rid, "prefill", tokens=int(n),
+                                         reprefill=req.preemptions > 0):
+                            self._dispatch_prefill(req)
+                    else:
+                        with self._rspan(req.rid, "prefill_chunk",
+                                         start=int(start), tokens=int(n)):
+                            self._dispatch_chunk(req, start, n)
+            ph["prefill_ms"] = sp.seconds * 1e3
             if not self.scheduler.running:
-                ph["round_ms"] = (time.perf_counter() - t_round0) * 1e3
-                self._note_phases({**ph, "tokens": 0.0})
-                return []
+                return [], ph
 
-            t_dec0 = time.perf_counter()
-            tables, seq_lens, active, aidx = self._tables_device()
-            # a prefill-role engine NEVER runs decode quanta: requests sit
-            # prefill_done until the router hands them (with their KV
-            # bytes) to the decode tier. Their FIRST token still commits
-            # through the pending-firsts fetch below, so TTFT is measured
-            # where the prefill ran.
-            can_decode = self.config.role != "prefill"
-            spec = (self.config.spec_tokens > 0 and can_decode
-                    and any(r.prefill_done for r in self.scheduler.running))
-            decode = can_decode and any(r.prefill_done
-                                        for r in self.scheduler.running)
-            step_fn = self._get_spec_step() if spec \
-                else (self._get_quantum_step() if decode else None)
-            tok_mat = None
-            if spec:
-                props = self._proposals_device()
-                tok_mat = jnp.concatenate([self._tokens[:, None], props],
-                                          axis=1)
-            # keys precomputed so the watchdogged closure touches NO engine
-            # state: an abandoned (hung) round thread finishing late can
-            # only drop its local result, never clobber recovered state
-            keys = [self._next_key()
-                    for _ in range(self.config.decode_quantum)]
-            pending = [(req, req._first_dev)
-                       for req in self.scheduler.running
-                       if getattr(req, "_first_dev", None) is not None]
-            pools, tokens = self.pools, self._tokens
-            apool = self.adapter_pool if self._lora else None
-            params, mesh = self.engine.params, self.engine.mesh
-            S = self.config.max_seqs
-            epoch = self._epoch
+            with span("ds:serve.decode_dispatch") as sp_dec:
+                tables, seq_lens, active, aidx = self._tables_device()
+                # a prefill-role engine NEVER runs decode quanta: requests
+                # sit prefill_done until the router hands them (with their
+                # KV bytes) to the decode tier. Their FIRST token still
+                # commits through the pending-firsts fetch below, so TTFT
+                # is measured where the prefill ran.
+                can_decode = self.config.role != "prefill"
+                spec = (self.config.spec_tokens > 0 and can_decode
+                        and any(r.prefill_done
+                                for r in self.scheduler.running))
+                decode = can_decode and any(r.prefill_done
+                                            for r in self.scheduler.running)
+                step_fn = self._get_spec_step() if spec \
+                    else (self._get_quantum_step() if decode else None)
+                tok_mat = None
+                if spec:
+                    props = self._proposals_device()
+                    tok_mat = jnp.concatenate(
+                        [self._tokens[:, None], props], axis=1)
+                # keys precomputed so the watchdogged closures touch NO
+                # engine state: an abandoned (hung) round thread finishing
+                # late can only drop its local result, never clobber
+                # recovered state
+                keys = [self._next_key()
+                        for _ in range(self.config.decode_quantum)]
+                pending = [(req, req._first_dev)
+                           for req in self.scheduler.running
+                           if getattr(req, "_first_dev", None) is not None]
+                pools, tokens = self.pools, self._tokens
+                apool = self.adapter_pool if self._lora else None
+                params, mesh = self.engine.params, self.engine.mesh
+                S = self.config.max_seqs
+                epoch = self._epoch
 
-            def quantum_and_fetch():
-                # the decode_dispatch fault seam lives INSIDE the guard: a
-                # hang here is exactly what the watchdog must time out
-                rb_faults.dispatch_seam()
-                if self._epoch != epoch:
-                    return None     # abandoned by a recovery: bail before
-                p, t, lens = pools, tokens, seq_lens   # touching the device
-                outs = []
-                spec_dev = None
-                with mesh:
-                    if spec:
-                        # ONE verify step per round: pending + K proposals
-                        # scored in a single span pass
-                        p, nxt, acc, t, lens = step_fn(
-                            params, p, tok_mat, tables, lens, active,
-                            keys[0], apool, aidx)
-                        spec_dev = (nxt, acc)
-                    elif decode:
-                        for k in keys:
-                            if self._epoch != epoch:
-                                return None
-                            p, t, lens = step_fn(params, p, t, tables, lens,
-                                                 active, k, apool, aidx)
-                            outs.append(t)
-                # dispatch done / fetch begins: the split the doctor uses
-                # to tell dispatch-bound from fetch-bound (local stamps —
-                # watchdog-thread-safe, committed only on success)
-                tq1 = time.perf_counter()
-                # the ONE sync of the round: the sampled tokens (quantum
-                # steps or the verify step's accept verdict) AND every
-                # pending prefill/chunk token ride a single device_get
-                toks, firsts, spec_host = jax.device_get(
-                    (jnp.stack(outs) if outs
-                     else jnp.zeros((0, S), jnp.int32),
-                     [f for _, f in pending], spec_dev))
-                return p, t, toks, firsts, spec_host, (
-                    tq1, time.perf_counter())
+                def dispatch():
+                    # the decode_dispatch fault seam lives INSIDE the
+                    # guard: a hang here is exactly what the watchdog must
+                    # time out
+                    rb_faults.dispatch_seam()
+                    if self._epoch != epoch:
+                        return None  # abandoned by a recovery: bail before
+                    p, t, lens = pools, tokens, seq_lens  # touching the device
+                    outs = []
+                    spec_dev = None
+                    with mesh:
+                        if spec:
+                            # ONE verify step per round: pending + K
+                            # proposals scored in a single span pass
+                            p, nxt, acc, t, lens = step_fn(
+                                params, p, tok_mat, tables, lens, active,
+                                keys[0], apool, aidx)
+                            spec_dev = (nxt, acc)
+                        elif decode:
+                            for k in keys:
+                                if self._epoch != epoch:
+                                    return None
+                                p, t, lens = step_fn(params, p, t, tables,
+                                                     lens, active, k, apool,
+                                                     aidx)
+                                outs.append(t)
+                    return p, t, outs, spec_dev
 
-            out = self._with_watchdog(quantum_and_fetch,
-                                      armed=self._quantum_warm)
-            if out is None:         # only reachable through a stale epoch
-                raise DecodeDispatchHang("round abandoned by recovery")
-            p, t, toks, firsts, spec_host, (tq1, tq2) = out
-            ph["decode_ms"] = (tq1 - t_dec0) * 1e3
-            ph["fetch_ms"] = (tq2 - tq1) * 1e3
+                dev = self._with_watchdog(dispatch, armed=self._quantum_warm)
+                if dev is None:     # only reachable through a stale epoch
+                    raise DecodeDispatchHang("round abandoned by recovery")
+                p, t, outs, spec_dev = dev
+            ph["decode_ms"] = sp_dec.seconds * 1e3
+            # dispatch done / fetch begins: the split the doctor uses to
+            # tell dispatch-bound from fetch-bound. The ONE sync of the
+            # round: the sampled tokens (quantum steps or the verify step's
+            # accept verdict) AND every pending prefill/chunk token ride a
+            # single device_get (under its own watchdog: a device that
+            # never answers hangs HERE)
+            with span("ds:serve.fetch") as sp:
+                toks, firsts, spec_host = self._with_watchdog(
+                    lambda: jax.device_get(
+                        (jnp.stack(outs) if outs
+                         else jnp.zeros((0, S), jnp.int32),
+                         [f for _, f in pending], spec_dev)),
+                    armed=self._quantum_warm)
+            ph["fetch_ms"] = sp.seconds * 1e3
             if self._tracer is not None and decode:
                 for req in self.scheduler.running:
                     if req.prefill_done:
                         self._tracer.add_span(
                             req.rid, "decode_quantum",
-                            self._tracer.epoch(t_dec0),
-                            self._tracer.epoch(tq2),
+                            self._tracer.epoch(sp_dec.t0),
+                            self._tracer.epoch(sp.t0 + sp.seconds),
                             steps=(1 if spec
                                    else self.config.decode_quantum))
             if decode:
@@ -1433,20 +1457,19 @@ class ServingEngine:
         finally:
             if keep is not None:
                 self.allocator.set_reserve(0)
-        t0 = time.perf_counter()
-        if spec_host is not None:
-            finished = self._commit_spec(spec_host, pending, firsts)
-        else:
-            finished = self._commit_round(np.asarray(toks), pending, firsts)
-        ph["commit_ms"] = (time.perf_counter() - t0) * 1e3
-        ph["round_ms"] = (time.perf_counter() - t_round0) * 1e3
-        self._note_phases({**ph, "tokens": float(self._round_tokens)})
+        with span("ds:serve.commit") as sp:
+            if spec_host is not None:
+                finished = self._commit_spec(spec_host, pending, firsts)
+            else:
+                finished = self._commit_round(np.asarray(toks), pending,
+                                              firsts)
+        ph["commit_ms"] = sp.seconds * 1e3
         if self._tracer is not None:
             for req in finished:
                 self._tracer.instant(req.rid, "finish",
                                      tokens=len(req.generated))
                 self._tracer.end(req.rid)
-        return finished
+        return finished, ph
 
     def _note_tokens(self, req: Request, m: int, now: float) -> None:
         """Inter-token-latency bookkeeping: a commit burst of ``m`` tokens
@@ -1458,8 +1481,9 @@ class ServingEngine:
             return
         self._round_tokens += m        # phase ring's per-token denominator
         if req.last_token_t is not None:
-            per_tok = (now - req.last_token_t) * 1e3 / m
-            self._itl_ms.extend([per_tok] * m)
+            gap_ms = (now - req.last_token_t) * 1e3
+            self._itl_ms.extend([gap_ms / m] * m)
+            req.max_gap_ms = max(req.max_gap_ms or 0.0, gap_ms)
         req.last_token_t = now
 
     def _commit_round(self, toks, pending, firsts) -> List[Request]:
@@ -2227,6 +2251,8 @@ class ServingEngine:
         # latch and the tracer's sync self-report are window-scoped too —
         # the reset-parity sweep pins that every rollup counter clears
         self._phases.clear()
+        self._phase_totals = dict.fromkeys(self._PHASE_OUT, 0.0)
+        self._rounds = 0
         self._round_tokens = 0
         self._phase_stall_events = 0
         if self._tracer is not None:
@@ -2266,7 +2292,12 @@ class ServingEngine:
         counters (``spec_steps/proposed/accepted`` + ``spec_accept_rate``),
         the chunking counters (``prefill_chunks/chunk_tokens``),
         ``cow_forks``, and — with the cache armed — the ``prefix_*``
-        counters incl. ``prefix_hit_rate`` and ``prefix_held_blocks``."""
+        counters incl. ``prefix_hit_rate`` and ``prefix_held_blocks``.
+
+        Request lifecycle (always on): ``queue_wait_p50/p90_ms``
+        (``admit_t - submit_t``), ``first_token_wait_p50/p90_ms``
+        (``first_token_t - admit_t``) and ``token_gap_max_p50/p90_ms``
+        (each request's longest gap between two token deliveries)."""
         done = [r for r in self._finished if r.first_token_t is not None]
         out: Dict[str, float] = {
             "completed": float(len(self._finished)),
@@ -2309,6 +2340,19 @@ class ServingEngine:
             itl = np.asarray(self._itl_ms)
             out["p50_itl_ms"] = float(np.percentile(itl, 50))
             out["p99_itl_ms"] = float(np.percentile(itl, 99))
+        # the request lifecycle over the window's finished requests: with
+        # the caller's own due -> add_request lateness these split TTFT
+        # into queue wait and admission -> first token
+        for key, vals in (
+                ("queue_wait", [(r.admit_t - r.submit_t) * 1e3
+                                for r in done if r.admit_t is not None]),
+                ("first_token_wait", [(r.first_token_t - r.admit_t) * 1e3
+                                      for r in done if r.admit_t is not None]),
+                ("token_gap_max", [r.max_gap_ms for r in done
+                                   if r.max_gap_ms is not None])):
+            if vals:
+                out[f"{key}_p50_ms"] = float(np.percentile(vals, 50))
+                out[f"{key}_p90_ms"] = float(np.percentile(vals, 90))
         out.update({k: float(v) for k, v in self._lat.items()})
         if self._lat["spec_proposed"]:
             out["spec_accept_rate"] = float(round(

@@ -805,11 +805,13 @@ def _paged_attention(q, pool_k, pool_v, tables, index, cfg: TransformerConfig,
         return g.transpose(0, 2, 1, 3, 4).reshape(S, Nkv, MB * bs, D)
 
     sc = None
-    if kv_scale is not None:
-        ks, vs = kv_scale                        # [NB, Nkv, bs] f32
-        sc = tuple(jnp.take(s, tables, axis=0).transpose(0, 2, 1, 3)
-                   .reshape(S, Nkv, MB * bs) for s in (ks, vs))
-    return _decode_attention(q, view(pool_k), view(pool_v), index, cfg,
+    with jax.named_scope("kv_gather"):
+        if kv_scale is not None:
+            ks, vs = kv_scale                    # [NB, Nkv, bs] f32
+            sc = tuple(jnp.take(s, tables, axis=0).transpose(0, 2, 1, 3)
+                       .reshape(S, Nkv, MB * bs) for s in (ks, vs))
+        vk, vv = view(pool_k), view(pool_v)
+    return _decode_attention(q, vk, vv, index, cfg,
                              kv_row=kv_row, kv_scale=sc, window=window)
 
 
@@ -854,19 +856,21 @@ def _paged_span_attention(q, pool_k, pool_v, tables, prior_lens,
         g = jnp.take(pool, tables, axis=0)       # [S, MB, Nkv, bs, D]
         return g.transpose(0, 2, 1, 3, 4).reshape(S, Nkv, MB * bs, D)
 
-    vk, vv = view(pool_k), view(pool_v)
-    Tp = vk.shape[2]
+    with jax.named_scope("kv_gather"):
+        vk, vv = view(pool_k), view(pool_v)
+        Tp = vk.shape[2]
+        if kv_scale is not None:
+            ks, vs = kv_scale
+            ksg = jnp.take(ks, tables, axis=0).transpose(0, 2, 1, 3) \
+                .reshape(S, Nkv, Tp)
+            vsg = jnp.take(vs, tables, axis=0).transpose(0, 2, 1, 3) \
+                .reshape(S, Nkv, Tp)
     qg = q.transpose(0, 2, 1, 3).reshape(S, Nkv, rep, T, D)
     pos = prior_lens[:, None] + jnp.arange(T)[None, :]       # [S, T] abs
     if kv_scale is not None:
         # int8 pool, int8 math — the _decode_attention recipe batched
         # over T: quantize each query row, contract on the int8 MXU, fold
         # q/k scales into the scores
-        ks, vs = kv_scale
-        ksg = jnp.take(ks, tables, axis=0).transpose(0, 2, 1, 3) \
-            .reshape(S, Nkv, Tp)
-        vsg = jnp.take(vs, tables, axis=0).transpose(0, 2, 1, 3) \
-            .reshape(S, Nkv, Tp)
         q32 = qg.astype(jnp.float32)
         qs_ = jnp.maximum(jnp.max(jnp.abs(q32), axis=-1) / 127.0, 1e-8)
         qi = jnp.clip(jnp.round(q32 / qs_[..., None]), -127, 127
@@ -2044,12 +2048,16 @@ def decode_step_paged(params: Params, tokens, cfg: TransformerConfig,
     seq_lens = jnp.asarray(seq_lens, jnp.int32)
     if active is None:
         active = jnp.ones((S,), jnp.bool_)
-    x = params["tok_embed"][tokens[:, None]].astype(cfg.dtype)   # [S, 1, H]
-    if cfg.position_type == "learned":
-        x = x + params["pos_embed"][seq_lens][:, None].astype(cfg.dtype)
-    if cfg.embed_norm:
-        x = _norm(x, params["embed_norm_scale"],
-                  params.get("embed_norm_bias"), cfg)
+    # named scopes: the train forward's words (embed / layers / attn /
+    # mlp | moe / lm_head), plus attn/kv_gather and attn/kv_write for the
+    # pool traffic — a kernel's instruction name follows its scope
+    with jax.named_scope("embed"):
+        x = params["tok_embed"][tokens[:, None]].astype(cfg.dtype)  # [S,1,H]
+        if cfg.position_type == "learned":
+            x = x + params["pos_embed"][seq_lens][:, None].astype(cfg.dtype)
+        if cfg.embed_norm:
+            x = _norm(x, params["embed_norm_scale"],
+                      params.get("embed_norm_bias"), cfg)
     positions = seq_lens[:, None]                                # [S, 1]
     int8_kv = cfg.kv_cache_bits == 8
     bs = pools["k"].shape[3]
@@ -2085,40 +2093,43 @@ def decode_step_paged(params: Params, tokens, cfg: TransformerConfig,
             attn_window=None if wins is None else wins[i], lora=lora_i)
         return y, (k_row, v_row)
 
-    x, (k_rows, v_rows) = lax.scan(body, x, jnp.arange(cfg.num_layers))
+    with jax.named_scope("layers"):
+        x, (k_rows, v_rows) = lax.scan(body, x, jnp.arange(cfg.num_layers))
     # one [S, L, nkv, hd] scatter writes every layer's fresh row at
     # (block_tables[s, len // bs], len % bs); inactive slots hit the trash
     # block (duplicate trash writes are unordered and never read)
-    blk = jnp.take_along_axis(block_tables, (seq_lens // bs)[:, None],
-                              axis=1)[:, 0]
-    blk = jnp.where(active, blk, 0)
-    off = jnp.where(active, seq_lens % bs, 0)
-    if int8_kv:
-        kq, ks_ = _quant_kv(k_rows)           # [L, S, nkv, 1, hd] -> + [.,1]
-        vq, vs_ = _quant_kv(v_rows)
-        new_pools = {
-            "k": pools["k"].at[:, blk, :, off, :].set(
-                jnp.moveaxis(kq[:, :, :, 0, :], 1, 0)),
-            "v": pools["v"].at[:, blk, :, off, :].set(
-                jnp.moveaxis(vq[:, :, :, 0, :], 1, 0)),
-            "k_scale": pools["k_scale"].at[:, blk, :, off].set(
-                jnp.moveaxis(ks_[:, :, :, 0], 1, 0)),
-            "v_scale": pools["v_scale"].at[:, blk, :, off].set(
-                jnp.moveaxis(vs_[:, :, :, 0], 1, 0)),
-        }
-    else:
-        new_pools = {
-            "k": pools["k"].at[:, blk, :, off, :].set(
-                jnp.moveaxis(k_rows[:, :, :, 0, :].astype(pools["k"].dtype),
-                             1, 0)),
-            "v": pools["v"].at[:, blk, :, off, :].set(
-                jnp.moveaxis(v_rows[:, :, :, 0, :].astype(pools["v"].dtype),
-                             1, 0)),
-        }
-    if cfg.final_norm:
-        x = _norm(x, params["final_norm_scale"],
-                  params.get("final_norm_bias"), cfg)
-    logits = lm_head_logits(x, params)
+    with jax.named_scope("attn"), jax.named_scope("kv_write"):
+        blk = jnp.take_along_axis(block_tables, (seq_lens // bs)[:, None],
+                                  axis=1)[:, 0]
+        blk = jnp.where(active, blk, 0)
+        off = jnp.where(active, seq_lens % bs, 0)
+        if int8_kv:
+            kq, ks_ = _quant_kv(k_rows)       # [L, S, nkv, 1, hd] -> + [.,1]
+            vq, vs_ = _quant_kv(v_rows)
+            new_pools = {
+                "k": pools["k"].at[:, blk, :, off, :].set(
+                    jnp.moveaxis(kq[:, :, :, 0, :], 1, 0)),
+                "v": pools["v"].at[:, blk, :, off, :].set(
+                    jnp.moveaxis(vq[:, :, :, 0, :], 1, 0)),
+                "k_scale": pools["k_scale"].at[:, blk, :, off].set(
+                    jnp.moveaxis(ks_[:, :, :, 0], 1, 0)),
+                "v_scale": pools["v_scale"].at[:, blk, :, off].set(
+                    jnp.moveaxis(vs_[:, :, :, 0], 1, 0)),
+            }
+        else:
+            new_pools = {
+                "k": pools["k"].at[:, blk, :, off, :].set(
+                    jnp.moveaxis(
+                        k_rows[:, :, :, 0, :].astype(pools["k"].dtype), 1, 0)),
+                "v": pools["v"].at[:, blk, :, off, :].set(
+                    jnp.moveaxis(
+                        v_rows[:, :, :, 0, :].astype(pools["v"].dtype), 1, 0)),
+            }
+    with jax.named_scope("lm_head"):
+        if cfg.final_norm:
+            x = _norm(x, params["final_norm_scale"],
+                      params.get("final_norm_bias"), cfg)
+        logits = lm_head_logits(x, params)
     return logits[:, 0, :], new_pools
 
 
@@ -2156,13 +2167,14 @@ def decode_span_paged(params: Params, tokens, cfg: TransformerConfig,
         active = jnp.ones((S,), jnp.bool_)
     if n_rows is None:
         n_rows = jnp.full((S,), T, jnp.int32)
-    x = params["tok_embed"][tokens].astype(cfg.dtype)            # [S, T, H]
     positions = seq_lens[:, None] + jnp.arange(T)[None, :]       # [S, T]
-    if cfg.position_type == "learned":
-        x = x + params["pos_embed"][positions].astype(cfg.dtype)
-    if cfg.embed_norm:
-        x = _norm(x, params["embed_norm_scale"],
-                  params.get("embed_norm_bias"), cfg)
+    with jax.named_scope("embed"):
+        x = params["tok_embed"][tokens].astype(cfg.dtype)        # [S, T, H]
+        if cfg.position_type == "learned":
+            x = x + params["pos_embed"][positions].astype(cfg.dtype)
+        if cfg.embed_norm:
+            x = _norm(x, params["embed_norm_scale"],
+                      params.get("embed_norm_bias"), cfg)
     int8_kv = cfg.kv_cache_bits == 8
     bs = pools["k"].shape[3]
     MB = block_tables.shape[1]
@@ -2198,7 +2210,8 @@ def decode_span_paged(params: Params, tokens, cfg: TransformerConfig,
             attn_window=None if wins is None else wins[i], lora=lora_i)
         return y, (k_row, v_row)                 # rows: [S, nkv, T, hd]
 
-    x, (k_rows, v_rows) = lax.scan(body, x, jnp.arange(cfg.num_layers))
+    with jax.named_scope("layers"):
+        x, (k_rows, v_rows) = lax.scan(body, x, jnp.arange(cfg.num_layers))
     # one [S*T]-row scatter writes every (slot, position) pair's fresh row
     # across all layers; pad/inactive rows route to the trash block 0
     # (duplicate trash writes are unordered and never read). Positions at
@@ -2220,31 +2233,33 @@ def decode_span_paged(params: Params, tokens, cfg: TransformerConfig,
             a = a.astype(dtype)
         return a.reshape((S * T,) + a.shape[2:])
 
-    if int8_kv:
-        kq, ks_ = _quant_kv(k_rows)              # scales [L, S, nkv, T]
-        vq, vs_ = _quant_kv(v_rows)
+    def flat_s(s):                               # [L,S,nkv,T] -> [S*T,...]
+        return jnp.transpose(s, (1, 3, 0, 2)).reshape(S * T, -1, s.shape[2])
 
-        def flat_s(s):                           # [L,S,nkv,T] -> [S*T,...]
-            return jnp.transpose(s, (1, 3, 0, 2)).reshape(S * T, -1,
-                                                          s.shape[2])
-
-        new_pools = {
-            "k": pools["k"].at[:, blk, :, off, :].set(flat(kq)),
-            "v": pools["v"].at[:, blk, :, off, :].set(flat(vq)),
-            "k_scale": pools["k_scale"].at[:, blk, :, off].set(flat_s(ks_)),
-            "v_scale": pools["v_scale"].at[:, blk, :, off].set(flat_s(vs_)),
-        }
-    else:
-        new_pools = {
-            "k": pools["k"].at[:, blk, :, off, :].set(
-                flat(k_rows, pools["k"].dtype)),
-            "v": pools["v"].at[:, blk, :, off, :].set(
-                flat(v_rows, pools["v"].dtype)),
-        }
-    if cfg.final_norm:
-        x = _norm(x, params["final_norm_scale"],
-                  params.get("final_norm_bias"), cfg)
-    return lm_head_logits(x, params), new_pools
+    with jax.named_scope("attn"), jax.named_scope("kv_write"):
+        if int8_kv:
+            kq, ks_ = _quant_kv(k_rows)          # scales [L, S, nkv, T]
+            vq, vs_ = _quant_kv(v_rows)
+            new_pools = {
+                "k": pools["k"].at[:, blk, :, off, :].set(flat(kq)),
+                "v": pools["v"].at[:, blk, :, off, :].set(flat(vq)),
+                "k_scale": pools["k_scale"].at[:, blk, :, off].set(
+                    flat_s(ks_)),
+                "v_scale": pools["v_scale"].at[:, blk, :, off].set(
+                    flat_s(vs_)),
+            }
+        else:
+            new_pools = {
+                "k": pools["k"].at[:, blk, :, off, :].set(
+                    flat(k_rows, pools["k"].dtype)),
+                "v": pools["v"].at[:, blk, :, off, :].set(
+                    flat(v_rows, pools["v"].dtype)),
+            }
+    with jax.named_scope("lm_head"):
+        if cfg.final_norm:
+            x = _norm(x, params["final_norm_scale"],
+                      params.get("final_norm_bias"), cfg)
+        return lm_head_logits(x, params), new_pools
 
 
 def prefill_paged(params: Params, input_ids, cfg: TransformerConfig,
@@ -2273,13 +2288,16 @@ def prefill_paged(params: Params, input_ids, cfg: TransformerConfig,
         L_, _, nkv, _ = a.shape
         return a[:, 0].reshape(L_, nkv, nblk, bs).transpose(0, 2, 1, 3)
 
-    new_pools = {"k": pools["k"].at[:, block_ids].set(to_blocks(cache["k"])),
-                 "v": pools["v"].at[:, block_ids].set(to_blocks(cache["v"]))}
-    if cfg.kv_cache_bits == 8:
-        new_pools["k_scale"] = pools["k_scale"].at[:, block_ids].set(
-            to_blocks_s(cache["k_scale"]))
-        new_pools["v_scale"] = pools["v_scale"].at[:, block_ids].set(
-            to_blocks_s(cache["v_scale"]))
+    # forward() carries embed / layers / attn / mlp | moe / lm_head
+    with jax.named_scope("attn"), jax.named_scope("kv_write"):
+        new_pools = {
+            "k": pools["k"].at[:, block_ids].set(to_blocks(cache["k"])),
+            "v": pools["v"].at[:, block_ids].set(to_blocks(cache["v"]))}
+        if cfg.kv_cache_bits == 8:
+            new_pools["k_scale"] = pools["k_scale"].at[:, block_ids].set(
+                to_blocks_s(cache["k_scale"]))
+            new_pools["v_scale"] = pools["v_scale"].at[:, block_ids].set(
+                to_blocks_s(cache["v_scale"]))
     return last, new_pools
 
 

@@ -175,5 +175,6 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, seq_lens, *,
         out_shape=jax.ShapeDtypeStruct((S, Nkv, rep, D), q.dtype),
         compiler_params=compiler_params,
         interpret=_interpret(),
+        name="paged_decode",
     )(tables, lens, qg, k_pool, v_pool, k_row, v_row)
     return o.reshape(S, 1, Nq, D)

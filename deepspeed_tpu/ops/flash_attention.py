@@ -246,6 +246,7 @@ def _fwd(q, k, v, kv_mask, sm_scale, causal, block_q, block_k):
         ],
         compiler_params=_compiler_params(3),
         interpret=_interpret(),
+        name="flash_fwd",
     )(qg, k, v, *extra)
     return o.reshape(B, N, S, D), lse.reshape(B, N, S, 1)
 
@@ -435,6 +436,7 @@ def _bwd(sm_scale, causal, block_q, block_k, fused, residuals, g):
         + ([pltpu.VMEM((rows, 128), jnp.float32)] if fused else []),
         compiler_params=_compiler_params(3),
         interpret=_interpret(),
+        name="flash_dq",
     )(qg, k, v, dog, lseg, auxg, *extra)
 
     # ---- dK/dV: grid (B, Nkv, num_kv, num_q), Q innermost ----
@@ -466,6 +468,7 @@ def _bwd(sm_scale, causal, block_q, block_k, fused, residuals, g):
         ],
         compiler_params=_compiler_params(3),
         interpret=_interpret(),
+        name="flash_dkv",
     )(qg, k, v, dog, lseg, auxg, *extra)
     return dq.reshape(B, N, S, D), dk, dv
 

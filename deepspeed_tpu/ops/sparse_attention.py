@@ -469,6 +469,7 @@ def _sp_fwd(q, k, v, idx, cnt, sm_scale, causal, block):
         ],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        name="sparse_fwd",
     )(idx, cnt, q, jnp.swapaxes(k, 2, 3), jnp.swapaxes(v, 2, 3))
     return o, lse
 
@@ -499,6 +500,7 @@ def _sp_bwd(sm_scale, causal, block, adjacency, residuals, g):
         out_shape=jax.ShapeDtypeStruct((B, N, S, D), q.dtype),
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        name="sparse_dq",
     )(idx, cnt, q, jnp.swapaxes(k, 2, 3), jnp.swapaxes(v, 2, 3), do, lse,
       delta)
 
@@ -516,6 +518,7 @@ def _sp_bwd(sm_scale, causal, block, adjacency, residuals, g):
                    jax.ShapeDtypeStruct((B, N, S, D), q.dtype)],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        name="sparse_dkv",
     )(cidx, ccnt, jnp.swapaxes(q, 2, 3), k, v, jnp.swapaxes(do, 2, 3),
       jnp.swapaxes(lse, 2, 3), jnp.swapaxes(delta, 2, 3))
     return dq, dk, dv

@@ -13,6 +13,8 @@ from typing import Any, Callable, Iterable, Iterator, Optional
 
 import numpy as np
 
+from deepspeed_tpu.telemetry.tracing import span
+
 
 class DataLoader:
     """Minimal batching loader over an indexable dataset of dict rows (or a
@@ -108,8 +110,9 @@ class PrefetchLoader:
     wrapped loader's order — prefetch reorders nothing, including across
     epoch boundaries (``set_epoch``/``epoch`` proxy through).
 
-    ``tracer`` (a telemetry ``StepTracer``) records each device_put top-up
-    as a ``prefetch`` span in the step trace timeline.
+    Each device_put top-up is a ``ds:train.prefetch`` span (visible to any
+    profiler session); ``tracer`` (a telemetry ``StepTracer``) also records
+    it as a ``prefetch`` span in the step trace timeline.
     """
 
     def __init__(self, loader, put_fn: Callable[[Any], Any], depth: int = 2,
@@ -123,10 +126,9 @@ class PrefetchLoader:
         self.tracer = tracer
 
     def _put(self, batch):
-        if self.tracer is not None:
-            with self.tracer.span("prefetch", cat="data"):
-                return self.put_fn(batch)
-        return self.put_fn(batch)
+        with (self.tracer.span("prefetch", cat="data")
+              if self.tracer is not None else span("ds:train.prefetch")):
+            return self.put_fn(batch)
 
     def __len__(self):
         return len(self.loader)

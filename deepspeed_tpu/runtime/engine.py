@@ -20,7 +20,6 @@ API parity:
   engine.global_steps, get_lr, get_loss_scale, ...
 """
 
-import contextlib
 import dataclasses
 import json
 import math
@@ -45,6 +44,7 @@ from deepspeed_tpu.runtime import zero as zero_mod
 from deepspeed_tpu.runtime import checkpointing as ckpt_mod
 from deepspeed_tpu.runtime.lr_schedules import get_scheduler
 from deepspeed_tpu.telemetry import accumulators as tel_acc
+from deepspeed_tpu.telemetry.tracing import span as _span
 from deepspeed_tpu.utils import logging as log_mod
 from deepspeed_tpu.utils.timer import SynchronizedWallClockTimer, ThroughputTimer
 
@@ -2049,9 +2049,11 @@ class Engine:
             self._tel_wall_steps = self.global_steps
 
     def _tel_span(self, name: str):
-        """Tracer span when telemetry is on, else a no-op context."""
+        """The host phase ``ds:train.<name>``, always: a profiler session
+        sees it with or without telemetry. With telemetry on it goes
+        through the StepTracer, which also feeds its ring and window sums."""
         return (self._tracer.span(name) if self._tracer is not None
-                else contextlib.nullcontext())
+                else _span(f"ds:train.{name}"))
 
     def _capture_static_args(self, fn, args, divisor: int):
         """Remember the jitted step + abstract arg shapes ONCE so the lazy

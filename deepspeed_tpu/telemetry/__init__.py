@@ -7,9 +7,12 @@ serving with zero added steady-state syncs). Four pieces:
                  ["telemetry"]`` leaf, advanced inside the jitted step and
                  drained through ``engine._log_step``'s ONE batched
                  device_get; windows are host-side snapshot diffs
-  tracing      — host span recorder around the dispatch/prefetch/block
-                 phases of ``engine.train_batches`` (Chrome-trace export)
-                 plus windowed ``jax.profiler`` capture
+  tracing      — ``span``: the one host-span primitive (a
+                 ``jax.profiler.TraceAnnotation`` named ``ds:<layer>.<phase>``
+                 plus the elapsed seconds), always on in both engines; the
+                 span recorder around the dispatch/prefetch/block phases of
+                 ``engine.train_batches`` (Chrome-trace export) and the
+                 windowed ``jax.profiler`` capture
   anomaly      — structured-severity events (loss spikes, grad-norm drift,
                  overflow bursts, dispatch-stall regressions) from the
                  drained window stats
@@ -50,12 +53,12 @@ from deepspeed_tpu.telemetry.exposition import (Histogram, parse_exposition,
 from deepspeed_tpu.telemetry.join import joined_rates, static_step_cost
 from deepspeed_tpu.telemetry.request_trace import (RequestTracer,
                                                    merge_chrome_trace)
-from deepspeed_tpu.telemetry.tracing import StepTracer
+from deepspeed_tpu.telemetry.tracing import StepTracer, span
 
 __all__ = [
     "HIST_BUCKETS", "HIST_LOG2_MIN", "AnomalyDetector", "Histogram",
     "HostWindow", "RequestTracer", "SEVERITY_NUM", "StepTracer", "accumulate",
     "init_leaf", "joined_rates", "merge_chrome_trace", "parse_exposition",
-    "render_prometheus", "severity_num", "static_step_cost",
+    "render_prometheus", "severity_num", "span", "static_step_cost",
     "update_to_param_ratio", "window_stats",
 ]

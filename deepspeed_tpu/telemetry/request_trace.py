@@ -12,8 +12,8 @@ re-prefill, adapter page-in, drain and migration).
 
 Design rules, in priority order:
 
-* **Zero added device syncs.** Span bookkeeping is two ``perf_counter``
-  calls and a deque append — no ``device_get``, no ``block_until_ready``.
+* **Zero added device syncs.** Span bookkeeping is one
+  :func:`~deepspeed_tpu.telemetry.tracing.span` and a deque append — no ``device_get``, no ``block_until_ready``.
   A tracing-armed engine is bit-identical to an untraced one (pinned by
   ``test_fleet_obs``). The ``on_span`` hook is the documented defect seam:
   anything it does per span is on the caller, and :data:`device_syncs`
@@ -38,11 +38,12 @@ the trace id so Perfetto's flow queries can follow a migration.
 """
 
 import collections
-import contextlib
 import json
 import os
 import time
 from typing import Any, Callable, Dict, Iterable, List, Optional
+
+from deepspeed_tpu.telemetry.tracing import span as _span
 
 __all__ = ["RequestTracer", "merge_chrome_trace"]
 
@@ -120,13 +121,10 @@ class RequestTracer:
             ev["args"] = args
         self._append(ev)
 
-    @contextlib.contextmanager
     def span(self, rid: str, name: str, cat: str = "serve", **args: Any):
-        t0 = self._now()
-        try:
-            yield
-        finally:
-            self.add_span(rid, name, t0, self._now(), cat=cat, **args)
+        """Timed by the package's one primitive: the span is also a
+        ``ds:request.<name>`` annotation in any profiler session."""
+        return _RequestSpan(self, rid, name, cat, args)
 
     def instant(self, rid: str, name: str, **args: Any) -> None:
         ev = {"name": name, "cat": "event", "ph": "i", "s": "t",
@@ -167,6 +165,23 @@ class RequestTracer:
     def export(self) -> Dict[str, Any]:
         """One replica's stream, mergeable by :func:`merge_chrome_trace`."""
         return {"replica": self.replica, "events": list(self.events)}
+
+
+class _RequestSpan(_span):
+    """The primitive, recorded into its tracer's ring on exit (a class and
+    not a generator: the serving doctor prices the per-span cost)."""
+    __slots__ = ("_rec",)
+
+    def __init__(self, tracer: RequestTracer, rid, name, cat, args):
+        _span.__init__(self, "ds:request." + name, rid=rid)
+        self._rec = (tracer, rid, name, cat, args)
+
+    def __exit__(self, *exc) -> bool:
+        _span.__exit__(self, *exc)
+        tracer, rid, name, cat, args = self._rec
+        t0 = tracer.epoch(self.t0)
+        tracer.add_span(rid, name, t0, t0 + self.seconds, cat=cat, **args)
+        return False
 
 
 def merge_chrome_trace(streams: Iterable[Dict[str, Any]],

@@ -1,4 +1,24 @@
-"""Step tracing: host-side span recorder + windowed jax.profiler capture.
+"""Host spans: the one primitive (:func:`span`), the train step's span
+recorder (:class:`StepTracer`) and the windowed jax.profiler capture.
+
+:func:`span` is the only way this package opens a host span. It enters a
+``jax.profiler.TraceAnnotation``, so during ANY profiler session — the
+benchmark's or an operator's — the span lies on the host plane of the trace,
+on the same clock as the device planes, and it hands the elapsed seconds to
+its caller. No ring, no export, no switch: with no session active an
+annotation costs well under a microsecond. Every program span is named
+``ds:<layer>.<phase>``; the whole vocabulary:
+
+  ``ds:serve.round`` and, inside it, once per round in this order:
+  ``ds:serve.schedule``, ``ds:serve.housekeeping``,
+  ``ds:serve.prefill_dispatch``, ``ds:serve.decode_dispatch``,
+  ``ds:serve.fetch``, ``ds:serve.commit`` (``ServingEngine.step`` / ``_round``);
+  ``ds:train.dispatch``, ``ds:train.prefetch``, ``ds:train.data_wait``,
+  ``ds:train.block`` (``Engine.train_batch`` / ``train_batches``);
+  ``ds:request.<phase>`` — ``RequestTracer``'s per-request spans, only
+  while request tracing is armed.
+
+Step tracing: host-side span recorder + windowed jax.profiler capture.
 
 Reference analogue: ``deepspeed/utils/timer.py`` wall-clock timers plus the
 ``flops_profiler``'s latency printouts — all eager, all per step. Under async
@@ -19,8 +39,8 @@ Spans are appended to a bounded ring and exported as Chrome-trace JSON
 comes from the complementary windowed ``jax.profiler.start_trace`` capture
 (:meth:`StepTracer.maybe_profile`), configured via ``telemetry.trace``.
 
-Per-span cost is two ``perf_counter`` calls and a deque append — safe to
-leave on in the steady-state loop.
+Per-span cost is one :func:`span` and a deque append — safe to leave on in
+the steady-state loop.
 """
 
 import collections
@@ -30,7 +50,36 @@ import os
 import time
 from typing import Any, Dict, Optional
 
+from jax.profiler import TraceAnnotation
+
 from deepspeed_tpu.utils.logging import logger
+
+
+class span:
+    """``with span("ds:serve.fetch") as sp: ...`` — a TraceAnnotation on the
+    profiler's clock around the block; afterwards ``sp.seconds`` is the
+    elapsed host time and ``sp.t0`` the ``perf_counter`` reading at entry.
+    ``**args`` (and :meth:`note`, for what is known only at the end) become
+    the annotation's arguments; they are encoded only while a profiler
+    session is active."""
+    __slots__ = ("t0", "seconds", "_ann")
+
+    def __init__(self, name: str, **args: Any):
+        self._ann = TraceAnnotation(name, **args)
+        self.t0 = self.seconds = 0.0
+
+    def note(self, **args: Any) -> None:
+        self._ann.set_metadata(**args)
+
+    def __enter__(self) -> "span":
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.seconds = time.perf_counter() - self.t0
+        self._ann.__exit__(*exc)
+        return False
 
 
 class StepTracer:
@@ -49,17 +98,19 @@ class StepTracer:
 
     @contextlib.contextmanager
     def span(self, name: str, cat: str = "step"):
-        t0 = time.perf_counter()
+        """``ds:train.<name>`` through :func:`span`, then into the ring and
+        the window sums under the bare ``name``."""
+        sp = span(f"ds:train.{name}")
         try:
-            yield
+            with sp:
+                yield
         finally:
-            t1 = time.perf_counter()
             self.events.append({
                 "name": name, "cat": cat, "ph": "X",
-                "ts": (t0 - self._t0) * 1e6, "dur": (t1 - t0) * 1e6,
+                "ts": (sp.t0 - self._t0) * 1e6, "dur": sp.seconds * 1e6,
                 "pid": self._pid, "tid": 0,
             })
-            self._window_s[name] = self._window_s.get(name, 0.0) + (t1 - t0)
+            self._window_s[name] = self._window_s.get(name, 0.0) + sp.seconds
             self._window_n[name] = self._window_n.get(name, 0) + 1
 
     def instant(self, name: str, args: Optional[Dict[str, Any]] = None):

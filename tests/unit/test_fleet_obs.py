@@ -187,11 +187,14 @@ class _PhaseRig:
     from deepspeed_tpu.inference.serving import ServingEngine as _SE
     _STALL_MIN_ROUND_MS = _SE._STALL_MIN_ROUND_MS
     _STALL_FRACTION = _SE._STALL_FRACTION
+    _PHASE_OUT = _SE._PHASE_OUT
     _note_phases = _SE._note_phases
     phase_decomposition = _SE.phase_decomposition
 
     def __init__(self, warm=True):
         self._phases = collections.deque(maxlen=256)
+        self._phase_totals = dict.fromkeys(self._PHASE_OUT, 0.0)
+        self._rounds = 0
         self._quantum_warm = warm
         self._phase_stall_events = 0
         self._tracer = None

@@ -1,0 +1,144 @@
+"""Every ``pl.pallas_call`` of the program has a stable name (ISSUE 23).
+
+A per-kernel reading of a device trace keys on the Mosaic custom call's
+instruction name. Unnamed, that is the enclosing ``named_scope`` on one
+chip (``%attn.N``) and ``%shard_map.N`` under a mesh, so the forward, dQ
+and dK/dV kernels cannot be told apart. ``name=`` puts the kernel's own
+name innermost: in the lowered text's locations (checked here on the CPU,
+interpret mode) and — the reading that matters — in the instruction names
+of the program the TPU's compiler builds, on one chip AND mapped over a
+2x2 mesh (compiled here for a described v5e, no chip attached; all in this
+one file, behind a fixture, so only this file's worker loads libtpu).
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.ops import decode_attention as da
+from deepspeed_tpu.ops import flash_attention as fa
+from deepspeed_tpu.ops import sparse_attention as sa
+
+
+def _flash_grads(wrap=lambda f: f):
+    def loss(q, k, v):
+        with jax.named_scope("attn"):
+            o = wrap(lambda q, k, v: fa.flash_attention(q, k, v, causal=True)
+                     )(q, k, v)
+        return (o.astype(jnp.float32) ** 2).sum()
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+
+
+def _paged(q, kp, vp, tables, lens, kr, vr):
+    return da.paged_decode_attention(q, kp, vp, tables, lens, kv_row=(kr, vr))
+
+
+# ---- the lowered text, on the CPU ------------------------------------------
+
+def test_flash_kernel_names_in_the_lowered_text():
+    q = jnp.zeros((1, 128, 4, 64), jnp.float32)
+    k = jnp.zeros((1, 128, 2, 64), jnp.float32)
+    text = _flash_grads().lower(q, k, k).as_text(debug_info=True)
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert re.search(rf'"jit\(loss\)/[^"]*\b{name}\b[^"]*/pallas_call"',
+                         text), name
+
+
+def test_paged_decode_kernel_name_in_the_lowered_text():
+    S, NB, Nkv, bs, D, MB = 2, 5, 2, 16, 64, 2
+    text = jax.jit(_paged).lower(
+        jnp.zeros((S, 1, 4, D)), jnp.zeros((NB, Nkv, bs, D)),
+        jnp.zeros((NB, Nkv, bs, D)), jnp.zeros((S, MB), jnp.int32),
+        jnp.zeros((S,), jnp.int32), jnp.zeros((S, Nkv, 1, D)),
+        jnp.zeros((S, Nkv, 1, D))).as_text(debug_info=True)
+    assert re.search(r'"jit\(_paged\)/paged_decode/pallas_call"', text)
+
+
+def test_sparse_kernel_names_in_the_lowered_text():
+    cfg = sa.get_sparsity_config("fixed", block=16, num_local_blocks=2)
+    q = jnp.zeros((1, 64, 2, 64), jnp.float32)
+
+    def loss(q, k, v):
+        return sa.sparse_attention(q, k, v, cfg, causal=True).sum()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q).as_text(
+        debug_info=True)
+    for name in ("sparse_fwd", "sparse_dq", "sparse_dkv"):
+        assert re.search(rf'\b{name}\b[^"]*/pallas_call"', text), name
+
+
+# ---- the instruction names the TPU's compiler gives -------------------------
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu from loading
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture()
+def mosaic(monkeypatch):
+    """The kernels ask ``jax.default_backend()`` (the CPU here) whether to
+    interpret: steer them to the Mosaic lowering, in the test. The suite's
+    "highest" matmul precision is the CPU parity tests' (conftest.py); the
+    chip runs the kernels at the default, and at "highest" their fp32
+    passes do not fit VMEM."""
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    monkeypatch.setattr(da, "_interpret", lambda: False)
+    with jax.default_matmul_precision("default"):
+        yield
+
+
+def _mosaic_calls(compiled):
+    return sorted(set(re.findall(
+        r'(%[\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"',
+        compiled.as_text())))
+
+
+B, S, NQ, NKV, D = 4, 2048, 32, 8, 128          # the train cells' attention
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def test_flash_instruction_names_on_one_chip(topo, mosaic):
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    q, kv = (_sds((B, S, n, D), jnp.bfloat16, one) for n in (NQ, NKV))
+    calls = _mosaic_calls(_flash_grads().lower(q, kv, kv).compile())
+    assert [c.split(".")[0] for c in calls] == [
+        "%flash_dkv", "%flash_dq", "%flash_fwd"], calls
+
+
+def test_flash_instruction_names_under_a_mesh(topo, mosaic):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("fsdp", "tensor"))
+    spec = P("fsdp", None, "tensor", None)
+
+    def over_mesh(f):
+        return jax.shard_map(f, mesh=mesh, in_specs=(spec,) * 3,
+                             out_specs=spec, check_vma=False)
+    ns = NamedSharding(mesh, spec)
+    q, kv = (_sds((B, S, n, D), jnp.bfloat16, ns) for n in (NQ, NKV))
+    calls = _mosaic_calls(_flash_grads(over_mesh).lower(q, kv, kv).compile())
+    assert [c.split(".")[0] for c in calls] == [
+        "%flash_dkv", "%flash_dq", "%flash_fwd"], calls
+
+
+def test_paged_decode_instruction_name(topo, mosaic):
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    slots, NB, bs, MB = 8, 65, 64, 16
+    row = _sds((slots, NKV, 1, D), jnp.bfloat16, one)
+    pool = _sds((NB, NKV, bs, D), jnp.bfloat16, one)
+    calls = _mosaic_calls(jax.jit(_paged).lower(
+        _sds((slots, 1, NQ, D), jnp.bfloat16, one), pool, pool,
+        _sds((slots, MB), jnp.int32, one), _sds((slots,), jnp.int32, one),
+        row, row).compile())
+    assert [c.split(".")[0] for c in calls] == ["%paged_decode"], calls

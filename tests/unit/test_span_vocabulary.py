@@ -1,0 +1,360 @@
+"""One span vocabulary on the profiler's clock (ISSUE 23).
+
+``telemetry.tracing.span`` is the only way the program opens a host span: a
+``jax.profiler.TraceAnnotation`` named ``ds:<layer>.<phase>`` plus the
+elapsed seconds. Pinned here, with a REAL profiler session on the CPU
+backend (the host plane works without a chip):
+
+  - the primitive lands in the trace, returns elapsed time, and nests;
+  - a serving round is one ``ds:serve.round`` holding each phase once;
+  - ``phase_decomposition()`` sums the whole stats window, not a ring;
+  - the request lifecycle (``admit_t``, ``max_gap_ms``) and the six
+    ``stats()`` keys, a preempted request included, and ``admit_t``
+    agreeing with ``RequestTracer``'s ``queue_wait`` span;
+  - the train loop's host phases are in the trace WITHOUT telemetry.
+"""
+
+import collections
+import glob
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu
+from deepspeed_tpu.models import TransformerConfig, make_model
+from deepspeed_tpu.telemetry import RequestTracer, StepTracer, span
+
+SERVE_PHASES = ["ds:serve.schedule", "ds:serve.housekeeping",
+                "ds:serve.prefill_dispatch", "ds:serve.decode_dispatch",
+                "ds:serve.fetch", "ds:serve.commit"]
+
+
+class _Session:
+    """A profiler session; afterwards ``.spans`` holds every ``ds:`` event
+    of the host plane as (name, start_ns, end_ns, stats)."""
+
+    def __init__(self, tmp_path):
+        self.dir = str(tmp_path / "trace")
+        self.spans = []
+
+    def __enter__(self):
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(self.dir, profiler_options=opts)
+        return self
+
+    def __exit__(self, *exc):
+        jax.profiler.stop_trace()
+        (path,) = glob.glob(os.path.join(self.dir, "**", "*.xplane.pb"),
+                            recursive=True)
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("ds:"):
+                        self.spans.append((e.name, e.start_ns,
+                                           e.start_ns + e.duration_ns,
+                                           dict(e.stats)))
+        self.spans.sort(key=lambda s: s[1])
+        return False
+
+    def named(self, name):
+        return [s for s in self.spans if s[0] == name]
+
+
+def _tiny_model(layers=1, seq=64):
+    return make_model(TransformerConfig(
+        vocab_size=128, hidden_size=64, num_layers=layers, num_heads=4,
+        num_kv_heads=2, max_seq_len=seq, position_type="rotary",
+        activation="silu_glu", norm_type="rmsnorm", tie_embeddings=False,
+        dtype=jnp.float32, attention_impl="xla"))
+
+
+def _serving(model=None, **kw):
+    d = dict(max_seqs=2, block_size=16, max_model_len=64, decode_quantum=2,
+             prompt_bucket=16, decode_backend="xla")
+    d.update(kw)
+    return deepspeed_tpu.init_serving(model or _tiny_model(), config={},
+                                      serving=d, dtype=jnp.float32)
+
+
+def _prompt(n, seed=0):
+    return np.random.default_rng(seed).integers(0, 128, (n,)).astype(np.int32)
+
+
+def _add(srv, n, new, seed=0):
+    """add_request, and the Request the scheduler made of it."""
+    srv.add_request(_prompt(n, seed), new)
+    return srv.scheduler.waiting[-1]
+
+
+# ---------------------------------------------------------------------------
+# the primitive
+# ---------------------------------------------------------------------------
+
+class TestSpanPrimitive:
+    def test_returns_elapsed_time_without_a_session(self):
+        with span("ds:test.outer") as sp:
+            time.sleep(0.01)
+        assert 0.01 <= sp.seconds < 1.0
+        assert sp.t0 <= time.perf_counter() - sp.seconds
+
+    def test_opens_a_trace_annotation_and_nests(self, tmp_path):
+        with _Session(tmp_path) as ses:
+            with span("ds:test.outer", index=3) as outer:
+                with span("ds:test.inner") as inner:
+                    time.sleep(0.005)
+                outer.note(tokens=17)
+        (o,), (i,) = ses.named("ds:test.outer"), ses.named("ds:test.inner")
+        assert o[1] <= i[1] and i[2] <= o[2]            # nested, one clock
+        assert o[3] == {"index": 3, "tokens": 17} and i[3] == {}
+        # the profiler's duration and the caller's seconds are one span
+        assert (i[2] - i[1]) / 1e9 == pytest.approx(inner.seconds, abs=2e-3)
+        assert outer.seconds >= inner.seconds >= 0.005
+
+    def test_an_exception_passes_through_and_still_times(self):
+        with pytest.raises(KeyError):
+            with span("ds:test.raises") as sp:
+                raise KeyError("x")
+        assert sp.seconds >= 0.0
+
+    def test_step_tracer_times_through_it(self, tmp_path):
+        tr = StepTracer()
+        with _Session(tmp_path) as ses:
+            with tr.span("dispatch"):
+                time.sleep(0.002)
+        (ev,) = list(tr.events)
+        assert ev["name"] == "dispatch" and ev["dur"] >= 2000.0
+        assert tr.drain_window()["dispatch_count"] == 1
+        (s,) = ses.named("ds:train.dispatch")
+        assert (s[2] - s[1]) / 1e3 == pytest.approx(ev["dur"], abs=2000.0)
+
+    def test_request_tracer_times_through_it(self, tmp_path):
+        tr = RequestTracer(replica="rA")
+        tr.begin(7)
+        with _Session(tmp_path) as ses:
+            with tr.span(7, "prefill", tokens=5):
+                time.sleep(0.002)
+        (ev,) = [e for e in tr.events if e["name"] == "prefill"]
+        assert ev["dur"] >= 2000.0 and ev["args"] == {"tokens": 5}
+        assert abs(ev["ts"] / 1e6 - time.time()) < 60.0  # unix-epoch anchored
+        (s,) = ses.named("ds:request.prefill")
+        assert s[3] == {"rid": 7}
+
+
+# ---------------------------------------------------------------------------
+# the serving round
+# ---------------------------------------------------------------------------
+
+class TestServingRoundSpans:
+    def test_each_phase_once_per_round_inside_one_round_span(self, tmp_path):
+        srv = _serving()
+        srv.run([(_prompt(9), 4)])                  # compiles, off the trace
+        srv.reset_stats()
+        for k in (5, 11, 7):
+            srv.add_request(_prompt(k, seed=k), 5)
+        rounds = 0
+        with _Session(tmp_path) as ses:
+            while srv.scheduler.running or srv.scheduler.num_waiting:
+                srv.step()
+                rounds += 1
+        outer = ses.named("ds:serve.round")
+        assert len(outer) == rounds >= 3
+        assert [o[3]["index"] for o in outer] == list(range(rounds))
+        assert sum(o[3]["tokens"] for o in outer) == 15
+        for name in SERVE_PHASES:
+            assert len(ses.named(name)) == rounds, name
+        for _, lo, hi, _ in outer:
+            inside = [s for s in ses.spans if lo <= s[1] and s[2] <= hi
+                      and s[0] != "ds:serve.round"]
+            assert [s[0] for s in inside] == SERVE_PHASES  # once each, in order
+            assert all(a[2] <= b[1] for a, b in zip(inside, inside[1:]))
+        # the same rounds, as the host totals the doctor reads
+        d = srv.phase_decomposition()
+        assert d["serve_rounds"] == rounds and d["serve_tokens"] == 15.0
+        phases = sum(d[f"serve_{p}_ms"] for p in
+                     ("schedule", "housekeeping", "prefill_dispatch",
+                      "decode_dispatch", "fetch", "commit"))
+        assert 0.0 < phases <= d["serve_round_ms"]
+        traced_ms = sum(o[2] - o[1] for o in outer) / 1e6
+        assert d["serve_round_ms"] == pytest.approx(traced_ms, rel=0.05,
+                                                    abs=2.0)
+        srv.close()
+
+    def test_a_round_with_nothing_running_still_closes_its_span(self, tmp_path):
+        srv = _serving()
+        with _Session(tmp_path) as ses:
+            assert srv.step() == []
+        assert len(ses.named("ds:serve.round")) == 1
+        assert len(ses.named("ds:serve.schedule")) == 1
+        assert ses.named("ds:serve.fetch") == []
+        assert srv.phase_decomposition()["serve_rounds"] == 1.0
+        srv.close()
+
+    def test_fetch_runs_under_the_watchdog_and_keeps_its_span(self, tmp_path):
+        srv = _serving(dispatch_timeout_s=30.0)
+        srv.run([(_prompt(9), 4)])                  # arms the watchdog
+        srv.add_request(_prompt(6), 4)
+        with _Session(tmp_path) as ses:
+            srv.step()
+        assert len(ses.named("ds:serve.decode_dispatch")) == 1
+        assert len(ses.named("ds:serve.fetch")) == 1
+        assert srv.close() is True
+
+
+class TestPhaseTotals:
+    def _rig(self):
+        from deepspeed_tpu.inference.serving import ServingEngine as SE
+
+        class Rig:
+            _STALL_MIN_ROUND_MS = SE._STALL_MIN_ROUND_MS
+            _STALL_FRACTION = SE._STALL_FRACTION
+            _PHASE_OUT = SE._PHASE_OUT
+            _note_phases = SE._note_phases
+            phase_decomposition = SE.phase_decomposition
+
+            def __init__(self):
+                self._phases = collections.deque(maxlen=256)
+                self._phase_totals = dict.fromkeys(self._PHASE_OUT, 0.0)
+                self._rounds = 0
+                self._quantum_warm = True
+                self._phase_stall_events = 0
+                self._tracer = None
+        return Rig()
+
+    def test_sums_more_rounds_than_the_ring_holds(self):
+        rig = self._rig()
+        entry = {"schedule_ms": 0.1, "housekeeping_ms": 0.1,
+                 "prefill_ms": 0.2, "decode_ms": 0.4, "fetch_ms": 3.0,
+                 "commit_ms": 0.2, "round_ms": 4.0, "tokens": 8.0}
+        for _ in range(300):
+            rig._note_phases(dict(entry))
+        assert len(rig._phases) == 256              # the stall rule's ring
+        d = rig.phase_decomposition()
+        assert d["serve_rounds"] == 300.0 and d["serve_tokens"] == 2400.0
+        assert d["serve_round_ms"] == pytest.approx(1200.0)
+        assert d["serve_fetch_ms"] == pytest.approx(900.0)
+        assert d["serve_prefill_dispatch_ms"] == pytest.approx(60.0)
+        assert d["serve_decode_dispatch_ms"] == pytest.approx(120.0)
+
+    def test_reset_stats_clears_the_totals(self):
+        srv = _serving()
+        srv.run([(_prompt(9), 4)])
+        assert srv.phase_decomposition()["serve_round_ms"] > 0.0
+        srv.reset_stats()
+        d = srv.phase_decomposition()
+        assert d["serve_rounds"] == 0.0
+        assert all(d[k] == 0.0 for k in srv._PHASE_OUT.values())
+        srv.close()
+
+
+# ---------------------------------------------------------------------------
+# the request lifecycle
+# ---------------------------------------------------------------------------
+
+NEW_STATS = ["queue_wait_p50_ms", "queue_wait_p90_ms",
+             "first_token_wait_p50_ms", "first_token_wait_p90_ms",
+             "token_gap_max_p50_ms", "token_gap_max_p90_ms"]
+
+
+class TestRequestLifecycle:
+    def test_fields_and_stats_with_a_preempted_request(self):
+        """2 slots and a pool below full residency: uniform long
+        generations collide in growth and the newest is preempted. Its
+        ``admit_t`` stays the FIRST admission's; the gap it waited to be
+        re-admitted shows in ``max_gap_ms``."""
+        srv = _serving(_tiny_model(seq=128), max_model_len=128, num_blocks=9)
+        reqs = [_add(srv, 26, 40, seed=i) for i in range(4)]
+        first_admit = {}
+        done = []
+        while srv.scheduler.running or srv.scheduler.num_waiting:
+            done += srv.step()
+            for r in srv.scheduler.running:
+                first_admit.setdefault(r.rid, r.admit_t)
+        assert len(done) == 4 and sum(r.preemptions for r in done) >= 1
+        for r in reqs:
+            assert r.submit_t <= r.admit_t <= r.first_token_t <= r.finish_t
+            assert r.admit_t == first_admit[r.rid]      # never re-stamped
+            assert r.max_gap_ms is not None and r.max_gap_ms > 0.0
+            assert r.max_gap_ms <= (r.finish_t - r.first_token_t) * 1e3 + 1e-6
+        st = srv.stats()
+        for key in NEW_STATS:
+            assert key in st and st[key] >= 0.0, key
+        # the queue holds two of the four until a slot frees: p90 sees it
+        waits = sorted((r.admit_t - r.submit_t) * 1e3 for r in reqs)
+        assert st["queue_wait_p90_ms"] == pytest.approx(
+            float(np.percentile(waits, 90)))
+        assert st["queue_wait_p90_ms"] > st["queue_wait_p50_ms"] >= 0.0
+        gaps = [r.max_gap_ms for r in reqs]
+        assert st["token_gap_max_p90_ms"] == pytest.approx(
+            float(np.percentile(gaps, 90)))
+        ftw = [(r.first_token_t - r.admit_t) * 1e3 for r in reqs]
+        assert st["first_token_wait_p50_ms"] == pytest.approx(
+            float(np.percentile(ftw, 50)))
+        srv.reset_stats()
+        assert not any(k in srv.stats() for k in NEW_STATS)
+        srv.close()
+
+    def test_one_delivery_has_no_gap(self):
+        srv = _serving(decode_quantum=8)
+        req = _add(srv, 5, 3)
+        while srv.scheduler.running or srv.scheduler.num_waiting:
+            srv.step()
+        assert len(req.generated) == 3 and req.max_gap_ms is None
+        st = srv.stats()
+        assert "queue_wait_p90_ms" in st and "token_gap_max_p90_ms" not in st
+        srv.close()
+
+    def test_admit_t_agrees_with_the_tracers_queue_wait_span(self):
+        srv = _serving(request_trace=True)
+        reqs = {r.rid: r for r in (_add(srv, k, 6, seed=k)
+                                   for k in (5, 9, 7))}   # 2 slots: one waits
+        while srv.scheduler.running or srv.scheduler.num_waiting:
+            srv.step()
+        tr = srv.tracer
+        waits = [e for e in tr.events if e["name"] == "queue_wait"]
+        assert len(waits) == 3
+        for e in waits:
+            r = reqs[e["rid"]]
+            assert e["ts"] / 1e6 == pytest.approx(tr.epoch(r.submit_t),
+                                                  abs=1e-6)
+            assert (e["ts"] + e["dur"]) / 1e6 == pytest.approx(
+                tr.epoch(r.admit_t), abs=1e-6)
+        late = max(waits, key=lambda e: e["dur"])
+        assert late["dur"] / 1e3 == pytest.approx(
+            srv.stats()["queue_wait_p90_ms"], rel=0.25)
+        srv.close()
+
+
+# ---------------------------------------------------------------------------
+# the train loop's host phases
+# ---------------------------------------------------------------------------
+
+class TestTrainHostPhases:
+    def test_spans_without_telemetry(self, tmp_path):
+        model = make_model(TransformerConfig(
+            vocab_size=64, hidden_size=32, num_layers=1, num_heads=2,
+            max_seq_len=16))
+        engine, *_ = deepspeed_tpu.initialize(model=model, config={
+            "train_batch_size": 8,
+            "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
+            "pipeline": {"in_flight": 1, "prefetch": True}})
+        assert engine._tracer is None               # telemetry is off
+        rng = np.random.default_rng(0)
+        batches = [{"input_ids": rng.integers(0, 64, (8, 16), dtype=np.int32)}
+                   for _ in range(4)]
+        engine.train_batches(iter(batches[:1]), 1)  # compile, off the trace
+        with _Session(tmp_path) as ses:
+            engine.train_batches(iter(batches), 4)
+        assert len(ses.named("ds:train.dispatch")) == 4
+        assert len(ses.named("ds:train.prefetch")) == 4
+        assert len(ses.named("ds:train.data_wait")) >= 4
+        assert len(ses.named("ds:train.block")) == 3     # in_flight = 1
+        assert {s[0] for s in ses.spans} == {
+            "ds:train.dispatch", "ds:train.prefetch", "ds:train.data_wait",
+            "ds:train.block"}
+        engine.close()
