@@ -844,8 +844,8 @@ def _kernel_parity_matrix() -> dict:
                                        (2, 9, 4, 4, 2, 256, 64)]:
         ks = jax.random.split(jax.random.PRNGKey(NB * bs + D), 5)
         q = jax.random.normal(ks[0], (S, 1, Nkv * rep, D), jnp.bfloat16)
-        kp = jax.random.normal(ks[1], (NB, Nkv, bs, D), jnp.bfloat16)
-        vp = jax.random.normal(ks[2], (NB, Nkv, bs, D), jnp.bfloat16)
+        kp = jax.random.normal(ks[1], (NB, bs, Nkv, D), jnp.bfloat16)
+        vp = jax.random.normal(ks[2], (NB, bs, Nkv, D), jnp.bfloat16)
         kr = jax.random.normal(ks[3], (S, Nkv, 1, D), jnp.bfloat16)
         vr = jax.random.normal(ks[4], (S, Nkv, 1, D), jnp.bfloat16)
         rng_t = np.random.default_rng(S + D)
@@ -1273,9 +1273,9 @@ def _paged_backend_microbench(cfg, n_slots: int, num_blocks: int,
 
     nkv, hd = cfg.kv_heads, cfg.dim_per_head
     ks = jax.random.split(jax.random.PRNGKey(1), 2)
-    kp = jax.random.normal(ks[0], (num_blocks, nkv, block_size, hd),
+    kp = jax.random.normal(ks[0], (num_blocks, block_size, nkv, hd),
                            jnp.bfloat16)
-    vp = jax.random.normal(ks[1], (num_blocks, nkv, block_size, hd),
+    vp = jax.random.normal(ks[1], (num_blocks, block_size, nkv, hd),
                            jnp.bfloat16)
     xla_ms, pallas_ms = measure_paged_backends(
         cfg, kp, vp, max_seqs=n_slots, MB=MB, block_size=block_size,

@@ -246,7 +246,7 @@ def leg_kernels(sz: Sizes, pool_shape, rehearsal: bool) -> dict:
     # paged decode at the pool shape the server built: scattered block
     # tables, slots at different lengths (one inside its first block, one
     # exactly on a block boundary, one deep into the table)
-    NB, _, bs, _ = pool_shape
+    NB, bs, _, _ = pool_shape            # token-major [NB, bs, Nkv, D]
     S_slots, MB = sz.max_seqs, sz.max_model_len // bs
     kp = jax.random.normal(ks[4], pool_shape, jnp.bfloat16)
     vp = jax.random.normal(ks[5], pool_shape, jnp.bfloat16)
@@ -405,7 +405,7 @@ def leg_serve(sz: Sizes, chips: int, rehearsal: bool, forced_pallas: bool
     if forced_pallas:
         assert srv.decode_backend == "pallas", srv.backend_bench
     if chips > 1:
-        assert srv.pools["k"].sharding.spec[2] == "tensor"
+        assert srv.pools["k"].sharding.spec[3] == "tensor"
         assert_balanced("weights", per_device_bytes(srv.engine.params),
                         band=1.02)
         assert_balanced("KV pools", per_device_bytes(srv.pools),
@@ -425,7 +425,7 @@ def leg_serve(sz: Sizes, chips: int, rehearsal: bool, forced_pallas: bool
         assert ((0 <= o) & (o < cfg.vocab_size)).all()
     assert srv.allocator.used_blocks == 0, srv.allocator.used_blocks
     if chips > 1:
-        assert srv.pools["k"].sharding.spec[2] == "tensor"
+        assert srv.pools["k"].sharding.spec[3] == "tensor"
     st = srv.stats()
     log(f"  {len(outs)} requests on {sz.max_seqs} slots completed in "
         f"{wall:.1f}s (compiles included); generated "
@@ -587,7 +587,7 @@ def main(argv=None) -> int:
     from deepspeed_tpu.inference.serving import ServingConfig
     bs = ServingConfig().block_size
     pool_shape = (sz.max_seqs * (sz.max_model_len // bs) + 1,
-                  sz.hf["num_key_value_heads"], bs,
+                  bs, sz.hf["num_key_value_heads"],
                   sz.hf["hidden_size"] // sz.hf["num_attention_heads"])
     parity = run_leg("kernels", leg_kernels, sz, pool_shape, args.rehearsal)
     train = run_leg("train", leg_train, sz, args.chips, args.rehearsal)

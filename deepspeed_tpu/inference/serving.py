@@ -60,7 +60,7 @@ and resume/migration re-prefills THROUGH the cache.
 
 Pod-scale serving (ISSUE 15 — see README "Pod-scale serving"): the engine
 is mesh-native. Under **tensor parallelism** the paged block pools
-``[L, NB, nkv, block_size, hd]`` shard on the kv-head dim over the
+``[L, NB, block_size, nkv, hd]`` shard on the kv-head dim over the
 ``tensor`` mesh axis via the same Megatron col/row rules the weights use
 (``paged_cache_logical_axes``), every decode/prefill/span program pins its
 pool output to that sharding, and the per-layer out-projection reductions
@@ -144,7 +144,8 @@ def measure_paged_backends(mcfg, k_pool, v_pool, *, max_seqs: int, MB: int,
                            block_size: int, num_blocks: int, dtype,
                            iters: int = 10, mesh=None):
     """Time the paged Pallas kernel vs the XLA gather over the given
-    single-layer pools on a representative load: every slot half-to-full,
+    single-layer pools ([NB, block_size, n_kv, head_dim], the layout of
+    ``init_paged_cache``) on a representative load: every slot half-to-full,
     blocks scattered through the pool (a fresh pool's identity layout
     would flatter the gather). Returns (xla_ms, pallas_ms).
 
@@ -539,12 +540,18 @@ class ServingEngine:
         # block 0, which is never read). The gather must NOT donate the
         # pools — the source keeps serving its other requests; a
         # head-sharded engine's device_get assembles the full logical
-        # array, so payloads are mesh-independent.
+        # array, so payloads are mesh-independent. The PAYLOAD keeps the
+        # head-major order [L, n, nkv, bs, hd] (+ scales [L, n, nkv, bs])
+        # whatever the pool stores: the blocks are turned at this edge.
+        from deepspeed_tpu.models.transformer import (
+            paged_blocks_from_logical, paged_blocks_to_logical)
         self._gather_blocks_fn = jax.jit(
-            lambda pools, ids: jax.tree.map(lambda a: a[:, ids], pools))
+            lambda pools, ids: paged_blocks_to_logical(
+                jax.tree.map(lambda a: a[:, ids], pools)))
         self._scatter_blocks_fn = jax.jit(
             lambda pools, ids, data: jax.tree.map(
-                lambda a, d: a.at[:, ids].set(d), pools, data),
+                lambda a, d: a.at[:, ids].set(d), pools,
+                paged_blocks_from_logical(data)),
             donate_argnums=(0,), out_shardings=self._pool_shardings)
         # in-flight handoff staging: host bytes of exported payloads not
         # yet released + imported payloads not yet scattered. Real memory
@@ -1714,9 +1721,9 @@ class ServingEngine:
         assembles the full head dim, so tp2->tp2 and tp1->tp1 both ship
         the same bytes; tp CROSSING is refused by _check_geometry for the
         continuation-determinism reason, not here)."""
-        k = self.pools["k"]
+        k = self.pools["k"]              # [L, NB, bs, nkv, hd]
         return {"num_layers": int(k.shape[0]),
-                "kv_heads": int(k.shape[2]),
+                "kv_heads": int(k.shape[3]),
                 "head_dim": int(k.shape[4]),
                 "block_size": int(self.config.block_size),
                 "kv_bits": int(getattr(self.model.config,

@@ -747,20 +747,39 @@ def _decode_attention(q, ck, cv, index, cfg: TransformerConfig = None,
     return out.reshape(B, 1, Nq, D)
 
 
+def _gather_view(pool, tables):
+    """One layer's token-major pool slice [NB, bs, Nkv, D] read through the
+    block tables [S, MB] as the ring-buffer view [S, Nkv, MB*bs, D]."""
+    S, MB = tables.shape
+    _, bs, Nkv, D = pool.shape
+    g = jnp.take(pool, tables, axis=0)           # [S, MB, bs, Nkv, D]
+    return g.reshape(S, MB * bs, Nkv, D).transpose(0, 2, 1, 3)
+
+
+def _gather_scales(scale, tables, Nkv):
+    """One layer's scale plane [NB, Nkv*bs] read through the block tables
+    as [S, Nkv, MB*bs] (position-major within a head, like the view)."""
+    S, MB = tables.shape
+    g = jnp.take(scale, tables, axis=0)          # [S, MB, Nkv*bs]
+    return g.reshape(S, MB, Nkv, -1).transpose(0, 2, 1, 3).reshape(S, Nkv, -1)
+
+
 def _paged_attention(q, pool_k, pool_v, tables, index, cfg: TransformerConfig,
                      kv_row, kv_scale=None, backend="xla", window=None):
     """Single-token attention against the PAGED block pool.
 
     q: [S, 1, Nq, D] (one in-flight token per slot); pool_k/pool_v:
-    [NB, Nkv, bs, D] (one layer's slice of the shared block pool);
-    tables: [S, MB] int32 block ids (0 = the reserved trash block, masked
-    by the length); index: per-slot sequence length [S].
+    [NB, bs, Nkv, D] (one layer's slice of the shared block pool, stored
+    TOKEN-major: see ``init_paged_cache``); tables: [S, MB] int32 block
+    ids (0 = the reserved trash block, masked by the length); index:
+    per-slot sequence length [S].
 
     backend="pallas": the block-table gather is resolved inside the kernel's
     index maps (ops/decode_attention.paged_decode_attention) — only blocks
     covering the valid prefix ever cross HBM->VMEM, nothing materializes.
-    backend="xla": ``jnp.take`` materializes the slot's blocks as a
-    contiguous [S, Nkv, MB*bs, D] view and the math is the EXACT ring-buffer
+    backend="xla": ``jnp.take`` materializes the slot's blocks
+    ([S, MB, bs, Nkv, D] -> [S, MB*bs, Nkv, D]), the view is turned
+    head-major ([S, Nkv, MB*bs, D]) and the math is the EXACT ring-buffer
     path (_decode_attention with a per-slot cursor) — same einsums, same
     masking, which is what makes paged-vs-contiguous decode bit-for-bit
     comparable in tests. The backend is chosen by a measured micro-bench at
@@ -773,9 +792,7 @@ def _paged_attention(q, pool_k, pool_v, tables, index, cfg: TransformerConfig,
     single-token chain bit for bit (the Pallas kernel is single-token
     only and is never selected for spans).
     """
-    S = q.shape[0]
-    NB, Nkv, bs, D = pool_k.shape
-    MB = tables.shape[1]
+    Nkv = pool_k.shape[2]
     if q.shape[1] > 1:
         return _paged_span_attention(q, pool_k, pool_v, tables, index, cfg,
                                      kv_row, kv_scale=kv_scale,
@@ -793,24 +810,21 @@ def _paged_attention(q, pool_k, pool_v, tables, index, cfg: TransformerConfig,
             return paged_decode_attention(q, pool_k, pool_v, tables, index,
                                           kv_row=kv_row)
         from deepspeed_tpu.comm.schedule import shard_map_compat
-        qs, ps = P(None, None, "tensor", None), P(None, "tensor", None, None)
+        # q [S, 1, Nq, D] and the pool slices [NB, bs, Nkv, D] both carry
+        # their heads on dim 2; the fresh rows [S, Nkv, 1, D] on dim 1
+        hs, rs = P(None, None, "tensor", None), P(None, "tensor", None, None)
         return shard_map_compat(
             lambda q, pk, pv, t, ln, kr, vr: paged_decode_attention(
                 q, pk, pv, t, ln, kv_row=(kr, vr)),
-            mesh, in_specs=(qs, ps, ps, P(), P(), ps, ps), out_specs=qs,
+            mesh, in_specs=(hs, hs, hs, P(), P(), rs, rs), out_specs=hs,
             manual_axes=axes)(q, pool_k, pool_v, tables, index, *kv_row)
-
-    def view(pool):
-        g = jnp.take(pool, tables, axis=0)       # [S, MB, Nkv, bs, D]
-        return g.transpose(0, 2, 1, 3, 4).reshape(S, Nkv, MB * bs, D)
 
     sc = None
     with jax.named_scope("kv_gather"):
         if kv_scale is not None:
-            ks, vs = kv_scale                    # [NB, Nkv, bs] f32
-            sc = tuple(jnp.take(s, tables, axis=0).transpose(0, 2, 1, 3)
-                       .reshape(S, Nkv, MB * bs) for s in (ks, vs))
-        vk, vv = view(pool_k), view(pool_v)
+            sc = tuple(_gather_scales(s, tables, Nkv) for s in kv_scale)
+        vk, vv = (_gather_view(pool_k, tables),
+                  _gather_view(pool_v, tables))
     return _decode_attention(q, vk, vv, index, cfg,
                              kv_row=kv_row, kv_scale=sc, window=window)
 
@@ -844,27 +858,19 @@ def _paged_span_attention(q, pool_k, pool_v, tables, prior_lens,
     path, and the reason the int8 parity tests carry a weaker bar.
     """
     S, T = q.shape[0], q.shape[1]
-    NB, Nkv, bs, D = pool_k.shape
-    MB = tables.shape[1]
+    Nkv, D = pool_k.shape[2], pool_k.shape[3]
     Nq = q.shape[2]
     rep = Nq // Nkv
     chunk_k, chunk_v = kv_row                    # [S, Nkv, T, D]
     sm = (cfg.attn_scale if cfg is not None and cfg.attn_scale is not None
           else 1.0 / math.sqrt(D))
 
-    def view(pool):
-        g = jnp.take(pool, tables, axis=0)       # [S, MB, Nkv, bs, D]
-        return g.transpose(0, 2, 1, 3, 4).reshape(S, Nkv, MB * bs, D)
-
     with jax.named_scope("kv_gather"):
-        vk, vv = view(pool_k), view(pool_v)
+        vk, vv = (_gather_view(pool_k, tables),
+                  _gather_view(pool_v, tables))
         Tp = vk.shape[2]
         if kv_scale is not None:
-            ks, vs = kv_scale
-            ksg = jnp.take(ks, tables, axis=0).transpose(0, 2, 1, 3) \
-                .reshape(S, Nkv, Tp)
-            vsg = jnp.take(vs, tables, axis=0).transpose(0, 2, 1, 3) \
-                .reshape(S, Nkv, Tp)
+            ksg, vsg = (_gather_scales(s, tables, Nkv) for s in kv_scale)
     qg = q.transpose(0, 2, 1, 3).reshape(S, Nkv, rep, T, D)
     pos = prior_lens[:, None] + jnp.arange(T)[None, :]       # [S, T] abs
     if kv_scale is not None:
@@ -1131,7 +1137,7 @@ def transformer_layer(x, layer_params, cfg: TransformerConfig, mask=None,
     so a prefill pass can seed the cache.
 
     paged=(block_tables, backend): the cache tuple carries one layer's
-    BLOCK-POOL slices ([NB, nkv, bs, hd]) instead of per-batch ring
+    BLOCK-POOL slices ([NB, bs, nkv, hd]) instead of per-batch ring
     buffers, and `index` is the per-slot sequence-length vector —
     attention reads through the block table (decode_step_paged).
 
@@ -1990,34 +1996,88 @@ def merge_suffix(cfg: TransformerConfig, cache: Params,
 
 def init_paged_cache(cfg: TransformerConfig, num_blocks: int,
                      block_size: int, dtype=None) -> Params:
-    """Block pools [L, NB, n_kv, block_size, head_dim]. Block 0 is the
-    reserved TRASH block: null block-table entries point at it and inactive
-    slots write into it, so the compiled step needs no scatter masking —
-    trash contents are never read (masked by the per-slot length).
+    """Block pools, stored TOKEN-major: ``k``, ``v``
+    [L, NB, block_size, n_kv, head_dim]. One token's row is the whole
+    (n_kv, head_dim) minor tile, so the row a decode step, a span or a
+    prefill writes is scattered IN PLACE. Head-major
+    ([.., n_kv, block_size, head_dim]) a row is one sub-tile line of every
+    head, and the TPU compiler moved the WHOLE pool to another layout and
+    back around every such write (4 x 1.6 GB a step at 16 x 1537 blocks,
+    PERF.md §5-6, PR 24). The attention read turns the gathered blocks
+    head-major itself (``_gather_view``).
 
-    kv_cache_bits=8: int8 payloads + per-(block, head, row) f32 scales —
-    the attention read consumes the int8 bytes directly with dequant fused
-    into the score scaling (see _decode_attention / ops/quantizer)."""
+    Block 0 is the reserved TRASH block: null block-table entries point at
+    it and inactive slots write into it, so the compiled step needs no
+    scatter masking — trash contents are never read (masked by the
+    per-slot length).
+
+    kv_cache_bits=8: int8 payloads + per-(block, head, row) f32 scales in
+    planes [L, NB, n_kv * block_size] (head-major within a block: 4-D
+    planes are relayouted around the row write whatever the order of their
+    axes, this one is written in place and pads no lanes) — the attention
+    read consumes the int8 bytes directly with dequant fused into the score
+    scaling (see _decode_attention / ops/quantizer).
+
+    ``paged_blocks_to_logical`` / ``paged_blocks_from_logical`` translate
+    whole blocks to and from the head-major order [.., n_kv, block_size,
+    head_dim] that KV handoff payloads keep."""
     dtype = dtype or cfg.dtype
     L, nkv, hd = cfg.num_layers, cfg.kv_heads, cfg.dim_per_head
-    shape = (L, num_blocks, nkv, block_size, hd)
+    shape = (L, num_blocks, block_size, nkv, hd)
     if cfg.kv_cache_bits == 8:
+        plane = (L, num_blocks, nkv * block_size)
         return {"k": jnp.zeros(shape, jnp.int8),
                 "v": jnp.zeros(shape, jnp.int8),
-                "k_scale": jnp.zeros(shape[:-1], jnp.float32),
-                "v_scale": jnp.zeros(shape[:-1], jnp.float32)}
+                "k_scale": jnp.zeros(plane, jnp.float32),
+                "v_scale": jnp.zeros(plane, jnp.float32)}
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def paged_cache_logical_axes(cfg: Optional[TransformerConfig] = None
                              ) -> Params:
     """TP shards the pool over kv heads exactly like the weights; the block
-    dim stays unsharded (any block serves any sequence)."""
-    out = {"k": ("layers", None, "heads", None, None),
-           "v": ("layers", None, "heads", None, None)}
+    dim stays unsharded (any block serves any sequence). A scale plane's
+    last dim is head-major, so an even split of it is a split by heads."""
+    out = {"k": ("layers", None, None, "heads", None),
+           "v": ("layers", None, None, "heads", None)}
     if cfg is not None and cfg.kv_cache_bits == 8:
-        out["k_scale"] = ("layers", None, "heads", None)
-        out["v_scale"] = ("layers", None, "heads", None)
+        out["k_scale"] = ("layers", None, "heads")
+        out["v_scale"] = ("layers", None, "heads")
+    return out
+
+
+def paged_blocks_to_logical(blocks: Params) -> Params:
+    """Pool blocks (any [L, n, ...] slice of the pool tree) in the
+    head-major order of the KV handoff payload: ``k``, ``v``
+    [L, n, n_kv, block_size, head_dim], scales [L, n, n_kv, block_size]."""
+    L, n, bs, nkv, _ = blocks["k"].shape
+    return {name: (a.transpose(0, 1, 3, 2, 4) if a.ndim == 5
+                   else a.reshape(L, n, nkv, bs))
+            for name, a in blocks.items()}
+
+
+def paged_blocks_from_logical(blocks: Params) -> Params:
+    """Inverse of ``paged_blocks_to_logical``."""
+    return {name: (a.transpose(0, 1, 3, 2, 4) if a.ndim == 5
+                   else a.reshape(a.shape[:2] + (-1,)))
+            for name, a in blocks.items()}
+
+
+def _scatter_rows(pools: Params, blk, off, rows: Params) -> Params:
+    """Write one K/V row per (blk[i], off[i]) pair into every layer of the
+    pool, in place. rows: ``k``, ``v`` [L, N, n_kv, head_dim] (+ ``k_scale``,
+    ``v_scale`` [L, N, n_kv] for an int8 pool); blk, off: [N] int32."""
+    bs, nkv = pools["k"].shape[2:4]
+    out = {"k": pools["k"].at[:, blk, off].set(rows["k"]),
+           "v": pools["v"].at[:, blk, off].set(rows["v"])}
+    for name in sorted(set(rows) - {"k", "v"}):     # int8: the scale planes
+        # one scatter per head: N scalars into the head's own lanes. ONE
+        # scatter of all N * n_kv scalars is moved through a transposed
+        # copy of the whole plane once N reaches a few hundred (a span)
+        plane = pools[name]
+        for h in range(nkv):
+            plane = plane.at[:, blk, h * bs + off].set(rows[name][:, :, h])
+        out[name] = plane
     return out
 
 
@@ -2060,7 +2120,7 @@ def decode_step_paged(params: Params, tokens, cfg: TransformerConfig,
                       params.get("embed_norm_bias"), cfg)
     positions = seq_lens[:, None]                                # [S, 1]
     int8_kv = cfg.kv_cache_bits == 8
-    bs = pools["k"].shape[3]
+    bs = pools["k"].shape[2]
 
     def at_layer(tree, i):
         return jax.tree.map(
@@ -2095,36 +2155,24 @@ def decode_step_paged(params: Params, tokens, cfg: TransformerConfig,
 
     with jax.named_scope("layers"):
         x, (k_rows, v_rows) = lax.scan(body, x, jnp.arange(cfg.num_layers))
-    # one [S, L, nkv, hd] scatter writes every layer's fresh row at
-    # (block_tables[s, len // bs], len % bs); inactive slots hit the trash
-    # block (duplicate trash writes are unordered and never read)
+    # one [L, S, nkv, hd] scatter writes every layer's fresh row at
+    # (block_tables[s, len // bs], len % bs), a whole minor tile of the
+    # token-major pool; inactive slots hit the trash block (duplicate trash
+    # writes are unordered and never read)
     with jax.named_scope("attn"), jax.named_scope("kv_write"):
         blk = jnp.take_along_axis(block_tables, (seq_lens // bs)[:, None],
                                   axis=1)[:, 0]
         blk = jnp.where(active, blk, 0)
         off = jnp.where(active, seq_lens % bs, 0)
+        k_rows, v_rows = k_rows[:, :, :, 0], v_rows[:, :, :, 0]
         if int8_kv:
-            kq, ks_ = _quant_kv(k_rows)       # [L, S, nkv, 1, hd] -> + [.,1]
+            kq, ks_ = _quant_kv(k_rows)          # [L, S, nkv, hd] + [L,S,nkv]
             vq, vs_ = _quant_kv(v_rows)
-            new_pools = {
-                "k": pools["k"].at[:, blk, :, off, :].set(
-                    jnp.moveaxis(kq[:, :, :, 0, :], 1, 0)),
-                "v": pools["v"].at[:, blk, :, off, :].set(
-                    jnp.moveaxis(vq[:, :, :, 0, :], 1, 0)),
-                "k_scale": pools["k_scale"].at[:, blk, :, off].set(
-                    jnp.moveaxis(ks_[:, :, :, 0], 1, 0)),
-                "v_scale": pools["v_scale"].at[:, blk, :, off].set(
-                    jnp.moveaxis(vs_[:, :, :, 0], 1, 0)),
-            }
+            rows = {"k": kq, "v": vq, "k_scale": ks_, "v_scale": vs_}
         else:
-            new_pools = {
-                "k": pools["k"].at[:, blk, :, off, :].set(
-                    jnp.moveaxis(
-                        k_rows[:, :, :, 0, :].astype(pools["k"].dtype), 1, 0)),
-                "v": pools["v"].at[:, blk, :, off, :].set(
-                    jnp.moveaxis(
-                        v_rows[:, :, :, 0, :].astype(pools["v"].dtype), 1, 0)),
-            }
+            rows = {"k": k_rows.astype(pools["k"].dtype),
+                    "v": v_rows.astype(pools["v"].dtype)}
+        new_pools = _scatter_rows(pools, blk, off, rows)
     with jax.named_scope("lm_head"):
         if cfg.final_norm:
             x = _norm(x, params["final_norm_scale"],
@@ -2176,7 +2224,7 @@ def decode_span_paged(params: Params, tokens, cfg: TransformerConfig,
             x = _norm(x, params["embed_norm_scale"],
                       params.get("embed_norm_bias"), cfg)
     int8_kv = cfg.kv_cache_bits == 8
-    bs = pools["k"].shape[3]
+    bs = pools["k"].shape[2]
     MB = block_tables.shape[1]
 
     def at_layer(tree, i):
@@ -2227,34 +2275,19 @@ def decode_span_paged(params: Params, tokens, cfg: TransformerConfig,
     blk = jnp.where(write, blk, 0).reshape(-1)
     off = jnp.where(write, positions % bs, 0).reshape(-1)
 
-    def flat(a, dtype=None):                     # [L,S,nkv,T,hd]->[S*T,...]
-        a = jnp.transpose(a, (1, 3, 0, 2, 4))
-        if dtype is not None:
-            a = a.astype(dtype)
-        return a.reshape((S * T,) + a.shape[2:])
-
-    def flat_s(s):                               # [L,S,nkv,T] -> [S*T,...]
-        return jnp.transpose(s, (1, 3, 0, 2)).reshape(S * T, -1, s.shape[2])
+    def flat(a):           # [L, S, nkv, T(, hd)] -> [L, S*T, nkv(, hd)]
+        a = jnp.swapaxes(a, 2, 3)
+        return a.reshape((a.shape[0], S * T) + a.shape[3:])
 
     with jax.named_scope("attn"), jax.named_scope("kv_write"):
         if int8_kv:
             kq, ks_ = _quant_kv(k_rows)          # scales [L, S, nkv, T]
             vq, vs_ = _quant_kv(v_rows)
-            new_pools = {
-                "k": pools["k"].at[:, blk, :, off, :].set(flat(kq)),
-                "v": pools["v"].at[:, blk, :, off, :].set(flat(vq)),
-                "k_scale": pools["k_scale"].at[:, blk, :, off].set(
-                    flat_s(ks_)),
-                "v_scale": pools["v_scale"].at[:, blk, :, off].set(
-                    flat_s(vs_)),
-            }
+            rows = {"k": kq, "v": vq, "k_scale": ks_, "v_scale": vs_}
         else:
-            new_pools = {
-                "k": pools["k"].at[:, blk, :, off, :].set(
-                    flat(k_rows, pools["k"].dtype)),
-                "v": pools["v"].at[:, blk, :, off, :].set(
-                    flat(v_rows, pools["v"].dtype)),
-            }
+            rows = {"k": k_rows.astype(pools["k"].dtype),
+                    "v": v_rows.astype(pools["v"].dtype)}
+        new_pools = _scatter_rows(pools, blk, off, jax.tree.map(flat, rows))
     with jax.named_scope("lm_head"):
         if cfg.final_norm:
             x = _norm(x, params["final_norm_scale"],
@@ -2274,19 +2307,19 @@ def prefill_paged(params: Params, input_ids, cfg: TransformerConfig,
     appends). Returns (last_logits [1, V], pools). The contiguous prefill
     cache is a jit-local temporary — it never leaves the program."""
     B, P = input_ids.shape
-    bs = pools["k"].shape[3]
+    bs = pools["k"].shape[2]
     nblk = P // bs
     cache = init_cache(cfg, B, P)
     last, cache = prefill(params, input_ids, cfg, cache, length=length)
 
-    def to_blocks(a):          # [L, 1, nkv, P, hd] -> [L, nblk, nkv, bs, hd]
+    def to_blocks(a):          # [L, 1, nkv, P, hd] -> [L, nblk, bs, nkv, hd]
         L_, _, nkv, _, hd = a.shape
-        return (a[:, 0].reshape(L_, nkv, nblk, bs, hd)
-                .transpose(0, 2, 1, 3, 4))
+        return jnp.swapaxes(a[:, 0], 1, 2).reshape(L_, nblk, bs, nkv, hd)
 
-    def to_blocks_s(a):        # [L, 1, nkv, P] -> [L, nblk, nkv, bs]
+    def to_blocks_s(a):        # [L, 1, nkv, P] -> [L, nblk, nkv * bs]
         L_, _, nkv, _ = a.shape
-        return a[:, 0].reshape(L_, nkv, nblk, bs).transpose(0, 2, 1, 3)
+        return (a[:, 0].reshape(L_, nkv, nblk, bs).swapaxes(1, 2)
+                .reshape(L_, nblk, nkv * bs))
 
     # forward() carries embed / layers / attn / mlp | moe / lm_head
     with jax.named_scope("attn"), jax.named_scope("kv_write"):

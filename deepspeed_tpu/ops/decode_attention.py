@@ -27,9 +27,10 @@ GQA-native like the training kernel: each program holds the whole
 [Nkv, rep, D] query group of one slot; K/V blocks are read once per group.
 
 Layout: q [S, 1, Nq, D] (one in-flight token per slot); pools
-[NB, Nkv, bs, D]; block_tables [S, MB] int32 (entry 0 = reserved trash
-block — never valid, masked by seq_lens); seq_lens [S] int32 = valid
-prefix length per slot. The CURRENT token's (k, v) row arrives separately
+[NB, bs, Nkv, D] (token-major, models/transformer.init_paged_cache: the
+row a step writes is a whole minor tile); block_tables [S, MB] int32
+(entry 0 = reserved trash block — never valid, masked by seq_lens);
+seq_lens [S] int32 = valid prefix length per slot. The CURRENT token's (k, v) row arrives separately
 (kv_row) and folds into the online softmax at finalize — the caller
 scatters it into the pool afterwards, keeping the per-step pool update
 O(row), exactly like the ring-buffer path.
@@ -72,10 +73,11 @@ def _kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, kr_ref, vr_ref, o_ref,
     @pl.when(j * block_size < ln)
     def _step():
         q = q_ref[0].astype(jnp.float32) * sm_scale     # [nkv, rep, d]
-        k = k_ref[0].astype(jnp.float32)                # [nkv, bs, d]
+        k = k_ref[0].astype(jnp.float32)                # [bs, nkv, d]
         v = v_ref[0].astype(jnp.float32)
-        # batched over kv heads: [nkv, rep, bs]
-        sc = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+        # batched over kv heads (dim 0 of q, dim 1 of a block):
+        # [nkv, rep, bs]
+        sc = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (1,))),
                                  preferred_element_type=jnp.float32)
         t_pos = j * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (nkv, rep, block_size), 2)
@@ -87,7 +89,7 @@ def _kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, kr_ref, vr_ref, o_ref,
         p = jnp.exp(sc - m_new)
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(p, -1, keepdims=True)
-        pv = jax.lax.dot_general(p, v, (((2,), (1,)), ((0,), (0,))),
+        pv = jax.lax.dot_general(p, v, (((2,), (0,)), ((0,), (1,))),
                                  preferred_element_type=jnp.float32)
         acc_s[:, 0:rep] = acc_s[:, 0:rep] * alpha + pv
         m_s[:, 0:rep] = jnp.broadcast_to(m_new, (nkv, rep, m_s.shape[2]))
@@ -114,7 +116,7 @@ def _kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, kr_ref, vr_ref, o_ref,
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, seq_lens, *,
                            kv_row=None, sm_scale: Optional[float] = None):
-    """q: [S, 1, Nq, D]; k_pool/v_pool: [NB, Nkv, bs, D]; block_tables:
+    """q: [S, 1, Nq, D]; k_pool/v_pool: [NB, bs, Nkv, D]; block_tables:
     [S, MB] int32; seq_lens: [S] int32. Returns [S, 1, Nq, D].
 
     Valid pool rows for slot s are positions < seq_lens[s] (the fresh row
@@ -124,7 +126,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, seq_lens, *,
     their DMAs are elided and per-step HBM traffic is O(valid prefix).
     """
     S, one, Nq, D = q.shape
-    NB, Nkv, bs, _ = k_pool.shape
+    NB, bs, Nkv, _ = k_pool.shape
     MB = block_tables.shape[1]
     rep = Nq // Nkv
     if sm_scale is None:
@@ -148,7 +150,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, seq_lens, *,
 
     q_spec = pl.BlockSpec((1, Nkv, rep, D), lambda s, j, t, ln: (s, 0, 0, 0),
                           memory_space=pltpu.VMEM)
-    kv_spec = pl.BlockSpec((1, Nkv, bs, D), kv_index,
+    kv_spec = pl.BlockSpec((1, bs, Nkv, D), kv_index,
                            memory_space=pltpu.VMEM)
     row_spec = pl.BlockSpec((1, Nkv, 1, D), lambda s, j, t, ln: (s, 0, 0, 0),
                             memory_space=pltpu.VMEM)

@@ -17,16 +17,10 @@ from deepspeed_tpu.ops.decode_attention import paged_decode_attention
 
 
 def _ref_paged(q, k_pool, v_pool, tables, lens, k_row, v_row):
-    from deepspeed_tpu.models.transformer import _decode_attention
-    S = q.shape[0]
-    NB, Nkv, bs, D = k_pool.shape
-    MB = tables.shape[1]
-
-    def view(pool):
-        g = jnp.take(pool, tables, axis=0)        # [S, MB, Nkv, bs, D]
-        return g.transpose(0, 2, 1, 3, 4).reshape(S, Nkv, MB * bs, D)
-
-    return _decode_attention(q, view(k_pool), view(v_pool),
+    from deepspeed_tpu.models.transformer import (_decode_attention,
+                                                  _gather_view)
+    return _decode_attention(q, _gather_view(k_pool, tables),
+                             _gather_view(v_pool, tables),
                              jnp.asarray(lens, jnp.int32), None,
                              kv_row=(k_row, v_row))
 
@@ -34,8 +28,8 @@ def _ref_paged(q, k_pool, v_pool, tables, lens, k_row, v_row):
 def _rand_case(key, S, NB, MB, Nkv, rep, bs, D, dtype=jnp.float32):
     ks = jax.random.split(jax.random.PRNGKey(key), 5)
     q = jax.random.normal(ks[0], (S, 1, Nkv * rep, D), dtype)
-    k_pool = jax.random.normal(ks[1], (NB, Nkv, bs, D), dtype)
-    v_pool = jax.random.normal(ks[2], (NB, Nkv, bs, D), dtype)
+    k_pool = jax.random.normal(ks[1], (NB, bs, Nkv, D), dtype)
+    v_pool = jax.random.normal(ks[2], (NB, bs, Nkv, D), dtype)
     k_row = jax.random.normal(ks[3], (S, Nkv, 1, D), dtype)
     v_row = jax.random.normal(ks[4], (S, Nkv, 1, D), dtype)
     # distinct non-trash blocks per slot (block 0 reserved), shuffled so the
@@ -85,8 +79,8 @@ def test_trash_block_and_stale_rows_ignored():
     # table -> its output must be exactly the fresh-row value
     tables = tables.at[1].set(0)
     blk2 = int(tables[0, 1])
-    kp = kp.at[blk2, :, 8:].set(1e4)          # rows 40.. of slot 0 stale
-    vp = vp.at[blk2, :, 8:].set(1e4)
+    kp = kp.at[blk2, 8:].set(1e4)             # rows 40.. of slot 0 stale
+    vp = vp.at[blk2, 8:].set(1e4)
     out = paged_decode_attention(q, kp, vp, tables, lens, kv_row=(kr, vr))
     ref = _ref_paged(q, kp, vp, tables, lens, kr, vr)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),

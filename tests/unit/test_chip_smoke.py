@@ -237,13 +237,14 @@ class TestPallasKernelsOnAMesh:
         mesh = self._mesh(devices8, tensor=2)
         N, Nkv, D, bs = 8, 2, 64, 16
         ks = jax.random.split(jax.random.PRNGKey(1), 4)
-        psh = NamedSharding(mesh, P(None, "tensor", None, None))
-        kp = jax.device_put(jax.random.normal(ks[0], (9, Nkv, bs, D)), psh)
-        vp = jax.device_put(jax.random.normal(ks[1], (9, Nkv, bs, D)), psh)
-        kr = jax.device_put(jax.random.normal(ks[2], (2, Nkv, 1, D)), psh)
-        q = jax.device_put(
-            jax.random.normal(ks[3], (2, 1, N, D)),
-            NamedSharding(mesh, P(None, None, "tensor", None)))
+        # token-major pool slices [NB, bs, Nkv, D] and q carry their heads
+        # on dim 2, the fresh rows [S, Nkv, 1, D] on dim 1
+        hsh = NamedSharding(mesh, P(None, None, "tensor", None))
+        rsh = NamedSharding(mesh, P(None, "tensor", None, None))
+        kp = jax.device_put(jax.random.normal(ks[0], (9, bs, Nkv, D)), hsh)
+        vp = jax.device_put(jax.random.normal(ks[1], (9, bs, Nkv, D)), hsh)
+        kr = jax.device_put(jax.random.normal(ks[2], (2, Nkv, 1, D)), rsh)
+        q = jax.device_put(jax.random.normal(ks[3], (2, 1, N, D)), hsh)
         tables = jnp.array([[1, 2, 3, 0], [4, 5, 0, 0]], jnp.int32)
         lens = jnp.array([40, 16], jnp.int32)
 

@@ -292,6 +292,84 @@ class TestKvHandoff:
         with pytest.raises(ResumeIncompatible):
             f32.accept_migration(recs2, source="src", kv=payloads2)
 
+    def test_payload_keeps_the_head_major_byte_order(self, model, params):
+        """The pool is stored token-major ([L, NB, bs, nkv, hd], scale
+        planes [L, NB, nkv*bs]: ISSUE 24); the PAYLOAD is not — its
+        ``data`` bytes, ``geometry`` and crc are those of the head-major
+        arrays [L, n, nkv, bs, hd] / [L, n, nkv, bs] that every payload
+        written before the change carries. Pinned on a hand-filled int8
+        pool whose logical content is built here, in the payload's
+        order."""
+        from deepspeed_tpu.models.transformer import (
+            paged_blocks_from_logical)
+        src = _serving(model, params, config={"kv_cache_bits": 8},
+                       role="prefill")
+        (rid,) = _prefill_all(src, _reqs(n=1, lens=(21,)))
+        L, NB, bs, nkv, hd = src.pools["k"].shape
+        assert (bs, nkv, hd) == (16, 2, 16)
+        rng = np.random.default_rng(7)
+        logical = {
+            "k": rng.integers(-127, 128, (L, NB, nkv, bs, hd), np.int8),
+            "v": rng.integers(-127, 128, (L, NB, nkv, bs, hd), np.int8),
+            "k_scale": rng.random((L, NB, nkv, bs), np.float32),
+            "v_scale": rng.random((L, NB, nkv, bs), np.float32)}
+        filled = paged_blocks_from_logical(
+            {n: jnp.asarray(a) for n, a in logical.items()})
+        assert {n: a.shape for n, a in filled.items()} == \
+            {n: a.shape for n, a in src.pools.items()}
+        src.pools = filled
+        pl = src.export_kv([rid])[rid]
+        req = src._requests[rid]
+        assert (pl["rows"], pl["blocks"]) == (21, 2)
+        want = {n: np.ascontiguousarray(a[:, req.block_ids[:2]])
+                for n, a in logical.items()}
+        assert set(pl["data"]) == set(want)
+        for n in want:
+            assert pl["data"][n].dtype == want[n].dtype
+            assert pl["data"][n].shape == want[n].shape
+            assert pl["data"][n].tobytes() == want[n].tobytes(), n
+        assert pl["geometry"] == {
+            "num_layers": L, "kv_heads": nkv, "head_dim": hd,
+            "block_size": bs, "kv_bits": 8, "dtype": "int8"}
+        assert pl["schema"] == 1 and pl["crc"] == kv_payload_crc(want)
+
+    def test_int8_round_trip_lands_the_same_blocks(self, model, params):
+        """export_kv -> accept_migration(kv=) -> the receiver's pool holds
+        the payload's blocks byte for byte (exported again from there,
+        before any decode step touches them) and the continuation is the
+        colocated int8 engine's."""
+        reqs = _reqs(n=2)
+        q = {"kv_cache_bits": 8}
+        base = _serving(model, params, config=q).run(
+            [(p.copy(), k) for p, k in reqs])
+        src = _serving(model, params, config=q, role="prefill")
+        dst = _serving(model, params, config=q, role="both")
+        rids = _prefill_all(src, reqs)
+        payloads = src.export_kv(rids)
+        dst.accept_migration(src.release_requests(rids), source="src",
+                             kv=payloads)
+        for _ in range(50):                # admit + scatter + tail span
+            live = {r.rid: r for r in dst.scheduler.running}
+            if all(rid in live and live[rid].prefill_done for rid in rids):
+                break
+            dst.step()
+        back = dst.export_kv(rids)
+        for rid in rids:
+            rows = payloads[rid]["rows"]
+            assert back[rid]["rows"] >= rows
+            for n, a in payloads[rid]["data"].items():
+                b = back[rid]["data"][n][:, :a.shape[1]]
+                # rows past the shipped ones belong to the receiver
+                full, part = divmod(rows, 16)
+                np.testing.assert_array_equal(a[:, :full], b[:, :full], n)
+                if part:
+                    np.testing.assert_array_equal(
+                        a[:, full, :, :part], b[:, full, :, :part], n)
+        outs = _run_to_done(dst, rids)
+        for rid in base:
+            np.testing.assert_array_equal(base[rid], outs[rid])
+        assert dst.stats()["handoff_fallbacks"] == 0
+
     def test_handoff_mid_chunked_prefill(self, model, params):
         """A chunked-prefill request handed off MID-PROMPT ships only the
         rows it has cached; the receiver's tail span finishes the prompt

@@ -50,8 +50,8 @@ def test_flash_kernel_names_in_the_lowered_text():
 def test_paged_decode_kernel_name_in_the_lowered_text():
     S, NB, Nkv, bs, D, MB = 2, 5, 2, 16, 64, 2
     text = jax.jit(_paged).lower(
-        jnp.zeros((S, 1, 4, D)), jnp.zeros((NB, Nkv, bs, D)),
-        jnp.zeros((NB, Nkv, bs, D)), jnp.zeros((S, MB), jnp.int32),
+        jnp.zeros((S, 1, 4, D)), jnp.zeros((NB, bs, Nkv, D)),
+        jnp.zeros((NB, bs, Nkv, D)), jnp.zeros((S, MB), jnp.int32),
         jnp.zeros((S,), jnp.int32), jnp.zeros((S, Nkv, 1, D)),
         jnp.zeros((S, Nkv, 1, D))).as_text(debug_info=True)
     assert re.search(r'"jit\(_paged\)/paged_decode/pallas_call"', text)
@@ -136,7 +136,7 @@ def test_paged_decode_instruction_name(topo, mosaic):
     one = jax.sharding.SingleDeviceSharding(topo.devices[0])
     slots, NB, bs, MB = 8, 65, 64, 16
     row = _sds((slots, NKV, 1, D), jnp.bfloat16, one)
-    pool = _sds((NB, NKV, bs, D), jnp.bfloat16, one)
+    pool = _sds((NB, bs, NKV, D), jnp.bfloat16, one)
     calls = _mosaic_calls(jax.jit(_paged).lower(
         _sds((slots, 1, NQ, D), jnp.bfloat16, one), pool, pool,
         _sds((slots, MB), jnp.int32, one), _sds((slots,), jnp.int32, one),

@@ -1,0 +1,191 @@
+"""The paged pool is written IN PLACE (ISSUE 24).
+
+A decode step, a span and a prefill each write a few K/V rows into a pool
+of gigabytes. Stored head-major (``[L, NB, n_kv, block_size, head_dim]``)
+a token's row is one line inside the ``(block_size, head_dim)`` tile of
+every head, and the TPU's compiler answered each such write by moving the
+WHOLE pool to another layout, scattering, and moving it back: four copies
+of 1.6 GB a step in the chat cell (PERF.md §5-6). Stored token-major the
+row is a whole minor tile and the scatter is in place.
+
+Nothing on the CPU shows this: the copies exist only in the program the
+TPU's compiler builds. So the serving engine's own jitted functions are
+compiled here for a described v5e (no chip attached) at the two serve
+cells' pool shapes and a bf16 pool, and the compiled text must hold no op
+that produces a whole pool or scale plane other than the scatters.
+
+libtpu is loaded behind a fixture, as in ``test_kernel_names.py``, the
+only other file that describes a chip. Under several workers each of the
+two files' workers loads it; the driver's command allows that
+(``ALLOW_MULTIPLE_LIBTPU_LOAD=1``), and where it is not allowed the later
+file's tests skip, they do not fail.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu
+from deepspeed_tpu.models import TransformerConfig, make_model
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu from loading
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+# the pools of the benchmark's two serve cells (benchmark/configs/*-serve
+# .json: layers x blocks, slots, table width at 64-token blocks) and a
+# float pool of the chat cell's shape at half the blocks
+CASES = {
+    "chat-int8": dict(layers=16, blocks=1537, slots=48, mb=32, bits=8),
+    "mixtral-int8": dict(layers=4, blocks=1025, slots=32, mb=32, bits=8),
+    "chat-bf16": dict(layers=16, blocks=769, slots=48, mb=16, bits=0),
+}
+BS, NKV, HD = 64, 8, 128                 # both families: 8 kv heads of 128
+
+
+@pytest.fixture(scope="module")
+def engines():
+    """One toy-sized engine per case on the CPU: only its jitted FUNCTIONS
+    are used, re-lowered on abstract arguments at the cell's shapes (a
+    program is shaped by its arguments, not by the engine's own pool)."""
+    built = {}
+
+    def get(case):
+        if case not in built:
+            c = CASES[case]
+            cfg = TransformerConfig(
+                vocab_size=512, hidden_size=256, num_layers=c["layers"],
+                num_heads=NKV, num_kv_heads=NKV, head_dim=HD,
+                intermediate_size=512, max_seq_len=4096, position_type="rotary",
+                activation="silu_glu", norm_type="rmsnorm",
+                tie_embeddings=False, dtype=jnp.bfloat16,
+                attention_impl="xla")
+            built[case] = deepspeed_tpu.init_serving(
+                make_model(cfg), config={"kv_cache_bits": c["bits"]},
+                serving=dict(max_seqs=2, block_size=BS, max_model_len=128,
+                             decode_backend="xla"),
+                dtype=jnp.bfloat16)
+        return built[case]
+    yield get
+    for srv in built.values():
+        srv.close()
+
+
+def _abstract(tree, sharding):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _program(srv, case, kind, one_chip, mb=None):
+    """(lowered-and-compiled program, abstract pools) of one of the
+    engine's three pool-writing functions at the case's shapes (``mb``:
+    another table width than the case's)."""
+    c = CASES[case]
+    S, MB = c["slots"], mb or c["mb"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = _abstract(srv.engine.params, one_chip)
+    pools = _abstract(jax.eval_shape(
+        lambda: srv.model.init_paged_cache(c["blocks"], BS,
+                                           dtype=jnp.bfloat16)), one_chip)
+    key = sds((2,), jnp.uint32)
+    if kind == "step":                    # the decode quantum's one step
+        fn = jax.jit(srv._get_quantum_step().__wrapped__,
+                     donate_argnums=(1, 4))
+        args = (params, pools, sds((S,), jnp.int32), sds((S, MB), jnp.int32),
+                sds((S,), jnp.int32), sds((S,), jnp.bool_), key)
+    elif kind == "span":                  # speculation verify, T = 4
+        fn = jax.jit(srv._get_spec_step().__wrapped__, donate_argnums=(1,))
+        args = (params, pools, sds((S, 4), jnp.int32),
+                sds((S, MB), jnp.int32), sds((S,), jnp.int32),
+                sds((S,), jnp.bool_), key)
+    else:                                 # prefill of one 256-token bucket
+        fn = jax.jit(srv._get_prefill_fn(256).__wrapped__,
+                     donate_argnums=(2,))
+        args = (params, sds((1, 256), jnp.int32), pools,
+                sds((256 // BS,), jnp.int32), sds((), jnp.int32), key)
+    # the suite's "highest" matmul precision is the CPU parity tests'
+    # (conftest.py); the chip runs at the default
+    with jax.default_matmul_precision("default"):
+        return fn.lower(*args).compile(), pools
+
+
+_INSTR = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]*)\]\S* "
+                    r"([\w\-]+)\(")
+
+
+def whole_pool_ops(hlo: str, pools) -> list:
+    """Instructions OUTSIDE fused computations whose result is a whole
+    pool leaf and that are a copy, a transpose, or a fusion that holds no
+    scatter: each one reads and writes the leaf's every byte."""
+    names = {"int8": "s8", "bfloat16": "bf16", "float32": "f32"}
+    shapes = {(names[np.dtype(a.dtype).name], ",".join(map(str, a.shape)))
+              for a in jax.tree.leaves(pools)}
+    # computation name -> its lines
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    fused = set(re.findall(r"kind=k\w+, calls=%([\w.\-]+)", hlo))
+    bad = []
+    for name, lines in comps.items():
+        if name in fused:
+            continue
+        for line in lines:
+            m = _INSTR.match(line)
+            if not m or (m.group(1), m.group(2)) not in shapes:
+                continue
+            op = m.group(3)
+            if op == "fusion":
+                callee = re.search(r"calls=%([\w.\-]+)", line).group(1)
+                if any(" scatter(" in l for l in comps.get(callee, ())):
+                    continue
+            elif op not in ("copy", "transpose"):
+                continue
+            bad.append(line.strip()[:160])
+    return bad
+
+
+@pytest.mark.parametrize("case,kind", [
+    ("chat-int8", "step"), ("mixtral-int8", "step"), ("chat-bf16", "step"),
+    ("chat-int8", "span"), ("chat-int8", "prefill")])
+def test_pool_is_written_in_place(case, kind, one_chip, engines):
+    compiled, pools = _program(engines(case), case, kind, one_chip)
+    bad = whole_pool_ops(compiled.as_text(), pools)
+    assert not bad, "\n".join(bad)
+    # no temporary of a whole leaf's size either (a relayout's scratch, a
+    # scatter that lost its alias). At the cells' table width the READ
+    # side's gathered views (slots x table x block, K and V, three passes)
+    # are 0.6-1.0 GB of temporaries by themselves — more than the whole
+    # Mixtral pool — and share their space with whatever the write needs,
+    # so the write's own temporaries are read off the same program with a
+    # table one block wide: under the bytes of ONE K/V leaf (a layer's K
+    # and V slices, copied inside the scan, are already a quarter of a
+    # four-layer pool).
+    if kind != "prefill":                 # a prefill reads no table
+        compiled, pools = _program(engines(case), case, kind, one_chip, mb=1)
+    leaf = pools["k"]
+    leaf_bytes = int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < leaf_bytes, (temp, leaf_bytes)

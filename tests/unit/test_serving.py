@@ -221,8 +221,8 @@ def test_paged_kernel_agrees_with_xla_gather():
     # D=16 < the kernel's TPU-lane sweet spot but interpret mode is exact
     ks = jax.random.split(jax.random.PRNGKey(5), 5)
     q = jax.random.normal(ks[0], (S, 1, nq, D), jnp.float32)
-    pk = jax.random.normal(ks[1], (NB, nkv, bs, D), jnp.float32)
-    pv = jax.random.normal(ks[2], (NB, nkv, bs, D), jnp.float32)
+    pk = jax.random.normal(ks[1], (NB, bs, nkv, D), jnp.float32)
+    pv = jax.random.normal(ks[2], (NB, bs, nkv, D), jnp.float32)
     kr = jax.random.normal(ks[3], (S, nkv, 1, D), jnp.float32)
     vr = jax.random.normal(ks[4], (S, nkv, 1, D), jnp.float32)
     tabs = jnp.asarray(
@@ -399,7 +399,7 @@ def test_measure_paged_backends_returns_timings():
     from deepspeed_tpu.inference.serving import measure_paged_backends
     cfg = _cfg()
     nkv, hd = cfg.kv_heads, cfg.dim_per_head
-    kp = jnp.zeros((5, nkv, 8, hd), jnp.float32)
+    kp = jnp.zeros((5, 8, nkv, hd), jnp.float32)
     xla_ms, pallas_ms = measure_paged_backends(
         cfg, kp, kp, max_seqs=2, MB=2, block_size=8, num_blocks=5,
         dtype=jnp.float32, iters=1)

@@ -89,11 +89,12 @@ def test_tp2_token_identical_and_pool_bytes_law():
     srv2 = _serving(model, params, mesh=_mesh(2, tensor=2))
     assert (srv2.tp, srv2.ep) == (2, 1)
     assert srv2.mesh_desc == "tensor=2"
-    # the pool shards on the kv-head dim (axis 2) over `tensor`
+    # the token-major pool [L, NB, bs, nkv, hd] shards on the kv-head dim
+    # (axis 3) over `tensor`
     spec = srv2.pools["k"].sharding.spec
-    assert spec[2] == "tensor", spec
+    assert spec[3] == "tensor", spec
     shard = srv2.pools["k"].sharding.shard_shape(srv2.pools["k"].shape)
-    assert shard[2] * 2 == srv2.pools["k"].shape[2]
+    assert shard[3] * 2 == srv2.pools["k"].shape[3]
     outs2 = srv2.run(list(reqs))
     for rid in outs1:
         np.testing.assert_array_equal(outs1[rid], outs2[rid],
@@ -106,7 +107,7 @@ def test_tp2_token_identical_and_pool_bytes_law():
     assert (st2["tp"], st2["ep"]) == (2.0, 1.0)
     # the out_shardings pin: after full serving rounds (prefill + quantum
     # steps + donations) the pool is still head-sharded, not replicated
-    assert srv2.pools["k"].sharding.spec[2] == "tensor"
+    assert srv2.pools["k"].sharding.spec[3] == "tensor"
 
 
 def test_ep4_moe_matches_unsharded():
