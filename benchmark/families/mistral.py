@@ -1,5 +1,13 @@
-"""Plain references: the Mistral block and the Mixtral block.
+"""The Mistral family: its plain reference, its cost model, its toy widths.
 
+A model FAMILY is a file found by the configuration's published
+``model_type`` (``harness/loadgen.load_family``), like a traffic kind or a
+per-layer reader. This one also serves Mixtral (``mixtral.py`` re-exports
+it): the expert layer is chosen by what the parameter tree holds, the
+operation and byte counts by ``num_local_experts``.
+
+1. The plain reference
+----------------------
 Straight ``jax.numpy`` in float32 under ``default_matmul_precision
 ("highest")`` (on a TPU a float32 matmul otherwise runs in bf16 passes): no
 kernel, no cache, no batching, no capacity. One sequence at a time, one
@@ -26,6 +34,26 @@ Departures: none in the arithmetic. The parameter tree is the program's
 or fused ``w_in_gate`` = [up | gate], experts as ``moe_w_in/moe_w_gate/moe_w_out``
 [L, E, ...], router ``wg``): the reference reads the SAME stored values the
 engine serves or trains with and upcasts them to float32.
+
+2. Operations (``train_flops_per_token``, ``flash_flops``)
+----------------------------------------------------------
+Computed from the published config's shapes. Counts matrix-multiplication
+work only: 2 FLOPs per multiply-add. The input embedding is a row lookup and does no matmul, so its table is NOT in the
+count (``bench._count_params`` includes it: at 2 layers that is 131 M of
+698 M parameters, an MFU overstated by ~18 %). The output head is a matmul
+whether tied or not. Recomputed operations (remat replays) never count.
+
+Attention is causal: a query at position i needs keys 0..i, so the required
+work is HALF the S x S square. Every function here counts that half once
+and says so; the PaLM "12 L H S" term counts the full square.
+
+3. Bytes of a decode step (``decode_step_bytes``)
+------------------------------------------------
+A decode step reads every weight the batch touches once and the live KV
+rows of every running sequence. With a full batch every expert of an MoE
+layer is hit (32 tokens x top-2 over 8 experts: P(an expert idle) =
+(6/8)^32 ~ 1e-4), so all experts count. Writes (one KV row per sequence)
+are four orders of magnitude smaller and are left out.
 """
 import functools
 import math
@@ -37,7 +65,15 @@ F32 = jnp.float32
 _HIGHEST = functools.partial(jax.default_matmul_precision, "highest")
 
 
-def _dims(hf):
+# --rehearsal: the same head grouping at toy widths (head_dim 64); the layer
+# pattern has period 1, and a configuration's own expert count stays
+TOY = {"vocab_size": 512, "hidden_size": 512, "intermediate_size": 512,
+       "num_attention_heads": 8, "num_key_value_heads": 2,
+       "num_hidden_layers": 2}
+
+
+def dims(hf: dict):
+    """(hidden, query heads, kv heads, head size) of a published config."""
     H = hf["hidden_size"]
     nh = hf["num_attention_heads"]
     nkv = hf.get("num_key_value_heads") or nh
@@ -88,7 +124,7 @@ class Reference:
 
     def _attn_block(self, layers, i, x):
         hf = self.hf
-        H, nh, nkv, hd = _dims(hf)
+        H, nh, nkv, hd = dims(hf)
         S = x.shape[0]
         h = _rms(x, _at(layers, "ln1_scale", i), hf["rms_norm_eps"])
         if "wqkv" in layers:
@@ -191,3 +227,89 @@ class Reference:
             tot += float((lse - gold).sum())
             n += lg.shape[0]
         return tot / n
+
+
+# ---- the cost model: operations ------------------------------------------
+
+def attn_proj_params(hf: dict) -> int:
+    H, nh, nkv, hd = dims(hf)
+    return H * nh * hd + 2 * H * nkv * hd + nh * hd * H
+
+
+def ffn_params(hf: dict, active: bool = True) -> int:
+    """One layer's feed-forward matmul parameters (gated: three matrices).
+    MoE: ``active`` counts the experts one token uses plus the router;
+    otherwise every expert (what a decode step must READ)."""
+    H, F = hf["hidden_size"], hf["intermediate_size"]
+    E = hf.get("num_local_experts", 1)
+    if E <= 1:
+        return 3 * H * F
+    k = hf["num_experts_per_tok"] if active else E
+    return k * 3 * H * F + H * E
+
+
+def head_params(hf: dict) -> int:
+    return hf["hidden_size"] * hf["vocab_size"]
+
+
+def matmul_params(hf: dict, active: bool = True) -> int:
+    """Parameters that take part in a matmul for one token."""
+    L = hf["num_hidden_layers"]
+    return L * (attn_proj_params(hf) + ffn_params(hf, active)) + head_params(hf)
+
+
+def attn_flops_per_token_fwd(hf: dict, seq_len: int) -> float:
+    """QK^T and PV of ONE layer's forward, per token, causal half: a token
+    at a uniformly random position sees seq_len / 2 keys on average."""
+    _, nh, _, hd = dims(hf)
+    return 2 * 2 * (seq_len / 2) * nh * hd
+
+
+def train_flops_per_token(hf: dict, seq_len: int) -> float:
+    """Forward + backward (= 3x forward) for one token of a seq_len
+    sequence: 6 FLOPs per matmul parameter plus causal attention."""
+    L = hf["num_hidden_layers"]
+    return 6.0 * matmul_params(hf) + 3.0 * L * attn_flops_per_token_fwd(hf, seq_len)
+
+
+def flash_flops(hf: dict, batch: int, seq_len: int) -> dict:
+    """FLOPs the flash-attention kernels of ONE layer need for one step.
+
+    Forward: QK^T and PV (2 matmuls). Backward: dV, dP, dQ, dK and the
+    score recompute that replaces the stored probabilities (5 matmuls), the
+    usual FlashAttention accounting (backward = 2.5 x forward). Each matmul
+    is 2 * S^2 * hd per head over the full square; causal halves it, counted
+    once. A remat replay of the forward is extra kernel TIME and zero
+    required FLOPs."""
+    _, nh, _, hd = dims(hf)
+    one = 2.0 * batch * nh * seq_len * seq_len * hd / 2.0   # one causal matmul
+    return {"fwd": 2 * one, "bwd": 5 * one, "total": 7 * one}
+
+
+# ---- the cost model: bytes of a decode step -------------------------------
+
+def weight_bytes(hf: dict, bytes_per_param: float = 2.0) -> float:
+    """Layer stack + output head as served (bf16). The embedding table is
+    read one row per token and is not counted."""
+    return matmul_params(hf, active=False) * bytes_per_param
+
+
+def kv_bytes_per_token(hf: dict, kv_bits: int) -> float:
+    """K and V of one cached position across all layers. int8 pools carry
+    one f32 scale per (position, kv head) for each of K and V."""
+    _, _, nkv, hd = dims(hf)
+    L = hf["num_hidden_layers"]
+    if kv_bits == 8:
+        per_head = hd * 1 + 4
+    else:
+        per_head = hd * 2
+    return 2.0 * L * nkv * per_head
+
+
+def decode_step_bytes(hf: dict, counters: dict) -> float:
+    """Least bytes one decode step reads: weights once + the live cache
+    (``counters``: what the serve job counted over the window — the pool's
+    ``kv_cache_bits`` and the mean of the live rows sampled after each
+    round)."""
+    return (weight_bytes(hf) + kv_bytes_per_token(hf, counters["kv_cache_bits"])
+            * counters["mean_live_tokens"])

@@ -1,9 +1,11 @@
 """What both jobs share: the compile counter, device readings, the model
-config, the profiler session, the rehearsal's toy widths."""
+config, the profiler session, the rehearsal's shrink of the traffic."""
 import glob
 import json
 import os
 import shutil
+
+from . import loadgen
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 OUT_DIR = os.path.join(BENCH_DIR, "out")
@@ -11,10 +13,8 @@ OUT_DIR = os.path.join(BENCH_DIR, "out")
 # keys of a configuration file that are not the published config
 _NOT_HF = ("source", "reduced", "assumed", "deployment", "run", "correct")
 
-# --rehearsal: the same family, head grouping and expert count at toy widths
-# (head_dim 64); lengths of the traffic are divided by REHEARSAL_SHRINK
-TOY = {"vocab_size": 512, "hidden_size": 512, "intermediate_size": 512,
-       "num_attention_heads": 8, "num_key_value_heads": 2}
+# --rehearsal: the configuration's family gives the toy widths (its `TOY`);
+# lengths of the traffic are divided by REHEARSAL_SHRINK
 REHEARSAL_SHRINK = 8
 
 
@@ -28,19 +28,20 @@ def load_config(name: str) -> dict:
 
 
 def hf_of(cfg: dict, rehearsal: bool = False) -> dict:
-    """The published config dict as it is run (depth cut included)."""
+    """The published config dict as it is run (depth cut included); for the
+    rehearsal, at its family's toy widths and depth."""
     hf = {k: v for k, v in cfg.items() if k not in _NOT_HF}
     if rehearsal:
-        hf.update(TOY)
-        hf["num_hidden_layers"] = min(2, hf["num_hidden_layers"])
+        hf.update(loadgen.load_family(hf).TOY)
     return hf
 
 
 def model_config(cfg: dict, hf: dict, max_seq_len: int):
+    """The program's model config, by the program's own reading of the
+    published keys (depth included, whatever the family calls it)."""
     from deepspeed_tpu.models.hf_import import hf_config_to_transformer
     return hf_config_to_transformer(
-        hf, num_layers=hf["num_hidden_layers"], max_seq_len=max_seq_len,
-        **cfg["run"].get("overrides", {}))
+        hf, max_seq_len=max_seq_len, **cfg["run"].get("overrides", {}))
 
 
 class CompileCounter:

@@ -10,6 +10,9 @@ in the kind name are underscores in the file name) with
 a pure function of its arguments. ``ctx`` carries what only the cell knows:
 ``vocab_size``, ``seconds`` (the window), ``max_model_len``. Helpers shared
 by the kinds live here so that a new kind stays a few lines.
+
+``load_module`` is also how every other file of its own is found by name: a
+per-layer reader (``layer_metrics/``), a model family (``families/``).
 """
 import importlib.util
 import json
@@ -21,15 +24,37 @@ import numpy as np
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+# what a family file provides (benchmark/README.md, "A family")
+FAMILY_PROTOCOL = ("Reference", "train_flops_per_token", "flash_flops",
+                   "decode_step_bytes", "TOY")
+
+
 def load_module(directory: str, name: str):
     path = os.path.join(BENCH_DIR, directory, name.replace("-", "_") + ".py")
     if not os.path.exists(path):
-        raise FileNotFoundError(f"{directory} has no {os.path.basename(path)}")
+        raise FileNotFoundError(f"{directory} has no {os.path.basename(path)} "
+                                f"(looked for {path})")
     spec = importlib.util.spec_from_file_location(
         f"benchmark.{directory}.{name.replace('-', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_family(hf: dict):
+    """The model family of a configuration: ``families/<model_type>.py``,
+    found by the published ``model_type`` the configuration file carries at
+    its top level. There is no default family: a ``model_type`` without a
+    file, or a file without the whole protocol, raises."""
+    if not hf.get("model_type"):
+        raise KeyError("the configuration has no `model_type`: its family file "
+                       "(benchmark/families/<model_type>.py) cannot be found")
+    family = load_module("families", hf["model_type"])
+    missing = [n for n in FAMILY_PROTOCOL if not hasattr(family, n)]
+    if missing:
+        raise AttributeError(f"{family.__file__} lacks {missing} of the family "
+                             f"protocol {list(FAMILY_PROTOCOL)}")
+    return family
 
 
 def load_traffic(name: str) -> dict:

@@ -12,7 +12,7 @@ import time
 
 import numpy as np
 
-from . import common, correct, loadgen, metrics, reference
+from . import common, correct, loadgen, metrics
 from .common import log
 
 
@@ -43,14 +43,32 @@ def build(cell, cfg, traffic, seed: int, rehearsal: bool):
         make_model(mcfg, name=cell["config"]), serving=serving,
         rng=jax.random.PRNGKey(seed), **run_cfg.get("init_serving", {}))
     kv_bits = int(srv.model.config.kv_cache_bits or 0)
-    log(f"engine: decode_backend={srv.decode_backend} kv_cache_bits={kv_bits} "
-        f"pool {tuple(srv.pools['k'].shape)} {srv.pools['k'].dtype} "
-        f"max_seqs={srv.config.max_seqs} max_model_len={srv.max_model_len}")
+    log(f"engine: decode_backend={srv.decode_backend} kv_cache_bits={kv_bits} pool "
+        + ", ".join(f"{k} {v['shape']} {v['dtype']}" for k, v in pool_leaves(srv).items())
+        + f" max_seqs={srv.config.max_seqs} max_model_len={srv.max_model_len}")
     for key, want in ({} if rehearsal else run_cfg.get("expect", {})).items():
-        got = {"decode_backend": srv.decode_backend, "kv_cache_bits": kv_bits}[key]
+        got = engine_attr(srv, key)
         if got != want:
             raise RuntimeError(f"config expects {key}={want!r}, engine has {got!r}")
     return srv, hf, traffic
+
+
+def pool_leaves(srv) -> dict:
+    """Every leaf of the engine's cache pool by its path: shape and dtype."""
+    import jax
+    return {jax.tree_util.keystr(path, simple=True, separator="/"):
+            {"shape": tuple(x.shape), "dtype": str(x.dtype)}
+            for path, x in jax.tree_util.tree_leaves_with_path(srv.pools)}
+
+
+def engine_attr(srv, key: str):
+    """An ``expect`` key of a configuration: the attribute of that name on
+    the serving engine or, failing that, on its model's config."""
+    for owner in (srv, srv.model.config):
+        if hasattr(owner, key):
+            return getattr(owner, key)
+    raise KeyError(f"config expects {key!r}: neither the serving engine nor "
+                   "its model config has an attribute of that name")
 
 
 def warm(srv, traffic, vocab: int, seed: int):
@@ -233,7 +251,7 @@ def run(cell, cfg, traffic, args, env) -> dict:
     counters = dict(d, compiles_in_window=compiles_in_window,
                     max_seqs=srv.config.max_seqs,
                     decode_quantum=srv.config.decode_quantum,
-                    kv_cache_bits=kv_bits, stats=srv.stats(),
+                    kv_cache_bits=kv_bits, pool=pool_leaves(srv), stats=srv.stats(),
                     phases=srv.phase_decomposition(),
                     bytes_in_use=mem["bytes_in_use"], setup_parts=setup_parts)
     host = {k: counters.pop(k) for k in ("ttft_ms", "tpot_ms", "late_ms", "miss_ms")}
@@ -272,11 +290,15 @@ def run(cell, cfg, traffic, args, env) -> dict:
                if len(finished[rid_of[idx]].generated) != schedule[idx]["max_new_tokens"]]
     checks = [{"name": "finished_requests_have_their_length",
                "wrong": len(bad_len), "ok": not bad_len and bool(done_idx)}]
-    ref = reference.Reference(hf, srv.engine.params)
+    # env["family"] is not bound to a name of its own: every local variable of
+    # this frame, which is live during the warm-up, costs a serve cell ~0.25 s
+    # of setup_s on the chip's host (PERF.md section 6, PR 25)
+    ref = env["family"].Reference(hf, srv.engine.params)
     t_ref = time.perf_counter()
     checks.append(correct.check_tokens_vs_reference(
         samples, ref, float(cc["margin"]), float(cc["min_judged_share"]),
         float(cc["min_agreement"]), float(cc.get("max_mismatch_share", 0.0))))
+    checks[-1]["family"] = env["family"].__name__
     log(f"reference forward over {len(samples)} requests took "
         f"{time.perf_counter() - t_ref:.1f} s (after the window, not in setup_s)")
     checks.append({"name": "no_compile_in_window", "count": compiles_in_window,

@@ -9,7 +9,7 @@ import time
 
 import numpy as np
 
-from . import common, correct, flops, loadgen, reference
+from . import common, correct, loadgen
 from .common import log
 
 
@@ -19,6 +19,7 @@ def run(cell, cfg, traffic, args, env) -> dict:
     from deepspeed_tpu.models import make_model
 
     hf = common.hf_of(cfg, args.rehearsal)
+    family = env["family"]
     run_cfg = cfg["run"]
     if args.rehearsal:
         traffic = dict(traffic)
@@ -101,7 +102,7 @@ def run(cell, cfg, traffic, args, env) -> dict:
     window_losses = losses[warm:warm + steps]
     tokens = steps * sched["tokens_per_step"]
     rate = tokens / t_end / chips
-    fpt = flops.train_flops_per_token(hf, seq)
+    fpt = family.train_flops_per_token(hf, seq)
     log(f"samples: {steps} steps = {tokens} tokens in a {t_end:.3f} s window; "
         f"{fpt / 1e9:.3f} GFLOP/token (matmul params, causal half) -> MFU "
         f"{100 * rate * fpt / env['peaks']['bf16_flops_per_s']:.1f} % of "
@@ -118,11 +119,11 @@ def run(cell, cfg, traffic, args, env) -> dict:
     cc = cfg["correct"]
     checks = [correct.check_losses(window_losses)]
     batch = pool[fed % len(pool)]
-    ref = reference.Reference(hf, engine.state["params"])
+    ref = family.Reference(hf, engine.state["params"])
     ref_loss = ref.loss(batch)
     eng_loss = float(inner({"input_ids": batch})["loss"])
-    checks.append(correct.check_loss_vs_reference(
-        eng_loss, ref_loss, float(cc["loss_rel_tol"])))
+    checks.append(dict(correct.check_loss_vs_reference(
+        eng_loss, ref_loss, float(cc["loss_rel_tol"])), family=family.__name__))
     checks.append({"name": "no_compile_in_window", "count": compiles_in_window,
                    "ok": compiles_in_window == 0})
     bad = sum(1 for x in window_losses if not np.isfinite(x))
@@ -131,6 +132,7 @@ def run(cell, cfg, traffic, args, env) -> dict:
                 "step_ms_groups": [float(x) for x in gaps],
                 "sequences_per_step": sched["sequences_per_step"],
                 "seq_len": seq, "tokens_per_step": sched["tokens_per_step"],
+                "num_layers": mcfg.num_layers,
                 "flops_per_token": fpt, "losses": losses, "warm_steps": warm,
                 "compiles_in_window": compiles_in_window,
                 "bytes_in_use": mem["bytes_in_use"]}

@@ -56,6 +56,11 @@ def main(argv=None) -> int:
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                                    f" --xla_force_host_platform_device_count={cell['chips']}")
 
+    # the configuration and its model family, BEFORE the backend comes up: a
+    # `model_type` without benchmark/families/<model_type>.py fails here
+    from benchmark.harness import common, loadgen
+    cfg = common.load_config(cell["config"])
+    family = loadgen.load_family(cfg)
     import jax
     devs = jax.devices()
     # setup_s starts HERE, with the backend up. Before it: the interpreter,
@@ -64,7 +69,7 @@ def main(argv=None) -> int:
     # host (PERF.md section 2); they are logged as `runtime start` instead.
     t_start = time.perf_counter()
     import deepspeed_tpu  # noqa: F401 — a checkout without the program fails here
-    from benchmark.harness import common, correct, loadgen, peaks, trace_reduce
+    from benchmark.harness import correct, peaks, trace_reduce
     from benchmark.harness.common import log
     from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
@@ -95,13 +100,12 @@ def main(argv=None) -> int:
         return 1
     pk = peaks.peaks_for("TPU v5 lite" if args.rehearsal else device["kind"])
 
-    cfg = common.load_config(cell["config"])
     traffic = loadgen.load_traffic(cell["traffic"])
     job = importlib.import_module(f"benchmark.harness.{cfg['run']['job']}_job")
     log(f"runtime start (interpreter + import jax + backend up; NOT in setup_s) "
         f"{t_start - T_PROCESS:.1f} s")
     env = {"t_start": t_start, "compiles": compiles, "peaks": pk,
-           "device": device}
+           "device": device, "family": family}
     res = job.run(cell, cfg, traffic, args, env)
 
     # ---- per-layer metrics: one reader per file ---------------------------
@@ -112,7 +116,7 @@ def main(argv=None) -> int:
         log(f"trace reduced in {time.perf_counter() - t:.1f} s: window "
             f"{reduced['window_s']:.3f} s, {reduced['n_devices']} device plane(s)")
     run = {"job": res["job"], "cell": cell, "config": cfg, "traffic": traffic,
-           "hf": res["hf"], "chips": cell["chips"], "peaks": pk,
+           "hf": res["hf"], "family": family, "chips": cell["chips"], "peaks": pk,
            "counters": res["counters"], "host": res["host"], "trace": reduced,
            "e2e": res["e2e"]}
     per_layer = {}

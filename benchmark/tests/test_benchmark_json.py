@@ -76,6 +76,24 @@ def test_every_workload_resolves_to_files_that_exist():
         assert "setup_s" in e2e_cells[c] and len(e2e_cells[c]) >= 2
 
 
+def test_every_configuration_resolves_to_a_family_with_the_whole_protocol():
+    for c in B["configs"]:
+        cfg = common.load_config(c["name"])
+        fam = loadgen.load_family(cfg)
+        assert os.path.samefile(fam.__file__, os.path.join(
+            ROOT, "benchmark", "families", cfg["model_type"] + ".py"))
+        for name in loadgen.FAMILY_PROTOCOL:
+            assert hasattr(fam, name), (c["name"], name)
+        assert callable(fam.Reference.logits)
+        if cfg["run"]["job"] == "train":
+            assert callable(fam.Reference.loss)
+        # the toy widths replace published keys, they invent none
+        assert set(fam.TOY) <= set(cfg), (c["name"], set(fam.TOY) - set(cfg))
+        toy = common.hf_of(cfg, rehearsal=True)
+        for k in ("num_local_experts", "num_experts_per_tok", "sliding_window"):
+            assert toy.get(k) == cfg.get(k)
+
+
 def test_every_per_layer_metric_has_a_reader_that_agrees_with_its_entry():
     cells = {w["name"] for w in B["workloads"]}
     where = {m["name"]: set(m.get("workloads", cells)) for m in B["end_to_end"]}

@@ -8,7 +8,7 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
-from benchmark.harness import common, correct, reference  # noqa: E402
+from benchmark.harness import common, correct, loadgen  # noqa: E402
 
 
 @pytest.mark.parametrize("name", ["mistral-7b-serve", "mixtral-8x7b-serve"])
@@ -26,10 +26,28 @@ def test_reference_matches_the_programs_forward_at_toy_widths(name):
     ids = np.random.default_rng(0).integers(0, hf["vocab_size"], 96, dtype=np.int32)
     with jax.default_matmul_precision("highest"):
         want = np.asarray(model.apply(params, jnp.asarray(ids)[None]))[0]
-    got = np.asarray(reference.Reference(hf, params).logits(ids, pad_to=128))
+    family = loadgen.load_family(hf)
+    assert family.__name__ == "benchmark.families." + hf["model_type"]
+    got = np.asarray(family.Reference(hf, params).logits(ids, pad_to=128))
     assert got.shape == want.shape == (96, hf["vocab_size"])
     # float32 both ways, different op order: agreement to rounding
     assert np.abs(got - want).max() < 2e-4 * max(1.0, np.abs(want).max())
+
+
+def test_a_model_type_without_a_family_file_raises_with_the_path():
+    with pytest.raises(FileNotFoundError) as e:
+        loadgen.load_family({"model_type": "no-such-family"})
+    assert os.path.join(ROOT, "benchmark", "families", "no_such_family.py") in str(e.value)
+    with pytest.raises(KeyError, match="model_type"):
+        loadgen.load_family({"hidden_size": 8})
+
+
+def test_a_family_file_without_the_whole_protocol_raises(monkeypatch, tmp_path):
+    (tmp_path / "families").mkdir()
+    (tmp_path / "families" / "half.py").write_text("TOY = {}\nclass Reference: pass\n")
+    monkeypatch.setattr(loadgen, "BENCH_DIR", str(tmp_path))
+    with pytest.raises(AttributeError, match="train_flops_per_token"):
+        loadgen.load_family({"model_type": "half"})
 
 
 class _FakeRef:

@@ -10,7 +10,7 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
-from benchmark.harness import trace_reduce as tr  # noqa: E402
+from benchmark.harness import common, loadgen, peaks, trace_reduce as tr  # noqa: E402
 
 FIXTURES = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures")
 
@@ -116,3 +116,24 @@ def test_recorded_trace_reduces_within_bounds(recorded):
     assert d["collective_ns"] == 0                                  # one chip
     assert any(n == "bench:window" for n, _, _ in r["spans"])
     assert len(r["breakdown"]["device_ops"]) == 10
+
+
+def test_the_roofline_readers_read_what_they_read_before_the_family_seam(recorded):
+    """The readers reach the cost model through the configuration's family;
+    the numbers are those of harness/flops.py and harness/bytes.py (PR 24)."""
+    pk = peaks.peaks_for("TPU v5 lite")
+    hf = common.hf_of(common.load_config("mistral-7b-train"))
+    run = {"trace": recorded, "chips": 1, "peaks": pk, "hf": hf,
+           "family": loadgen.load_family(hf),
+           "counters": {"sequences_per_step": 4, "seq_len": 2048, "num_layers": 2}}
+    got = loadgen.load_module("layer_metrics", "flash_attention_roofline").read(run)
+    assert got == pytest.approx(30.461886738808406, rel=1e-12)
+    # a decode step of 32.777 ms over 4321 live int8 rows of 16 layers
+    step = {"n_devices": 1, "devices": [
+        {"modules": {"jit_step": {"count": 80, "total_ns": 80 * 32.777e6}}}]}
+    hf = common.hf_of(common.load_config("mistral-7b-serve"))
+    run = {"trace": step, "peaks": pk, "hf": hf, "family": loadgen.load_family(hf),
+           "counters": {"kv_cache_bits": 8, "mean_live_tokens": 4321.0}}
+    for name in ("decode_step_roofline", "sat_decode_step_roofline"):
+        got = loadgen.load_module("layer_metrics", name).read(run)
+        assert got == pytest.approx(27.519673638744937, rel=1e-12)
