@@ -295,6 +295,12 @@ class ServingConfig:
     role: str = "both"                 # prefill | decode | both
 
 
+# expert-routing counters of a stats window (ServingEngine._note_expert_load)
+_MOE_COUNTERS = {"kept": 0, "asked": 0, "max_over_mean": 0.0, "rounds": 0,
+                 "touched": 0.0, "steps": 0, "prefill_touched": 0.0,
+                 "prefills": 0}
+
+
 class ServingEngine:
     """Continuous-batching server over an InferenceEngine's params/mesh.
 
@@ -561,6 +567,8 @@ class ServingEngine:
         self._stats_t0: Optional[float] = None
         # latency-frontier counters (reset_stats windows)
         self._itl_ms: List[float] = []
+        # expert-routing counters (reset_stats windows; _note_expert_load)
+        self._moe = dict(_MOE_COUNTERS)
         self._lat = {"spec_steps": 0, "spec_proposed": 0,
                      "spec_accepted": 0, "prefill_chunks": 0,
                      "prefill_chunk_tokens": 0, "cow_forks": 0}
@@ -870,11 +878,15 @@ class ServingEngine:
         fn = self._prefill_fns.get(P)
         if fn is None:
             import jax
+            from deepspeed_tpu.moe.sharded_moe import expert_load_tap
 
             def prefill(params, ids, pools, block_ids, length, key):
-                last, pools = self.model.prefill_paged(
-                    params, ids, pools, block_ids, length=length)
-                return self._sample(last, key), pools
+                with expert_load_tap() as tap:
+                    last, pools = self.model.prefill_paged(
+                        params, ids, pools, block_ids, length=length)
+                # the first token travels with the expert load [L, E + 1] of
+                # the REAL prompt tokens (None for a model without experts)
+                return (self._sample(last, key), tap.stacked()), pools
 
             outs = ((self._repl_sharding, self._pool_shardings)
                     if self._pool_shardings is not None else None)
@@ -891,6 +903,7 @@ class ServingEngine:
         if self._quantum_step is None:
             import jax
             import jax.numpy as jnp
+            from deepspeed_tpu.moe.sharded_moe import expert_load_tap
 
             backend = self.decode_backend
 
@@ -900,12 +913,17 @@ class ServingEngine:
                 # shared weights — donating it would force a re-page of
                 # every resident adapter each quantum step
                 lora = (apool, aidx) if apool is not None else None
-                logits, pools = self.model.decode_step_paged(
-                    params, tokens, pools, tables, seq_lens,
-                    active=active, backend=backend, lora=lora)
+                with expert_load_tap() as tap:
+                    logits, pools = self.model.decode_step_paged(
+                        params, tokens, pools, tables, seq_lens,
+                        active=active, backend=backend, lora=lora)
                 nxt = self._sample(logits, key)
                 nxt = jnp.where(active, nxt, tokens)
-                return pools, nxt, seq_lens + active.astype(jnp.int32)
+                # the tokens travel with the step's expert load [L, E + 1]
+                # over the ACTIVE slots (None for a model without experts):
+                # collected and fetched together
+                return (pools, (nxt, tap.stacked()),
+                        seq_lens + active.astype(jnp.int32))
 
             r = self._repl_sharding
             outs = ((self._pool_shardings, r, r)
@@ -1100,10 +1118,11 @@ class ServingEngine:
             first, self.pools = fn(self.engine.params, jnp.asarray(buf),
                                    self.pools, block_ids,
                                    jnp.int32(ctx.size), self._next_key())
-        self._tokens = self._tokens.at[req.slot].set(first[0])
+        self._tokens = self._tokens.at[req.slot].set(first[0][0])
         req.cached_rows = ctx.size
         req.prefill_done = True
-        req._first_dev = first                 # fetched at round boundary
+        # (token, the prompt's expert load): fetched at round boundary
+        req._first_dev = first
         self._publish_prefill(req, ctx)
 
     def _publish_prefill(self, req: Request, ctx) -> None:
@@ -1198,7 +1217,7 @@ class ServingEngine:
         if final:
             self._tokens = self._tokens.at[req.slot].set(first[0])
             req.prefill_done = True
-            req._first_dev = first             # fetched at round boundary
+            req._first_dev = (first, None)     # (token, no load): fetched
 
     def _tables_device(self):
         import jax.numpy as jnp
@@ -1424,10 +1443,12 @@ class ServingEngine:
                             for k in keys:
                                 if self._epoch != epoch:
                                     return None
+                                # t: (tokens, the step's expert load)
                                 p, t, lens = step_fn(params, p, t, tables,
                                                      lens, active, k, apool,
                                                      aidx)
                                 outs.append(t)
+                                t = t[0]
                     return p, t, outs, spec_dev
 
                 dev = self._with_watchdog(dispatch, armed=self._quantum_warm)
@@ -1440,13 +1461,15 @@ class ServingEngine:
             # round: the sampled tokens (quantum steps or the verify step's
             # accept verdict) AND every pending prefill/chunk token ride a
             # single device_get (under its own watchdog: a device that
-            # never answers hangs HERE)
+            # never answers hangs HERE). The expert load of each decode
+            # step and of each pending prefill rides the same call.
             with span("ds:serve.fetch") as sp:
                 toks, firsts, spec_host = self._with_watchdog(
                     lambda: jax.device_get(
-                        (jnp.stack(outs) if outs
+                        (jnp.stack([o[0] for o in outs]) if outs
                          else jnp.zeros((0, S), jnp.int32),
-                         [f for _, f in pending], spec_dev)),
+                         ([f for _, f in pending], [o[1] for o in outs]),
+                         spec_dev)),
                     armed=self._quantum_warm)
             ph["fetch_ms"] = sp.seconds * 1e3
             if self._tracer is not None and decode:
@@ -1465,6 +1488,7 @@ class ServingEngine:
             if keep is not None:
                 self.allocator.set_reserve(0)
         with span("ds:serve.commit") as sp:
+            firsts = self._note_expert_load(firsts)
             if spec_host is not None:
                 finished = self._commit_spec(spec_host, pending, firsts)
             else:
@@ -1492,6 +1516,41 @@ class ServingEngine:
             self._itl_ms.extend([gap_ms / m] * m)
             req.max_gap_ms = max(req.max_gap_ms or 0.0, gap_ms)
         req.last_token_t = now
+
+    def _note_expert_load(self, fetched) -> list:
+        """One round's routing counters, from arrays the round's one fetch
+        brought. ``fetched``: ([(first token, load) of each pending prefill],
+        [load of each decode step]); a load is [L, E + 1] int32 —
+        assignments kept per expert and layer, then the assignments asked
+        for (``sharded_moe._LoadTap``) — over the ACTIVE slots of a decode
+        step or the real tokens of a whole-prompt prefill, or None (a model
+        without experts, a chunked prefill). Speculation verify spans are
+        not counted. Returns the first tokens alone."""
+        firsts, step_loads = fetched
+        prefill_loads = [ld for _, ld in firsts]
+        firsts = [f for f, _ in firsts]
+        step_loads = [ld for ld in step_loads if ld is not None]
+        prefill_loads = [ld for ld in prefill_loads if ld is not None]
+        if not (step_loads or prefill_loads):
+            return firsts
+        m = self._moe
+        loads = np.asarray(step_loads + prefill_loads, np.int64)  # [n, L, E+1]
+        kept = loads[..., :-1]
+        m["kept"] += int(kept.sum())
+        m["asked"] += int(loads[..., -1].sum())
+        per_layer = kept.sum(axis=0)                               # [L, E]
+        busy = per_layer[per_layer.sum(axis=1) > 0]
+        if busy.size:
+            m["max_over_mean"] += float(np.mean(busy.max(axis=1)
+                                                / busy.mean(axis=1)))
+            m["rounds"] += 1
+        touched = (kept > 0).sum(axis=2).mean(axis=1)              # [n]
+        live = loads[:len(step_loads), :, -1].any(axis=1)  # steps with an active slot
+        m["touched"] += float(touched[:len(step_loads)][live].sum())
+        m["steps"] += int(live.sum())
+        m["prefill_touched"] += float(touched[len(step_loads):].sum())
+        m["prefills"] += len(prefill_loads)
+        return firsts
 
     def _commit_round(self, toks, pending, firsts) -> List[Request]:
         first_tok = {req.rid: int(np.asarray(f)[0])
@@ -2246,6 +2305,7 @@ class ServingEngine:
                           "handoffs": 0, "handoff_bytes": 0,
                           "handoff_fallbacks": 0}
         self._itl_ms = []
+        self._moe = dict(_MOE_COUNTERS)
         self._lat = {"spec_steps": 0, "spec_proposed": 0,
                      "spec_accepted": 0, "prefill_chunks": 0,
                      "prefill_chunk_tokens": 0, "cow_forks": 0}
@@ -2304,7 +2364,17 @@ class ServingEngine:
         Request lifecycle (always on): ``queue_wait_p50/p90_ms``
         (``admit_t - submit_t``), ``first_token_wait_p50/p90_ms``
         (``first_token_t - admit_t``) and ``token_gap_max_p50/p90_ms``
-        (each request's longest gap between two token deliveries)."""
+        (each request's longest gap between two token deliveries).
+
+        Expert routing (always on, a model with experts only; counted over
+        active slots and real prompt tokens, ``_note_expert_load``):
+        ``moe_load_max_over_mean`` (fullest expert's assignments over the
+        mean expert's, per layer; mean over layers and rounds),
+        ``moe_experts_touched_per_step`` (distinct experts a decode step's
+        active slots read, mean over layers and steps; ``..._per_prefill``:
+        the same for a whole-prompt prefill's real tokens) and
+        ``moe_dropped_share`` (assignments the dispatch dropped; 0 for a
+        dropless model)."""
         done = [r for r in self._finished if r.first_token_t is not None]
         out: Dict[str, float] = {
             "completed": float(len(self._finished)),
@@ -2360,6 +2430,17 @@ class ServingEngine:
             if vals:
                 out[f"{key}_p50_ms"] = float(np.percentile(vals, 50))
                 out[f"{key}_p90_ms"] = float(np.percentile(vals, 90))
+        m = self._moe
+        if m["asked"]:
+            out["moe_assignments"] = float(m["kept"])
+            out["moe_dropped_share"] = 1.0 - m["kept"] / m["asked"]
+        if m["rounds"]:
+            out["moe_load_max_over_mean"] = m["max_over_mean"] / m["rounds"]
+        if m["steps"]:
+            out["moe_experts_touched_per_step"] = m["touched"] / m["steps"]
+        if m["prefills"]:
+            out["moe_experts_touched_per_prefill"] = (m["prefill_touched"]
+                                                      / m["prefills"])
         out.update({k: float(v) for k, v in self._lat.items()})
         if self._lat["spec_proposed"]:
             out["spec_accept_rate"] = float(round(

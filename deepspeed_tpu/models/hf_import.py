@@ -163,6 +163,27 @@ def _mixtral_table(cfg):
     return L
 
 
+def _olmoe_table(cfg):
+    """OLMoE (allenai/OLMoE-1B-7B): the Llama backbone with an expert layer
+    under ``mlp`` (router ``mlp.gate``, experts ``mlp.experts.N.{gate,up,
+    down}_proj``) and an RMSNorm over the whole q and the whole k projection
+    (``self_attn.{q,k}_norm``)."""
+    L = [r for r in _llama_table(cfg) if "mlp" not in r[0]]
+    pre = r"^(?:model\.)?layers\.(\d+)\."
+    L += [
+        (pre + r"self_attn\.q_norm\.weight$", ("layers", "q_norm"), None),
+        (pre + r"self_attn\.k_norm\.weight$", ("layers", "k_norm"), None),
+        (pre + r"mlp\.gate\.weight$", ("layers", "wg"), _t),
+        (pre + r"mlp\.experts\.(\d+)\.gate_proj\.weight$",
+         ("layers", "moe_w_gate"), _t),
+        (pre + r"mlp\.experts\.(\d+)\.up_proj\.weight$",
+         ("layers", "moe_w_in"), _t),
+        (pre + r"mlp\.experts\.(\d+)\.down_proj\.weight$",
+         ("layers", "moe_w_out"), _t),
+    ]
+    return L
+
+
 def _opt_table(cfg):
     S = cfg.max_seq_len
 
@@ -529,7 +550,8 @@ _SKIP = re.compile(r"(rotary_emb\.inv_freq|\.attn\.(bias|masked_bias)$"
 
 
 _TABLES = {"llama": _llama_table, "gpt2": _gpt2_table,
-           "mixtral": _mixtral_table, "opt": _opt_table,
+           "mixtral": _mixtral_table, "olmoe": _olmoe_table,
+           "opt": _opt_table,
            "bloom": _bloom_table, "bert": _bert_table,
            "roberta": _roberta_table, "clip": _clip_table,
            "gptj": _gptj_table, "gpt_neox": _gptneox_table,
@@ -543,6 +565,8 @@ def _detect_family(keys) -> str:
     for k in keys:
         if "block_sparse_moe" in k:
             return "mixtral"
+        if ".mlp.experts." in k or ".self_attn.q_norm." in k:
+            return "olmoe"
         if k.startswith("roberta."):
             return "roberta"
         if "text_model." in k or "token_embedding" in k:
@@ -716,7 +740,9 @@ def load_hf_params(src, cfg, *, shardings=None, dtype=None,
             except ValueError:
                 continue
             if fam == "llama" and cfg.num_experts > 1:
-                fam = "mixtral"  # llama backbone + experts in the config
+                # llama backbone + experts in the config: the first keys of
+                # a shard (embed_tokens, layer 0's attention) look alike
+                fam = "olmoe" if cfg.qk_norm else "mixtral"
             table = _TABLES[fam](cfg)
             logger.info(f"hf import: detected {fam}-family checkpoint")
             for k, a in pending:
@@ -891,8 +917,8 @@ def export_hf_state_dict(params, cfg, *, family: Optional[str] = None
     Completes the interop contract (load_hf_params round-trips through it)."""
     import jax
     params = jax.tree.map(lambda a: np.asarray(jax.device_get(a)), params)
-    if (family in ("opt", "bloom", "mixtral", "bert", "roberta", "gptj",
-                   "gpt_neox", "gpt_neo", "distilbert")
+    if (family in ("opt", "bloom", "mixtral", "olmoe", "bert", "roberta",
+                   "gptj", "gpt_neox", "gpt_neo", "distilbert")
             or cfg.num_experts > 1
             or cfg.activation == "relu" or cfg.position_type == "alibi"
             or cfg.parallel_block or not cfg.causal or not cfg.qkv_bias
@@ -978,7 +1004,7 @@ def hf_config_to_transformer(hf_cfg, **overrides):
         # param tree does not carry — importing would silently drop them.
         raise ValueError("qwen2 attention biases are not supported yet; "
                          "convert without biases explicitly if acceptable")
-    if mt in ("llama", "mistral", "mixtral"):
+    if mt in ("llama", "mistral", "mixtral", "olmoe"):
         kw = dict(
             vocab_size=get("vocab_size"), hidden_size=get("hidden_size"),
             num_layers=get("num_hidden_layers"),
@@ -997,6 +1023,22 @@ def hf_config_to_transformer(hf_cfg, **overrides):
                 top_k=get("num_experts_per_tok", 2),
                 moe_aux_loss_weight=float(get("router_aux_loss_coef", 0.02)),
                 use_residual=False)
+        elif mt == "olmoe":
+            # what the model IS: every layer an expert layer, no shared
+            # expert, dropless, the top-k weights NOT renormalised unless
+            # the config says so, q/k RMSNorm, `intermediate_size` the width
+            # of ONE expert
+            if get("clip_qkv") is not None:
+                raise ValueError("olmoe clip_qkv is not supported (the "
+                                 "published 1B-7B config has null)")
+            if get("attention_bias", False):
+                raise ValueError("olmoe attention_bias=true is not supported")
+            kw.update(
+                num_experts=get("num_experts", 64),
+                top_k=get("num_experts_per_tok", 8),
+                norm_topk_prob=bool(get("norm_topk_prob", False)),
+                drop_tokens=False, qk_norm=True, use_residual=False,
+                moe_aux_loss_weight=float(get("router_aux_loss_coef", 0.01)))
     elif mt == "opt":
         if get("word_embed_proj_dim", get("hidden_size")) != get("hidden_size"):
             raise ValueError(

@@ -32,6 +32,7 @@ from jax.sharding import PartitionSpec as P
 # through the O(S^2) XLA attention
 from deepspeed_tpu.ops.decode_attention import paged_decode_attention
 from deepspeed_tpu.ops.flash_attention import flash_attention
+from deepspeed_tpu.moe import sharded_moe as _moe
 
 Params = Dict[str, Any]
 
@@ -73,6 +74,10 @@ class TransformerConfig:
     attn_out_bias: Optional[bool] = None
     final_norm: bool = True             # BERT has no final LN (post-LN covers)
     rope_theta: float = 10000.0
+    # ARCHITECTURE (OLMoE): RMSNorm over the WHOLE q projection and the whole
+    # k projection (`q_norm` [nh*hd], `k_norm` [nkv*hd]), before the split
+    # into heads and before RoPE
+    qk_norm: bool = False
     tie_embeddings: bool = True
     dropout_rate: float = 0.0
     dtype: Any = jnp.bfloat16                   # activation/compute dtype
@@ -118,6 +123,10 @@ class TransformerConfig:
     min_capacity: int = 4
     noisy_gate_policy: str = None               # None | Jitter | RSample
     drop_tokens: bool = True
+    # ARCHITECTURE: divide the k kept router weights by their sum (Mixtral)
+    # or take the softmax's values as they are, summing to < 1 (OLMoE,
+    # `norm_topk_prob: false`)
+    norm_topk_prob: bool = True
     use_residual: bool = False                  # PR-MoE
     moe_aux_loss_weight: float = 0.01
     remat: bool = False
@@ -275,6 +284,9 @@ def init_params(key, cfg: TransformerConfig) -> Params:
         "w_in": stacked(lkeys[4], (H, F)),
         "w_out": stacked(lkeys[5], (F, H), scale=out_scale),
     }
+    if cfg.qk_norm:
+        layers["q_norm"] = jnp.ones((L, nh * hd), dt)
+        layers["k_norm"] = jnp.ones((L, nkv * hd), dt)
     if cfg.num_experts > 1:
         E = cfg.num_experts
         layers["wg"] = stacked(lkeys[7], (H, E))
@@ -338,6 +350,11 @@ def logical_axes(cfg: TransformerConfig) -> Params:
         "w_in": ("layers", "embed", "mlp"),
         "w_out": ("layers", "mlp", "embed"),
     }
+    if cfg.qk_norm:
+        # split like the projection's columns; the norm's mean square is a
+        # reduction over the whole (global) projection under GSPMD
+        layers["q_norm"] = ("layers", "qkv")
+        layers["k_norm"] = ("layers", "qkv")
     if cfg.num_experts > 1:
         layers["wg"] = ("layers", "embed", None)
         layers["moe_w_in"] = ("layers", "expert", "embed", "mlp")
@@ -450,6 +467,14 @@ def _norm(x, scale, bias, cfg: TransformerConfig):
     if bias is not None:
         y = y + bias.astype(jnp.float32)
     return y.astype(x.dtype)
+
+
+def _rms_whole(x, scale, eps: float):
+    """RMSNorm over the last dim whatever `cfg.norm_type` is (q/k norm)."""
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+    return (x32 * lax.rsqrt(var + eps) * scale.astype(jnp.float32)
+            ).astype(x.dtype)
 
 
 def alibi_slopes(n_heads: int) -> jnp.ndarray:
@@ -1183,6 +1208,12 @@ def transformer_layer(x, layer_params, cfg: TransformerConfig, mask=None,
             k = k + _lora_delta(h, tabs["k"], aidx)
         if "v" in tabs:
             v = v + _lora_delta(h, tabs["v"], aidx)
+    if "q_norm" in p:
+        # over the whole projection, all heads together: under `tensor` the
+        # mean square is reduced across the axis by GSPMD (these are global
+        # arrays), never per shard
+        q = _rms_whole(q, p["q_norm"], cfg.norm_eps)
+        k = _rms_whole(k, p["k_norm"], cfg.norm_eps)
     q = q.reshape(B, S, nh, hd)
     k = k.reshape(B, S, nkv, hd)
     v = v.reshape(B, S, nkv, hd)
@@ -1376,6 +1407,31 @@ def _remat_policy(cfg: TransformerConfig):
     return policies.get(cfg.remat_policy)
 
 
+def _hold_expert_stacks(layers: Params, cfg: TransformerConfig,
+                        deterministic: bool = True):
+    """(layers without the expert stacks, the expert stacks) for a layer
+    scan of a dropless MoE at inference that may see MANY tokens (a prompt
+    in ``forward``, a chunk in ``decode_span_paged``): the scan slices
+    everything else per layer, and ``_held_layer`` hands the expert stacks
+    to the layer WHOLE with the layer's index (``_moe.LayerOf``), because
+    the grouped-matmul kernel would otherwise be fed a fresh copy of the
+    layer's experts (2.4 GB a layer at OLMoE's widths). A one-token-a-slot
+    step never reaches the kernel below 256 slots and keeps its slices; so
+    does training: a whole stack closed over by a scan body gets a
+    whole-stack cotangent per iteration."""
+    if not (deterministic and cfg.num_experts > 1 and not cfg.drop_tokens
+            and not cfg.quantized_weights and not cfg.offload_params):
+        return layers, {}
+    held = {k: v for k, v in layers.items() if k.startswith("moe_w_")}
+    return {k: v for k, v in layers.items() if k not in held}, held
+
+
+def _held_layer(layer_p: Params, held: Params, i) -> Params:
+    if not held:
+        return layer_p
+    return {**layer_p, **{k: _moe.LayerOf(v, i) for k, v in held.items()}}
+
+
 def _fetch_layer(layer_p, cfg: TransformerConfig):
     """ZeRO-Infinity param residency: move ONE layer's weights host -> HBM.
     Inside the remat region backward re-fetches instead of keeping them live.
@@ -1435,8 +1491,16 @@ def forward(params: Params, input_ids, cfg: TransformerConfig, *,
     wins = (jnp.asarray(cfg.attn_windows, jnp.int32)
             if cfg.attn_windows else None)
 
+    layers, held = _hold_expert_stacks(layers, cfg, deterministic)
+    if held:        # the layer's index rides the scan with its slices
+        layers = {**layers, "_layer": jnp.arange(
+            jax.tree.leaves(layers)[0].shape[0])}
+
     def body(carry, xs):
         layer_p, w = xs if wins is not None else (xs, None)
+        if held:
+            layer_p = dict(layer_p)
+            layer_p = _held_layer(layer_p, held, layer_p.pop("_layer"))
         x_c, rng, aux_acc = carry
         if cfg.offload_params:
             layer_p = _fetch_layer(layer_p, cfg)
@@ -1444,14 +1508,17 @@ def forward(params: Params, input_ids, cfg: TransformerConfig, *,
             rng, sub = jax.random.split(rng)
         else:
             sub = None
-        out = transformer_layer(x_c, layer_p, cfg, mask=attention_mask,
-                                positions=positions, dropout_rng=sub,
-                                deterministic=deterministic,
-                                return_kv=return_kv, attn_window=w)
+        with _moe.layer_load_tap() as tap:
+            out = transformer_layer(x_c, layer_p, cfg, mask=attention_mask,
+                                    positions=positions, dropout_rng=sub,
+                                    deterministic=deterministic,
+                                    return_kv=return_kv, attn_window=w)
         if return_kv:
             y, aux, kv = out
         else:
             (y, aux), kv = out, None
+        if tap is not None:     # a serving prefill reads the expert load
+            kv = (kv, tap.stacked())
         return (y, rng, aux_acc + aux), kv
 
     if cfg.remat or cfg.remat_policy not in ("none", None):
@@ -1469,6 +1536,7 @@ def forward(params: Params, input_ids, cfg: TransformerConfig, *,
                                   "scan_layers=True")
     aux_total = jnp.float32(0.0)
     kv_stack = None
+    listening = _moe.expert_load_wanted()
     if cfg.scan_layers and use_pld:
         L = jax.tree.leaves(layers)[0].shape[0]
         theta = jnp.asarray(pld_theta, jnp.float32)
@@ -1543,8 +1611,11 @@ def forward(params: Params, input_ids, cfg: TransformerConfig, *,
                         else layer_p)
             kvs.append(kv)
         x, aux_total = carry[0], carry[2]
-        if return_kv:
+        if return_kv or listening:
             kv_stack = jax.tree.map(lambda *xs: jnp.stack(xs), *kvs)
+    if listening:
+        kv_stack, loads = kv_stack
+        _moe.record_expert_load(loads)
 
     if cfg.final_norm:
         x = _norm(x, params["final_norm_scale"],
@@ -1741,12 +1812,15 @@ def prefill(params: Params, input_ids, cfg: TransformerConfig, cache: Params,
     causality keeps logits at length-1 exact, and the cursor is set so decode
     overwrites the pad rows before they can ever be attended.
     """
-    logits, kv = forward(params, input_ids, cfg, attention_mask=attention_mask,
-                         return_kv=True)
     S = input_ids.shape[1]
     # traced length is fine: the index ops below are dynamic, so one program
     # serves every prompt length in the same padded-shape bucket
     true_len = jnp.asarray(S if length is None else length, jnp.int32)
+    # an expert-load tap counts the real prompt tokens, not the bucket's pad
+    with _moe.counted_tokens(jnp.broadcast_to(
+            jnp.arange(S)[None] < true_len, input_ids.shape)):
+        logits, kv = forward(params, input_ids, cfg,
+                             attention_mask=attention_mask, return_kv=True)
     k, v = kv  # [L, B, S, nkv, hd] -> cache layout [L, B, nkv, S, hd]
     k, v = jnp.swapaxes(k, 2, 3), jnp.swapaxes(v, 2, 3)
     if cfg.kv_cache_bits == 8:
@@ -2147,14 +2221,19 @@ def decode_step_paged(params: Params, tokens, cfg: TransformerConfig,
             apool, aidx = lora
             lora_i = ({k: (v["a"], v["b"])
                        for k, v in at_layer(apool, i).items()}, aidx)
-        y, _, (k_row, v_row) = transformer_layer(
-            x_c, layer_p, cfg, positions=positions, deterministic=True,
-            cache=c, return_kv=False, paged=(block_tables, backend),
-            attn_window=None if wins is None else wins[i], lora=lora_i)
-        return y, (k_row, v_row)
+        with _moe.layer_load_tap() as tap:
+            y, _, (k_row, v_row) = transformer_layer(
+                x_c, layer_p, cfg, positions=positions, deterministic=True,
+                cache=c, return_kv=False, paged=(block_tables, backend),
+                attn_window=None if wins is None else wins[i], lora=lora_i)
+        return y, (k_row, v_row, tap and tap.stacked())
 
-    with jax.named_scope("layers"):
-        x, (k_rows, v_rows) = lax.scan(body, x, jnp.arange(cfg.num_layers))
+    # an expert-load tap counts the active slots: the others compute in
+    # lockstep
+    with jax.named_scope("layers"), _moe.counted_tokens(active):
+        x, (k_rows, v_rows, loads) = lax.scan(body, x,
+                                              jnp.arange(cfg.num_layers))
+    _moe.record_expert_load(loads)
     # one [L, S, nkv, hd] scatter writes every layer's fresh row at
     # (block_tables[s, len // bs], len % bs), a whole minor tile of the
     # token-major pool; inactive slots hit the trash block (duplicate trash
@@ -2235,8 +2314,10 @@ def decode_span_paged(params: Params, tokens, cfg: TransformerConfig,
     wins = (jnp.asarray(cfg.attn_windows, jnp.int32)
             if cfg.attn_windows else None)
 
+    sliced, held = _hold_expert_stacks(params["layers"], cfg)
+
     def body(x_c, i):
-        layer_p = at_layer(params["layers"], i)
+        layer_p = _held_layer(at_layer(sliced, i), held, i)
         pk = lax.dynamic_index_in_dim(pools["k"], i, 0, keepdims=False)
         pv = lax.dynamic_index_in_dim(pools["v"], i, 0, keepdims=False)
         sc = ((lax.dynamic_index_in_dim(pools["k_scale"], i, 0,
@@ -2252,14 +2333,21 @@ def decode_span_paged(params: Params, tokens, cfg: TransformerConfig,
             apool, aidx = lora
             lora_i = ({k: (v["a"], v["b"])
                        for k, v in at_layer(apool, i).items()}, aidx)
-        y, _, (k_row, v_row) = transformer_layer(
-            x_c, layer_p, cfg, positions=positions, deterministic=True,
-            cache=c, return_kv=False, paged=(block_tables, backend),
-            attn_window=None if wins is None else wins[i], lora=lora_i)
-        return y, (k_row, v_row)                 # rows: [S, nkv, T, hd]
+        with _moe.layer_load_tap() as tap:
+            y, _, (k_row, v_row) = transformer_layer(
+                x_c, layer_p, cfg, positions=positions, deterministic=True,
+                cache=c, return_kv=False, paged=(block_tables, backend),
+                attn_window=None if wins is None else wins[i], lora=lora_i)
+        # rows: [S, nkv, T, hd]
+        return y, (k_row, v_row, tap and tap.stacked())
 
-    with jax.named_scope("layers"):
-        x, (k_rows, v_rows) = lax.scan(body, x, jnp.arange(cfg.num_layers))
+    # an expert-load tap counts the rows that are written: no pad token of a
+    # bucketed chunk, no inactive slot
+    counted = active[:, None] & (jnp.arange(T)[None, :] < n_rows[:, None])
+    with jax.named_scope("layers"), _moe.counted_tokens(counted):
+        x, (k_rows, v_rows, loads) = lax.scan(body, x,
+                                              jnp.arange(cfg.num_layers))
+    _moe.record_expert_load(loads)
     # one [S*T]-row scatter writes every (slot, position) pair's fresh row
     # across all layers; pad/inactive rows route to the trash block 0
     # (duplicate trash writes are unordered and never read). Positions at

@@ -1,4 +1,4 @@
-"""Sharded mixture-of-experts: gating + capacity dispatch + expert compute.
+"""Sharded mixture-of-experts: routing, then one of two dispatches.
 
 Reference: ``deepspeed/moe/sharded_moe.py`` — ``top1gating:176`` /
 ``top2gating:274`` (capacity, load-balance aux loss, random token priority),
@@ -7,22 +7,48 @@ einsum dispatch/combine, ``_AllToAll:87`` applied at ``:506,520``;
 
 TPU-native: the reference wraps torch.distributed all_to_all in an autograd
 Function around per-rank expert stacks. Here experts are a stacked leading
-`experts` dim sharded over the `expert` mesh axis, dispatch/combine are
-einsums with one-hot capacity masks (same math as the reference's fairscale
-lineage), and GSPMD inserts the all-to-alls when the token-sharded input
-meets the expert-sharded stack — over ICI, with static capacity shapes
-(drop/pad exactly like the reference's capacity semantics).
+`experts` dim sharded over the `expert` mesh axis.
+
+Routing is one softmax in float32 and one ``lax.top_k`` whatever k is; the
+kept weights are divided by their sum where the model says so
+(``cfg.norm_topk_prob``: Mixtral does, OLMoE does not).
+
+Dispatches, chosen by what the model IS (``cfg.drop_tokens``) and by the
+call's shapes, not by an option of their own:
+
+- **capacity** (``drop_tokens=True``, training's default): dispatch/combine
+  are einsums with one-hot ``[T, E, C]`` masks (same math as the reference's
+  fairscale lineage), and GSPMD inserts the all-to-alls when the
+  token-sharded input meets the expert-sharded stack — over ICI, with static
+  capacity shapes (drop/pad exactly like the reference's capacity
+  semantics).
+- **dropless** (``drop_tokens=False``: Mixtral, OLMoE as published), a call
+  of many tokens at inference on one device (``_sorts``): the T·k (token,
+  expert) pairs are sorted by expert, each projection is ONE grouped
+  matmul over the T·k rows (``_grouped_matmul``:
+  static shapes — T·k rows and E group sizes), and the rows are weighted and
+  summed back per token. Work and memory are proportional to the T·k
+  assignments; nothing has both a token and an expert-times-capacity extent
+  (capacity = T computes E·T rows for T·k assignments: 8 x too many at 64
+  experts top-8, and a ``[T, E, T]`` mask besides).
+- **dropless**, a call of few tokens (``_one_hot_is_cheaper``: while T rows
+  per expert hide under the expert's weight bytes), and EVERY dropless call
+  under a mesh or in training: the capacity dispatch with capacity = T,
+  which drops nothing, needs no sort and no kernel, and carries the
+  sharding constraints of the capacity path.
+
+``expert_load_tap`` is how a serving step reads what routing did.
 """
 
-import dataclasses
+import contextlib
 import math
-from typing import Optional, Tuple
+import threading
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.sharding import PartitionSpec as P
-
-from deepspeed_tpu.utils.logging import logger
 
 
 def _constrain(x, spec: P):
@@ -40,69 +66,348 @@ def _capacity(num_tokens: int, num_experts: int, capacity_factor: float,
     return max(cap, min_capacity)
 
 
-def top_k_gating(logits, k: int, capacity: int, *, rng=None,
-                 noise_policy: Optional[str] = None, train: bool = True):
-    """Compute dispatch/combine tensors with capacity limits.
+# --------------------------------------------------------------------------
+# the routing tap: expert load out of a traced program
+# --------------------------------------------------------------------------
 
-    logits: [T, E]. Returns (combine [T,E,C] f32, dispatch [T,E,C] bool,
-    aux_loss scalar, metrics dict). Same semantics as the reference's
-    top1gating/top2gating: per-expert position by cumsum order (token
-    priority = sequence order), tokens over capacity dropped; aux loss =
-    E * mean(gates_e) * mean(assignment_e) summed over experts (switch loss).
-    """
-    T, E = logits.shape
+class _LoadTap:
+    """What one ``expert_load_tap`` block collected, at TRACE time: one
+    int32 ``[E + 1]`` row per MoE layer called inside it — assignments KEPT
+    per expert, then the assignments ASKED for (counted tokens x k), so
+    ``1 - kept / asked`` is the dropped share."""
+
+    def __init__(self):
+        self.rows: List[jnp.ndarray] = []
+
+    def stacked(self) -> Optional[jnp.ndarray]:
+        """[layers, E + 1] in call order; None if no MoE layer ran."""
+        return jnp.stack(self.rows) if self.rows else None
+
+
+class _TraceState(threading.local):
+    """Trace-time state, one per THREAD: the serving engine's watchdog
+    traces a step on a thread of its own and may abandon it, and two engines
+    of one process (router replicas) may trace at once."""
+
+    def __init__(self):
+        self.taps: List[_LoadTap] = []        # innermost last
+        self.counted: List[jnp.ndarray] = []  # innermost last: which tokens count
+
+
+_STATE = _TraceState()
+
+
+@contextlib.contextmanager
+def expert_load_tap():
+    """Collect the expert load of every ``moe_ffn`` traced inside the block
+    (a serving step reads what routing did without a change to any
+    signature between it and ``moe_ffn``). No tap open: nothing is computed.
+
+    The rows are tracers of the trace that is current where ``moe_ffn``
+    runs, so the body of a layer scan opens ``layer_load_tap`` around its
+    layer, returns ``tap.stacked()`` among the body's outputs, and the
+    caller hands the scan's stacked rows on with ``record_expert_load``."""
+    tap = _LoadTap()
+    _STATE.taps.append(tap)
+    try:
+        yield tap
+    finally:
+        _STATE.taps.remove(tap)
+
+
+@contextlib.contextmanager
+def layer_load_tap():
+    """``expert_load_tap`` for the body of a layer scan: yields None, and
+    costs nothing, when nobody outside is listening."""
+    if not _STATE.taps:
+        yield None
+        return
+    with expert_load_tap() as tap:
+        yield tap
+
+
+def expert_load_wanted() -> bool:
+    return bool(_STATE.taps)
+
+
+def record_expert_load(rows) -> None:
+    """Hand rows ([..., E + 1]: a layer scan's stacked output, or None) to
+    the innermost open tap."""
+    if _STATE.taps and rows is not None:
+        rows = rows.reshape(-1, rows.shape[-1])
+        _STATE.taps[-1].rows.extend(rows[i] for i in range(rows.shape[0]))
+
+
+@contextlib.contextmanager
+def counted_tokens(mask):
+    """The tokens whose routing a tap counts: ``mask`` is bool with one
+    entry per token of the ``x`` that ``moe_ffn`` will see (any shape of
+    that size). A serving step's inactive slots and a prompt bucket's pad
+    tokens compute in lockstep and must not count."""
+    _STATE.counted.append(mask)
+    try:
+        yield
+    finally:
+        _STATE.counted.pop()
+
+
+def _tap_load(kept, k: int) -> None:
+    """One row for the innermost tap from ``kept`` [T, E]: the assignments
+    of each token that got a place at each expert."""
+    T = kept.shape[0]
+    kept = kept.astype(jnp.int32)
+    asked = jnp.int32(T * k)
+    if _STATE.counted:
+        mask = _STATE.counted[-1].reshape(T).astype(jnp.int32)
+        kept, asked = kept * mask[:, None], jnp.sum(mask) * k
+    _STATE.taps[-1].rows.append(
+        jnp.concatenate([jnp.sum(kept, axis=0), asked[None]]))
+
+
+# --------------------------------------------------------------------------
+# routing
+# --------------------------------------------------------------------------
+
+def route(logits, k: int, *, renormalize: bool = True, rng=None,
+          noise_policy: Optional[str] = None, train: bool = True):
+    """Softmax over ALL experts in float32, one ``lax.top_k``.
+
+    logits: [T, E] -> (weights [T, k] f32, experts [T, k] int32, gates
+    [T, E] f32). ``renormalize`` divides the k kept weights by their sum
+    (Mixtral); without it they are the softmax's own values and sum to less
+    than 1 (OLMoE, ``norm_topk_prob: false``). Ties go to the lower expert
+    index, as the argmax chain this replaces broke them."""
     if noise_policy == "Jitter" and train and rng is not None:
         logits = logits * jax.random.uniform(rng, logits.shape, logits.dtype,
                                              1.0 - 1e-2, 1.0 + 1e-2)
     elif noise_policy == "RSample" and train and rng is not None:
         logits = logits + jax.random.gumbel(rng, logits.shape, logits.dtype)
-
     gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)      # [T, E]
+    weights, experts = lax.top_k(gates, k)
+    if renormalize and k > 1:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, experts.astype(jnp.int32), gates
 
+
+def _switch_aux(gates, first_choice):
+    """Switch load-balance loss from the FIRST choice only (reference: top2
+    uses mask1): E * sum_e mean(gates_e) * mean(assignment_e)."""
+    E = gates.shape[-1]
+    ce = jnp.mean(jax.nn.one_hot(first_choice, E, dtype=jnp.float32), axis=0)
+    return jnp.sum(jnp.mean(gates, axis=0) * ce) * E, ce
+
+
+def top_k_gating(logits, k: int, capacity: int, *, rng=None,
+                 noise_policy: Optional[str] = None, train: bool = True,
+                 renormalize: bool = True):
+    """Compute dispatch/combine tensors with capacity limits, for any k.
+
+    logits: [T, E]. Returns (combine [T,E,C] f32, dispatch [T,E,C] bool,
+    aux_loss scalar, metrics dict). Same semantics as the reference's
+    top1gating/top2gating: per-expert position by cumsum order (token
+    priority = sequence order, earlier choices before later ones), tokens
+    over capacity dropped; aux loss = E * mean(gates_e) * mean(assignment_e)
+    summed over experts (switch loss). With ``renormalize`` the weights that
+    SURVIVE the capacity are divided by their sum (reference: top2 denom).
+    """
+    T, E = logits.shape
+    # the division happens after the drop, over the survivors
+    weights, experts, gates = route(logits, k, renormalize=False, rng=rng,
+                                    noise_policy=noise_policy, train=train)
+    aux, ce = _switch_aux(gates, experts[:, 0])
+    metrics = {"expert_load": ce}
+
+    # position of each (choice, token) within its expert: ONE cumsum over
+    # the choice-major list, so a later choice is offset by the FULL
+    # pre-drop count of the earlier ones (reference top2gating offsets
+    # locations2 by sum(mask1)): choice-2 tokens must not reuse slots freed
+    # by dropped choice-1 tokens, or drop statistics diverge.
+    onehot = jax.nn.one_hot(experts.T, E, dtype=jnp.float32)     # [k, T, E]
+    flat = onehot.reshape(k * T, E)
+    pos = jnp.sum((jnp.cumsum(flat, axis=0) - 1.0) * flat, axis=-1)
+    pos = pos.astype(jnp.int32).reshape(k, T)
+    keep = pos < capacity
+    gate_val = jnp.where(keep, weights.T, 0.0)                   # [k, T]
+    pos_oh = jax.nn.one_hot(jnp.clip(pos, 0, capacity - 1), capacity,
+                            dtype=jnp.float32)                   # [k, T, C]
+    # one elementwise pass per choice builds the [T, E, C] mask (a token's
+    # choices are distinct experts, so each entry gets at most one term)
     combine = jnp.zeros((T, E, capacity), jnp.float32)
-    aux = jnp.float32(0.0)
-    masked_gates = gates
-    gate_sum = jnp.zeros((T,), jnp.float32)
-
-    # iterate the k choices (k is 1 or 2 — static unroll like the reference)
-    claimed = jnp.zeros((E,), jnp.int32)    # slots already used per expert
-    metrics = {}
-    for choice in range(k):
-        idx = jnp.argmax(masked_gates, axis=-1)                      # [T]
-        onehot = jax.nn.one_hot(idx, E, dtype=jnp.float32)           # [T, E]
-        # aux loss from the FIRST choice only (reference: top2 uses mask1)
-        if choice == 0:
-            me = jnp.mean(gates, axis=0)
-            ce = jnp.mean(onehot, axis=0)
-            aux = jnp.sum(me * ce) * E
-            metrics["expert_load"] = ce
-        # position of each token within its expert (sequence priority)
-        pos_in_expert = (jnp.cumsum(onehot, axis=0) - 1.0) * onehot  # [T, E]
-        pos = jnp.sum(pos_in_expert, axis=-1).astype(jnp.int32) + \
-            jnp.sum(onehot * claimed[None, :], axis=-1).astype(jnp.int32)
-        keep = pos < capacity
-        gate_val = jnp.sum(gates * onehot, axis=-1)                  # [T]
-        gate_val = jnp.where(keep, gate_val, 0.0)
-        pos_oh = jax.nn.one_hot(jnp.clip(pos, 0, capacity - 1), capacity,
-                                dtype=jnp.float32)                   # [T, C]
-        combine = combine + (gate_val[:, None] * onehot * keep[:, None])[..., None] \
-            * pos_oh[:, None, :]
-        gate_sum = gate_sum + gate_val
-        # offset next choice by the FULL pre-drop count (reference top2gating
-        # offsets locations2 by sum(mask1)): choice-2 tokens must not reuse
-        # slots freed by dropped choice-1 tokens, or drop statistics diverge.
-        claimed = claimed + jnp.sum(onehot, axis=0).astype(jnp.int32)
-        # mask out the chosen expert for the next choice
-        masked_gates = masked_gates * (1.0 - onehot)
-
-    # normalize combine weights over the k choices (reference: top2 denom)
-    if k > 1:
+    for c in range(k):
+        combine = combine + (gate_val[c][:, None] * onehot[c]
+                             * keep[c][:, None])[..., None] * pos_oh[c][:, None, :]
+    if renormalize and k > 1:
+        gate_sum = jnp.sum(gate_val, axis=0)
         safe = jnp.where(gate_sum > 0, gate_sum, 1.0)
         combine = combine / safe[:, None, None]
 
     dispatch = combine > 0
     metrics["dropped_fraction"] = 1.0 - jnp.sum(dispatch) / (T * k)
+    # [T, E] assignments that got a slot, whatever their weight (a gate that
+    # underflowed to 0 is kept, not dropped)
+    metrics["kept"] = jnp.sum(onehot * keep[..., None], axis=0)
     return combine, dispatch, aux, metrics
+
+
+# --------------------------------------------------------------------------
+# expert compute
+# --------------------------------------------------------------------------
+
+def _glu_or_gelu(up, gate):
+    return jax.nn.silu(gate) * up if gate is not None else jax.nn.gelu(up)
+
+
+class LayerOf:
+    """Layer ``index`` (traced) of a stacked ``[L, E, K, N]`` expert array
+    that has NOT been sliced. A Pallas call's operand is a whole buffer, so
+    a layer scan that hands it ``stack[i]`` first COPIES the layer's experts
+    (0.8 GB a stack and layer at OLMoE's widths, three stacks; XLA's own
+    ``ragged_dot`` is such a call too, and with it and the copies OLMoE's
+    cell serves 12.7 % fewer tokens a second: PERF.md section 6, PR 26).
+    The grouped-matmul kernel reads the layer's experts out of the whole
+    stack instead; every other consumer calls ``whole()`` and gets the slice
+    XLA fuses into it."""
+    __slots__ = ("stack", "index")
+
+    def __init__(self, stack, index):
+        self.stack, self.index = stack, index
+
+    def whole(self):
+        return lax.dynamic_index_in_dim(self.stack, self.index, 0,
+                                        keepdims=False)
+
+
+def _whole(w, dtype):
+    return (w.whole() if isinstance(w, LayerOf) else w).astype(dtype)
+
+
+def _grouped_matmul(rows, w, group_sizes, kernel: bool):
+    """rows [M, K] sorted by expert, w [E, K, N] (or a ``LayerOf`` such),
+    group_sizes [E] (sum <= M) -> [M, N]: row i times the matrix of the
+    expert whose group it is in.
+
+    ``kernel`` (a TPU in bf16; ``_sorts`` has already kept a mesh and
+    training away): the Pallas kernel of ``ops/grouped_matmul.py``
+    (``%moe_gmm.N`` in a trace), which reads the layer's experts out of the
+    whole stack. Elsewhere — the CPU, float32, widths off the kernel's
+    tiles — XLA's own ``ragged_dot`` (``%ragged-dot-none.N`` on a TPU, a
+    Mosaic call too: fed a layer's slice it costs the copy above).
+    Measured, OLMoE's cell on the chip, same call and seeds: 473.9 / 476.1
+    tokens/s with the kernel, 415.7 / 414.6 with ``ragged_dot`` alone
+    (PERF.md section 6, PR 26)."""
+    if not kernel:
+        return lax.ragged_dot(rows, _whole(w, rows.dtype), group_sizes)
+    from deepspeed_tpu.ops.grouped_matmul import grouped_matmul
+    if isinstance(w, LayerOf):
+        return grouped_matmul(rows, w.stack, w.index, group_sizes)
+    return grouped_matmul(rows, w[None], 0, group_sizes)
+
+
+def _use_gmm_kernel(moe_params, dtype) -> bool:
+    from deepspeed_tpu.ops.grouped_matmul import supported
+    w = moe_params["w_in"]
+    w = w.stack if isinstance(w, LayerOf) else w
+    return (dtype == jnp.bfloat16 and w.dtype == dtype
+            and supported(*w.shape[-2:]) and supported(*w.shape[-2:][::-1])
+            and jax.default_backend() == "tpu")
+
+
+def _one_hot_ffn(moe_params, tokens, logits, cfg, C: int, rng, train,
+                  expert_axis):
+    """Dispatch by one-hot [T, E, C] masks; tokens over capacity dropped
+    (none at C = T)."""
+    dt = tokens.dtype
+    with jax.named_scope("route"):
+        combine, dispatch, aux, metrics = top_k_gating(
+            logits, cfg.top_k, C, rng=rng, noise_policy=cfg.noisy_gate_policy,
+            train=train, renormalize=cfg.norm_topk_prob)
+        if _STATE.taps:
+            _tap_load(metrics["kept"], cfg.top_k)
+    # dispatch: [T,E,C] x [T,H] -> [E,C,H]; GSPMD all-to-alls tokens to the
+    # expert-sharded dim (reference: _AllToAll.apply at sharded_moe.py:506)
+    with jax.named_scope("dispatch"):
+        expert_in = jnp.einsum("tec,th->ech", dispatch.astype(dt), tokens)
+        expert_in = _constrain(expert_in, P(expert_axis, None, None))
+    with jax.named_scope("experts"):
+        up = jnp.einsum("ech,ehf->ecf", expert_in,
+                        _whole(moe_params["w_in"], dt))
+        gate = (jnp.einsum("ech,ehf->ecf", expert_in,
+                           _whole(moe_params["w_gate"], dt))
+                if "w_gate" in moe_params else None)
+        out = jnp.einsum("ecf,efh->ech", _glu_or_gelu(up, gate),
+                         _whole(moe_params["w_out"], dt))
+        out = _constrain(out, P(expert_axis, None, None))
+    with jax.named_scope("combine"):
+        y = jnp.einsum("tec,ech->th", combine.astype(dt), out)
+    return y, aux
+
+
+def _sorted_ffn(moe_params, tokens, logits, cfg, rng, train):
+    """Dispatch by sorting the T*k (token, expert) pairs by expert: every
+    token reaches all k of its experts, and the work is T*k rows."""
+    T, H = tokens.shape
+    E, k, dt = logits.shape[-1], cfg.top_k, tokens.dtype
+    with jax.named_scope("route"):
+        weights, experts, gates = route(
+            logits, k, renormalize=cfg.norm_topk_prob, rng=rng,
+            noise_policy=cfg.noisy_gate_policy, train=train)
+        aux, _ = _switch_aux(gates, experts[:, 0])
+    with jax.named_scope("dispatch"):
+        flat = experts.reshape(T * k)
+        onehot = jax.nn.one_hot(flat, E, dtype=jnp.int32)         # [T*k, E]
+        group_sizes = jnp.sum(onehot, axis=0)                     # [E]
+        if _STATE.taps:
+            _tap_load(jnp.sum(onehot.reshape(T, k, E), axis=1), k)
+        order = jnp.argsort(flat)                # stable: token order kept
+        rows_in = jnp.take(tokens, order // k, axis=0)            # [T*k, H]
+    with jax.named_scope("experts"):
+        kernel = _use_gmm_kernel(moe_params, dt)
+        up = _grouped_matmul(rows_in, moe_params["w_in"], group_sizes, kernel)
+        gate = (_grouped_matmul(rows_in, moe_params["w_gate"], group_sizes,
+                                kernel)
+                if "w_gate" in moe_params else None)
+        rows_out = _grouped_matmul(_glu_or_gelu(up, gate), moe_params["w_out"],
+                                   group_sizes, kernel)
+    with jax.named_scope("combine"):
+        # back to (token, choice) order, then the weighted sum over a
+        # token's k rows in float32
+        per_choice = jnp.take(rows_out, jnp.argsort(order), axis=0)
+        y = jnp.sum(per_choice.reshape(T, k, H).astype(jnp.float32)
+                    * weights[..., None], axis=1).astype(dt)
+    return y, aux
+
+
+def _one_hot_is_cheaper(T: int, E: int, k: int) -> bool:
+    """Which dropless dispatch a call of T tokens takes, from its shapes.
+
+    Both stream each expert's matrices; they differ in what they multiply.
+    The one-hot masks with capacity = T give EVERY expert all T rows: E
+    visits of T rows, nothing to sort, no kernel, and a ``[T, E, T]`` mask.
+    The sorted dispatch multiplies only the T*k assigned rows, but a row
+    tile that straddles experts is visited once per expert: ``row tiles + E
+    - 1`` visits (``ops/grouped_matmul.visit_cost``). While T rows per
+    expert still hide under the expert's weight bytes the one-hot dispatch
+    is the cheaper or equal one (Mixtral's cell, 8 experts: 8 visits against
+    8-11 at every T it runs, and its set-up does not pay for three Mosaic
+    kernels per program: PERF.md section 6, PR 26); beyond, E*T rows cost E/k
+    times the needed multiplies and the mask grows with T squared (OLMoE,
+    64 experts: 205 visit-units against 93 at a 768-token prompt)."""
+    from deepspeed_tpu.ops.grouped_matmul import (WEIGHT_BOUND_ROWS, row_tile,
+                                                  visit_cost)
+    one_hot = E * max(1.0, T / WEIGHT_BOUND_ROWS)
+    return one_hot <= visit_cost(T * k, E, row_tile(T * k, E))
+
+
+def _sorts(T: int, E: int, k: int, train: bool) -> bool:
+    """Whether a dropless call of T tokens sorts. Only at inference on ONE
+    device, and only past ``_one_hot_is_cheaper``: the sorted dispatch sets
+    no sharding constraint and has never been compiled with the experts
+    sharded, and the one-hot einsums are what GSPMD places the all-to-alls
+    around (``_constrain(..., P(expert_axis))``), so under a mesh and in
+    training a dropless call keeps them, as before PR 26."""
+    from deepspeed_tpu.parallel.context import kernel_mesh
+    return (not train and kernel_mesh()[0] is None
+            and not _one_hot_is_cheaper(T, E, k))
 
 
 def moe_ffn(moe_params, x, cfg, *, rng=None, train: bool = True,
@@ -117,31 +422,17 @@ def moe_ffn(moe_params, x, cfg, *, rng=None, train: bool = True,
     E = moe_params["wg"].shape[-1]
     T = B * S
     tokens = x.reshape(T, H)
-    cf = cfg.capacity_factor if train else cfg.eval_capacity_factor
-    C = _capacity(T, E, cf, cfg.min_capacity)
-    if not cfg.drop_tokens:
-        C = T  # no dropping: capacity covers everything (expensive; parity)
-
-    logits = tokens.astype(jnp.float32) @ moe_params["wg"].astype(jnp.float32)
-    combine, dispatch, aux, _ = top_k_gating(
-        logits, cfg.top_k, C, rng=rng, noise_policy=cfg.noisy_gate_policy,
-        train=train)
-
-    # dispatch: [T,E,C] x [T,H] -> [E,C,H]; GSPMD all-to-alls tokens to the
-    # expert-sharded dim (reference: _AllToAll.apply at sharded_moe.py:506)
-    expert_in = jnp.einsum("tec,th->ech", dispatch.astype(x.dtype), tokens)
-    expert_in = _constrain(expert_in, P(expert_axis, None, None))
-
-    up = jnp.einsum("ech,ehf->ecf", expert_in,
-                    moe_params["w_in"].astype(x.dtype))
-    if "w_gate" in moe_params:
-        gate = jnp.einsum("ech,ehf->ecf", expert_in,
-                          moe_params["w_gate"].astype(x.dtype))
-        act = jax.nn.silu(gate) * up
+    with jax.named_scope("route"):
+        logits = tokens.astype(jnp.float32) @ moe_params["wg"].astype(jnp.float32)
+    if cfg.drop_tokens:
+        cf = cfg.capacity_factor if train else cfg.eval_capacity_factor
+        C = _capacity(T, E, cf, cfg.min_capacity)
+        y, aux = _one_hot_ffn(moe_params, tokens, logits, cfg, C, rng, train,
+                               expert_axis)
+    elif _sorts(T, E, cfg.top_k, train):
+        y, aux = _sorted_ffn(moe_params, tokens, logits, cfg, rng, train)
     else:
-        act = jax.nn.gelu(up)
-    out = jnp.einsum("ecf,efh->ech", act, moe_params["w_out"].astype(x.dtype))
-    out = _constrain(out, P(expert_axis, None, None))
-
-    y = jnp.einsum("tec,ech->th", combine.astype(x.dtype), out)
+        # capacity = tokens: the masks drop nothing
+        y, aux = _one_hot_ffn(moe_params, tokens, logits, cfg, T, rng, train,
+                               expert_axis)
     return y.reshape(B, S, H), aux

@@ -1,0 +1,151 @@
+"""Grouped matmul for the dropless expert layer: a Pallas TPU kernel.
+
+``rows [M, K]`` are sorted by expert, ``group_sizes [E]`` says how many rows
+each expert has, and row i is multiplied by ITS expert's ``[K, N]`` matrix —
+one call per projection of an expert layer (``moe/sharded_moe.py``). The
+weights are the WHOLE stack ``[L, E, K, N]`` plus the layer's index: a
+Pallas operand is a whole buffer, so handing the kernel ``stack[layer]``
+makes XLA copy that layer's experts first (0.8 GB a layer at OLMoE's widths,
+three stacks: 30 % of a decode step, PERF.md section 6, PR 26); the kernel's
+index map picks ``(layer, expert)`` blocks out of the stack in place.
+
+Structure (after JAX's megablox ``gmm``, which it replaced: tracing that
+one's group metadata cost 0.6 s per program on the chip's host, 3.4 s of a
+Mixtral run's set-up): the grid walks every (row tile, expert) pair that
+shares a row — a *visit* —, at most ``row tiles + E - 1`` of them and exactly
+``num_visits`` at run time (a dynamic grid bound); a visit multiplies the
+tile's ``tm`` rows by the expert's matrix, tile by tile over K, into a
+float32 accumulator, and stores the rows that belong to the expert. Visits
+of one row tile are consecutive, so its output block stays in fast memory
+between them. Rows past ``sum(group_sizes)`` belong to nobody: their output
+is never written and never read.
+
+A visit streams the expert's weight tile whatever ``tm`` is and is bound by
+that up to ~240 rows (a v5e's 197 TFLOP/s over 819 GB/s), by the multiply
+beyond: ``row_tile`` picks ``tm`` from the shapes.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# weight tile (contraction, columns): 4 MiB in bf16, double-buffered
+TK, TN = 2048, 1024
+# rows up to which streaming an expert's weight tile hides the multiply: a
+# v5e's 197 TFLOP/s over 819 GB/s
+WEIGHT_BOUND_ROWS = 240.0
+
+
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+def visit_cost(rows: int, experts: int, tm: int) -> float:
+    """The kernel's time in units of one weight-bound expert visit: at worst
+    ``row tiles + experts - 1`` visits, each bound by the expert's weight
+    bytes up to ``WEIGHT_BOUND_ROWS`` rows and by the multiply beyond."""
+    return (-(-rows // tm) + experts - 1) * max(1.0, tm / WEIGHT_BOUND_ROWS)
+
+
+def row_tile(rows: int, experts: int) -> int:
+    """Rows per tile, from the shapes alone: few experts want large tiles
+    (Mixtral's 512 prefill rows over 8 experts: 9 visits at 256 rows, 11 at
+    128), many experts and few rows small ones (a decode step's 256 rows
+    over 64 experts: 65 visits at 128 rows, each cheaper than at 256)."""
+    def cost(tm):
+        return visit_cost(rows, experts, tm)
+    return min((64, 128, 256), key=cost)
+
+
+def supported(K: int, N: int) -> bool:
+    """The kernel tiles K and N without a remainder."""
+    return K % min(TK, K) == 0 and N % min(TN, N) == 0 and K % 128 == 0 \
+        and N % 128 == 0
+
+
+def visits(group_sizes, tm: int, tiles_m: int):
+    """The walk over (row tile, expert) pairs: ``offsets [E + 1]`` (row at
+    which each expert's group starts), ``expert [V]`` and ``tile [V]`` of
+    each visit (V = tiles_m + E - 1, the most there can be; entries past
+    ``num_visits`` repeat the last real one and are never run), and
+    ``num_visits``."""
+    E = group_sizes.shape[0]
+    sizes = group_sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    n = jnp.where(sizes > 0, (ends - 1) // tm - starts // tm + 1, 0)
+    vend = jnp.cumsum(n)                          # visits up to and with e
+    num_visits = vend[-1]
+    v = jnp.minimum(jnp.arange(tiles_m + E - 1, dtype=jnp.int32),
+                    jnp.maximum(num_visits - 1, 0))
+    expert = jnp.minimum(
+        jnp.sum((vend[None, :] <= v[:, None]).astype(jnp.int32), axis=1), E - 1)
+    tile = jnp.take(starts // tm, expert) + v - jnp.take(vend - n, expert)
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    return offsets, expert, jnp.clip(tile, 0, tiles_m - 1), num_visits
+
+
+def _kernel(layer_ref, offsets_ref, expert_ref, tile_ref, lhs_ref, rhs_ref,
+            out_ref, acc_ref, *, tm: int, tiles_k: int):
+    del layer_ref                                  # used by the index maps
+    v, k_i = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(k_i == 0)
+    def _zero():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    acc_ref[...] += jnp.dot(lhs_ref[...], rhs_ref[...],
+                            preferred_element_type=jnp.float32)
+
+    @pl.when(k_i == tiles_k - 1)
+    def _store():
+        e = expert_ref[v]
+        row = lax.broadcasted_iota(jnp.int32, acc_ref.shape, 0) \
+            + tile_ref[v] * tm
+        mine = (row >= offsets_ref[e]) & (row < offsets_ref[e + 1])
+        out_ref[...] = jnp.where(mine, acc_ref[...],
+                                 out_ref[...].astype(jnp.float32)
+                                 ).astype(out_ref.dtype)
+
+
+def grouped_matmul(rows, stack, layer, group_sizes):
+    """rows [M, K] sorted by expert, stack [L, E, K, N], layer (int32
+    scalar, may be traced), group_sizes [E] int32 with sum <= M -> [M, N] in
+    ``rows.dtype``; rows past the groups' sum come back undefined."""
+    M, K = rows.shape
+    L, E, K2, N = stack.shape
+    assert K == K2 and supported(K, N), (rows.shape, stack.shape)
+    tm = row_tile(M, E)
+    pad = -M % tm
+    if pad:
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+    tiles_m = (M + pad) // tm
+    tk, tn = min(TK, K), min(TN, N)
+    tiles_k, tiles_n = K // tk, N // tn
+    offsets, expert, tile, num_visits = visits(group_sizes, tm, tiles_m)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,          # (layer, offsets, expert, tile)
+        grid=(tiles_n, num_visits, tiles_k),
+        in_specs=[
+            pl.BlockSpec((tm, tk), lambda n, v, k, ly, off, ex, tl: (tl[v], k)),
+            pl.BlockSpec((None, None, tk, tn),
+                         lambda n, v, k, ly, off, ex, tl: (ly[0], ex[v], k, n)),
+        ],
+        out_specs=pl.BlockSpec((tm, tn),
+                               lambda n, v, k, ly, off, ex, tl: (tl[v], n)),
+        scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel, tm=tm, tiles_k=tiles_k),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((M + pad, N), rows.dtype),
+        compiler_params=None if _interpret() else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        interpret=_interpret(),
+        name="moe_gmm",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), offsets, expert, tile,
+      rows, stack)
+    return out[:M] if pad else out

@@ -1,0 +1,63 @@
+"""Tier-1 guards the yardstick: ``benchmark/tests`` collected from here.
+
+``benchmark/`` is the on-chip benchmark and carries its own CPU tests
+(``python -m pytest benchmark/tests``: the trace reducer, the generators, the
+families' arithmetic, the family seam, ``BENCHMARK.json`` itself). The
+driver's tier-1 command collects ``tests/`` only, so until ISSUE 26 a PR
+could break the instrument and stay green. This module runs that suite ONCE,
+in a process of its own (its modules put ``benchmark/tests`` on ``sys.path``
+and two of them define a fixture of the same name, so they are not imported
+into this one), and reports every one of its tests as a case here: the count
+of passes moves with the suite's.
+"""
+import os
+import subprocess
+import sys
+import xml.etree.ElementTree as ET
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+SUITE = os.path.join("benchmark", "tests")
+_PYTEST = [sys.executable, "-m", "pytest", SUITE, "-q", "-p", "no:cacheprovider",
+           "-p", "no:xdist", "-p", "no:randomly"]
+_ENV = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+_ENV["JAX_PLATFORMS"] = "cpu"
+
+
+def _node_ids():
+    """The suite's test ids, by the suite's own collection (parametrised
+    cases included): every worker gets the same list."""
+    p = subprocess.run(_PYTEST + ["--collect-only"], cwd=ROOT, env=_ENV,
+                       capture_output=True, text=True, timeout=300)
+    ids = [ln.strip() for ln in p.stdout.splitlines() if "::" in ln and " " not in ln.strip()]
+    if p.returncode != 0 or not ids:
+        return [f"COLLECTION FAILED rc={p.returncode}: {p.stdout[-1500:]}{p.stderr[-1500:]}"]
+    return ids
+
+
+NODE_IDS = _node_ids()
+
+
+@pytest.fixture(scope="module")
+def outcomes(tmp_path_factory):
+    """One run of the whole suite -> {test id: (outcome, detail)}."""
+    xml = tmp_path_factory.mktemp("benchmark_suite") / "junit.xml"
+    p = subprocess.run(_PYTEST + [f"--junitxml={xml}", "-o", "junit_family=xunit1"],
+                       cwd=ROOT, env=_ENV, capture_output=True, text=True, timeout=1200)
+    out = {}
+    for case in ET.parse(xml).getroot().iter("testcase"):
+        path = case.get("classname").replace(".", "/") + ".py"
+        bad = [c for c in case if c.tag in ("failure", "error", "skipped")]
+        out[f"{path}::{case.get('name')}"] = (
+            bad[0].tag if bad else "passed",
+            (bad[0].get("message") or "") + "\n" + (bad[0].text or "") if bad else "")
+    out["__log__"] = ("", p.stdout[-3000:])
+    return out
+
+
+@pytest.mark.parametrize("node_id", NODE_IDS)
+def test_benchmark_suite(node_id, outcomes):
+    assert node_id in outcomes, (node_id, outcomes["__log__"][1])
+    outcome, detail = outcomes[node_id]
+    assert outcome == "passed", f"{node_id}: {outcome}\n{detail[-3000:]}"

@@ -189,3 +189,96 @@ def test_pool_is_written_in_place(case, kind, one_chip, engines):
     leaf_bytes = int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < leaf_bytes, (temp, leaf_bytes)
+
+
+# ---- the dropless expert dispatch (ISSUE 26) --------------------------------
+#
+# Same file because it is the same kind of test: a property that only the
+# program the TPU's compiler builds can show, compiled for the described v5e.
+
+def _moe_engine(**overrides):
+    """An OLMoE-shaped engine (64 experts, top-8, q/k norm, dropless) at
+    narrow widths; only its jitted functions are used."""
+    from deepspeed_tpu.models.hf_import import hf_config_to_transformer
+    hf = {"model_type": "olmoe", "vocab_size": 512, "hidden_size": 256,
+          "intermediate_size": 128, "num_hidden_layers": 2,
+          "num_attention_heads": 4, "num_key_value_heads": 4,
+          "num_experts": 64, "num_experts_per_tok": 8, "norm_topk_prob": False,
+          "rms_norm_eps": 1e-5, "rope_theta": 10000, "tie_word_embeddings": False}
+    cfg = hf_config_to_transformer(hf, max_seq_len=1024, dtype=jnp.bfloat16,
+                                   **overrides)
+    return deepspeed_tpu.init_serving(
+        make_model(cfg), config={"kv_cache_bits": 8},
+        serving=dict(max_seqs=2, block_size=BS, max_model_len=128,
+                     decode_backend="xla"), dtype=jnp.bfloat16)
+
+
+def _largest_arrays(hlo: str, at_least: int) -> list:
+    """Instructions (anywhere, fused or not) whose result has at least
+    ``at_least`` elements and is not a parameter or a view of one."""
+    bad = []
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if not m or m.group(3) in ("parameter", "bitcast", "get-tuple-element"):
+            continue
+        dims = [int(d) for d in m.group(2).split(",") if d]
+        if dims and int(np.prod(dims)) >= at_least:
+            bad.append(line.strip()[:160])
+    return bad
+
+
+def test_dropless_dispatch_is_proportional_to_the_assignments(one_chip, monkeypatch):
+    """A prompt of T = 512 tokens, E = 64, k = 8, H = 256: the sorted
+    dispatch's largest arrays are the T*k rows ([4096, 256]); nothing in the
+    compiled program has the E x T x H = 8.4 M elements of an expert-major
+    dispatch buffer, let alone the T x E x T = 16.8 M of a one-hot mask, the
+    program's temporaries stay under that buffer's bytes, the kernel reads
+    the layer's experts out of the whole stack (no copy of one layer's), and
+    a decode step of 32 slots — which takes the one-hot masks, [32, 64, 32]
+    — has no such array either. The control — the same prompt through the
+    capacity path with C = T — holds both."""
+    T_, E, H = 512, 64, 256
+
+    def compiled_text(srv, kind):
+        S, MB = 32, 2          # a small pool: the largest arrays must be the layer's
+
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        params = _abstract(srv.engine.params, one_chip)
+        pools = _abstract(jax.eval_shape(
+            lambda: srv.model.init_paged_cache(S * MB + 1, BS)), one_chip)
+        key = sds((2,), jnp.uint32)
+        if kind == "prefill":
+            fn = jax.jit(srv._get_prefill_fn(T_).__wrapped__, donate_argnums=(2,))
+            args = (params, sds((1, T_), jnp.int32), pools,
+                    sds((T_ // BS,), jnp.int32), sds((), jnp.int32), key)
+        else:
+            fn = jax.jit(srv._get_quantum_step().__wrapped__, donate_argnums=(1, 4))
+            args = (params, pools, sds((S,), jnp.int32), sds((S, MB), jnp.int32),
+                    sds((S,), jnp.int32), sds((S,), jnp.bool_), key)
+        # the program asks the backend which dispatch to build; the test
+        # answers for the chip it compiles for
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        try:
+            with jax.default_matmul_precision("default"):
+                c = fn.lower(*args).compile()
+        finally:
+            monkeypatch.undo()
+        return c.as_text(), c.memory_analysis().temp_size_in_bytes
+
+    srv = _moe_engine()
+    text, temp = compiled_text(srv, "prefill")
+    assert "tpu_custom_call" in text and "%moe_gmm" in text  # the kernel is in
+    assert not _largest_arrays(text, E * T_ * H), _largest_arrays(text, E * T_ * H)[:3]
+    assert temp < E * T_ * H * 2, temp
+    per_layer = f"bf16[{E},{H},128]"
+    assert not [l for l in text.splitlines()
+                if f" = {per_layer}" in l and "parameter" not in l], per_layer
+    text, temp = compiled_text(srv, "step")
+    assert "%moe_gmm" not in text                            # few tokens: one-hot
+    assert not _largest_arrays(text, E * T_ * H) and temp < E * T_ * H * 2
+    srv.close()
+    srv = _moe_engine(drop_tokens=True, eval_capacity_factor=float(E))
+    text, _ = compiled_text(srv, "prefill")
+    srv.close()
+    assert _largest_arrays(text, E * T_ * H)                 # the control
