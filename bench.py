@@ -835,8 +835,8 @@ def _kernel_parity_matrix() -> dict:
 
     # paged decode kernel (block-table gather resolved in the index maps)
     # vs the XLA gather path through models/transformer._paged_attention
-    # (which itself feeds _decode_attention) so the masking contract lives
-    # in ONE place instead of a re-implemented reference drifting here.
+    # (the token-major read, _paged_token_attention) so the masking contract
+    # lives in ONE place instead of a re-implemented reference drifting here.
     # Mixed per-slot lengths incl. 0 (fresh slot) and a full table.
     from deepspeed_tpu.models.transformer import _paged_attention
     for S, NB, MB, Nkv, rep, bs, D in [(8, 33, 4, 8, 1, 64, 64),

@@ -772,61 +772,102 @@ def _decode_attention(q, ck, cv, index, cfg: TransformerConfig = None,
     return out.reshape(B, 1, Nq, D)
 
 
-def _gather_view(pool, tables):
-    """One layer's token-major pool slice [NB, bs, Nkv, D] read through the
-    block tables [S, MB] as the ring-buffer view [S, Nkv, MB*bs, D]."""
+def _gather_blocks(pool, tables, layer=None):
+    """A slot's blocks as stored: the token-major pool read through the
+    block tables [S, MB] as [S, MB*bs, Nkv, D], ONE gather and nothing
+    around it.
+
+    pool: one layer's [NB, bs, Nkv, D], or the WHOLE leaf
+    [L, NB, bs, Nkv, D] with ``layer`` (traced) the layer to read. Inside a
+    layer scan the whole leaf is the one to pass: a ``dynamic_index_in_dim``
+    ahead of the gather is a COPY of the layer's slice of each pool on every
+    layer (100 MB a pool in the chat cell: 4.4 ms a step, PERF.md §5-6,
+    PR 27), while the layer as an offset into the leaf viewed
+    [L*NB, bs, Nkv, D] — merging the two MAJOR dims is a bitcast — costs
+    nothing. Table entries are block ids the allocator issued or 0, the
+    trash block: always in bounds, so the gather clips and selects no fill
+    value; what a slot may see is decided by the length mask on the
+    scores."""
     S, MB = tables.shape
-    _, bs, Nkv, D = pool.shape
-    g = jnp.take(pool, tables, axis=0)           # [S, MB, bs, Nkv, D]
-    return g.reshape(S, MB * bs, Nkv, D).transpose(0, 2, 1, 3)
+    if layer is not None:
+        NB = pool.shape[1]
+        pool = pool.reshape((-1,) + pool.shape[2:])
+        tables = layer * NB + tables
+    g = jnp.take(pool, tables, axis=0, mode="clip")  # [S, MB, bs, Nkv, D]
+    return g.reshape((S, MB * pool.shape[1]) + pool.shape[2:])
 
 
-def _gather_scales(scale, tables, Nkv):
-    """One layer's scale plane [NB, Nkv*bs] read through the block tables
-    as [S, Nkv, MB*bs] (position-major within a head, like the view)."""
+def _gather_scales(scale, tables, Nkv, layer=None):
+    """A scale plane [NB, Nkv*bs] (or the whole [L, NB, Nkv*bs] with
+    ``layer``) read through the block tables as [S, Nkv, MB*bs]
+    (position-major within a head). The layer is a coordinate of the gather:
+    the planes live in a layout of their own, where merging [L, NB] copies
+    the plane."""
     S, MB = tables.shape
-    g = jnp.take(scale, tables, axis=0)          # [S, MB, Nkv*bs]
+    idx = (tables,) if layer is None else (layer, tables)
+    g = scale.at[idx].get(mode="clip")           # [S, MB, Nkv*bs]
     return g.reshape(S, MB, Nkv, -1).transpose(0, 2, 1, 3).reshape(S, Nkv, -1)
 
 
+def _quant_query(q32):
+    """Per-row symmetric int8 of a float32 query [..., D] -> (int8, scale
+    [...]): the ``_decode_attention`` recipe, shared by the paged reads."""
+    qs = jnp.maximum(jnp.max(jnp.abs(q32), axis=-1) / 127.0, 1e-8)
+    qi = jnp.clip(jnp.round(q32 / qs[..., None]), -127, 127).astype(jnp.int8)
+    return qi, qs
+
+
+def _quant_probs(pv):
+    """Probabilities x V scale [..., T] (non-negative) requantised per row
+    -> (int8, scale [...]): the ``_decode_pv`` recipe."""
+    ps = jnp.maximum(jnp.max(pv, axis=-1) / 127.0, 1e-20)
+    return jnp.clip(jnp.round(pv / ps[..., None]), 0, 127).astype(jnp.int8), ps
+
+
 def _paged_attention(q, pool_k, pool_v, tables, index, cfg: TransformerConfig,
-                     kv_row, kv_scale=None, backend="xla", window=None):
+                     kv_row, kv_scale=None, backend="xla", window=None,
+                     layer=None):
     """Single-token attention against the PAGED block pool.
 
     q: [S, 1, Nq, D] (one in-flight token per slot); pool_k/pool_v:
-    [NB, bs, Nkv, D] (one layer's slice of the shared block pool, stored
-    TOKEN-major: see ``init_paged_cache``); tables: [S, MB] int32 block
-    ids (0 = the reserved trash block, masked by the length); index:
-    per-slot sequence length [S].
+    [NB, bs, Nkv, D], one layer's slice of the shared block pool, stored
+    TOKEN-major (see ``init_paged_cache``) — or, with ``layer`` (a traced
+    index), the WHOLE leaves [L, NB, bs, Nkv, D] and scale planes, which is
+    what a layer scan passes (``_gather_blocks`` says why); tables: [S, MB]
+    int32 block ids (0 = the reserved trash block, masked by the length);
+    index: per-slot sequence length [S].
 
     backend="pallas": the block-table gather is resolved inside the kernel's
     index maps (ops/decode_attention.paged_decode_attention) — only blocks
     covering the valid prefix ever cross HBM->VMEM, nothing materializes.
-    backend="xla": ``jnp.take`` materializes the slot's blocks
-    ([S, MB, bs, Nkv, D] -> [S, MB*bs, Nkv, D]), the view is turned
-    head-major ([S, Nkv, MB*bs, D]) and the math is the EXACT ring-buffer
-    path (_decode_attention with a per-slot cursor) — same einsums, same
-    masking, which is what makes paged-vs-contiguous decode bit-for-bit
-    comparable in tests. The backend is chosen by a measured micro-bench at
-    serving-engine init, not a config flag.
+    backend="xla": ONE ``jnp.take`` per pool materializes the slot's blocks
+    as stored ([S, MB*bs, Nkv, D]) and ``_paged_token_attention`` contracts
+    that view as gathered. Its arithmetic is the ring-buffer path's
+    (``_decode_attention`` with a per-slot cursor) operation for operation,
+    which is what keeps paged-vs-contiguous decode bit-for-bit comparable in
+    tests. The backend is chosen by a measured micro-bench at serving-engine
+    init, not a config flag.
 
     Multi-token queries (q [S, T, Nq, D] with T > 1 — the speculation
     verify / chunked-prefill span path, ``decode_span_paged``) route to
-    ``_paged_span_attention``: per-position ``_decode_attention`` with the
-    span itself as the kv suffix, so every position's math is the
-    single-token chain bit for bit (the Pallas kernel is single-token
-    only and is never selected for spans).
+    ``_paged_span_attention``: every position's math is the single-token
+    chain's (the Pallas kernel is single-token only and is never selected
+    for spans).
     """
-    Nkv = pool_k.shape[2]
+    Nkv = pool_k.shape[-2]
     if q.shape[1] > 1:
         return _paged_span_attention(q, pool_k, pool_v, tables, index, cfg,
                                      kv_row, kv_scale=kv_scale,
-                                     window=window)
+                                     window=window, layer=layer)
     use_pallas = (backend == "pallas" and kv_scale is None
                   and window is None and q.dtype != jnp.float16
                   and (cfg is None or (cfg.position_type != "alibi"
                                        and cfg.attn_scale is None)))
     if use_pallas:
+        if layer is not None:        # a kernel's operand is a whole buffer
+            pool_k, pool_v = (lax.dynamic_index_in_dim(p, layer, 0,
+                                                       keepdims=False)
+                              for p in (pool_k, pool_v))
         # heads over `tensor` like the pools themselves: each chip runs the
         # kernel on its kv-head slice (parallel.context.kernel_mesh)
         from deepspeed_tpu.parallel.context import kernel_mesh
@@ -847,16 +888,115 @@ def _paged_attention(q, pool_k, pool_v, tables, index, cfg: TransformerConfig,
     sc = None
     with jax.named_scope("kv_gather"):
         if kv_scale is not None:
-            sc = tuple(_gather_scales(s, tables, Nkv) for s in kv_scale)
-        vk, vv = (_gather_view(pool_k, tables),
-                  _gather_view(pool_v, tables))
-    return _decode_attention(q, vk, vv, index, cfg,
-                             kv_row=kv_row, kv_scale=sc, window=window)
+            sc = tuple(_gather_scales(s, tables, Nkv, layer)
+                       for s in kv_scale)
+        vk, vv = (_gather_blocks(pool_k, tables, layer),
+                  _gather_blocks(pool_v, tables, layer))
+    return _paged_token_attention(q, vk, vv, index, cfg, kv_row, sc, window)
+
+
+def _head_groups():
+    """How many groups of kv heads a block-diagonal contraction keeps apart:
+    the ambient mesh's ``tensor`` degree (the pools and the heads are sharded
+    over it, and a contraction across kv heads would sum across chips), 1
+    without a mesh."""
+    from deepspeed_tpu.parallel.context import kernel_mesh
+    return kernel_mesh()[1].get("tensor", 1)
+
+
+def _paged_token_attention(q, vk, vv, index, cfg, kv_row, kv_scale, window):
+    """One token per slot against the gathered TOKEN-major view.
+
+    q: [S, 1, Nq, D]; vk/vv: [S, T, Nkv, D] as ``_gather_blocks`` returns
+    them (T = MB*bs positions, rows at >= index stale); kv_row: the fresh
+    (k, v) [S, Nkv, 1, D], folded into the same softmax; kv_scale: the int8
+    pool's gathered (k, v) scales [S, Nkv, T], or None.
+
+    The paged read's OWN contraction: ``_decode_attention`` wants the
+    ring buffer's head-major [B, Nkv, T, D], and borrowing it cost the view
+    a select-and-transpose pass (7.1 ms a step in the chat cell) and, at one
+    query head per kv head, the int8 scores as an elementwise s32
+    multiply-reduce over a WIDENED view (two s32 views of 268 MB a layer in
+    OLMoE's cell: 33 of its step's 58 ms; PERF.md §5-6, PR 27). Here the
+    view is contracted as stored, and the recipe is ``_decode_attention``'s
+    to the letter — query quantised per row, int8 x int8 -> int32, q and k
+    scales multiplied into the scores, probabilities x v-scale requantised
+    per row — so the results are its results bit for bit.
+
+    The int8 contractions are written block-diagonally so that they are
+    matmuls at ANY number of query heads per kv head: the quantised query
+    is laid out [S, Nkv, D, Nkv*rep] with zeros off the diagonal and the
+    scores are one matmul per slot contracting (Nkv, D); P.V likewise gives
+    [S, Nkv*rep, Nkv, D], of which the diagonal blocks are kept. The zeros
+    add exact zeros to an int32 sum. It is Nkv times the multiply-adds of
+    the plain form (6.4 GFLOP a layer in the chat cell: tens of
+    microseconds of the int8 MXU) and none of its passes over the view.
+    Under a ``tensor`` mesh the diagonal is laid per group of local kv
+    heads (``_head_groups``), so no sum crosses chips.
+    """
+    S, _, Nq, D = q.shape
+    T, Nkv = vk.shape[1], vk.shape[2]
+    rep = Nq // Nkv
+    sm = (cfg.attn_scale if cfg is not None and cfg.attn_scale is not None
+          else 1.0 / math.sqrt(D))
+    qg = q.reshape(S, Nkv, rep, D)
+    k_row, v_row = kv_row                        # [S, Nkv, 1, D]
+    if kv_scale is not None:
+        X = _head_groups()
+        G = Nkv // X                             # kv heads of one group
+        eye = jnp.eye(G, dtype=jnp.int8)
+        qi, qs = _quant_query(qg.astype(jnp.float32))
+        # [S, X, G, rep, D] x eye[G, H] -> [S, X, G, D, H, rep]
+        qd = jnp.einsum("sxgrd,gh->sxgdhr", qi.reshape(S, X, G, rep, D), eye)
+        scores = jnp.einsum("stxgd,sxgdhr->sxhrt",
+                            vk.reshape(S, T, X, G, D), qd,
+                            preferred_element_type=jnp.int32
+                            ).reshape(S, Nkv, rep, T).astype(jnp.float32)
+        scores = scores * qs[..., None] * kv_scale[0][:, :, None, :]
+    else:
+        scores = jnp.einsum("sgrd,stgd->sgrt", qg, vk).astype(jnp.float32)
+    scores = scores * sm
+    index = jnp.asarray(index, jnp.int32)[:, None]
+    if cfg is not None and cfg.position_type == "alibi":
+        rel = (jnp.arange(T)[None, :] - index).astype(jnp.float32)  # k - q
+        slopes = alibi_slopes(Nq).reshape(Nkv, rep)
+        scores = scores + slopes[None, :, :, None] * rel[:, None, None, :]
+    # rows at >= index are stale (or another request's, or the trash
+    # block's); the current token's logit comes from the fresh row
+    keep = jnp.arange(T)[None, :] < index
+    if window is not None:
+        # local band: position t visible iff index - t < window; <= 0 global
+        w = jnp.asarray(window, jnp.int32)
+        keep = keep & ((w <= 0) | (index - jnp.arange(T)[None, :] < w))
+    scores = jnp.where(keep[:, None, None, :], scores, -1e30)
+    s_self = jnp.einsum("bgrd,bgtd->bgrt", qg,
+                        k_row.astype(qg.dtype)).astype(jnp.float32)
+    s_self = s_self * sm
+    probs = jax.nn.softmax(jnp.concatenate([scores, s_self], axis=-1),
+                           axis=-1)
+    pp = probs[..., :T]
+    if kv_scale is not None:
+        # fold the per-position V scale into the probs, requantize per row,
+        # keep the contraction on the int8 MXU (the _decode_pv recipe)
+        pvi, ps = _quant_probs(pp * kv_scale[1][:, :, None, :])
+        # every (query head, kv head) pair of a group, then its diagonal
+        acc = jnp.einsum("sxhrt,stxgd->sxhrgd",
+                         pvi.reshape(S, X, G, rep, T),
+                         vv.reshape(S, T, X, G, D),
+                         preferred_element_type=jnp.int32)
+        acc = jnp.sum(jnp.where((eye != 0)[None, None, :, None, :, None],
+                                acc, 0), axis=4)
+        out = (acc.reshape(S, Nkv, rep, D).astype(jnp.float32)
+               * ps[..., None]).astype(q.dtype)
+    else:
+        out = jnp.einsum("sgrt,stgd->sgrd", pp.astype(q.dtype), vv)
+    out = out + probs[..., T:].astype(q.dtype) * v_row.astype(q.dtype)
+    return out.reshape(S, 1, Nq, D)
 
 
 def _paged_span_attention(q, pool_k, pool_v, tables, prior_lens,
                           cfg: TransformerConfig, kv_row, kv_scale=None,
-                          window=None):
+                          window=None, layer=None):
     """T-token attention for a span appended at each slot's cursor.
 
     q: [S, T, Nq, D]; kv_row: the span's fresh (k, v) [S, Nkv, T, D];
@@ -883,35 +1023,34 @@ def _paged_span_attention(q, pool_k, pool_v, tables, prior_lens,
     path, and the reason the int8 parity tests carry a weaker bar.
     """
     S, T = q.shape[0], q.shape[1]
-    Nkv, D = pool_k.shape[2], pool_k.shape[3]
+    Nkv, D = pool_k.shape[-2:]
     Nq = q.shape[2]
     rep = Nq // Nkv
     chunk_k, chunk_v = kv_row                    # [S, Nkv, T, D]
     sm = (cfg.attn_scale if cfg is not None and cfg.attn_scale is not None
           else 1.0 / math.sqrt(D))
 
+    # the pool view stays token-major [S, Tp, Nkv, D], as gathered
     with jax.named_scope("kv_gather"):
-        vk, vv = (_gather_view(pool_k, tables),
-                  _gather_view(pool_v, tables))
-        Tp = vk.shape[2]
+        vk, vv = (_gather_blocks(pool_k, tables, layer),
+                  _gather_blocks(pool_v, tables, layer))
+        Tp = vk.shape[1]
         if kv_scale is not None:
-            ksg, vsg = (_gather_scales(s, tables, Nkv) for s in kv_scale)
+            ksg, vsg = (_gather_scales(s, tables, Nkv, layer)
+                        for s in kv_scale)
     qg = q.transpose(0, 2, 1, 3).reshape(S, Nkv, rep, T, D)
     pos = prior_lens[:, None] + jnp.arange(T)[None, :]       # [S, T] abs
     if kv_scale is not None:
         # int8 pool, int8 math — the _decode_attention recipe batched
         # over T: quantize each query row, contract on the int8 MXU, fold
         # q/k scales into the scores
-        q32 = qg.astype(jnp.float32)
-        qs_ = jnp.maximum(jnp.max(jnp.abs(q32), axis=-1) / 127.0, 1e-8)
-        qi = jnp.clip(jnp.round(q32 / qs_[..., None]), -127, 127
-                      ).astype(jnp.int8)
-        sp = jnp.einsum("bgrtd,bgsd->bgrts", qi, vk,
+        qi, qs_ = _quant_query(qg.astype(jnp.float32))
+        sp = jnp.einsum("bgrtd,bsgd->bgrts", qi, vk,
                         preferred_element_type=jnp.int32
                         ).astype(jnp.float32)
         sp = sp * qs_[..., None] * ksg[:, :, None, None, :]
     else:
-        sp = jnp.einsum("bgrtd,bgsd->bgrts", qg, vk).astype(jnp.float32)
+        sp = jnp.einsum("bgrtd,bsgd->bgrts", qg, vk).astype(jnp.float32)
     sp = sp * sm
     if cfg is not None and cfg.position_type == "alibi":
         rel = (jnp.arange(Tp)[None, None, :]
@@ -948,16 +1087,13 @@ def _paged_span_attention(q, pool_k, pool_v, tables, prior_lens,
     if kv_scale is not None:
         # fold the per-position V scale into the probs, requantize, keep
         # the contraction on the int8 MXU (the _decode_pv recipe)
-        pv = pp * vsg[:, :, None, None, :]
-        ps = jnp.maximum(jnp.max(pv, axis=-1) / 127.0, 1e-20)
-        pvi = jnp.clip(jnp.round(pv / ps[..., None]), 0, 127
-                       ).astype(jnp.int8)
-        acc = jnp.einsum("bgrts,bgsd->bgrtd", pvi, vv,
+        pvi, ps = _quant_probs(pp * vsg[:, :, None, None, :])
+        acc = jnp.einsum("bgrts,bsgd->bgrtd", pvi, vv,
                          preferred_element_type=jnp.int32
                          ).astype(jnp.float32)
         out = (acc * ps[..., None]).astype(q.dtype)
     else:
-        out = jnp.einsum("bgrts,bgsd->bgrtd", pp.astype(q.dtype), vv)
+        out = jnp.einsum("bgrts,bsgd->bgrtd", pp.astype(q.dtype), vv)
     out = out + jnp.einsum("bgrtu,bgud->bgrtd", pc.astype(q.dtype),
                            chunk_v.astype(q.dtype))
     # [S, Nkv, rep, T, D] -> [S, T, Nq, D]
@@ -1161,10 +1297,13 @@ def transformer_layer(x, layer_params, cfg: TransformerConfig, mask=None,
     one tiny column update). return_kv: also return the (post-rotary) K/V
     so a prefill pass can seed the cache.
 
-    paged=(block_tables, backend): the cache tuple carries one layer's
-    BLOCK-POOL slices ([NB, bs, nkv, hd]) instead of per-batch ring
-    buffers, and `index` is the per-slot sequence-length vector —
-    attention reads through the block table (decode_step_paged).
+    paged=(block_tables, backend, layer): the cache tuple carries the
+    WHOLE block pools ([L, NB, bs, nkv, hd], and the whole scale planes of
+    an int8 pool) instead of per-batch ring buffers, `layer` is this
+    layer's (traced) index into them and `index` the per-slot
+    sequence-length vector — attention gathers the layer's blocks straight
+    out of the whole pool through the block table (decode_step_paged;
+    ``_gather_blocks`` says why the pool is not sliced first).
 
     lora=({proj: (A, B)}, idx): one layer's adapter slot tables + the
     per-row adapter-slot index — each projection in the dict gains the
@@ -1251,13 +1390,13 @@ def transformer_layer(x, layer_params, cfg: TransformerConfig, mask=None,
         # buffer (the decode loop guarantees index < read_len), so XLA only
         # touches O(read_len) bytes instead of max_len
         if paged is not None:
-            tables, backend = paged
+            tables, backend, layer = paged
             with jax.named_scope("attn"):
                 attn_out = _paged_attention(q, ck, cv, tables, index, cfg,
                                             kv_row=(k_row, v_row),
                                             kv_scale=kv_scale,
                                             backend=backend,
-                                            window=attn_window)
+                                            window=attn_window, layer=layer)
         elif read_len is not None and read_len < ck.shape[2]:
             sc = (tuple(s[:, :, :read_len] for s in kv_scale)
                   if kv_scale is not None else None)
@@ -2077,8 +2216,9 @@ def init_paged_cache(cfg: TransformerConfig, num_blocks: int,
     ([.., n_kv, block_size, head_dim]) a row is one sub-tile line of every
     head, and the TPU compiler moved the WHOLE pool to another layout and
     back around every such write (4 x 1.6 GB a step at 16 x 1537 blocks,
-    PERF.md §5-6, PR 24). The attention read turns the gathered blocks
-    head-major itself (``_gather_view``).
+    PERF.md §5-6, PR 24). The attention read gathers a layer's blocks out
+    of the whole pool and contracts them token-major, as stored
+    (``_gather_blocks``, ``_paged_token_attention``).
 
     Block 0 is the reserved TRASH block: null block-table entries point at
     it and inactive slots write into it, so the compiled step needs no
@@ -2090,7 +2230,7 @@ def init_paged_cache(cfg: TransformerConfig, num_blocks: int,
     planes are relayouted around the row write whatever the order of their
     axes, this one is written in place and pads no lanes) — the attention
     read consumes the int8 bytes directly with dequant fused into the score
-    scaling (see _decode_attention / ops/quantizer).
+    scaling (see _paged_token_attention / ops/quantizer).
 
     ``paged_blocks_to_logical`` / ``paged_blocks_from_logical`` translate
     whole blocks to and from the head-major order [.., n_kv, block_size,
@@ -2206,14 +2346,9 @@ def decode_step_paged(params: Params, tokens, cfg: TransformerConfig,
 
     def body(x_c, i):
         layer_p = at_layer(params["layers"], i)
-        pk = lax.dynamic_index_in_dim(pools["k"], i, 0, keepdims=False)
-        pv = lax.dynamic_index_in_dim(pools["v"], i, 0, keepdims=False)
-        sc = ((lax.dynamic_index_in_dim(pools["k_scale"], i, 0,
-                                        keepdims=False),
-               lax.dynamic_index_in_dim(pools["v_scale"], i, 0,
-                                        keepdims=False))
-              if int8_kv else None)
-        c = (pk, pv, seq_lens, None, sc)
+        # the WHOLE pools: the layer is a coordinate of the read's gather
+        sc = (pools["k_scale"], pools["v_scale"]) if int8_kv else None
+        c = (pools["k"], pools["v"], seq_lens, None, sc)
         if cfg.offload_params:
             layer_p = _fetch_layer(layer_p, cfg)
         lora_i = None
@@ -2224,7 +2359,7 @@ def decode_step_paged(params: Params, tokens, cfg: TransformerConfig,
         with _moe.layer_load_tap() as tap:
             y, _, (k_row, v_row) = transformer_layer(
                 x_c, layer_p, cfg, positions=positions, deterministic=True,
-                cache=c, return_kv=False, paged=(block_tables, backend),
+                cache=c, return_kv=False, paged=(block_tables, backend, i),
                 attn_window=None if wins is None else wins[i], lora=lora_i)
         return y, (k_row, v_row, tap and tap.stacked())
 
@@ -2318,14 +2453,9 @@ def decode_span_paged(params: Params, tokens, cfg: TransformerConfig,
 
     def body(x_c, i):
         layer_p = _held_layer(at_layer(sliced, i), held, i)
-        pk = lax.dynamic_index_in_dim(pools["k"], i, 0, keepdims=False)
-        pv = lax.dynamic_index_in_dim(pools["v"], i, 0, keepdims=False)
-        sc = ((lax.dynamic_index_in_dim(pools["k_scale"], i, 0,
-                                        keepdims=False),
-               lax.dynamic_index_in_dim(pools["v_scale"], i, 0,
-                                        keepdims=False))
-              if int8_kv else None)
-        c = (pk, pv, seq_lens, None, sc)
+        # the WHOLE pools: the layer is a coordinate of the read's gather
+        sc = (pools["k_scale"], pools["v_scale"]) if int8_kv else None
+        c = (pools["k"], pools["v"], seq_lens, None, sc)
         if cfg.offload_params:
             layer_p = _fetch_layer(layer_p, cfg)
         lora_i = None
@@ -2336,7 +2466,7 @@ def decode_span_paged(params: Params, tokens, cfg: TransformerConfig,
         with _moe.layer_load_tap() as tap:
             y, _, (k_row, v_row) = transformer_layer(
                 x_c, layer_p, cfg, positions=positions, deterministic=True,
-                cache=c, return_kv=False, paged=(block_tables, backend),
+                cache=c, return_kv=False, paged=(block_tables, backend, i),
                 attn_window=None if wins is None else wins[i], lora=lora_i)
         # rows: [S, nkv, T, hd]
         return y, (k_row, v_row, tap and tap.stacked())

@@ -1,11 +1,12 @@
 """Paged Pallas decode-attention parity (reference test model:
 tests/unit/ops kernel-vs-torch parity, SURVEY §4).
 
-The XLA reference is the materialized block-table gather fed through
-``models/transformer._decode_attention`` (the ring-buffer math with a
-per-slot cursor) — the same function the serving engine's XLA backend uses,
-so the masking contract lives in ONE place instead of a re-implemented
-reference drifting here.
+The kernel's reference is the materialized block-table gather, turned
+head-major, fed through ``models/transformer._decode_attention`` (the
+ring-buffer math with a per-slot cursor). The serving engine's XLA backend
+has a contraction of its own since ISSUE 27 (``_paged_token_attention``,
+the gathered view consumed token-major as stored); it is checked here
+against a plain numpy reference.
 """
 
 import jax
@@ -16,9 +17,17 @@ import pytest
 from deepspeed_tpu.ops.decode_attention import paged_decode_attention
 
 
+def _gather_view(pool, tables):
+    """One layer's token-major pool slice [NB, bs, Nkv, D] read through the
+    block tables [S, MB] as the ring-buffer view [S, Nkv, MB*bs, D]."""
+    S, MB = tables.shape
+    _, bs, Nkv, D = pool.shape
+    g = jnp.take(pool, tables, axis=0)           # [S, MB, bs, Nkv, D]
+    return g.reshape(S, MB * bs, Nkv, D).transpose(0, 2, 1, 3)
+
+
 def _ref_paged(q, k_pool, v_pool, tables, lens, k_row, v_row):
-    from deepspeed_tpu.models.transformer import (_decode_attention,
-                                                  _gather_view)
+    from deepspeed_tpu.models.transformer import _decode_attention
     return _decode_attention(q, _gather_view(k_pool, tables),
                              _gather_view(v_pool, tables),
                              jnp.asarray(lens, jnp.int32), None,
@@ -108,6 +117,95 @@ def test_table_permutation_invariance():
     o1 = paged_decode_attention(q, kp, vp, t1, lens, kv_row=(kr, vr))
     o2 = paged_decode_attention(q, kp2, vp2, t2, lens, kv_row=(kr, vr))
     np.testing.assert_array_equal(np.asarray(o1), np.asarray(o2))
+
+
+# ---------------------------------------------------------------------------
+# The XLA read of the paged pool (ISSUE 27): one gather out of the whole
+# pool, contracted token-major as gathered
+# ---------------------------------------------------------------------------
+
+def _plain_reference(q, k, v, tables, lens, k_row, v_row):
+    """Single-token attention over each slot's first ``lens[s]`` positions
+    plus its fresh row, in float64 with numpy and nothing else: k, v are a
+    layer's DEQUANTISED pool [NB, bs, Nkv, D]."""
+    q, k, v, k_row, v_row = (np.asarray(a, np.float64)
+                             for a in (q, k, v, k_row, v_row))
+    S, _, Nq, D = q.shape
+    Nkv = k.shape[2]
+    rep = Nq // Nkv
+    out = np.zeros((S, 1, Nq, D))
+    for s in range(S):
+        n = int(lens[s])
+        rows_k = k[np.asarray(tables[s])].reshape(-1, Nkv, D)[:n]
+        rows_v = v[np.asarray(tables[s])].reshape(-1, Nkv, D)[:n]
+        for h in range(Nq):
+            g = h // rep
+            ks = np.concatenate([rows_k[:, g], k_row[s, g]], 0)   # [n+1, D]
+            vs = np.concatenate([rows_v[:, g], v_row[s, g]], 0)
+            logit = ks @ q[s, 0, h] / np.sqrt(D)
+            p = np.exp(logit - logit.max())
+            out[s, 0, h] = (p / p.sum()) @ vs
+    return out
+
+
+@pytest.mark.parametrize("layer", [None, 2])
+@pytest.mark.parametrize("rep", [1, 4])
+@pytest.mark.parametrize("bits", [0, 8])
+def test_xla_read_against_plain_reference(bits, rep, layer):
+    """Random tables with trash entries and junk ids past the live prefix,
+    lengths 0, 1, one block and the full table, one and four query heads a
+    kv head, a float and an int8 pool, a layer's slice and the whole pool
+    with the layer as a (traced) coordinate of the gather."""
+    from deepspeed_tpu.models.transformer import _paged_attention
+    L, NB, bs, MB, Nkv, D = 3, 11, 16, 4, 2, 32
+    lens = np.array([0, 1, bs, MB * bs], np.int32)
+    S = len(lens)
+    rng = np.random.default_rng(100 * bits + 10 * rep + (layer or 0))
+    tables = rng.integers(1, NB, (S, MB)).astype(np.int32)  # junk, in bounds
+    tables[0] = 0                                  # an empty slot: all trash
+    tables[1, 1:] = 0                              # nulls past the one block
+    q = rng.normal(size=(S, 1, Nkv * rep, D)).astype(np.float32)
+    k_row = rng.normal(size=(S, Nkv, 1, D)).astype(np.float32)
+    v_row = rng.normal(size=(S, Nkv, 1, D)).astype(np.float32)
+    if bits == 8:
+        pk = rng.integers(-127, 128, (L, NB, bs, Nkv, D)).astype(np.int8)
+        pv = rng.integers(-127, 128, (L, NB, bs, Nkv, D)).astype(np.int8)
+        ksc = (rng.random((L, NB, Nkv * bs)) * 0.02 + 1e-3).astype(np.float32)
+        vsc = (rng.random((L, NB, Nkv * bs)) * 0.02 + 1e-3).astype(np.float32)
+        # a plane is head-major within a block: [.., Nkv, bs] -> [.., bs, Nkv]
+        deq = lambda p, sc: p.astype(np.float64) * np.swapaxes(
+            sc.reshape(L, NB, Nkv, bs), 2, 3)[..., None]
+        k_f, v_f = deq(pk, ksc), deq(pv, vsc)
+        scales = (jnp.asarray(ksc), jnp.asarray(vsc))
+        tol = 3e-2      # the query and the probabilities are quantised too
+    else:
+        pk = rng.normal(size=(L, NB, bs, Nkv, D)).astype(np.float32)
+        pv = rng.normal(size=(L, NB, bs, Nkv, D)).astype(np.float32)
+        k_f, v_f, scales, tol = pk, pv, None, 2e-5
+    pk, pv = jnp.asarray(pk), jnp.asarray(pv)
+    i = 1 if layer is None else layer
+
+    if layer is None:           # a 4-D slice with no layer: still works
+        def read(q, pk, pv, scales, i):
+            sc = None if scales is None else tuple(s[1] for s in scales)
+            return _paged_attention(q, pk[1], pv[1], jnp.asarray(tables),
+                                    jnp.asarray(lens), None,
+                                    kv_row=(k_row, v_row), kv_scale=sc)
+    else:
+        def read(q, pk, pv, scales, i):
+            return _paged_attention(q, pk, pv, jnp.asarray(tables),
+                                    jnp.asarray(lens), None,
+                                    kv_row=(k_row, v_row), kv_scale=scales,
+                                    layer=i)
+    out = jax.jit(read)(jnp.asarray(q), pk, pv, scales, jnp.int32(i))
+    ref = _plain_reference(q, k_f[i], v_f[i], tables, lens, k_row, v_row)
+    np.testing.assert_allclose(np.asarray(out, np.float64), ref,
+                               rtol=tol, atol=tol)
+    # the empty slot attends only to its fresh row
+    np.testing.assert_allclose(
+        np.asarray(out[0]),
+        np.repeat(v_row[0], rep, axis=0).reshape(1, Nkv * rep, D),
+        rtol=1e-5, atol=1e-5)
 
 
 class TestInt8KVCache:

@@ -10,9 +10,18 @@ row is a whole minor tile and the scatter is in place.
 
 Nothing on the CPU shows this: the copies exist only in the program the
 TPU's compiler builds. So the serving engine's own jitted functions are
-compiled here for a described v5e (no chip attached) at the two serve
+compiled here for a described v5e (no chip attached) at the three serve
 cells' pool shapes and a bf16 pool, and the compiled text must hold no op
 that produces a whole pool or scale plane other than the scatters.
+
+And it is READ once (ISSUE 27): a decode step gathers a layer's blocks
+straight out of the whole pool and contracts the gathered token-major view
+as it is. The same compiles must hold no copy of a layer's slice of a pool
+(the parent sliced the pool per layer ahead of the gather: 100 MB of each
+pool a layer in the chat cell), no array of the gathered view's size in a
+wider type than the pool's (with one query head per kv head the parent
+widened both views to s32, 268 MB each a layer in OLMoE's cell, and
+multiplied them elementwise), and few temporaries.
 
 libtpu is loaded behind a fixture, as in ``test_kernel_names.py``, the
 only other file that describes a chip. Under several workers each of the
@@ -45,15 +54,21 @@ def one_chip():
     return jax.sharding.SingleDeviceSharding(topo.devices[0])
 
 
-# the pools of the benchmark's two serve cells (benchmark/configs/*-serve
-# .json: layers x blocks, slots, table width at 64-token blocks) and a
-# float pool of the chat cell's shape at half the blocks
+# the pools of the benchmark's three serve cells (benchmark/configs/*-serve
+# .json: layers x blocks, slots, table width at 64-token blocks, kv and
+# query heads of 128) and a float pool of the chat cell's shape at half the
+# blocks
 CASES = {
-    "chat-int8": dict(layers=16, blocks=1537, slots=48, mb=32, bits=8),
-    "mixtral-int8": dict(layers=4, blocks=1025, slots=32, mb=32, bits=8),
-    "chat-bf16": dict(layers=16, blocks=769, slots=48, mb=16, bits=0),
+    "chat-int8": dict(layers=16, blocks=1537, slots=48, mb=32, bits=8,
+                      nkv=8, nq=32),
+    "mixtral-int8": dict(layers=4, blocks=1025, slots=32, mb=32, bits=8,
+                         nkv=8, nq=32),
+    "olmoe-int8": dict(layers=14, blocks=513, slots=32, mb=16, bits=8,
+                       nkv=16, nq=16),
+    "chat-bf16": dict(layers=16, blocks=769, slots=48, mb=16, bits=0,
+                      nkv=8, nq=32),
 }
-BS, NKV, HD = 64, 8, 128                 # both families: 8 kv heads of 128
+BS, HD = 64, 128
 
 
 @pytest.fixture(scope="module")
@@ -68,7 +83,7 @@ def engines():
             c = CASES[case]
             cfg = TransformerConfig(
                 vocab_size=512, hidden_size=256, num_layers=c["layers"],
-                num_heads=NKV, num_kv_heads=NKV, head_dim=HD,
+                num_heads=c["nq"], num_kv_heads=c["nkv"], head_dim=HD,
                 intermediate_size=512, max_seq_len=4096, position_type="rotary",
                 activation="silu_glu", norm_type="rmsnorm",
                 tie_embeddings=False, dtype=jnp.bfloat16,
@@ -128,16 +143,15 @@ def _program(srv, case, kind, one_chip, mb=None):
 
 _INSTR = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]*)\]\S* "
                     r"([\w\-]+)\(")
+_BYTES = {"s8": 1, "u8": 1, "pred": 1, "bf16": 2, "f16": 2, "f32": 4,
+          "s32": 4, "u32": 4}
+_HLO_NAME = {"int8": "s8", "bfloat16": "bf16", "float32": "f32"}
+# not arrays a program writes: arguments and views of them
+_VIEWS = ("parameter", "bitcast", "get-tuple-element", "tuple", "while")
 
 
-def whole_pool_ops(hlo: str, pools) -> list:
-    """Instructions OUTSIDE fused computations whose result is a whole
-    pool leaf and that are a copy, a transpose, or a fusion that holds no
-    scatter: each one reads and writes the leaf's every byte."""
-    names = {"int8": "s8", "bfloat16": "bf16", "float32": "f32"}
-    shapes = {(names[np.dtype(a.dtype).name], ",".join(map(str, a.shape)))
-              for a in jax.tree.leaves(pools)}
-    # computation name -> its lines
+def _computations(hlo: str):
+    """(computation name -> its lines, the names of the FUSED ones)."""
     comps, cur = {}, None
     for line in hlo.splitlines():
         m = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
@@ -147,24 +161,61 @@ def whole_pool_ops(hlo: str, pools) -> list:
             cur = None
         elif cur is not None:
             cur.append(line)
-    fused = set(re.findall(r"kind=k\w+, calls=%([\w.\-]+)", hlo))
-    bad = []
+    return comps, set(re.findall(r"kind=k\w+, calls=%([\w.\-]+)", hlo))
+
+
+def _instructions(hlo: str, fused_too: bool = False):
+    """(type, element count, opcode, line, holds a scatter) of every
+    instruction outside fused computations (``fused_too``: inside them as
+    well) — outside them each result is an array the program writes."""
+    comps, fused = _computations(hlo)
     for name, lines in comps.items():
-        if name in fused:
+        if name in fused and not fused_too:
             continue
         for line in lines:
             m = _INSTR.match(line)
-            if not m or (m.group(1), m.group(2)) not in shapes:
+            if not m or m.group(1) not in _BYTES:
                 continue
-            op = m.group(3)
-            if op == "fusion":
+            dims = [int(d) for d in m.group(2).split(",") if d]
+            scatters = False
+            if m.group(3) == "fusion":
                 callee = re.search(r"calls=%([\w.\-]+)", line).group(1)
-                if any(" scatter(" in l for l in comps.get(callee, ())):
-                    continue
-            elif op not in ("copy", "transpose"):
-                continue
-            bad.append(line.strip()[:160])
-    return bad
+                scatters = any(" scatter(" in l for l in comps.get(callee, ()))
+            yield (m.group(1), int(np.prod(dims)) if dims else 1, m.group(3),
+                   line.strip()[:160], scatters)
+
+
+def whole_pool_ops(hlo: str, pools) -> list:
+    """Instructions OUTSIDE fused computations whose result has a whole
+    pool leaf's BYTES (whatever its shape: a relayout may merge or split
+    dims) and that are a copy, a transpose, a reshape, or a fusion that
+    holds no scatter: each one reads and writes the leaf's every byte."""
+    sizes = {int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
+             for a in jax.tree.leaves(pools)}
+    return [line for ty, n, op, line, scatters in _instructions(hlo)
+            if n * _BYTES[ty] in sizes and not scatters
+            and op in ("copy", "transpose", "reshape", "fusion")]
+
+
+def layer_slice_ops(hlo: str, pools) -> list:
+    """Instructions outside fused computations that produce ONE LAYER's
+    slice of a pool leaf or of a scale plane (its type and element count):
+    the slice taken ahead of the gather is a copy."""
+    slices = {(_HLO_NAME[np.dtype(a.dtype).name], int(np.prod(a.shape[1:])))
+              for a in jax.tree.leaves(pools)}
+    return [line for ty, n, op, line, _ in _instructions(hlo)
+            if (ty, n) in slices and op not in _VIEWS]
+
+
+def widened_view_ops(hlo: str, pools, slots: int, mb: int) -> list:
+    """Instructions, fused or not, whose result has the gathered view's
+    element count (slots x table x block x heads x head_dim) in a type
+    wider than the pool's."""
+    leaf = pools["k"]
+    count = slots * mb * int(np.prod(leaf.shape[2:]))
+    width = np.dtype(leaf.dtype).itemsize
+    return [line for ty, n, op, line, _ in _instructions(hlo, fused_too=True)
+            if n == count and _BYTES[ty] > width and op not in _VIEWS]
 
 
 @pytest.mark.parametrize("case,kind", [
@@ -176,19 +227,50 @@ def test_pool_is_written_in_place(case, kind, one_chip, engines):
     assert not bad, "\n".join(bad)
     # no temporary of a whole leaf's size either (a relayout's scratch, a
     # scatter that lost its alias). At the cells' table width the READ
-    # side's gathered views (slots x table x block, K and V, three passes)
-    # are 0.6-1.0 GB of temporaries by themselves — more than the whole
-    # Mixtral pool — and share their space with whatever the write needs,
-    # so the write's own temporaries are read off the same program with a
-    # table one block wide: under the bytes of ONE K/V leaf (a layer's K
-    # and V slices, copied inside the scan, are already a quarter of a
-    # four-layer pool).
+    # side's gathered views (slots x table x block, K and V) are temporaries
+    # too — 0.2 GB in the chat cell, a quarter of the whole Mixtral pool —
+    # and share their space with whatever the write needs, so the write's
+    # own temporaries are read off the same program with a table one block
+    # wide: under the bytes of ONE K/V leaf.
     if kind != "prefill":                 # a prefill reads no table
         compiled, pools = _program(engines(case), case, kind, one_chip, mb=1)
     leaf = pools["k"]
     leaf_bytes = int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < leaf_bytes, (temp, leaf_bytes)
+
+
+@pytest.mark.parametrize("case,kind", [
+    ("chat-int8", "step"), ("mixtral-int8", "step"), ("olmoe-int8", "step"),
+    ("chat-int8", "span")])
+def test_pool_is_read_once(case, kind, one_chip, engines):
+    """The read of a decode step (and of a span): one gather per pool and
+    layer out of the WHOLE pool, contracted as gathered (ISSUE 27). The
+    parent fails the first assertion in all four cases (a
+    ``dynamic-slice_bitcast_fusion`` per pool and plane) and the widened
+    view in OLMoE's."""
+    c = CASES[case]
+    compiled, pools = _program(engines(case), case, kind, one_chip)
+    hlo = compiled.as_text()
+    bad = layer_slice_ops(hlo, pools)
+    assert not bad, "\n".join(bad)
+    # OLMoE's cut has 14 layers, not a multiple of the 8-row tile: the
+    # compiler keeps its scale planes [14, 513, 1024] layer-major between
+    # steps and moves them layer-second-minor around the row scatters, four
+    # copies of 29 MB a step (0.33 ms of 58 on the chip, parent and change
+    # alike: PERF.md section 7, PR 27). The write's business, not the
+    # read's: that shape checks its payload leaves.
+    leaves = ({n: pools[n] for n in "kv"} if case == "olmoe-int8" else pools)
+    bad = whole_pool_ops(hlo, leaves)
+    assert not bad, "\n".join(bad)
+    if kind == "step":
+        bad = widened_view_ops(hlo, pools, c["slots"], c["mb"])
+        assert not bad, "\n".join(bad)
+    if (case, kind) == ("chat-int8", "step"):
+        # the gathered K and V views (0.1 GB each) and the scores; the
+        # parent's step held 1.0 GB (slices, views, their head-major twins)
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < 0.3e9, temp
 
 
 # ---- the dropless expert dispatch (ISSUE 26) --------------------------------

@@ -189,18 +189,30 @@ def _paged_vs_contiguous(kv_bits, dtype, steps=24):
         lc, cache = dsc(params, tok, cache)
         lp, pools = dsp(params, tok_p, pools, tabs_d, lens)
         lens = lens + 1
-        # bit-for-bit: the paged read is the SAME einsum chain on a
-        # gathered view of identical values (junk masked to exact zeros)
-        np.testing.assert_array_equal(np.asarray(lc), np.asarray(lp),
-                                      err_msg=f"step {i}")
+        if kv_bits == 8:
+            # bit-for-bit: the int8 read sums integers (exact in any
+            # order) and its float operations are the ring path's, in the
+            # ring path's order (junk masked to exact zeros)
+            np.testing.assert_array_equal(np.asarray(lc), np.asarray(lp),
+                                          err_msg=f"step {i}")
+        else:
+            # a float pool is contracted token-major, as gathered, where
+            # the ring buffer is head-major: the same products summed in
+            # another order, so a logit may round to the neighbouring
+            # bf16 value (PR 27). Greedy tokens stay equal (below).
+            lc32, lp32 = (np.asarray(a, np.float32) for a in (lc, lp))
+            ulp = 2.0 ** (np.floor(np.log2(np.abs(lc32).max())) - 7)
+            err = np.abs(lc32 - lp32).max() / ulp
+            assert err <= 2, (i, err)
         tok = jnp.argmax(lc, -1).astype(jnp.int32)
         tok_p = jnp.argmax(lp, -1).astype(jnp.int32)
         np.testing.assert_array_equal(np.asarray(tok), np.asarray(tok_p))
 
 
 def test_paged_matches_contiguous_bf16():
-    """>= 20 greedy decode steps, bf16 cache: logits and tokens exactly
-    equal between the paged pool and the contiguous ring buffer."""
+    """>= 20 greedy decode steps, bf16 cache: tokens exactly equal and
+    logits within 2 bf16 ulps (of the step's largest logit) between the
+    paged pool and the contiguous ring buffer."""
     _paged_vs_contiguous(0, jnp.bfloat16)
 
 
@@ -209,6 +221,58 @@ def test_paged_matches_contiguous_int8_kv():
     """Same contract through the int8-quantized pool (scales gathered and
     fused into the score scaling — identical math to the int8 ring)."""
     _paged_vs_contiguous(8, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("span", [1, 3], ids=["token", "span"])
+@pytest.mark.parametrize("bits", [0, 8], ids=["float", "int8"])
+def test_layer_is_a_coordinate_of_the_gather(bits, span):
+    """A layer scan hands the read the WHOLE pools and its index (ISSUE 27);
+    what it returns is what a layer's slice returns, bit for bit — for one
+    token a slot and for a span, a float and an int8 pool, trash entries in
+    the tables and an empty slot."""
+    from deepspeed_tpu.models.transformer import _paged_attention
+    L, NB, bs, MB, nkv, nq, D, S = 3, 9, 16, 3, 2, 8, 16, 3
+    rng = np.random.default_rng(bits + span)
+    if bits == 8:
+        pk = jnp.asarray(rng.integers(-127, 128, (L, NB, bs, nkv, D)), jnp.int8)
+        pv = jnp.asarray(rng.integers(-127, 128, (L, NB, bs, nkv, D)), jnp.int8)
+        sc = tuple(jnp.asarray(rng.random((L, NB, nkv * bs)) * 0.02 + 1e-3,
+                               jnp.float32) for _ in range(2))
+    else:
+        pk = jnp.asarray(rng.normal(size=(L, NB, bs, nkv, D)), jnp.float32)
+        pv = jnp.asarray(rng.normal(size=(L, NB, bs, nkv, D)), jnp.float32)
+        sc = None
+    q = jnp.asarray(rng.normal(size=(S, span, nq, D)), jnp.float32)
+    kr = jnp.asarray(rng.normal(size=(S, nkv, span, D)), jnp.float32)
+    vr = jnp.asarray(rng.normal(size=(S, nkv, span, D)), jnp.float32)
+    tabs = rng.integers(1, NB, (S, MB)).astype(np.int32)
+    tabs[0] = 0
+    tabs[1, 2:] = 0
+    tabs, lens = jnp.asarray(tabs), jnp.asarray([0, 21, MB * bs - span],
+                                                jnp.int32)
+    cfg = _cfg()
+
+    @jax.jit
+    def whole(pk, pv, sc):
+        return jax.lax.scan(lambda c, i: (c, _paged_attention(
+            q, pk, pv, tabs, lens, cfg, kv_row=(kr, vr), kv_scale=sc,
+            layer=i)), 0, jnp.arange(L))[1]
+
+    @jax.jit
+    def sliced(pk, pv, sc):
+        return jnp.stack([_paged_attention(
+            q, pk[i], pv[i], tabs, lens, cfg, kv_row=(kr, vr),
+            kv_scale=None if sc is None else (sc[0][i], sc[1][i]))
+            for i in range(L)])
+    np.testing.assert_array_equal(np.asarray(whole(pk, pv, sc)),
+                                  np.asarray(sliced(pk, pv, sc)))
+
+
+def test_paged_matches_contiguous_int8_kv_first_steps():
+    """The quick tier's share of the int8 contract above: the block-diagonal
+    int8 contractions of the paged read (ISSUE 27) give the int8 ring
+    buffer's logits EXACTLY over the first steps."""
+    _paged_vs_contiguous(8, jnp.bfloat16, steps=5)
 
 
 def test_paged_kernel_agrees_with_xla_gather():
