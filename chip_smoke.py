@@ -275,7 +275,7 @@ def leg_kernels(sz: Sizes, pool_shape, rehearsal: bool) -> dict:
 
 def leg_train(sz: Sizes, chips: int, rehearsal: bool) -> dict:
     """A few steps on a repeated seeded batch, `transformer.fused_backward`
-    off and on (on is what bench.py ships): loss finite and falling, the
+    off and on: loss finite and falling, the
     timed steps end in block_until_ready, the step lowers with the Mosaic
     kernels, and on four chips nothing is replicated or lopsided."""
     import jax
@@ -284,7 +284,7 @@ def leg_train(sz: Sizes, chips: int, rehearsal: bool) -> dict:
     from deepspeed_tpu.models import make_model
     from deepspeed_tpu.utils.hlo_check import assert_no_spmd_replication
 
-    # the model as bench.py's headline rung configures it
+    # the train cells' settings (benchmark/configs/mistral-7b-train.json)
     cfg = model_config(sz, sz.train_layers, sz.seq, rehearsal, remat=True,
                        remat_policy="dots_saveable", loss_chunk=sz.seq)
     batch = {"input_ids": np.random.default_rng(0).integers(

@@ -49,8 +49,9 @@ class AnalysisSettings:
     # collectives smaller than this are control-plane sync (loss means,
     # overflow flags) and exempt from the kind policy
     min_collective_bytes: int = 1024
-    # exact census pin: {kind: count}; empty -> kind policy only
-    expect_collectives: Dict[str, int] = dataclasses.field(default_factory=dict)
+    # exact census pin: {kind: count | {"count": n, "bytes": b}}; empty ->
+    # kind policy only
+    expect_collectives: Dict[str, Any] = dataclasses.field(default_factory=dict)
     # donation: buffers below the floor are noise (scalars, counters)
     min_donation_bytes: int = 1024
     # dtype promotion: smallest f32-widened result worth flagging
@@ -154,8 +155,10 @@ class CollectiveAudit:
             # means a collective was hoisted out of the loop, more means one
             # was duplicated into it
             k = int(art.meta.get("fuse_steps", 1) or 1)
-            expected = {kind: n * k
-                        for kind, n in settings.expect_collectives.items()}
+            expected = {
+                kind: ({f: n[f] * k for f in n} if isinstance(n, dict)
+                       else n * k)
+                for kind, n in settings.expect_collectives.items()}
             findings.extend(compare_census(
                 full, expected, art.name,
                 source="config analysis.expect_collectives"

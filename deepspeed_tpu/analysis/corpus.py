@@ -68,10 +68,13 @@ def undonated_state(devices=None):
                             settings=AnalysisSettings())
 
 
-def extra_collective(devices=None):
+def extra_collective(devices=None, seeded=True):
     """Collective audit: a data-parallel grad step with ONE gratuitous extra
     all-reduce (a replicated batch statistic nobody asked for) — the census
-    pin catches what no structural rule can."""
+    pin catches what no structural rule can. XLA combines the extra
+    reduction into the grad's all-reduce (one tuple-shaped op), so the pin
+    holds the BYTES beside the count. ``seeded=False`` is the defect-free
+    twin: the same step without the statistic."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -84,6 +87,8 @@ def extra_collective(devices=None):
     def grads(w, x):
         loss = lambda w_: jnp.sum((x @ w_) ** 2)
         g = jax.grad(loss)(w)          # batch-sharded x -> one all-reduce
+        if not seeded:
+            return g, g[0, 0]
         extra = jnp.sum(x, axis=0)     # the silent extra: replicated [128]
         return g, g[0, 0] + 1e-12 * jnp.sum(extra)
 
@@ -91,21 +96,27 @@ def extra_collective(devices=None):
     art = lower_program(jitted, w_abs, x_abs, name="grad_step", mesh=mesh,
                         donatable=None, donation_expected=False,
                         meta={"skip_required": True})
-    # the clean program compiles to exactly one all-reduce; pin it
+    # the clean program compiles to exactly one all-reduce, of the [128,128]
+    # f32 gradient; pin it
     return analyze_programs(
         [art], _stage0_config(), _FakePlan(),
-        settings=AnalysisSettings(expect_collectives={"all-reduce": 1}))
+        settings=AnalysisSettings(expect_collectives={
+            "all-reduce": {"count": 1, "bytes": 128 * 128 * 4}}))
 
 
-def f32_upcast(devices=None):
+def f32_upcast(devices=None, seeded=True):
     """Dtype lint: a bf16 program that MATERIALIZES a >=1MiB f32 widening
     of an activation (a fused elementwise convert would be fine — the lint
-    only flags top-level converts that allocate the f32 buffer)."""
+    only flags converts whose result is a buffer). ``seeded=False`` is the
+    defect-free twin: the same loss with the widening left inside its
+    fusion."""
     import jax
     import jax.numpy as jnp
 
     def loss(x):
         big = x.astype(jnp.float32)    # the defect: 512*512*4 = 1 MiB copy
+        if not seeded:
+            return jnp.sum(big * big)
         return jnp.sum(big * big), big  # returning it forces materialization
 
     x_abs = jax.ShapeDtypeStruct((512, 512), jnp.bfloat16)
@@ -194,14 +205,16 @@ def fused_loop_hoist(devices=None):
         settings=AnalysisSettings(expect_collectives={"all-reduce": 1}))
 
 
-def telemetry_leak(devices=None):
+def telemetry_leak(devices=None, seeded=True):
     """Telemetry done WRONG, both ways the real accumulators must never be:
     (a) the stats buffer is NOT donated — every step holds the old and new
     [256,256] window plane live at once (the real leaf rides the donated
     state); (b) the per-step update all-reduces a batch statistic across
     `data` instead of accumulating device-locally (the real leaf adds one
     dense collective: zero). The donation lint must flag the un-donated
-    buffer and the census pin must flag the extra all-reduce."""
+    buffer and the census pin must flag the extra reduction — by its
+    BYTES: XLA combines it into the grad's all-reduce. ``seeded=False`` is
+    the defect-free twin: stats donated and accumulated locally."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -217,23 +230,28 @@ def telemetry_leak(devices=None):
     def step(params, telemetry, x):
         loss = lambda w_: jnp.sum((x @ w_) ** 2)
         g = jax.grad(loss)(params["w"])  # batch-sharded x -> one all-reduce
-        # defect (b): a replicated batch statistic folded into the stats
-        # plane — GSPMD must insert a second all-reduce every step
-        batch_mean = jnp.mean(x, axis=0)
-        stats = telemetry["stats"] + jnp.tile(batch_mean, 2)[None, :]
+        if seeded:
+            # defect (b): a replicated batch statistic folded into the stats
+            # plane — GSPMD must reduce it across `data` every step
+            batch_mean = jnp.mean(x, axis=0)
+            stats = telemetry["stats"] + jnp.tile(batch_mean, 2)[None, :]
+        else:
+            stats = telemetry["stats"] + 1.0
         return {"w": params["w"] - 1e-3 * g}, {"stats": stats}
 
     # defect (a): only the params are donated; the telemetry arg is not
-    jitted = jax.jit(step, donate_argnums=(0,),
+    jitted = jax.jit(step, donate_argnums=(0,) if seeded else (0, 1),
                      out_shardings=({"w": repl}, {"stats": repl}))
     art = lower_program(
         jitted, params_abs, tel_abs, x_abs, name="telemetry_step", mesh=mesh,
         donatable={"params": params_abs, "telemetry": tel_abs},
         meta={"skip_required": True})
-    # the clean program compiles to exactly the one grad all-reduce; pin it
+    # the clean program compiles to exactly the one grad all-reduce, of the
+    # [128,128] f32 gradient; pin it
     return analyze_programs(
         [art], _stage0_config(), _FakePlan(),
-        settings=AnalysisSettings(expect_collectives={"all-reduce": 1}))
+        settings=AnalysisSettings(expect_collectives={
+            "all-reduce": {"count": 1, "bytes": 128 * 128 * 4}}))
 
 
 def deferred_sync_regression(devices=None):
@@ -402,55 +420,6 @@ class NoisyLossModel:
         # GSPMD must insert an extra all-reduce to materialize it
         extra = jnp.mean(batch["input_ids"].astype(jnp.float32), axis=0)
         return loss + 1e-12 * jnp.sum(extra)
-
-
-def serialized_backward(devices=None):
-    """Serialized backward: a tensor=2 row-parallel projection whose chunked
-    collective-matmul overlap (`transformer.tp_overlap_chunks`) was silently
-    disabled — the program compiled the single fat boundary all-reduce
-    instead of the 4 chunk-interleaved psums the config asked for. The
-    census pin expects the chunked shape (4 all-reduces) and sees 1 —
-    census drift — and the one serial reduction is fully exposed, so the
-    overlap gate (max_exposed_collectives=0) fires too. The measured twin
-    of this defect is the doctor corpus entry of the same name
-    (``doctor --corpus serialized-backward``): there the exposed wire time
-    trips ``exposed-collective-measured`` on a traced step."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    devs = devices or jax.devices()[:2]
-    if len(devs) < 2:
-        raise SystemExit("corpus: needs >= 2 devices "
-                         "(--xla_force_host_platform_device_count)")
-    mesh = Mesh(list(devs)[:2], ("tensor",))
-    x_abs = jax.ShapeDtypeStruct((8, 256, 128), jnp.float32,
-                                 sharding=NamedSharding(
-                                     mesh, P(None, None, "tensor")))
-    w_abs = jax.ShapeDtypeStruct((128, 64), jnp.float32,
-                                 sharding=NamedSharding(mesh,
-                                                        P("tensor", None)))
-
-    def serial(x, w):
-        # the defect: the plain matmul — one local dot + ONE synchronous
-        # all-reduce of the whole [8, 256, 64] output at the end (the
-        # chunked path emits 4 independent psums the scheduler interleaves)
-        return x @ w
-
-    repl = NamedSharding(mesh, P())
-    jitted = jax.jit(serial, out_shardings=repl)
-    art = lower_program(jitted, x_abs, w_abs, name="row_parallel_proj",
-                        mesh=mesh, donatable=None, donation_expected=False,
-                        meta={"skip_required": True})
-    from deepspeed_tpu.config import Config
-    cfg = Config.load({"train_batch_size": 4,
-                       "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
-                       "bf16": {"enabled": False},
-                       "transformer": {"tp_overlap_chunks": 4}})
-    return analyze_programs(
-        [art], cfg, _FakePlan(),
-        settings=AnalysisSettings(
-            expect_collectives={"all-reduce": 4},
-            max_exposed_collectives=0, min_exposed_bytes=1))
 
 
 def _paged_decode_program(num_blocks: int, devices=None):
@@ -786,7 +755,7 @@ def offload_serial_pipeline(devices=None):
     pipeline was silently disabled — every param fetch resolves
     synchronously on the critical path and every write drains before the
     next layer runs, so the step pays the full storage latency on top of
-    compute (the BENCH_r05 capacity shape: offload_cpu_adam_ratio 7x).
+    compute.
     ``audit_offload`` drives the REAL InfinityExecutor with calibrated
     injected fetch latency; the drained defect exposes ~the whole injected
     budget and ``offload-overlap`` must fire (host-stall dominant). The
@@ -828,11 +797,11 @@ def tracing_sync_leak(devices=None):
     """Serving doctor gate: the REAL ``RequestTracer`` armed with an
     ``on_span`` hook that performs a ``device_get`` per span — the
     documented defect seam of the zero-sync tracing contract. The hook
-    self-reports through ``tracer.device_syncs`` and the measured span
-    overhead is priced against the round budget; ``tracing-sync-leak``
+    self-reports through ``tracer.device_syncs``; ``tracing-sync-leak``
     must fire (device-syncs). The host-clock twin (same span load, no
-    hook) stays under the 1% overhead gate and passes — both directions
-    CLI-runnable (``doctor --corpus tracing-sync-leak``)."""
+    hook) reports zero syncs and passes — both directions CLI-runnable
+    (``doctor --corpus tracing-sync-leak``). The measured span overhead
+    is a reported field; it gates nothing."""
     from deepspeed_tpu.profiling.doctor import run_corpus_entry
     return run_corpus_entry("tracing-sync-leak")
 
@@ -909,7 +878,6 @@ CORPUS = {
     "exposed-collective-trace": exposed_collective_trace,
     "serving-blind-stall": serving_blind_stall,
     "tracing-sync-leak": tracing_sync_leak,
-    "serialized-backward": serialized_backward,
     "staging-buffer-alias": staging_buffer_alias,
     "allocator-unlocked-share": allocator_unlocked_share,
     "drain-schema-skew": drain_schema_skew,

@@ -289,7 +289,9 @@ class ConvertOp:
 
 
 _CONVERT_RE = re.compile(
-    r"=\s*(f32|f64)\[([\d,]*)\][^ ]*\s+convert\((bf16|f16)\[")
+    r"^\s+(ROOT\s+)?%?[\w.\-]+\s*=\s*(f32|f64)\[([\d,]*)\][^ ]*\s+"
+    r"convert\((?:(\w+)\[[^ ]*\s+)?%?([\w.\-]+)\)")
+_DEF_DTYPE_RE = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\w+)\[")
 _COMPUTATION_HEADER_RE = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s*(?:\()")
 
 
@@ -297,25 +299,39 @@ def parse_upcasts(hlo_text: str, min_bytes: int = 0) -> List[ConvertOp]:
     """Widening converts (bf16/f16 -> f32/f64) with result bytes >=
     min_bytes, in optimized HLO.
 
-    Only TOP-LEVEL converts (entry / while-body / conditional computations)
-    count: a convert inside a ``%fused_computation`` body is elementwise
-    inside one kernel and never materializes the f32 buffer — flagging it
-    would indict every fused softmax/grad cast a bf16 model intends.
+    Only converts whose RESULT is a buffer count: one at the top level
+    (entry / while-body / conditional computations), or one that is the
+    ROOT of a fusion (0.9's CPU pipeline wraps a lone convert in a
+    ``%wrapped_convert_computation``: the fusion's output IS the widened
+    copy). A convert in the middle of a ``%fused_computation`` body is
+    elementwise inside one kernel and never materializes the f32 buffer —
+    flagging it would indict every fused softmax/grad cast a bf16 model
+    intends. The operand's dtype is read off the line where the text
+    carries it and off the operand's definition where it does not (0.9
+    prints operands by name only).
     """
     out = []
     in_fusion = False
+    narrow: Dict[str, str] = {}   # instruction name -> "bf16" | "f16"
     for line in hlo_text.splitlines():
         if not line.startswith(" "):  # computation header at column 0
             m = _COMPUTATION_HEADER_RE.match(line)
             if m:
-                in_fusion = "fused_" in m.group(2)
+                in_fusion = ("fused_" in m.group(2)
+                             or m.group(2).startswith("wrapped_"))
             continue
-        if in_fusion:
+        d = _DEF_DTYPE_RE.match(line)
+        if d and d.group(2) in ("bf16", "f16"):
+            narrow[d.group(1)] = d.group(2)
+        if " convert(" not in line:
             continue
-        m = _CONVERT_RE.search(line)
-        if not m:
+        m = _CONVERT_RE.match(line)
+        if not m or (in_fusion and not m.group(1)):
             continue
-        to_dt, dims, from_dt = m.groups()
+        _, to_dt, dims, inline_dt, operand = m.groups()
+        from_dt = inline_dt or narrow.get(operand)
+        if from_dt not in ("bf16", "f16"):
+            continue
         nb = shape_bytes(to_dt, dims)
         if nb < min_bytes:
             continue
@@ -477,7 +493,8 @@ class _Liveness:
 
         Returns (buffers: {var: _Buffer}, body_at: {idx: (bytes, breakdown)},
         param_var: {param_number: var}, root: (idx, out_vars) | None,
-        boundary: first backward-stamped instruction index | -1, n_instr).
+        boundary: index of the first backward-stamped instruction that reads
+        a forward temporary | -1, n_instr).
         param_classes None = body computation: parameters are caller-owned
         views and contribute nothing here.
         """
@@ -490,6 +507,7 @@ class _Liveness:
         param_var: Dict[int, str] = {}
         root = None
         boundary = -1
+        bwd_vars = set()
         i = 0
 
         def roots(var: str, _depth: int = 0) -> List[str]:
@@ -532,8 +550,9 @@ class _Liveness:
                 continue
             is_root = bool(m.group(1))
             var, rhs = m.group(2).lstrip("%"), m.group(3)
-            if boundary < 0 and _BWD_MARK_RE.search(line):
-                boundary = i
+            is_bwd = bool(_BWD_MARK_RE.search(line))
+            if is_bwd:
+                bwd_vars.add(var)
             stripped = _strip_attrs(rhs)
             om = _OPCODE_RE.search(stripped)
             opcode = om.group(1) if om else ""
@@ -542,6 +561,13 @@ class _Liveness:
             for op_var in operands:
                 for r in roots(op_var):
                     bufs[r].last = max(bufs[r].last, i)
+                    # the backward STARTS where it first reads a forward
+                    # temporary: backward-stamped leaves (partition-id,
+                    # constants, the zero accumulators broadcast from them)
+                    # are scheduled at the top of the entry computation
+                    if is_bwd and boundary < 0 and r not in bwd_vars \
+                            and not bufs[r].is_param:
+                        boundary = i
             if opcode == "parameter":
                 if param_classes is not None:
                     pm = _PARAM_NUM_RE.search(stripped)

@@ -85,7 +85,7 @@ def analyze_programs(artifacts: List[ProgramArtifacts], config, plan,
         # UNFILTERED overlap census: min_exposed_bytes only exempts
         # control-plane ops from the OverlapAudit gate — the recorded
         # census must match the telemetry join's (min_bytes=0) so
-        # dryrun_multichip and bench.py report comparable numbers
+        # dryrun_multichip and the join report comparable numbers
         report.overlap[art.name] = overlap_summary(overlap_ops)
         if baseline and art.name in baseline.get("census", {}):
             report.extend(compare_census(

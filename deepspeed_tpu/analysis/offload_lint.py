@@ -2,8 +2,7 @@
 
 The capacity tier lives and dies by overlap: a layer-streamed step that
 runs fetch -> compute -> host-Adam -> write-back in sequence pays the full
-storage wire time on top of compute (the BENCH_r05 shape: a 7x
-``offload_cpu_adam_ratio`` with ``capacity_mfu`` 0.0061), while the
+storage wire time on top of compute, while the
 three-way pipeline — read(i+1) || update(i) || write(i-1), double-buffered
 layer fetches in the fwd/bwd walks — hides almost all of it. The reference
 solved exactly this with its pipelined optimizer swapper

@@ -169,25 +169,39 @@ def compare_census(got: Dict[str, Dict[str, int]],
                    source: str) -> List[Finding]:
     """Exact census pin: any drift in collective counts — extra, missing, or
     changed — is an error. `want` values may be plain counts or
-    {"count": n, ...} dicts (baseline form)."""
+    {"count": n, "bytes": b} dicts (baseline form). A pin that carries
+    bytes holds them too: XLA's combiner merges an added reduction into an
+    existing op (one tuple-shaped all-reduce), which moves the bytes and
+    leaves the count alone."""
     findings = []
     want_counts = {k: (v["count"] if isinstance(v, dict) else int(v))
                    for k, v in want.items()}
-    got_counts = {k: c["count"] for k, c in got.items()}
-    for kind in sorted(set(want_counts) | set(got_counts)):
-        w, g = want_counts.get(kind, 0), got_counts.get(kind, 0)
-        if w == g:
+    want_bytes = {k: v["bytes"] for k, v in want.items()
+                  if isinstance(v, dict) and "bytes" in v}
+    for kind in sorted(set(want_counts) | set(got)):
+        c = got.get(kind, {"count": 0, "bytes": 0})
+        w, g = want_counts.get(kind, 0), c["count"]
+        wb, gb = want_bytes.get(kind, c["bytes"]), c["bytes"]
+        if w != g:
+            what = (f"has {g} ({'extra' if g > w else 'missing'} "
+                    f"{abs(g - w)})")
+        elif wb != gb:
+            what = (f"has the {g} op(s) moving {gb} bytes where {wb} are "
+                    "pinned (combined into an existing op, the count hides "
+                    "it)")
+        else:
             continue
-        drift = "extra" if g > w else "missing"
+        more = (g, gb) > (w, wb)
         findings.append(Finding(
             rule="collective-census-drift",
             program=program,
             ident=kind,
-            nbytes=got.get(kind, {}).get("bytes", 0),
+            nbytes=gb,
             message=(f"{kind}: expected {w} per {source}, compiled program "
-                     f"has {g} ({drift} {abs(g - w)}) — a collective was "
-                     f"silently {'added' if g > w else 'removed'}"),
-            data={"expected": w, "got": g, "source": source}))
+                     f"{what} — a collective was silently "
+                     f"{'added' if more else 'removed'}"),
+            data={"expected": w, "got": g, "expected_bytes": wb,
+                  "got_bytes": gb, "source": source}))
     return findings
 
 

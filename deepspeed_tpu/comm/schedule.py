@@ -1,10 +1,9 @@
-"""Collective scheduling: deferred gradient sync + hierarchical reduction.
+"""Collective scheduling: deferred gradient sync.
 
 Reference: ``runtime/zero/stage_1_and_2.py`` — DeepSpeed's headline ZeRO
 throughput comes as much from *when* collectives run as from sharding
 itself: ``overlap_comm`` overlaps grad reduction with backward compute,
-``no_sync`` defers it across accumulation boundaries, and the hierarchical
-all-reduce splits a flat ring into intra-node + inter-node phases.
+``no_sync`` defers it across accumulation boundaries.
 
 TPU-native design: GSPMD owns collective *placement*, so scheduling policy
 is expressed structurally —
@@ -18,15 +17,6 @@ is expressed structurally —
   boundary produces exactly the reduction the eager path spreads over every
   microbatch. Stage-1/2 dp-sync collective counts become independent of
   ``gradient_accumulation_steps`` (DeepSpeed ``no_sync`` semantics).
-
-* **hierarchical reduction** (``comm.hierarchical_grad_reduce``): on
-  ``data x fsdp`` meshes the dp grad mean decomposes into an fsdp-axis
-  reduce-scatter (inner, fast ICI ring, full payload) followed by a
-  data-axis all-reduce of the *sharded* buffer (outer ring, 1/fsdp of the
-  bytes). Expressed as sharding-constraint hints: the accumulator is pinned
-  to an fsdp-sharded spec before the data-axis reduction, so GSPMD must
-  realize the two phases separately. The analysis census pins the result
-  exactly for the MULTICHIP mesh plans.
 
 Everything here is pure spec/tree surgery plus the in-``shard_map``
 boundary reduction; the engine wires it into the dense GSPMD step, the
@@ -42,7 +32,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 DATA_AXIS = "data"
-FSDP_AXIS = "fsdp"
 
 
 def shard_map_compat(f, mesh, *, in_specs, out_specs, manual_axes):
@@ -75,11 +64,6 @@ def _from_entries(entries) -> P:
     return P(*out)
 
 
-def spec_axes(spec: P):
-    """All mesh axis names a spec references."""
-    return {a for e in _entries(spec) for a in e}
-
-
 def axis_dim(spec: P, axis: str) -> Optional[int]:
     """Dim index carrying `axis`, or None."""
     for i, e in enumerate(_entries(spec)):
@@ -99,40 +83,6 @@ def local_tree(spec_tree, axis: str = DATA_AXIS):
     """grad_specs -> their local (manual-over-`axis`) counterparts."""
     return jax.tree.map(lambda s: drop_axis(s, axis), spec_tree,
                         is_leaf=lambda x: isinstance(x, P))
-
-
-def hierarchical_spec(grad_spec: P, shape: Tuple[int, ...], plan) -> P:
-    """Intermediate fsdp-sharded spec for one grad leaf: the buffer the
-    data-axis phase of the hierarchical reduction operates on.
-
-    Leaves already fsdp-sharded (stage 3) keep their spec — the
-    decomposition is inherent there. Otherwise shard the largest dim that
-    is unsharded and divisible by the fsdp degree; leaves where nothing
-    divides stay as-is (tiny tensors ride the flat reduction).
-    """
-    if plan.fsdp <= 1 or FSDP_AXIS in spec_axes(grad_spec):
-        return grad_spec
-    sizes = plan.axis_sizes()
-    entries = _entries(grad_spec)
-    while len(entries) < len(shape):
-        entries.append(())
-    best_dim, best_size = -1, 0
-    for i, dim in enumerate(shape):
-        denom = int(np.prod([sizes.get(a, 1) for a in entries[i]])) \
-            if entries[i] else 1
-        local = dim // denom if denom and dim % denom == 0 else 0
-        if local and local % plan.fsdp == 0 and local > best_size:
-            best_dim, best_size = i, local
-    if best_dim < 0:
-        return grad_spec
-    entries[best_dim] = entries[best_dim] + (FSDP_AXIS,)
-    return _from_entries(entries)
-
-
-def hierarchical_tree(grad_specs, shape_tree, plan):
-    return jax.tree.map(
-        lambda s, sh: hierarchical_spec(s, tuple(sh), plan),
-        grad_specs, shape_tree, is_leaf=lambda x: isinstance(x, P))
 
 
 def deferred_supported(plan) -> Tuple[bool, str]:

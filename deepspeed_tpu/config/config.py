@@ -241,11 +241,6 @@ class CommConfig(ConfigModel):
     # independent of gradient_accumulation_steps. Costs a full-size (not
     # 1/dp) grad accumulator per device under stage 2.
     deferred_grad_sync: bool = False
-    # on data x fsdp meshes, decompose the dp grad mean into an fsdp-axis
-    # reduce-scatter followed by a data-axis all-reduce of the SHARDED
-    # buffer: the big payload stays on the inner (fast) axis, the outer
-    # axis moves 1/fsdp of the bytes
-    hierarchical_grad_reduce: bool = False
     # 0 = lax.scan microbatch loop (one static collective site, compile time
     # independent of gas); K >= gas = fully unrolled microbatches (the
     # latency-hiding scheduler can overlap microbatch i's reduction with
@@ -428,8 +423,11 @@ class AnalysisConfig(ConfigModel):
     # overflow flags), exempt from the kind policy
     min_collective_bytes: int = 1024
     # exact census pin {op-kind: count}; any drift is an error. Empty = kind
-    # policy only (see analysis/expectations.py)
-    expect_collectives: Dict[str, int] = config_field({})
+    # policy only (see analysis/expectations.py). A value may also be
+    # {"count": n, "bytes": b} (what the report's census prints): the bytes
+    # are then held too, which is what catches a reduction XLA combined
+    # into an existing op
+    expect_collectives: Dict[str, Any] = config_field({})
     min_donation_bytes: int = 1024
     min_upcast_bytes: int = 1 << 20
     min_replicated_bytes: int = 1 << 20
@@ -522,15 +520,6 @@ class TransformerTuningConfig(ConfigModel):
     # the delta epilogue runs inside the backward grids; removes the XLA
     # delta pass + its [B,N,S,1] HBM round-trip per layer per step
     fused_backward: bool = False
-    # chunked TP collective-matmul overlap: row-parallel out-projections
-    # decompose the tensor-axis reduction into this many independent psums
-    # the latency-hiding scheduler can interleave with the next chunk's
-    # matmul. 0/1 = off; no-op without a tensor mesh axis.
-    tp_overlap_chunks: int = 0
-
-    def validate(self):
-        if self.tp_overlap_chunks < 0:
-            raise ConfigError("transformer.tp_overlap_chunks must be >= 0")
 
 
 @dataclasses.dataclass

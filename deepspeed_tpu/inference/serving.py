@@ -149,10 +149,8 @@ def measure_paged_backends(mcfg, k_pool, v_pool, *, max_seqs: int, MB: int,
     blocks scattered through the pool (a fresh pool's identity layout
     would flatter the gather). Returns (xla_ms, pallas_ms).
 
-    ONE recipe shared by ServingEngine._select_backend (real pools at
-    engine init) and bench._paged_backend_microbench (synthetic bf16
-    pools when the headline pool is int8) — the bench's serve_backend_*
-    evidence stays exactly what the engine measures."""
+    One caller: ServingEngine._select_backend, on the engine's real pools
+    at init (``decode_backend="auto"`` on a float pool)."""
     import contextlib
     import jax
     import jax.numpy as jnp

@@ -141,12 +141,6 @@ class TransformerConfig:
     # delta pass between the forward and the dQ/dKV kernels. Set via the
     # engine's `transformer.fused_backward` config section.
     fused_backward: bool = False
-    # chunked tensor-parallel collective-matmul overlap: the row-parallel
-    # out-projections (wo, w_out) decompose their tensor-axis reduction
-    # into this many independent psums so the latency-hiding scheduler can
-    # run chunk i's wire time under chunk i+1's matmul. 0/1 = off. Set via
-    # `transformer.tp_overlap_chunks`.
-    tp_overlap_chunks: int = 0
     # Random-LTD (reference: runtime/data_pipeline/data_routing/basic_layer.py
     # RandomLayerTokenDrop): middle layers process a random kept-token subset
     # during training. random_ltd_keep is a SHAPE (static); the engine's
@@ -442,16 +436,6 @@ def _constrain_batch_axes(x):
     if seq_ax and x.shape[1] % shape["seq"]:
         seq_ax = None
     return jax.lax.with_sharding_constraint(x, P(batch, seq_ax))
-
-
-def _row_parallel(x, w, cfg: TransformerConfig):
-    """Row-parallel out-projection: the chunked collective-matmul overlap
-    path when `transformer.tp_overlap_chunks` is set and a tensor axis is
-    active, the plain matmul otherwise (identical numerics either way)."""
-    if cfg.tp_overlap_chunks and cfg.tp_overlap_chunks > 1:
-        from deepspeed_tpu.parallel.partitioning import row_parallel_matmul
-        return row_parallel_matmul(x, w, chunks=cfg.tp_overlap_chunks)
-    return x @ w
 
 
 def _norm(x, scale, bias, cfg: TransformerConfig):
@@ -1148,16 +1132,16 @@ def _wmat(h, w):
     return h @ w.astype(h.dtype)
 
 
-def _wrow(x, w, cfg: TransformerConfig):
+def _wrow(x, w):
     """Row-parallel twin of ``_wmat``: the per-out-channel scale factors
     out of the contraction, so it applies AFTER the tensor-axis reduction
     (the out columns of wo/w_out are unsharded under the Megatron rules —
     one replicated row multiply, exact)."""
     if isinstance(w, dict):
-        y = _row_parallel(x, w["q"].astype(x.dtype), cfg)
+        y = x @ w["q"].astype(x.dtype)
         return y * jnp.reshape(w["scale"],
                                w["scale"].shape[-1:]).astype(x.dtype)
-    return _row_parallel(x, w.astype(x.dtype), cfg)
+    return x @ w.astype(x.dtype)
 
 
 def _lora_delta(h, ab, idx):
@@ -1424,7 +1408,7 @@ def transformer_layer(x, layer_params, cfg: TransformerConfig, mask=None,
             attn_out = attention(q, k, v, mask=mask, causal=cfg.causal,
                                  cfg=cfg, window=attn_window)
     attn_flat = attn_out.reshape(B, S, nh * hd)
-    attn_out = _wrow(attn_flat, p["wo"], cfg)
+    attn_out = _wrow(attn_flat, p["wo"])
     if lora is not None and "o" in lora[0]:
         attn_out = attn_out + _lora_delta(attn_flat, lora[0]["o"], lora[1])
     if "bo" in p:
@@ -1483,7 +1467,7 @@ def transformer_layer(x, layer_params, cfg: TransformerConfig, mask=None,
             ug = _wmat(h, p["w_in_gate"])
             half = ug.shape[-1] // 2
             act = _activation(ug[..., :half], ug[..., half:], cfg)
-            out = _wrow(act, p["w_out"], cfg)
+            out = _wrow(act, p["w_out"])
             if "b_out" in p:
                 out = out + p["b_out"].astype(h.dtype)
     else:
@@ -1493,7 +1477,7 @@ def transformer_layer(x, layer_params, cfg: TransformerConfig, mask=None,
                 up = up + p["b_in"].astype(h.dtype)
             gate = _wmat(h, p["w_gate"]) if "w_gate" in p else None
             act = _activation(up, gate, cfg)
-            out = _wrow(act, p["w_out"], cfg)
+            out = _wrow(act, p["w_out"])
             if "b_out" in p:
                 out = out + p["b_out"].astype(h.dtype)
     if cfg.parallel_block:

@@ -175,24 +175,20 @@ class Engine:
         # --- model-level perf levers (`transformer` config section):
         # applied with the act-quant rebuild idiom — dataclasses.replace +
         # make_model keeps the param structure identical; only the compute
-        # path (fused attention backward, chunked TP collective overlap)
-        # changes. Runs BEFORE pipeline wrapping so staged models get the
-        # same levers.
+        # path (fused attention backward) changes. Runs BEFORE pipeline
+        # wrapping so staged models get the same levers.
         tcfg = config.transformer
-        if tcfg.fused_backward or tcfg.tp_overlap_chunks > 1:
+        if tcfg.fused_backward:
             from deepspeed_tpu.models.transformer import (
                 TransformerConfig as _TC)
             if isinstance(getattr(model, "config", None), _TC):
                 from deepspeed_tpu.models import make_model as _mk
                 model = _mk(dataclasses.replace(
-                    model.config, fused_backward=tcfg.fused_backward,
-                    tp_overlap_chunks=int(tcfg.tp_overlap_chunks)),
+                    model.config, fused_backward=tcfg.fused_backward),
                     name=model.name)
                 self.model = model
-                logger.info(
-                    "transformer tuning: fused_backward="
-                    f"{tcfg.fused_backward} tp_overlap_chunks="
-                    f"{tcfg.tp_overlap_chunks}")
+                logger.info("transformer tuning: fused_backward="
+                            f"{tcfg.fused_backward}")
             else:
                 logger.warning("`transformer` config section ignored: model "
                                "is not a transformer ModelSpec")
@@ -227,7 +223,6 @@ class Engine:
         self._rng = rng if rng is not None else jax.random.PRNGKey(config.seed)
         param_shapes = jax.eval_shape(model.init, self._rng)
         shape_tree = jax.tree.map(lambda s: s.shape, param_shapes)
-        self._shape_tree = shape_tree  # comm.schedule needs divisibility info
         self.param_specs = jax.tree.map(
             lambda spec, sh: zero_mod.zero_param_spec(spec, sh, self.plan, zero_cfg),
             base_specs, shape_tree, is_leaf=lambda x: isinstance(x, P))
@@ -1026,22 +1021,14 @@ class Engine:
         tel_on = self._tel_in_graph
         tel_ratio = tel_on and cfg.telemetry.update_ratio
 
-        # --- communication scheduling (comm.schedule: deferred grad sync +
-        # hierarchical 2D-mesh reduction; reference: overlap_comm /
-        # contiguous_gradients / no_sync in runtime/zero/stage_1_and_2.py)
+        # --- communication scheduling (comm.schedule: deferred grad sync;
+        # reference: overlap_comm / contiguous_gradients / no_sync in
+        # runtime/zero/stage_1_and_2.py)
         from deepspeed_tpu.comm import schedule as comm_sched
         ccfg = cfg.comm
         unroll = max(0, int(ccfg.microbatch_unroll))
         self._microbatch_unroll = unroll  # one derivation; onebit reads it
         self._deferred_sync = False
-        self._hier_reduce = False
-        if ccfg.hierarchical_grad_reduce:
-            if self.plan.data > 1 and self.plan.fsdp > 1:
-                self._hier_reduce = True
-            else:
-                logger.info("comm.hierarchical_grad_reduce is a no-op: needs "
-                            "a 2D data x fsdp mesh "
-                            f"(have {self.plan.describe()})")
         if ccfg.deferred_grad_sync:
             if self._onebit_comm:
                 logger.info(
@@ -1067,22 +1054,13 @@ class Engine:
                     logger.info(
                         "comm.deferred_grad_sync: microbatch grads "
                         "accumulate in a per-device local buffer; ONE "
-                        f"data-axis sync per step (gas={gas})"
-                        + (", hierarchical fsdp-phase reduction"
-                           if self._hier_reduce else ""))
-        # accumulator target specs: the hierarchical hint pins the fsdp-
-        # sharded intermediate the data-axis phase operates on
-        acc_specs = self.grad_specs
-        if self._hier_reduce:
-            acc_specs = comm_sched.hierarchical_tree(
-                self.grad_specs, self._shape_tree, self.plan)
+                        f"data-axis sync per step (gas={gas})")
         deferred = self._deferred_sync
-        hier = self._hier_reduce
         plan = self.plan
         local_acc_specs = None
         deferred_unroll = unroll
         if deferred:
-            local_ = comm_sched.local_tree(acc_specs)
+            local_ = comm_sched.local_tree(self.grad_specs)
             if any(len(s) for s in jax.tree.leaves(
                     local_, is_leaf=lambda x: isinstance(x, P))):
                 local_acc_specs = local_
@@ -1220,17 +1198,11 @@ class Engine:
             else:
                 grads, mean_loss = self._accum_micro_grads(
                     lambda p, mb, r: micro_grads(p, mb, r, scale,
-                                                 step=state["step"],
-                                                 specs=acc_specs),
+                                                 step=state["step"]),
                     params, batch, gas, rng,
                     postprocess=lambda t: jax.lax.with_sharding_constraint(
-                        t, acc_specs),
+                        t, self.grad_specs),
                     unroll=unroll)
-                if hier:
-                    # phase 2 hint: the fsdp-sharded buffer resharded onto
-                    # the final grad placement
-                    grads = jax.lax.with_sharding_constraint(
-                        grads, self.grad_specs)
             if fp16:
                 mean_loss = mean_loss / scale
             return mean_loss, grads

@@ -1,7 +1,7 @@
 """Where the persistent XLA compilation cache lives.
 
 One rule, for every entry point that wants compiled programs to survive the
-process (``chip_smoke.py``, ``bench.py``, the test suite's opt-in):
+process (``chip_smoke.py``, ``benchmark/run.py``):
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: do nothing. JAX reads the variable
   itself, and no code path here sets another directory — whoever runs the

@@ -73,6 +73,19 @@ def devices8():
     return devs
 
 
+@pytest.fixture(scope="session")
+def topo():
+    """A v5e 2x2 host, described with no chip attached: programs compile
+    for its devices through the TPU's own pipeline and never run."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu from loading
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
 @pytest.fixture()
 def rng():
     return jax.random.PRNGKey(0)

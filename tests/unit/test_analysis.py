@@ -269,6 +269,30 @@ class TestMemoryParsers:
         assert est.peak_bytes < (1 << 22) + (1 << 22) + (1 << 22)
         assert est.peak_bytes >= (1 << 22) + (1 << 22)
 
+    def test_boundary_skips_hoisted_backward_leaves(self):
+        """The fwd/bwd boundary is where the backward first READS a forward
+        temporary: 0.9 schedules backward-stamped leaves (partition-id,
+        constants, zero accumulators) at the top of the entry computation,
+        before any forward op."""
+        bwd = ', metadata={op_name="jit(s)/transpose(jvp(f))/mul"}'
+        hlo = "\n".join([
+            "HloModule jit_s, is_scheduled=true",
+            "",
+            "ENTRY %main (p0: f32[1024,1024]) -> f32[1024,1024] {",
+            "  %pid = u32[] partition-id()" + bwd,
+            "  %zero = f32[1024,1024]{1,0} broadcast(%pid)" + bwd,
+            "  %p0 = f32[1024,1024]{1,0} parameter(0)",
+            "  %act = f32[1024,1024]{1,0} tanh(%p0)",
+            "  %out = f32[1024,1024]{1,0} exponential(%act)",
+            "  %g = f32[1024,1024]{1,0} multiply(%act, %zero)" + bwd,
+            "  ROOT %r = f32[1024,1024]{1,0} add(%g, %out)" + bwd,
+            "}",
+        ])
+        est = estimate_peak_hbm(hlo, param_classes={0: "params"})
+        assert est.boundary_index == 5           # %g, not %pid
+        # live across it: %act, %out and the hoisted accumulator %zero
+        assert est.boundary_bytes == 3 * (1 << 22)
+
     def test_remat_census_markers(self):
         hlo = "\n".join([
             '  %f = f32[4]{0} fusion(%x), metadata={op_name="jit(s)/'
@@ -352,6 +376,15 @@ class TestSeededCorpus:
         assert not report.ok, f"{name}: seeded violation not flagged"
         rules = {f.rule for f in report.findings}
         assert _CORPUS_RULES[name] in rules, (name, rules)
+
+    @pytest.mark.parametrize("name", ["extra-collective", "f32-upcast",
+                                      "telemetry-leak"])
+    def test_defect_free_twin_is_ok(self, name, devices8):
+        """The same program without its planted defect, under the same pin
+        and settings: the analyzer tells the two apart."""
+        from deepspeed_tpu.analysis.corpus import CORPUS
+        report = CORPUS[name](devices8[:2], seeded=False)
+        assert report.ok and not report.findings, report.summary()
 
     def test_deferred_sync_regression_reports_exposed(self, devices8):
         """The gas=4 per-microbatch reduce-scatter corpus entry must be
@@ -445,9 +478,11 @@ class TestSeededCorpus:
 # {ar 41, ag 22, a2a 2} / {ag 45, ar 30, a2a 17}: 0.9's CPU pipeline
 # combines the per-parameter grad all-reduces (41 -> 4), so a single extra
 # all-reduce now merges into a combined one and no longer moves the COUNT
-# (see test_extra_allreduce_in_model_fails_pin, ROADMAP D9).
+# (see test_extra_allreduce_in_model_fails_pin)...
 STAGE2_CENSUS = {"all-reduce": 4, "all-gather": 20}
 STAGE3_CENSUS = {"all-gather": 19, "all-reduce": 4, "all-to-all": 6}
+# ...so the stage-2 pin holds the four all-reduces' BYTES beside their count
+STAGE2_PIN = {**STAGE2_CENSUS, "all-reduce": {"count": 4, "bytes": 69592}}
 
 
 class TestCleanConfigs:
@@ -462,10 +497,11 @@ class TestCleanConfigs:
     def test_stage2_vs_stage3_census_pinned(self, devices8):
         """The collective-audit acceptance gate: exact counts per stage on a
         2-device mesh; an extra (or vanished) collective is a hard failure."""
-        for stage, axes, want in ((2, {"data": 2}, STAGE2_CENSUS),
-                                  (3, {"fsdp": 2}, STAGE3_CENSUS)):
+        for stage, axes, pin, want in (
+                (2, {"data": 2}, STAGE2_PIN, STAGE2_CENSUS),
+                (3, {"fsdp": 2}, STAGE3_CENSUS, STAGE3_CENSUS)):
             report = audit_stage(stage, axes, devices=devices8[:2],
-                                 analysis={"expect_collectives": want})
+                                 analysis={"expect_collectives": pin})
             assert report.ok, f"stage {stage}:\n{report.summary()}"
             got = {k: c["count"]
                    for k, c in report.census["train_step"].items()}
@@ -498,17 +534,22 @@ class TestCleanConfigs:
     def test_extra_allreduce_in_model_fails_pin(self, devices8):
         """A model-level silently-added cross-replica reduction must break
         the stage-2 pin — the reference's unnoticeable extra allreduce is a
-        hard failure here."""
+        hard failure here. XLA combines it into one of the four existing
+        all-reduces, so it is the pinned BYTES that move (+64: the [16]
+        f32 statistic); the clean model passes the same pin
+        (test_stage2_vs_stage3_census_pinned)."""
         from deepspeed_tpu.analysis.corpus import NoisyLossModel
         report = audit_stage(
             2, {"data": 2}, model=NoisyLossModel(tiny_model()),
             devices=devices8[:2],
-            analysis={"expect_collectives": STAGE2_CENSUS})
+            analysis={"expect_collectives": STAGE2_PIN})
         assert not report.ok
         drift = [f for f in report.findings
                  if f.rule == "collective-census-drift"
-                 and f.data["got"] > f.data["expected"]]
+                 and f.ident == "all-reduce"]
         assert drift, report.summary()
+        assert drift[0].data["got_bytes"] \
+            == drift[0].data["expected_bytes"] + 16 * 4
 
     def test_donation_covers_whole_state(self, devices8):
         """Every param/optimizer buffer of the stage-2 step aliases an
@@ -557,7 +598,7 @@ class TestMemoryLintEngine:
     def test_audit_reports_peak_with_class_breakdown(self, devices8):
         """engine.audit() must report per-program peak_hbm_bytes with the
         params/grads/opt/activations breakdown (the acceptance surface
-        bench.py and the CLI JSON expose)."""
+        the CLI JSON exposes)."""
         report = cached_audit(2, {"data": 2}, devices8[:2])
         mem = report.memory["train_step"]
         assert mem["peak_hbm_bytes"] > 0

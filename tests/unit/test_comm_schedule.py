@@ -5,12 +5,11 @@ Pins the ISSUE-4 tentpole contracts:
     identically to the per-microbatch path over 20 fp16 steps with a forced
     overflow at step 7 (mirroring test_dataloader_prefetch's parity idiom),
     across ZeRO stages 1/2/3 on a 2-dev mesh, including the fused K-step
-    program and the hierarchical 2D-mesh reduction;
+    program and a data=2 x fsdp=4 mesh;
   * the stage-2 collective census is INDEPENDENT of
     gradient_accumulation_steps when deferral is on (exact pin), and the
     per-microbatch grad sync scales exactly gas x when it is off
     (microbatch-unrolled lowering makes each sync a distinct static site);
-  * the hierarchical data=2 x fsdp=4 reduction census is pinned exactly;
   * the overlap analyzer classifies scheduled collectives as
     overlapped/exposed and gates on analysis.max_exposed_collectives;
   * the 1/gas scaling is folded into the scan accumulator update — no
@@ -162,24 +161,22 @@ class TestDeferredParity:
         assert fused.skipped_steps == ref.skipped_steps == 1
         np.testing.assert_array_equal(w_bits(ref), w_bits(fused))
 
-    def test_hierarchical_2d_bit_for_bit(self, devices8):
-        """data=2 x fsdp=4: deferred + hierarchical reduction (fsdp-phase
-        reduce-scatter, data-phase all-reduce) trains bit-identically."""
+    def test_deferred_2d_bit_for_bit(self, devices8):
+        """data=2 x fsdp=4: the deferred region is manual over `data` only
+        and unrolls its microbatch loop beside the auto fsdp axis; it
+        trains bit-identically to the eager path."""
         batches = int_batches(n=10, boost_at=3)
         axes = {"data": 2, "fsdp": 4}
         eager, *_ = deepspeed_tpu.initialize(
             model=IntLinearMean(), config=fp16_cfg(2, axes, False, gas=2),
             devices=devices8)
-        hier, *_ = deepspeed_tpu.initialize(
+        deferred, *_ = deepspeed_tpu.initialize(
             model=IntLinearMean(),
-            config=fp16_cfg(2, axes, True, gas=2,
-                            comm={"deferred_grad_sync": True,
-                                  "hierarchical_grad_reduce": True}),
-            devices=devices8)
+            config=fp16_cfg(2, axes, True, gas=2), devices=devices8)
         run_steps(eager, batches)
-        run_steps(hier, batches)
-        assert eager.skipped_steps == hier.skipped_steps == 1
-        np.testing.assert_array_equal(w_bits(eager), w_bits(hier))
+        run_steps(deferred, batches)
+        assert eager.skipped_steps == deferred.skipped_steps == 1
+        np.testing.assert_array_equal(w_bits(eager), w_bits(deferred))
 
     def test_quadratic_first_step_bitwise(self, devices8):
         """Grad computation parity at exact (integer) params: the very first
@@ -231,7 +228,7 @@ STAGE2_EAGER_GAS1_AR = 4        # = test_analysis.STAGE2_CENSUS["all-reduce"]
 EAGER_AR_PER_MB = 1             # per-microbatch grad sync all-reduces
 
 
-def census_of(stage, axes, devices, gas, *, deferred, unroll=0, hier=False,
+def census_of(stage, axes, devices, gas, *, deferred, unroll=0,
               expect=None, fuse=0):
     cfg = {"train_batch_size": 16,
            "gradient_accumulation_steps": gas,
@@ -241,7 +238,6 @@ def census_of(stage, axes, devices, gas, *, deferred, unroll=0, hier=False,
                                  "stage3_param_persistence_threshold": 0},
            "mesh": {"axes": axes},
            "comm": {"deferred_grad_sync": deferred,
-                    "hierarchical_grad_reduce": hier,
                     "microbatch_unroll": unroll},
            "steps_per_print": 100}
     if fuse:
@@ -307,22 +303,6 @@ class TestDeferredCensus:
         assert single == STAGE2_DEFERRED_CENSUS
         assert fused == {k: 2 * v
                          for k, v in STAGE2_DEFERRED_CENSUS.items()}, fused
-
-    def test_hierarchical_2d_census_pinned(self, devices8):
-        """Exact pin for the hierarchical data=2 x fsdp=4 reduction (the
-        MULTICHIP mesh plan): the deferred boundary runs an fsdp-phase
-        reduce-scatter and the data-axis phase operates on the sharded
-        buffer. An unexplained shift here is a comm-schedule regression."""
-        rep = census_of(3, {"data": 2, "fsdp": 4}, devices8, 1,
-                        deferred=True, hier=True)
-        assert rep.ok, rep.summary()
-        got = {k: c["count"] for k, c in rep.census["train_step"].items()}
-        want = {"all-reduce": 59, "all-gather": 61, "all-to-all": 7,
-                "reduce-scatter": 20, "collective-permute": 11}
-        assert got == want, got
-        # the decomposition's signature: explicit reduce-scatter sites AND
-        # data-axis all-reduces coexist
-        assert got["reduce-scatter"] >= 20 and got["all-reduce"] > 0
 
 
 # --------------------------------------------------------------------------
@@ -506,18 +486,6 @@ class TestScheduleSpecs:
         assert comm_sched.axis_dim(P(None, "data"), "data") == 1
         assert comm_sched.axis_dim(P(("data", "fsdp")), "fsdp") == 0
         assert comm_sched.axis_dim(P("fsdp"), "data") is None
-
-    def test_hierarchical_spec(self):
-        from deepspeed_tpu.parallel.mesh import MeshPlan
-        plan = MeshPlan(data=2, fsdp=4)
-        # already fsdp-sharded (stage 3): unchanged
-        assert comm_sched.hierarchical_spec(P("fsdp", "data"), (8, 8), plan) \
-            == P("fsdp", "data")
-        # unsharded dim divisible by fsdp gains the intermediate
-        assert comm_sched.hierarchical_spec(P("data", None), (8, 8), plan) \
-            == P("data", "fsdp")
-        # nothing divides -> unchanged (tiny tensors ride the flat path)
-        assert comm_sched.hierarchical_spec(P(), (3,), plan) == P()
 
     def test_deferred_supported_gates(self):
         from deepspeed_tpu.parallel.mesh import MeshPlan

@@ -266,7 +266,11 @@ class TestServingDoctor:
         assert not bad.ok
         f = next(f for f in bad.findings if f.rule == "tracing-sync-leak")
         assert f.ident == "device-syncs"
-        assert doctor.audit_tracing(leaky=False).ok
+        good = doctor.audit_tracing(leaky=False)
+        assert good.ok and good.findings == []
+        # the gate is the sync count; the overhead is reported, not gated
+        assert good.meta["serving"]["trace_device_syncs"] == 0
+        assert "serve_trace_overhead_pct" in good.meta["serving"]
 
     def test_gate_fails_closed_when_unpriced(self):
         from deepspeed_tpu.profiling import doctor

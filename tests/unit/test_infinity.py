@@ -137,9 +137,8 @@ class TestInfinityExecutor:
         engine._infinity_exec.close()
 
     def test_measure_decomposition_reports_positive_times(self, tmp_path):
-        """The capacity rung's transfer-vs-compute decomposition (bench.py
-        emits it as offload_dma_ms/offload_compute_ms + overlap fraction):
-        both probes measure real work and the per-step scaling is 2L chunk
+        """The capacity rung's transfer-vs-compute decomposition
+        (offload_dma_ms/offload_compute_ms + overlap fraction): both probes measure real work and the per-step scaling is 2L chunk
         DMAs (fwd+bwd fetch) x L layer fwd+bwd computations."""
         engine, *_ = deepspeed_tpu.initialize(model=_model(),
                                               config=_cfg_dict(tmp_path))

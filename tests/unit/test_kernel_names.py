@@ -7,8 +7,9 @@ and dK/dV kernels cannot be told apart. ``name=`` puts the kernel's own
 name innermost: in the lowered text's locations (checked here on the CPU,
 interpret mode) and — the reading that matters — in the instruction names
 of the program the TPU's compiler builds, on one chip AND mapped over a
-2x2 mesh (compiled here for a described v5e, no chip attached; all in this
-one file, behind a fixture, so only this file's worker loads libtpu).
+2x2 mesh (compiled here for a described v5e, no chip attached, behind the
+``topo`` fixture of tests/conftest.py, so only a worker that runs such a
+test loads libtpu).
 """
 
 import re
@@ -70,18 +71,6 @@ def test_sparse_kernel_names_in_the_lowered_text():
 
 
 # ---- the instruction names the TPU's compiler gives -------------------------
-
-@pytest.fixture(scope="module")
-def topo():
-    import os
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from jax.experimental import topologies
-    try:
-        return topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu from loading
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-
 
 @pytest.fixture()
 def mosaic(monkeypatch):

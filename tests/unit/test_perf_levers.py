@@ -1,5 +1,5 @@
-"""ISSUE-8 perf levers: fused attention backward, chunked TP overlap,
-tied-embedding head fix, serialized-backward corpus, comms census summary.
+"""ISSUE-8 perf levers: fused attention backward, tied-embedding head fix,
+comms census summary.
 
 Pins the tentpole contracts:
   * the tied-embedding lm_head (lm_head_logits: dot_general on the
@@ -10,23 +10,15 @@ Pins the tentpole contracts:
     backward Pallas grids) is BIT-FOR-BIT identical to the unfused path —
     kernel-level, and end-to-end over 20 fp16 engine steps with a forced
     overflow across ZeRO stages 1/3 (test_comm_schedule methodology);
-  * `parallel.partitioning.row_parallel_matmul` (chunked collective-matmul
-    overlap) is bit-identical to the plain matmul on a tensor mesh, falls
-    back cleanly off-mesh, and the engine-level `transformer.
-    tp_overlap_chunks` path trains bit-for-bit vs the unchunked path;
   * the `dots_and_attn` remat policy saves the flash kernel's named
     outputs across the fwd/bwd boundary — the backward stops replaying the
     online-softmax forward (pallas_call count drops);
-  * corpus `serialized-backward` fires census-drift + collective-exposed
-    from `lint --corpus` and exposed-collective-measured from
-    `doctor --corpus`, while the correctly-chunked twin passes the census;
   * `comm.log_summary(engine=)` reports the GSPMD census of the real
     compiled train step (kinds + bytes) next to the trace-time totals.
 
-Bit-parity methodology: both fused-backward and chunked-TP REORDER nothing
-— the fused grids compute the same f32 delta the XLA pass computed, and
-each chunked output element sums the same per-shard partials in the same
-order — so parity is exact, not approximate. The forced overflow at step 7
+Bit-parity methodology: fused-backward REORDERS nothing — the fused grids
+compute the same f32 delta the XLA pass computed — so parity is exact, not
+approximate. The forced overflow at step 7
 pokes the live loss scale to 2^24: the engine trains the model in fp16, so
 scaled grads (~scale x O(1)) blow past fp16's 65504 max and go non-finite
 deterministically, then the backoff halves the scale each skipped step
@@ -223,59 +215,6 @@ class TestFusedBackwardKernel:
 
 
 # --------------------------------------------------------------------------
-# chunked TP collective-matmul overlap
-# --------------------------------------------------------------------------
-
-class TestRowParallelMatmul:
-    def test_bitwise_on_tensor_mesh(self, devices8):
-        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        from deepspeed_tpu.parallel.partitioning import row_parallel_matmul
-        mesh = Mesh(np.array(devices8[:2]), ("tensor",))
-        rng = np.random.default_rng(0)
-        x = jnp.asarray(rng.standard_normal((2, 64, 32)), jnp.float32)
-        w = jax.device_put(
-            jnp.asarray(rng.standard_normal((32, 16)), jnp.float32),
-            NamedSharding(mesh, P("tensor", None)))
-        with mesh:
-            plain = jax.jit(lambda x, w: x @ w)(x, w)
-            chunked = jax.jit(
-                lambda x, w: row_parallel_matmul(x, w, chunks=4))(x, w)
-        assert np.asarray(plain).tobytes() == np.asarray(chunked).tobytes()
-
-    def test_fallback_without_mesh(self):
-        from deepspeed_tpu.parallel.partitioning import row_parallel_matmul
-        x = jnp.ones((2, 8, 4), jnp.float32)
-        w = jnp.ones((4, 4), jnp.float32)
-        out = row_parallel_matmul(x, w, chunks=4)
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(x @ w))
-
-    def test_chunk_census_on_tensor_mesh(self, devices8):
-        """The chunked decomposition compiles to `chunks` independent
-        all-reduces (the serialized twin compiles to ONE) — the census
-        shape the serialized-backward corpus entry pins."""
-        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        from deepspeed_tpu.analysis.hlo_parse import (collective_census,
-                                                      parse_overlap)
-        from deepspeed_tpu.parallel.partitioning import row_parallel_matmul
-        mesh = Mesh(np.array(devices8[:2]), ("tensor",))
-        x_abs = jax.ShapeDtypeStruct((8, 256, 128), jnp.float32)
-        w_abs = jax.ShapeDtypeStruct(
-            (128, 64), jnp.float32,
-            sharding=NamedSharding(mesh, P("tensor", None)))
-
-        def census_of(fn):
-            with mesh:
-                compiled = jax.jit(fn).lower(x_abs, w_abs).compile()
-            return collective_census(parse_overlap(compiled.as_text()))
-
-        serial = census_of(lambda x, w: x @ w)
-        chunked = census_of(
-            lambda x, w: row_parallel_matmul(x, w, chunks=4))
-        assert serial.get("all-reduce", {}).get("count") == 1, serial
-        assert chunked.get("all-reduce", {}).get("count") == 4, chunked
-
-
-# --------------------------------------------------------------------------
 # engine-level bit-for-bit parity (20 fp16 steps, forced overflow)
 # --------------------------------------------------------------------------
 
@@ -283,22 +222,6 @@ class TestEngineParity:
     """Numerics-parity cases: 2 engine builds x 20 fp16 steps each — slow
     tier (tests/run_slow.sh `perf_levers` budget line); the kernel-level
     bitwise pins above stay quick."""
-
-    @pytest.mark.slow
-    @pytest.mark.parametrize("stage", [1, 3])
-    def test_tp_overlap_on_off_bitwise(self, stage, devices8):
-        """transformer.tp_overlap_chunks on/off across ZeRO 1/3 on a
-        data=2 x tensor=2 mesh: 20 fp16 steps, forced overflow at 7."""
-        axes = {"data": 2, "tensor": 2}
-        base = engine_cfg(stage, axes)
-        chunked = engine_cfg(stage, axes,
-                             transformer={"tp_overlap_chunks": 4})
-        pa, pb, oa, ob = run_parity(tiny_tied, base, chunked,
-                                    devices=list(devices8)[:4])
-        # both arms overflow for the same deterministic burst AND recover
-        # (strictly fewer skips than the 13 post-poke steps)
-        assert oa == ob and 1 <= oa <= 12, (oa, ob)
-        assert_params_bitwise(pa, pb)
 
     @pytest.mark.slow
     @pytest.mark.parametrize("stage", [1, 3])
@@ -329,11 +252,9 @@ class TestTransformerTuningConfig:
         engine, *_ = deepspeed_tpu.initialize(
             model=tiny_tied(),
             config=engine_cfg(0, {"data": 1},
-                              transformer={"fused_backward": True,
-                                           "tp_overlap_chunks": 4}),
+                              transformer={"fused_backward": True}),
             devices=list(jax.devices())[:1])
         assert engine.model.config.fused_backward is True
-        assert engine.model.config.tp_overlap_chunks == 4
 
     def test_non_transformer_model_ignored(self):
         class Lin:
@@ -356,31 +277,6 @@ class TestTransformerTuningConfig:
             devices=list(jax.devices())[:1])
         m = engine.train_batch({"x": np.ones((4, 4), np.float32)})
         assert np.isfinite(float(np.asarray(jax.device_get(m["loss"]))))
-
-
-# --------------------------------------------------------------------------
-# serialized-backward corpus (lint + doctor faces)
-# --------------------------------------------------------------------------
-
-class TestSerializedBackwardCorpus:
-    def test_lint_entry_fires_census_and_exposure(self, devices8):
-        from deepspeed_tpu.analysis.corpus import run_corpus
-        report = run_corpus("serialized-backward", devices=devices8[:2])
-        assert not report.ok
-        rules = {f.rule for f in report.findings}
-        assert "collective-census-drift" in rules, rules
-        assert "collective-exposed" in rules, rules
-
-    def test_doctor_entry_fires_measured_gate(self):
-        from deepspeed_tpu.profiling.doctor import run_corpus_entry
-        report = run_corpus_entry("serialized-backward")
-        assert not report.ok
-        assert any(f.rule == "exposed-collective-measured"
-                   for f in report.findings)
-
-    def test_doctor_cli_exits_nonzero(self):
-        from deepspeed_tpu.profiling import doctor
-        assert doctor.main(["--corpus", "serialized-backward"]) != 0
 
 
 # --------------------------------------------------------------------------

@@ -23,9 +23,9 @@ wider type than the pool's (with one query head per kv head the parent
 widened both views to s32, 268 MB each a layer in OLMoE's cell, and
 multiplied them elementwise), and few temporaries.
 
-libtpu is loaded behind a fixture, as in ``test_kernel_names.py``, the
-only other file that describes a chip. Under several workers each of the
-two files' workers loads it; the driver's command allows that
+libtpu is loaded behind a fixture (``topo``, tests/conftest.py), shared
+with ``test_kernel_names.py``, the only other file that describes a chip.
+Under several workers each of the two files' workers loads it; the driver's command allows that
 (``ALLOW_MULTIPLE_LIBTPU_LOAD=1``), and where it is not allowed the later
 file's tests skip, they do not fail.
 """
@@ -42,15 +42,7 @@ from deepspeed_tpu.models import TransformerConfig, make_model
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    import os
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from jax.experimental import topologies
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu from loading
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+def one_chip(topo):
     return jax.sharding.SingleDeviceSharding(topo.devices[0])
 
 

@@ -587,7 +587,9 @@ class TestTelemetryLeakCorpus:
         assert not report.ok
         rules = {f.rule for f in report.findings}
         assert "donation-missing" in rules          # un-donated stats leaf
-        assert "collective-census-drift" in rules   # per-step collective
+        # the per-step collective, seen by its bytes (the defect-free twin:
+        # test_analysis.py::TestSeededCorpus::test_defect_free_twin_is_ok)
+        assert "collective-census-drift" in rules
         leak = next(f for f in report.findings
                     if f.rule == "donation-missing")
         assert "telemetry" in leak.ident
