@@ -80,6 +80,7 @@ import contextlib
 import dataclasses
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional
 
 import numpy as np
@@ -293,6 +294,16 @@ class ServingConfig:
     role: str = "both"                 # prefill | decode | both
 
 
+def _table_ladder(MB: int) -> tuple:
+    """The block-table widths a decode round may be dispatched at: a half,
+    three quarters and the whole of the full width ``MB``, rounded up to
+    whole columns (32 -> 16, 24, 32; 16 -> 8, 12, 16). A constant of the
+    engine's shape, not a setting: one step program is built per entry, and
+    each costs a serve cell ~0.5 s of set-up (why there is no quarter:
+    PERF.md section 6, PR 29)."""
+    return tuple(sorted({-(-MB * q // 4) for q in (2, 3, 4)}))
+
+
 # expert-routing counters of a stats window (ServingEngine._note_expert_load)
 _MOE_COUNTERS = {"kept": 0, "asked": 0, "max_over_mean": 0.0, "rounds": 0,
                  "touched": 0.0, "steps": 0, "prefill_touched": 0.0,
@@ -370,6 +381,7 @@ class ServingEngine:
                 f"{c.block_size}-token block")
         self.max_model_len = want
         self.MB = self.max_model_len // c.block_size     # table width
+        self._table_widths = _table_ladder(self.MB)
         num_blocks = c.num_blocks or (c.max_seqs * self.MB + 1)
         if num_blocks - 1 < self.MB:
             raise ValueError(
@@ -570,6 +582,9 @@ class ServingEngine:
         self._lat = {"spec_steps": 0, "spec_proposed": 0,
                      "spec_accepted": 0, "prefill_chunks": 0,
                      "prefill_chunk_tokens": 0, "cow_forks": 0}
+        # plain decode rounds dispatched per block-table width
+        # (reset_stats windows; _tables_device)
+        self._table_rounds = dict.fromkeys(self._table_widths, 0)
         # reliability bookkeeping ---------------------------------------
         self._counters = {"shed": 0, "deadline_misses": 0, "degraded": 0,
                           "recoveries": 0, "recovery_ms": 0.0,
@@ -892,42 +907,89 @@ class ServingEngine:
             self._prefill_fns[P] = fn
         return fn
 
-    def _get_quantum_step(self):
-        """The single decode step all slots share — compiled once for the
-        pool shape; dispatched `decode_quantum` times back-to-back with no
-        host sync in between (the PR-2 dispatch-window idea). Only the
+    def _quantum_step_fn(self):
+        """The single decode step all slots share, jitted (not yet compiled
+        for any shape): dispatched `decode_quantum` times back-to-back with
+        no host sync in between (the PR-2 dispatch-window idea). Only the
         pools and the length vector are donated: the sampled-token arrays
-        are collected across the quantum and fetched once."""
+        are collected across the quantum and fetched once. The function is
+        named ``step``: the benchmark finds the decode step in a device
+        trace by the program name ``jit_step``."""
+        import jax
+        import jax.numpy as jnp
+        from deepspeed_tpu.moe.sharded_moe import expert_load_tap
+
+        backend = self.decode_backend
+
+        def step(params, pools, tokens, tables, seq_lens, active, key,
+                 apool=None, aidx=None):
+            # apool rides as a trailing NON-donated arg: read-only
+            # shared weights — donating it would force a re-page of
+            # every resident adapter each quantum step
+            lora = (apool, aidx) if apool is not None else None
+            with expert_load_tap() as tap:
+                logits, pools = self.model.decode_step_paged(
+                    params, tokens, pools, tables, seq_lens,
+                    active=active, backend=backend, lora=lora)
+            nxt = self._sample(logits, key)
+            nxt = jnp.where(active, nxt, tokens)
+            # the tokens travel with the step's expert load [L, E + 1]
+            # over the ACTIVE slots (None for a model without experts):
+            # collected and fetched together
+            return (pools, (nxt, tap.stacked()),
+                    seq_lens + active.astype(jnp.int32))
+
+        r = self._repl_sharding
+        outs = ((self._pool_shardings, r, r)
+                if self._pool_shardings is not None else None)
+        return jax.jit(step, donate_argnums=(1, 4), out_shardings=outs)
+
+    def _get_quantum_step(self):
+        """{table width: the decode step compiled for it}, one program per
+        entry of the ladder (``_table_ladder``). All of them are built when
+        the first is asked for — the first decode round, and again after a
+        backend swap — by lowering on abstract arguments: nothing runs, no
+        pool is donated, and no width is ever compiled inside a serving
+        window, whichever lengths the traffic brings. Every op of the
+        step's read of the pool (block gathers, scores, softmax, P.V) is
+        sized by the table's width, so a round whose longest sequence ends
+        in the first half of the context reads half of what the full table
+        makes it read."""
         if self._quantum_step is None:
             import jax
             import jax.numpy as jnp
-            from deepspeed_tpu.moe.sharded_moe import expert_load_tap
+            from deepspeed_tpu.analysis.program import abstractify
 
-            backend = self.decode_backend
+            S = self.config.max_seqs
+            sds = jax.ShapeDtypeStruct
+            fn = self._quantum_step_fn()
+            # the big operands carry the shardings they live in; the small
+            # ones are host-built each round and follow
+            params, pools, apool = abstractify(
+                (self.engine.params, self.pools,
+                 self.adapter_pool if self._lora else None))
 
-            def step(params, pools, tokens, tables, seq_lens, active, key,
-                     apool=None, aidx=None):
-                # apool rides as a trailing NON-donated arg: read-only
-                # shared weights — donating it would force a re-page of
-                # every resident adapter each quantum step
-                lora = (apool, aidx) if apool is not None else None
-                with expert_load_tap() as tap:
-                    logits, pools = self.model.decode_step_paged(
-                        params, tokens, pools, tables, seq_lens,
-                        active=active, backend=backend, lora=lora)
-                nxt = self._sample(logits, key)
-                nxt = jnp.where(active, nxt, tokens)
-                # the tokens travel with the step's expert load [L, E + 1]
-                # over the ACTIVE slots (None for a model without experts):
-                # collected and fetched together
-                return (pools, (nxt, tap.stacked()),
-                        seq_lens + active.astype(jnp.int32))
+            def lower(W):
+                with self.engine.mesh:
+                    return fn.lower(
+                        params, pools, sds((S,), jnp.int32),
+                        sds((S, W), jnp.int32), sds((S,), jnp.int32),
+                        sds((S,), jnp.bool_), sds((2,), jnp.uint32), apool,
+                        sds((S,), jnp.int32))
 
-            r = self._repl_sharding
-            outs = ((self._pool_shardings, r, r)
-                    if self._pool_shardings is not None else None)
-            self._quantum_step = jax.jit(step, donate_argnums=(1, 4),
-                                         out_shardings=outs)
+            # traced and lowered on a fresh thread, one width after the
+            # other, and compiled (a cache load, once warm) on this one
+            # meanwhile: below the serving loop's frames a lowering costs
+            # twice what it costs on an empty stack (three programs 3.3 s
+            # against 1.5 s in Mixtral's cell, PERF.md section 6, PR 29),
+            # and the widths have to fit a cell's set-up. The thread sees
+            # no thread-local jax.config context of the caller's; the mesh
+            # is entered there
+            with ThreadPoolExecutor(1) as pool:
+                lowered = {W: pool.submit(lower, W)
+                           for W in self._table_widths}
+                self._quantum_step = {W: lo.result().compile()
+                                      for W, lo in lowered.items()}
         return self._quantum_step
 
     def _get_spec_step(self):
@@ -1217,15 +1279,32 @@ class ServingEngine:
             req.prefill_done = True
             req._first_dev = (first, None)     # (token, no load): fetched
 
-    def _tables_device(self):
+    def _tables_device(self, full: bool = False):
+        """The round's block tables ``ids[max_seqs, W]``, lengths, active
+        mask and adapter indices, on the device. ``W`` is the smallest
+        width of the ladder (``_table_ladder``) that holds the longest
+        ``block_ids`` among the running requests: those already cover the
+        quantum's writes (``Scheduler._grow``), so every row a step reads
+        or writes lies in the first ``W`` columns, and the columns dropped
+        hold only positions past every slot's length, whose probabilities
+        are exact zeros. Inactive slots read column 0 of an all-zero row,
+        the trash block. Only the plain quantum step takes the narrow
+        table; ``full`` (the speculation verify step) keeps all ``MB``
+        columns, as do chunk dispatch (its own ``tab[1, MB]``), the fork
+        and KV import / export: each is a program family of its own to
+        warm, and no benchmark cell runs them."""
         import jax.numpy as jnp
-        ids = np.zeros((self.config.max_seqs, self.MB), np.int32)
+        running = self.scheduler.running
+        need = self.MB if full else max(
+            (len(req.block_ids) for req in running), default=0)
+        W = next(w for w in self._table_widths if w >= need)
+        ids = np.zeros((self.config.max_seqs, W), np.int32)
         lens = np.zeros((self.config.max_seqs,), np.int32)
         act = np.zeros((self.config.max_seqs,), bool)
         # per-slot adapter index into the device slot pool (0 = the null
         # adapter): free slots read slot 0 — an exact-zero delta
         aidx = np.zeros((self.config.max_seqs,), np.int32)
-        for req in self.scheduler.running:
+        for req in running:
             ids[req.slot, :len(req.block_ids)] = req.block_ids
             lens[req.slot] = req.cached_rows
             # a mid-prefill request (chunked prompt still landing) holds
@@ -1385,7 +1464,6 @@ class ServingEngine:
                 return [], ph
 
             with span("ds:serve.decode_dispatch") as sp_dec:
-                tables, seq_lens, active, aidx = self._tables_device()
                 # a prefill-role engine NEVER runs decode quanta: requests
                 # sit prefill_done until the router hands them (with their
                 # KV bytes) to the decode tier. Their FIRST token still
@@ -1397,8 +1475,12 @@ class ServingEngine:
                                 for r in self.scheduler.running))
                 decode = can_decode and any(r.prefill_done
                                             for r in self.scheduler.running)
+                tables, seq_lens, active, aidx = self._tables_device(
+                    full=spec)
+                # the plain step runs as the program of the table's width
                 step_fn = self._get_spec_step() if spec \
-                    else (self._get_quantum_step() if decode else None)
+                    else (self._get_quantum_step()[tables.shape[1]]
+                          if decode else None)
                 tok_mat = None
                 if spec:
                     props = self._proposals_device()
@@ -1481,6 +1563,8 @@ class ServingEngine:
                                    else self.config.decode_quantum))
             if decode:
                 self._quantum_warm = True
+                if not spec:
+                    self._table_rounds[tables.shape[1]] += 1
             self.pools, self._tokens = p, t
         finally:
             if keep is not None:
@@ -2307,6 +2391,7 @@ class ServingEngine:
         self._lat = {"spec_steps": 0, "spec_proposed": 0,
                      "spec_accepted": 0, "prefill_chunks": 0,
                      "prefill_chunk_tokens": 0, "cow_forks": 0}
+        self._table_rounds = dict.fromkeys(self._table_widths, 0)
         if self._prefix_cache is not None:
             self._prefix_cache.reset_stats()
         if self._lora:
@@ -2342,7 +2427,7 @@ class ServingEngine:
         self._round_thread = None
         return True
 
-    def stats(self) -> Dict[str, float]:
+    def stats(self) -> Dict[str, Any]:
         """TTFT p50/p99 (ms) + aggregate generated-token throughput across
         everything finished so far — the SLO numbers the serving bench
         emits — plus the reliability counters (shed / deadline_misses /
@@ -2372,9 +2457,15 @@ class ServingEngine:
         active slots read, mean over layers and steps; ``..._per_prefill``:
         the same for a whole-prompt prefill's real tokens) and
         ``moe_dropped_share`` (assignments the dispatch dropped; 0 for a
-        dropless model)."""
+        dropless model).
+
+        Block-table width (always on): ``table_width_rounds`` — a dict
+        ``{width in columns: plain decode rounds dispatched at it}`` over
+        the ladder (``_tables_device``; speculation rounds keep the full
+        table and are not counted), the one value that is not a float —
+        and ``table_width_mean`` over those rounds."""
         done = [r for r in self._finished if r.first_token_t is not None]
-        out: Dict[str, float] = {
+        out: Dict[str, Any] = {
             "completed": float(len(self._finished)),
             "preemptions": float(sum(r.preemptions
                                      for r in self._finished)),
@@ -2440,6 +2531,11 @@ class ServingEngine:
             out["moe_experts_touched_per_prefill"] = (m["prefill_touched"]
                                                       / m["prefills"])
         out.update({k: float(v) for k, v in self._lat.items()})
+        out["table_width_rounds"] = dict(self._table_rounds)
+        rounds = sum(self._table_rounds.values())
+        if rounds:
+            out["table_width_mean"] = sum(
+                w * n for w, n in self._table_rounds.items()) / rounds
         if self._lat["spec_proposed"]:
             out["spec_accept_rate"] = float(round(
                 self._lat["spec_accepted"] / self._lat["spec_proposed"], 4))

@@ -486,7 +486,7 @@ def test_counters_ride_the_rounds_one_fetch(monkeypatch):
     L, E, k = 2, 64, 8
     key = jax.ShapeDtypeStruct((2,), jnp.uint32)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)   # noqa: E731
-    out = jax.eval_shape(srv._get_quantum_step().__wrapped__, srv.engine.params,
+    out = jax.eval_shape(srv._quantum_step_fn().__wrapped__, srv.engine.params,
                          srv.pools, i32(4), i32(4, srv.MB), i32(4),
                          jax.ShapeDtypeStruct((4,), jnp.bool_), key)
     assert (out[1][1].shape, out[1][1].dtype) == ((L, E + 1), jnp.int32)   # beside the tokens

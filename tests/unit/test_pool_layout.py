@@ -113,7 +113,7 @@ def _program(srv, case, kind, one_chip, mb=None):
                                            dtype=jnp.bfloat16)), one_chip)
     key = sds((2,), jnp.uint32)
     if kind == "step":                    # the decode quantum's one step
-        fn = jax.jit(srv._get_quantum_step().__wrapped__,
+        fn = jax.jit(srv._quantum_step_fn().__wrapped__,
                      donate_argnums=(1, 4))
         args = (params, pools, sds((S,), jnp.int32), sds((S, MB), jnp.int32),
                 sds((S,), jnp.int32), sds((S,), jnp.bool_), key)
@@ -265,6 +265,32 @@ def test_pool_is_read_once(case, kind, one_chip, engines):
         assert temp < 0.3e9, temp
 
 
+@pytest.mark.parametrize("case,width", [
+    ("chat-int8", 24), ("chat-int8", 16), ("olmoe-int8", 12)])
+def test_a_narrower_table_narrows_the_read(case, width, one_chip, engines):
+    """The read is sized by the table's width and by nothing else (ISSUE
+    29): handed ``width`` of the cell's columns, the step gathers
+    ``[slots * width, block, kv heads, head_dim]`` of each pool and holds
+    no array of the full table's view, in any type — so a round whose
+    longest sequence ends early reads that much less, with not a line of
+    the model changed."""
+    c = CASES[case]
+    compiled, pools = _program(engines(case), case, "step", one_chip,
+                               mb=width)
+    hlo = compiled.as_text()
+    view = int(np.prod(pools["k"].shape[2:]))     # block x kv heads x head_dim
+    full, narrow = c["slots"] * c["mb"] * view, c["slots"] * width * view
+    gathers = [line for ty, n, op, line, _ in _instructions(hlo)
+               if (ty, n, op) == ("s8", narrow, "fusion")
+               and f"s8[{c['slots'] * width},{BS},{c['nkv']},{HD}]" in line]
+    assert len(gathers) == 2, "\n".join(gathers)        # K and V, a layer
+    bad = [line for ty, n, op, line, _ in _instructions(hlo, fused_too=True)
+           if n == full and op not in _VIEWS]
+    assert not bad, "\n".join(bad)
+    assert not layer_slice_ops(hlo, pools)
+    assert not widened_view_ops(hlo, pools, c["slots"], width)
+
+
 # ---- the dropless expert dispatch (ISSUE 26) --------------------------------
 #
 # Same file because it is the same kind of test: a property that only the
@@ -327,7 +353,7 @@ def test_dropless_dispatch_is_proportional_to_the_assignments(one_chip, monkeypa
             args = (params, sds((1, T_), jnp.int32), pools,
                     sds((T_ // BS,), jnp.int32), sds((), jnp.int32), key)
         else:
-            fn = jax.jit(srv._get_quantum_step().__wrapped__, donate_argnums=(1, 4))
+            fn = jax.jit(srv._quantum_step_fn().__wrapped__, donate_argnums=(1, 4))
             args = (params, pools, sds((S,), jnp.int32), sds((S, MB), jnp.int32),
                     sds((S,), jnp.int32), sds((S,), jnp.bool_), key)
         # the program asks the backend which dispatch to build; the test

@@ -390,6 +390,135 @@ def test_serving_int8_kv_pool():
         assert (got == one).mean() > 0.9
 
 
+# ---------------------------------------------------------------------------
+# Block tables bucketed by the longest live sequence (ISSUE 29)
+# ---------------------------------------------------------------------------
+
+def _drive_widths(srv, script):
+    """Run ``script`` ({round: [(prompt, max_new_tokens), ...]}) to the end
+    -> (outputs by submission order, [(round's table width, longest
+    ``block_ids`` among the running requests when it was built)])."""
+    rounds, seen, rids, outs = 0, [], [], {}
+    build = srv._tables_device
+
+    def spy(full=False):
+        out = build(full)
+        seen.append((out[0].shape[1], max(len(r.block_ids)
+                                          for r in srv.scheduler.running)))
+        return out
+
+    srv._tables_device = spy
+    while rounds <= max(script) or not srv.scheduler.done:
+        for prompt, n in script.get(rounds, ()):
+            rids.append(srv.add_request(prompt, n))
+        for r in srv.step():
+            outs[r.rid] = r.output
+        rounds += 1
+    return [outs[r] for r in rids], seen
+
+
+@pytest.mark.parametrize("kv_bits", [0, 8], ids=["float-pool", "int8-pool"])
+def test_table_width_follows_the_longest_live_sequence(kv_bits, monkeypatch):
+    """A decode round is handed only the table columns it can reach: the
+    smallest width of the ladder that holds the longest running request's
+    blocks. The columns left out hold exact zeros of the softmax, so the
+    tokens are those of an engine whose ladder is the full table alone."""
+    from deepspeed_tpu.inference import serving
+    model = make_model(_cfg())
+    params = jax.device_get(model.init(jax.random.PRNGKey(0)))
+    rng = np.random.default_rng(29)
+
+    def prompt(n):
+        return rng.integers(0, 128, size=(n,)).astype(np.int32)
+
+    # 16 columns of 16 tokens, ladder 8 / 12 / 16: a request that grows from
+    # one block to six, a short one beside it, and a long one admitted
+    # mid-flight that crosses both boundaries and finishes first
+    script = {0: [(prompt(10), 76), (prompt(40), 20)],
+              3: [(prompt(150), 48)]}
+
+    def engine():
+        return deepspeed_tpu.init_serving(
+            model, config={"kv_cache_bits": kv_bits}, params=params,
+            serving=dict(max_seqs=3, block_size=16, max_model_len=256,
+                         decode_quantum=4, prompt_bucket=16),
+            dtype=jnp.float32)
+
+    srv = engine()
+    assert srv._table_widths == (8, 12, 16)
+    outs, seen = _drive_widths(srv, script)
+    # (b) every round: the smallest ladder entry that holds the longest list
+    for width, longest in seen:
+        assert width == min(w for w in srv._table_widths if w >= longest)
+    widths = [w for w, _ in seen]
+    assert set(widths) == {8, 12, 16}
+    assert widths[2] == 8 and widths[3] == 12    # widened by the admission
+    assert widths[-1] == 8 and 16 in widths      # ... and narrowed after it
+    # (c) the counter: one entry a width, summing to the decode rounds
+    st = srv.stats()
+    assert st["table_width_rounds"] == {w: widths.count(w)
+                                        for w in srv._table_widths}
+    assert st["table_width_mean"] == pytest.approx(np.mean(widths))
+    srv.reset_stats()
+    assert not any(srv.stats()["table_width_rounds"].values())
+    assert "table_width_mean" not in srv.stats()
+    # (a) the same tokens as with the full table in every round
+    monkeypatch.setattr(serving, "_table_ladder", lambda MB: (MB,))
+    full = engine()
+    assert full._table_widths == (16,)
+    want, seen_full = _drive_widths(full, script)
+    assert {w for w, _ in seen_full} == {16}
+    for got, ref in zip(outs, want):
+        np.testing.assert_array_equal(got, ref)
+
+
+def test_no_step_program_is_built_after_the_first_decode_round():
+    """Every width's step program is built with the first, from abstract
+    arguments: however the lengths move afterwards, no decode step is
+    lowered or compiled again — also after the backend swap has thrown the
+    programs away and the next decode round has rebuilt them."""
+    events = ("/jax/core/compile/jaxpr_to_mlir_module_duration",
+              "/jax/core/compile/backend_compile_duration")
+    names = []
+
+    def on(name, secs, **kw):
+        if name in events:
+            names.append(str(kw.get("fun_name", "?")))
+
+    # head_dim 64: the forced Pallas backend runs (interpret mode), so the
+    # degradation to the gather backend is the real swap
+    srv = _serving(make_model(_cfg(hidden_size=256)), max_seqs=3,
+                   max_model_len=256, decode_backend="pallas")
+    assert srv.decode_backend == "pallas"
+    rng = np.random.default_rng(7)
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+
+        def load():
+            # lengths that visit every width: 8, 12 and 16 columns
+            return [(rng.integers(0, 128, size=(n,)).astype(np.int32), k)
+                    for n, k in ((70, 8), (10, 60), (130, 70))]
+
+        for swap in (False, True):
+            if swap:
+                srv._degrade_backend()
+                assert srv.decode_backend == "xla"
+            srv.reset_stats()
+            del names[:]
+            srv.add_request(np.arange(5, dtype=np.int32), 6)
+            srv.step()                    # the first decode round
+            # one lowering and one compile a width, here and nowhere else
+            assert names.count("jit(step)") == 2 * len(srv._table_widths)
+            del names[:]
+            srv.run(load())
+            assert "jit(step)" not in names, names
+            rounds = srv.stats()["table_width_rounds"]
+            assert all(rounds.values()), rounds
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on)
+        srv.close()
+
+
 def test_backend_selection_event_and_reason():
     """The backend choice short-circuits with a recorded reason and lands
     in the telemetry event stream. Capability gates take precedence over
