@@ -1,0 +1,30 @@
+"""Share of the device's busy time spent in the recurrence's two Pallas
+kernels, prefill and decode together: self time of the ops the family's
+``ssm_kernel`` finds by NAME in the device trace (``%ssm_scan.N``, the
+chunked scan of a prompt; ``%ssm_step.N``, the one-step update of every
+slot's state) over the busy time of the traced stretch. The projections, the
+convolution and the gated norm around them are XLA fusions and are not in
+it. A program without such kernels reads nothing."""
+from benchmark.harness import trace_reduce
+
+HEADER = {"layer": "recurrent mixer (models/mamba.py, ops/ssm.py)", "unit": "%",
+          "moves": "serve_tokens_per_s", "jobs": ["serve"],
+          "source": "device_trace", "better": "lower"}
+
+
+def kernel_seconds(run, which=None):
+    """Self seconds of the recurrence's kernels in the traced stretch
+    (``which``: ``"scan"`` / ``"step"`` alone); None where the family or
+    the trace has none."""
+    t, fam = run["trace"], run["family"]
+    if not t or not t.get("busy_s") or not hasattr(fam, "ssm_kernel"):
+        return None
+    s = trace_reduce.op_seconds(
+        t, lambda name: fam.ssm_kernel(name) in ((which,) if which
+                                                  else ("scan", "step")))
+    return s or None
+
+
+def read(run):
+    s = kernel_seconds(run)
+    return 100.0 * s / run["trace"]["busy_s"] if s else None

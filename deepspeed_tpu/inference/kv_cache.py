@@ -258,7 +258,23 @@ def blocks_for(n_tokens: int, block_size: int) -> int:
     return -(-n_tokens // block_size)
 
 
-def pool_bytes(cfg, num_blocks: int, block_size: int, dtype=None) -> int:
+def state_pool_bytes(cfg, max_seqs: int, dtype=None) -> int:
+    """LOGICAL bytes of the per-slot recurrent state of a model with
+    recurrent blocks (``models/hybrid.py``): per block and slot a float32
+    state [heads, head dim, state size] and the last K - 1 rows of the
+    convolution's input in the pool dtype. 0 for every other model."""
+    Lm = int(getattr(cfg, "recurrent_blocks", 0) or 0)
+    if not Lm:
+        return 0
+    import numpy as _np
+    from deepspeed_tpu.models.mamba import dims
+    nh, hd, _, N, _, conv_dim, K = dims(cfg)
+    itemsize = _np.dtype(dtype if dtype is not None else cfg.dtype).itemsize
+    return Lm * max_seqs * (nh * hd * N * 4 + (K - 1) * conv_dim * itemsize)
+
+
+def pool_bytes(cfg, num_blocks: int, block_size: int, dtype=None,
+               max_seqs: int = 0) -> int:
     """LOGICAL resident bytes of the block pools for a transformer config
     — the paged-cache memory math the README documents. int8: 1 byte/elem
     payload + 4 bytes/row/head scale x2 (k, v); float: itemsize of the
@@ -270,13 +286,18 @@ def pool_bytes(cfg, num_blocks: int, block_size: int, dtype=None) -> int:
     ``stats()["pool_bytes"]`` report — is this divided by the tp degree
     (``parallel.partitioning.sharded_bytes`` prices it from the committed
     shardings; the memory-law test pins per_device * tp == logical)."""
-    L, nkv, hd = cfg.num_layers, cfg.kv_heads, cfg.dim_per_head
+    # the layers that own K/V: all of a homogeneous stack, the attention
+    # blocks of a hybrid one, whose per-slot recurrent state (for
+    # ``max_seqs`` slots) is counted beside them
+    L = getattr(cfg, "attention_blocks", cfg.num_layers)
+    nkv, hd = cfg.kv_heads, cfg.dim_per_head
     rows = L * num_blocks * nkv * block_size
+    state = state_pool_bytes(cfg, max_seqs, dtype)
     if cfg.kv_cache_bits == 8:
-        return rows * hd * 2 + rows * 4 * 2
+        return rows * hd * 2 + rows * 4 * 2 + state
     import numpy as _np
     itemsize = _np.dtype(dtype if dtype is not None else cfg.dtype).itemsize
-    return rows * hd * itemsize * 2
+    return rows * hd * itemsize * 2 + state
 
 
 def kv_payload_nbytes(data: Dict[str, "object"]) -> int:

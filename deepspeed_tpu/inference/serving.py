@@ -102,6 +102,24 @@ class DecodeDispatchHang(RuntimeError):
     fetch) never came back within ``dispatch_timeout_s``."""
 
 
+class RecurrentStateUnsupported(ValueError):
+    """What a model with recurrent blocks (``block_pattern`` with "M":
+    ``nemotron_h``) cannot be served with, refused at ``init_serving`` or
+    at the call: a request's state there is its K/V blocks AND a recurrent
+    state per slot, and everything that shares, rolls back, resumes or ships
+    a request's state by its blocks alone — the prefix cache and its
+    copy-on-write fork, chunked prefill, speculation, K/V export / import,
+    LoRA on the projections, a pool split over ``tensor`` — would need a
+    snapshot of that state, which nothing keeps yet."""
+
+    def __init__(self, what: str):
+        super().__init__(
+            f"{what} is not supported on a model with recurrent blocks: a "
+            "request's state is its K/V blocks and a per-slot recurrent "
+            "state, and no snapshot of the latter is kept")
+        self.what = what
+
+
 class ResumeIncompatible(ValueError):
     """A drained request (or a whole foreign drain) cannot be restored on
     THIS engine: the local block-table width / ``max_model_len`` is smaller
@@ -379,6 +397,18 @@ class ServingEngine:
                 f"max_model_len/model max_seq_len ({c.max_model_len} / "
                 f"{model_cap}) leaves no room for one "
                 f"{c.block_size}-token block")
+        # a model with recurrent blocks keeps a state per slot beside the
+        # K/V blocks of its attention blocks (models/hybrid.py)
+        self._recurrent = int(getattr(mcfg, "recurrent_blocks", 0) or 0)
+        if self._recurrent:
+            for armed, what in (
+                    (c.enable_prefix_cache, "the prefix cache"),
+                    (c.prefill_token_budget is not None, "chunked prefill"),
+                    (c.spec_tokens > 0, "speculative decoding"),
+                    (c.adapter_slots > 0, "LoRA adapter serving"),
+                    (self.tp > 1, "a tensor-parallel pool")):
+                if armed:
+                    raise RecurrentStateUnsupported(what)
         self.max_model_len = want
         self.MB = self.max_model_len // c.block_size     # table width
         self._table_widths = _table_ladder(self.MB)
@@ -476,11 +506,15 @@ class ServingEngine:
         # fresh-pool program cached: fault recovery rebuilds the pool with
         # the same jitted init the constructor uses
         self._init_pools_fn = jax.jit(
-            lambda: model.init_paged_cache(num_blocks, c.block_size,
-                                           dtype=engine.dtype),
+            lambda: model.init_paged_cache(
+                num_blocks, c.block_size, dtype=engine.dtype,
+                **({"max_seqs": c.max_seqs} if self._recurrent else {})),
             out_shardings=self._pool_shardings)
         with engine.mesh:
             self.pools = self._init_pools_fn()
+        # the per-slot recurrent state's dtype (None for every other model)
+        self.state_pool_dtype = (str(self.pools["ssm"].dtype)
+                                 if self._recurrent else None)
         # logical pool size (the README memory math, mesh-independent) vs
         # the PER-DEVICE shard each chip actually holds: on a tp-sharded
         # engine the resident HBM is logical / tp (the kv-head slice), and
@@ -488,7 +522,8 @@ class ServingEngine:
         # the logical array (ISSUE 15: the old single number overstated
         # HBM by the tp degree on sharded engines)
         self.pool_bytes_logical = pool_bytes(mcfg, num_blocks, c.block_size,
-                                             dtype=engine.dtype)
+                                             dtype=engine.dtype,
+                                             max_seqs=c.max_seqs)
         from deepspeed_tpu.parallel.partitioning import sharded_bytes
         self.pool_bytes = sharded_bytes(self.pools)
         # --- adapter slot pool (ISSUE 17: paged multi-LoRA) ------------
@@ -538,6 +573,9 @@ class ServingEngine:
         self._finished: List[Request] = []
         self._cancelled: List[Request] = []
         self._prefill_fns: Dict[int, Any] = {}
+        # {"step" | "prefill_<bucket>": the expert layers' dispatch form},
+        # written when a program is traced (stats()["moe_dispatch"])
+        self._moe_forms: Dict[str, Optional[str]] = {}
         self._chunk_fns: Dict[int, Any] = {}
         self._quantum_step = None
         self._spec_step = None
@@ -893,10 +931,14 @@ class ServingEngine:
             import jax
             from deepspeed_tpu.moe.sharded_moe import expert_load_tap
 
-            def prefill(params, ids, pools, block_ids, length, key):
+            def prefill(params, ids, pools, block_ids, length, key, *slot):
+                # slot: the request's slot, for a model that keeps a
+                # recurrent state per slot; nothing for every other model
                 with expert_load_tap() as tap:
                     last, pools = self.model.prefill_paged(
-                        params, ids, pools, block_ids, length=length)
+                        params, ids, pools, block_ids, length=length,
+                        **({"slot": slot[0]} if slot else {}))
+                self._moe_forms[f"prefill_{P}"] = tap.form      # trace time
                 # the first token travels with the expert load [L, E + 1] of
                 # the REAL prompt tokens (None for a model without experts)
                 return (self._sample(last, key), tap.stacked()), pools
@@ -931,6 +973,7 @@ class ServingEngine:
                 logits, pools = self.model.decode_step_paged(
                     params, tokens, pools, tables, seq_lens,
                     active=active, backend=backend, lora=lora)
+            self._moe_forms["step"] = tap.form                  # trace time
             nxt = self._sample(logits, key)
             nxt = jnp.where(active, nxt, tokens)
             # the tokens travel with the step's expert load [L, E + 1]
@@ -1177,13 +1220,21 @@ class ServingEngine:
         with self.engine.mesh:
             first, self.pools = fn(self.engine.params, jnp.asarray(buf),
                                    self.pools, block_ids,
-                                   jnp.int32(ctx.size), self._next_key())
+                                   jnp.int32(ctx.size), self._next_key(),
+                                   *self._state_slot(req))
         self._tokens = self._tokens.at[req.slot].set(first[0][0])
         req.cached_rows = ctx.size
         req.prefill_done = True
         # (token, the prompt's expert load): fetched at round boundary
         req._first_dev = first
         self._publish_prefill(req, ctx)
+
+    def _state_slot(self, req: Request) -> tuple:
+        """What a prefill needs beyond the blocks: nothing, or — a model
+        with recurrent blocks — the request's slot, whose recurrent state
+        the prefill overwrites (a whole prompt from a zero state, so a slot
+        given again carries nothing of the last request)."""
+        return (np.int32(req.slot),) if self._recurrent else ()
 
     def _publish_prefill(self, req: Request, ctx) -> None:
         """Index a prefill's FULL blocks in the prefix cache as soon as
@@ -1206,6 +1257,8 @@ class ServingEngine:
         pin on the shared block is dropped. Runs BEFORE any of the
         request's own writes — full shared blocks stay referenced, the
         partial one is never written in place."""
+        if self._recurrent:
+            raise RecurrentStateUnsupported("a copy-on-write fork")
         src, dst = req.cow_src, req.cow_dst
         with self.engine.mesh:
             self.pools = self._copy_block_fn(self.pools, np.int32(src),
@@ -1889,6 +1942,8 @@ class ServingEngine:
         them in ``pool_bytes``/``kv_staging_bytes``."""
         import jax
         import jax.numpy as jnp
+        if self._recurrent:
+            raise RecurrentStateUnsupported("K/V export")
         bs = self.config.block_size
         out: Dict[int, Dict[str, Any]] = {}
         for rid in request_ids:
@@ -1980,6 +2035,8 @@ class ServingEngine:
         ``BlockAllocator`` path, the payload scatters into them before
         the 1-tail-span prefill runs, and the continuation is
         token-identical to the colocated engine."""
+        if self._recurrent:
+            raise RecurrentStateUnsupported("K/V import")
         req = self._requests.get(request_id)
         if req is None or req.state != "waiting":
             raise ResumeIncompatible(
@@ -2246,6 +2303,8 @@ class ServingEngine:
                     "on an engine at least as large as the drained one")
             payload = kv.get(req.rid)
             if payload is not None:
+                if self._recurrent:
+                    raise RecurrentStateUnsupported("K/V import")
                 # all-or-nothing with the rest of the batch: a bad payload
                 # refuses HERE, before anything is enqueued
                 self._validate_kv_payload(req, payload, source)
@@ -2374,6 +2433,15 @@ class ServingEngine:
 
     # ---- stats -------------------------------------------------------
 
+    def _state_bytes(self) -> int:
+        """Per-device bytes of the per-slot recurrent state pool (0 for a
+        model without recurrent blocks)."""
+        if not self._recurrent:
+            return 0
+        from deepspeed_tpu.models.hybrid import STATE_LEAVES
+        from deepspeed_tpu.parallel.partitioning import sharded_bytes
+        return sharded_bytes({k: self.pools[k] for k in STATE_LEAVES})
+
     def reset_stats(self) -> None:
         """Start a fresh measurement window: completed-request records,
         cancellations, reliability counters and the throughput clock reset
@@ -2459,6 +2527,16 @@ class ServingEngine:
         ``moe_dropped_share`` (assignments the dispatch dropped; 0 for a
         dropless model).
 
+        The two kinds of state (always on): ``kv_pool_bytes`` (the K/V block
+        pool's share of ``pool_bytes``) and, for a model with recurrent
+        blocks, ``state_pool_bytes`` (the per-slot recurrent state) and
+        ``state_slots_live`` (slots whose state belongs to a running
+        request);
+        ``moe_dispatch`` — a dict ``{"step" | "prefill_<bucket>": form}``
+        of the expert layers' dispatch form (``one-hot`` | ``sorted/moe_gmm``
+        | ``sorted/ragged_dot`` | ``capacity``) in each program built so far,
+        recorded when the program is traced.
+
         Block-table width (always on): ``table_width_rounds`` — a dict
         ``{width in columns: plain decode rounds dispatched at it}`` over
         the ladder (``_tables_device``; speculation rounds keep the full
@@ -2530,6 +2608,13 @@ class ServingEngine:
         if m["prefills"]:
             out["moe_experts_touched_per_prefill"] = (m["prefill_touched"]
                                                       / m["prefills"])
+        forms = {k: v for k, v in self._moe_forms.items() if v}
+        if forms:
+            out["moe_dispatch"] = forms
+        out["kv_pool_bytes"] = float(self.pool_bytes - self._state_bytes())
+        if self._recurrent:
+            out["state_pool_bytes"] = float(self._state_bytes())
+            out["state_slots_live"] = float(len(self.scheduler.running))
         out.update({k: float(v) for k, v in self._lat.items()})
         out["table_width_rounds"] = dict(self._table_rounds)
         rounds = sum(self._table_rounds.values())

@@ -992,6 +992,75 @@ def _even_rotary(head_dim: int, pct: float) -> int:
     return max(2, rd)
 
 
+def _nemotron_h_kwargs(get) -> dict:
+    """``nemotron_h`` (NVIDIA Nemotron-H / Nemotron-3): a hybrid stack whose
+    ``hybrid_override_pattern`` names each block's ONE mixer — ``M`` Mamba-2,
+    ``E`` an expert feed-forward (sigmoid router with a correction bias,
+    non-gated relu^2 experts, a shared expert), ``*`` attention without a
+    positional embedding. ``-`` (a dense MLP block, the older Nemotron-H
+    checkpoints) and any other letter are refused: nothing here computes
+    them."""
+    pattern = get("hybrid_override_pattern")
+    if not pattern:
+        raise ValueError("nemotron_h needs `hybrid_override_pattern`")
+    bad = sorted(set(pattern) - set("ME*"))
+    if bad:
+        raise ValueError(
+            f"nemotron_h hybrid_override_pattern has {bad}: only M (Mamba-2), "
+            "E (experts) and * (attention) blocks are supported"
+            + (" — '-' is a dense MLP block" if "-" in bad else ""))
+    L = get("num_hidden_layers")
+    if L is not None and L != len(pattern):
+        raise ValueError(f"nemotron_h: num_hidden_layers={L} but "
+                         f"hybrid_override_pattern has {len(pattern)} blocks")
+    for key, want in (("mlp_hidden_act", "relu2"), ("mamba_hidden_act", "silu"),
+                      ("n_group", 1), ("topk_group", 1)):
+        if get(key, want) != want:
+            raise ValueError(f"nemotron_h {key}={get(key)!r} is not supported "
+                             f"(the published config has {want!r})")
+    for key in ("attention_bias", "mlp_bias", "mamba_proj_bias", "use_bias"):
+        if get(key, False):
+            raise ValueError(f"nemotron_h {key}=true is not supported")
+    if not get("use_conv_bias", True):
+        raise ValueError("nemotron_h use_conv_bias=false is not supported")
+    if (get("n_shared_experts", 1) or 0) > 1:
+        raise ValueError("nemotron_h: more than one shared expert")
+    return dict(
+        vocab_size=get("vocab_size"), hidden_size=get("hidden_size"),
+        num_layers=len(pattern), block_pattern=pattern,
+        num_heads=get("num_attention_heads"),
+        num_kv_heads=get("num_key_value_heads"),
+        head_dim=get("head_dim") or get("attention_head_dim"),
+        max_seq_len=get("max_position_embeddings", 4096),
+        norm_eps=float(get("layer_norm_epsilon", get("norm_eps", 1e-5))),
+        # the attention blocks apply no rotary: rope_theta and
+        # partial_rotary_factor are in the config and are not read
+        position_type="none", norm_type="rmsnorm", activation="relu2",
+        tie_embeddings=bool(get("tie_word_embeddings", False)),
+        # the E blocks: `moe_intermediate_size` is ONE expert's width
+        intermediate_size=get("moe_intermediate_size",
+                              get("intermediate_size")),
+        num_experts=get("n_routed_experts"),
+        top_k=get("num_experts_per_tok"),
+        norm_topk_prob=bool(get("norm_topk_prob", True)),
+        moe_scoring="sigmoid",
+        routed_scaling_factor=float(get("routed_scaling_factor", 1.0)),
+        moe_shared_size=(get("moe_shared_expert_intermediate_size", 0)
+                         if get("n_shared_experts", 1) else 0),
+        drop_tokens=False, use_residual=False,
+        # the M blocks: the inner width is heads x head dim, not
+        # expand x hidden (4096 against 5376 at the published sizes)
+        mamba_num_heads=get("mamba_num_heads"),
+        mamba_head_dim=get("mamba_head_dim"),
+        mamba_n_groups=get("n_groups", 1),
+        ssm_state_size=get("ssm_state_size"),
+        conv_kernel=get("conv_kernel", 4),
+        mamba_chunk=get("chunk_size", 128),
+        time_step_min=float(get("time_step_min", 0.001)),
+        time_step_max=float(get("time_step_max", 0.1)),
+        time_step_floor=float(get("time_step_floor", 1e-4)))
+
+
 def hf_config_to_transformer(hf_cfg, **overrides):
     """Build a TransformerConfig from a transformers PretrainedConfig (or a
     config.json dict)."""
@@ -1039,6 +1108,8 @@ def hf_config_to_transformer(hf_cfg, **overrides):
                 norm_topk_prob=bool(get("norm_topk_prob", False)),
                 drop_tokens=False, qk_norm=True, use_residual=False,
                 moe_aux_loss_weight=float(get("router_aux_loss_coef", 0.01)))
+    elif mt == "nemotron_h":
+        kw = _nemotron_h_kwargs(get)
     elif mt == "opt":
         if get("word_embed_proj_dim", get("hidden_size")) != get("hidden_size"):
             raise ValueError(

@@ -48,7 +48,7 @@ class TransformerConfig:
     intermediate_size: Optional[int] = None     # None -> 4*hidden (gelu) / 8/3 (glu)
     max_seq_len: int = 1024
     position_type: str = "learned"              # learned | rotary | alibi | none
-    activation: str = "gelu"                    # gelu | silu_glu | gelu_glu
+    activation: str = "gelu"                    # gelu | silu_glu | gelu_glu | relu | relu2
     norm_type: str = "layernorm"                # layernorm | rmsnorm
     norm_eps: float = 1e-5
     # layernorm right after the token embedding (BLOOM's
@@ -129,6 +129,33 @@ class TransformerConfig:
     norm_topk_prob: bool = True
     use_residual: bool = False                  # PR-MoE
     moe_aux_loss_weight: float = 0.01
+    # ARCHITECTURE (nemotron_h): how the router scores. "softmax" over all
+    # experts (Mixtral, OLMoE), or "sigmoid" per expert: the CHOICE is the
+    # top-k of score + a stored correction bias (`e_bias` [E]), the WEIGHTS
+    # are the scores themselves, and after `norm_topk_prob` they are
+    # multiplied by `routed_scaling_factor`. `moe_shared_size` > 0: an expert
+    # of that width, of the experts' own form, that every token passes.
+    moe_scoring: str = "softmax"                # softmax | sigmoid
+    routed_scaling_factor: float = 1.0
+    moe_shared_size: int = 0
+    # ARCHITECTURE (nemotron_h): a HYBRID stack. One letter a block — "M" a
+    # Mamba-2 mixer, "E" an expert feed-forward, "*" attention — and every
+    # block is h + mixer(norm(h)): ONE mixer, not attention + FFN. None is
+    # the homogeneous stack of every other family. models/hybrid.py walks
+    # the pattern; models/mamba.py is the M mixer (heads x head dim inner
+    # width, `mamba_n_groups` B/C groups of `ssm_state_size`, a causal
+    # depthwise convolution of `conv_kernel`, a chunked scan of
+    # `mamba_chunk`); time_step_* shape the initialisation of dt_bias only.
+    block_pattern: Optional[str] = None
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_n_groups: int = 1
+    ssm_state_size: int = 0
+    conv_kernel: int = 4
+    mamba_chunk: int = 128
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
     remat: bool = False
     # none | dots_saveable | save_nothing | dots_and_attn (dots + the flash
     # kernel's named outputs: the backward reuses O/log-sum-exp instead of
@@ -174,6 +201,20 @@ class TransformerConfig:
     @property
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
+
+    @property
+    def recurrent_blocks(self) -> int:
+        """Blocks that keep a recurrent state per serving slot (the "M"
+        blocks of a hybrid stack); 0 for every homogeneous model."""
+        return (self.block_pattern or "").count("M")
+
+    @property
+    def attention_blocks(self) -> int:
+        """Blocks that own K/V: every layer of a homogeneous stack, the "*"
+        blocks of a hybrid one."""
+        if self.block_pattern is None:
+            return self.num_layers
+        return self.block_pattern.count("*")
 
     @property
     def dim_per_head(self) -> int:
@@ -252,6 +293,9 @@ def mixtral_config(size: str = "8x7b", **overrides) -> TransformerConfig:
 # --------------------------------------------------------------------------
 
 def init_params(key, cfg: TransformerConfig) -> Params:
+    if cfg.block_pattern:
+        from deepspeed_tpu.models import hybrid
+        return hybrid.init_params(key, cfg)
     H, L = cfg.hidden_size, cfg.num_layers
     nh, nkv, hd, F = cfg.num_heads, cfg.kv_heads, cfg.dim_per_head, cfg.ffn_dim
     k = iter(jax.random.split(key, 16))
@@ -334,6 +378,9 @@ def init_params(key, cfg: TransformerConfig) -> Params:
 
 def logical_axes(cfg: TransformerConfig) -> Params:
     """Pytree of logical-axis tuples, same structure as init_params output."""
+    if cfg.block_pattern:
+        from deepspeed_tpu.models import hybrid
+        return hybrid.logical_axes(cfg)
     layers = {
         "ln1_scale": ("layers", "unmodeled"),
         "ln2_scale": ("layers", "unmodeled"),
@@ -618,6 +665,8 @@ def _activation(x, gate, cfg: TransformerConfig):
         return jax.nn.gelu(gate) * x
     if cfg.activation == "relu":   # OPT family
         return jax.nn.relu(x)
+    if cfg.activation == "relu2":  # nemotron_h: relu(x)^2, no gate
+        return jnp.square(jax.nn.relu(x))
     if cfg.activation == "quick_gelu":   # CLIP text encoder
         return x * jax.nn.sigmoid(1.702 * x)
     return jax.nn.gelu(x)
@@ -1580,6 +1629,15 @@ def forward(params: Params, input_ids, cfg: TransformerConfig, *,
     segment ids for encoder models (type_vocab_size > 0); None -> zeros.
     inputs_embeds: pre-computed [B, S, H] embeddings instead of a token
     lookup (vision towers / soft prompts); positions still apply."""
+    if cfg.block_pattern:
+        from deepspeed_tpu.models import hybrid
+        return hybrid.forward(
+            params, input_ids, cfg, deterministic=deterministic,
+            dropout_rng=dropout_rng, return_aux=return_aux,
+            return_hidden=return_hidden, positions=positions,
+            attention_mask=attention_mask, token_type_ids=token_type_ids,
+            layer_override=layer_override, return_kv=return_kv,
+            pld_theta=pld_theta, inputs_embeds=inputs_embeds)
     with jax.named_scope("embed"):
         if inputs_embeds is not None:
             B, S = inputs_embeds.shape[:2]
@@ -2509,10 +2567,20 @@ def prefill_paged(params: Params, input_ids, cfg: TransformerConfig,
     appends). Returns (last_logits [1, V], pools). The contiguous prefill
     cache is a jit-local temporary — it never leaves the program."""
     B, P = input_ids.shape
-    bs = pools["k"].shape[2]
-    nblk = P // bs
     cache = init_cache(cfg, B, P)
     last, cache = prefill(params, input_ids, cfg, cache, length=length)
+    return last, _write_prefill_blocks(pools, block_ids, cache,
+                                       cfg.kv_cache_bits == 8)
+
+
+def _write_prefill_blocks(pools: Params, block_ids, cache: Params,
+                          int8: bool) -> Params:
+    """One request's contiguous prefill cache into its blocks of the pool:
+    cache ``k``, ``v`` [L, 1, nkv, P, hd] (+ ``k_scale``, ``v_scale``
+    [L, 1, nkv, P] for an int8 pool), P = len(block_ids) blocks. Returns the
+    pool's K/V leaves."""
+    bs = pools["k"].shape[2]
+    nblk = cache["k"].shape[3] // bs
 
     def to_blocks(a):          # [L, 1, nkv, P, hd] -> [L, nblk, bs, nkv, hd]
         L_, _, nkv, _, hd = a.shape
@@ -2528,12 +2596,12 @@ def prefill_paged(params: Params, input_ids, cfg: TransformerConfig,
         new_pools = {
             "k": pools["k"].at[:, block_ids].set(to_blocks(cache["k"])),
             "v": pools["v"].at[:, block_ids].set(to_blocks(cache["v"]))}
-        if cfg.kv_cache_bits == 8:
+        if int8:
             new_pools["k_scale"] = pools["k_scale"].at[:, block_ids].set(
                 to_blocks_s(cache["k_scale"]))
             new_pools["v_scale"] = pools["v_scale"].at[:, block_ids].set(
                 to_blocks_s(cache["v_scale"]))
-    return last, new_pools
+    return new_pools
 
 
 def chunked_cross_entropy(x, head, labels, chunk: int,
@@ -2671,15 +2739,48 @@ class ModelSpec:
         return 6.0 * n_params + attn
 
 
-def make_model(cfg: TransformerConfig, name: str = "transformer") -> ModelSpec:
-    return ModelSpec(
+def _common_spec(cfg: TransformerConfig, name: str) -> dict:
+    """What every ModelSpec of this module has, whatever its cache."""
+    return dict(
         init=lambda key: init_params(key, cfg),
         loss_fn=lambda params, batch, rng=None, deterministic=True:
             lm_loss(params, batch, cfg, dropout_rng=rng, deterministic=deterministic),
         apply=lambda params, input_ids, **kw: forward(params, input_ids, cfg, **kw),
         logical_axes=logical_axes(cfg),
         config=cfg,
-        name=name,
+        name=name)
+
+
+def _make_hybrid_model(cfg: TransformerConfig, name: str) -> ModelSpec:
+    """A model with a ``block_pattern`` (models/hybrid.py): the same
+    ModelSpec, with the paged serving protocol over two kinds of state and
+    no contiguous-cache protocol (``generate`` recomputes). No span
+    protocol: a span that is rolled back (a rejected draft) or resumed (a
+    prompt chunk, a shared prefix) needs a snapshot of the recurrent state,
+    which nothing keeps yet — the serving engine refuses what would call it."""
+    from deepspeed_tpu.models import hybrid
+    hybrid.blocks(cfg)                         # a bad pattern fails here
+    return ModelSpec(
+        **_common_spec(cfg, name),
+        init_paged_cache=lambda num_blocks, block_size, dtype=None, **kw:
+            hybrid.init_paged_cache(cfg, num_blocks, block_size, dtype=dtype,
+                                    **kw),
+        prefill_paged=lambda params, input_ids, pools, block_ids, **kw:
+            hybrid.prefill_paged(params, input_ids, cfg, pools, block_ids,
+                                 **kw),
+        decode_step_paged=lambda params, tokens, pools, block_tables,
+            seq_lens, **kw:
+            hybrid.decode_step_paged(params, tokens, cfg, pools,
+                                     block_tables, seq_lens, **kw),
+        paged_cache_axes=lambda: hybrid.paged_cache_logical_axes(cfg),
+    )
+
+
+def make_model(cfg: TransformerConfig, name: str = "transformer") -> ModelSpec:
+    if cfg.block_pattern:
+        return _make_hybrid_model(cfg, name)
+    return ModelSpec(
+        **_common_spec(cfg, name),
         init_cache=lambda batch_size, max_len, dtype=None:
             init_cache(cfg, batch_size, max_len, dtype=dtype),
         prefill=lambda params, input_ids, cache, **kw:

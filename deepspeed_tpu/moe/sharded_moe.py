@@ -9,9 +9,17 @@ TPU-native: the reference wraps torch.distributed all_to_all in an autograd
 Function around per-rank expert stacks. Here experts are a stacked leading
 `experts` dim sharded over the `expert` mesh axis.
 
-Routing is one softmax in float32 and one ``lax.top_k`` whatever k is; the
-kept weights are divided by their sum where the model says so
-(``cfg.norm_topk_prob``: Mixtral does, OLMoE does not).
+Routing is one scoring in float32 and one ``lax.top_k`` whatever k is
+(``route``). The scoring is the model's (``cfg.moe_scoring``): a softmax over
+all experts (Mixtral, OLMoE), or a sigmoid per expert whose CHOICE adds a
+stored correction bias and whose WEIGHTS are the plain scores, times
+``cfg.routed_scaling_factor`` (``nemotron_h``). The kept weights are divided
+by their sum where the model says so (``cfg.norm_topk_prob``: Mixtral and
+``nemotron_h`` do, OLMoE does not). The experts are gated (SwiGLU) when the
+stack has a ``w_gate``, plain otherwise, with the activation the model names
+(``cfg.activation``: ``relu2`` is ``relu(x)^2``), and a ``shared_w_in`` /
+``shared_w_out`` pair in the parameters is an expert of the same form that
+EVERY token passes, added unweighted (named scope ``moe/shared``).
 
 Dispatches, chosen by what the model IS (``cfg.drop_tokens``) and by the
 call's shapes, not by an option of their own:
@@ -78,6 +86,10 @@ class _LoadTap:
 
     def __init__(self):
         self.rows: List[jnp.ndarray] = []
+        # the dispatch form of the ``moe_ffn`` calls traced inside the
+        # block: ``capacity`` | ``one-hot`` | ``sorted/moe_gmm`` |
+        # ``sorted/ragged_dot`` (None if no MoE layer ran)
+        self.form: Optional[str] = None
 
     def stacked(self) -> Optional[jnp.ndarray]:
         """[layers, E + 1] in call order; None if no MoE layer ran."""
@@ -169,23 +181,38 @@ def _tap_load(kept, k: int) -> None:
 # --------------------------------------------------------------------------
 
 def route(logits, k: int, *, renormalize: bool = True, rng=None,
-          noise_policy: Optional[str] = None, train: bool = True):
-    """Softmax over ALL experts in float32, one ``lax.top_k``.
+          noise_policy: Optional[str] = None, train: bool = True,
+          scoring: str = "softmax", bias=None, scale: float = 1.0):
+    """Scores over ALL experts in float32, one ``lax.top_k``.
 
     logits: [T, E] -> (weights [T, k] f32, experts [T, k] int32, gates
     [T, E] f32). ``renormalize`` divides the k kept weights by their sum
     (Mixtral); without it they are the softmax's own values and sum to less
     than 1 (OLMoE, ``norm_topk_prob: false``). Ties go to the lower expert
-    index, as the argmax chain this replaces broke them."""
+    index, as the argmax chain this replaces broke them.
+
+    ``scoring="sigmoid"`` (``nemotron_h``): the scores are a sigmoid per
+    expert, the k experts are the top-k of ``scores + bias`` (``bias`` [E],
+    the stored ``e_score_correction_bias``), the weights are the SCORES at
+    those experts (the bias chooses, it does not weigh). ``scale`` multiplies
+    the weights last."""
     if noise_policy == "Jitter" and train and rng is not None:
         logits = logits * jax.random.uniform(rng, logits.shape, logits.dtype,
                                              1.0 - 1e-2, 1.0 + 1e-2)
     elif noise_policy == "RSample" and train and rng is not None:
         logits = logits + jax.random.gumbel(rng, logits.shape, logits.dtype)
-    gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)      # [T, E]
-    weights, experts = lax.top_k(gates, k)
+    if scoring == "sigmoid":
+        gates = jax.nn.sigmoid(logits.astype(jnp.float32))           # [T, E]
+        chosen = gates if bias is None else gates + bias.astype(jnp.float32)
+        experts = lax.top_k(chosen, k)[1]
+        weights = jnp.take_along_axis(gates, experts, axis=-1)
+    else:
+        gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)  # [T, E]
+        weights, experts = lax.top_k(gates, k)
     if renormalize and k > 1:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
     return weights, experts.astype(jnp.int32), gates
 
 
@@ -199,7 +226,8 @@ def _switch_aux(gates, first_choice):
 
 def top_k_gating(logits, k: int, capacity: int, *, rng=None,
                  noise_policy: Optional[str] = None, train: bool = True,
-                 renormalize: bool = True):
+                 renormalize: bool = True, scoring: str = "softmax",
+                 bias=None, scale: float = 1.0):
     """Compute dispatch/combine tensors with capacity limits, for any k.
 
     logits: [T, E]. Returns (combine [T,E,C] f32, dispatch [T,E,C] bool,
@@ -213,7 +241,8 @@ def top_k_gating(logits, k: int, capacity: int, *, rng=None,
     T, E = logits.shape
     # the division happens after the drop, over the survivors
     weights, experts, gates = route(logits, k, renormalize=False, rng=rng,
-                                    noise_policy=noise_policy, train=train)
+                                    noise_policy=noise_policy, train=train,
+                                    scoring=scoring, bias=bias)
     aux, ce = _switch_aux(gates, experts[:, 0])
     metrics = {"expert_load": ce}
 
@@ -240,6 +269,8 @@ def top_k_gating(logits, k: int, capacity: int, *, rng=None,
         gate_sum = jnp.sum(gate_val, axis=0)
         safe = jnp.where(gate_sum > 0, gate_sum, 1.0)
         combine = combine / safe[:, None, None]
+    if scale != 1.0:
+        combine = combine * scale
 
     dispatch = combine > 0
     metrics["dropped_fraction"] = 1.0 - jnp.sum(dispatch) / (T * k)
@@ -253,8 +284,21 @@ def top_k_gating(logits, k: int, capacity: int, *, rng=None,
 # expert compute
 # --------------------------------------------------------------------------
 
-def _glu_or_gelu(up, gate):
-    return jax.nn.silu(gate) * up if gate is not None else jax.nn.gelu(up)
+def _glu_or_gelu(up, gate, act: str = "gelu"):
+    """Gated (a ``w_gate`` stack: SwiGLU) or plain, by the model's
+    activation: ``relu2`` is ``relu(x)^2``."""
+    if gate is not None:
+        return jax.nn.silu(gate) * up
+    if act == "relu2":
+        return jnp.square(jax.nn.relu(up))
+    return jax.nn.relu(up) if act == "relu" else jax.nn.gelu(up)
+
+
+def _routing(moe_params, cfg) -> dict:
+    """The model's scoring as ``route`` / ``top_k_gating`` take it."""
+    return {"scoring": getattr(cfg, "moe_scoring", "softmax"),
+            "bias": moe_params.get("e_bias"),
+            "scale": float(getattr(cfg, "routed_scaling_factor", 1.0))}
 
 
 class LayerOf:
@@ -281,10 +325,11 @@ def _whole(w, dtype):
     return (w.whole() if isinstance(w, LayerOf) else w).astype(dtype)
 
 
-def _grouped_matmul(rows, w, group_sizes, kernel: bool):
-    """rows [M, K] sorted by expert, w [E, K, N] (or a ``LayerOf`` such),
-    group_sizes [E] (sum <= M) -> [M, N]: row i times the matrix of the
-    expert whose group it is in.
+def _grouped_matmul(rows, w, group_sizes, kernel: bool,
+                    transposed: bool = False):
+    """rows [M, K] sorted by expert, w [E, K, N] (or a ``LayerOf`` such;
+    ``transposed``: [E, N, K]), group_sizes [E] (sum <= M) -> [M, N]: row i
+    times the matrix of the expert whose group it is in.
 
     ``kernel`` (a TPU in bf16; ``_sorts`` has already kept a mesh and
     training away): the Pallas kernel of ``ops/grouped_matmul.py``
@@ -296,16 +341,27 @@ def _grouped_matmul(rows, w, group_sizes, kernel: bool):
     tokens/s with the kernel, 415.7 / 414.6 with ``ragged_dot`` alone
     (PERF.md section 6, PR 26)."""
     if not kernel:
-        return lax.ragged_dot(rows, _whole(w, rows.dtype), group_sizes)
+        w = _whole(w, rows.dtype)
+        return lax.ragged_dot(rows, jnp.swapaxes(w, 1, 2) if transposed else w,
+                              group_sizes)
     from deepspeed_tpu.ops.grouped_matmul import grouped_matmul
     if isinstance(w, LayerOf):
-        return grouped_matmul(rows, w.stack, w.index, group_sizes)
-    return grouped_matmul(rows, w[None], 0, group_sizes)
+        return grouped_matmul(rows, w.stack, w.index, group_sizes, transposed)
+    return grouped_matmul(rows, w[None], 0, group_sizes, transposed)
+
+
+def _w_in(moe_params):
+    """(the up projection's stack, whether its matrices are stored [F, H]):
+    ``w_in`` [E, H, F], or ``w_in_t`` [E, F, H], what a width F off the 128
+    grid is served from (``ops/grouped_matmul.grouped_matmul``)."""
+    if "w_in_t" in moe_params:
+        return moe_params["w_in_t"], True
+    return moe_params["w_in"], False
 
 
 def _use_gmm_kernel(moe_params, dtype) -> bool:
     from deepspeed_tpu.ops.grouped_matmul import supported
-    w = moe_params["w_in"]
+    w = _w_in(moe_params)[0]
     w = w.stack if isinstance(w, LayerOf) else w
     return (dtype == jnp.bfloat16 and w.dtype == dtype
             and supported(*w.shape[-2:]) and supported(*w.shape[-2:][::-1])
@@ -320,7 +376,8 @@ def _one_hot_ffn(moe_params, tokens, logits, cfg, C: int, rng, train,
     with jax.named_scope("route"):
         combine, dispatch, aux, metrics = top_k_gating(
             logits, cfg.top_k, C, rng=rng, noise_policy=cfg.noisy_gate_policy,
-            train=train, renormalize=cfg.norm_topk_prob)
+            train=train, renormalize=cfg.norm_topk_prob,
+            **_routing(moe_params, cfg))
         if _STATE.taps:
             _tap_load(metrics["kept"], cfg.top_k)
     # dispatch: [T,E,C] x [T,H] -> [E,C,H]; GSPMD all-to-alls tokens to the
@@ -329,12 +386,14 @@ def _one_hot_ffn(moe_params, tokens, logits, cfg, C: int, rng, train,
         expert_in = jnp.einsum("tec,th->ech", dispatch.astype(dt), tokens)
         expert_in = _constrain(expert_in, P(expert_axis, None, None))
     with jax.named_scope("experts"):
-        up = jnp.einsum("ech,ehf->ecf", expert_in,
-                        _whole(moe_params["w_in"], dt))
+        w_in, transposed = _w_in(moe_params)
+        up = jnp.einsum("ech,efh->ecf" if transposed else "ech,ehf->ecf",
+                        expert_in, _whole(w_in, dt))
         gate = (jnp.einsum("ech,ehf->ecf", expert_in,
                            _whole(moe_params["w_gate"], dt))
                 if "w_gate" in moe_params else None)
-        out = jnp.einsum("ecf,efh->ech", _glu_or_gelu(up, gate),
+        out = jnp.einsum("ecf,efh->ech",
+                         _glu_or_gelu(up, gate, cfg.activation),
                          _whole(moe_params["w_out"], dt))
         out = _constrain(out, P(expert_axis, None, None))
     with jax.named_scope("combine"):
@@ -350,7 +409,8 @@ def _sorted_ffn(moe_params, tokens, logits, cfg, rng, train):
     with jax.named_scope("route"):
         weights, experts, gates = route(
             logits, k, renormalize=cfg.norm_topk_prob, rng=rng,
-            noise_policy=cfg.noisy_gate_policy, train=train)
+            noise_policy=cfg.noisy_gate_policy, train=train,
+            **_routing(moe_params, cfg))
         aux, _ = _switch_aux(gates, experts[:, 0])
     with jax.named_scope("dispatch"):
         flat = experts.reshape(T * k)
@@ -362,12 +422,13 @@ def _sorted_ffn(moe_params, tokens, logits, cfg, rng, train):
         rows_in = jnp.take(tokens, order // k, axis=0)            # [T*k, H]
     with jax.named_scope("experts"):
         kernel = _use_gmm_kernel(moe_params, dt)
-        up = _grouped_matmul(rows_in, moe_params["w_in"], group_sizes, kernel)
+        w_in, transposed = _w_in(moe_params)
+        up = _grouped_matmul(rows_in, w_in, group_sizes, kernel, transposed)
         gate = (_grouped_matmul(rows_in, moe_params["w_gate"], group_sizes,
                                 kernel)
                 if "w_gate" in moe_params else None)
-        rows_out = _grouped_matmul(_glu_or_gelu(up, gate), moe_params["w_out"],
-                                   group_sizes, kernel)
+        rows_out = _grouped_matmul(_glu_or_gelu(up, gate, cfg.activation),
+                                   moe_params["w_out"], group_sizes, kernel)
     with jax.named_scope("combine"):
         # back to (token, choice) order, then the weighted sum over a
         # token's k rows in float32
@@ -414,8 +475,12 @@ def moe_ffn(moe_params, x, cfg, *, rng=None, train: bool = True,
             expert_axis: str = "expert"):
     """MoE feed-forward over tokens.
 
-    x: [B, S, H]; moe_params: {"wg": [H, E], "w_in": [E, H, F],
-    "w_out": [E, F, H], optional "w_gate": [E, H, F]}.
+    x: [B, S, H]; moe_params: {"wg": [H, E], "w_in": [E, H, F] (or
+    "w_in_t": the same matrices stored [E, F, H], what a width F off the
+    128 grid is served from: ``ops/grouped_matmul.grouped_matmul``),
+    "w_out": [E, F, H], optional "w_gate": [E, H, F], "e_bias": [E] (the
+    sigmoid scoring's correction bias), "shared_w_in" [H, Fs] and
+    "shared_w_out" [Fs, H] (an always-on expert)}.
     Returns (y [B,S,H], aux_loss scalar).
     """
     B, S, H = x.shape
@@ -427,12 +492,23 @@ def moe_ffn(moe_params, x, cfg, *, rng=None, train: bool = True,
     if cfg.drop_tokens:
         cf = cfg.capacity_factor if train else cfg.eval_capacity_factor
         C = _capacity(T, E, cf, cfg.min_capacity)
+        form = "capacity"
         y, aux = _one_hot_ffn(moe_params, tokens, logits, cfg, C, rng, train,
                                expert_axis)
     elif _sorts(T, E, cfg.top_k, train):
+        form = ("sorted/moe_gmm" if _use_gmm_kernel(moe_params, tokens.dtype)
+                else "sorted/ragged_dot")
         y, aux = _sorted_ffn(moe_params, tokens, logits, cfg, rng, train)
     else:
         # capacity = tokens: the masks drop nothing
+        form = "one-hot"
         y, aux = _one_hot_ffn(moe_params, tokens, logits, cfg, T, rng, train,
                                expert_axis)
+    for tap in _STATE.taps:       # a serving engine reports it (`stats()`)
+        tap.form = form
+    if "shared_w_in" in moe_params:
+        with jax.named_scope("shared"):
+            up = tokens @ moe_params["shared_w_in"].astype(tokens.dtype)
+            y = y + _glu_or_gelu(up, None, cfg.activation) \
+                @ moe_params["shared_w_out"].astype(tokens.dtype)
     return y.reshape(B, S, H), aux

@@ -60,10 +60,20 @@ def row_tile(rows: int, experts: int) -> int:
     return min((64, 128, 256), key=cost)
 
 
+def _tile(dim: int, cap: int) -> int:
+    """The tile of a contraction or column extent: the largest multiple of
+    128 up to ``cap`` that divides it, or — a width off the 128 grid, such
+    as 1856 — the whole extent (a block may span a whole dimension whatever
+    its size). 0: no such tile."""
+    if dim % 128:
+        return dim if dim <= 2 * cap else 0
+    return max((t for t in range(128, min(cap, dim) + 1, 128)
+                if dim % t == 0), default=0)
+
+
 def supported(K: int, N: int) -> bool:
     """The kernel tiles K and N without a remainder."""
-    return K % min(TK, K) == 0 and N % min(TN, N) == 0 and K % 128 == 0 \
-        and N % 128 == 0
+    return bool(_tile(K, TK) and _tile(N, TN))
 
 
 def visits(group_sizes, tm: int, tiles_m: int):
@@ -89,7 +99,8 @@ def visits(group_sizes, tm: int, tiles_m: int):
 
 
 def _kernel(layer_ref, offsets_ref, expert_ref, tile_ref, lhs_ref, rhs_ref,
-            out_ref, acc_ref, *, tm: int, tiles_k: int):
+            out_ref, acc_ref, *, tm: int, tiles_k: int,
+            transposed: bool = False):
     del layer_ref                                  # used by the index maps
     v, k_i = pl.program_id(1), pl.program_id(2)
 
@@ -97,8 +108,13 @@ def _kernel(layer_ref, offsets_ref, expert_ref, tile_ref, lhs_ref, rhs_ref,
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(lhs_ref[...], rhs_ref[...],
-                            preferred_element_type=jnp.float32)
+    if transposed:                # rhs tile [tn, tk]: contract the last dims
+        acc_ref[...] += lax.dot_general(
+            lhs_ref[...], rhs_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    else:
+        acc_ref[...] += jnp.dot(lhs_ref[...], rhs_ref[...],
+                                preferred_element_type=jnp.float32)
 
     @pl.when(k_i == tiles_k - 1)
     def _store():
@@ -111,19 +127,28 @@ def _kernel(layer_ref, offsets_ref, expert_ref, tile_ref, lhs_ref, rhs_ref,
                                  ).astype(out_ref.dtype)
 
 
-def grouped_matmul(rows, stack, layer, group_sizes):
+def grouped_matmul(rows, stack, layer, group_sizes, transposed: bool = False):
     """rows [M, K] sorted by expert, stack [L, E, K, N], layer (int32
     scalar, may be traced), group_sizes [E] int32 with sum <= M -> [M, N] in
-    ``rows.dtype``; rows past the groups' sum come back undefined."""
+    ``rows.dtype``; rows past the groups' sum come back undefined.
+
+    ``transposed``: the stack holds each matrix as ``[N, K]`` (``[L, E, N,
+    K]``). An operand of a Mosaic call is read in row-major order, and the
+    TPU stores an array whose last extent is off the 128 grid (an expert
+    width of 1856) with that extent second-to-last, so a ``[.., 2688, 1856]``
+    stack handed to the kernel is first copied WHOLE into row-major order;
+    stored ``[.., 1856, 2688]`` it is read in place."""
     M, K = rows.shape
     L, E, K2, N = stack.shape
+    if transposed:
+        K2, N = N, K2
     assert K == K2 and supported(K, N), (rows.shape, stack.shape)
     tm = row_tile(M, E)
     pad = -M % tm
     if pad:
         rows = jnp.pad(rows, ((0, pad), (0, 0)))
     tiles_m = (M + pad) // tm
-    tk, tn = min(TK, K), min(TN, N)
+    tk, tn = _tile(K, TK), _tile(N, TN)
     tiles_k, tiles_n = K // tk, N // tn
     offsets, expert, tile, num_visits = visits(group_sizes, tm, tiles_m)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -131,6 +156,9 @@ def grouped_matmul(rows, stack, layer, group_sizes):
         grid=(tiles_n, num_visits, tiles_k),
         in_specs=[
             pl.BlockSpec((tm, tk), lambda n, v, k, ly, off, ex, tl: (tl[v], k)),
+            pl.BlockSpec((None, None, tn, tk),
+                         lambda n, v, k, ly, off, ex, tl: (ly[0], ex[v], n, k))
+            if transposed else
             pl.BlockSpec((None, None, tk, tn),
                          lambda n, v, k, ly, off, ex, tl: (ly[0], ex[v], k, n)),
         ],
@@ -139,7 +167,8 @@ def grouped_matmul(rows, stack, layer, group_sizes):
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, tm=tm, tiles_k=tiles_k),
+        functools.partial(_kernel, tm=tm, tiles_k=tiles_k,
+                          transposed=transposed),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M + pad, N), rows.dtype),
         compiler_params=None if _interpret() else pltpu.CompilerParams(

@@ -22,6 +22,7 @@ import pytest
 from deepspeed_tpu.ops import decode_attention as da
 from deepspeed_tpu.ops import flash_attention as fa
 from deepspeed_tpu.ops import sparse_attention as sa
+from deepspeed_tpu.ops import ssm
 
 
 def _flash_grads(wrap=lambda f: f):
@@ -70,6 +71,62 @@ def test_sparse_kernel_names_in_the_lowered_text():
         assert re.search(rf'\b{name}\b[^"]*/pallas_call"', text), name
 
 
+def _scan(x, dt, A, B, C, S0):
+    with jax.named_scope("ssm"), jax.named_scope("scan"):
+        return ssm.ssm_scan(x, dt, A, B, C, S0, chunk=16, kernel=True)
+
+
+def _step(pool, x, dt, A, B, C):
+    with jax.named_scope("ssm"), jax.named_scope("step"):
+        return ssm.ssm_step(pool, 1, x, dt, A, B, C, kernel=True)
+
+
+def test_ssm_kernel_names_in_the_lowered_text():
+    f32 = jnp.float32
+    text = jax.jit(_scan).lower(
+        jnp.zeros((32, 8, 16), f32), jnp.zeros((32, 8), f32), jnp.zeros((8,), f32),
+        jnp.zeros((32, 2, 32), f32), jnp.zeros((32, 2, 32), f32),
+        jnp.zeros((8, 16, 32), f32)).as_text(debug_info=True)
+    assert re.search(r'"jit\(_scan\)/ssm/scan/ssm_scan/pallas_call"', text)
+    text = jax.jit(_step).lower(
+        jnp.zeros((2, 3, 8, 16, 32), f32), jnp.zeros((3, 8, 16), f32),
+        jnp.zeros((3, 8), f32), jnp.zeros((8,), f32), jnp.zeros((3, 2, 32), f32),
+        jnp.zeros((3, 2, 32), f32)).as_text(debug_info=True)
+    assert re.search(r'"jit\(_step\)/ssm/step/ssm_step/pallas_call"', text)
+
+
+def test_the_hybrid_scopes_in_the_lowered_text():
+    """``ssm/conv``, ``ssm/scan``, ``ssm/step`` and ``moe/shared`` reach the
+    lowered text of the programs a serving engine builds."""
+    import os
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))))
+    from benchmark.families.nemotron_h import TOY
+    from deepspeed_tpu.models import make_model
+    from deepspeed_tpu.models.hf_import import hf_config_to_transformer
+    hf = {"model_type": "nemotron_h", "n_shared_experts": 1,
+          "max_position_embeddings": 256, "num_experts_per_tok": 2, **TOY}
+    model = make_model(hf_config_to_transformer(hf, dtype=jnp.float32))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    pools = jax.eval_shape(lambda: model.init_paged_cache(
+        9, 16, dtype=jnp.float32, max_seqs=2))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)       # noqa: E731
+    step = jax.jit(model.decode_step_paged).lower(
+        params, i32(2), pools, i32(2, 4), i32(2)).as_text(debug_info=True)
+    prefill = jax.jit(model.prefill_paged).lower(
+        params, i32(1, 32), pools, i32(2), length=i32(), slot=i32()
+    ).as_text(debug_info=True)
+    for text, scopes in ((step, ("ssm/conv", "ssm/step", "moe/shared")),
+                         (prefill, ("ssm/conv", "ssm/scan", "moe/shared",
+                                    "ssm/state_write"))):
+        for scope in scopes:
+            assert re.search(rf'/layer\d/{scope}/', text), scope
+    # the K/V blocks are written once, after the walk, by the writer every
+    # model's prefill shares (transformer._write_prefill_blocks)
+    assert re.search(r'"jit\([^)]*\)/attn/kv_write/scatter"', prefill)
+
+
 # ---- the instruction names the TPU's compiler gives -------------------------
 
 @pytest.fixture()
@@ -81,6 +138,7 @@ def mosaic(monkeypatch):
     passes do not fit VMEM."""
     monkeypatch.setattr(fa, "_interpret", lambda: False)
     monkeypatch.setattr(da, "_interpret", lambda: False)
+    monkeypatch.setattr(ssm, "_interpret", lambda: False)
     with jax.default_matmul_precision("default"):
         yield
 
@@ -131,3 +189,27 @@ def test_paged_decode_instruction_name(topo, mosaic):
         _sds((slots, MB), jnp.int32, one), _sds((slots,), jnp.int32, one),
         row, row).compile())
     assert [c.split(".")[0] for c in calls] == ["%paged_decode"], calls
+
+
+def test_ssm_instruction_names_at_the_published_sizes(topo, mosaic):
+    """Nemotron-3-Nano's Mamba blocks: 64 heads of 64, 8 groups, state 128;
+    a 1024-token prompt, and one step over 128 slots whose state pool is
+    updated in place (the call's output aliases the donated pool: no
+    temporary of the pool's size)."""
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    bf, f32 = jnp.bfloat16, jnp.float32
+    T, H, P, G, N, S = 1024, 64, 64, 8, 128, 128
+    scan = jax.jit(lambda *a: ssm.ssm_scan(*a, kernel=True)).lower(
+        _sds((T, H, P), bf, one), _sds((T, H), f32, one), _sds((H,), f32, one),
+        _sds((T, G, N), bf, one), _sds((T, G, N), bf, one),
+        _sds((H, P, N), f32, one)).compile()
+    assert [c.split(".")[0] for c in _mosaic_calls(scan)] == ["%ssm_scan"]
+    step = jax.jit(lambda pool, *a: ssm.ssm_step(pool, 2, *a, kernel=True),
+                   donate_argnums=(0,)).lower(
+        _sds((4, S, H, P, N), f32, one), _sds((S, H, P), bf, one),
+        _sds((S, H), f32, one), _sds((H,), f32, one), _sds((S, G, N), bf, one),
+        _sds((S, G, N), bf, one)).compile()
+    assert [c.split(".")[0] for c in _mosaic_calls(step)] == ["%ssm_step"]
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes == 4 * S * H * P * N * 4
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20

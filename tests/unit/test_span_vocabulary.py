@@ -206,6 +206,59 @@ class TestServingRoundSpans:
         assert srv.close() is True
 
 
+class TestHybridRound:
+    """A model with recurrent blocks adds no phase to a round: a prefill is
+    a whole prompt from a zero state that overwrites the slot's rows of the
+    state pool, so a slot given again needs no separate reset dispatch."""
+
+    def _hybrid(self):
+        import sys
+        root = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        sys.path.insert(0, root)
+        from benchmark.families.nemotron_h import TOY
+        from deepspeed_tpu.models.hf_import import hf_config_to_transformer
+        hf = {"model_type": "nemotron_h", "n_shared_experts": 1,
+              "routed_scaling_factor": 2.5, "max_position_embeddings": 256,
+              "num_experts_per_tok": 2, **TOY}
+        model = make_model(hf_config_to_transformer(hf, dtype=jnp.float32))
+        return deepspeed_tpu.init_serving(
+            model, config={"kv_cache_bits": 0}, dtype=jnp.float32,
+            serving=dict(max_seqs=2, block_size=16, max_model_len=128,
+                         decode_quantum=4, prompt_bucket=16))
+
+    def test_a_round_holds_the_phases_every_model_has(self, tmp_path):
+        srv = self._hybrid()
+        srv.run([(_prompt(9), 4)])                  # compiles, off the trace
+        for k in (5, 11, 7):                        # one slot is given again
+            srv.add_request(_prompt(k, seed=k), 5)
+        with _Session(tmp_path) as ses:
+            while srv.scheduler.running or srv.scheduler.num_waiting:
+                srv.step()
+        rounds = ses.named("ds:serve.round")
+        assert rounds
+        for r in rounds:
+            inside = [s[0] for s in ses.spans
+                      if s[0] != "ds:serve.round"
+                      and r[1] <= s[1] and s[2] <= r[2]]
+            assert inside == SERVE_PHASES
+        assert {s[0] for s in ses.spans} == set(SERVE_PHASES) | {
+            "ds:serve.round"}
+        srv.close()
+
+    def test_stats_tell_the_two_kinds_of_state_apart(self):
+        srv = self._hybrid()
+        st = srv.stats()
+        assert st["state_pool_bytes"] > 0 and st["state_slots_live"] == 0
+        assert st["state_pool_bytes"] + st["kv_pool_bytes"] == st["pool_bytes"]
+        srv.close()
+        srv = _serving()
+        st = srv.stats()
+        assert "state_pool_bytes" not in st and "state_slots_live" not in st
+        assert st["kv_pool_bytes"] == st["pool_bytes"]
+        srv.close()
+
+
 class TestPhaseTotals:
     def _rig(self):
         from deepspeed_tpu.inference.serving import ServingEngine as SE
