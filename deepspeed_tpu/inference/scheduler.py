@@ -40,6 +40,7 @@ vLLM default): cheap at serving contexts and needs zero extra pool state.
 
 import collections
 import dataclasses
+import heapq
 import time
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
@@ -207,7 +208,10 @@ class RequestScheduler:
         self.pool_watermark = pool_watermark
         self.waiting: Deque[Request] = collections.deque()
         self.running: List[Request] = []   # admission order (oldest first)
-        self._free_slots = list(range(max_seqs - 1, -1, -1))
+        # a heap: an admission takes the LOWEST free slot, so the running
+        # requests sit in [0, n) with few holes and the engine can size a
+        # decode round by the highest slot alive (serving._slot_ladder)
+        self._free_slots = list(range(max_seqs))
         self._next_rid = 0
         self._next_seq = 0                 # first-admission counter (aging)
 
@@ -305,6 +309,11 @@ class RequestScheduler:
         self.prefix_cache.insert_full(ctx, req.block_ids, valid)
         self.prefix_cache.donate_boundary(ctx, req.block_ids, valid)
 
+    def free_slot(self, slot: int) -> None:
+        """A request left the running set: its slot can be given out again
+        (the lowest free one first)."""
+        heapq.heappush(self._free_slots, slot)
+
     def finish(self, req: Request) -> None:
         """Evict a completed sequence: its prefix publishes to the cache,
         then slot and blocks return to the pool (shared blocks decrement —
@@ -313,7 +322,7 @@ class RequestScheduler:
         req.state = "finished"
         req.finish_t = time.perf_counter()
         self.running.remove(req)
-        self._free_slots.append(req.slot)
+        self.free_slot(req.slot)
         self._release_cow(req)
         self._publish(req)
         if req.block_ids:
@@ -328,7 +337,7 @@ class RequestScheduler:
         (prompt + whatever was generated) stays readable."""
         if req.state == "running":
             self.running.remove(req)
-            self._free_slots.append(req.slot)
+            self.free_slot(req.slot)
             self._release_cow(req)
             self._publish(req)
             if req.block_ids:
@@ -371,7 +380,7 @@ class RequestScheduler:
         #                                        eviction: re-admission
         #                                        recomputes (the engine
         #                                        drops the staged payload)
-        self._free_slots.append(req.slot)
+        self.free_slot(req.slot)
         self._release_cow(req)
         self.allocator.free(req.block_ids, owner=req.rid)
         req.block_ids = []
@@ -529,7 +538,7 @@ class RequestScheduler:
                 # the scatter's write targets.
                 req.cached_rows = req.kv_rows
             req.prefill_done = False
-            req.slot = self._free_slots.pop()
+            req.slot = heapq.heappop(self._free_slots)   # the LOWEST
             req.state = "running"
             if req.admission_seq is None:      # aging: resumed requests
                 req.admission_seq = self._next_seq  # keep their first seq

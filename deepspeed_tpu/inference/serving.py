@@ -317,9 +317,30 @@ def _table_ladder(MB: int) -> tuple:
     three quarters and the whole of the full width ``MB``, rounded up to
     whole columns (32 -> 16, 24, 32; 16 -> 8, 12, 16). A constant of the
     engine's shape, not a setting: one step program is built per entry, and
-    each costs a serve cell ~0.5 s of set-up (why there is no quarter:
-    PERF.md section 6, PR 29)."""
+    each costs a serve cell 0.45-0.7 s of set-up — its tracing and lowering,
+    which no cache keeps (PERF.md section 6, PR 33: six more programs took
+    the chat cell's warm ``setup_s`` from 11.0 to 13.5 s; why there is no
+    quarter of the width)."""
     return tuple(sorted({-(-MB * q // 4) for q in (2, 3, 4)}))
+
+
+def _slot_ladder(max_seqs: int) -> tuple:
+    """The slot counts a decode round may be dispatched at: a quarter of
+    ``max_seqs`` in whole tiles of 16 rows (what a bf16 activation's rows
+    come in on the TPU), and the whole — 48 -> 16, 48; 128 -> 32, 128. An
+    engine whose quarter is half of its slots or more has the whole alone
+    (32 -> 32): the narrow step would save under half of the read and no
+    matmul, and costs what every step program costs, half a second of
+    every start. The scheduler hands out the lowest free slot, so the
+    running requests sit in the first rows and a round whose highest
+    running slot lies below the quarter runs as the quarter's program
+    (``_tables_device``). A constant of the engine's shape like
+    ``_table_ladder``; the quarter is built at the full table width alone
+    (``ServingEngine._step_shapes``): no half, and no narrower table at
+    the quarter, where the width moves a step by a thirtieth (PERF.md
+    sections 5 and 6, PR 33)."""
+    few = -(-max_seqs // 64) * 16
+    return (few, max_seqs) if 2 * few < max_seqs else (max_seqs,)
 
 
 # expert-routing counters of a stats window (ServingEngine._note_expert_load)
@@ -412,6 +433,11 @@ class ServingEngine:
         self.max_model_len = want
         self.MB = self.max_model_len // c.block_size     # table width
         self._table_widths = _table_ladder(self.MB)
+        # a per-slot state pool is updated whole and in place (a Pallas
+        # operand is a whole buffer: a narrower step would copy
+        # ``state[:, :slots]``), so its engine keeps every round at max_seqs
+        self._slot_counts = ((c.max_seqs,) if self._recurrent
+                             else _slot_ladder(c.max_seqs))
         num_blocks = c.num_blocks or (c.max_seqs * self.MB + 1)
         if num_blocks - 1 < self.MB:
             raise ValueError(
@@ -620,9 +646,9 @@ class ServingEngine:
         self._lat = {"spec_steps": 0, "spec_proposed": 0,
                      "spec_accepted": 0, "prefill_chunks": 0,
                      "prefill_chunk_tokens": 0, "cow_forks": 0}
-        # plain decode rounds dispatched per block-table width
-        # (reset_stats windows; _tables_device)
-        self._table_rounds = dict.fromkeys(self._table_widths, 0)
+        # plain decode rounds dispatched per (slot count, block-table
+        # width) (reset_stats windows; _tables_device)
+        self._table_rounds = self._step_shapes()
         # reliability bookkeeping ---------------------------------------
         self._counters = {"shed": 0, "deadline_misses": 0, "degraded": 0,
                           "recoveries": 0, "recovery_ms": 0.0,
@@ -969,13 +995,20 @@ class ServingEngine:
             # shared weights — donating it would force a re-page of
             # every resident adapter each quantum step
             lora = (apool, aidx) if apool is not None else None
+            # the step is sized by its tables: handed the first n of the
+            # engine's slots it works on the first n pending tokens and
+            # hands the vector back whole, so a round that widens again
+            # finds every slot's token
+            n = tables.shape[0]
             with expert_load_tap() as tap:
                 logits, pools = self.model.decode_step_paged(
-                    params, tokens, pools, tables, seq_lens,
+                    params, tokens[:n], pools, tables, seq_lens,
                     active=active, backend=backend, lora=lora)
             self._moe_forms["step"] = tap.form                  # trace time
             nxt = self._sample(logits, key)
-            nxt = jnp.where(active, nxt, tokens)
+            nxt = jnp.where(active, nxt, tokens[:n])
+            if n < tokens.shape[0]:
+                nxt = jnp.concatenate([nxt, tokens[n:]])
             # the tokens travel with the step's expert load [L, E + 1]
             # over the ACTIVE slots (None for a model without experts):
             # collected and fetched together
@@ -987,23 +1020,39 @@ class ServingEngine:
                 if self._pool_shardings is not None else None)
         return jax.jit(step, donate_argnums=(1, 4), out_shardings=outs)
 
+    def _step_shapes(self) -> dict:
+        """{(slot count, table width): 0} over the shapes a decode round's
+        tables may have, each with a step program of its own and a counter
+        of its rounds: every table width at ``max_seqs``, and the narrower
+        slot counts at the full table, in the order of what a step gathers
+        (slots x width blocks a layer). A round takes the first that holds
+        it (``_tables_device``)."""
+        shapes = [(self._slot_counts[-1], W) for W in self._table_widths] \
+            + [(S, self.MB) for S in self._slot_counts[:-1]]
+        return dict.fromkeys(sorted(shapes, key=lambda sh: (sh[0] * sh[1],
+                                                            sh)), 0)
+
     def _get_quantum_step(self):
-        """{table width: the decode step compiled for it}, one program per
-        entry of the ladder (``_table_ladder``). All of them are built when
-        the first is asked for — the first decode round, and again after a
-        backend swap — by lowering on abstract arguments: nothing runs, no
-        pool is donated, and no width is ever compiled inside a serving
-        window, whichever lengths the traffic brings. Every op of the
-        step's read of the pool (block gathers, scores, softmax, P.V) is
-        sized by the table's width, so a round whose longest sequence ends
-        in the first half of the context reads half of what the full table
-        makes it read."""
+        """{(slot count, table width): the decode step compiled for it}, one
+        program per entry of ``_step_shapes``, keyed like the ``shape`` of
+        a round's tables. All of them are built when the first is asked
+        for — the first decode round, and again after a backend swap — by
+        lowering on abstract arguments: nothing runs, no pool is donated,
+        and no shape is ever compiled inside a serving window, whichever
+        lengths and however many requests the traffic brings. Every op of
+        the step's read of the pool (block gathers, scores, softmax, P.V)
+        is sized slots x width, so a round whose requests sit in the first
+        quarter of the slots reads a quarter (48 slots: a third) of what
+        the full tables make it read, and one whose longest sequence ends
+        in the first half of the context half; the matmuls over the
+        weights have that many rows. The per-slot token vector stays
+        ``max_seqs`` long in every program (a prefill writes its first
+        token at its slot)."""
         if self._quantum_step is None:
             import jax
             import jax.numpy as jnp
             from deepspeed_tpu.analysis.program import abstractify
 
-            S = self.config.max_seqs
             sds = jax.ShapeDtypeStruct
             fn = self._quantum_step_fn()
             # the big operands carry the shardings they live in; the small
@@ -1012,27 +1061,27 @@ class ServingEngine:
                 (self.engine.params, self.pools,
                  self.adapter_pool if self._lora else None))
 
-            def lower(W):
+            def lower(S, W):
                 with self.engine.mesh:
                     return fn.lower(
-                        params, pools, sds((S,), jnp.int32),
+                        params, pools, sds(self._tokens.shape, jnp.int32),
                         sds((S, W), jnp.int32), sds((S,), jnp.int32),
                         sds((S,), jnp.bool_), sds((2,), jnp.uint32), apool,
                         sds((S,), jnp.int32))
 
-            # traced and lowered on a fresh thread, one width after the
+            # traced and lowered on a fresh thread, one shape after the
             # other, and compiled (a cache load, once warm) on this one
             # meanwhile: below the serving loop's frames a lowering costs
             # twice what it costs on an empty stack (three programs 3.3 s
             # against 1.5 s in Mixtral's cell, PERF.md section 6, PR 29),
-            # and the widths have to fit a cell's set-up. The thread sees
+            # and the shapes have to fit a cell's set-up. The thread sees
             # no thread-local jax.config context of the caller's; the mesh
             # is entered there
             with ThreadPoolExecutor(1) as pool:
-                lowered = {W: pool.submit(lower, W)
-                           for W in self._table_widths}
-                self._quantum_step = {W: lo.result().compile()
-                                      for W, lo in lowered.items()}
+                lowered = {shape: pool.submit(lower, *shape)
+                           for shape in self._step_shapes()}
+                self._quantum_step = {shape: lo.result().compile()
+                                      for shape, lo in lowered.items()}
         return self._quantum_step
 
     def _get_spec_step(self):
@@ -1333,30 +1382,36 @@ class ServingEngine:
             req._first_dev = (first, None)     # (token, no load): fetched
 
     def _tables_device(self, full: bool = False):
-        """The round's block tables ``ids[max_seqs, W]``, lengths, active
-        mask and adapter indices, on the device. ``W`` is the smallest
-        width of the ladder (``_table_ladder``) that holds the longest
-        ``block_ids`` among the running requests: those already cover the
-        quantum's writes (``Scheduler._grow``), so every row a step reads
-        or writes lies in the first ``W`` columns, and the columns dropped
-        hold only positions past every slot's length, whose probabilities
-        are exact zeros. Inactive slots read column 0 of an all-zero row,
-        the trash block. Only the plain quantum step takes the narrow
-        table; ``full`` (the speculation verify step) keeps all ``MB``
-        columns, as do chunk dispatch (its own ``tab[1, MB]``), the fork
-        and KV import / export: each is a program family of its own to
-        warm, and no benchmark cell runs them."""
+        """The round's block tables ``ids[S, W]``, lengths, active mask and
+        adapter indices, on the device: the first shape of ``_step_shapes``
+        — the one whose step gathers least — with ``S`` above the highest
+        running slot and ``W`` no less than the longest ``block_ids`` among
+        the running requests. The rows dropped hold no request (the
+        scheduler gives out the lowest free slot) and a row's attention
+        sees no other row; the blocks already cover the quantum's writes
+        (``Scheduler._grow``), so every row a step reads or writes lies in
+        the first ``W`` columns, and the columns dropped hold only
+        positions past every slot's length, whose probabilities are exact
+        zeros. Inactive slots among the first ``S`` read column 0 of an
+        all-zero row, the trash block. Only the plain quantum step takes
+        the narrow tables; ``full`` (the speculation verify step) keeps
+        all ``max_seqs`` rows and ``MB`` columns, as do chunk dispatch (its
+        own ``tab[1, MB]``), the fork and KV import / export: each is a
+        program family of its own to warm, and no benchmark cell runs
+        them."""
         import jax.numpy as jnp
         running = self.scheduler.running
-        need = self.MB if full else max(
-            (len(req.block_ids) for req in running), default=0)
-        W = next(w for w in self._table_widths if w >= need)
-        ids = np.zeros((self.config.max_seqs, W), np.int32)
-        lens = np.zeros((self.config.max_seqs,), np.int32)
-        act = np.zeros((self.config.max_seqs,), bool)
+        need = (self.config.max_seqs, self.MB) if full else (
+            max((req.slot for req in running), default=0) + 1,
+            max((len(req.block_ids) for req in running), default=0))
+        S, W = next(shape for shape in self._table_rounds
+                    if shape[0] >= need[0] and shape[1] >= need[1])
+        ids = np.zeros((S, W), np.int32)
+        lens = np.zeros((S,), np.int32)
+        act = np.zeros((S,), bool)
         # per-slot adapter index into the device slot pool (0 = the null
         # adapter): free slots read slot 0 — an exact-zero delta
-        aidx = np.zeros((self.config.max_seqs,), np.int32)
+        aidx = np.zeros((S,), np.int32)
         for req in running:
             ids[req.slot, :len(req.block_ids)] = req.block_ids
             lens[req.slot] = req.cached_rows
@@ -1530,9 +1585,9 @@ class ServingEngine:
                                             for r in self.scheduler.running)
                 tables, seq_lens, active, aidx = self._tables_device(
                     full=spec)
-                # the plain step runs as the program of the table's width
+                # the plain step runs as the program of the tables' shape
                 step_fn = self._get_spec_step() if spec \
-                    else (self._get_quantum_step()[tables.shape[1]]
+                    else (self._get_quantum_step()[tables.shape]
                           if decode else None)
                 tok_mat = None
                 if spec:
@@ -1617,7 +1672,7 @@ class ServingEngine:
             if decode:
                 self._quantum_warm = True
                 if not spec:
-                    self._table_rounds[tables.shape[1]] += 1
+                    self._table_rounds[tables.shape] += 1
             self.pools, self._tokens = p, t
         finally:
             if keep is not None:
@@ -2126,7 +2181,7 @@ class ServingEngine:
             self._kv_staging.pop(req.rid, None)      # export consumed
             if req.state == "running":
                 self.scheduler.running.remove(req)
-                self.scheduler._free_slots.append(req.slot)
+                self.scheduler.free_slot(req.slot)
                 self.scheduler._release_cow(req)
                 self.scheduler._publish(req)
                 if req.block_ids:
@@ -2459,7 +2514,7 @@ class ServingEngine:
         self._lat = {"spec_steps": 0, "spec_proposed": 0,
                      "spec_accepted": 0, "prefill_chunks": 0,
                      "prefill_chunk_tokens": 0, "cow_forks": 0}
-        self._table_rounds = dict.fromkeys(self._table_widths, 0)
+        self._table_rounds = self._step_shapes()
         if self._prefix_cache is not None:
             self._prefix_cache.reset_stats()
         if self._lora:
@@ -2537,11 +2592,13 @@ class ServingEngine:
         | ``sorted/ragged_dot`` | ``capacity``) in each program built so far,
         recorded when the program is traced.
 
-        Block-table width (always on): ``table_width_rounds`` — a dict
-        ``{width in columns: plain decode rounds dispatched at it}`` over
-        the ladder (``_tables_device``; speculation rounds keep the full
-        table and are not counted), the one value that is not a float —
-        and ``table_width_mean`` over those rounds."""
+        The decode step's shape (always on; ``_tables_device``; speculation
+        rounds keep the full tables and are not counted):
+        ``step_shape_rounds`` — a dict ``{"<slots>x<width in columns>":
+        plain decode rounds dispatched at it}`` over both ladders — and
+        ``table_width_rounds`` ``{width: rounds}``, the same rounds summed
+        over the slot counts; ``slot_count_mean`` and ``table_width_mean``
+        over those rounds."""
         done = [r for r in self._finished if r.first_token_t is not None]
         out: Dict[str, Any] = {
             "completed": float(len(self._finished)),
@@ -2616,11 +2673,16 @@ class ServingEngine:
             out["state_pool_bytes"] = float(self._state_bytes())
             out["state_slots_live"] = float(len(self.scheduler.running))
         out.update({k: float(v) for k, v in self._lat.items()})
-        out["table_width_rounds"] = dict(self._table_rounds)
+        out["step_shape_rounds"] = {
+            f"{S}x{W}": n for (S, W), n in self._table_rounds.items()}
+        out["table_width_rounds"] = {
+            w: sum(n for (_, W), n in self._table_rounds.items() if W == w)
+            for w in self._table_widths}
         rounds = sum(self._table_rounds.values())
         if rounds:
-            out["table_width_mean"] = sum(
-                w * n for w, n in self._table_rounds.items()) / rounds
+            out["slot_count_mean"], out["table_width_mean"] = (
+                sum(shape[i] * n for shape, n in self._table_rounds.items())
+                / rounds for i in (0, 1))
         if self._lat["spec_proposed"]:
             out["spec_accept_rate"] = float(round(
                 self._lat["spec_accepted"] / self._lat["spec_proposed"], 4))

@@ -232,6 +232,29 @@ def test_serving_slot_reuse_and_stats(toy):
     srv.close()
 
 
+def test_a_per_slot_state_pool_keeps_every_slot_in_the_step(toy):
+    """An engine that holds a per-slot state pool dispatches every decode
+    round at ``max_seqs`` (ISSUE 33): the step kernel updates ``[blocks,
+    slots, ...]`` whole and in place, so a step over the first rows would
+    copy them out. Engines without one narrow (tests/unit/test_serving.py)."""
+    from deepspeed_tpu.inference import serving
+    _, model, params, ref = toy
+    srv = _serve(model, params, max_seqs=40)
+    assert serving._slot_ladder(40) == (16, 40)
+    assert serving._slot_ladder(128) == (32, 128)
+    assert srv._slot_counts == (40,)
+    assert set(srv._step_shapes()) == {(40, W) for W in srv._table_widths}
+    reqs = [(_ids(9, 70), 6), (_ids(21, 71), 5)]
+    outs = srv.run(reqs)
+    for (p, m), rid in zip(reqs, sorted(outs)):
+        assert list(np.asarray(outs[rid])[-m:]) == _greedy(ref, p, m)
+    st = srv.stats()
+    assert st["slot_count_mean"] == 40
+    assert all(k.startswith("40x") for k in st["step_shape_rounds"])
+    assert srv.pools["ssm"].shape[1] == 40
+    srv.close()
+
+
 def test_serving_preemption_rebuilds_the_state(toy):
     _, model, params, ref = toy
     # 2 slots x 40 new tokens over 8 usable blocks: growth collides

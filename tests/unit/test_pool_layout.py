@@ -97,12 +97,13 @@ def _abstract(tree, sharding):
         tree)
 
 
-def _program(srv, case, kind, one_chip, mb=None):
+def _program(srv, case, kind, one_chip, mb=None, slots=None):
     """(lowered-and-compiled program, abstract pools) of one of the
     engine's three pool-writing functions at the case's shapes (``mb``:
-    another table width than the case's)."""
+    another table width than the case's; ``slots``: the step handed the
+    first ``slots`` rows of the case's slots)."""
     c = CASES[case]
-    S, MB = c["slots"], mb or c["mb"]
+    S, MB = slots or c["slots"], mb or c["mb"]
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -115,8 +116,9 @@ def _program(srv, case, kind, one_chip, mb=None):
     if kind == "step":                    # the decode quantum's one step
         fn = jax.jit(srv._quantum_step_fn().__wrapped__,
                      donate_argnums=(1, 4))
-        args = (params, pools, sds((S,), jnp.int32), sds((S, MB), jnp.int32),
-                sds((S,), jnp.int32), sds((S,), jnp.bool_), key)
+        args = (params, pools, sds((c["slots"],), jnp.int32),
+                sds((S, MB), jnp.int32), sds((S,), jnp.int32),
+                sds((S,), jnp.bool_), key)
     elif kind == "span":                  # speculation verify, T = 4
         fn = jax.jit(srv._get_spec_step().__wrapped__, donate_argnums=(1,))
         args = (params, pools, sds((S, 4), jnp.int32),
@@ -289,6 +291,43 @@ def test_a_narrower_table_narrows_the_read(case, width, one_chip, engines):
     assert not bad, "\n".join(bad)
     assert not layer_slice_ops(hlo, pools)
     assert not widened_view_ops(hlo, pools, c["slots"], width)
+
+
+@pytest.mark.parametrize("share", [4, 2], ids=["quarter", "half"])
+@pytest.mark.parametrize("case", ["chat-int8", "mixtral-int8", "olmoe-int8",
+                                  "chat-bf16"])
+def test_fewer_slots_narrow_the_read(case, share, one_chip, engines):
+    """The read is sized slots x width by the tables' SHAPE (ISSUE 33):
+    handed the first quarter of the cell's slots (of 48: the engine's own
+    ladder) or half of them, the step gathers ``[slots' * width, block, kv heads, head_dim]``
+    of each pool and holds no array of the full ``slots x width`` view, in
+    any type; the pool leaves are still written in place; and the per-slot
+    token vector goes in and comes back ``max_seqs`` long."""
+    from deepspeed_tpu.inference.serving import _slot_ladder
+    c = CASES[case]
+    few = {4: -(-c["slots"] // 32) * 8, 2: c["slots"] // 2}[share]
+    assert few < c["slots"] and _slot_ladder(48) == (16, 48)
+    compiled, pools = _program(engines(case), case, "step", one_chip,
+                               slots=few)
+    hlo = compiled.as_text()
+    leaf = pools["k"]
+    ty = _HLO_NAME[np.dtype(leaf.dtype).name]
+    view = int(np.prod(leaf.shape[2:]))           # block x kv heads x head_dim
+    full, narrow = c["slots"] * c["mb"] * view, few * c["mb"] * view
+    gathers = [line for t, n, op, line, _ in _instructions(hlo)
+               if (t, n, op) == (ty, narrow, "fusion")
+               and f"{ty}[{few * c['mb']},{BS},{c['nkv']},{HD}]" in line]
+    assert len(gathers) == 2, "\n".join(gathers)        # K and V, a layer
+    bad = [line for t, n, op, line, _ in _instructions(hlo, fused_too=True)
+           if n == full and op not in _VIEWS]
+    assert not bad, "\n".join(bad)
+    assert not layer_slice_ops(hlo, pools)
+    assert not widened_view_ops(hlo, pools, few, c["mb"])
+    leaves = ({n: pools[n] for n in "kv"} if case == "olmoe-int8" else pools)
+    bad = whole_pool_ops(hlo, leaves)
+    assert not bad, "\n".join(bad)
+    (_, (tokens, _), lens) = compiled.out_info
+    assert tokens.shape == (c["slots"],) and lens.shape == (few,)
 
 
 # ---- the dropless expert dispatch (ISSUE 26) --------------------------------

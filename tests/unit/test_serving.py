@@ -145,6 +145,33 @@ class TestScheduler:
         s.schedule()
         assert len(r.block_ids) == 3        # clamped, no table overflow
 
+    @pytest.mark.parametrize("leave", ["finish", "cancel", "preempt"])
+    def test_lowest_free_slot_is_handed_out(self, leave):
+        """An admission takes the LOWEST free slot, however the slots came
+        back (ISSUE 33): the running requests stay in the first rows and a
+        decode round can be sized by the highest of them. The stack this
+        replaces handed back the slot freed last."""
+        _, s = _sched(num_blocks=64, max_seqs=6)
+        reqs = [s.submit(np.arange(10), 8) for _ in range(6)]
+        s.schedule()
+        assert [r.slot for r in reqs] == [0, 1, 2, 3, 4, 5]
+        for r in (reqs[1], reqs[4], reqs[3]):       # 3 is freed LAST
+            getattr(s, leave)(r)
+        if leave == "preempt":
+            # the victims head the queue again, newest preemption first
+            assert list(s.waiting) == [reqs[3], reqs[4], reqs[1]]
+            again = s.schedule()["admitted"]
+            assert [(r.rid, r.slot) for r in again] == [
+                (reqs[3].rid, 1), (reqs[4].rid, 3), (reqs[1].rid, 4)]
+            return
+        new = [s.submit(np.arange(10), 8) for _ in range(2)]
+        s.schedule()
+        assert [r.slot for r in new] == [1, 3]
+        s.finish(reqs[0])
+        last = s.submit(np.arange(10), 8)
+        s.schedule()
+        assert last.slot == 0 and sorted(s._free_slots) == [4]
+
 
 # ---------------------------------------------------------------------------
 # Paged vs contiguous decode: bit-for-bit
@@ -394,23 +421,26 @@ def test_serving_int8_kv_pool():
 # Block tables bucketed by the longest live sequence (ISSUE 29)
 # ---------------------------------------------------------------------------
 
-def _drive_widths(srv, script):
-    """Run ``script`` ({round: [(prompt, max_new_tokens), ...]}) to the end
-    -> (outputs by submission order, [(round's table width, longest
-    ``block_ids`` among the running requests when it was built)])."""
+def _drive_widths(srv, script, shapes=False):
+    """Run ``script`` ({round: [(prompt, max_new_tokens[, adapter]), ...]})
+    to the end -> (outputs by submission order, [(round's table width,
+    longest ``block_ids`` among the running requests when it was built)]);
+    ``shapes``: [(the tables' whole shape, highest running slot)]."""
     rounds, seen, rids, outs = 0, [], [], {}
     build = srv._tables_device
 
     def spy(full=False):
         out = build(full)
-        seen.append((out[0].shape[1], max(len(r.block_ids)
-                                          for r in srv.scheduler.running)))
+        running = srv.scheduler.running
+        seen.append((out[0].shape, max(r.slot for r in running)) if shapes
+                    else (out[0].shape[1],
+                          max(len(r.block_ids) for r in running)))
         return out
 
     srv._tables_device = spy
     while rounds <= max(script) or not srv.scheduler.done:
-        for prompt, n in script.get(rounds, ()):
-            rids.append(srv.add_request(prompt, n))
+        for prompt, n, *adapter in script.get(rounds, ()):
+            rids.append(srv.add_request(prompt, n, adapter_id=sum(adapter)))
         for r in srv.step():
             outs[r.rid] = r.output
         rounds += 1
@@ -472,6 +502,109 @@ def test_table_width_follows_the_longest_live_sequence(kv_bits, monkeypatch):
         np.testing.assert_array_equal(got, ref)
 
 
+@pytest.mark.parametrize("kind", ["float-pool", "int8-pool", "lora"])
+def test_slot_count_follows_the_highest_running_slot(kind, monkeypatch):
+    """A decode round is handed only the slots it can reach (ISSUE 33): the
+    smallest count of the ladder above the highest running slot — at the
+    full table width below ``max_seqs``, where a program per width does not
+    pay its set-up, and at the table's width as before at ``max_seqs``. A
+    row's attention sees no other row and the rows left out hold no
+    request, so the tokens are those of an engine whose ladder is
+    ``max_seqs`` alone; the per-slot token vector stays whole, so a round
+    that widens again finds the tokens prefills left beyond the narrow
+    rounds' rows."""
+    from deepspeed_tpu.inference import serving
+    from deepspeed_tpu.inference.lora import make_random_adapter
+    cfg = _cfg()
+    model = make_model(cfg)
+    params = jax.device_get(model.init(jax.random.PRNGKey(0)))
+    rng = np.random.default_rng(33)
+    lora = kind == "lora"
+
+    def req(n, new, i=0):
+        return (rng.integers(0, 128, size=(n,)).astype(np.int32), new,
+                *([i % 3] if lora else []))
+
+    # 40 slots, ladder 16 / 40. Eighteen arrivals fill slots 0-17; sixteen
+    # of them leave after two rounds, the straggler in slot 17 keeps the
+    # rounds wide until it leaves, the one in slot 0 runs on; two arrivals
+    # in round 4 take the lowest free slots, 1 and 2 (the stack would have
+    # given them the slots freed last), and seventeen more in round 12 widen
+    # the rounds again, their first tokens waiting in slots the narrow
+    # rounds left out
+    script = {0: [req(60, 60)] + [req(5 + i, 6, i) for i in range(16)]
+              + [req(20, 22, 1)],
+              4: [req(12, 30, 2), req(30, 26)],
+              12: [req(7 + i, 5, i) for i in range(17)]}
+
+    def engine():
+        srv = deepspeed_tpu.init_serving(
+            model, config={"kv_cache_bits": 8 if kind == "int8-pool" else 0},
+            params=params, dtype=jnp.float32,
+            serving=dict(max_seqs=40, block_size=16, max_model_len=128,
+                         decode_quantum=4, prompt_bucket=16,
+                         **(dict(adapter_slots=3, lora_rank=4) if lora
+                            else {})))
+        for a in (1, 2) if lora else ():
+            srv.register_adapter(a, make_random_adapter(cfg, 4, seed=a,
+                                                        scale=0.2))
+        return srv
+
+    srv = engine()
+    assert srv._slot_counts == (16, 40) and srv._table_widths == (4, 6, 8)
+    compiled = []
+
+    def on(name, secs, **kw):
+        compiled.append((name.rsplit("/", 1)[-1], str(kw.get("fun_name"))))
+
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        outs, seen = _drive_widths(srv, script, shapes=True)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on)
+    # every (slots, width) program is lowered once, at the first decode round
+    assert list(srv._get_quantum_step()) == [(16, 8), (40, 4), (40, 6), (40, 8)]
+    assert compiled.count(("jaxpr_to_mlir_module_duration", "jit(step)")) == 4
+    # every round: the smallest ladder entry above the highest running slot
+    for shape, highest in seen:
+        assert shape[0] == min(n for n in srv._slot_counts if n > highest)
+        assert shape in srv._step_shapes() and (shape[0] == 40 or shape[1] == 8)
+    slots = [shape[0] for shape, _ in seen]
+    assert slots[:2] == [40, 40]
+    # the straggler in slot 17 (22 tokens: six rounds) keeps the round wide
+    # after the sixteen short ones have left, and the round narrows with it
+    assert [h for _, h in seen[2:6]] == [17] * 4 and slots[2:6] == [40] * 4
+    assert slots[6:12] == [16] * 6           # with the two new arrivals in
+    assert [h for _, h in seen[6:8]] == [2, 2]
+    assert slots[12] == 40 and slots[-1] == 16
+    # the counter: one entry a (slots, width), summing to the decode rounds
+    st = srv.stats()
+    shapes = [shape for shape, _ in seen]
+    assert st["step_shape_rounds"] == {
+        f"{S}x{W}": shapes.count((S, W)) for S, W in srv._step_shapes()}
+    assert len({shape for shape in shapes if shape[0] == 40}) > 1
+    assert sum(st["step_shape_rounds"].values()) == len(seen)
+    assert st["table_width_rounds"] == {
+        W: sum(1 for shape in shapes if shape[1] == W) for W in (4, 6, 8)}
+    assert st["slot_count_mean"] == pytest.approx(np.mean(slots))
+    assert st["table_width_mean"] == pytest.approx(
+        np.mean([shape[1] for shape in shapes]))
+    srv.reset_stats()
+    st = srv.stats()
+    assert not any(st["step_shape_rounds"].values())
+    assert "slot_count_mean" not in st and "table_width_mean" not in st
+    assert srv._tokens.shape == (40,)
+    # the same tokens as with every slot in every round
+    monkeypatch.setattr(serving, "_slot_ladder", lambda S: (S,))
+    full = engine()
+    assert full._slot_counts == (40,) and len(full._step_shapes()) == 3
+    want, seen_full = _drive_widths(full, script, shapes=True)
+    assert {shape[0] for shape, _ in seen_full} == {40}
+    assert [h for _, h in seen_full] == [h for _, h in seen]
+    for got, ref in zip(outs, want):
+        np.testing.assert_array_equal(got, ref)
+
+
 def test_no_step_program_is_built_after_the_first_decode_round():
     """Every width's step program is built with the first, from abstract
     arguments: however the lengths move afterwards, no decode step is
@@ -507,8 +640,8 @@ def test_no_step_program_is_built_after_the_first_decode_round():
             del names[:]
             srv.add_request(np.arange(5, dtype=np.int32), 6)
             srv.step()                    # the first decode round
-            # one lowering and one compile a width, here and nowhere else
-            assert names.count("jit(step)") == 2 * len(srv._table_widths)
+            # one lowering and one compile a shape, here and nowhere else
+            assert names.count("jit(step)") == 2 * len(srv._step_shapes())
             del names[:]
             srv.run(load())
             assert "jit(step)" not in names, names
