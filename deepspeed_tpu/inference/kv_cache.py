@@ -286,10 +286,11 @@ def pool_bytes(cfg, num_blocks: int, block_size: int, dtype=None,
     ``stats()["pool_bytes"]`` report — is this divided by the tp degree
     (``parallel.partitioning.sharded_bytes`` prices it from the committed
     shardings; the memory-law test pins per_device * tp == logical)."""
-    # the layers that own K/V: all of a homogeneous stack, the attention
-    # blocks of a hybrid one, whose per-slot recurrent state (for
-    # ``max_seqs`` slots) is counted beside them
-    L = getattr(cfg, "attention_blocks", cfg.num_layers)
+    # the planes of K/V a token keeps (``TransformerConfig.kv_planes``): one
+    # per layer of a homogeneous stack, per attention block of a hybrid one
+    # — whose per-slot recurrent state (for ``max_seqs`` slots) is counted
+    # beside them — and per pass of a looped one
+    L = getattr(cfg, "kv_planes", cfg.num_layers)
     nkv, hd = cfg.kv_heads, cfg.dim_per_head
     rows = L * num_blocks * nkv * block_size
     state = state_pool_bytes(cfg, max_seqs, dtype)

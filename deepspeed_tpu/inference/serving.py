@@ -343,7 +343,7 @@ def _slot_ladder(max_seqs: int) -> tuple:
     return (few, max_seqs) if 2 * few < max_seqs else (max_seqs,)
 
 
-# expert-routing counters of a stats window (ServingEngine._note_expert_load)
+# expert-routing counters of a stats window (ServingEngine._note_counters)
 _MOE_COUNTERS = {"kept": 0, "asked": 0, "max_over_mean": 0.0, "rounds": 0,
                  "touched": 0.0, "steps": 0, "prefill_touched": 0.0,
                  "prefills": 0}
@@ -641,8 +641,12 @@ class ServingEngine:
         self._stats_t0: Optional[float] = None
         # latency-frontier counters (reset_stats windows)
         self._itl_ms: List[float] = []
-        # expert-routing counters (reset_stats windows; _note_expert_load)
+        # expert-routing counters (reset_stats windows; _note_counters)
         self._moe = dict(_MOE_COUNTERS)
+        # a looped model's exit distribution, summed over every sampled
+        # position of the stats window, then their count (_note_counters)
+        self._ut_steps = int(getattr(mcfg, "ut_steps", 1))
+        self._exit = np.zeros((self._ut_steps + 1,), np.float64)
         self._lat = {"spec_steps": 0, "spec_proposed": 0,
                      "spec_accepted": 0, "prefill_chunks": 0,
                      "prefill_chunk_tokens": 0, "cow_forks": 0}
@@ -955,19 +959,24 @@ class ServingEngine:
         fn = self._prefill_fns.get(P)
         if fn is None:
             import jax
+            from deepspeed_tpu.models.looped import exit_tap
             from deepspeed_tpu.moe.sharded_moe import expert_load_tap
 
             def prefill(params, ids, pools, block_ids, length, key, *slot):
                 # slot: the request's slot, for a model that keeps a
                 # recurrent state per slot; nothing for every other model
-                with expert_load_tap() as tap:
+                with expert_load_tap() as tap, exit_tap() as gate:
                     last, pools = self.model.prefill_paged(
                         params, ids, pools, block_ids, length=length,
                         **({"slot": slot[0]} if slot else {}))
                 self._moe_forms[f"prefill_{P}"] = tap.form      # trace time
-                # the first token travels with the expert load [L, E + 1] of
-                # the REAL prompt tokens (None for a model without experts)
-                return (self._sample(last, key), tap.stacked()), pools
+                # the first token travels with the program's counters: the
+                # expert load [L, E + 1] of the REAL prompt tokens (None for
+                # a model without experts) and the exit distribution
+                # [passes + 1] of the position it was sampled at (None for
+                # a model that is not looped)
+                return (self._sample(last, key),
+                        (tap.stacked(), gate.summed())), pools
 
             outs = ((self._repl_sharding, self._pool_shardings)
                     if self._pool_shardings is not None else None)
@@ -985,6 +994,7 @@ class ServingEngine:
         trace by the program name ``jit_step``."""
         import jax
         import jax.numpy as jnp
+        from deepspeed_tpu.models.looped import exit_tap
         from deepspeed_tpu.moe.sharded_moe import expert_load_tap
 
         backend = self.decode_backend
@@ -1000,7 +1010,7 @@ class ServingEngine:
             # hands the vector back whole, so a round that widens again
             # finds every slot's token
             n = tables.shape[0]
-            with expert_load_tap() as tap:
+            with expert_load_tap() as tap, exit_tap() as gate:
                 logits, pools = self.model.decode_step_paged(
                     params, tokens[:n], pools, tables, seq_lens,
                     active=active, backend=backend, lora=lora)
@@ -1009,10 +1019,11 @@ class ServingEngine:
             nxt = jnp.where(active, nxt, tokens[:n])
             if n < tokens.shape[0]:
                 nxt = jnp.concatenate([nxt, tokens[n:]])
-            # the tokens travel with the step's expert load [L, E + 1]
-            # over the ACTIVE slots (None for a model without experts):
-            # collected and fetched together
-            return (pools, (nxt, tap.stacked()),
+            # the tokens travel with the step's counters over the ACTIVE
+            # slots — the expert load [L, E + 1] (None for a model without
+            # experts) and the exit distribution [passes + 1] (None for a
+            # model that is not looped): collected and fetched together
+            return (pools, (nxt, (tap.stacked(), gate.summed())),
                     seq_lens + active.astype(jnp.int32))
 
         r = self._repl_sharding
@@ -1274,7 +1285,7 @@ class ServingEngine:
         self._tokens = self._tokens.at[req.slot].set(first[0][0])
         req.cached_rows = ctx.size
         req.prefill_done = True
-        # (token, the prompt's expert load): fetched at round boundary
+        # (token, the prefill's counters): fetched at round boundary
         req._first_dev = first
         self._publish_prefill(req, ctx)
 
@@ -1379,7 +1390,7 @@ class ServingEngine:
         if final:
             self._tokens = self._tokens.at[req.slot].set(first[0])
             req.prefill_done = True
-            req._first_dev = (first, None)     # (token, no load): fetched
+            req._first_dev = (first, (None, None))   # (token, no counters)
 
     def _tables_device(self, full: bool = False):
         """The round's block tables ``ids[S, W]``, lengths, active mask and
@@ -1631,7 +1642,7 @@ class ServingEngine:
                             for k in keys:
                                 if self._epoch != epoch:
                                     return None
-                                # t: (tokens, the step's expert load)
+                                # t: (tokens, the step's counters)
                                 p, t, lens = step_fn(params, p, t, tables,
                                                      lens, active, k, apool,
                                                      aidx)
@@ -1649,8 +1660,8 @@ class ServingEngine:
             # round: the sampled tokens (quantum steps or the verify step's
             # accept verdict) AND every pending prefill/chunk token ride a
             # single device_get (under its own watchdog: a device that
-            # never answers hangs HERE). The expert load of each decode
-            # step and of each pending prefill rides the same call.
+            # never answers hangs HERE). The counters of each decode
+            # step and of each pending prefill ride the same call.
             with span("ds:serve.fetch") as sp:
                 toks, firsts, spec_host = self._with_watchdog(
                     lambda: jax.device_get(
@@ -1678,7 +1689,7 @@ class ServingEngine:
             if keep is not None:
                 self.allocator.set_reserve(0)
         with span("ds:serve.commit") as sp:
-            firsts = self._note_expert_load(firsts)
+            firsts = self._note_counters(firsts)
             if spec_host is not None:
                 finished = self._commit_spec(spec_host, pending, firsts)
             else:
@@ -1707,20 +1718,30 @@ class ServingEngine:
             req.max_gap_ms = max(req.max_gap_ms or 0.0, gap_ms)
         req.last_token_t = now
 
-    def _note_expert_load(self, fetched) -> list:
-        """One round's routing counters, from arrays the round's one fetch
-        brought. ``fetched``: ([(first token, load) of each pending prefill],
-        [load of each decode step]); a load is [L, E + 1] int32 —
-        assignments kept per expert and layer, then the assignments asked
-        for (``sharded_moe._LoadTap``) — over the ACTIVE slots of a decode
-        step or the real tokens of a whole-prompt prefill, or None (a model
-        without experts, a chunked prefill). Speculation verify spans are
-        not counted. Returns the first tokens alone."""
-        firsts, step_loads = fetched
-        prefill_loads = [ld for _, ld in firsts]
+    def _note_counters(self, fetched) -> list:
+        """One round's program counters, from arrays the round's one fetch
+        brought. ``fetched``: ([(first token, counters) of each pending
+        prefill], [counters of each decode step]); counters are (expert
+        load, exit distribution). A load is [L, E + 1] int32 — assignments
+        kept per expert and layer, then the assignments asked for
+        (``sharded_moe._LoadTap``) — over the ACTIVE slots of a decode step
+        or the real tokens of a whole-prompt prefill, or None (a model
+        without experts, a chunked prefill). An exit distribution is
+        float32 [passes + 1] — per pass the probability of leaving there,
+        summed over the active slots of a decode step or taken at the
+        position a whole-prompt prefill samples from, then how many were
+        summed (``looped._ExitTap``) — or None (a model that is not looped).
+        Speculation verify spans are not counted. Returns the first tokens
+        alone."""
+        firsts, step_counters = fetched
+        prefill_counters = [c for _, c in firsts]
         firsts = [f for f, _ in firsts]
-        step_loads = [ld for ld in step_loads if ld is not None]
-        prefill_loads = [ld for ld in prefill_loads if ld is not None]
+        exits = [c[1] for c in step_counters + prefill_counters
+                 if c[1] is not None]
+        if exits:
+            self._exit += np.sum(np.asarray(exits, np.float64), axis=0)
+        step_loads = [c[0] for c in step_counters if c[0] is not None]
+        prefill_loads = [c[0] for c in prefill_counters if c[0] is not None]
         if not (step_loads or prefill_loads):
             return firsts
         m = self._moe
@@ -1970,8 +1991,14 @@ class ServingEngine:
         assembles the full head dim, so tp2->tp2 and tp1->tp1 both ship
         the same bytes; tp CROSSING is refused by _check_geometry for the
         continuation-determinism reason, not here)."""
-        k = self.pools["k"]              # [L, NB, bs, nkv, hd]
-        return {"num_layers": int(k.shape[0]),
+        k = self.pools["k"]              # [planes, NB, bs, nkv, hd]
+        mcfg = self.model.config
+        # the planes a block carries, and what they are planes OF: a looped
+        # engine and an unlooped one of equal plane counts hold different
+        # things at the same index
+        return {"kv_planes": int(k.shape[0]),
+                "num_layers": int(getattr(mcfg, "num_layers", k.shape[0])),
+                "ut_steps": int(getattr(mcfg, "ut_steps", 1)),
                 "kv_heads": int(k.shape[3]),
                 "head_dim": int(k.shape[4]),
                 "block_size": int(self.config.block_size),
@@ -2068,7 +2095,7 @@ class ServingEngine:
             refuse(f"blocks={n} does not cover rows={rows} at block_size="
                    f"{self.config.block_size} (table width {self.MB})")
         k = payload["data"].get("k")
-        want_shape = (local["num_layers"], n, local["kv_heads"],
+        want_shape = (local["kv_planes"], n, local["kv_heads"],
                       local["block_size"], local["head_dim"])
         if getattr(k, "shape", None) != want_shape:
             refuse(f"k payload shape {getattr(k, 'shape', None)} != "
@@ -2511,6 +2538,7 @@ class ServingEngine:
                           "handoff_fallbacks": 0}
         self._itl_ms = []
         self._moe = dict(_MOE_COUNTERS)
+        self._exit[:] = 0.0
         self._lat = {"spec_steps": 0, "spec_proposed": 0,
                      "spec_accepted": 0, "prefill_chunks": 0,
                      "prefill_chunk_tokens": 0, "cow_forks": 0}
@@ -2573,7 +2601,7 @@ class ServingEngine:
         (each request's longest gap between two token deliveries).
 
         Expert routing (always on, a model with experts only; counted over
-        active slots and real prompt tokens, ``_note_expert_load``):
+        active slots and real prompt tokens, ``_note_counters``):
         ``moe_load_max_over_mean`` (fullest expert's assignments over the
         mean expert's, per layer; mean over layers and rounds),
         ``moe_experts_touched_per_step`` (distinct experts a decode step's
@@ -2581,6 +2609,16 @@ class ServingEngine:
         the same for a whole-prompt prefill's real tokens) and
         ``moe_dropped_share`` (assignments the dispatch dropped; 0 for a
         dropless model).
+
+        A looped model (always on, ``ut_steps`` > 1 only): ``ut_steps``,
+        ``kv_planes`` (K/V planes a token keeps: passes x layers),
+        ``kv_bytes_per_token`` (logical pool bytes of one token over all
+        planes), and from the exit gate, over every position a token was
+        sampled at (the active slots of the plain decode steps, the last
+        prompt position of a whole-prompt prefill; ``_note_counters``):
+        ``exit_step_expected`` (the mean of sum_t (t + 1) p_t, in 1 ..
+        ut_steps: the passes an exit policy at that distribution would run)
+        and ``exit_cdf`` (mean cumulative p after each pass).
 
         The two kinds of state (always on): ``kv_pool_bytes`` (the K/V block
         pool's share of ``pool_bytes``) and, for a model with recurrent
@@ -2669,6 +2707,17 @@ class ServingEngine:
         if forms:
             out["moe_dispatch"] = forms
         out["kv_pool_bytes"] = float(self.pool_bytes - self._state_bytes())
+        if self._ut_steps > 1:
+            mcfg = self.model.config
+            out["ut_steps"] = float(self._ut_steps)
+            out["kv_planes"] = float(mcfg.kv_planes)
+            out["kv_bytes_per_token"] = float(pool_bytes(
+                mcfg, 1, 1, dtype=self.engine.dtype))
+            if self._exit[-1]:
+                p = self._exit[:-1] / self._exit[-1]
+                out["exit_step_expected"] = float(
+                    np.dot(np.arange(1, p.size + 1), p))
+                out["exit_cdf"] = [float(x) for x in np.cumsum(p)]
         if self._recurrent:
             out["state_pool_bytes"] = float(self._state_bytes())
             out["state_slots_live"] = float(len(self.scheduler.running))

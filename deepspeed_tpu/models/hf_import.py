@@ -184,6 +184,23 @@ def _olmoe_table(cfg):
     return L
 
 
+def _ouro_table(cfg):
+    """Ouro (ByteDance/Ouro): the Llama backbone with a second norm after
+    each sublayer (``input_layernorm_2`` after attention,
+    ``post_attention_layernorm_2`` after the feed-forward) and the exit gate
+    (``early_exit_gate``, a Linear [1, H] with a bias) beside the final
+    norm."""
+    pre = r"^(?:model\.)?layers\.(\d+)\."
+    return _llama_table(cfg) + [
+        (pre + r"input_layernorm_2\.weight$",
+         ("layers", "ln1_post_scale"), None),
+        (pre + r"post_attention_layernorm_2\.weight$",
+         ("layers", "ln2_post_scale"), None),
+        (r"^(?:model\.)?early_exit_gate\.weight$", ("exit_gate_w",), _t),
+        (r"^(?:model\.)?early_exit_gate\.bias$", ("exit_gate_b",), None),
+    ]
+
+
 def _opt_table(cfg):
     S = cfg.max_seq_len
 
@@ -551,7 +568,7 @@ _SKIP = re.compile(r"(rotary_emb\.inv_freq|\.attn\.(bias|masked_bias)$"
 
 _TABLES = {"llama": _llama_table, "gpt2": _gpt2_table,
            "mixtral": _mixtral_table, "olmoe": _olmoe_table,
-           "opt": _opt_table,
+           "ouro": _ouro_table, "opt": _opt_table,
            "bloom": _bloom_table, "bert": _bert_table,
            "roberta": _roberta_table, "clip": _clip_table,
            "gptj": _gptj_table, "gpt_neox": _gptneox_table,
@@ -567,6 +584,8 @@ def _detect_family(keys) -> str:
             return "mixtral"
         if ".mlp.experts." in k or ".self_attn.q_norm." in k:
             return "olmoe"
+        if "input_layernorm_2" in k or "early_exit_gate" in k:
+            return "ouro"
         if k.startswith("roberta."):
             return "roberta"
         if "text_model." in k or "token_embedding" in k:
@@ -743,6 +762,8 @@ def load_hf_params(src, cfg, *, shardings=None, dtype=None,
                 # llama backbone + experts in the config: the first keys of
                 # a shard (embed_tokens, layer 0's attention) look alike
                 fam = "olmoe" if cfg.qk_norm else "mixtral"
+            if fam == "llama" and cfg.sandwich_norm:
+                fam = "ouro"            # likewise: its own keys come later
             table = _TABLES[fam](cfg)
             logger.info(f"hf import: detected {fam}-family checkpoint")
             for k, a in pending:
@@ -922,7 +943,8 @@ def export_hf_state_dict(params, cfg, *, family: Optional[str] = None
             or cfg.num_experts > 1
             or cfg.activation == "relu" or cfg.position_type == "alibi"
             or cfg.parallel_block or not cfg.causal or not cfg.qkv_bias
-            or cfg.type_vocab_size or cfg.head_bias or cfg.attn_windows):
+            or cfg.type_vocab_size or cfg.head_bias or cfg.attn_windows
+            or cfg.sandwich_norm or cfg.exit_gate):
         raise NotImplementedError(
             "export_hf_state_dict covers the Llama and GPT-2 layouts; "
             "Mixtral/OPT/BLOOM/BERT/GPT-J/GPT-NeoX export is import-only "
@@ -1061,6 +1083,62 @@ def _nemotron_h_kwargs(get) -> dict:
         time_step_floor=float(get("time_step_floor", 1e-4)))
 
 
+class EarlyExitUnsupported(NotImplementedError):
+    """A looped model whose ``early_exit_threshold`` is below 1: a token
+    would leave the stack at the first pass whose cumulative exit
+    probability reaches the threshold and write no K/V for the passes it
+    skipped, and which K/V later tokens then read there is a cache policy
+    the config does not give. Only the published threshold 1 (every pass
+    runs) is served."""
+
+    def __init__(self, threshold):
+        super().__init__(
+            f"early_exit_threshold={threshold!r} < 1 is not supported: a "
+            "token that leaves a looped stack early writes no K/V for the "
+            "passes it skips, and the config does not say what later tokens "
+            "read there. The published value 1 runs every pass")
+        self.threshold = threshold
+
+
+def _ouro_kwargs(get) -> dict:
+    """``ouro`` (ByteDance Ouro, a looped language model): the
+    ``num_hidden_layers`` layers run ``total_ut_steps`` times over the same
+    weights, each pass with K/V planes of its own; a block norms its
+    sublayers' outputs as well as their inputs; the final norm ends every
+    pass; an exit gate reads each pass's output."""
+    threshold = get("early_exit_threshold", 1.0)
+    if threshold is not None and float(threshold) < 1.0:
+        raise EarlyExitUnsupported(threshold)
+    bad = sorted(set(get("layer_types") or ()) - {"full_attention"})
+    if bad:
+        raise ValueError(f"ouro layer_types has {bad}: only full_attention "
+                         "layers are supported")
+    if get("use_sliding_window", False):
+        raise ValueError("ouro use_sliding_window=true is not supported")
+    if get("rope_scaling") is not None:
+        raise ValueError(f"ouro rope_scaling={get('rope_scaling')!r} is not "
+                         "supported (the published config has null)")
+    if get("hidden_act", "silu") != "silu":
+        raise ValueError(f"ouro hidden_act={get('hidden_act')!r} is not "
+                         "supported (the published config has 'silu')")
+    for key in ("attention_bias", "mlp_bias"):
+        if get(key, False):
+            raise ValueError(f"ouro {key}=true is not supported")
+    return dict(
+        vocab_size=get("vocab_size"), hidden_size=get("hidden_size"),
+        num_layers=get("num_hidden_layers"),
+        num_heads=get("num_attention_heads"),
+        num_kv_heads=get("num_key_value_heads"), head_dim=get("head_dim"),
+        intermediate_size=get("intermediate_size"),
+        max_seq_len=get("max_position_embeddings", 4096),
+        rope_theta=float(get("rope_theta", 10000.0)),
+        norm_eps=get("rms_norm_eps", 1e-6),
+        position_type="rotary", activation="silu_glu", norm_type="rmsnorm",
+        tie_embeddings=bool(get("tie_word_embeddings", False)),
+        ut_steps=int(get("total_ut_steps", 1)), sandwich_norm=True,
+        exit_gate=True)
+
+
 def hf_config_to_transformer(hf_cfg, **overrides):
     """Build a TransformerConfig from a transformers PretrainedConfig (or a
     config.json dict)."""
@@ -1110,6 +1188,8 @@ def hf_config_to_transformer(hf_cfg, **overrides):
                 moe_aux_loss_weight=float(get("router_aux_loss_coef", 0.01)))
     elif mt == "nemotron_h":
         kw = _nemotron_h_kwargs(get)
+    elif mt == "ouro":
+        kw = _ouro_kwargs(get)
     elif mt == "opt":
         if get("word_embed_proj_dim", get("hidden_size")) != get("hidden_size"):
             raise ValueError(

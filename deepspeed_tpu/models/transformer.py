@@ -33,6 +33,7 @@ from jax.sharding import PartitionSpec as P
 from deepspeed_tpu.ops.decode_attention import paged_decode_attention
 from deepspeed_tpu.ops.flash_attention import flash_attention
 from deepspeed_tpu.moe import sharded_moe as _moe
+from deepspeed_tpu.models import looped as _looped
 
 Params = Dict[str, Any]
 
@@ -156,6 +157,28 @@ class TransformerConfig:
     time_step_min: float = 0.001
     time_step_max: float = 0.1
     time_step_floor: float = 1e-4
+    # ARCHITECTURE (ouro): a LOOPED stack. The `num_layers` layers run
+    # `ut_steps` times over the SAME weights, the final norm is applied at
+    # the end of every pass and what it gives is what the next pass starts
+    # from (the last pass's goes to the head). A pass keeps K/V of its own:
+    # the cache has `kv_planes` = ut_steps x layers planes, pass t's layer i
+    # at plane t * num_layers + i. `sandwich_norm`: an RMSNorm AFTER each
+    # sublayer too, before its output joins the residual (`ln1_post_scale`,
+    # `ln2_post_scale`). `exit_gate`: a linear gate [H, 1] + bias on each
+    # pass's output, whose sigmoid gives the exit distribution over passes
+    # (models/looped.py); every pass always runs, and a serving engine
+    # reads the distribution as a counter. 1 / False: every other family.
+    ut_steps: int = 1
+    sandwich_norm: bool = False
+    exit_gate: bool = False
+    # INITIALISER of the norm scales (`init_params` only; a checkpoint
+    # brings its own). 0 / 1: every scale starts at 1, as a trained-from-
+    # scratch model's does. `norm_init_jitter` j draws the block's norm
+    # scales and the final norm's in U(1 - j, 1 + j) times their start;
+    # `post_norm_init` is the start of the scales AFTER a sublayer
+    # (`sandwich_norm`), the branch scale of a deep residual stack.
+    norm_init_jitter: float = 0.0
+    post_norm_init: float = 1.0
     remat: bool = False
     # none | dots_saveable | save_nothing | dots_and_attn (dots + the flash
     # kernel's named outputs: the backward reuses O/log-sum-exp instead of
@@ -215,6 +238,13 @@ class TransformerConfig:
         if self.block_pattern is None:
             return self.num_layers
         return self.block_pattern.count("*")
+
+    @property
+    def kv_planes(self) -> int:
+        """Planes of K/V cache a token keeps: one per pass and block that
+        owns K/V. THE number every cache, pool, byte count and handoff
+        geometry is sized by."""
+        return self.ut_steps * self.attention_blocks
 
     @property
     def dim_per_head(self) -> int:
@@ -305,6 +335,13 @@ def init_params(key, cfg: TransformerConfig) -> Params:
     def normal(key, shape, scale=std):
         return (jax.random.normal(key, shape) * scale).astype(dt)
 
+    def norm_scale(key, shape, start=1.0):
+        j = cfg.norm_init_jitter
+        if not j:
+            return jnp.full(shape, start, dt)
+        return jax.random.uniform(key, shape, jnp.float32, start * (1 - j),
+                                  start * (1 + j)).astype(dt)
+
     # per-layer params, stacked on a leading L dim
     lkeys = jax.random.split(next(k), 12)
 
@@ -312,9 +349,10 @@ def init_params(key, cfg: TransformerConfig) -> Params:
         return (jax.random.normal(key, (L,) + shape) * scale).astype(dt)
 
     out_scale = std / math.sqrt(2 * L)  # gpt-2 residual init scaling
+    skeys = jax.random.split(lkeys[11], 4)
     layers = {
-        "ln1_scale": jnp.ones((L, H), dt),
-        "ln2_scale": jnp.ones((L, H), dt),
+        "ln1_scale": norm_scale(skeys[0], (L, H)),
+        "ln2_scale": norm_scale(skeys[2], (L, H)),
         "wq": stacked(lkeys[0], (H, nh * hd)),
         "wk": stacked(lkeys[1], (H, nkv * hd)),
         "wv": stacked(lkeys[2], (H, nkv * hd)),
@@ -325,6 +363,9 @@ def init_params(key, cfg: TransformerConfig) -> Params:
     if cfg.qk_norm:
         layers["q_norm"] = jnp.ones((L, nh * hd), dt)
         layers["k_norm"] = jnp.ones((L, nkv * hd), dt)
+    if cfg.sandwich_norm:
+        layers["ln1_post_scale"] = norm_scale(skeys[1], (L, H), cfg.post_norm_init)
+        layers["ln2_post_scale"] = norm_scale(skeys[3], (L, H), cfg.post_norm_init)
     if cfg.num_experts > 1:
         E = cfg.num_experts
         layers["wg"] = stacked(lkeys[7], (H, E))
@@ -356,8 +397,16 @@ def init_params(key, cfg: TransformerConfig) -> Params:
         "tok_embed": normal(next(k), (cfg.vocab_size, H)),
         "layers": layers,
     }
+    gk = (jax.random.split(next(k), 3)
+          if cfg.exit_gate or cfg.norm_init_jitter else (None,))
     if cfg.final_norm:
-        params["final_norm_scale"] = jnp.ones((H,), dt)
+        params["final_norm_scale"] = norm_scale(gk[0], (H,))
+    if cfg.exit_gate:
+        # the gate starts scale-free: a unit-RMS stream times std
+        # 2 / sqrt(H) gives logits of std ~2 at any width, so the exit
+        # distribution starts spread over the passes
+        params["exit_gate_w"] = normal(gk[1], (H, 1), scale=2 / math.sqrt(H))
+        params["exit_gate_b"] = normal(gk[2], (1,), scale=1.0)
     if cfg.position_type == "learned":
         params["pos_embed"] = normal(next(k), (cfg.max_seq_len, H), scale=0.01)
     if cfg.type_vocab_size:
@@ -396,6 +445,9 @@ def logical_axes(cfg: TransformerConfig) -> Params:
         # reduction over the whole (global) projection under GSPMD
         layers["q_norm"] = ("layers", "qkv")
         layers["k_norm"] = ("layers", "qkv")
+    if cfg.sandwich_norm:
+        layers["ln1_post_scale"] = ("layers", "unmodeled")
+        layers["ln2_post_scale"] = ("layers", "unmodeled")
     if cfg.num_experts > 1:
         layers["wg"] = ("layers", "embed", None)
         layers["moe_w_in"] = ("layers", "expert", "embed", "mlp")
@@ -429,6 +481,9 @@ def logical_axes(cfg: TransformerConfig) -> Params:
     }
     if cfg.final_norm:
         axes["final_norm_scale"] = ("unmodeled",)
+    if cfg.exit_gate:
+        axes["exit_gate_w"] = ("unmodeled", None)
+        axes["exit_gate_b"] = (None,)
     if cfg.position_type == "learned":
         axes["pos_embed"] = (None, "embed")
     if cfg.type_vocab_size:
@@ -1320,7 +1375,9 @@ def transformer_layer(x, layer_params, cfg: TransformerConfig, mask=None,
                       positions=None, dropout_rng=None, deterministic=True,
                       cache=None, return_kv: bool = False, attn_window=None,
                       paged=None, lora=None):
-    """One pre-norm block: x + attn(ln1(x)); x + mlp(ln2(x)).
+    """One pre-norm block: x + attn(ln1(x)); x + mlp(ln2(x)). With the
+    after-sublayer scales in the tree (``sandwich_norm``):
+    x + ln1_post(attn(ln1(x))); x + ln2_post(mlp(ln2(x))).
 
     cache=(ck, cv, index[, read_len]): decode mode — x is [B, 1, H]. The
     buffer is NOT modified: attention treats the fresh (k, v) row as a
@@ -1462,6 +1519,10 @@ def transformer_layer(x, layer_params, cfg: TransformerConfig, mask=None,
         attn_out = attn_out + _lora_delta(attn_flat, lora[0]["o"], lora[1])
     if "bo" in p:
         attn_out = attn_out + p["bo"].astype(h.dtype)
+    if "ln1_post_scale" in p:
+        # sandwich norm: the sublayer's output is normed before it joins
+        # the residual
+        attn_out = _norm(attn_out, p["ln1_post_scale"], None, cfg)
     if cfg.parallel_block:
         # GPT-J/NeoX: one residual, both sublayers read the SAME input x
         # (GPT-J shares a single LN — its import fills both slots with ln_1)
@@ -1529,6 +1590,8 @@ def transformer_layer(x, layer_params, cfg: TransformerConfig, mask=None,
             out = _wrow(act, p["w_out"])
             if "b_out" in p:
                 out = out + p["b_out"].astype(h.dtype)
+    if "ln2_post_scale" in p:
+        out = _norm(out, p["ln2_post_scale"], None, cfg)
     if cfg.parallel_block:
         x = (x + _dropout(attn_out, cfg, dropout_rng, deterministic, 0)
              + _dropout(out, cfg, dropout_rng, deterministic, 1))
@@ -1613,6 +1676,33 @@ def _fetch_layer(layer_p, cfg: TransformerConfig):
     from jax.memory import Space
     return jax.tree.map(
         lambda a: jax.device_put(a, Space.Device).astype(cfg.dtype), layer_p)
+
+
+def _pass_end(x, params: Params, cfg: TransformerConfig):
+    """What ends each pass of a looped stack (``looped.walk``): the final
+    norm — its output is what the next pass starts from, and the last pass's
+    goes to the head, which therefore norms nothing again — and the exit
+    gate's value on it, for a listening engine (else None)."""
+    x = _norm(x, params["final_norm_scale"], params.get("final_norm_bias"),
+              cfg)
+    return x, _looped.gate(x, params)
+
+
+def _walk_layers(body, x, params: Params, cfg: TransformerConfig):
+    """``looped.walk`` for a decode walk: ``body(x, i, t)`` over the layers'
+    indices (the weights at ``i``, the cache at ``looped.plane(i, t, L)``),
+    once per pass."""
+    return _looped.walk(body, x, cfg.num_layers, cfg.ut_steps,
+                        lambda x: _pass_end(x, params, cfg))
+
+
+def _head_norm(x, params: Params, cfg: TransformerConfig):
+    """The final norm ahead of the head. A looped stack's last pass has
+    applied it (``_pass_end``)."""
+    if cfg.final_norm and cfg.ut_steps == 1:
+        x = _norm(x, params["final_norm_scale"],
+                  params.get("final_norm_bias"), cfg)
+    return x
 
 
 def forward(params: Params, input_ids, cfg: TransformerConfig, *,
@@ -1715,6 +1805,9 @@ def forward(params: Params, input_ids, cfg: TransformerConfig, *,
     if use_pld and not cfg.scan_layers:
         raise NotImplementedError("progressive_layer_drop requires "
                                   "scan_layers=True")
+    if cfg.ut_steps > 1 and (use_pld or use_ltd or not cfg.scan_layers):
+        raise _looped.LoopedModelUnsupported(
+            "an unrolled, token-dropping or layer-dropping walk")
     aux_total = jnp.float32(0.0)
     kv_stack = None
     listening = _moe.expert_load_wanted()
@@ -1740,11 +1833,17 @@ def forward(params: Params, input_ids, cfg: TransformerConfig, *,
     elif cfg.scan_layers and not use_ltd:
         # "layers" scope: under scan every layer shares the one traced body,
         # so the trace join attributes the stack in aggregate (per-layer
-        # splits need scan_layers=False — the unrolled path names each one)
-        with jax.named_scope("layers"):
-            (x, _, aux_total), kv_stack = lax.scan(
-                body, (x, dropout_rng, aux_total),
-                (layers, wins) if wins is not None else layers)
+        # splits need scan_layers=False — the unrolled path names each one).
+        # A looped stack scans the same slices once per pass; its K/V come
+        # back one plane per (pass, layer)
+        def end(c):
+            x_n, lam = _pass_end(c[0], params, cfg)
+            return (x_n,) + c[1:], lam
+
+        (x, _, aux_total), kv_stack = _looped.walk(
+            lambda c, xs, t: body(c, xs), (x, dropout_rng, aux_total),
+            (layers, wins) if wins is not None else layers, cfg.ut_steps,
+            end)
     else:
         n_layers = jax.tree.leaves(layers)[0].shape[0]
         carry = (x, dropout_rng, aux_total)
@@ -1798,9 +1897,7 @@ def forward(params: Params, input_ids, cfg: TransformerConfig, *,
         kv_stack, loads = kv_stack
         _moe.record_expert_load(loads)
 
-    if cfg.final_norm:
-        x = _norm(x, params["final_norm_scale"],
-                  params.get("final_norm_bias"), cfg)
+    x = _head_norm(x, params, cfg)
     if return_hidden:
         return x, aux_total
     with jax.named_scope("lm_head"):
@@ -1936,7 +2033,8 @@ def cross_entropy_loss(logits, labels, ignore_index: int = -100):
 
 def init_cache(cfg: TransformerConfig, batch_size: int, max_len: int,
                dtype=None) -> Params:
-    """Preallocated KV buffers [L, B, n_kv, max_len, head_dim] + cursor.
+    """Preallocated KV buffers [planes, B, n_kv, max_len, head_dim] + cursor
+    (``cfg.kv_planes``: a plane per layer, and per pass of a looped stack).
 
     Fixed shapes so prefill/decode each compile exactly once; the kv-head dim
     carries the "heads" logical axis so TP shards the cache like the weights.
@@ -1947,7 +2045,7 @@ def init_cache(cfg: TransformerConfig, batch_size: int, max_len: int,
     attention reads half the bytes (see _quant_kv / _decode_attention).
     """
     dtype = dtype or cfg.dtype
-    L, nkv, hd = cfg.num_layers, cfg.kv_heads, cfg.dim_per_head
+    L, nkv, hd = cfg.kv_planes, cfg.kv_heads, cfg.dim_per_head
     out = {"index": jnp.zeros((), jnp.int32)}
     if cfg.kv_cache_bits == 8:
         out["k"] = jnp.zeros((L, batch_size, nkv, max_len, hd), jnp.int8)
@@ -1998,11 +2096,14 @@ def prefill(params: Params, input_ids, cfg: TransformerConfig, cache: Params,
     # serves every prompt length in the same padded-shape bucket
     true_len = jnp.asarray(S if length is None else length, jnp.int32)
     # an expert-load tap counts the real prompt tokens, not the bucket's pad
+    # ... and an exit-gate tap the position whose logits are returned
     with _moe.counted_tokens(jnp.broadcast_to(
-            jnp.arange(S)[None] < true_len, input_ids.shape)):
+            jnp.arange(S)[None] < true_len, input_ids.shape)), \
+        _looped.counted_tokens(jnp.broadcast_to(
+            jnp.arange(S)[None] == true_len - 1, input_ids.shape)):
         logits, kv = forward(params, input_ids, cfg,
                              attention_mask=attention_mask, return_kv=True)
-    k, v = kv  # [L, B, S, nkv, hd] -> cache layout [L, B, nkv, S, hd]
+    k, v = kv  # [planes, B, S, nkv, hd] -> cache layout [planes, B, nkv, S, hd]
     k, v = jnp.swapaxes(k, 2, 3), jnp.swapaxes(v, 2, 3)
     if cfg.kv_cache_bits == 8:
         kq, ks = _quant_kv(k)
@@ -2067,14 +2168,16 @@ def decode_step(params: Params, token, cfg: TransformerConfig,
     wins = (jnp.asarray(cfg.attn_windows, jnp.int32)
             if cfg.attn_windows else None)
 
-    def body(x_c, i):
+    def body(x_c, i, t):
         layer_p = at_layer(params["layers"], i)
-        ck = lax.dynamic_index_in_dim(cache["k"], i, 0, keepdims=False)
-        cv = lax.dynamic_index_in_dim(cache["v"], i, 0, keepdims=False)
+        # the weights at layer i, the cache at the plane of (pass, layer)
+        pl = _looped.plane(i, t, cfg.num_layers)
+        ck = lax.dynamic_index_in_dim(cache["k"], pl, 0, keepdims=False)
+        cv = lax.dynamic_index_in_dim(cache["v"], pl, 0, keepdims=False)
         if int8_kv:
-            sc = (lax.dynamic_index_in_dim(cache["k_scale"], i, 0,
+            sc = (lax.dynamic_index_in_dim(cache["k_scale"], pl, 0,
                                            keepdims=False),
-                  lax.dynamic_index_in_dim(cache["v_scale"], i, 0,
+                  lax.dynamic_index_in_dim(cache["v_scale"], pl, 0,
                                            keepdims=False))
             c = (ck, cv, index, read_len, sc)
         else:
@@ -2087,9 +2190,8 @@ def decode_step(params: Params, token, cfg: TransformerConfig,
             attn_window=None if wins is None else wins[i])
         return y, (k_row, v_row)
 
-    x, (k_rows, v_rows) = lax.scan(body, x,
-                                   jnp.arange(cfg.num_layers))
-    # one tiny [L, B, nkv, 1, hd] column write — the ring buffers update
+    x, (k_rows, v_rows) = _walk_layers(body, x, params, cfg)
+    # one tiny [planes, B, nkv, 1, hd] column write — the ring buffers update
     # in place (XLA aliases the dus when the cache is a loop carry /
     # donated input), instead of the scan re-stacking full buffers
     if int8_kv:
@@ -2110,9 +2212,7 @@ def decode_step(params: Params, token, cfg: TransformerConfig,
                                          (0, 0, 0, index, 0))
         new_v = lax.dynamic_update_slice(cache["v"], v_rows,
                                          (0, 0, 0, index, 0))
-    if cfg.final_norm:
-        x = _norm(x, params["final_norm_scale"],
-                  params.get("final_norm_bias"), cfg)
+    x = _head_norm(x, params, cfg)
     logits = lm_head_logits(x, params)
     new_cache = {"k": new_k, "v": new_v, "index": index + 1}
     if int8_kv:
@@ -2128,6 +2228,11 @@ def init_suffix(cfg: TransformerConfig, batch_size: int, seg_len: int,
     O(seg) per token instead of the ring buffer's O(T). Float caches keep
     the suffix in the CACHE's dtype (merge is a plain cast-free write);
     int8 caches keep it in compute dtype (merge quantizes)."""
+    if cfg.ut_steps > 1:
+        # decode_step_suffix unrolls its layers in Python: a looped stack
+        # would unroll ut_steps x layers of them. generate() decodes a
+        # looped model through decode_step (make_model offers no suffix)
+        raise _looped.LoopedModelUnsupported("the two-level suffix decode")
     L, nkv, hd = cfg.num_layers, cfg.kv_heads, cfg.dim_per_head
     dtype = cfg.dtype
     if cache is not None and cache["k"].dtype != jnp.int8:
@@ -2151,6 +2256,8 @@ def decode_step_suffix(params: Params, token, cfg: TransformerConfig,
     decode workspace of inference_context.h, which likewise never
     reallocates the big buffer inside the token loop.
     """
+    if cfg.ut_steps > 1:
+        raise _looped.LoopedModelUnsupported("the two-level suffix decode")
     if token.ndim == 1:
         token = token[:, None]
     B = token.shape[0]
@@ -2252,7 +2359,9 @@ def merge_suffix(cfg: TransformerConfig, cache: Params,
 def init_paged_cache(cfg: TransformerConfig, num_blocks: int,
                      block_size: int, dtype=None) -> Params:
     """Block pools, stored TOKEN-major: ``k``, ``v``
-    [L, NB, block_size, n_kv, head_dim]. One token's row is the whole
+    [planes, NB, block_size, n_kv, head_dim] (``cfg.kv_planes``: a plane
+    per layer, and per pass of a looped stack; written L below). One
+    token's row is the whole
     (n_kv, head_dim) minor tile, so the row a decode step, a span or a
     prefill writes is scattered IN PLACE. Head-major
     ([.., n_kv, block_size, head_dim]) a row is one sub-tile line of every
@@ -2278,7 +2387,7 @@ def init_paged_cache(cfg: TransformerConfig, num_blocks: int,
     whole blocks to and from the head-major order [.., n_kv, block_size,
     head_dim] that KV handoff payloads keep."""
     dtype = dtype or cfg.dtype
-    L, nkv, hd = cfg.num_layers, cfg.kv_heads, cfg.dim_per_head
+    L, nkv, hd = cfg.kv_planes, cfg.kv_heads, cfg.dim_per_head
     shape = (L, num_blocks, block_size, nkv, hd)
     if cfg.kv_cache_bits == 8:
         plane = (L, num_blocks, nkv * block_size)
@@ -2386,9 +2495,10 @@ def decode_step_paged(params: Params, tokens, cfg: TransformerConfig,
     wins = (jnp.asarray(cfg.attn_windows, jnp.int32)
             if cfg.attn_windows else None)
 
-    def body(x_c, i):
+    def body(x_c, i, t):
         layer_p = at_layer(params["layers"], i)
-        # the WHOLE pools: the layer is a coordinate of the read's gather
+        # the WHOLE pools: the plane — the layer, and the pass of a looped
+        # stack — is a coordinate of the read's gather
         sc = (pools["k_scale"], pools["v_scale"]) if int8_kv else None
         c = (pools["k"], pools["v"], seq_lens, None, sc)
         if cfg.offload_params:
@@ -2401,17 +2511,18 @@ def decode_step_paged(params: Params, tokens, cfg: TransformerConfig,
         with _moe.layer_load_tap() as tap:
             y, _, (k_row, v_row) = transformer_layer(
                 x_c, layer_p, cfg, positions=positions, deterministic=True,
-                cache=c, return_kv=False, paged=(block_tables, backend, i),
+                cache=c, return_kv=False,
+                paged=(block_tables, backend,
+                       _looped.plane(i, t, cfg.num_layers)),
                 attn_window=None if wins is None else wins[i], lora=lora_i)
         return y, (k_row, v_row, tap and tap.stacked())
 
-    # an expert-load tap counts the active slots: the others compute in
-    # lockstep
-    with jax.named_scope("layers"), _moe.counted_tokens(active):
-        x, (k_rows, v_rows, loads) = lax.scan(body, x,
-                                              jnp.arange(cfg.num_layers))
+    # an expert-load tap and an exit-gate tap count the active slots: the
+    # others compute in lockstep
+    with _moe.counted_tokens(active), _looped.counted_tokens(active):
+        x, (k_rows, v_rows, loads) = _walk_layers(body, x, params, cfg)
     _moe.record_expert_load(loads)
-    # one [L, S, nkv, hd] scatter writes every layer's fresh row at
+    # one [planes, S, nkv, hd] scatter writes every plane's fresh row at
     # (block_tables[s, len // bs], len % bs), a whole minor tile of the
     # token-major pool; inactive slots hit the trash block (duplicate trash
     # writes are unordered and never read)
@@ -2430,9 +2541,7 @@ def decode_step_paged(params: Params, tokens, cfg: TransformerConfig,
                     "v": v_rows.astype(pools["v"].dtype)}
         new_pools = _scatter_rows(pools, blk, off, rows)
     with jax.named_scope("lm_head"):
-        if cfg.final_norm:
-            x = _norm(x, params["final_norm_scale"],
-                      params.get("final_norm_bias"), cfg)
+        x = _head_norm(x, params, cfg)
         logits = lm_head_logits(x, params)
     return logits[:, 0, :], new_pools
 
@@ -2493,9 +2602,9 @@ def decode_span_paged(params: Params, tokens, cfg: TransformerConfig,
 
     sliced, held = _hold_expert_stacks(params["layers"], cfg)
 
-    def body(x_c, i):
+    def body(x_c, i, t):
         layer_p = _held_layer(at_layer(sliced, i), held, i)
-        # the WHOLE pools: the layer is a coordinate of the read's gather
+        # the WHOLE pools: the plane is a coordinate of the read's gather
         sc = (pools["k_scale"], pools["v_scale"]) if int8_kv else None
         c = (pools["k"], pools["v"], seq_lens, None, sc)
         if cfg.offload_params:
@@ -2508,7 +2617,9 @@ def decode_span_paged(params: Params, tokens, cfg: TransformerConfig,
         with _moe.layer_load_tap() as tap:
             y, _, (k_row, v_row) = transformer_layer(
                 x_c, layer_p, cfg, positions=positions, deterministic=True,
-                cache=c, return_kv=False, paged=(block_tables, backend, i),
+                cache=c, return_kv=False,
+                paged=(block_tables, backend,
+                       _looped.plane(i, t, cfg.num_layers)),
                 attn_window=None if wins is None else wins[i], lora=lora_i)
         # rows: [S, nkv, T, hd]
         return y, (k_row, v_row, tap and tap.stacked())
@@ -2516,9 +2627,8 @@ def decode_span_paged(params: Params, tokens, cfg: TransformerConfig,
     # an expert-load tap counts the rows that are written: no pad token of a
     # bucketed chunk, no inactive slot
     counted = active[:, None] & (jnp.arange(T)[None, :] < n_rows[:, None])
-    with jax.named_scope("layers"), _moe.counted_tokens(counted):
-        x, (k_rows, v_rows, loads) = lax.scan(body, x,
-                                              jnp.arange(cfg.num_layers))
+    with _moe.counted_tokens(counted), _looped.counted_tokens(counted):
+        x, (k_rows, v_rows, loads) = _walk_layers(body, x, params, cfg)
     _moe.record_expert_load(loads)
     # one [S*T]-row scatter writes every (slot, position) pair's fresh row
     # across all layers; pad/inactive rows route to the trash block 0
@@ -2549,10 +2659,7 @@ def decode_span_paged(params: Params, tokens, cfg: TransformerConfig,
                     "v": v_rows.astype(pools["v"].dtype)}
         new_pools = _scatter_rows(pools, blk, off, jax.tree.map(flat, rows))
     with jax.named_scope("lm_head"):
-        if cfg.final_norm:
-            x = _norm(x, params["final_norm_scale"],
-                      params.get("final_norm_bias"), cfg)
-        return lm_head_logits(x, params), new_pools
+        return lm_head_logits(_head_norm(x, params, cfg), params), new_pools
 
 
 def prefill_paged(params: Params, input_ids, cfg: TransformerConfig,
@@ -2777,10 +2884,19 @@ def _make_hybrid_model(cfg: TransformerConfig, name: str) -> ModelSpec:
 
 
 def make_model(cfg: TransformerConfig, name: str = "transformer") -> ModelSpec:
+    _looped.check(cfg)
     if cfg.block_pattern:
         return _make_hybrid_model(cfg, name)
+    # the two-level suffix decode unrolls its layers: a looped model is
+    # decoded through decode_step, whose cache has a plane per pass
+    suffix = {} if cfg.ut_steps > 1 else dict(
+        init_suffix=lambda batch_size, seg_len, cache=None:
+            init_suffix(cfg, batch_size, seg_len, cache=cache),
+        decode_step_suffix=lambda params, token, cache, suffix, **kw:
+            decode_step_suffix(params, token, cfg, cache, suffix, **kw),
+        merge_suffix=lambda cache, suffix: merge_suffix(cfg, cache, suffix))
     return ModelSpec(
-        **_common_spec(cfg, name),
+        **_common_spec(cfg, name), **suffix,
         init_cache=lambda batch_size, max_len, dtype=None:
             init_cache(cfg, batch_size, max_len, dtype=dtype),
         prefill=lambda params, input_ids, cache, **kw:
@@ -2788,11 +2904,6 @@ def make_model(cfg: TransformerConfig, name: str = "transformer") -> ModelSpec:
         decode_step=lambda params, token, cache, **kw:
             decode_step(params, token, cfg, cache, **kw),
         cache_axes=lambda: cache_logical_axes(cfg),
-        init_suffix=lambda batch_size, seg_len, cache=None:
-            init_suffix(cfg, batch_size, seg_len, cache=cache),
-        decode_step_suffix=lambda params, token, cache, suffix, **kw:
-            decode_step_suffix(params, token, cfg, cache, suffix, **kw),
-        merge_suffix=lambda cache, suffix: merge_suffix(cfg, cache, suffix),
         init_paged_cache=lambda num_blocks, block_size, dtype=None:
             init_paged_cache(cfg, num_blocks, block_size, dtype=dtype),
         prefill_paged=lambda params, input_ids, pools, block_ids, **kw:

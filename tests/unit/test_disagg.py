@@ -329,7 +329,8 @@ class TestKvHandoff:
             assert pl["data"][n].shape == want[n].shape
             assert pl["data"][n].tobytes() == want[n].tobytes(), n
         assert pl["geometry"] == {
-            "num_layers": L, "kv_heads": nkv, "head_dim": hd,
+            "kv_planes": L, "num_layers": L, "ut_steps": 1,
+            "kv_heads": nkv, "head_dim": hd,
             "block_size": bs, "kv_bits": 8, "dtype": "int8"}
         assert pl["schema"] == 1 and pl["crc"] == kv_payload_crc(want)
 

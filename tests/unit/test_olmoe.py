@@ -489,10 +489,12 @@ def test_counters_ride_the_rounds_one_fetch(monkeypatch):
     out = jax.eval_shape(srv._quantum_step_fn().__wrapped__, srv.engine.params,
                          srv.pools, i32(4), i32(4, srv.MB), i32(4),
                          jax.ShapeDtypeStruct((4,), jnp.bool_), key)
-    assert (out[1][1].shape, out[1][1].dtype) == ((L, E + 1), jnp.int32)   # beside the tokens
+    load, exits = out[1][1]                  # the counters, beside the tokens
+    assert (load.shape, load.dtype) == ((L, E + 1), jnp.int32) and exits is None
     out = jax.eval_shape(srv._get_prefill_fn(64).__wrapped__, srv.engine.params,
                          i32(1, 64), srv.pools, i32(1), i32(), key)
-    assert (out[0][1].shape, out[0][1].dtype) == ((L, E + 1), jnp.int32)   # beside the first token
+    load, exits = out[0][1]                  # beside the first token
+    assert (load.shape, load.dtype) == ((L, E + 1), jnp.int32) and exits is None
 
     gets = []
     real_get = jax.device_get

@@ -421,3 +421,99 @@ def test_dropless_dispatch_is_proportional_to_the_assignments(one_chip, monkeypa
     text, _ = compiled_text(srv, "prefill")
     srv.close()
     assert _largest_arrays(text, E * T_ * H)                 # the control
+
+
+# ---- a looped model's pool: 192 planes, 8.4 GB (ISSUE 35) -------------------
+
+def _dus_fusions(hlo: str) -> set:
+    """Names of the fused computations whose ROOT is a dynamic-update-slice:
+    a prefill's block write once the compiler has turned its scatter into
+    one, in place like the scatter."""
+    comps, _ = _computations(hlo)
+    return {name for name, lines in comps.items()
+            if any(l.lstrip().startswith("ROOT") and " dynamic-update-slice(" in l
+                   for l in lines)}
+
+
+@pytest.mark.parametrize("kind,width", [("step", 10), ("prefill", 128),
+                                        ("prefill", 192)])
+def test_the_looped_cells_programs_fit_the_chip_and_write_the_pool_in_place(
+        kind, width, one_chip, monkeypatch):
+    """Ouro-2.6B at the PUBLISHED widths — 48 layers walked 4 times, a pool
+    of 192 planes x 161 blocks (benchmark/configs/ouro-2.6b-serve.json) —
+    through the engine's own step (16 slots x 10 columns, the one shape the
+    cell's rounds take) and prefill functions: the program compiles for the
+    described v5e with the layers SCANNED (a loop of passes around a loop
+    of layers, not 192 unrolled bodies), arguments + temporaries fit the
+    chip's 15.75 GiB, and no op writes a whole K or V leaf (4.2 GB each:
+    beside 12.75 GiB of arguments ONE copy does not fit) but the row
+    scatter and the block write."""
+    import json
+    import os
+    from deepspeed_tpu.inference.serving import ServingConfig, ServingEngine
+    from deepspeed_tpu.models import transformer as T
+    from deepspeed_tpu.models.hf_import import hf_config_to_transformer
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs", "ouro-2.6b-serve.json")) as f:
+        conf = json.load(f)
+    hf = {k: v for k, v in conf.items() if k not in (
+        "source", "reduced", "assumed", "deployment", "run", "correct")}
+    serving = conf["run"]["serving"]
+    cfg = hf_config_to_transformer(hf, max_seq_len=serving["max_model_len"],
+                                   dtype=jnp.bfloat16, kv_cache_bits=8)
+    model = make_model(cfg)
+    # the engine's parameters (bf16, fused projections) and pool, as shapes
+    params = _abstract(jax.eval_shape(lambda: T.fuse_layer_stack(
+        jax.tree.map(lambda a: a.astype(jnp.bfloat16),
+                     model.init(jax.random.PRNGKey(0))), cfg)), one_chip)
+    pools = _abstract(jax.eval_shape(lambda: model.init_paged_cache(
+        serving["num_blocks"], BS, dtype=jnp.bfloat16)), one_chip)
+    assert pools["k"].shape == (192, 161, BS, 16, HD) and pools["k"].dtype == jnp.int8
+    # the engine's jitted functions without an engine: nothing of this size
+    # is ever placed here
+    srv = object.__new__(ServingEngine)
+    srv.model, srv.decode_backend = model, "xla"
+    srv.config = ServingConfig(max_seqs=serving["max_seqs"])
+    srv._moe_forms, srv._prefill_fns = {}, {}
+    srv._repl_sharding = srv._pool_shardings = None
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    S, key = serving["max_seqs"], sds((2,), jnp.uint32)
+    if kind == "step":
+        fn = jax.jit(srv._quantum_step_fn().__wrapped__, donate_argnums=(1, 4))
+        args = (params, pools, sds((S,), jnp.int32), sds((S, width), jnp.int32),
+                sds((S,), jnp.int32), sds((S,), jnp.bool_), key)
+    else:
+        fn = jax.jit(srv._get_prefill_fn(width).__wrapped__, donate_argnums=(2,))
+        args = (params, sds((1, width), jnp.int32), pools,
+                sds((width // BS,), jnp.int32), sds((), jnp.int32), key)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        with jax.default_matmul_precision("default"):
+            compiled = fn.lower(*args).compile()
+    finally:
+        monkeypatch.undo()
+    hlo, mem = compiled.as_text(), compiled.memory_analysis()
+    resident = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    print(f"{kind} {width}: arguments {mem.argument_size_in_bytes / 2**30:.2f} GiB, "
+          f"temporaries {mem.temp_size_in_bytes / 2**30:.2f} GiB")
+    assert resident < 15.75 * 2**30, resident
+    assert mem.alias_size_in_bytes >= 161 * 64 * 811_008     # the pool, donated
+    # passes around layers (+ the flash kernel's own loops in a prefill)
+    assert 2 <= hlo.count(" while(") <= 6, hlo.count(" while(")
+    in_place = _dus_fusions(hlo)
+    whole = {l.strip().split(" = ")[0]: l for l in hlo.splitlines()}
+
+    def callee(line):              # `line` is cut short: find it whole
+        m = re.search(r"calls=%([\w.\-]+)", whole[line.split(" = ")[0]])
+        return m and m.group(1)
+    bad = [line for line in whole_pool_ops(hlo, {n: pools[n] for n in "kv"})
+           if callee(line) not in in_place]
+    assert not bad, "\n".join(bad)
+    assert not layer_slice_ops(hlo, pools)
+    if kind == "step":
+        assert not widened_view_ops(hlo, pools, S, width)
+        # the exit gate's counter leaves with the tokens
+        (_, (tokens, (load, exits)), lens) = compiled.out_info
+        assert load is None and exits.shape == (cfg.ut_steps + 1,)
