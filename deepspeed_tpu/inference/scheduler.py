@@ -33,6 +33,14 @@ Policy (the vLLM shape):
     per-request TTFT/total deadlines at round boundaries.
   - Eviction: a finished sequence frees its slot and blocks at the next
     boundary; freed blocks admit the queue head immediately.
+  - Looking ahead: the serving engine dispatches a round before it has
+    fetched the last steps of the one before, so a request may have
+    ``inflight_rows`` rows (and as many tokens) on the device that the
+    host has not seen. Growth
+    and the tables' lengths count them; a request whose budget those
+    tokens exhaust is ENDING — ``schedule`` gives its slot and blocks to
+    the same round's admissions and the engine finishes it when the
+    tokens arrive.
 
 Preempted requests resume by RE-PREFILLING prompt+generated (recompute, the
 vLLM default): cheap at serving contexts and needs zero extra pool state.
@@ -69,8 +77,10 @@ class Request:
     prompt: np.ndarray                     # [P] int32 (original prompt)
     max_new_tokens: int
     submit_t: float = 0.0
-    # lifecycle: waiting -> running -> finished (preempt: back to waiting;
-    # a missed deadline or shed: -> cancelled)
+    # lifecycle: waiting -> running (-> ending) -> finished (preempt: back
+    # to waiting; a missed deadline or shed: -> cancelled). ending: the
+    # tokens in flight exhaust the budget — slot and blocks are given
+    # again, the request finishes when its last tokens are committed
     state: str = "waiting"
     slot: Optional[int] = None
     block_ids: List[int] = dataclasses.field(default_factory=list)
@@ -79,6 +89,11 @@ class Request:
     # length; each decode step adds one) — the serving engine's masks and
     # the scheduler's block-growth math both read THIS, not len(context)
     cached_rows: int = 0
+    # rows (one sampled token each) of a decode round dispatched and not
+    # yet committed: the engine adds the quantum at dispatch and moves it
+    # into cached_rows at commit, so cached_rows keeps meaning rows whose
+    # tokens the host holds
+    inflight_rows: int = 0
     # set the moment an eos token is appended (O(1) finish checks — a
     # membership scan of `generated` per token would be quadratic)
     eos_seen: bool = False
@@ -208,6 +223,10 @@ class RequestScheduler:
         self.pool_watermark = pool_watermark
         self.waiting: Deque[Request] = collections.deque()
         self.running: List[Request] = []   # admission order (oldest first)
+        # counted-ahead finishes of the round being scheduled: no slot, no
+        # blocks, last tokens in flight. Empty between two engine rounds
+        # (the round's commit finishes them; a recovery requeues them)
+        self.ending: List[Request] = []
         # a heap: an admission takes the LOWEST free slot, so the running
         # requests sit in [0, n) with few holes and the engine can size a
         # decode round by the highest slot alive (serving._slot_ladder)
@@ -268,7 +287,7 @@ class RequestScheduler:
         order; the resume path replays the drained engine's order."""
         req.state = "waiting"
         req.submit_t = time.perf_counter()
-        req.cached_rows = 0
+        req.cached_rows = req.inflight_rows = 0
         req.slot = None
         req.block_ids = []
         req.admission_seq = None
@@ -314,13 +333,13 @@ class RequestScheduler:
         (the lowest free one first)."""
         heapq.heappush(self._free_slots, slot)
 
-    def finish(self, req: Request) -> None:
-        """Evict a completed sequence: its prefix publishes to the cache,
-        then slot and blocks return to the pool (shared blocks decrement —
-        the cache's references keep them alive)."""
-        assert req.state == "running", req.state
-        req.state = "finished"
-        req.finish_t = time.perf_counter()
+    def vacate(self, req: Request) -> None:
+        """A request leaves the running set for good: its prefix publishes
+        to the cache, then slot and blocks return to the pool (shared
+        blocks decrement — the cache's references keep them alive). Rows
+        of a round still in flight land in the freed blocks first: the
+        pool threads through every dispatch, so whoever is given them
+        writes after."""
         self.running.remove(req)
         self.free_slot(req.slot)
         self._release_cow(req)
@@ -330,20 +349,25 @@ class RequestScheduler:
         req.block_ids = []
         req.slot = None
 
+    def finish(self, req: Request) -> None:
+        """Evict a completed sequence. One counted ahead (``ending``) gave
+        its slot and blocks away when it was counted."""
+        if req.state == "ending":
+            self.ending.remove(req)
+        else:
+            assert req.state == "running", req.state
+            self.vacate(req)
+        req.state = "finished"
+        req.finish_t = time.perf_counter()
+
     def cancel(self, req: Request, reason: str = "cancelled") -> None:
         """Evict a request wherever it is in its lifecycle (deadline miss /
         shed): a running request's slot and blocks return to the pool
         MID-decode, a waiting one leaves the queue. Its partial output
-        (prompt + whatever was generated) stays readable."""
+        (prompt + whatever was generated) stays readable; tokens of a
+        round in flight are dropped when they arrive."""
         if req.state == "running":
-            self.running.remove(req)
-            self.free_slot(req.slot)
-            self._release_cow(req)
-            self._publish(req)
-            if req.block_ids:
-                self.allocator.free(req.block_ids, owner=req.rid)
-            req.block_ids = []
-            req.slot = None
+            self.vacate(req)
         elif req.state == "waiting":
             try:
                 self.waiting.remove(req)
@@ -370,19 +394,23 @@ class RequestScheduler:
         lives in ``_preempt_newest``; this is the mechanism — also used
         by the serving engine when an admission cannot pin its adapter
         slot (every slot held by another in-flight adapter)."""
-        self.running.remove(req)
+        (self.running if req.state == "running" else self.ending).remove(req)
         req.state = "waiting"
         req.preemptions += 1
-        req.cached_rows = 0                    # resumes by re-prefilling
+        # resumes by re-prefilling what the host holds: tokens of a round
+        # in flight are dropped when they arrive (the engine's record of
+        # that round names this request at its old ``preemptions``)
+        req.cached_rows = req.inflight_rows = 0
         req.prefill_done = False
         req.prefix_rows = 0
         req.kv_rows = 0                        # imported KV never survives
         #                                        eviction: re-admission
         #                                        recomputes (the engine
         #                                        drops the staged payload)
-        self.free_slot(req.slot)
-        self._release_cow(req)
-        self.allocator.free(req.block_ids, owner=req.rid)
+        if req.slot is not None:               # an ending request has none
+            self.free_slot(req.slot)
+            self._release_cow(req)
+            self.allocator.free(req.block_ids, owner=req.rid)
         req.block_ids = []
         req.slot = None
         self.waiting.appendleft(req)           # resumes before new arrivals
@@ -405,12 +433,14 @@ class RequestScheduler:
         """Evict every running request back to the queue (fault recovery:
         the device pool is being rebuilt, host cursors are authoritative).
         Victims are taken newest-first, so the queue ends oldest-first and
-        FIFO re-admission preserves the original service order."""
-        n = 0
-        while self.running:
-            self._preempt_newest()
-            n += 1
-        return n
+        FIFO re-admission preserves the original service order. Requests
+        counted ahead as ending go back too: their last tokens die with the
+        round in flight."""
+        victims = sorted(self.running + self.ending,
+                         key=self._effective_seq, reverse=True)
+        for req in victims:
+            self.preempt(req)
+        return len(victims)
 
     def _can_alloc(self, n: int) -> bool:
         """can_alloc with cache pressure: when the free list is short, ask
@@ -435,7 +465,8 @@ class RequestScheduler:
 
     def schedule(self, token_budget: Optional[int] = None) -> Dict[str, Any]:
         """One step-boundary decision. Returns {"admitted": [...],
-        "preempted": [...], "prefill": [(req, start, n), ...]}; admitted
+        "preempted": [...], "ended": [...], "prefill": [(req, start, n),
+        ...]}; admitted
         requests have slot + prompt blocks assigned (and any cached prefix
         mapped — ``cached_rows`` starts at the shared rows), running
         requests are guaranteed block coverage for the next quantum.
@@ -451,6 +482,17 @@ class RequestScheduler:
         the oldest prefilling request always gets at least one block-worth
         of tokens, so a budget below the block size cannot wedge."""
         preempted: List[Request] = []
+        # 0. finishes by length, counted ahead: the tokens in flight use up
+        #    the budget (an eos can only end it sooner), so the request
+        #    takes no part in this round and its slot, blocks and state row
+        #    go to the admissions below. It stays ``ending`` until the
+        #    engine commits those tokens and finishes it.
+        ended = [req for req in self.running
+                 if req.inflight_rows and req.remaining <= req.inflight_rows]
+        for req in ended:
+            self.vacate(req)
+            req.state = "ending"
+            self.ending.append(req)
         # 1. growth for the already-running, oldest EFFECTIVE admission
         #    first (aging order, not list order — a resumed request
         #    regrows before fresher tenants); exhaustion preempts from the
@@ -458,8 +500,9 @@ class RequestScheduler:
         for req in sorted(self.running, key=self._effective_seq):
             if req.state != "running":
                 continue                        # lost its slot this round
-            # the quantum writes rows cached_rows .. cached_rows+quantum-1
-            target = req.cached_rows + self.quantum
+            # the quantum writes quantum rows behind those cached or in
+            # flight
+            target = req.cached_rows + req.inflight_rows + self.quantum
             while not self._grow(req, target):
                 victim = self._preempt_newest()
                 if victim is None or victim is req:
@@ -546,7 +589,7 @@ class RequestScheduler:
                 req.admit_t = now
             self.running.append(req)
             admitted.append(req)
-        return {"admitted": admitted, "preempted": preempted,
+        return {"admitted": admitted, "preempted": preempted, "ended": ended,
                 "prefill": self._prefill_spans(token_budget)}
 
     def _prefill_spans(self, token_budget: Optional[int]
@@ -594,4 +637,4 @@ class RequestScheduler:
 
     @property
     def done(self) -> bool:
-        return not self.waiting and not self.running
+        return not (self.waiting or self.running or self.ending)

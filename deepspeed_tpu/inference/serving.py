@@ -78,6 +78,7 @@ mesh-incompatible placement with the typed ``ResumeIncompatible``.
 import collections
 import contextlib
 import dataclasses
+import functools
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -95,6 +96,68 @@ from deepspeed_tpu.robustness import events as rb_events
 from deepspeed_tpu.robustness import faults as rb_faults
 from deepspeed_tpu.robustness.preemption import Preempted
 from deepspeed_tpu.telemetry.tracing import span
+
+
+@dataclasses.dataclass(eq=False)
+class _DispatchedRound:
+    """A decode round on the device's queue: what its commits need, so they
+    can come after slots have changed hands. ``entries``: (request, its
+    slot, its ``preemptions``) per decoding slot — a request that has since
+    finished, been cancelled, handed off or preempted is no longer the one
+    dispatched, and its tokens are dropped. The round's steps are fetched
+    in two parts, each ``(tokens [steps, max_seqs], [per step (expert load,
+    exit distribution)])`` stacked on the device right behind its last step
+    (a stack at fetch time would queue behind whatever was dispatched
+    since): ``head``, fetched by the call that dispatched the round — for a
+    verify step its (argmaxes, accepted lengths), ``spec`` —, and ``tail``,
+    the last steps, which stay in flight while the host commits the head
+    and schedules and dispatches the next round, and are fetched by the
+    next call. Either is None when it holds no step. ``t0``: when the
+    dispatch began (request traces)."""
+    entries: list
+    shape: tuple
+    head: Any
+    tail: Any
+    spec: bool
+    t0: float
+
+    def live(self):
+        """The entries still owed tokens."""
+        return [(req, slot) for req, slot, life in self.entries
+                if req.preemptions == life
+                and req.state in ("running", "ending")]
+
+
+def _ahead_steps(quantum: int) -> int:
+    """How many of a plain round's last steps stay unfetched while the next
+    round is scheduled and dispatched: a quarter of the quantum. They have
+    to outlast the host's work between two rounds (commit, schedule,
+    tables, keys, the first dispatch: 9-20 ms in the benchmark's cells,
+    whose steps take 13-53 ms), and every step more is a step by which a
+    request's tokens reach the host later and its slot is given away
+    later."""
+    return max(1, quantum // 4)
+
+
+def _in_one_chunk(fn, *args):
+    """``fn(*args)`` with every frame below in ONE chunk of the interpreter's
+    frame stack. CPython 3.12 keeps a thread's frames in 16 KiB chunks and
+    maps a new chunk at every call that crosses a chunk's end, unmapping it
+    at the return. JAX's tracing and lowering recursion is deeper than a
+    chunk, so some call in it straddles a boundary, and WHICH one — a rare
+    one or one made a thousand times a program — moves with the size of
+    every frame above: a local variable more or less in ``_round``, in
+    ``step`` or in the caller's loop. That is the seconds of ``setup_s`` that
+    came and went with frame sizes since PR 23 (a chat cell's 16 prefill
+    programs: 2.6 or 4.7 s of lowering, and no better on a fresh thread,
+    whose tracing then straddled instead; PERF.md section 6, PR 36). A chunk
+    is as large as the frame that opens it needs, so this frame declares an
+    evaluation stack of 64 Ki words it never uses: it opens a chunk of
+    1 MiB, and the ~0.5 MiB behind it hold whatever ``fn`` calls."""
+    return fn(*args)
+
+
+_in_one_chunk.__code__ = _in_one_chunk.__code__.replace(co_stacksize=1 << 16)
 
 
 class DecodeDispatchHang(RuntimeError):
@@ -347,6 +410,16 @@ def _slot_ladder(max_seqs: int) -> tuple:
 _MOE_COUNTERS = {"kept": 0, "asked": 0, "max_over_mean": 0.0, "rounds": 0,
                  "touched": 0.0, "steps": 0, "prefill_touched": 0.0,
                  "prefills": 0}
+
+
+# latency-tier and round-order counters of a stats window
+_LAT_COUNTERS = {"spec_steps": 0, "spec_proposed": 0, "spec_accepted": 0,
+                 "prefill_chunks": 0, "prefill_chunk_tokens": 0,
+                 "cow_forks": 0,
+                 # plain decode rounds dispatched while the round before was
+                 # still unfetched (of the rounds step_shape_rounds counts),
+                 # and slot-rounds whose tokens nobody was left to take
+                 "rounds_ahead": 0, "dropped_slot_rounds": 0}
 
 
 class ServingEngine:
@@ -638,6 +711,7 @@ class ServingEngine:
         # — stats()["pool_bytes"] prices it alongside the device pool.
         self._kv_staging: Dict[int, int] = {}
         self._rng_counter = 0
+        self._base_key = None              # _next_key
         self._stats_t0: Optional[float] = None
         # latency-frontier counters (reset_stats windows)
         self._itl_ms: List[float] = []
@@ -647,9 +721,7 @@ class ServingEngine:
         # position of the stats window, then their count (_note_counters)
         self._ut_steps = int(getattr(mcfg, "ut_steps", 1))
         self._exit = np.zeros((self._ut_steps + 1,), np.float64)
-        self._lat = {"spec_steps": 0, "spec_proposed": 0,
-                     "spec_accepted": 0, "prefill_chunks": 0,
-                     "prefill_chunk_tokens": 0, "cow_forks": 0}
+        self._lat = dict(_LAT_COUNTERS)
         # plain decode rounds dispatched per (slot count, block-table
         # width) (reset_stats windows; _tables_device)
         self._table_rounds = self._step_shapes()
@@ -668,6 +740,11 @@ class ServingEngine:
         # the watchdog arms only once the quantum step has run once: the
         # first round's jit compile is legitimate wall time, not a hang
         self._quantum_warm = False
+        # the plain decode round whose last steps the last step() left
+        # unfetched: the next step() dispatches its own round first and
+        # only then fetches and commits them, so the chip never waits for
+        # the host's commit / schedule / tables / first dispatch
+        self._inflight: Optional[_DispatchedRound] = None
         self._draining = False
         self._preemption = None            # attach_preemption()
         self._drain_dir: Optional[str] = None
@@ -1085,11 +1162,11 @@ class ServingEngine:
             # meanwhile: below the serving loop's frames a lowering costs
             # twice what it costs on an empty stack (three programs 3.3 s
             # against 1.5 s in Mixtral's cell, PERF.md section 6, PR 29),
-            # and the shapes have to fit a cell's set-up. The thread sees
-            # no thread-local jax.config context of the caller's; the mesh
-            # is entered there
+            # and the shapes have to fit a cell's set-up (why:
+            # ``_in_one_chunk``). The thread sees no thread-local jax.config
+            # context of the caller's; the mesh is entered there
             with ThreadPoolExecutor(1) as pool:
-                lowered = {shape: pool.submit(lower, *shape)
+                lowered = {shape: pool.submit(_in_one_chunk, lower, *shape)
                            for shape in self._step_shapes()}
                 self._quantum_step = {shape: lo.result().compile()
                                       for shape, lo in lowered.items()}
@@ -1150,8 +1227,12 @@ class ServingEngine:
     def _next_key(self):
         import jax
         self._rng_counter += 1
-        return jax.random.fold_in(jax.random.PRNGKey(20260803),
-                                  self._rng_counter)
+        if self._base_key is None:
+            # made once: a PRNGKey is two device programs, eight times a
+            # round, queued between one round's last step and the next
+            # round's first
+            self._base_key = jax.random.PRNGKey(20260803)
+        return jax.random.fold_in(self._base_key, self._rng_counter)
 
     # ---- request API -------------------------------------------------
 
@@ -1276,7 +1357,10 @@ class ServingEngine:
         buf[0, :ctx.size] = ctx
         nblk = P // self.config.block_size
         block_ids = jnp.asarray(req.block_ids[:nblk], jnp.int32)
-        fn = self._get_prefill_fn(P)
+        # a bucket's first prompt traces and lowers its program: not across
+        # a chunk boundary of the interpreter's frame stack
+        fn = self._get_prefill_fn(P) if P in self._prefill_fns else \
+            functools.partial(_in_one_chunk, self._get_prefill_fn(P))
         with self.engine.mesh:
             first, self.pools = fn(self.engine.params, jnp.asarray(buf),
                                    self.pools, block_ids,
@@ -1425,7 +1509,9 @@ class ServingEngine:
         aidx = np.zeros((S,), np.int32)
         for req in running:
             ids[req.slot, :len(req.block_ids)] = req.block_ids
-            lens[req.slot] = req.cached_rows
+            # rows of a round still on the device's queue are written by
+            # the time this round's first step reads the lengths
+            lens[req.slot] = req.cached_rows + req.inflight_rows
             # a mid-prefill request (chunked prompt still landing) holds
             # its slot but must not decode yet
             act[req.slot] = req.prefill_done
@@ -1437,7 +1523,10 @@ class ServingEngine:
         """One scheduling round: enforce deadlines, evict/admit/preempt at
         the boundary, then one decode quantum. Prefill dispatches and the
         quantum's K decode dispatches issue with NO host sync between them;
-        the single sync is the token fetch at the end. Returns requests
+        the single sync is the token fetch at the end — which leaves the
+        quantum's last steps (``_ahead_steps``) in flight for the next call
+        to fetch, so the chip has work while the host commits, schedules
+        and dispatches the next round (``_round``). Returns requests
         finished this round.
 
         Reliability: a latched SIGTERM drains the engine first (raising
@@ -1484,10 +1573,33 @@ class ServingEngine:
         """The round proper, inside ``step()``'s ``ds:serve.round`` span:
         (requests finished, host milliseconds per phase). Each phase is one
         ``ds:serve.<phase>`` span (telemetry.tracing.span: on the
-        profiler's clock in any session)."""
-        import jax
-        import jax.numpy as jnp
+        profiler's clock in any session), in the order schedule ->
+        housekeeping -> prefill_dispatch -> decode_dispatch -> fetch ->
+        commit. The loop looks ahead: the fetch takes the LAST steps of the
+        plain decode round the call before dispatched (``_inflight``), this
+        call's first tokens and the FIRST steps of the round this call
+        dispatched, and leaves that round's last ``_ahead_steps`` on the
+        device's queue — the chip runs them while the host commits,
+        schedules, builds tables and dispatches the next round, so between
+        a round's last step and the next round's first it has only this
+        call's prefills to run and nothing to wait for. What keeps that
+        sound:
 
+        * commit works from the dispatched round's record, not from
+          ``scheduler.running`` (a slot may have changed hands);
+        * a finish by LENGTH that the steps in flight bring is counted
+          ahead (``RequestScheduler.schedule``: the request is ``ending``,
+          its slot goes to an admission of this very call); a finish only
+          the tokens show (an eos in those steps) is found one round late,
+          and the quantum that slot ran meanwhile is dropped and counted
+          (``dropped_slot_rounds``);
+        * first tokens of this call's prefills ride this call's fetch;
+        * ``Request.cached_rows`` stays rows whose tokens the host holds;
+          tables and growth add ``inflight_rows``;
+        * a speculation round needs the last tokens on the host and leaves
+          nothing behind (dispatch, then fetch all of it, in one call); a
+          recovery discards the steps in flight with the round it was
+          dispatching."""
         self._round_tokens = 0
         ph = {"schedule_ms": 0.0, "housekeeping_ms": 0.0, "prefill_ms": 0.0,
               "decode_ms": 0.0, "fetch_ms": 0.0, "commit_ms": 0.0}
@@ -1530,7 +1642,7 @@ class ServingEngine:
                     # admission bounces back to the queue head, exactly the
                     # KV-pool-exhaustion discipline applied to the adapter
                     # pool
-                    for req in decisions["preempted"]:
+                    for req in decisions["preempted"] + decisions["ended"]:
                         self._release_adapter(req)
                     for req in decisions["admitted"]:
                         if not self._acquire_adapter(req):
@@ -1579,129 +1691,161 @@ class ServingEngine:
                                          start=int(start), tokens=int(n)):
                             self._dispatch_chunk(req, start, n)
             ph["prefill_ms"] = sp.seconds * 1e3
-            if not self.scheduler.running:
+            prior = self._inflight
+            if prior is None and not self.scheduler.running:
                 return [], ph
 
+            # a prefill-role engine NEVER runs decode quanta: requests sit
+            # prefill_done until the router hands them (with their KV
+            # bytes) to the decode tier. Their FIRST token still commits
+            # through the pending-firsts fetch below, so TTFT is measured
+            # where the prefill ran.
+            decode = self.config.role != "prefill" and any(
+                r.prefill_done for r in self.scheduler.running)
+            # a verify step's proposals are drafted from the tokens the
+            # host holds: a speculation round is fetched whole by the call
+            # that dispatches it and leaves nothing in flight, so a
+            # speculating engine never finds anything in flight either
+            spec = decode and self.config.spec_tokens > 0
+            rec = None
             with span("ds:serve.decode_dispatch") as sp_dec:
-                # a prefill-role engine NEVER runs decode quanta: requests
-                # sit prefill_done until the router hands them (with their
-                # KV bytes) to the decode tier. Their FIRST token still
-                # commits through the pending-firsts fetch below, so TTFT
-                # is measured where the prefill ran.
-                can_decode = self.config.role != "prefill"
-                spec = (self.config.spec_tokens > 0 and can_decode
-                        and any(r.prefill_done
-                                for r in self.scheduler.running))
-                decode = can_decode and any(r.prefill_done
-                                            for r in self.scheduler.running)
-                tables, seq_lens, active, aidx = self._tables_device(
-                    full=spec)
-                # the plain step runs as the program of the tables' shape
-                step_fn = self._get_spec_step() if spec \
-                    else (self._get_quantum_step()[tables.shape]
-                          if decode else None)
-                tok_mat = None
-                if spec:
-                    props = self._proposals_device()
-                    tok_mat = jnp.concatenate(
-                        [self._tokens[:, None], props], axis=1)
-                # keys precomputed so the watchdogged closures touch NO
-                # engine state: an abandoned (hung) round thread finishing
-                # late can only drop its local result, never clobber
-                # recovered state
-                keys = [self._next_key()
-                        for _ in range(self.config.decode_quantum)]
+                if decode:
+                    rec = self._dispatch_round(spec, sp_dec.t0)
+                    if not spec:
+                        self._table_rounds[rec.shape] += 1
+                        self._lat["rounds_ahead"] += prior is not None
+                # first tokens ride THIS call's fetch: a prefill dispatched
+                # above sits on the device's queue behind the steps in
+                # flight and ahead of the round just dispatched
                 pending = [(req, req._first_dev)
                            for req in self.scheduler.running
                            if getattr(req, "_first_dev", None) is not None]
-                pools, tokens = self.pools, self._tokens
-                apool = self.adapter_pool if self._lora else None
-                params, mesh = self.engine.params, self.engine.mesh
-                S = self.config.max_seqs
-                epoch = self._epoch
-
-                def dispatch():
-                    # the decode_dispatch fault seam lives INSIDE the
-                    # guard: a hang here is exactly what the watchdog must
-                    # time out
-                    rb_faults.dispatch_seam()
-                    if self._epoch != epoch:
-                        return None  # abandoned by a recovery: bail before
-                    p, t, lens = pools, tokens, seq_lens  # touching the device
-                    outs = []
-                    spec_dev = None
-                    with mesh:
-                        if spec:
-                            # ONE verify step per round: pending + K
-                            # proposals scored in a single span pass
-                            p, nxt, acc, t, lens = step_fn(
-                                params, p, tok_mat, tables, lens, active,
-                                keys[0], apool, aidx)
-                            spec_dev = (nxt, acc)
-                        elif decode:
-                            for k in keys:
-                                if self._epoch != epoch:
-                                    return None
-                                # t: (tokens, the step's counters)
-                                p, t, lens = step_fn(params, p, t, tables,
-                                                     lens, active, k, apool,
-                                                     aidx)
-                                outs.append(t)
-                                t = t[0]
-                    return p, t, outs, spec_dev
-
-                dev = self._with_watchdog(dispatch, armed=self._quantum_warm)
-                if dev is None:     # only reachable through a stale epoch
-                    raise DecodeDispatchHang("round abandoned by recovery")
-                p, t, outs, spec_dev = dev
             ph["decode_ms"] = sp_dec.seconds * 1e3
-            # dispatch done / fetch begins: the split the doctor uses to
-            # tell dispatch-bound from fetch-bound. The ONE sync of the
-            # round: the sampled tokens (quantum steps or the verify step's
-            # accept verdict) AND every pending prefill/chunk token ride a
-            # single device_get (under its own watchdog: a device that
-            # never answers hangs HERE). The counters of each decode
-            # step and of each pending prefill ride the same call.
-            with span("ds:serve.fetch") as sp:
-                toks, firsts, spec_host = self._with_watchdog(
-                    lambda: jax.device_get(
-                        (jnp.stack([o[0] for o in outs]) if outs
-                         else jnp.zeros((0, S), jnp.int32),
-                         ([f for _, f in pending], [o[1] for o in outs]),
-                         spec_dev)),
-                    armed=self._quantum_warm)
-            ph["fetch_ms"] = sp.seconds * 1e3
-            if self._tracer is not None and decode:
-                for req in self.scheduler.running:
-                    if req.prefill_done:
-                        self._tracer.add_span(
-                            req.rid, "decode_quantum",
-                            self._tracer.epoch(sp_dec.t0),
-                            self._tracer.epoch(sp.t0 + sp.seconds),
-                            steps=(1 if spec
-                                   else self.config.decode_quantum))
-            if decode:
-                self._quantum_warm = True
-                if not spec:
-                    self._table_rounds[tables.shape] += 1
-            self.pools, self._tokens = p, t
         finally:
             if keep is not None:
                 self.allocator.set_reserve(0)
-        with span("ds:serve.commit") as sp:
-            firsts = self._note_counters(firsts)
-            if spec_host is not None:
-                finished = self._commit_spec(spec_host, pending, firsts)
-            else:
-                finished = self._commit_round(np.asarray(toks), pending,
-                                              firsts)
-        ph["commit_ms"] = sp.seconds * 1e3
+        # what this call fetches and commits: the last steps of the round
+        # the call before dispatched, which the chip ran while the host did
+        # all of the above, and the first steps of the round just
+        # dispatched — whose last steps stay on the device's queue
+        self._inflight = rec if rec is not None and rec.tail else None
+        finished = self._land(prior, rec, pending, ph)
+        if decode:
+            self._quantum_warm = True
+        if self._inflight is not None and not self.scheduler.running:
+            # everything it decodes for ended in this commit: nobody is
+            # left to take the rest of its tokens
+            if rec.head is None:
+                self._lat["dropped_slot_rounds"] += len(rec.entries)
+            self._inflight = None
         if self._tracer is not None:
             for req in finished:
                 self._tracer.instant(req.rid, "finish",
                                      tokens=len(req.generated))
                 self._tracer.end(req.rid)
         return finished, ph
+
+    def _dispatch_round(self, spec: bool, t0: float) -> _DispatchedRound:
+        """Dispatch (no sync) one decode round for the running requests
+        that have their prompt in — the quantum's steps, or ONE verify step
+        — and thread the pools and the token vector through it. Returns the
+        round's record; its tokens are fetched by ``_land``, a plain
+        round's in two parts (``_ahead_steps``)."""
+        import jax.numpy as jnp
+        tables, seq_lens, active, aidx = self._tables_device(full=spec)
+        # the plain step runs as the program of the tables' shape
+        step_fn = self._get_spec_step() if spec \
+            else self._get_quantum_step()[tables.shape]
+        tok_mat = None
+        if spec:
+            props = self._proposals_device()
+            tok_mat = jnp.concatenate([self._tokens[:, None], props], axis=1)
+        # keys precomputed so the watchdogged closure touches NO engine
+        # state: an abandoned (hung) round thread finishing late can only
+        # drop its local result, never clobber recovered state
+        keys = [self._next_key() for _ in range(self.config.decode_quantum)]
+        pools, tokens = self.pools, self._tokens
+        apool = self.adapter_pool if self._lora else None
+        params, mesh = self.engine.params, self.engine.mesh
+        epoch = self._epoch
+        n_head = len(keys) - _ahead_steps(len(keys))
+
+        def part(outs):
+            # stacked HERE, behind the part's last step and ahead of the
+            # next, so that fetching it waits for nothing dispatched later
+            return (jnp.stack([o[0] for o in outs]),
+                    [o[1] for o in outs]) if outs else None
+
+        def dispatch():
+            # the decode_dispatch fault seam lives INSIDE the guard: a hang
+            # here is exactly what the watchdog must time out
+            rb_faults.dispatch_seam()
+            if self._epoch != epoch:
+                return None  # abandoned by a recovery: bail before
+            p, t, lens = pools, tokens, seq_lens  # touching the device
+            outs, head = [], None
+            with mesh:
+                if spec:
+                    # ONE verify step per round: pending + K proposals
+                    # scored in a single span pass
+                    p, nxt, acc, t, lens = step_fn(
+                        params, p, tok_mat, tables, lens, active, keys[0],
+                        apool, aidx)
+                    return p, t, (nxt, acc), None
+                for k in keys:
+                    if self._epoch != epoch:
+                        return None
+                    # t: (tokens, the step's counters)
+                    p, t, lens = step_fn(params, p, t, tables, lens, active,
+                                         k, apool, aidx)
+                    outs.append(t)
+                    t = t[0]
+                    if head is None and len(outs) == n_head:
+                        head, outs = part(outs), []
+                return p, t, head, part(outs)
+
+        dev = self._with_watchdog(dispatch, armed=self._quantum_warm)
+        if dev is None:     # only reachable through a stale epoch
+            raise DecodeDispatchHang("round abandoned by recovery")
+        self.pools, self._tokens, head, tail = dev
+        entries = [(req, req.slot, req.preemptions)
+                   for req in self.scheduler.running if req.prefill_done]
+        if not spec:
+            for req, _, _ in entries:
+                req.inflight_rows += len(keys)
+        return _DispatchedRound(entries, tuple(tables.shape), head, tail,
+                                spec, t0)
+
+    def _land(self, prior: Optional[_DispatchedRound],
+              rec: Optional[_DispatchedRound], pending: list,
+              ph: Dict[str, float]) -> List[Request]:
+        """The ONE sync of a call and its commit: the last steps of the
+        round in flight (``prior.tail``), the first token of every prefill
+        / last chunk dispatched since (``pending``) and the first steps of
+        the round just dispatched (``rec.head``; a verify step: all of it)
+        ride a single ``device_get`` (under its own watchdog: a device that
+        never answers hangs HERE), with the counters of each decode step
+        and of each pending prefill. It returns when the device has run
+        those — ``rec.tail`` is still queued behind them. Writes its two
+        phases into ``ph``; returns the requests that finished."""
+        import jax
+        # dispatch done / fetch begins: the split the doctor uses to tell
+        # dispatch-bound from fetch-bound
+        with span("ds:serve.fetch") as sp:
+            firsts, tail, head = self._with_watchdog(
+                lambda: jax.device_get((
+                    [f for _, f in pending],
+                    prior.tail if prior is not None else None,
+                    rec.head if rec is not None else None)),
+                armed=self._quantum_warm)
+        ph["fetch_ms"] = sp.seconds * 1e3
+        with span("ds:serve.commit") as sp_c:
+            counters = [c for r, part in ((prior, tail), (rec, head))
+                        if part is not None and not r.spec for c in part[1]]
+            firsts = self._note_counters((firsts, counters))
+            finished = self._commit(prior, tail, pending, firsts, rec, head)
+        ph["commit_ms"] = sp_c.seconds * 1e3
+        return finished
 
     def _note_tokens(self, req: Request, m: int, now: float) -> None:
         """Inter-token-latency bookkeeping: a commit burst of ``m`` tokens
@@ -1763,79 +1907,74 @@ class ServingEngine:
         m["prefills"] += len(prefill_loads)
         return firsts
 
-    def _commit_round(self, toks, pending, firsts) -> List[Request]:
-        first_tok = {req.rid: int(np.asarray(f)[0])
-                     for (req, _), f in zip(pending, firsts)}
+    def _commit(self, prior, tail, pending, firsts, rec,
+                head) -> List[Request]:
+        """Hand the fetched tokens to their requests, oldest first: the
+        last steps (``tail``) of the round in flight (``prior``), each
+        pending first token, the first steps (``head``) of the round just
+        dispatched (``rec``) — each from its round's RECORD, whose slots
+        may since have gone to other requests. A plain part gives every
+        live entry its steps' tokens up to the request's budget or eos (the
+        rest, like the rows behind them, are overshoot); a verify round
+        (argmaxes, accepted lengths) its accepted proposal prefix plus the
+        model's correction / bonus token, 1..K+1 tokens — the emitted
+        stream is the target model's own argmaxes, so output is
+        token-identical to the unspeculated run; the cursor advanced by
+        accepted + 1 on device, rejected rows sit beyond it, stale until
+        overwritten. An entry whose request is no longer the one dispatched
+        is dropped, and counted where it had ended before anything of the
+        round was committed for it. Returns the requests that finished."""
         now = time.perf_counter()
-        finished: List[Request] = []
         eos = self.config.eos_token_id
-        for req in list(self.scheduler.running):
-            slot = req.slot
-            got = 0
-            if req.rid in first_tok:
-                # prefill's pending token: its KV row was written by the
-                # quantum's step 0, so it is part of the sequence now
-                self._append(req, first_tok[req.rid], eos)
-                req._first_dev = None
-                got += 1
-                if req.first_token_t is None:
-                    req.first_token_t = now
-            if not req.prefill_done:
-                # chunked prompt still landing: the quantum skipped this
-                # slot (inactive), nothing to absorb
-                self._note_tokens(req, got, now)
-                continue
-            for i in range(toks.shape[0]):
-                if self._done(req):
-                    break
-                self._append(req, int(toks[i, slot]), eos)
-                got += 1
-            req.cached_rows += toks.shape[0]
-            self._note_tokens(req, got, now)
-            if self._done(req):
-                self.scheduler.finish(req)
-                self._release_adapter(req)
-                self._finished.append(req)
-                finished.append(req)
-        return finished
+        got: Dict[int, list] = {}         # rid -> [request, tokens delivered]
 
-    def _commit_spec(self, spec_host, pending, firsts) -> List[Request]:
-        """Commit a verify round: each decoding slot gains its accepted
-        proposal prefix plus the model's correction/bonus token (1..K+1
-        tokens — the emitted stream is the target model's own argmaxes,
-        so output is token-identical to the unspeculated run). The cursor
-        advanced by accepted+1 on device; rejected rows sit beyond it,
-        stale until overwritten — shared blocks untouched."""
-        nxt, acc = spec_host
-        first_tok = {req.rid: int(np.asarray(f)[0])
-                     for (req, _), f in zip(pending, firsts)}
-        now = time.perf_counter()
-        finished: List[Request] = []
-        eos = self.config.eos_token_id
-        K = self.config.spec_tokens
-        for req in list(self.scheduler.running):
-            slot = req.slot
-            got = 0
-            if req.rid in first_tok:
-                self._append(req, first_tok[req.rid], eos)
-                req._first_dev = None
-                got += 1
-                if req.first_token_t is None:
-                    req.first_token_t = now
-            if not req.prefill_done:
-                self._note_tokens(req, got, now)
-                continue
-            a = int(acc[slot])
-            for i in range(a + 1):
+        def deliver(req, tokens):
+            n = got.setdefault(req.rid, [req, 0])
+            for tok in tokens:
                 if self._done(req):
                     break
-                self._append(req, int(nxt[slot, i]), eos)
-                got += 1
-            req.cached_rows += a + 1
-            self._lat["spec_steps"] += 1
-            self._lat["spec_proposed"] += K
-            self._lat["spec_accepted"] += a
-            self._note_tokens(req, got, now)
+                self._append(req, tok, eos)
+                n[1] += 1
+
+        def part(rnd, fetched, first):
+            # still owed tokens, and not ended by an older part just above
+            live = [(req, slot) for req, slot in rnd.live()
+                    if not self._done(req)]
+            if first:
+                self._lat["dropped_slot_rounds"] += (len(rnd.entries)
+                                                     - len(live))
+            for req, slot in live:
+                if rnd.spec:
+                    nxt, acc = fetched
+                    row = nxt[slot, :int(acc[slot]) + 1]
+                    self._lat["spec_steps"] += 1
+                    self._lat["spec_proposed"] += self.config.spec_tokens
+                    self._lat["spec_accepted"] += row.size - 1
+                else:
+                    row = fetched[0][:, slot]
+                    req.inflight_rows -= row.size
+                req.cached_rows += row.size
+                deliver(req, row.tolist())
+                if self._tracer is not None:
+                    self._tracer.add_span(
+                        req.rid, "decode_quantum",
+                        self._tracer.epoch(rnd.t0), self._tracer.epoch(now),
+                        steps=1 if rnd.spec else row.size)
+
+        if tail is not None:
+            part(prior, tail, first=prior.head is None)
+        for (req, _), f in zip(pending, firsts):
+            # prefill's pending token: its KV row is written by the next
+            # step that decodes for it, so it is part of the sequence now
+            deliver(req, [int(np.asarray(f)[0])])
+            req._first_dev = None
+            if req.first_token_t is None:
+                req.first_token_t = now
+        if head is not None:
+            part(rec, head, first=True)
+        finished: List[Request] = []
+        for req, n in got.values():
+            self._note_tokens(req, n, now)
             if self._done(req):
                 self.scheduler.finish(req)
                 self._release_adapter(req)
@@ -1878,6 +2017,9 @@ class ServingEngine:
         import jax.numpy as jnp
         t0 = time.perf_counter()
         self._epoch += 1          # abandoned round threads see this and bail
+        # the round in flight dies with the pool it wrote into: preempt_all
+        # takes every request back to what the host holds
+        self._inflight = None
         self.allocator.set_reserve(0)
         n = self.scheduler.preempt_all()
         for req in self._requests.values():
@@ -2207,14 +2349,9 @@ class ServingEngine:
             self._drop_kv_payload(req, count=False)  # moving, not falling
             self._kv_staging.pop(req.rid, None)      # export consumed
             if req.state == "running":
-                self.scheduler.running.remove(req)
-                self.scheduler.free_slot(req.slot)
-                self.scheduler._release_cow(req)
-                self.scheduler._publish(req)
-                if req.block_ids:
-                    self.allocator.free(req.block_ids, owner=req.rid)
-                req.block_ids = []
-                req.slot = None
+                # tokens of a round in flight stay behind: the record and
+                # the exported rows both stand at what the host holds
+                self.scheduler.vacate(req)
             else:
                 try:
                     self.scheduler.waiting.remove(req)
@@ -2241,8 +2378,10 @@ class ServingEngine:
         every ``request_migrated`` event a failover emits).
 
         Only the host cursors (prompt + generated + budget) drive
-        ``resume`` — the restarted engine rebuilds device state by
-        re-prefilling. The block table / slot / cached_rows snapshot is
+        ``resume`` (tokens of decode steps still in flight are not among
+        them: those steps are dropped, not fetched) — the restarted engine
+        rebuilds device state by re-prefilling. The block table / slot /
+        cached_rows snapshot is
         recorded for post-mortems (which slot held what at the drain),
         not restored: a fresh pool has no use for the old physical ids.
         The drained engine's geometry (``max_model_len``, block size,
@@ -2308,6 +2447,15 @@ class ServingEngine:
                                what="serving drain state write")
         integrity.write_manifest(tag_dir)
         integrity.write_commit_marker(tag_dir)
+        # a round in flight is not waited for (the device may be why the
+        # engine drains) and nobody finishes outside step(): the state
+        # holds its requests at the tokens the host has, whoever resumes
+        # them recomputes the rest, and here they go back to the queue like
+        # any preemption, so this engine agrees with what it wrote
+        rec, self._inflight = self._inflight, None
+        for req, _ in (rec.live() if rec is not None else ()):
+            self.scheduler.preempt(req)
+            self._release_adapter(req)
         rb_events.emit("serving_drained", requests=len(live), tag=tag,
                        path=tag_dir)
         self._drain_events()
@@ -2539,9 +2687,7 @@ class ServingEngine:
         self._itl_ms = []
         self._moe = dict(_MOE_COUNTERS)
         self._exit[:] = 0.0
-        self._lat = {"spec_steps": 0, "spec_proposed": 0,
-                     "spec_accepted": 0, "prefill_chunks": 0,
-                     "prefill_chunk_tokens": 0, "cow_forks": 0}
+        self._lat = dict(_LAT_COUNTERS)
         self._table_rounds = self._step_shapes()
         if self._prefix_cache is not None:
             self._prefix_cache.reset_stats()
@@ -2636,7 +2782,19 @@ class ServingEngine:
         plain decode rounds dispatched at it}`` over both ladders — and
         ``table_width_rounds`` ``{width: rounds}``, the same rounds summed
         over the slot counts; ``slot_count_mean`` and ``table_width_mean``
-        over those rounds."""
+        over those rounds.
+
+        The round order (always on; ``_round``): ``rounds_ahead`` — of the
+        rounds ``step_shape_rounds`` counts, those dispatched while the
+        last steps of the round before were still unfetched (all but the
+        first after an idle moment: the chip then never waits for the host
+        between two rounds) — and ``dropped_slot_rounds`` — slot-rounds run
+        for a request that had already ended: the quantum a slot ran after
+        an eos among the steps the host had not fetched yet, or behind a
+        one-token budget (0 where every request ends by length past its
+        first token). Tokens of the steps in flight are in neither
+        ``generated`` nor ``cached_rows`` until the next ``step()`` commits
+        them."""
         done = [r for r in self._finished if r.first_token_t is not None]
         out: Dict[str, Any] = {
             "completed": float(len(self._finished)),

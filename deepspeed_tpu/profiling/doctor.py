@@ -275,8 +275,9 @@ SERVING_KNOBS = {
     "decode_dispatch": "fewer, larger steps: raise decode_quantum, or "
                        "hunt per-step recompiles (decode_backend/bucket "
                        "drift)",
-    "fetch": "healthy: the device is the bottleneck — scale the mesh or "
-             "shrink the model, not the host loop",
+    "fetch": "healthy: the device is the bottleneck (the host has the "
+             "next round dispatched before the last is through, and waits) "
+             "— scale the mesh or shrink the model, not the host loop",
     "housekeeping": "adapter paging / CoW fork traffic dominates: more "
                     "adapter_slots (or adapter-affinity routing) so hot "
                     "adapters stay resident instead of re-paging",
@@ -290,7 +291,16 @@ def diagnose_serving(decomp: Dict[str, Any]) -> Dict[str, Any]:
     (host-scheduling-bound / dispatch-bound / fetch-bound / paging-bound),
     the top-2 phases for the bench, per-token round cost, and the tracing
     evidence (device-sync self-report + measured overhead) passed through
-    for ``gate_serving``."""
+    for ``gate_serving``.
+
+    The loop looks ahead (``ServingEngine._round``): a call's fetch
+    leaves the round's last steps on the device's queue, so the non-fetch
+    phases of the next call are host time the chip does not wait for as
+    long as they are shorter than those steps. The reading holds: while
+    the device is the slower side the fetch absorbs the difference and
+    dominates (fetch-bound: health); once the host is the slower side the
+    tokens are ready when it asks, the fetch shrinks to the copy, and the
+    dominant phase names the host work to cut."""
     phases = {
         "schedule": float(decomp.get("serve_schedule_ms", 0.0)),
         "housekeeping": float(decomp.get("serve_housekeeping_ms", 0.0)),

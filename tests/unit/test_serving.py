@@ -137,6 +137,36 @@ class TestScheduler:
         assert s.waiting[0] is r2 and s.waiting[1] is r3
         assert r2.cached_rows == 0
 
+    def test_a_finish_by_length_is_counted_ahead(self):
+        """Rows in flight count: growth covers the quantum BEHIND them, and
+        a request whose budget they exhaust gives slot and blocks to an
+        admission of the same decision, staying ``ending`` (the scheduler
+        is not done) until the engine finishes it — or a recovery takes it
+        back to the queue with everything else."""
+        alloc, s = _sched(max_seqs=1)
+        a, b = s.submit(np.arange(10), 5), s.submit(np.arange(10), 12)
+        assert s.schedule()["admitted"] == [a] and len(a.block_ids) == 1
+        a.cached_rows, a.prefill_done = 10, True
+        a.generated.append(7)                  # its first token, committed
+        a.inflight_rows = 4                    # and a quantum on its way
+        out = s.schedule()
+        assert out["ended"] == [a] and out["admitted"] == [b]
+        assert a.state == "ending" and a.slot is None and b.slot == 0
+        assert s.running == [b] and s.ending == [a] and not s.done
+        s.finish(a)
+        assert a.state == "finished" and not s.ending
+        # b: three of its twelve tokens left after the rows in flight
+        b.cached_rows, b.prefill_done, b.inflight_rows = 14, True, 4
+        b.generated.extend(range(5))
+        out = s.schedule()
+        assert out["ended"] == [] and len(b.block_ids) == 2   # 14 + 4 + 4
+        b.generated.extend(range(4))
+        b.cached_rows = 18
+        assert s.schedule()["ended"] == [b]
+        assert s.preempt_all() == 1 and s.waiting[0] is b
+        assert b.state == "waiting" and b.inflight_rows == 0
+        assert not s.ending and alloc.used_blocks == 0
+
     def test_growth_clamps_at_table_width(self):
         alloc, s = _sched(num_blocks=32, max_seqs=2, bs=16, quantum=8, mb=3)
         r = s.submit(np.arange(40), 16)
@@ -1160,3 +1190,291 @@ class TestDrainResume:
             small.accept_migration(too_big, source="r-big")
         # all-or-nothing: the failed batch enqueued nothing
         assert small.scheduler.num_waiting == 1
+
+
+# ---------------------------------------------------------------------------
+# The loop looks ahead (ISSUE 36): a call of step() dispatches round k+1
+# while the last steps of round k are still unfetched, then fetches those,
+# its prefills' first tokens and the first steps of round k+1
+# ---------------------------------------------------------------------------
+
+_AHEAD_FAMILIES = {
+    "mistral": dict(),
+    "mixtral": dict(num_experts=4, top_k=2, drop_tokens=False),
+    "olmoe": dict(num_experts=8, top_k=4, drop_tokens=False, qk_norm=True,
+                  norm_topk_prob=False, num_kv_heads=4),
+    "hybrid": dict(position_type="none", activation="relu2", num_experts=4,
+                   top_k=2, drop_tokens=False, moe_scoring="sigmoid",
+                   routed_scaling_factor=2.5, moe_shared_size=48,
+                   block_pattern="ME*E", num_layers=4, head_dim=16,
+                   mamba_num_heads=4, mamba_head_dim=16, mamba_n_groups=2,
+                   ssm_state_size=16, mamba_chunk=16, intermediate_size=64),
+    "looped": dict(ut_steps=3, sandwich_norm=True, exit_gate=True,
+                   num_kv_heads=4),
+}
+
+
+def _ahead_load(n=10, seed=7, new=(2, 30)):
+    rng = np.random.default_rng(seed)
+    return [(rng.integers(0, 128, size=(int(rng.integers(3, 40)),)
+                          ).astype(np.int32), int(rng.integers(*new)))
+            for _ in range(n)]
+
+
+def _drive(srv, reqs, parent_order=False, each=None):
+    """The requests through ``step()`` as ``serve_job.drive`` calls it ->
+    (outputs in submission order, requests running after each call).
+    ``parent_order``: fetch the steps left in flight right after the call
+    that dispatched them, so every schedule sees what the parent's saw — a
+    finish at the commit, its slot given out in the NEXT call."""
+    rids = [srv.add_request(p, n) for p, n in reqs]
+    occupancy = []
+    while srv.scheduler.running or srv.scheduler.num_waiting:
+        srv.step()
+        if parent_order and srv._inflight is not None:
+            rec, srv._inflight = srv._inflight, None
+            srv._land(rec, None, [], {"fetch_ms": 0.0, "commit_ms": 0.0})
+        occupancy.append(len(srv.scheduler.running))
+        if each is not None:
+            each(srv)
+    done = {r.rid: r for r in srv._finished}
+    return [done[i].output for i in rids], occupancy
+
+
+@pytest.mark.parametrize("kv_bits", [0, 8], ids=["float-pool", "int8-pool"])
+@pytest.mark.parametrize("family", list(_AHEAD_FAMILIES))
+def test_looking_ahead_gives_the_parent_orders_tokens(family, kv_bits):
+    """Ten requests over three slots: token for token what the same engine
+    gives when every round is fetched whole in the call that dispatched
+    it, with no more decode rounds and no lower occupancy, whatever the
+    model keeps per slot (K/V planes, a recurrent state row, planes per
+    pass)."""
+    model = make_model(_cfg(**_AHEAD_FAMILIES[family]))
+    srv = deepspeed_tpu.init_serving(
+        model, config={"kv_cache_bits": kv_bits}, dtype=jnp.float32,
+        serving=dict(max_seqs=3, block_size=16, max_model_len=128,
+                     decode_quantum=4, prompt_bucket=16))
+    reqs = _ahead_load()
+    want, occ_parent = _drive(srv, reqs, parent_order=True)
+    st = srv.stats()
+    rounds_parent = sum(st["step_shape_rounds"].values())
+    assert st["rounds_ahead"] == 0 and st["dropped_slot_rounds"] == 0
+    srv.reset_stats()
+    got, occ = _drive(srv, reqs)
+    for i, (a, b) in enumerate(zip(got, want)):
+        np.testing.assert_array_equal(a, b, err_msg=f"request {i}")
+        assert len(a) == len(reqs[i][0]) + reqs[i][1]
+    st = srv.stats()
+    rounds = sum(st["step_shape_rounds"].values())
+    assert rounds == rounds_parent
+    assert st["rounds_ahead"] == rounds - 1      # all but the first
+    assert st["dropped_slot_rounds"] == 0
+    # the same slot-rounds in one call more (the last fetch): occupancy as
+    # the harness reads it, after each call, is not below the parent's
+    assert np.mean(occ) >= np.mean(occ_parent)
+    assert srv._inflight is None and srv.allocator.used_blocks == 0
+    srv.close()
+
+
+@pytest.mark.parametrize("quantum", [1, 2, 8])
+def test_every_quantum_splits_into_fetched_steps_and_steps_behind(quantum):
+    """A quarter of the quantum stays in flight, at least one step: a
+    quantum of 1 is all behind (every round fetched a call later), of 2 one
+    and one, of 8 six and two. Tokens and rounds are the parent order's."""
+    from deepspeed_tpu.inference.serving import _ahead_steps
+    assert _ahead_steps(quantum) == {1: 1, 2: 1, 8: 2}[quantum]
+    srv = _serving(decode_quantum=quantum, max_seqs=3)
+    reqs = _ahead_load(n=6, new=(1, 14))
+    want, _ = _drive(srv, reqs, parent_order=True)
+    rounds_parent = sum(srv.stats()["step_shape_rounds"].values())
+    srv.reset_stats()
+    got, _ = _drive(srv, reqs)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    st = srv.stats()
+    assert sum(st["step_shape_rounds"].values()) == rounds_parent
+    assert st["rounds_ahead"] > 0 and srv._inflight is None
+
+
+def test_a_finish_by_length_gives_its_slot_away_in_the_same_call():
+    """The scheduler counts a finish by length ahead: the call that
+    dispatches round k+1 gives the slot of a request whose budget the
+    unfetched steps of round k exhaust to an admission, and finishes the
+    request when those tokens are committed a moment later."""
+    srv = _serving(max_seqs=1)             # quantum 4: 3 steps + 1 behind
+    first = srv.add_request(np.arange(5, dtype=np.int32), 5)   # 1 + 3 + 1
+    second = srv.add_request(np.arange(9, dtype=np.int32), 6)
+    seen = []
+    schedule = srv.scheduler.schedule
+
+    def spy(**kw):
+        out = schedule(**kw)
+        seen.append(([r.rid for r in out["ended"]],
+                     [(r.rid, r.slot) for r in out["admitted"]],
+                     srv._inflight is not None))
+        return out
+
+    srv.scheduler.schedule = spy
+    assert srv.step() == [] and seen[-1] == ([], [(first, 0)], False)
+    req = srv._requests[first]
+    assert len(req.generated) == 4 and req.inflight_rows == 1
+    assert req.cached_rows == 8                  # rows the host holds
+    done = srv.step()
+    # ended and admitted in ONE schedule, with round 1 still in flight
+    assert seen[-1] == ([first], [(second, 0)], True)
+    assert [r.rid for r in done] == [first] and len(req.generated) == 5
+    assert req.state == "finished" and not srv.scheduler.ending
+    assert [r.rid for r in srv.scheduler.running] == [second]
+    while not srv.scheduler.done:
+        srv.step()
+    assert srv.stats()["dropped_slot_rounds"] == 0
+    eng = deepspeed_tpu.init_inference(
+        srv.model, config={"kv_cache_bits": 0}, dtype=jnp.float32,
+        params=jax.device_get(srv.engine.params))
+    for r, n in ((srv._finished[0], 5), (srv._finished[1], 6)):
+        one = np.asarray(eng.generate(r.prompt[None], max_new_tokens=n))[0]
+        np.testing.assert_array_equal(r.output, one)
+
+
+@pytest.mark.parametrize("nth,dropped", [(3, 0), (5, 1)],
+                         ids=["in-the-fetched-steps", "in-the-step-behind"])
+def test_an_eos_ends_the_request_with_the_parent_orders_tokens(nth, dropped):
+    """A finish the host cannot count. An eos among the steps a call
+    fetches is seen before the next round is dispatched, like the
+    parent's; one in the steps left in flight (quantum 4: the 5th token)
+    is found a round late — the request is in the next round by then, that
+    slot's quantum is dropped and counted. Either way the tokens are the
+    parent order's, and end AT the eos."""
+    model = make_model(_cfg())
+    params = jax.device_get(model.init(jax.random.PRNGKey(0)))
+    for seed in range(20):                 # an eos that is not seen sooner
+        prompt = np.random.default_rng(seed).integers(0, 128, 9
+                                                      ).astype(np.int32)
+        free = _serving(model=model, params=params).run([(prompt, 24)])[0]
+        new = list(free[len(prompt):])
+        if new[nth - 1] not in new[:nth - 1]:
+            break
+    eos = int(new[nth - 1])
+    outs = {}
+    for order in ("parent", "ahead"):
+        srv = _serving(model=model, params=params, eos_token_id=eos)
+        outs[order], _ = _drive(srv, [(prompt, 24)],
+                                parent_order=order == "parent")
+        assert srv.stats()["dropped_slot_rounds"] == (
+            dropped if order == "ahead" else 0)
+        assert srv._inflight is None and srv.allocator.used_blocks == 0
+    np.testing.assert_array_equal(outs["ahead"][0], outs["parent"][0])
+    np.testing.assert_array_equal(outs["ahead"][0],
+                                  free[:len(prompt) + nth])
+
+
+def test_something_runs_or_waits_while_tokens_are_uncommitted(tmp_path):
+    """The harness calls ``step()`` only while ``scheduler.running or
+    num_waiting``: a request stays running until its last tokens are on
+    the host, so that loop finishes every request at its full length, and
+    ``run()`` and ``drain()`` leave no round in flight."""
+    srv = _serving()
+    reqs = _ahead_load(n=6, new=(1, 20))
+
+    def holds(srv):
+        rec = srv._inflight
+        if rec is not None and rec.live():
+            assert srv.scheduler.running
+            assert all(req in srv.scheduler.running for req, _ in rec.live())
+        assert not srv.scheduler.ending          # only inside a round
+
+    outs, _ = _drive(srv, reqs, each=holds)
+    assert [len(o) for o in outs] == [len(p) + n for p, n in reqs]
+    assert srv._inflight is None and srv.scheduler.done
+    srv.reset_stats()
+    again = srv.run(list(reqs))
+    assert srv._inflight is None
+    for a, b in zip(outs, (again[i] for i in sorted(again))):
+        np.testing.assert_array_equal(a, b)
+    # a drain mid-load: the round in flight is dropped, not fetched — the
+    # state holds what the host has and a fresh engine computes the rest
+    srv.reset_stats()
+    rids = [srv.add_request(p, n) for p, n in reqs]
+    srv.step(), srv.step()
+    assert srv._inflight is not None and srv._inflight.live()
+    before = list(srv._finished)
+    srv.drain(str(tmp_path))
+    assert srv._inflight is None and srv._finished == before
+    assert all(r.inflight_rows == 0 for r in
+               list(srv.scheduler.waiting) + srv.scheduler.running)
+    srv2 = _serving(params=jax.device_get(srv.engine.params))
+    srv2.resume(str(tmp_path))
+    resumed = {r.rid: r.output for r in before}
+    while not srv2.scheduler.done:
+        for r in srv2.step():
+            resumed[r.rid] = r.output
+    assert sorted(resumed) == rids
+    for a, i in zip(outs, rids):
+        np.testing.assert_array_equal(a, resumed[i])
+
+
+def test_a_speculation_engine_never_runs_ahead():
+    """A verify step's proposals are drafted from the tokens on the host:
+    a speculation round is fetched whole by the call that dispatched it."""
+    model = make_model(_cfg())
+    params = jax.device_get(model.init(jax.random.PRNGKey(0)))
+    reqs = _ahead_load(n=5, new=(4, 20))
+    want, _ = _drive(_serving(model=model, params=params), reqs)
+
+    def never(srv):
+        assert srv._inflight is None
+
+    srv = _serving(model=model, params=params, spec_tokens=2)
+    got, _ = _drive(srv, reqs, each=never)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    st = srv.stats()
+    assert st["rounds_ahead"] == 0 and st["spec_steps"] > 0
+    assert sum(st["step_shape_rounds"].values()) == 0
+
+
+def test_a_prompt_buckets_first_prefill_runs_in_one_chunk(monkeypatch):
+    """A bucket's first prompt traces and lowers its prefill below a frame
+    that opens a 1 MiB chunk of the interpreter's frame stack (ISSUE 36:
+    across a 16 KiB chunk boundary the same lowering cost a chat cell 2.6
+    or 4.7 s of set-up, whichever way a frame's size above it pushed);
+    later prompts of the bucket, and a re-prefill after a preemption, call
+    the program directly. Tokens are one-shot ``generate``'s."""
+    from deepspeed_tpu.inference import serving
+    # 64 Ki words of declared stack: the chunk CPython opens for the frame
+    # is twice that, the rest holds what the frame calls
+    assert serving._in_one_chunk.__code__.co_stacksize == 1 << 16
+    assert serving._in_one_chunk(divmod, 7, 2) == (3, 1)
+    firsts, traced = [], []
+    in_one_chunk = serving._in_one_chunk
+
+    def spy(fn, *args):
+        if fn in srv._prefill_fns.values():            # not a step's lowering
+            firsts.append(args[1].shape[1])            # the bucket
+        return in_one_chunk(fn, *args)
+
+    monkeypatch.setattr(serving, "_in_one_chunk", spy)
+    model = make_model(_cfg())
+    srv = _serving(model, max_seqs=2, num_blocks=9)
+    prefill_paged = srv.model.prefill_paged
+
+    def count(params, ids, *a, **kw):
+        traced.append(ids.shape[1])
+        return prefill_paged(params, ids, *a, **kw)
+
+    srv.model.prefill_paged = count
+    rng = np.random.default_rng(3)
+    reqs = [(rng.integers(0, 128, size=(n,)).astype(np.int32), 40)
+            for n in (9, 26, 12, 30)]                  # buckets 16, 32, 16, 32
+    outs = srv.run(reqs)
+    assert srv.stats()["preemptions"] >= 1             # re-prefills happened
+    # the prompts' buckets and those of the re-prefilled contexts, each
+    # traced once, under the roomy frame
+    assert {16, 32} < set(firsts) and len(set(firsts)) == len(firsts)
+    assert traced == firsts and sorted(firsts) == sorted(srv._prefill_fns)
+    eng = deepspeed_tpu.init_inference(
+        model, config={"kv_cache_bits": 0}, dtype=jnp.float32,
+        params=jax.device_get(srv.engine.params))
+    for i, (p, n) in enumerate(reqs):
+        one = np.asarray(eng.generate(p[None], max_new_tokens=n))[0]
+        np.testing.assert_array_equal(outs[sorted(outs)[i]], one)

@@ -33,6 +33,12 @@ drain serializes mid-chunk prefills and preemption re-prefills re-match
 the cache on resume. The acceptance bar is the same and stricter: outputs
 bit-identical to the PLAIN fault-free engine (latency features and
 faults both invisible in the token stream).
+
+ISSUE 36: the loop dispatches a round before it has fetched the one before,
+so every fault after the first call finds a round in flight. The quick
+tier holds one case per fault (``TestFaultsWithARoundInFlight``): the round
+in flight is discarded or committed whole, never half, and the tokens are
+the undisturbed run's.
 """
 
 import glob
@@ -51,8 +57,6 @@ from deepspeed_tpu.robustness import events as rb_events
 from deepspeed_tpu.robustness import faults as rb_faults
 from deepspeed_tpu.robustness.faults import FaultInjector, FaultSchedule
 from deepspeed_tpu.robustness.preemption import Preempted, PreemptionHandler
-
-pytestmark = pytest.mark.slow
 
 N_REQUESTS = 32
 
@@ -94,6 +98,7 @@ def _serving(model, params, jsonl=None, **kw):
                                       params=jax.device_get(params))
 
 
+@pytest.mark.slow
 class TestServingChaosSoak:
     def test_soak_bit_identical_to_fault_free(self, tmp_path):
         model = _model()
@@ -224,6 +229,7 @@ def _shared_load(n=24):
     return reqs
 
 
+@pytest.mark.slow
 class TestLatencyTierChaosSoak:
     def test_soak_with_prefix_cache_and_speculation_armed(self, tmp_path):
         """ISSUE 12: the fault schedule replayed with CoW prefix cache +
@@ -308,3 +314,91 @@ class TestLatencyTierChaosSoak:
             if e.scheduler.done:
                 assert e.allocator.used_blocks == \
                     e._prefix_cache.held_blocks
+
+
+class TestFaultsWithARoundInFlight:
+    """Each fault hits while the round before is still unfetched; what the
+    host holds stays authoritative and the outputs are the undisturbed
+    run's. Quick tier: a short load, one fault a case."""
+
+    LOAD = [(5, 14), (23, 11), (12, 17), (30, 9), (8, 13)]
+
+    def _reqs(self):
+        rng = np.random.default_rng(36)
+        return [(rng.integers(0, 128, size=(n,)).astype(np.int32), k)
+                for n, k in self.LOAD]
+
+    @pytest.mark.parametrize("fault,serving", [
+        ({"kind": "decode_dispatch", "at": 2, "mode": "hang", "hang_s": 2.0},
+         dict(dispatch_timeout_s=0.6, decode_backend="xla")),
+        ({"kind": "backend_fault", "at": 2}, dict(decode_backend="pallas")),
+    ], ids=["dispatch-hang", "backend-fault"])
+    def test_recovery_discards_the_round_in_flight(self, fault, serving):
+        model = _model()
+        params = model.init(jax.random.PRNGKey(0))
+        reqs = self._reqs()
+        base = _serving(model, params, max_seqs=2, num_blocks=None,
+                        **serving).run(list(reqs))
+        inj = rb_faults.install(FaultInjector(FaultSchedule([fault], seed=1)))
+        srv = _serving(model, params, max_seqs=2, num_blocks=None, **serving)
+        found = []
+        recover = srv._recover
+
+        def spy(reason):
+            rec = srv._inflight
+            found.append(rec is not None and len(rec.live()))
+            recover(reason)
+            # discarded WHOLE: nothing of it is left to commit, every
+            # request stands at the tokens the host held
+            assert srv._inflight is None and not srv.scheduler.running
+            assert all(r.inflight_rows == 0 and r.cached_rows == 0
+                       for r in srv.scheduler.waiting)
+
+        srv._recover = spy
+        outs = srv.run(list(reqs))
+        assert [f["kind"] for f in inj.fired] == [fault["kind"]]
+        assert found and found[0] > 0            # a round WAS in flight
+        if fault["kind"] == "backend_fault":
+            assert srv.decode_backend == "xla"
+            assert rb_events.history("backend_degraded")
+        assert srv.stats()["recoveries"] == len(found)
+        for i in base:
+            np.testing.assert_array_equal(base[i], outs[i],
+                                          err_msg=f"request {i}")
+        assert srv.close()
+
+    def test_a_preemption_drops_the_victims_tokens_in_flight(self):
+        """A pool below full residency: growth preempts the newest request
+        while its quantum's last step is still on the device's queue. That
+        token is dropped when it arrives (never appended to a context that
+        was re-prefilled without it) and computed again."""
+        model = _model()
+        params = model.init(jax.random.PRNGKey(0))
+        rng = np.random.default_rng(4)
+        reqs = [(rng.integers(0, 128, size=(26,)).astype(np.int32), 40)
+                for _ in range(4)]
+        base = _serving(model, params, max_seqs=2, num_blocks=None,
+                        decode_backend="xla").run(list(reqs))
+        srv = _serving(model, params, max_seqs=2, num_blocks=9,
+                       decode_backend="xla")
+        hit = []
+        preempt = srv.scheduler.preempt
+
+        def spy(req):
+            rec = srv._inflight
+            hit.append((rec is not None
+                        and req in [r for r, _ in rec.live()],
+                        req.inflight_rows, len(req.generated)))
+            return preempt(req)
+
+        srv.scheduler.preempt = spy
+        outs = srv.run(list(reqs))
+        st = srv.stats()
+        assert st["preemptions"] >= 1 and st["recoveries"] == 0
+        # hit WITH steps in flight: the victim restarts from what the host
+        # held, the step behind it is computed again
+        assert hit and all(inflight and rows > 0 for inflight, rows, _ in hit)
+        for i in base:
+            np.testing.assert_array_equal(base[i], outs[i],
+                                          err_msg=f"request {i}")
+        assert srv.allocator.used_blocks == 0 and srv._inflight is None

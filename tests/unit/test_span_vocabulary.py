@@ -352,14 +352,21 @@ class TestRequestLifecycle:
         assert not any(k in srv.stats() for k in NEW_STATS)
         srv.close()
 
-    def test_one_delivery_has_no_gap(self):
+    @pytest.mark.parametrize("budget,deliveries", [(3, 1), (12, 2)])
+    def test_one_delivery_has_no_gap(self, budget, deliveries):
+        """A quantum of 8: the call that dispatches a prompt's prefill
+        fetches its first token and six steps' tokens together; the two
+        steps behind them, and every later round, are deliveries of their
+        own."""
         srv = _serving(decode_quantum=8)
-        req = _add(srv, 5, 3)
+        req = _add(srv, 5, budget)
         while srv.scheduler.running or srv.scheduler.num_waiting:
             srv.step()
-        assert len(req.generated) == 3 and req.max_gap_ms is None
+        assert len(req.generated) == budget
+        assert (req.max_gap_ms is None) == (deliveries == 1)
         st = srv.stats()
-        assert "queue_wait_p90_ms" in st and "token_gap_max_p90_ms" not in st
+        assert "queue_wait_p90_ms" in st
+        assert ("token_gap_max_p90_ms" not in st) == (deliveries == 1)
         srv.close()
 
     def test_admit_t_agrees_with_the_tracers_queue_wait_span(self):
