@@ -79,6 +79,7 @@ import collections
 import contextlib
 import dataclasses
 import functools
+import gc
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -113,13 +114,15 @@ class _DispatchedRound:
     the last steps, which stay in flight while the host commits the head
     and schedules and dispatches the next round, and are fetched by the
     next call. Either is None when it holds no step. ``t0``: when the
-    dispatch began (request traces)."""
+    dispatch began (request traces). ``covered``: what the probe read just
+    before the round's first step was issued (``_dispatch_round``)."""
     entries: list
     shape: tuple
     head: Any
     tail: Any
     spec: bool
     t0: float
+    covered: Optional[bool] = None
 
     def live(self):
         """The entries still owed tokens."""
@@ -158,6 +161,34 @@ def _in_one_chunk(fn, *args):
 
 
 _in_one_chunk.__code__ = _in_one_chunk.__code__.replace(co_stacksize=1 << 16)
+
+
+class _GcClock:
+    """Seconds this process has spent in garbage collections of any
+    generation: ONE ``gc.callbacks`` hook, hung up by the first serving
+    engine and shared by all of them (the list is the process's, and so is
+    a collection), two ``perf_counter`` stamps a collection. A round reads
+    ``seconds`` before and after itself (``ServingEngine.step``): its
+    ``gc_ms``."""
+
+    def __init__(self):
+        self.seconds = 0.0
+        self._t0 = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t0 = time.perf_counter()
+        elif self._t0 is not None:
+            self.seconds += time.perf_counter() - self._t0
+            self._t0 = None
+
+    def install(self) -> "_GcClock":
+        if self not in gc.callbacks:
+            gc.callbacks.append(self)
+        return self
+
+
+_GC_CLOCK = _GcClock()
 
 
 class DecodeDispatchHang(RuntimeError):
@@ -419,7 +450,10 @@ _LAT_COUNTERS = {"spec_steps": 0, "spec_proposed": 0, "spec_accepted": 0,
                  # plain decode rounds dispatched while the round before was
                  # still unfetched (of the rounds step_shape_rounds counts),
                  # and slot-rounds whose tokens nobody was left to take
-                 "rounds_ahead": 0, "dropped_slot_rounds": 0}
+                 "rounds_ahead": 0, "dropped_slot_rounds": 0,
+                 # of the rounds ahead: the chip still had work when the
+                 # next round's first step was issued / had run dry
+                 "ahead_covered_rounds": 0, "ahead_dry_rounds": 0}
 
 
 class ServingEngine:
@@ -752,15 +786,15 @@ class ServingEngine:
         # round-phase decomposition: every _round() times its phases
         # (schedule / housekeeping / prefill dispatch / decode dispatch /
         # token fetch / commit) through telemetry.tracing.span — always on,
-        # seven inactive TraceAnnotations a round. The milliseconds add up
-        # in _phase_totals over the stats window; the ring of the last 256
-        # rounds is the stall rule's baseline (_note_phases), nothing else.
-        self._phases: "collections.deque[Dict[str, float]]" = \
-            collections.deque(maxlen=256)
-        self._phase_totals = dict.fromkeys(self._PHASE_OUT, 0.0)
-        self._rounds = 0                   # rounds in this stats window
-        self._round_tokens = 0             # tokens committed this round
-        self._phase_stall_events = 0       # serving_phase_stall emissions
+        # seven inactive TraceAnnotations a round. A round leaves ONE record
+        # (_round's docstring); what the stats window keeps of them is in
+        # _reset_round_records.
+        self._gc = _GC_CLOCK.install()
+        self._reset_round_records()
+        # when the engine last came to hold no request (None: it holds
+        # one), and what a round's record says of the interval it ended
+        self._empty_since: Optional[float] = None
+        self._empty_before_s = 0.0
         self._tracer = None                # RequestTracer when armed
         if c.request_trace:
             self.enable_request_trace(replica=c.trace_replica)
@@ -881,22 +915,78 @@ class ServingEngine:
     # fires — CPU-test rounds stay quiet
     _STALL_MIN_ROUND_MS = 50.0
     _STALL_FRACTION = 0.6
+    # the ring of round records: a 45 s window of the fastest cell is ~450
+    # rounds; and how many of the slowest rounds a window keeps whole
+    _RING_ROUNDS = 512
+    _SLOW_ROUNDS = 8
+    _PHASES = ("schedule", "housekeeping", "prefill", "decode", "fetch",
+               "commit")
 
-    def _note_phases(self, entry: Dict[str, float]) -> None:
-        """Add one round's phase decomposition to the window's totals (and
-        to the ring, the stall rule's baseline) and emit (at most one
-        per stats window) a ``serving_phase_stall`` event when a NON-fetch
-        phase dominates a round that regressed against the window's own
-        steady state (3x the prior-round median, with >= 8 warm rounds of
-        baseline — jit-compile rounds never have one, so short CPU runs
-        stay quiet). The fetch phase is exempt: the one sync of the round
-        legitimately waits on the device — a doctor reading fetch-bound
-        means 'the accelerator is the bottleneck', which is health, not a
-        stall."""
+    def _reset_round_records(self) -> None:
+        """What a stats window keeps of its rounds' records: the ring of
+        the newest (the ONE bounded store: the stall rule's baseline and
+        the median), the totals over every round, and over the
+        decode-dominated rounds (``_decode_dominated``) the maximum of each
+        phase and of the round and the slowest few, each with its
+        follower."""
+        self._phases: "collections.deque[Dict[str, Any]]" = \
+            collections.deque(maxlen=self._RING_ROUNDS)
+        self._phase_totals = dict.fromkeys(self._PHASE_OUT, 0.0)
+        self._phase_max = dict.fromkeys(
+            [f"{p}_ms" for p in self._PHASES] + ["round_ms"], 0.0)
+        self._slow: List[list] = []        # [record, follower], slowest first
+        self._slow_open: Optional[list] = None   # the pair owed a follower
+        self._gc_ms_total = 0.0
+        self._empty_s = 0.0                # closed empty intervals
+        self._rounds = 0                   # rounds in this stats window
+        self._round_tokens = 0             # tokens committed this round
+        self._phase_stall_events = 0       # serving_phase_stall emissions
+
+    @staticmethod
+    def _decode_dominated(entry: Dict[str, Any]) -> bool:
+        """A round whose length is its decode steps': fewer prompts
+        admitted than requests were decoding when it began. That leaves out
+        the round that fills every empty slot at the start of a saturating
+        window and the first round after an empty engine, whose length is
+        their prefills', and a round that ran nothing."""
+        return entry["prefills"] < entry["running_before"]
+
+    def _note_phases(self, entry: Dict[str, Any]) -> None:
+        """Take one round's record (``_round``) into the stats window: the
+        ring, the totals over every round, the maxima and the slowest
+        rounds over the decode-dominated ones — a slow round is kept with
+        the record of the round that FOLLOWED it, whose probe tells a slow
+        device (``ahead_covered`` True, as in any sound round) from a host
+        that was away while the device went on (False, and a short fetch).
+        Then the stall rule: at most one ``serving_phase_stall`` event per
+        stats window, when a phase dominates a round that regressed against
+        the window's own steady state (3x the prior-round median, with >= 8
+        warm rounds of baseline — jit-compile rounds never have one, so
+        short CPU runs stay quiet). The fetch counts like any phase: a
+        round's device time is constant to four digits, so a fetch three
+        times the median is the device, its runtime or a descheduled host,
+        and the record — the event's payload — says which. (That the
+        window's TOTALS are fetch-bound is still health: the doctor's
+        reading, ``profiling.doctor.diagnose_serving``.)"""
         self._phases.append(entry)
         self._rounds += 1
         for key in self._phase_totals:
             self._phase_totals[key] += entry[key]
+        self._gc_ms_total += entry["gc_ms"]
+        if self._slow_open is not None:
+            self._slow_open[1] = entry
+            self._slow_open = None
+        if self._decode_dominated(entry):
+            for key, top in self._phase_max.items():
+                if entry[key] > top:
+                    self._phase_max[key] = entry[key]
+            slow = self._slow
+            if len(slow) < self._SLOW_ROUNDS \
+                    or entry["round_ms"] > slow[-1][0]["round_ms"]:
+                self._slow_open = [entry, None]
+                slow.append(self._slow_open)
+                slow.sort(key=lambda pair: -pair[0]["round_ms"])
+                del slow[self._SLOW_ROUNDS:]
         if (not self._quantum_warm or self._phase_stall_events
                 or len(self._phases) < 9
                 or entry["round_ms"] < self._STALL_MIN_ROUND_MS):
@@ -904,15 +994,31 @@ class ServingEngine:
         prior = sorted(e["round_ms"] for e in list(self._phases)[:-1])
         if entry["round_ms"] < 3.0 * max(prior[len(prior) // 2], 1e-9):
             return
-        for phase in ("schedule", "housekeeping", "prefill", "decode",
-                      "commit"):
+        for phase in self._PHASES:
             ms = entry[f"{phase}_ms"]
             if ms > self._STALL_FRACTION * entry["round_ms"]:
                 self._phase_stall_events += 1
                 rb_events.emit("serving_phase_stall", phase=phase,
                                phase_ms=round(ms, 2),
-                               round_ms=round(entry["round_ms"], 2))
+                               round_ms=round(entry["round_ms"], 2),
+                               record=dict(entry))
                 break
+
+    def _open_empty_s(self, now: float) -> float:
+        """Seconds of the stats window for which the engine has held no
+        request up to ``now``, if it holds none; else 0."""
+        if self._empty_since is None or self._stats_t0 is None:
+            return 0.0
+        return max(0.0, now - max(self._empty_since, self._stats_t0))
+
+    def _occupied(self, now: float) -> None:
+        """The engine holds a request again: close the empty interval, if
+        one is open. Only what lies inside the stats window counts, in
+        ``engine_empty_s`` and in the next round's ``empty_before_ms``."""
+        gone = self._open_empty_s(now)
+        self._empty_s += gone
+        self._empty_before_s += gone
+        self._empty_since = None
 
     def obs_meta(self) -> Dict[str, Any]:
         """Compact rollup payload for the router's fleet aggregation:
@@ -1294,62 +1400,69 @@ class ServingEngine:
         counted (stats()["shed"]) and evented, never silently queued.
         ``adapter_id`` routes the request through a registered LoRA
         adapter (0 = base model); unknown ids refuse at submission, not
-        at dispatch."""
-        prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
-        if adapter_id:
-            if not self._lora:
+        at dispatch. The engine's part of a submission — validation, the
+        scheduler's ``submit`` — is one ``ds:serve.submit`` span; on an
+        engine that held nothing it ends the empty interval that
+        ``step()``'s ``ds:serve.drained`` began."""
+        with span("ds:serve.submit"):
+            prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
+            if adapter_id:
+                if not self._lora:
+                    raise ValueError(
+                        f"adapter_id={adapter_id} with adapter_slots=0: "
+                        "LoRA serving is off")
+                if adapter_id not in self.adapter_store:
+                    raise ValueError(
+                        f"adapter_id={adapter_id} is not registered "
+                        "(register_adapter first)")
+            if max_new_tokens < 1:
+                # the prefill inherently samples one token; a 0-budget request
+                # would still emit it
+                raise ValueError(f"max_new_tokens={max_new_tokens}: must be "
+                                 ">= 1")
+            if prompt.size + max_new_tokens > self.max_model_len:
                 raise ValueError(
-                    f"adapter_id={adapter_id} with adapter_slots=0: "
-                    "LoRA serving is off")
-            if adapter_id not in self.adapter_store:
-                raise ValueError(
-                    f"adapter_id={adapter_id} is not registered "
-                    "(register_adapter first)")
-        if max_new_tokens < 1:
-            # the prefill inherently samples one token; a 0-budget request
-            # would still emit it
-            raise ValueError(f"max_new_tokens={max_new_tokens}: must be "
-                             ">= 1")
-        if prompt.size + max_new_tokens > self.max_model_len:
-            raise ValueError(
-                f"prompt ({prompt.size}) + max_new_tokens "
-                f"({max_new_tokens}) exceeds max_model_len "
-                f"{self.max_model_len}")
-        if self._draining:
-            self._counters["shed"] += 1
-            rb_events.emit("request_shed", reason="draining")
-            raise AdmissionRejected("draining")
-        try:
-            req = self.scheduler.submit(
-                prompt, max_new_tokens, rid=request_id,
-                ttft_deadline_ms=(ttft_deadline_ms
-                                  if ttft_deadline_ms is not None
-                                  else self.config.ttft_deadline_ms),
-                deadline_ms=(deadline_ms if deadline_ms is not None
-                             else self.config.deadline_ms),
-                adapter_id=adapter_id)
-        except AdmissionRejected as e:
-            self._counters["shed"] += 1
-            rb_events.emit("request_shed", reason=e.reason, **e.detail)
-            raise
-        self._requests[req.rid] = req
-        if self._tracer is not None:
-            self._tracer.begin(req.rid)
-            self._tracer.instant(req.rid, "admitted",
-                                 prompt_tokens=int(prompt.size),
-                                 adapter=adapter_id)
-        # queue-wait clock: spans from here (or the latest preemption)
-        # until the request's next dispatch
-        req._trace_wait_t0 = req.submit_t
-        if self._stats_t0 is None:
-            self._stats_t0 = req.submit_t
-        return req.rid
+                    f"prompt ({prompt.size}) + max_new_tokens "
+                    f"({max_new_tokens}) exceeds max_model_len "
+                    f"{self.max_model_len}")
+            if self._draining:
+                self._counters["shed"] += 1
+                rb_events.emit("request_shed", reason="draining")
+                raise AdmissionRejected("draining")
+            try:
+                req = self.scheduler.submit(
+                    prompt, max_new_tokens, rid=request_id,
+                    ttft_deadline_ms=(ttft_deadline_ms
+                                      if ttft_deadline_ms is not None
+                                      else self.config.ttft_deadline_ms),
+                    deadline_ms=(deadline_ms if deadline_ms is not None
+                                 else self.config.deadline_ms),
+                    adapter_id=adapter_id)
+            except AdmissionRejected as e:
+                self._counters["shed"] += 1
+                rb_events.emit("request_shed", reason=e.reason, **e.detail)
+                raise
+            self._requests[req.rid] = req
+            if self._tracer is not None:
+                self._tracer.begin(req.rid)
+                self._tracer.instant(req.rid, "admitted",
+                                     prompt_tokens=int(prompt.size),
+                                     adapter=adapter_id)
+            # queue-wait clock: spans from here (or the latest preemption)
+            # until the request's next dispatch
+            req._trace_wait_t0 = req.submit_t
+            # an engine that held nothing holds this one now; the stats
+            # window opens with its first request
+            self._occupied(req.submit_t)
+            if self._stats_t0 is None:
+                self._stats_t0 = req.submit_t
+            return req.rid
 
     def _dispatch_prefill(self, req: Request):
         """Dispatch (no sync) the request's (re-)prefill: writes its
         context rows into its blocks, leaves the next sampled token pending
         in the device token vector AND as a per-request handle fetched at
-        the round boundary."""
+        the round boundary. Returns the padded prompt length."""
         import jax.numpy as jnp
         ctx = req.context
         P = self._pad_prompt(ctx.size)
@@ -1372,6 +1485,7 @@ class ServingEngine:
         # (token, the prefill's counters): fetched at round boundary
         req._first_dev = first
         self._publish_prefill(req, ctx)
+        return P
 
     def _state_slot(self, req: Request) -> tuple:
         """What a prefill needs beyond the blocks: nothing, or — a model
@@ -1448,7 +1562,7 @@ class ServingEngine:
         of the request's context computed against the rows already in its
         blocks. The final chunk samples the request's first token and
         flips it into the decoding set (same pending-token protocol as the
-        whole-prompt prefill)."""
+        whole-prompt prefill). Returns the padded chunk length."""
         import jax.numpy as jnp
         ctx = req.context
         final = start + n == ctx.size
@@ -1475,6 +1589,7 @@ class ServingEngine:
             self._tokens = self._tokens.at[req.slot].set(first[0])
             req.prefill_done = True
             req._first_dev = (first, (None, None))   # (token, no counters)
+        return C
 
     def _tables_device(self, full: bool = False):
         """The round's block tables ``ids[S, W]``, lengths, active mask and
@@ -1527,7 +1642,9 @@ class ServingEngine:
         quantum's last steps (``_ahead_steps``) in flight for the next call
         to fetch, so the chip has work while the host commits, schedules
         and dispatches the next round (``_round``). Returns requests
-        finished this round.
+        finished this round. A call that leaves the engine holding nothing
+        marks the moment (``ds:serve.drained``, zero length, after the
+        round's span has closed; ``_empty_since``).
 
         Reliability: a latched SIGTERM drains the engine first (raising
         ``Preempted``); a round failure — failed/hung dispatch, injected
@@ -1543,13 +1660,15 @@ class ServingEngine:
         last_err: Optional[BaseException] = None
         for _attempt in range(max(0, self.config.round_retries) + 1):
             try:
+                gc_s = self._gc.seconds
                 with span("ds:serve.round", index=self._rounds) as rs:
                     finished, ph = self._round()
                     rs.note(running=len(self.scheduler.running),
                             tokens=self._round_tokens)
                 # only a round that completed counts in the window's totals
                 self._note_phases({**ph, "round_ms": rs.seconds * 1e3,
-                                   "tokens": float(self._round_tokens)})
+                                   "tokens": float(self._round_tokens),
+                                   "gc_ms": (self._gc.seconds - gc_s) * 1e3})
                 break
             except (Preempted, KeyboardInterrupt):
                 raise
@@ -1567,15 +1686,35 @@ class ServingEngine:
             raise RuntimeError(
                 "serving round failed after "
                 f"{self.config.round_retries} recovery retries") from last_err
+        if self._empty_since is None and self._inflight is None \
+                and self.scheduler.done:
+            # nothing running, waiting or in flight: the engine is empty
+            # from here to the next ds:serve.submit, on both clocks
+            self._empty_since = time.perf_counter()
+            with span("ds:serve.drained"):
+                pass
         return finished
 
     def _round(self):
         """The round proper, inside ``step()``'s ``ds:serve.round`` span:
-        (requests finished, host milliseconds per phase). Each phase is one
+        (requests finished, the round's record). Each phase is one
         ``ds:serve.<phase>`` span (telemetry.tracing.span: on the
         profiler's clock in any session), in the order schedule ->
         housekeeping -> prefill_dispatch -> decode_dispatch -> fetch ->
-        commit. The loop looks ahead: the fetch takes the LAST steps of the
+        commit, and its host milliseconds are in the record
+        (``schedule_ms`` ... ``commit_ms``; ``step()`` adds ``round_ms``,
+        ``tokens`` and ``gc_ms``, the collections inside the round) beside
+        what the round was: ``index``; ``t_s``, seconds into the stats
+        window at its start; ``running_before``, the requests running when
+        it began; ``prefills`` / ``prefill_tokens``, the prompts or chunks
+        dispatched in it and their padded tokens; ``shape``, the ``(slots,
+        columns)`` of the decode round it dispatched, or None;
+        ``empty_before_ms``, how long the engine had held nothing when it
+        began; and ``ahead_covered``, the probe (``_dispatch_round``):
+        True if the chip still had work when this round's first step was
+        issued, False if its queue had run dry and it waited for the host,
+        None if nothing was in flight from the call before. The loop looks
+        ahead: the fetch takes the LAST steps of the
         plain decode round the call before dispatched (``_inflight``), this
         call's first tokens and the FIRST steps of the round this call
         dispatched, and leaves that round's last ``_ahead_steps`` on the
@@ -1601,8 +1740,19 @@ class ServingEngine:
           recovery discards the steps in flight with the round it was
           dispatching."""
         self._round_tokens = 0
-        ph = {"schedule_ms": 0.0, "housekeeping_ms": 0.0, "prefill_ms": 0.0,
+        now = time.perf_counter()
+        if not self.scheduler.done:
+            self._occupied(now)      # a request that came by another door
+        ph = {"index": self._rounds,
+              "t_s": (now - self._stats_t0
+                      if self._stats_t0 is not None else 0.0),
+              "running_before": len(self.scheduler.running),
+              "prefills": 0, "prefill_tokens": 0, "shape": None,
+              "ahead_covered": None,
+              "empty_before_ms": self._empty_before_s * 1e3,
+              "schedule_ms": 0.0, "housekeeping_ms": 0.0, "prefill_ms": 0.0,
               "decode_ms": 0.0, "fetch_ms": 0.0, "commit_ms": 0.0}
+        self._empty_before_s = 0.0
         info = rb_faults.serving_round_seam()
         keep = info.get("squeeze")
         if keep is not None:
@@ -1671,10 +1821,12 @@ class ServingEngine:
                                          rows=int(req.kv_rows)):
                             self._dispatch_kv_import(req)
             ph["housekeeping_ms"] = sp.seconds * 1e3
+            newest = None    # the newest array on the device's queue
             with span("ds:serve.prefill_dispatch") as sp:
                 for req, start, n in decisions["prefill"]:
                     if req.state != "running":
                         continue     # bounced by the adapter-slot pin above
+                    ph["prefills"] += 1
                     if start == 0 and n == len(req.context) \
                             and not self._lora:
                         # whole prompt in one go: the PR-9 program (and its
@@ -1685,15 +1837,23 @@ class ServingEngine:
                         # count flat
                         with self._rspan(req.rid, "prefill", tokens=int(n),
                                          reprefill=req.preemptions > 0):
-                            self._dispatch_prefill(req)
+                            ph["prefill_tokens"] += self._dispatch_prefill(
+                                req)
                     else:
                         with self._rspan(req.rid, "prefill_chunk",
                                          start=int(start), tokens=int(n)):
-                            self._dispatch_chunk(req, start, n)
+                            ph["prefill_tokens"] += self._dispatch_chunk(
+                                req, start, n)
+                    if getattr(req, "_first_dev", None) is not None:
+                        newest = req._first_dev[0]
             ph["prefill_ms"] = sp.seconds * 1e3
             prior = self._inflight
             if prior is None and not self.scheduler.running:
                 return [], ph
+            if prior is None:
+                newest = None    # the chip had nothing when the call began
+            elif newest is None:
+                newest = prior.tail[0]
 
             # a prefill-role engine NEVER runs decode quanta: requests sit
             # prefill_done until the router hands them (with their KV
@@ -1710,10 +1870,14 @@ class ServingEngine:
             rec = None
             with span("ds:serve.decode_dispatch") as sp_dec:
                 if decode:
-                    rec = self._dispatch_round(spec, sp_dec.t0)
+                    rec = self._dispatch_round(spec, sp_dec.t0, newest)
+                    ph["shape"], ph["ahead_covered"] = rec.shape, rec.covered
                     if not spec:
                         self._table_rounds[rec.shape] += 1
                         self._lat["rounds_ahead"] += prior is not None
+                        self._lat["ahead_covered_rounds"] += \
+                            rec.covered is True
+                        self._lat["ahead_dry_rounds"] += rec.covered is False
                 # first tokens ride THIS call's fetch: a prefill dispatched
                 # above sits on the device's queue behind the steps in
                 # flight and ahead of the round just dispatched
@@ -1745,12 +1909,19 @@ class ServingEngine:
                 self._tracer.end(req.rid)
         return finished, ph
 
-    def _dispatch_round(self, spec: bool, t0: float) -> _DispatchedRound:
+    def _dispatch_round(self, spec: bool, t0: float,
+                        newest=None) -> _DispatchedRound:
         """Dispatch (no sync) one decode round for the running requests
         that have their prompt in — the quantum's steps, or ONE verify step
         — and thread the pools and the token vector through it. Returns the
         round's record; its tokens are fetched by ``_land``, a plain
-        round's in two parts (``_ahead_steps``)."""
+        round's in two parts (``_ahead_steps``). ``newest``: the newest
+        array the engine had put on the device's queue before this round —
+        the stacked tokens of the last steps in flight, or the first token
+        of the call's last prefill — or None when nothing was in flight.
+        It is asked ``is_ready()`` once the tables and keys are built, just
+        before the first step is issued (no sync, no copy): not ready means
+        the chip still had work when the host got there (``covered``)."""
         import jax.numpy as jnp
         tables, seq_lens, active, aidx = self._tables_device(full=spec)
         # the plain step runs as the program of the tables' shape
@@ -1804,6 +1975,7 @@ class ServingEngine:
                         head, outs = part(outs), []
                 return p, t, head, part(outs)
 
+        covered = None if newest is None else not newest.is_ready()
         dev = self._with_watchdog(dispatch, armed=self._quantum_warm)
         if dev is None:     # only reachable through a stale epoch
             raise DecodeDispatchHang("round abandoned by recovery")
@@ -1814,7 +1986,7 @@ class ServingEngine:
             for req, _, _ in entries:
                 req.inflight_rows += len(keys)
         return _DispatchedRound(entries, tuple(tables.shape), head, tail,
-                                spec, t0)
+                                spec, t0, covered)
 
     def _land(self, prior: Optional[_DispatchedRound],
               rec: Optional[_DispatchedRound], pending: list,
@@ -2694,14 +2866,13 @@ class ServingEngine:
         if self._lora:
             p = self.adapter_slots
             p.hits = p.evictions = p.page_ins = 0
-        # fleet observability (ISSUE 18): the phase ring, the blind-stall
-        # latch and the tracer's sync self-report are window-scoped too —
-        # the reset-parity sweep pins that every rollup counter clears
-        self._phases.clear()
-        self._phase_totals = dict.fromkeys(self._PHASE_OUT, 0.0)
-        self._rounds = 0
-        self._round_tokens = 0
-        self._phase_stall_events = 0
+        # fleet observability (ISSUE 18): the rounds' records, the
+        # blind-stall latch and the tracer's sync self-report are
+        # window-scoped too — the reset-parity sweep pins that every rollup
+        # counter clears. An empty interval still open is counted from the
+        # new window's first request (_occupied)
+        self._reset_round_records()
+        self._empty_before_s = 0.0
         if self._tracer is not None:
             self._tracer.device_syncs = 0
 
@@ -2779,22 +2950,32 @@ class ServingEngine:
         The decode step's shape (always on; ``_tables_device``; speculation
         rounds keep the full tables and are not counted):
         ``step_shape_rounds`` — a dict ``{"<slots>x<width in columns>":
-        plain decode rounds dispatched at it}`` over both ladders — and
-        ``table_width_rounds`` ``{width: rounds}``, the same rounds summed
-        over the slot counts; ``slot_count_mean`` and ``table_width_mean``
-        over those rounds.
+        plain decode rounds dispatched at it}`` over both ladders.
 
         The round order (always on; ``_round``): ``rounds_ahead`` — of the
         rounds ``step_shape_rounds`` counts, those dispatched while the
         last steps of the round before were still unfetched (all but the
-        first after an idle moment: the chip then never waits for the host
-        between two rounds) — and ``dropped_slot_rounds`` — slot-rounds run
+        first after an idle moment) —, of those ``ahead_covered_rounds``
+        (the chip still had work when the round's first step was issued:
+        it never waited for the host) and ``ahead_dry_rounds`` (its queue
+        had run dry), and ``dropped_slot_rounds`` — slot-rounds run
         for a request that had already ended: the quantum a slot ran after
         an eos among the steps the host had not fetched yet, or behind a
         one-token budget (0 where every request ends by length past its
         first token). Tokens of the steps in flight are in neither
         ``generated`` nor ``cached_rows`` until the next ``step()`` commits
-        them."""
+        them.
+
+        The rounds' records (always on; ``_round``, ``_note_phases``), over
+        the stats window: ``slow_rounds`` — the eight slowest
+        decode-dominated rounds (fewer prompts admitted than requests were
+        decoding), slowest first, each ``[its record, the record of the
+        round that followed it]`` —, ``round_ms_max`` and ``phase_ms_max``
+        (``{phase: ms}``) over those rounds, ``round_ms_median`` over the
+        ring's, ``gc_ms_total`` (garbage collection inside rounds), and the
+        empty engine: ``engine_empty_s`` (seconds of the window in which it
+        held no request, an interval still open included) of
+        ``stats_window_s`` (since the window's first request)."""
         done = [r for r in self._finished if r.first_token_t is not None]
         out: Dict[str, Any] = {
             "completed": float(len(self._finished)),
@@ -2882,14 +3063,20 @@ class ServingEngine:
         out.update({k: float(v) for k, v in self._lat.items()})
         out["step_shape_rounds"] = {
             f"{S}x{W}": n for (S, W), n in self._table_rounds.items()}
-        out["table_width_rounds"] = {
-            w: sum(n for (_, W), n in self._table_rounds.items() if W == w)
-            for w in self._table_widths}
-        rounds = sum(self._table_rounds.values())
-        if rounds:
-            out["slot_count_mean"], out["table_width_mean"] = (
-                sum(shape[i] * n for shape, n in self._table_rounds.items())
-                / rounds for i in (0, 1))
+        out["slow_rounds"] = [[dict(rec), nxt and dict(nxt)]
+                              for rec, nxt in self._slow]
+        out["gc_ms_total"] = float(self._gc_ms_total)
+        typical = [e["round_ms"] for e in self._phases
+                   if self._decode_dominated(e)]
+        if typical:
+            out["round_ms_median"] = float(np.median(typical))
+            out["round_ms_max"] = float(self._phase_max["round_ms"])
+            out["phase_ms_max"] = {p: float(self._phase_max[f"{p}_ms"])
+                                   for p in self._PHASES}
+        now = time.perf_counter()
+        out["stats_window_s"] = (now - self._stats_t0
+                                 if self._stats_t0 is not None else 0.0)
+        out["engine_empty_s"] = self._empty_s + self._open_empty_s(now)
         if self._lat["spec_proposed"]:
             out["spec_accept_rate"] = float(round(
                 self._lat["spec_accepted"] / self._lat["spec_proposed"], 4))

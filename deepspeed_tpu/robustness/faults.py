@@ -91,10 +91,10 @@ replica imports; ``at`` counts 0-based handoff attempts):
 Observability of injected faults (ISSUE 18): every kind above already
 emits ``fault_injected`` plus its recovery record; the fleet-observability
 layer adds two read-side event types an injected stall surfaces through —
-``serving_phase_stall {phase, phase_ms, round_ms}`` when a warm engine's
-round regresses >= 3x its window median with a non-fetch phase dominant
+``serving_phase_stall {phase, phase_ms, round_ms, record}`` when a warm
+engine's round regresses >= 3x its window median with one phase dominant
 (a ``pool_exhaust`` squeeze or adapter-paging storm reads as
-``housekeeping``-bound here), and ``trace_export {path, events,
+``housekeeping``-bound here, a ``decode_dispatch`` hang as ``decode``), and ``trace_export {path, events,
 replicas}`` when a merged Chrome trace is written. Neither is a fault
 kind — they are how a fault LOOKS from the doctor's side of the glass.
 

@@ -13,6 +13,11 @@ annotation costs well under a microsecond. Every program span is named
   ``ds:serve.schedule``, ``ds:serve.housekeeping``,
   ``ds:serve.prefill_dispatch``, ``ds:serve.decode_dispatch``,
   ``ds:serve.fetch``, ``ds:serve.commit`` (``ServingEngine.step`` / ``_round``);
+  ``ds:serve.submit`` — the engine's part of ``add_request`` — and
+  ``ds:serve.drained`` — a marker of no length at the end of the ``step()``
+  that left the engine holding no request: from its end to the next
+  ``ds:serve.submit`` the engine is empty, and a chip idle then waits for
+  a request, not for the host;
   ``ds:train.dispatch``, ``ds:train.prefetch``, ``ds:train.data_wait``,
   ``ds:train.block`` (``Engine.train_batch`` / ``train_batches``);
   ``ds:request.<phase>`` — ``RequestTracer``'s per-request spans, only

@@ -173,7 +173,10 @@ class TestExposition:
 # ---------------------------------------------------------------------------
 
 def _entry(round_ms=1.0, **phases):
-    e = {"schedule_ms": 0.1, "housekeeping_ms": 0.1, "prefill_ms": 0.1,
+    e = {"index": 0, "t_s": 0.0, "running_before": 2, "prefills": 0,
+         "prefill_tokens": 0, "shape": (2, 2), "ahead_covered": True,
+         "empty_before_ms": 0.0, "gc_ms": 0.0,
+         "schedule_ms": 0.1, "housekeeping_ms": 0.1, "prefill_ms": 0.1,
          "decode_ms": 0.2, "fetch_ms": 0.3, "commit_ms": 0.1,
          "round_ms": round_ms, "tokens": 8.0}
     e.update(phases)
@@ -181,22 +184,22 @@ def _entry(round_ms=1.0, **phases):
 
 
 class _PhaseRig:
-    """The ServingEngine phase-ring surface, host-only: the REAL
+    """The ServingEngine's round-record surface, host-only: the REAL
     ``_note_phases`` / ``phase_decomposition`` bound to a stub so the
     stall-event state machine is pinned without a jit compile."""
     from deepspeed_tpu.inference.serving import ServingEngine as _SE
     _STALL_MIN_ROUND_MS = _SE._STALL_MIN_ROUND_MS
     _STALL_FRACTION = _SE._STALL_FRACTION
-    _PHASE_OUT = _SE._PHASE_OUT
+    _RING_ROUNDS, _SLOW_ROUNDS = _SE._RING_ROUNDS, _SE._SLOW_ROUNDS
+    _PHASES, _PHASE_OUT = _SE._PHASES, _SE._PHASE_OUT
+    _reset_round_records = _SE._reset_round_records
+    _decode_dominated = staticmethod(_SE._decode_dominated)
     _note_phases = _SE._note_phases
     phase_decomposition = _SE.phase_decomposition
 
     def __init__(self, warm=True):
-        self._phases = collections.deque(maxlen=256)
-        self._phase_totals = dict.fromkeys(self._PHASE_OUT, 0.0)
-        self._rounds = 0
+        self._reset_round_records()
         self._quantum_warm = warm
-        self._phase_stall_events = 0
         self._tracer = None
 
 
@@ -215,14 +218,28 @@ class TestPhaseStallEvent:
         assert len(rb_events.history("serving_phase_stall")) == 1
         assert rig.phase_decomposition()["serve_phase_stall_events"] == 1.0
 
-    def test_fetch_dominance_is_exempt(self):
-        """Fetch-bound means the accelerator is the bottleneck — health,
-        not a stall."""
+    def test_a_fetch_three_times_the_median_is_a_stall(self):
+        """A round's device time is constant, so a fetch that dominates a
+        round three times the median is the device, its runtime or a
+        descheduled host: the event fires, once a window, and carries the
+        round's whole record. (Fetch-bound TOTALS are still health: the
+        doctor's reading, TestServingDoctor.)"""
         rig = _PhaseRig()
         for _ in range(9):
-            rig._note_phases(_entry())
-        rig._note_phases(_entry(round_ms=200.0, fetch_ms=190.0))
-        assert rb_events.history("serving_phase_stall") == []
+            rig._note_phases(_entry(round_ms=100.0, fetch_ms=80.0))
+        assert rb_events.history("serving_phase_stall") == []   # steady
+        slow = _entry(round_ms=400.0, fetch_ms=390.0, index=9, gc_ms=3.5)
+        rig._note_phases(slow)
+        (ev,) = rb_events.history("serving_phase_stall")
+        assert ev["phase"] == "fetch" and ev["phase_ms"] == 390.0
+        assert ev["record"] == slow
+        rig._note_phases(_entry(round_ms=900.0, fetch_ms=890.0))
+        assert len(rb_events.history("serving_phase_stall")) == 1
+        rig._reset_round_records()              # a new stats window
+        for _ in range(9):
+            rig._note_phases(_entry(round_ms=100.0, fetch_ms=80.0))
+        rig._note_phases(_entry(round_ms=400.0, fetch_ms=390.0))
+        assert len(rb_events.history("serving_phase_stall")) == 2
 
     def test_cold_engine_and_thin_baseline_stay_quiet(self):
         cold = _PhaseRig(warm=False)

@@ -249,8 +249,8 @@ def test_a_per_slot_state_pool_keeps_every_slot_in_the_step(toy):
     for (p, m), rid in zip(reqs, sorted(outs)):
         assert list(np.asarray(outs[rid])[-m:]) == _greedy(ref, p, m)
     st = srv.stats()
-    assert st["slot_count_mean"] == 40
     assert all(k.startswith("40x") for k in st["step_shape_rounds"])
+    assert sum(st["step_shape_rounds"].values()) > 0
     assert srv.pools["ssm"].shape[1] == 40
     srv.close()
 

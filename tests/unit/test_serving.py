@@ -516,12 +516,10 @@ def test_table_width_follows_the_longest_live_sequence(kv_bits, monkeypatch):
     assert widths[-1] == 8 and 16 in widths      # ... and narrowed after it
     # (c) the counter: one entry a width, summing to the decode rounds
     st = srv.stats()
-    assert st["table_width_rounds"] == {w: widths.count(w)
-                                        for w in srv._table_widths}
-    assert st["table_width_mean"] == pytest.approx(np.mean(widths))
+    assert st["step_shape_rounds"] == {f"3x{w}": widths.count(w)
+                                       for w in srv._table_widths}
     srv.reset_stats()
-    assert not any(srv.stats()["table_width_rounds"].values())
-    assert "table_width_mean" not in srv.stats()
+    assert not any(srv.stats()["step_shape_rounds"].values())
     # (a) the same tokens as with the full table in every round
     monkeypatch.setattr(serving, "_table_ladder", lambda MB: (MB,))
     full = engine()
@@ -614,15 +612,12 @@ def test_slot_count_follows_the_highest_running_slot(kind, monkeypatch):
         f"{S}x{W}": shapes.count((S, W)) for S, W in srv._step_shapes()}
     assert len({shape for shape in shapes if shape[0] == 40}) > 1
     assert sum(st["step_shape_rounds"].values()) == len(seen)
-    assert st["table_width_rounds"] == {
-        W: sum(1 for shape in shapes if shape[1] == W) for W in (4, 6, 8)}
-    assert st["slot_count_mean"] == pytest.approx(np.mean(slots))
-    assert st["table_width_mean"] == pytest.approx(
-        np.mean([shape[1] for shape in shapes]))
+    assert {int(k.split("x")[1]) for k in st["step_shape_rounds"]} == {4, 6, 8}
     srv.reset_stats()
     st = srv.stats()
     assert not any(st["step_shape_rounds"].values())
-    assert "slot_count_mean" not in st and "table_width_mean" not in st
+    assert set(st["step_shape_rounds"]) == {
+        f"{S}x{W}" for S, W in srv._step_shapes()}
     assert srv._tokens.shape == (40,)
     # the same tokens as with every slot in every round
     monkeypatch.setattr(serving, "_slot_ladder", lambda S: (S,))
@@ -675,8 +670,9 @@ def test_no_step_program_is_built_after_the_first_decode_round():
             del names[:]
             srv.run(load())
             assert "jit(step)" not in names, names
-            rounds = srv.stats()["table_width_rounds"]
-            assert all(rounds.values()), rounds
+            rounds = srv.stats()["step_shape_rounds"]
+            assert all(rounds[f"{srv.config.max_seqs}x{w}"]
+                       for w in srv._table_widths), rounds
     finally:
         jax.monitoring.unregister_event_duration_listener(on)
         srv.close()

@@ -8,6 +8,10 @@ backend (the host plane works without a chip):
   - the primitive lands in the trace, returns elapsed time, and nests;
   - a serving round is one ``ds:serve.round`` holding each phase once;
   - ``phase_decomposition()`` sums the whole stats window, not a ring;
+  - a round leaves ONE record (ISSUE 37): what it was, its phases, the
+    probe, its collections, the empty interval it ended — and the window
+    keeps the slowest with their followers, and how long the engine held
+    nothing (``ds:serve.drained`` -> ``ds:serve.submit``);
   - the request lifecycle (``admit_t``, ``max_gap_ms``) and the six
     ``stats()`` keys, a preempted request included, and ``admit_t``
     agreeing with ``RequestTracer``'s ``queue_wait`` span;
@@ -243,7 +247,7 @@ class TestHybridRound:
                       and r[1] <= s[1] and s[2] <= r[2]]
             assert inside == SERVE_PHASES
         assert {s[0] for s in ses.spans} == set(SERVE_PHASES) | {
-            "ds:serve.round"}
+            "ds:serve.round", "ds:serve.drained"}
         srv.close()
 
     def test_stats_tell_the_two_kinds_of_state_apart(self):
@@ -259,6 +263,21 @@ class TestHybridRound:
         srv.close()
 
 
+def _record(round_ms=4.0, **over):
+    """A round's record as ``step()`` hands it to ``_note_phases``."""
+    e = {"index": 0, "t_s": 0.0, "running_before": 2, "prefills": 0,
+         "prefill_tokens": 0, "shape": (2, 2), "ahead_covered": True,
+         "empty_before_ms": 0.0, "gc_ms": 0.0,
+         "schedule_ms": 0.1, "housekeeping_ms": 0.1, "prefill_ms": 0.2,
+         "decode_ms": 0.4, "fetch_ms": 3.0, "commit_ms": 0.2,
+         "round_ms": round_ms, "tokens": 8.0}
+    e.update(over)
+    return e
+
+
+RECORD_KEYS = set(_record())
+
+
 class TestPhaseTotals:
     def _rig(self):
         from deepspeed_tpu.inference.serving import ServingEngine as SE
@@ -266,33 +285,30 @@ class TestPhaseTotals:
         class Rig:
             _STALL_MIN_ROUND_MS = SE._STALL_MIN_ROUND_MS
             _STALL_FRACTION = SE._STALL_FRACTION
-            _PHASE_OUT = SE._PHASE_OUT
+            _RING_ROUNDS, _SLOW_ROUNDS = SE._RING_ROUNDS, SE._SLOW_ROUNDS
+            _PHASES, _PHASE_OUT = SE._PHASES, SE._PHASE_OUT
+            _reset_round_records = SE._reset_round_records
+            _decode_dominated = staticmethod(SE._decode_dominated)
             _note_phases = SE._note_phases
             phase_decomposition = SE.phase_decomposition
 
             def __init__(self):
-                self._phases = collections.deque(maxlen=256)
-                self._phase_totals = dict.fromkeys(self._PHASE_OUT, 0.0)
-                self._rounds = 0
+                self._reset_round_records()
                 self._quantum_warm = True
-                self._phase_stall_events = 0
                 self._tracer = None
         return Rig()
 
     def test_sums_more_rounds_than_the_ring_holds(self):
         rig = self._rig()
-        entry = {"schedule_ms": 0.1, "housekeeping_ms": 0.1,
-                 "prefill_ms": 0.2, "decode_ms": 0.4, "fetch_ms": 3.0,
-                 "commit_ms": 0.2, "round_ms": 4.0, "tokens": 8.0}
-        for _ in range(300):
-            rig._note_phases(dict(entry))
-        assert len(rig._phases) == 256              # the stall rule's ring
+        for _ in range(600):
+            rig._note_phases(_record())
+        assert len(rig._phases) == 512              # the one bounded store
         d = rig.phase_decomposition()
-        assert d["serve_rounds"] == 300.0 and d["serve_tokens"] == 2400.0
-        assert d["serve_round_ms"] == pytest.approx(1200.0)
-        assert d["serve_fetch_ms"] == pytest.approx(900.0)
-        assert d["serve_prefill_dispatch_ms"] == pytest.approx(60.0)
-        assert d["serve_decode_dispatch_ms"] == pytest.approx(120.0)
+        assert d["serve_rounds"] == 600.0 and d["serve_tokens"] == 4800.0
+        assert d["serve_round_ms"] == pytest.approx(2400.0)
+        assert d["serve_fetch_ms"] == pytest.approx(1800.0)
+        assert d["serve_prefill_dispatch_ms"] == pytest.approx(120.0)
+        assert d["serve_decode_dispatch_ms"] == pytest.approx(240.0)
 
     def test_reset_stats_clears_the_totals(self):
         srv = _serving()
@@ -303,6 +319,323 @@ class TestPhaseTotals:
         assert d["serve_rounds"] == 0.0
         assert all(d[k] == 0.0 for k in srv._PHASE_OUT.values())
         srv.close()
+
+
+# ---------------------------------------------------------------------------
+# the round's record (ISSUE 37)
+# ---------------------------------------------------------------------------
+
+def _drain(srv):
+    """Step until the engine holds nothing; the records of those rounds."""
+    n0 = srv._rounds
+    while not srv.scheduler.done or srv._inflight is not None:
+        srv.step()
+    return list(srv._phases)[n0 - srv._rounds:] if srv._rounds > n0 else []
+
+
+class _Probe:
+    """Stands for the newest array on the device's queue."""
+
+    def __init__(self, ready):
+        self.ready, self.asked = ready, 0
+
+    def is_ready(self):
+        self.asked += 1
+        return self.ready
+
+
+def _stub_probe(monkeypatch, srv, probe):
+    real = srv._dispatch_round
+    monkeypatch.setattr(
+        srv, "_dispatch_round", lambda spec, t0, newest=None: real(
+            spec, t0, newest if newest is None else probe))
+
+
+class TestRoundRecord:
+    def test_every_field_on_every_kind_of_round(self):
+        srv = _serving(decode_quantum=4)
+        srv.add_request(_prompt(9), 12)
+        srv.step()                                  # the window's first
+        srv.step()                                  # a plain round
+        srv.add_request(_prompt(5, seed=1), 6)
+        srv.step()                                  # one with an admission
+        first, plain, admit = list(srv._phases)
+        for rec in (first, plain, admit):
+            assert set(rec) == RECORD_KEYS
+        assert [r["index"] for r in (first, plain, admit)] == [0, 1, 2]
+        assert 0.0 <= first["t_s"] < plain["t_s"] < admit["t_s"]
+        assert (first["running_before"], first["prefills"],
+                first["prefill_tokens"]) == (0, 1, 16)
+        assert first["ahead_covered"] is None       # nothing was in flight
+        assert (plain["running_before"], plain["prefills"],
+                plain["prefill_tokens"]) == (1, 0, 0)
+        assert plain["shape"] == (2, 2) and plain["ahead_covered"] in (
+            True, False)
+        assert (admit["running_before"], admit["prefills"],
+                admit["prefill_tokens"]) == (1, 1, 16)
+        assert admit["ahead_covered"] in (True, False)
+        assert all(r["gc_ms"] >= 0.0 and r["empty_before_ms"] == 0.0
+                   for r in (first, plain, admit))
+        assert not srv._decode_dominated(first)
+        assert srv._decode_dominated(plain)
+        assert not srv._decode_dominated(admit)     # 1 prompt, 1 decoding
+        _drain(srv)
+        # the empty engine's early return leaves a whole record too
+        assert srv.step() == []
+        empty = srv._phases[-1]
+        assert set(empty) == RECORD_KEYS
+        assert (empty["shape"], empty["ahead_covered"], empty["prefills"],
+                empty["running_before"]) == (None, None, 0, 0)
+        assert not srv._decode_dominated(empty)
+        srv.close()
+
+    def test_a_speculation_round_has_no_probe(self):
+        srv = _serving(spec_tokens=2)
+        srv.add_request(_prompt(9), 8)
+        recs = _drain(srv)
+        assert len(recs) >= 2 and srv.stats()["spec_steps"] >= 1
+        for rec in recs:
+            assert set(rec) == RECORD_KEYS and rec["ahead_covered"] is None
+        assert recs[1]["shape"] == (2, 4)           # the full tables
+        st = srv.stats()
+        assert st["ahead_covered_rounds"] == st["ahead_dry_rounds"] == 0.0
+        srv.close()
+
+    @pytest.mark.parametrize("ready", [False, True])
+    def test_the_probe_is_counted_both_ways(self, monkeypatch, ready):
+        """Not ready: the chip still had work when the host came to issue
+        the round's first step. What a CPU backend's timing would say
+        decides nothing here: the array is a stub."""
+        srv = _serving(decode_quantum=4)
+        probe = _Probe(ready)
+        _stub_probe(monkeypatch, srv, probe)
+        srv.add_request(_prompt(9), 14)
+        recs = _drain(srv)
+        ahead = [r for r in recs if r["ahead_covered"] is not None]
+        assert recs[0]["ahead_covered"] is None     # an empty engine before
+        assert len(ahead) >= 2 and probe.asked == len(ahead)  # once a round
+        assert all(r["ahead_covered"] is (not ready) for r in ahead)
+        st = srv.stats()
+        assert st["rounds_ahead"] == len(ahead)
+        assert st["ahead_dry_rounds" if ready
+                  else "ahead_covered_rounds"] == len(ahead)
+        assert st["ahead_covered_rounds" if ready
+                  else "ahead_dry_rounds"] == 0.0
+        srv.reset_stats()
+        st = srv.stats()
+        assert st["ahead_covered_rounds"] == st["ahead_dry_rounds"] == 0.0
+        srv.close()
+
+    def test_the_probe_asks_the_last_prefills_first_token(self, monkeypatch):
+        """With steps in flight AND a prompt dispatched in this call, the
+        newest array on the queue is that prompt's first token; without a
+        prompt, the stacked tokens of the steps in flight."""
+        srv = _serving(decode_quantum=4)
+        asked = []
+        real = srv._dispatch_round
+
+        def spy(spec, t0, newest=None):
+            asked.append(newest)
+            return real(spec, t0, newest)
+        monkeypatch.setattr(srv, "_dispatch_round", spy)
+        srv.add_request(_prompt(9), 12)
+        srv.step()
+        tail = srv._inflight.tail[0]
+        srv.step()
+        srv.add_request(_prompt(5, seed=1), 6)
+        late = srv.scheduler.waiting[-1]
+        srv.step()
+        assert asked[0] is None and asked[1] is tail
+        assert asked[2].shape == (1,) and late.first_token_t is not None
+        assert hasattr(asked[1], "is_ready") and hasattr(asked[2], "is_ready")
+        _drain(srv)
+        srv.close()
+
+
+class TestSlowRounds:
+    def test_keeps_the_slowest_with_their_followers(self):
+        """Twelve rounds of a warm engine are made slow through the
+        dispatch seam (a hang with no watchdog armed is a sleep): the
+        window keeps the eight slowest, slowest first, each with the
+        record of the round that followed it."""
+        from deepspeed_tpu.robustness import faults as rb_faults
+        from deepspeed_tpu.robustness.faults import (FaultInjector,
+                                                     FaultSchedule)
+        srv = _serving(decode_quantum=2, max_model_len=128,
+                       model=_tiny_model(seq=128))
+        srv.run([(_prompt(9), 100)])                # every table width
+        srv.reset_stats()
+        # four short sleeps and eight long ones, far enough apart that a
+        # loaded host's jitter cannot reorder the two groups
+        sleeps = {3 + 2 * i: 0.010 if i % 3 == 0 else 0.100 + 0.005 * i
+                  for i in range(12)}
+        rb_faults.clear()
+        rb_faults.install(FaultInjector(FaultSchedule(
+            [{"kind": "decode_dispatch", "at": at, "mode": "hang",
+              "hang_s": s} for at, s in sleeps.items()], seed=0)))
+        try:
+            srv.add_request(_prompt(9), 70)
+            srv.add_request(_prompt(7, seed=1), 70)
+            recs = _drain(srv)
+        finally:
+            rb_faults.clear()
+        st = srv.stats()
+        pairs = st["slow_rounds"]
+        assert len(pairs) == 8
+        by_ms = sorted((r for r in recs if srv._decode_dominated(r)),
+                       key=lambda r: -r["round_ms"])
+        assert [p[0]["index"] for p in pairs] == [r["index"]
+                                                  for r in by_ms[:8]]
+        # ... which are the eight long sleeps
+        assert {p[0]["index"] for p in pairs} == {
+            at for at, s in sleeps.items() if s >= 0.1}
+        for rec, nxt in pairs:
+            assert set(rec) == set(nxt) == RECORD_KEYS
+            assert nxt["index"] == rec["index"] + 1
+            assert rec["decode_ms"] >= 1e3 * sleeps[rec["index"]]
+        assert st["round_ms_max"] == pairs[0][0]["round_ms"]
+        assert st["phase_ms_max"]["decode"] == max(
+            r["decode_ms"] for r in by_ms)
+        assert st["round_ms_median"] < st["round_ms_max"] / 3
+        assert set(st["phase_ms_max"]) == {"schedule", "housekeeping",
+                                           "prefill", "decode", "fetch",
+                                           "commit"}
+        import json
+        json.dumps(st["slow_rounds"])               # rides out in a run's JSON
+        srv.reset_stats()
+        st = srv.stats()
+        assert st["slow_rounds"] == [] and st["gc_ms_total"] == 0.0
+        assert "round_ms_max" not in st and "phase_ms_max" not in st
+        srv.close()
+
+    def test_the_slowest_round_waits_for_its_follower(self):
+        rig = TestPhaseTotals()._rig()
+        rig._note_phases(_record(5.5, index=0))
+        assert rig._slow == [[_record(5.5, index=0), None]]
+        rig._note_phases(_record(9.0, index=1, prefills=2))  # not dominated
+        assert [p[0]["index"] for p in rig._slow] == [0]
+        assert rig._slow[0][1]["index"] == 1        # ... but a follower
+        assert rig._phase_max["round_ms"] == 5.5
+        for i in range(2, 12):
+            rig._note_phases(_record(float(i), index=i, gc_ms=0.5))
+        assert [p[0]["index"] for p in rig._slow] == [11, 10, 9, 8, 7, 6, 0,
+                                                      5]
+        assert rig._slow[0][1] is None and rig._slow[1][1]["index"] == 11
+        assert rig._gc_ms_total == pytest.approx(5.0)
+
+
+class TestGcClock:
+    def test_one_hook_times_every_collection(self):
+        import gc
+        from deepspeed_tpu.inference import serving
+        a, b = _serving(), _serving()
+        assert a._gc is b._gc is serving._GC_CLOCK
+        assert gc.callbacks.count(serving._GC_CLOCK) == 1
+        before = a._gc.seconds
+        gc.collect()
+        assert a._gc.seconds > before
+        a.close(), b.close()
+
+    def test_a_collection_inside_a_round_is_in_its_record(self, monkeypatch):
+        import gc
+        srv = _serving()
+        real = srv._land
+
+        def land(*args):
+            gc.collect()
+            return real(*args)
+        monkeypatch.setattr(srv, "_land", land)
+        srv.add_request(_prompt(9), 4)
+        recs = _drain(srv)
+        assert all(0.0 < r["gc_ms"] < r["round_ms"] for r in recs)
+        assert srv.stats()["gc_ms_total"] == pytest.approx(
+            sum(r["gc_ms"] for r in recs))
+        srv.close()
+
+
+class TestEmptyEngine:
+    def test_seconds_the_engine_held_nothing(self):
+        srv = _serving()
+        srv.run([(_prompt(9), 4)])                  # warm; ends empty
+        srv.reset_stats()
+        time.sleep(0.02)                            # before the window
+        srv.add_request(_prompt(5), 4)
+        st = srv.stats()
+        assert st["engine_empty_s"] == 0.0 and st["stats_window_s"] >= 0.0
+        recs = _drain(srv)
+        assert recs[0]["empty_before_ms"] == 0.0
+        assert srv._empty_since is not None
+        time.sleep(0.05)
+        srv.add_request(_prompt(6, seed=1), 4)      # add -> drain -> wait -> add
+        assert srv._empty_since is None
+        closed = srv.stats()["engine_empty_s"]
+        assert 0.05 <= closed < 5.0
+        recs = _drain(srv)
+        assert recs[0]["empty_before_ms"] == pytest.approx(closed * 1e3)
+        assert all(r["empty_before_ms"] == 0.0 for r in recs[1:])
+        time.sleep(0.03)                            # an interval still open
+        st = srv.stats()
+        assert st["engine_empty_s"] >= closed + 0.03
+        assert st["engine_empty_s"] < st["stats_window_s"]
+        assert srv.step() == []                     # does not stamp again
+        assert srv.stats()["engine_empty_s"] >= st["engine_empty_s"]
+        srv.reset_stats()
+        st = srv.stats()
+        assert st["engine_empty_s"] == 0.0 == st["stats_window_s"]
+        srv.close()
+
+    def test_a_request_that_came_by_another_door_ends_the_interval(self):
+        srv = _serving()
+        srv.add_request(_prompt(5), 3)
+        _drain(srv)
+        time.sleep(0.02)
+        # not add_request: the scheduler directly, as resume / migration do
+        srv.scheduler.submit(_prompt(6, seed=1), 3)
+        recs = _drain(srv)
+        assert recs[0]["empty_before_ms"] >= 20.0
+        assert srv.stats()["engine_empty_s"] >= 0.02
+        srv.close()
+
+    def test_drained_and_submit_on_the_profilers_clock(self, tmp_path):
+        srv = _serving()
+        srv.run([(_prompt(9), 4)])                  # compiles, off the trace
+        srv.reset_stats()
+        with _Session(tmp_path) as ses:
+            srv.add_request(_prompt(5), 4)
+            srv.add_request(_prompt(7, seed=1), 4)
+            _drain(srv)
+            time.sleep(0.03)
+            srv.add_request(_prompt(6, seed=2), 3)
+            _drain(srv)
+            with pytest.raises(ValueError):
+                srv.add_request(_prompt(6), 0)      # refused inside the span
+        submits, drained = ses.named("ds:serve.submit"), ses.named(
+            "ds:serve.drained")
+        rounds = ses.named("ds:serve.round")
+        assert len(submits) == 4 and len(drained) == 2
+        # every span of the session is either inside a round or one of the
+        # two top-level names: none overlaps another, none is left open
+        top = sorted(submits + drained + rounds, key=lambda x: x[1])
+        assert all(a[2] <= b[1] for a, b in zip(top, top[1:]))
+        for x in ses.spans:
+            if x not in top:
+                assert any(r[1] <= x[1] and x[2] <= r[2] for r in rounds)
+        # a drained marker follows its round at once; the empty interval
+        # is [its end, the next submit's start]
+        for d in drained:
+            r = max((r for r in rounds if r[2] <= d[1]), key=lambda r: r[2])
+            assert d[1] - r[2] < 5e6 and d[2] - d[1] < 1e6
+        gap_ns = submits[2][1] - drained[0][2]
+        assert gap_ns / 1e9 == pytest.approx(
+            srv.stats()["engine_empty_s"] - (
+                time.perf_counter() - srv._empty_since), abs=5e-3)
+        assert gap_ns >= 0.03e9
+        srv.close()
+
+    def test_the_vocabulary_lists_the_two_names(self):
+        from deepspeed_tpu.telemetry import tracing
+        assert "``ds:serve.submit``" in tracing.__doc__
+        assert "``ds:serve.drained``" in tracing.__doc__
 
 
 # ---------------------------------------------------------------------------
