@@ -115,9 +115,12 @@ class _DispatchedRound:
     and schedules and dispatches the next round, and are fetched by the
     next call. Either is None when it holds no step. ``t0``: when the
     dispatch began (request traces). ``covered``: what the probe read just
-    before the round's first step was issued (``_dispatch_round``)."""
+    before the round's first step was issued (``_dispatch_round``).
+    ``shape``: the round's key of ``_step_shapes``; ``held``: the blocks
+    the running requests held when it was built (``_tables_device``)."""
     entries: list
     shape: tuple
+    held: int
     head: Any
     tail: Any
     spec: bool
@@ -406,16 +409,29 @@ class ServingConfig:
     role: str = "both"                 # prefill | decode | both
 
 
-def _table_ladder(MB: int) -> tuple:
-    """The block-table widths a decode round may be dispatched at: a half,
-    three quarters and the whole of the full width ``MB``, rounded up to
-    whole columns (32 -> 16, 24, 32; 16 -> 8, 12, 16). A constant of the
-    engine's shape, not a setting: one step program is built per entry, and
-    each costs a serve cell 0.45-0.7 s of set-up — its tracing and lowering,
-    which no cache keeps (PERF.md section 6, PR 33: six more programs took
-    the chat cell's warm ``setup_s`` from 11.0 to 13.5 s; why there is no
-    quarter of the width)."""
-    return tuple(sorted({-(-MB * q // 4) for q in (2, 3, 4)}))
+# blocks of one entry of a decode round's block list: a RUN of two columns of
+# one slot, 128 positions at the cells' 64-token blocks — what the per-slot
+# view of the scores is tiled in, so an entry lands in it as whole tiles (one
+# block an entry: every pass between list and view relayouts 64-wide rows,
+# the read of 64 blocks in the chat cell 1.58 ms a step against 1.20; four:
+# up to three padded blocks a slot, PERF.md section 6, PR 38). A slot's blocks
+# are padded to whole runs with the trash block.
+_RUN = 2
+
+
+def _list_ladder(MB: int, shares: tuple) -> tuple:
+    """The lengths a decode round's block list may have, as columns a slot
+    (the list holds slots x columns blocks): ``1 / share`` of the full
+    table width ``MB`` for each of ``shares``, rounded up to whole runs
+    (32, (4, 2, 1) -> 8, 16, 32; 20 -> 6, 10, 20; 16, (8, 4) -> 2, 4). A
+    constant of the engine's shape, not a setting: one step program is
+    built per entry, and each costs a serve cell 0.45-0.7 s of set-up — its
+    tracing and lowering, which no cache keeps (PERF.md section 6, PR 33).
+    The ladder is over the SUM of the blocks the slots hold, which moves
+    far less from round to round and from seed to seed than the longest
+    slot's, so it is geometric: halves."""
+    return tuple(sorted({-(-MB // (share * _RUN)) * _RUN
+                         for share in shares}))
 
 
 def _slot_ladder(max_seqs: int) -> tuple:
@@ -423,16 +439,15 @@ def _slot_ladder(max_seqs: int) -> tuple:
     ``max_seqs`` in whole tiles of 16 rows (what a bf16 activation's rows
     come in on the TPU), and the whole — 48 -> 16, 48; 128 -> 32, 128. An
     engine whose quarter is half of its slots or more has the whole alone
-    (32 -> 32): the narrow step would save under half of the read and no
-    matmul, and costs what every step program costs, half a second of
+    (32 -> 32): the narrow step would save no matmul and under half of what
+    in the read follows the slots (the per-slot view of the scores and its
+    softmax), and costs what every step program costs, half a second of
     every start. The scheduler hands out the lowest free slot, so the
     running requests sit in the first rows and a round whose highest
-    running slot lies below the quarter runs as the quarter's program
-    (``_tables_device``). A constant of the engine's shape like
-    ``_table_ladder``; the quarter is built at the full table width alone
-    (``ServingEngine._step_shapes``): no half, and no narrower table at
-    the quarter, where the width moves a step by a thirtieth (PERF.md
-    sections 5 and 6, PR 33)."""
+    running slot lies below the quarter runs as one of the quarter's
+    programs (``_tables_device``). A constant of the engine's shape like
+    ``_list_ladder``; ``ServingEngine._step_shapes`` says how the programs
+    are spent on the two."""
     few = -(-max_seqs // 64) * 16
     return (few, max_seqs) if 2 * few < max_seqs else (max_seqs,)
 
@@ -453,7 +468,11 @@ _LAT_COUNTERS = {"spec_steps": 0, "spec_proposed": 0, "spec_accepted": 0,
                  "rounds_ahead": 0, "dropped_slot_rounds": 0,
                  # of the rounds ahead: the chip still had work when the
                  # next round's first step was issued / had run dry
-                 "ahead_covered_rounds": 0, "ahead_dry_rounds": 0}
+                 "ahead_covered_rounds": 0, "ahead_dry_rounds": 0,
+                 # over the plain rounds: blocks the step gathered a plane
+                 # (the length of the list it was handed) and blocks the
+                 # running requests held
+                 "kv_blocks_gathered": 0, "kv_blocks_held": 0}
 
 
 class ServingEngine:
@@ -539,7 +558,6 @@ class ServingEngine:
                     raise RecurrentStateUnsupported(what)
         self.max_model_len = want
         self.MB = self.max_model_len // c.block_size     # table width
-        self._table_widths = _table_ladder(self.MB)
         # a per-slot state pool is updated whole and in place (a Pallas
         # operand is a whole buffer: a narrower step would copy
         # ``state[:, :slots]``), so its engine keeps every round at max_seqs
@@ -756,9 +774,6 @@ class ServingEngine:
         self._ut_steps = int(getattr(mcfg, "ut_steps", 1))
         self._exit = np.zeros((self._ut_steps + 1,), np.float64)
         self._lat = dict(_LAT_COUNTERS)
-        # plain decode rounds dispatched per (slot count, block-table
-        # width) (reset_stats windows; _tables_device)
-        self._table_rounds = self._step_shapes()
         # reliability bookkeeping ---------------------------------------
         self._counters = {"shed": 0, "deadline_misses": 0, "degraded": 0,
                           "recoveries": 0, "recovery_ms": 0.0,
@@ -805,6 +820,9 @@ class ServingEngine:
 
         # backend micro-bench (one-time, on the REAL pool shapes) --------
         self.decode_backend, self.backend_bench = self._select_backend()
+        # plain decode rounds dispatched per (slot count, columns a slot)
+        # (reset_stats windows; _tables_device)
+        self._table_rounds = self._step_shapes()
 
     # ---- mesh geometry -----------------------------------------------
 
@@ -1188,11 +1206,12 @@ class ServingEngine:
             # shared weights — donating it would force a re-page of
             # every resident adapter each quantum step
             lora = (apool, aidx) if apool is not None else None
-            # the step is sized by its tables: handed the first n of the
-            # engine's slots it works on the first n pending tokens and
+            # the step is sized by what it is handed: the first n of the
+            # engine's slots (it works on the first n pending tokens and
             # hands the vector back whole, so a round that widens again
-            # finds every slot's token
-            n = tables.shape[0]
+            # finds every slot's token) and, on the XLA backend, the list
+            # of the blocks they hold (a ``BlockList``)
+            n = active.shape[0]
             with expert_load_tap() as tap, exit_tap() as gate:
                 logits, pools = self.model.decode_step_paged(
                     params, tokens[:n], pools, tables, seq_lens,
@@ -1215,33 +1234,47 @@ class ServingEngine:
         return jax.jit(step, donate_argnums=(1, 4), out_shardings=outs)
 
     def _step_shapes(self) -> dict:
-        """{(slot count, table width): 0} over the shapes a decode round's
-        tables may have, each with a step program of its own and a counter
-        of its rounds: every table width at ``max_seqs``, and the narrower
-        slot counts at the full table, in the order of what a step gathers
-        (slots x width blocks a layer). A round takes the first that holds
-        it (``_tables_device``)."""
-        shapes = [(self._slot_counts[-1], W) for W in self._table_widths] \
-            + [(S, self.MB) for S in self._slot_counts[:-1]]
+        """{(slot count, columns a slot): 0} over the shapes a plain decode
+        round may be dispatched at, each with a step program of its own and
+        a counter of its rounds, in the order of what a step gathers:
+        slots x columns blocks a plane, the LENGTH of the block list the
+        round is handed (``_tables_device``) — the slot count times a MEAN
+        number of columns, no slot's own. A round takes the first that
+        holds it. As many programs as the two ladders had when the second
+        was over the table's width (PR 29, PR 33): at ``max_seqs`` a
+        quarter, a half and the whole of ``max_seqs x MB`` — the whole is
+        the worst case, every slot at ``max_model_len`` —, and where the
+        slot ladder has a narrower count, an eighth and a quarter of ITS
+        ``slots x MB`` there and the half and the whole at ``max_seqs``
+        (48 slots x 32 columns: 16 x 4, 16 x 8, 48 x 16, 48 x 32; few
+        requests hold few blocks, and a round that overflows the narrow
+        count's lists runs at ``max_seqs``). The Pallas backend resolves
+        the tables inside its kernel and reads the blocks below each slot's
+        length whatever the table's width: a program a slot count, at
+        ``MB``."""
+        few, top = self._slot_counts[:-1], self._slot_counts[-1]
+        if self.decode_backend != "xla":
+            return {(S, self.MB): 0 for S in self._slot_counts}
+        shapes = [(S, W) for S in few for W in _list_ladder(self.MB, (8, 4))] \
+            + [(top, W) for W in _list_ladder(self.MB,
+                                              (2, 1) if few else (4, 2, 1))]
         return dict.fromkeys(sorted(shapes, key=lambda sh: (sh[0] * sh[1],
                                                             sh)), 0)
 
     def _get_quantum_step(self):
-        """{(slot count, table width): the decode step compiled for it}, one
-        program per entry of ``_step_shapes``, keyed like the ``shape`` of
-        a round's tables. All of them are built when the first is asked
-        for — the first decode round, and again after a backend swap — by
-        lowering on abstract arguments: nothing runs, no pool is donated,
-        and no shape is ever compiled inside a serving window, whichever
-        lengths and however many requests the traffic brings. Every op of
-        the step's read of the pool (block gathers, scores, softmax, P.V)
-        is sized slots x width, so a round whose requests sit in the first
-        quarter of the slots reads a quarter (48 slots: a third) of what
-        the full tables make it read, and one whose longest sequence ends
-        in the first half of the context half; the matmuls over the
-        weights have that many rows. The per-slot token vector stays
-        ``max_seqs`` long in every program (a prefill writes its first
-        token at its slot)."""
+        """{(slot count, columns a slot): the decode step compiled for it},
+        one program per entry of ``_step_shapes``, keyed like the ``shape``
+        of a round. All of them are built when the first is asked for — the
+        first decode round, and again after a backend swap — by lowering on
+        abstract arguments: nothing runs, no pool is donated, and no shape
+        is ever compiled inside a serving window, whichever lengths and
+        however many requests the traffic brings. On the XLA backend the
+        step's read of the pool — block gathers, scores, P.V — is sized by
+        the list it is handed, ``slots x columns`` blocks whoever holds
+        them, and only the float32 scores and their softmax by
+        ``slots x MB``; the matmuls over the weights have ``slots`` rows.
+        The per-slot token vector stays ``max_seqs`` long in every program
+        (a prefill writes its first token at its slot)."""
         if self._quantum_step is None:
             import jax
             import jax.numpy as jnp
@@ -1256,10 +1289,13 @@ class ServingEngine:
                  self.adapter_pool if self._lora else None))
 
             def lower(S, W):
+                tables = jax.tree.map(
+                    lambda a: sds(a.shape, jnp.int32),
+                    self._blank_tables(S, W))
                 with self.engine.mesh:
                     return fn.lower(
                         params, pools, sds(self._tokens.shape, jnp.int32),
-                        sds((S, W), jnp.int32), sds((S,), jnp.int32),
+                        tables, sds((S,), jnp.int32),
                         sds((S,), jnp.bool_), sds((2,), jnp.uint32), apool,
                         sds((S,), jnp.int32))
 
@@ -1591,39 +1627,78 @@ class ServingEngine:
             req._first_dev = (first, (None, None))   # (token, no counters)
         return C
 
+    def _blank_tables(self, S: int, W: int, full: bool = False):
+        """What a round of shape ``(S, W)`` hands its step in place of
+        block tables, on the host and holding no request: on the XLA
+        backend a ``BlockList`` of ``S x W`` blocks in runs of ``_RUN``
+        (every entry padding: the trash block, no place in the view); for
+        the Pallas kernel, and with ``full`` (a span), the rectangular
+        ``ids[S, W]``."""
+        from deepspeed_tpu.models.transformer import BlockList
+        if full or self.decode_backend != "xla":
+            return np.zeros((S, W), np.int32)
+        runs, wide = S * W // _RUN, -(-self.MB // _RUN)
+        return BlockList(np.zeros((runs * _RUN,), np.int32),
+                         np.full((runs,), S * wide, np.int32),
+                         np.full((S, wide), runs, np.int32))
+
     def _tables_device(self, full: bool = False):
-        """The round's block tables ``ids[S, W]``, lengths, active mask and
-        adapter indices, on the device: the first shape of ``_step_shapes``
-        — the one whose step gathers least — with ``S`` above the highest
-        running slot and ``W`` no less than the longest ``block_ids`` among
-        the running requests. The rows dropped hold no request (the
-        scheduler gives out the lowest free slot) and a row's attention
-        sees no other row; the blocks already cover the quantum's writes
-        (``Scheduler._grow``), so every row a step reads or writes lies in
-        the first ``W`` columns, and the columns dropped hold only
-        positions past every slot's length, whose probabilities are exact
-        zeros. Inactive slots among the first ``S`` read column 0 of an
-        all-zero row, the trash block. Only the plain quantum step takes
-        the narrow tables; ``full`` (the speculation verify step) keeps
-        all ``max_seqs`` rows and ``MB`` columns, as do chunk dispatch (its
-        own ``tab[1, MB]``), the fork and KV import / export: each is a
-        program family of its own to warm, and no benchmark cell runs
-        them."""
+        """What the round's step reads the pool through, with the lengths,
+        the active mask and the adapter indices, on the device, and the
+        round's shape (a key of ``_step_shapes``) with the blocks the
+        running requests hold.
+
+        The plain quantum step on the XLA backend is handed a FLAT LIST of
+        those blocks (a ``BlockList``): every request's ``block_ids``,
+        each padded to whole runs of ``_RUN`` columns with the
+        trash block, and the list padded to ``S x W`` blocks, the first
+        shape of ``_step_shapes`` — the one whose step gathers least —
+        with ``S`` above the highest running slot and ``S x W`` no less
+        than the blocks listed. What a step gathers, scores and contracts
+        follows that SUM, not ``S`` x the longest request's table. The rows
+        dropped hold no request (the scheduler gives out the lowest free
+        slot) and a row's attention sees no other row; the blocks already
+        cover the quantum's writes (``Scheduler._grow``), so one list
+        serves all its steps, as one table did; what the list does not
+        hold lies past every slot's length, where the probabilities are
+        exact zeros. Inactive slots among the first ``S`` have no entry
+        and length 0. The Pallas backend keeps rectangular tables
+        ``ids[S, MB]`` and the per-slot lengths: its kernel reads only the
+        blocks below a slot's length. ``full`` (the speculation verify
+        step) keeps all ``max_seqs`` rows and ``MB`` columns, as do chunk
+        dispatch (its own ``tab[1, MB]``), the fork and KV import /
+        export: each is a program family of its own to warm, and no
+        benchmark cell runs them."""
+        import jax
         import jax.numpy as jnp
         running = self.scheduler.running
-        need = (self.config.max_seqs, self.MB) if full else (
-            max((req.slot for req in running), default=0) + 1,
-            max((len(req.block_ids) for req in running), default=0))
-        S, W = next(shape for shape in self._table_rounds
-                    if shape[0] >= need[0] and shape[1] >= need[1])
-        ids = np.zeros((S, W), np.int32)
+        held = sum(len(req.block_ids) for req in running)
+        if full:
+            S, W = self.config.max_seqs, self.MB
+        else:
+            top = max((req.slot for req in running), default=0)
+            listed = sum(-(-len(req.block_ids) // _RUN) for req in running) \
+                * _RUN
+            S, W = next(sh for sh in self._table_rounds
+                        if sh[0] > top and sh[0] * sh[1] >= listed)
+        tables = self._blank_tables(S, W, full)
         lens = np.zeros((S,), np.int32)
         act = np.zeros((S,), bool)
         # per-slot adapter index into the device slot pool (0 = the null
         # adapter): free slots read slot 0 — an exact-zero delta
         aidx = np.zeros((S,), np.int32)
+        n = 0
         for req in running:
-            ids[req.slot, :len(req.block_ids)] = req.block_ids
+            k = len(req.block_ids)
+            if isinstance(tables, np.ndarray):
+                tables[req.slot, :k] = req.block_ids
+            else:
+                runs = -(-k // _RUN)
+                tables.ids[n * _RUN:n * _RUN + k] = req.block_ids
+                tables.where[n:n + runs] = \
+                    req.slot * tables.inv.shape[1] + np.arange(runs)
+                tables.inv[req.slot, :runs] = np.arange(n, n + runs)
+                n += runs
             # rows of a round still on the device's queue are written by
             # the time this round's first step reads the lengths
             lens[req.slot] = req.cached_rows + req.inflight_rows
@@ -1631,8 +1706,8 @@ class ServingEngine:
             # its slot but must not decode yet
             act[req.slot] = req.prefill_done
             aidx[req.slot] = req.adapter_slot or 0
-        return (jnp.asarray(ids), jnp.asarray(lens), jnp.asarray(act),
-                jnp.asarray(aidx))
+        return (jax.tree.map(jnp.asarray, tables), jnp.asarray(lens),
+                jnp.asarray(act), jnp.asarray(aidx)), (S, W), held
 
     def step(self) -> List[Request]:
         """One scheduling round: enforce deadlines, evict/admit/preempt at
@@ -1874,6 +1949,9 @@ class ServingEngine:
                     ph["shape"], ph["ahead_covered"] = rec.shape, rec.covered
                     if not spec:
                         self._table_rounds[rec.shape] += 1
+                        self._lat["kv_blocks_gathered"] += \
+                            rec.shape[0] * rec.shape[1]
+                        self._lat["kv_blocks_held"] += rec.held
                         self._lat["rounds_ahead"] += prior is not None
                         self._lat["ahead_covered_rounds"] += \
                             rec.covered is True
@@ -1923,10 +2001,11 @@ class ServingEngine:
         before the first step is issued (no sync, no copy): not ready means
         the chip still had work when the host got there (``covered``)."""
         import jax.numpy as jnp
-        tables, seq_lens, active, aidx = self._tables_device(full=spec)
-        # the plain step runs as the program of the tables' shape
+        (tables, seq_lens, active, aidx), shape, held = \
+            self._tables_device(full=spec)
+        # the plain step runs as the program of the round's shape
         step_fn = self._get_spec_step() if spec \
-            else self._get_quantum_step()[tables.shape]
+            else self._get_quantum_step()[shape]
         tok_mat = None
         if spec:
             props = self._proposals_device()
@@ -1985,8 +2064,8 @@ class ServingEngine:
         if not spec:
             for req, _, _ in entries:
                 req.inflight_rows += len(keys)
-        return _DispatchedRound(entries, tuple(tables.shape), head, tail,
-                                spec, t0, covered)
+        return _DispatchedRound(entries, shape, held, head, tail, spec, t0,
+                                covered)
 
     def _land(self, prior: Optional[_DispatchedRound],
               rec: Optional[_DispatchedRound], pending: list,
@@ -2233,6 +2312,7 @@ class ServingEngine:
             return
         self.decode_backend = "xla"
         self._quantum_step = None      # recompile with the gather backend
+        self._table_rounds = self._step_shapes()    # ... at its own shapes
         self._quantum_warm = False     # and re-warm before re-arming
         self._counters["degraded"] += 1
         self.backend_bench = dict(self.backend_bench, backend="xla",
@@ -2949,8 +3029,13 @@ class ServingEngine:
 
         The decode step's shape (always on; ``_tables_device``; speculation
         rounds keep the full tables and are not counted):
-        ``step_shape_rounds`` — a dict ``{"<slots>x<width in columns>":
-        plain decode rounds dispatched at it}`` over both ladders.
+        ``step_shape_rounds`` — a dict ``{"<slots>x<columns a slot>": plain
+        decode rounds dispatched at it}`` over ``_step_shapes``, slots x
+        columns being the blocks the step gathered a plane (the length of
+        its block list) —, ``kv_blocks_gathered``, that product summed over
+        those rounds, and ``kv_blocks_held``, the blocks the running
+        requests held, summed likewise: their ratio is how much of what the
+        steps gathered was anyone's.
 
         The round order (always on; ``_round``): ``rounds_ahead`` — of the
         rounds ``step_shape_rounds`` counts, those dispatched while the

@@ -397,7 +397,7 @@ def decode_step_paged(params, tokens, cfg, pools, block_tables, seq_lens,
     in lockstep; their K/V rows land in the trash block and their recurrent
     state stays as it is."""
     from deepspeed_tpu.models.transformer import (
-        _paged_attention, _quant_kv, _scatter_rows, _wrow)
+        _block_at, _paged_attention, _quant_kv, _scatter_rows, _wrow)
     if lora is not None:
         raise NotImplementedError("LoRA adapters on a hybrid model")
     S = tokens.shape[0]
@@ -439,9 +439,8 @@ def decode_step_paged(params, tokens, cfg, pools, block_tables, seq_lens,
                 x = x + y
     if k_rows:
         with jax.named_scope("attn"), jax.named_scope("kv_write"):
-            blk = jnp.take_along_axis(block_tables, (seq_lens // bs)[:, None],
-                                      axis=1)[:, 0]
-            blk = jnp.where(active, blk, 0)
+            blk = jnp.where(active, _block_at(block_tables, seq_lens // bs),
+                            0)
             off = jnp.where(active, seq_lens % bs, 0)
             kr, vr = jnp.stack(k_rows), jnp.stack(v_rows)  # [La, S, nkv, hd]
             if int8_kv:

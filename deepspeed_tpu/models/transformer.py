@@ -20,7 +20,7 @@ XLA:
 import dataclasses
 import math
 from functools import partial
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -860,10 +860,57 @@ def _decode_attention(q, ck, cv, index, cfg: TransformerConfig = None,
     return out.reshape(B, 1, Nq, D)
 
 
-def _gather_blocks(pool, tables, layer=None):
-    """A slot's blocks as stored: the token-major pool read through the
-    block tables [S, MB] as [S, MB*bs, Nkv, D], ONE gather and nothing
-    around it.
+class BlockList(NamedTuple):
+    """A decode round's live blocks as ONE flat list, the form the XLA read
+    of the paged pool works on (``_paged_list_attention``): what a step
+    gathers follows the list's length — the SUM of the blocks the slots
+    hold — not slots x the longest table.
+
+    The list's unit is a RUN of ``c`` consecutive columns of one slot (a
+    slot's last run padded with 0, the trash block; ``c`` = 1: a block).
+    ids: [R * c] int32 block ids, run after run; where: [R] int32, a run's
+    place in the per-slot view, ``slot * W + its number in the slot``
+    (``S * W`` for a padding run: no place); inv: [S, W] int32, the other
+    way round — the run that holds a slot's columns, R where the slot has
+    none; W = the runs of a full table. ``S``, ``W``, ``R`` and ``c`` are
+    read off the shapes. Built on the host by
+    ``ServingEngine._tables_device``; a rectangular table ``ids[S, MB]`` is
+    the list of all its entries, a block each (``_as_block_list``)."""
+    ids: Any
+    where: Any
+    inv: Any
+
+    @property
+    def run(self) -> int:
+        """Blocks of a run."""
+        return self.ids.shape[0] // self.where.shape[0]
+
+
+def _as_block_list(tables) -> BlockList:
+    """Block tables [S, MB] as the list of all their S x MB entries, row by
+    row (iotas and a bitcast: nothing is computed)."""
+    if isinstance(tables, BlockList):
+        return tables
+    S, MB = tables.shape
+    place = jnp.arange(S * MB, dtype=jnp.int32)
+    return BlockList(tables.reshape(-1), place, place.reshape(S, MB))
+
+
+def _block_at(tables, col):
+    """The block id in column ``col[s]`` of every slot's table -> [S]:
+    where a step writes its fresh row. tables: [S, MB] or a ``BlockList``
+    (a slot without that column reads some block of the list: the caller
+    masks inactive slots to the trash block)."""
+    if isinstance(tables, BlockList):
+        c = tables.run
+        n = jnp.take_along_axis(tables.inv, (col // c)[:, None], axis=1)[:, 0]
+        return jnp.take(tables.ids, n * c + col % c, mode="clip")
+    return jnp.take_along_axis(tables, col[:, None], axis=1)[:, 0]
+
+
+def _gather_blocks(pool, ids, layer=None):
+    """Blocks as stored: the token-major pool read through block ids of any
+    shape [...] as [..., bs, Nkv, D], ONE gather and nothing around it.
 
     pool: one layer's [NB, bs, Nkv, D], or the WHOLE leaf
     [L, NB, bs, Nkv, D] with ``layer`` (traced) the layer to read. Inside a
@@ -872,29 +919,34 @@ def _gather_blocks(pool, tables, layer=None):
     layer (100 MB a pool in the chat cell: 4.4 ms a step, PERF.md §5-6,
     PR 27), while the layer as an offset into the leaf viewed
     [L*NB, bs, Nkv, D] — merging the two MAJOR dims is a bitcast — costs
-    nothing. Table entries are block ids the allocator issued or 0, the
-    trash block: always in bounds, so the gather clips and selects no fill
-    value; what a slot may see is decided by the length mask on the
-    scores."""
-    S, MB = tables.shape
+    nothing. The ids are block ids the allocator issued or 0, the trash
+    block: always in bounds, so the gather clips and selects no fill value;
+    what a slot may see is decided by the length mask on the scores. The
+    decode step hands the flat list of a round's live blocks [N] (a
+    ``BlockList``: the read is sized by what the slots hold), a span its
+    rectangular tables [S, MB]."""
     if layer is not None:
         NB = pool.shape[1]
         pool = pool.reshape((-1,) + pool.shape[2:])
-        tables = layer * NB + tables
-    g = jnp.take(pool, tables, axis=0, mode="clip")  # [S, MB, bs, Nkv, D]
-    return g.reshape((S, MB * pool.shape[1]) + pool.shape[2:])
+        ids = layer * NB + ids
+    return jnp.take(pool, ids, axis=0, mode="clip")
 
 
-def _gather_scales(scale, tables, Nkv, layer=None):
+def _gather_scales(scale, ids, Nkv, layer=None):
     """A scale plane [NB, Nkv*bs] (or the whole [L, NB, Nkv*bs] with
-    ``layer``) read through the block tables as [S, Nkv, MB*bs]
-    (position-major within a head). The layer is a coordinate of the gather:
-    the planes live in a layout of their own, where merging [L, NB] copies
-    the plane."""
-    S, MB = tables.shape
-    idx = (tables,) if layer is None else (layer, tables)
-    g = scale.at[idx].get(mode="clip")           # [S, MB, Nkv*bs]
-    return g.reshape(S, MB, Nkv, -1).transpose(0, 2, 1, 3).reshape(S, Nkv, -1)
+    ``layer``) read through block ids [...] as [..., Nkv, bs]. The layer is
+    a coordinate of the gather: the planes live in a layout of their own,
+    where merging [L, NB] copies the plane."""
+    idx = (ids,) if layer is None else (layer, ids)
+    g = scale.at[idx].get(mode="clip")           # [..., Nkv*bs]
+    return g.reshape(ids.shape + (Nkv, -1))
+
+
+def _table_view(g):
+    """Scales gathered per block, [A, B, Nkv, bs] — a slot's table columns,
+    a run's blocks —, as [A, Nkv, B*bs]: position-major within a head."""
+    A, B, Nkv, bs = g.shape
+    return g.transpose(0, 2, 1, 3).reshape(A, Nkv, B * bs)
 
 
 def _quant_query(q32):
@@ -921,20 +973,26 @@ def _paged_attention(q, pool_k, pool_v, tables, index, cfg: TransformerConfig,
     [NB, bs, Nkv, D], one layer's slice of the shared block pool, stored
     TOKEN-major (see ``init_paged_cache``) — or, with ``layer`` (a traced
     index), the WHOLE leaves [L, NB, bs, Nkv, D] and scale planes, which is
-    what a layer scan passes (``_gather_blocks`` says why); tables: [S, MB]
-    int32 block ids (0 = the reserved trash block, masked by the length);
-    index: per-slot sequence length [S].
+    what a layer scan passes (``_gather_blocks`` says why); tables: a
+    ``BlockList``, the flat list of the blocks the slots hold (what the
+    serving engine hands the XLA backend), or rectangular [S, MB] int32
+    block ids (0 = the reserved trash block, masked by the length), which
+    read as the list of all their entries; index: per-slot sequence
+    length [S].
 
-    backend="pallas": the block-table gather is resolved inside the kernel's
-    index maps (ops/decode_attention.paged_decode_attention) — only blocks
-    covering the valid prefix ever cross HBM->VMEM, nothing materializes.
-    backend="xla": ONE ``jnp.take`` per pool materializes the slot's blocks
-    as stored ([S, MB*bs, Nkv, D]) and ``_paged_token_attention`` contracts
-    that view as gathered. Its arithmetic is the ring-buffer path's
-    (``_decode_attention`` with a per-slot cursor) operation for operation,
-    which is what keeps paged-vs-contiguous decode bit-for-bit comparable in
-    tests. The backend is chosen by a measured micro-bench at serving-engine
-    init, not a config flag.
+    backend="pallas" (rectangular tables): the block-table gather is
+    resolved inside the kernel's index maps
+    (ops/decode_attention.paged_decode_attention) — only blocks covering
+    the valid prefix ever cross HBM->VMEM, nothing materializes.
+    backend="xla": ONE ``jnp.take`` per pool materializes the LISTED blocks
+    as stored ([N, bs, Nkv, D]: N follows the sum of what the slots hold,
+    not slots x the longest table) and ``_paged_list_attention`` contracts
+    them as gathered, block by block, with one softmax a slot. Its
+    arithmetic is the ring-buffer path's (``_decode_attention`` with a
+    per-slot cursor) operation for operation, which is what keeps
+    paged-vs-contiguous decode bit-for-bit comparable in tests. The backend
+    is chosen by a measured micro-bench at serving-engine init, not a
+    config flag.
 
     Multi-token queries (q [S, T, Nq, D] with T > 1 — the speculation
     verify / chunked-prefill span path, ``decode_span_paged``) route to
@@ -948,6 +1006,7 @@ def _paged_attention(q, pool_k, pool_v, tables, index, cfg: TransformerConfig,
                                      kv_row, kv_scale=kv_scale,
                                      window=window, layer=layer)
     use_pallas = (backend == "pallas" and kv_scale is None
+                  and not isinstance(tables, BlockList)
                   and window is None and q.dtype != jnp.float16
                   and (cfg is None or (cfg.position_type != "alibi"
                                        and cfg.attn_scale is None)))
@@ -973,14 +1032,16 @@ def _paged_attention(q, pool_k, pool_v, tables, index, cfg: TransformerConfig,
             mesh, in_specs=(hs, hs, hs, P(), P(), rs, rs), out_specs=hs,
             manual_axes=axes)(q, pool_k, pool_v, tables, index, *kv_row)
 
+    blocks = _as_block_list(tables)
     sc = None
     with jax.named_scope("kv_gather"):
         if kv_scale is not None:
-            sc = tuple(_gather_scales(s, tables, Nkv, layer)
+            sc = tuple(_gather_scales(s, blocks.ids, Nkv, layer)
                        for s in kv_scale)
-        vk, vv = (_gather_blocks(pool_k, tables, layer),
-                  _gather_blocks(pool_v, tables, layer))
-    return _paged_token_attention(q, vk, vv, index, cfg, kv_row, sc, window)
+        vk, vv = (_gather_blocks(pool_k, blocks.ids, layer),
+                  _gather_blocks(pool_v, blocks.ids, layer))
+    return _paged_list_attention(q, vk, vv, blocks, index, cfg, kv_row, sc,
+                                 window)
 
 
 def _head_groups():
@@ -992,43 +1053,78 @@ def _head_groups():
     return kernel_mesh()[1].get("tensor", 1)
 
 
-def _paged_token_attention(q, vk, vv, index, cfg, kv_row, kv_scale, window):
-    """One token per slot against the gathered TOKEN-major view.
+def _paged_list_attention(q, vk, vv, blocks: BlockList, index, cfg, kv_row,
+                          kv_scale, window):
+    """One token per slot against the round's live blocks, gathered as ONE
+    flat list.
 
-    q: [S, 1, Nq, D]; vk/vv: [S, T, Nkv, D] as ``_gather_blocks`` returns
-    them (T = MB*bs positions, rows at >= index stale); kv_row: the fresh
-    (k, v) [S, Nkv, 1, D], folded into the same softmax; kv_scale: the int8
-    pool's gathered (k, v) scales [S, Nkv, T], or None.
+    q: [S, 1, Nq, D]; vk/vv: the blocks of ``blocks.ids`` as
+    ``_gather_blocks`` returns them, [R * c, block, Nkv, D], TOKEN-major as
+    stored — read as R runs of ``bs`` = c x block positions, [R, bs, Nkv,
+    D], a bitcast; kv_row: the fresh (k, v) [S, Nkv, 1, D], folded into the
+    same softmax; kv_scale: the int8 pool's gathered (k, v) scales
+    [R * c, Nkv, block], or None.
 
-    The paged read's OWN contraction: ``_decode_attention`` wants the
-    ring buffer's head-major [B, Nkv, T, D], and borrowing it cost the view
-    a select-and-transpose pass (7.1 ms a step in the chat cell) and, at one
-    query head per kv head, the int8 scores as an elementwise s32
-    multiply-reduce over a WIDENED view (two s32 views of 268 MB a layer in
-    OLMoE's cell: 33 of its step's 58 ms; PERF.md §5-6, PR 27). Here the
-    view is contracted as stored, and the recipe is ``_decode_attention``'s
-    to the letter — query quantised per row, int8 x int8 -> int32, q and k
-    scales multiplied into the scores, probabilities x v-scale requantised
-    per row — so the results are its results bit for bit.
+    Everything sized by the K/V — the two gathers, the scores, P.V — is
+    sized by the LIST: nothing of ``S x MB x block x Nkv x D`` exists.
+    Scores are taken per run against its slot's query (``q[slot]``), laid
+    into the per-slot view [S, Nkv, rep, W*bs] through ``blocks.inv``
+    (float32 scores: a sixteenth to a sixty-fourth of the int8 K/V's bytes
+    a position), and the mask, the ONE softmax per (slot, head) over the
+    pool positions and the fresh row and the requantisation per ROW run
+    there, as they always did; the int8 probabilities go back to their
+    runs (``blocks.where``), P.V is taken per run, and a slot's partial
+    sums are added up through ``blocks.inv`` again. Places of the view
+    that no run fills lie past the slot's length (the blocks cover it):
+    masked like stale rows.
+
+    The recipe is ``_decode_attention``'s to the letter — query quantised
+    per row, int8 x int8 -> int32, q and k scales multiplied into the
+    scores, probabilities x v-scale requantised per row, int32 P.V — and
+    the int32 sums are exact in any order, so the results are the ring
+    buffer's bit for bit, whatever the list's length and order. A float
+    pool's P.V is summed per run in float32 and then over the runs: the
+    same products in another order.
 
     The int8 contractions are written block-diagonally so that they are
-    matmuls at ANY number of query heads per kv head: the quantised query
-    is laid out [S, Nkv, D, Nkv*rep] with zeros off the diagonal and the
-    scores are one matmul per slot contracting (Nkv, D); P.V likewise gives
-    [S, Nkv*rep, Nkv, D], of which the diagonal blocks are kept. The zeros
-    add exact zeros to an int32 sum. It is Nkv times the multiply-adds of
-    the plain form (6.4 GFLOP a layer in the chat cell: tens of
-    microseconds of the int8 MXU) and none of its passes over the view.
-    Under a ``tensor`` mesh the diagonal is laid per group of local kv
-    heads (``_head_groups``), so no sum crosses chips.
+    matmuls at ANY number of query heads per kv head, over the view as
+    gathered (no transpose, no widened view: PR 27). Scores: the quantised
+    query is laid out [R, Nkv, D, Nkv*rep] with zeros off the diagonal and
+    contracted with a run over (Nkv, D). P.V: the probabilities are laid
+    out [R, Nkv*rep, bs, Nkv] with zeros off the diagonal and contracted
+    with the run over (bs, Nkv) — the run read as the matrix [bs*Nkv, D]
+    it is stored as — so a run's partial is [Nkv*rep, D] (every (query
+    head, kv head) pair first and the diagonal afterwards would be Nkv
+    times that in int32, per RUN: with a block a run half the bytes of the
+    K and V gathered, PERF.md section 6, PR 38). The zeros add exact zeros
+    to an int32 sum. Under a ``tensor`` mesh the diagonal is laid per
+    group of local kv heads (``_head_groups``), so no sum crosses chips.
     """
     S, _, Nq, D = q.shape
-    T, Nkv = vk.shape[1], vk.shape[2]
+    Nkv = vk.shape[2]
+    R, W = blocks.where.shape[0], blocks.inv.shape[1]    # runs; a slot's
+    c = blocks.run
+    bs = c * vk.shape[1]                                 # positions of a run
+    vk, vv = (a.reshape(R, bs, Nkv, D) for a in (vk, vv))
+    if kv_scale is not None:       # [R*c, Nkv, block] -> [R, Nkv, bs]
+        kv_scale = tuple(_table_view(a.reshape(R, c, Nkv, -1))
+                         for a in kv_scale)
+    T = W * bs
     rep = Nq // Nkv
     sm = (cfg.attn_scale if cfg is not None and cfg.attn_scale is not None
           else 1.0 / math.sqrt(D))
     qg = q.reshape(S, Nkv, rep, D)
     k_row, v_row = kv_row                        # [S, Nkv, 1, D]
+    # a padding entry has no slot: it reads the last slot's query and its
+    # scores and partial sums are never looked at (no place of `inv` names it)
+    slot = jnp.minimum(blocks.where // W, S - 1)                # [R]
+    held = (blocks.inv < R)[:, :, None, None]                    # [S, W,..]
+    inv = jnp.minimum(blocks.inv, R - 1)
+
+    def to_view(x):        # [R, Nkv, r, bs] -> [S, Nkv, r, W*bs]
+        v = jnp.take(x, inv, axis=0)             # [S, W, Nkv, r, bs]
+        return v.transpose(0, 2, 3, 1, 4).reshape(S, Nkv, x.shape[2], T)
+
     if kv_scale is not None:
         X = _head_groups()
         G = Nkv // X                             # kv heads of one group
@@ -1036,21 +1132,23 @@ def _paged_token_attention(q, vk, vv, index, cfg, kv_row, kv_scale, window):
         qi, qs = _quant_query(qg.astype(jnp.float32))
         # [S, X, G, rep, D] x eye[G, H] -> [S, X, G, D, H, rep]
         qd = jnp.einsum("sxgrd,gh->sxgdhr", qi.reshape(S, X, G, rep, D), eye)
-        scores = jnp.einsum("stxgd,sxgdhr->sxhrt",
-                            vk.reshape(S, T, X, G, D), qd,
+        scores = jnp.einsum("ntxgd,nxgdhr->nxhrt",
+                            vk.reshape(R, bs, X, G, D), qd[slot],
                             preferred_element_type=jnp.int32
-                            ).reshape(S, Nkv, rep, T).astype(jnp.float32)
-        scores = scores * qs[..., None] * kv_scale[0][:, :, None, :]
+                            ).reshape(R, Nkv, rep, bs).astype(jnp.float32)
+        scores = scores * qs[slot][..., None] * kv_scale[0][:, :, None, :]
     else:
-        scores = jnp.einsum("sgrd,stgd->sgrt", qg, vk).astype(jnp.float32)
-    scores = scores * sm
+        scores = jnp.einsum("ngrd,ntgd->ngrt", qg[slot], vk
+                            ).astype(jnp.float32)
+    scores = to_view(scores) * sm
     index = jnp.asarray(index, jnp.int32)[:, None]
     if cfg is not None and cfg.position_type == "alibi":
         rel = (jnp.arange(T)[None, :] - index).astype(jnp.float32)  # k - q
         slopes = alibi_slopes(Nq).reshape(Nkv, rep)
         scores = scores + slopes[None, :, :, None] * rel[:, None, None, :]
     # rows at >= index are stale (or another request's, or the trash
-    # block's); the current token's logit comes from the fresh row
+    # block's, or no block's); the current token's logit comes from the
+    # fresh row
     keep = jnp.arange(T)[None, :] < index
     if window is not None:
         # local band: position t visible iff index - t < window; <= 0 global
@@ -1063,21 +1161,33 @@ def _paged_token_attention(q, vk, vv, index, cfg, kv_row, kv_scale, window):
     probs = jax.nn.softmax(jnp.concatenate([scores, s_self], axis=-1),
                            axis=-1)
     pp = probs[..., :T]
+
+    def to_list(x):        # [S, Nkv, rep, W*bs] -> [R, Nkv, rep, bs]
+        v = x.reshape(S, Nkv, rep, W, bs).transpose(0, 3, 1, 2, 4)
+        return jnp.take(v.reshape(S * W, Nkv, rep, bs), blocks.where,
+                        axis=0, mode="clip")
+
+    def per_slot(part):    # [R, Nkv, rep, D] -> [S, Nkv, rep, D]
+        return jnp.sum(jnp.where(held, jnp.take(part, inv, axis=0)
+                                 .reshape(S, W, Nq, D), 0),
+                       axis=1).reshape(S, Nkv, rep, D)
+
     if kv_scale is not None:
         # fold the per-position V scale into the probs, requantize per row,
         # keep the contraction on the int8 MXU (the _decode_pv recipe)
-        pvi, ps = _quant_probs(pp * kv_scale[1][:, :, None, :])
-        # every (query head, kv head) pair of a group, then its diagonal
-        acc = jnp.einsum("sxhrt,stxgd->sxhrgd",
-                         pvi.reshape(S, X, G, rep, T),
-                         vv.reshape(S, T, X, G, D),
+        pvi, ps = _quant_probs(pp * to_view(kv_scale[1][:, :, None, :]))
+        # [R, X, H, rep, bs] x eye[H, G] -> [R, X, H, rep, bs, G]
+        pd = jnp.einsum("nxhrt,hg->nxhrtg",
+                        to_list(pvi).reshape(R, X, G, rep, bs), eye)
+        acc = jnp.einsum("nxhrtg,ntxgd->nxhrd", pd,
+                         vv.reshape(R, bs, X, G, D),
                          preferred_element_type=jnp.int32)
-        acc = jnp.sum(jnp.where((eye != 0)[None, None, :, None, :, None],
-                                acc, 0), axis=4)
-        out = (acc.reshape(S, Nkv, rep, D).astype(jnp.float32)
+        out = (per_slot(acc.reshape(R, Nkv, rep, D)).astype(jnp.float32)
                * ps[..., None]).astype(q.dtype)
     else:
-        out = jnp.einsum("sgrt,stgd->sgrd", pp.astype(q.dtype), vv)
+        acc = jnp.einsum("ngrt,ntgd->ngrd", to_list(pp.astype(q.dtype)), vv,
+                         preferred_element_type=jnp.float32)
+        out = per_slot(acc).astype(q.dtype)
     out = out + probs[..., T:].astype(q.dtype) * v_row.astype(q.dtype)
     return out.reshape(S, 1, Nq, D)
 
@@ -1120,11 +1230,11 @@ def _paged_span_attention(q, pool_k, pool_v, tables, prior_lens,
 
     # the pool view stays token-major [S, Tp, Nkv, D], as gathered
     with jax.named_scope("kv_gather"):
-        vk, vv = (_gather_blocks(pool_k, tables, layer),
-                  _gather_blocks(pool_v, tables, layer))
+        vk, vv = (_gather_blocks(p, tables, layer).reshape(S, -1, Nkv, D)
+                  for p in (pool_k, pool_v))
         Tp = vk.shape[1]
         if kv_scale is not None:
-            ksg, vsg = (_gather_scales(s, tables, Nkv, layer)
+            ksg, vsg = (_table_view(_gather_scales(s, tables, Nkv, layer))
                         for s in kv_scale)
     qg = q.transpose(0, 2, 1, 3).reshape(S, Nkv, rep, T, D)
     pos = prior_lens[:, None] + jnp.arange(T)[None, :]       # [S, T] abs
@@ -1392,7 +1502,8 @@ def transformer_layer(x, layer_params, cfg: TransformerConfig, mask=None,
     an int8 pool) instead of per-batch ring buffers, `layer` is this
     layer's (traced) index into them and `index` the per-slot
     sequence-length vector — attention gathers the layer's blocks straight
-    out of the whole pool through the block table (decode_step_paged;
+    out of the whole pool through the block table, or the flat list of the
+    slots' blocks a decode step is handed (decode_step_paged;
     ``_gather_blocks`` says why the pool is not sliced first).
 
     lora=({proj: (A, B)}, idx): one layer's adapter slot tables + the
@@ -2369,7 +2480,7 @@ def init_paged_cache(cfg: TransformerConfig, num_blocks: int,
     back around every such write (4 x 1.6 GB a step at 16 x 1537 blocks,
     PERF.md §5-6, PR 24). The attention read gathers a layer's blocks out
     of the whole pool and contracts them token-major, as stored
-    (``_gather_blocks``, ``_paged_token_attention``).
+    (``_gather_blocks``, ``_paged_list_attention``).
 
     Block 0 is the reserved TRASH block: null block-table entries point at
     it and inactive slots write into it, so the compiled step needs no
@@ -2381,7 +2492,7 @@ def init_paged_cache(cfg: TransformerConfig, num_blocks: int,
     planes are relayouted around the row write whatever the order of their
     axes, this one is written in place and pads no lanes) — the attention
     read consumes the int8 bytes directly with dequant fused into the score
-    scaling (see _paged_token_attention / ops/quantizer).
+    scaling (see _paged_list_attention / ops/quantizer).
 
     ``paged_blocks_to_logical`` / ``paged_blocks_from_logical`` translate
     whole blocks to and from the head-major order [.., n_kv, block_size,
@@ -2453,11 +2564,13 @@ def decode_step_paged(params: Params, tokens, cfg: TransformerConfig,
     """One decode step for every slot of a paged serving batch.
 
     tokens: [S] int32 (one in-flight token per slot); block_tables:
-    [S, MB] int32; seq_lens: [S] = tokens already in each slot's cache
-    (the fresh row is written AT seq_lens); active: [S] bool (None = all).
-    Returns (logits [S, V], pools). The program is shaped by the POOL and
-    table dims only — admitting/evicting sequences changes the table
-    contents, never the compiled program.
+    [S, MB] int32, or a ``BlockList`` — the flat list of the blocks the
+    slots hold, which sizes the XLA backend's read by their sum; seq_lens:
+    [S] = tokens already in each slot's cache (the fresh row is written AT
+    seq_lens); active: [S] bool (None = all). Returns (logits [S, V],
+    pools). The program is shaped by the POOL and table (or list) dims
+    only — admitting/evicting sequences changes their contents, never the
+    compiled program.
 
     ``lora``: optional ``(adapter_pool, aidx)`` — ``adapter_pool`` maps
     projection name -> {"a": [L, NS, In, r], "b": [L, NS, r, Out]} device
@@ -2527,9 +2640,7 @@ def decode_step_paged(params: Params, tokens, cfg: TransformerConfig,
     # token-major pool; inactive slots hit the trash block (duplicate trash
     # writes are unordered and never read)
     with jax.named_scope("attn"), jax.named_scope("kv_write"):
-        blk = jnp.take_along_axis(block_tables, (seq_lens // bs)[:, None],
-                                  axis=1)[:, 0]
-        blk = jnp.where(active, blk, 0)
+        blk = jnp.where(active, _block_at(block_tables, seq_lens // bs), 0)
         off = jnp.where(active, seq_lens % bs, 0)
         k_rows, v_rows = k_rows[:, :, :, 0], v_rows[:, :, :, 0]
         if int8_kv:

@@ -4,7 +4,7 @@ tests/unit/ops kernel-vs-torch parity, SURVEY §4).
 The kernel's reference is the materialized block-table gather, turned
 head-major, fed through ``models/transformer._decode_attention`` (the
 ring-buffer math with a per-slot cursor). The serving engine's XLA backend
-has a contraction of its own since ISSUE 27 (``_paged_token_attention``,
+has a contraction of its own since ISSUE 27 (``_paged_list_attention``,
 the gathered view consumed token-major as stored); it is checked here
 against a plain numpy reference.
 """

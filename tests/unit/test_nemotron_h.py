@@ -243,7 +243,8 @@ def test_a_per_slot_state_pool_keeps_every_slot_in_the_step(toy):
     assert serving._slot_ladder(40) == (16, 40)
     assert serving._slot_ladder(128) == (32, 128)
     assert srv._slot_counts == (40,)
-    assert set(srv._step_shapes()) == {(40, W) for W in srv._table_widths}
+    assert set(srv._step_shapes()) == {
+        (40, W) for W in serving._list_ladder(srv.MB, (4, 2, 1))}
     reqs = [(_ids(9, 70), 6), (_ids(21, 71), 5)]
     outs = srv.run(reqs)
     for (p, m), rid in zip(reqs, sorted(outs)):

@@ -23,6 +23,11 @@ wider type than the pool's (with one query head per kv head the parent
 widened both views to s32, 268 MB each a layer in OLMoE's cell, and
 multiplied them elementwise), and few temporaries.
 
+And what it reads is a flat LIST of the round's live blocks (ISSUE 38):
+handed ``N`` blocks the step gathers ``[N, block, kv heads, head_dim]`` of
+each pool a layer and holds nothing of the ``slots x table`` view's size, nor
+an int32 partial sum larger than the K and V it gathered.
+
 libtpu is loaded behind a fixture (``topo``, tests/conftest.py), shared
 with ``test_kernel_names.py``, the only other file that describes a chip.
 Under several workers each of the two files' workers loads it; the driver's command allows that
@@ -46,7 +51,7 @@ def one_chip(topo):
     return jax.sharding.SingleDeviceSharding(topo.devices[0])
 
 
-# the pools of the benchmark's three serve cells (benchmark/configs/*-serve
+# the pools of four of the benchmark's serve cells (benchmark/configs/*-serve
 # .json: layers x blocks, slots, table width at 64-token blocks, kv and
 # query heads of 128) and a float pool of the chat cell's shape at half the
 # blocks
@@ -59,6 +64,10 @@ CASES = {
                        nkv=16, nq=16),
     "chat-bf16": dict(layers=16, blocks=769, slots=48, mb=16, bits=0,
                       nkv=8, nq=32),
+    # one pass of PR 35's looped cell (48 of its 192 planes x 161 blocks,
+    # 16 slots x 20 columns, one query head per kv head)
+    "ouro-int8": dict(layers=48, blocks=161, slots=16, mb=20, bits=8,
+                      nkv=16, nq=16),
 }
 BS, HD = 64, 128
 
@@ -97,13 +106,24 @@ def _abstract(tree, sharding):
         tree)
 
 
-def _program(srv, case, kind, one_chip, mb=None, slots=None):
+def _block_list(sds, slots, columns, mb):
+    """The shapes of a ``BlockList`` of ``slots x columns`` blocks in the
+    engine's runs, for slots whose tables are ``mb`` wide."""
+    from deepspeed_tpu.inference.serving import _RUN
+    from deepspeed_tpu.models.transformer import BlockList
+    n = slots * columns
+    return BlockList(sds((n,), jnp.int32), sds((n // _RUN,), jnp.int32),
+                     sds((slots, -(-mb // _RUN)), jnp.int32))
+
+
+def _program(srv, case, kind, one_chip, shape=None):
     """(lowered-and-compiled program, abstract pools) of one of the
-    engine's three pool-writing functions at the case's shapes (``mb``:
-    another table width than the case's; ``slots``: the step handed the
-    first ``slots`` rows of the case's slots)."""
+    engine's three pool-writing functions at the case's shapes. ``shape``:
+    the step handed a block list of another ``(slots, columns a slot)``
+    than the case's full ``(slots, table width)`` — the first ``slots``
+    rows of the case's slots, ``slots x columns`` blocks."""
     c = CASES[case]
-    S, MB = slots or c["slots"], mb or c["mb"]
+    S, W = shape or (c["slots"], c["mb"])
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -117,12 +137,12 @@ def _program(srv, case, kind, one_chip, mb=None, slots=None):
         fn = jax.jit(srv._quantum_step_fn().__wrapped__,
                      donate_argnums=(1, 4))
         args = (params, pools, sds((c["slots"],), jnp.int32),
-                sds((S, MB), jnp.int32), sds((S,), jnp.int32),
+                _block_list(sds, S, W, c["mb"]), sds((S,), jnp.int32),
                 sds((S,), jnp.bool_), key)
     elif kind == "span":                  # speculation verify, T = 4
         fn = jax.jit(srv._get_spec_step().__wrapped__, donate_argnums=(1,))
         args = (params, pools, sds((S, 4), jnp.int32),
-                sds((S, MB), jnp.int32), sds((S,), jnp.int32),
+                sds((S, W), jnp.int32), sds((S,), jnp.int32),
                 sds((S,), jnp.bool_), key)
     else:                                 # prefill of one 256-token bucket
         fn = jax.jit(srv._get_prefill_fn(256).__wrapped__,
@@ -224,10 +244,11 @@ def test_pool_is_written_in_place(case, kind, one_chip, engines):
     # side's gathered views (slots x table x block, K and V) are temporaries
     # too — 0.2 GB in the chat cell, a quarter of the whole Mixtral pool —
     # and share their space with whatever the write needs, so the write's
-    # own temporaries are read off the same program with a table one block
-    # wide: under the bytes of ONE K/V leaf.
+    # own temporaries are read off the same program with a list of one run
+    # a slot (a span: a table one run wide): under the bytes of ONE K/V leaf.
     if kind != "prefill":                 # a prefill reads no table
-        compiled, pools = _program(engines(case), case, kind, one_chip, mb=1)
+        compiled, pools = _program(engines(case), case, kind, one_chip,
+                                   shape=(CASES[case]["slots"], 2))
     leaf = pools["k"]
     leaf_bytes = int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
     temp = compiled.memory_analysis().temp_size_in_bytes
@@ -267,67 +288,61 @@ def test_pool_is_read_once(case, kind, one_chip, engines):
         assert temp < 0.3e9, temp
 
 
-@pytest.mark.parametrize("case,width", [
-    ("chat-int8", 24), ("chat-int8", 16), ("olmoe-int8", 12)])
-def test_a_narrower_table_narrows_the_read(case, width, one_chip, engines):
-    """The read is sized by the table's width and by nothing else (ISSUE
-    29): handed ``width`` of the cell's columns, the step gathers
-    ``[slots * width, block, kv heads, head_dim]`` of each pool and holds
-    no array of the full table's view, in any type — so a round whose
-    longest sequence ends early reads that much less, with not a line of
-    the model changed."""
+# every list length of the cells' engines (``ServingEngine._step_shapes`` at
+# their slots and table widths) but the full one, which the tests above
+# compile; PR 35's looped cell is checked at its published widths below
+@pytest.mark.parametrize("case,slots,columns", [
+    ("chat-int8", 16, 4), ("chat-int8", 16, 8), ("chat-int8", 48, 16),
+    ("mixtral-int8", 32, 8), ("mixtral-int8", 32, 16),
+    ("olmoe-int8", 32, 4), ("olmoe-int8", 32, 8),
+    ("chat-bf16", 16, 2), ("chat-bf16", 16, 4), ("chat-bf16", 48, 8),
+    ("ouro-int8", 16, 6), ("ouro-int8", 16, 10)])
+def test_the_read_is_sized_by_the_list(case, slots, columns, one_chip,
+                                       engines):
+    """The read is sized by the LIST the step is handed and by nothing
+    else (ISSUE 38; ISSUE 29 and ISSUE 33 sized it by the tables' width and
+    rows): handed ``slots x columns`` blocks for the first ``slots`` of the
+    cell's slots, the step holds two gathers of ``[blocks, block, kv heads,
+    head_dim]`` a layer, no array of the ``slots x table`` view's size in
+    any type — nor of the full engine's —, no int32 array larger than the K
+    and V it gathered (P.V's partial sums are a block's ``[query heads,
+    head_dim]``, not every (query head, kv head) pair's), no widened view,
+    no slice of a layer; the pool leaves are still written in place; and
+    the per-slot token vector goes in and comes back ``max_seqs`` long."""
+    from deepspeed_tpu.inference.serving import _list_ladder, _slot_ladder
     c = CASES[case]
+    assert slots in _slot_ladder(c["slots"])
+    assert columns in _list_ladder(c["mb"], (8, 4, 2, 1))
     compiled, pools = _program(engines(case), case, "step", one_chip,
-                               mb=width)
-    hlo = compiled.as_text()
-    view = int(np.prod(pools["k"].shape[2:]))     # block x kv heads x head_dim
-    full, narrow = c["slots"] * c["mb"] * view, c["slots"] * width * view
-    gathers = [line for ty, n, op, line, _ in _instructions(hlo)
-               if (ty, n, op) == ("s8", narrow, "fusion")
-               and f"s8[{c['slots'] * width},{BS},{c['nkv']},{HD}]" in line]
-    assert len(gathers) == 2, "\n".join(gathers)        # K and V, a layer
-    bad = [line for ty, n, op, line, _ in _instructions(hlo, fused_too=True)
-           if n == full and op not in _VIEWS]
-    assert not bad, "\n".join(bad)
-    assert not layer_slice_ops(hlo, pools)
-    assert not widened_view_ops(hlo, pools, c["slots"], width)
-
-
-@pytest.mark.parametrize("share", [4, 2], ids=["quarter", "half"])
-@pytest.mark.parametrize("case", ["chat-int8", "mixtral-int8", "olmoe-int8",
-                                  "chat-bf16"])
-def test_fewer_slots_narrow_the_read(case, share, one_chip, engines):
-    """The read is sized slots x width by the tables' SHAPE (ISSUE 33):
-    handed the first quarter of the cell's slots (of 48: the engine's own
-    ladder) or half of them, the step gathers ``[slots' * width, block, kv heads, head_dim]``
-    of each pool and holds no array of the full ``slots x width`` view, in
-    any type; the pool leaves are still written in place; and the per-slot
-    token vector goes in and comes back ``max_seqs`` long."""
-    from deepspeed_tpu.inference.serving import _slot_ladder
-    c = CASES[case]
-    few = {4: -(-c["slots"] // 32) * 8, 2: c["slots"] // 2}[share]
-    assert few < c["slots"] and _slot_ladder(48) == (16, 48)
-    compiled, pools = _program(engines(case), case, "step", one_chip,
-                               slots=few)
+                               shape=(slots, columns))
     hlo = compiled.as_text()
     leaf = pools["k"]
     ty = _HLO_NAME[np.dtype(leaf.dtype).name]
     view = int(np.prod(leaf.shape[2:]))           # block x kv heads x head_dim
-    full, narrow = c["slots"] * c["mb"] * view, few * c["mb"] * view
-    gathers = [line for t, n, op, line, _ in _instructions(hlo)
-               if (t, n, op) == (ty, narrow, "fusion")
-               and f"{ty}[{few * c['mb']},{BS},{c['nkv']},{HD}]" in line]
+    n = slots * columns
+    gathers = [line for t, m, op, line, _ in _instructions(hlo)
+               if (t, m, op) == (ty, n * view, "fusion")
+               and f"{ty}[{n},{BS},{c['nkv']},{HD}]" in line]
     assert len(gathers) == 2, "\n".join(gathers)        # K and V, a layer
-    bad = [line for t, n, op, line, _ in _instructions(hlo, fused_too=True)
-           if n == full and op not in _VIEWS]
+    tables = {slots * c["mb"] * view, c["slots"] * c["mb"] * view} - {n * view}
+    bad = [line for t, m, op, line, _ in _instructions(hlo, fused_too=True)
+           if m in tables and op not in _VIEWS]
+    assert not bad, "\n".join(bad)
+    gathered = 2 * n * view * np.dtype(leaf.dtype).itemsize
+    bad = [line for t, m, op, line, _ in _instructions(hlo, fused_too=True)
+           if t == "s32" and m * 4 > gathered and op not in _VIEWS]
     assert not bad, "\n".join(bad)
     assert not layer_slice_ops(hlo, pools)
-    assert not widened_view_ops(hlo, pools, few, c["mb"])
-    leaves = ({n: pools[n] for n in "kv"} if case == "olmoe-int8" else pools)
+    assert not widened_view_ops(hlo, pools, slots, columns)
+    # OLMoE's and the looped cell's scale planes are relayouted around the
+    # row scatters (``test_pool_is_read_once``; PERF.md section 7, PR 35):
+    # the write's business, those shapes check their payload leaves
+    leaves = ({n: pools[n] for n in "kv"}
+              if case in ("olmoe-int8", "ouro-int8") else pools)
     bad = whole_pool_ops(hlo, leaves)
     assert not bad, "\n".join(bad)
     (_, (tokens, _), lens) = compiled.out_info
-    assert tokens.shape == (c["slots"],) and lens.shape == (few,)
+    assert tokens.shape == (c["slots"],) and lens.shape == (slots,)
 
 
 # ---- the dropless expert dispatch (ISSUE 26) --------------------------------
@@ -393,7 +408,7 @@ def test_dropless_dispatch_is_proportional_to_the_assignments(one_chip, monkeypa
                     sds((T_ // BS,), jnp.int32), sds((), jnp.int32), key)
         else:
             fn = jax.jit(srv._quantum_step_fn().__wrapped__, donate_argnums=(1, 4))
-            args = (params, pools, sds((S,), jnp.int32), sds((S, MB), jnp.int32),
+            args = (params, pools, sds((S,), jnp.int32), _block_list(sds, S, MB, MB),
                     sds((S,), jnp.int32), sds((S,), jnp.bool_), key)
         # the program asks the backend which dispatch to build; the test
         # answers for the chip it compiles for
@@ -482,7 +497,8 @@ def test_the_looped_cells_programs_fit_the_chip_and_write_the_pool_in_place(
     S, key = serving["max_seqs"], sds((2,), jnp.uint32)
     if kind == "step":
         fn = jax.jit(srv._quantum_step_fn().__wrapped__, donate_argnums=(1, 4))
-        args = (params, pools, sds((S,), jnp.int32), sds((S, width), jnp.int32),
+        args = (params, pools, sds((S,), jnp.int32),
+                _block_list(sds, S, width, serving["max_model_len"] // BS),
                 sds((S,), jnp.int32), sds((S,), jnp.bool_), key)
     else:
         fn = jax.jit(srv._get_prefill_fn(width).__wrapped__, donate_argnums=(2,))
