@@ -261,16 +261,18 @@ def blocks_for(n_tokens: int, block_size: int) -> int:
 def state_pool_bytes(cfg, max_seqs: int, dtype=None) -> int:
     """LOGICAL bytes of the per-slot recurrent state of a model with
     recurrent blocks (``models/hybrid.py``): per block and slot a float32
-    state [heads, head dim, state size] and the last K - 1 rows of the
-    convolution's input in the pool dtype. 0 for every other model."""
-    Lm = int(getattr(cfg, "recurrent_blocks", 0) or 0)
-    if not Lm:
+    state (Mamba-2 [heads, head dim, state size], Gated DeltaNet [value
+    heads, key dim, value dim]) and the last K - 1 rows of the convolution's
+    input in the pool dtype, every leaf of ``hybrid.state_shapes`` summed. 0
+    for every other model."""
+    if not int(getattr(cfg, "recurrent_blocks", 0) or 0):
         return 0
+    import math
     import numpy as _np
-    from deepspeed_tpu.models.mamba import dims
-    nh, hd, _, N, _, conv_dim, K = dims(cfg)
+    from deepspeed_tpu.models.hybrid import state_shapes
     itemsize = _np.dtype(dtype if dtype is not None else cfg.dtype).itemsize
-    return Lm * max_seqs * (nh * hd * N * 4 + (K - 1) * conv_dim * itemsize)
+    return sum(math.prod(shape) * (itemsize if name.endswith("conv") else 4)
+               for name, shape in state_shapes(cfg, max_seqs).items())
 
 
 def pool_bytes(cfg, num_blocks: int, block_size: int, dtype=None,

@@ -200,8 +200,8 @@ class DecodeDispatchHang(RuntimeError):
 
 
 class RecurrentStateUnsupported(ValueError):
-    """What a model with recurrent blocks (``block_pattern`` with "M":
-    ``nemotron_h``) cannot be served with, refused at ``init_serving`` or
+    """What a model with recurrent blocks (``block_pattern`` with "M" or
+    "G": ``nemotron_h``, ``qwen3_next``) cannot be served with, refused at ``init_serving`` or
     at the call: a request's state there is its K/V blocks AND a recurrent
     state per slot, and everything that shares, rolls back, resumes or ships
     a request's state by its blocks alone — the prefix cache and its
@@ -664,8 +664,9 @@ class ServingEngine:
         with engine.mesh:
             self.pools = self._init_pools_fn()
         # the per-slot recurrent state's dtype (None for every other model)
-        self.state_pool_dtype = (str(self.pools["ssm"].dtype)
-                                 if self._recurrent else None)
+        self.state_pool_dtype = next(
+            (str(self.pools[k].dtype) for k in ("ssm", "gdn")
+             if k in self.pools), None)
         # logical pool size (the README memory math, mesh-independent) vs
         # the PER-DEVICE shard each chip actually holds: on a tp-sharded
         # engine the resident HBM is logical / tp (the kv-head slice), and
@@ -2922,7 +2923,8 @@ class ServingEngine:
             return 0
         from deepspeed_tpu.models.hybrid import STATE_LEAVES
         from deepspeed_tpu.parallel.partitioning import sharded_bytes
-        return sharded_bytes({k: self.pools[k] for k in STATE_LEAVES})
+        return sharded_bytes({k: self.pools[k] for k in STATE_LEAVES
+                              if k in self.pools})
 
     def reset_stats(self) -> None:
         """Start a fresh measurement window: completed-request records,
@@ -3005,7 +3007,14 @@ class ServingEngine:
         active slots read, mean over layers and steps; ``..._per_prefill``:
         the same for a whole-prompt prefill's real tokens) and
         ``moe_dropped_share`` (assignments the dispatch dropped; 0 for a
-        dropless model).
+        dropless model). A model whose expert layers hold THE CHIP'S SHARE
+        of a deployment's experts (``TransformerConfig.moe_router_experts``)
+        reports ``moe_held`` and ``moe_router_width`` (experts held, experts
+        the router scores) and, in place of the dropped share,
+        ``moe_assignments_asked`` / ``moe_assignments_held``: of the
+        assignments the router made, those on the experts held here (their
+        ratio is about held / width under an even router). The other
+        counters are over the experts held.
 
         A looped model (always on, ``ut_steps`` > 1 only): ``ut_steps``,
         ``kv_planes`` (K/V planes a token keeps: passes x layers),
@@ -3019,7 +3028,8 @@ class ServingEngine:
 
         The two kinds of state (always on): ``kv_pool_bytes`` (the K/V block
         pool's share of ``pool_bytes``) and, for a model with recurrent
-        blocks, ``state_pool_bytes`` (the per-slot recurrent state) and
+        blocks, ``state_pool_bytes`` (the per-slot recurrent state, every
+        state leaf of the pool summed) and
         ``state_slots_live`` (slots whose state belongs to a running
         request);
         ``moe_dispatch`` — a dict ``{"step" | "prefill_<bucket>": form}``
@@ -3117,9 +3127,17 @@ class ServingEngine:
                 out[f"{key}_p50_ms"] = float(np.percentile(vals, 50))
                 out[f"{key}_p90_ms"] = float(np.percentile(vals, 90))
         m = self._moe
-        if m["asked"]:
+        mcfg = self.model.config
+        held, width = mcfg.num_experts, mcfg.moe_router_width
+        if m["asked"] and held == width:
             out["moe_assignments"] = float(m["kept"])
             out["moe_dropped_share"] = 1.0 - m["kept"] / m["asked"]
+        elif m["asked"]:
+            # this chip's share of a deployment's experts: of the
+            # assignments the router made, those on the experts held here
+            out.update(moe_held=float(held), moe_router_width=float(width),
+                       moe_assignments_asked=float(m["asked"]),
+                       moe_assignments_held=float(m["kept"]))
         if m["rounds"]:
             out["moe_load_max_over_mean"] = m["max_over_mean"] / m["rounds"]
         if m["steps"]:
@@ -3132,7 +3150,6 @@ class ServingEngine:
             out["moe_dispatch"] = forms
         out["kv_pool_bytes"] = float(self.pool_bytes - self._state_bytes())
         if self._ut_steps > 1:
-            mcfg = self.model.config
             out["ut_steps"] = float(self._ut_steps)
             out["kv_planes"] = float(mcfg.kv_planes)
             out["kv_bytes_per_token"] = float(pool_bytes(

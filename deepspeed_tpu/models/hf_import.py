@@ -1083,6 +1083,79 @@ def _nemotron_h_kwargs(get) -> dict:
         time_step_floor=float(get("time_step_floor", 1e-4)))
 
 
+def _qwen3_next_kwargs(get) -> dict:
+    """``qwen3_next`` (Qwen3-Next): every layer is a token mixer followed by
+    an expert layer, so a layer is TWO blocks of a hybrid stack — ``G``
+    (Gated DeltaNet) or, every ``full_attention_interval``-th layer, ``*``
+    (gated softmax attention: q/k RMSNorm per head, rotary over the first
+    ``partial_rotary_factor`` of a head, a sigmoid output gate), then ``E``
+    (softmax router, top-k renormalised, SwiGLU experts, a gated shared
+    expert). The multi-token-prediction module has no config key and is not
+    part of the next-token forward. Dense layers (``mlp_only_layers``,
+    ``decoder_sparse_step`` > 1), rope scaling, a sliding window and biases
+    are refused: nothing here computes them.
+
+    THE CHIP'S SHARE: ``num_experts`` is the experts held; a
+    ``num_experts_router`` key beside it (not a published key: a benchmark
+    configuration cut to one chip's share states it) is the router's width,
+    ``expert_first`` the first expert held. Absent: all experts held."""
+    L, interval = get("num_hidden_layers"), get("full_attention_interval", 4)
+    for key, want in (("decoder_sparse_step", 1), ("hidden_act", "silu"),
+                      ("rope_scaling", None)):
+        if get(key, want) != want:
+            raise ValueError(f"qwen3_next {key}={get(key)!r} is not supported "
+                             f"(the published config has {want!r})")
+    if get("mlp_only_layers"):
+        raise ValueError("qwen3_next mlp_only_layers: dense layers are not "
+                         "supported (the published config has [])")
+    for key in ("attention_bias", "use_sliding_window"):
+        if get(key, False):
+            raise ValueError(f"qwen3_next {key}=true is not supported")
+    pattern = "".join(("*" if (i + 1) % interval == 0 else "G") + "E"
+                      for i in range(L))
+    kinds = get("layer_types")
+    if kinds is not None and list(kinds) != [
+            "full_attention" if (i + 1) % interval == 0 else "linear_attention"
+            for i in range(L)]:
+        raise ValueError("qwen3_next layer_types disagrees with "
+                         f"full_attention_interval={interval}")
+    held = get("num_experts")
+    width, first = get("num_experts_router", held), get("expert_first", 0)
+    if not 0 <= first <= width - held:
+        raise ValueError(f"qwen3_next: experts {first} .. {first + held - 1} "
+                         f"held of num_experts_router={width}")
+    head_dim = get("head_dim") or get("hidden_size") // get("num_attention_heads")
+    return dict(
+        vocab_size=get("vocab_size"), hidden_size=get("hidden_size"),
+        num_layers=len(pattern), block_pattern=pattern,
+        num_heads=get("num_attention_heads"),
+        num_kv_heads=get("num_key_value_heads"), head_dim=head_dim,
+        max_seq_len=get("max_position_embeddings", 4096),
+        norm_eps=float(get("rms_norm_eps", 1e-6)),
+        position_type="rotary", norm_type="rmsnorm", activation="silu_glu",
+        rope_theta=float(get("rope_theta", 10000.0)),
+        rotary_dim=int(head_dim * float(get("partial_rotary_factor", 1.0))),
+        qk_norm_per_head=True, attn_out_gate=True,
+        tie_embeddings=bool(get("tie_word_embeddings", False)),
+        # the E blocks: `moe_intermediate_size` is ONE expert's width
+        # (`intermediate_size`, a dense layer's, is unread)
+        intermediate_size=get("moe_intermediate_size"),
+        num_experts=held, top_k=get("num_experts_per_tok"),
+        moe_router_experts=width if width != held else None,
+        moe_held_first=first,
+        norm_topk_prob=bool(get("norm_topk_prob", True)),
+        moe_shared_size=get("shared_expert_intermediate_size", 0) or 0,
+        moe_shared_gate=bool(get("shared_expert_intermediate_size", 0)),
+        drop_tokens=False, use_residual=False,
+        moe_aux_loss_weight=float(get("router_aux_loss_coef", 0.001)),
+        # the G blocks
+        gdn_num_k_heads=get("linear_num_key_heads"),
+        gdn_num_v_heads=get("linear_num_value_heads"),
+        gdn_head_k_dim=get("linear_key_head_dim"),
+        gdn_head_v_dim=get("linear_value_head_dim"),
+        conv_kernel=get("linear_conv_kernel_dim", 4))
+
+
 class EarlyExitUnsupported(NotImplementedError):
     """A looped model whose ``early_exit_threshold`` is below 1: a token
     would leave the stack at the first pass whose cumulative exit
@@ -1190,6 +1263,8 @@ def hf_config_to_transformer(hf_cfg, **overrides):
         kw = _nemotron_h_kwargs(get)
     elif mt == "ouro":
         kw = _ouro_kwargs(get)
+    elif mt == "qwen3_next":
+        kw = _qwen3_next_kwargs(get)
     elif mt == "opt":
         if get("word_embed_proj_dim", get("hidden_size")) != get("hidden_size"):
             raise ValueError(

@@ -1,26 +1,37 @@
 """Hybrid stacks: one MIXER per block, its kind from a static pattern.
 
 ``TransformerConfig.block_pattern`` is one letter a block (``nemotron_h``'s
-``hybrid_override_pattern``): ``M`` a Mamba-2 mixer (``models/mamba.py``),
-``E`` an expert feed-forward (``moe/sharded_moe.py``), ``*`` attention. Every
-block is ``h <- h + mixer(RMSNorm(h))``: no feed-forward after attention, no
-attention before an expert layer. ``models/transformer.py``'s ``init_params``,
+``hybrid_override_pattern``; ``qwen3_next``'s layers are TWO blocks each, a
+token mixer then ``E``): ``M`` a Mamba-2 mixer (``models/mamba.py``), ``G`` a
+Gated DeltaNet mixer (``models/gated_deltanet.py``), ``E`` an expert
+feed-forward (``moe/sharded_moe.py``), ``*`` attention. Every block is
+``h <- h + mixer(RMSNorm(h))``: the walker puts no feed-forward after
+attention and no attention before an expert layer, the pattern does. The
+attention blocks carry no positional embedding (``position_type="none"``,
+``nemotron_h``) or rotary over the first ``rotary_dim`` dims of a head
+(``"rotary"``), a per-head RMSNorm of q and k (``qk_norm_per_head``) and a
+sigmoid gate on their output, projected beside q (``attn_out_gate``), as the
+config says. ``models/transformer.py``'s ``init_params``,
 ``logical_axes``, ``forward``, ``init_paged_cache``, ``prefill_paged`` and
 ``decode_step_paged`` hand a config with a pattern to the functions here, so
 a hybrid model is a ``make_model`` like any other and serves through the same
 engine.
 
-Parameters are stacked PER KIND (``params["layers"]["mamba" | "moe" |
-"attn"]``, leading dim = blocks of that kind) and the walk over the pattern is
-unrolled: a block's index within its kind is a Python int, its slice of a
-stack a static one, and a program is still shaped by the pool and table
-dims only.
+Parameters are stacked PER KIND (``params["layers"]["mamba" | "gdn" | "moe"
+| "attn"]``, leading dim = blocks of that kind). The walk over a pattern that
+does not repeat is unrolled: a block's index within its kind is a Python
+int, its slice of a stack a static one. A pattern that repeats (three periods
+of ``GEGEGE*E``) is a ``lax.scan`` over its repeats with one unit unrolled in
+the body and the indices traced (``_walk``). Either way a program is shaped
+by the pool and table dims only.
 
 The cache is two kinds of state side by side in one tree (the serving
 engine's ``srv.pools``): the K/V block pool, whose layer dim counts the
-ATTENTION blocks only, and a per-slot state pool for the ``M`` blocks —
+ATTENTION blocks only, and a per-slot state pool for the recurrent blocks —
 ``ssm`` float32 ``[Lm, slots, heads, P, N]`` and ``conv`` ``[Lm, slots,
-K - 1, conv_dim]`` (the last K - 1 rows of ``xBC`` before the convolution).
+K - 1, conv_dim]`` (the last K - 1 rows of ``xBC`` before the convolution)
+for the ``M`` blocks, ``gdn`` float32 ``[Lg, slots, value heads, dk, dv]``
+and ``gdn_conv`` ``[Lg, slots, K - 1, conv_dim]`` for the ``G`` blocks.
 A prefill is a whole prompt from a zero state and overwrites the slot's rows
 with the state after the last TRUE position (so a slot given again carries
 nothing of the last request); a step advances the active slots' state in
@@ -33,19 +44,22 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from deepspeed_tpu.models import gated_deltanet as gdn
 from deepspeed_tpu.models import mamba
 from deepspeed_tpu.moe import sharded_moe as _moe
 
-KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
+KINDS = {"M": "mamba", "G": "gdn", "E": "moe", "*": "attn"}
 
 
 def blocks(cfg):
     """[(kind, index within its kind)] in block order."""
-    if cfg.position_type != "none" or cfg.norm_type != "rmsnorm":
+    if cfg.position_type not in ("none", "rotary") \
+            or cfg.norm_type != "rmsnorm":
         raise NotImplementedError(
             "a hybrid (block_pattern) stack is RMSNorm blocks whose attention "
-            f"carries no positional embedding; got position_type="
-            f"{cfg.position_type!r}, norm_type={cfg.norm_type!r}")
+            "carries no positional embedding or a rotary one; got "
+            f"position_type={cfg.position_type!r}, "
+            f"norm_type={cfg.norm_type!r}")
     if len(cfg.block_pattern) != cfg.num_layers:
         raise ValueError(f"block_pattern {cfg.block_pattern!r} has "
                          f"{len(cfg.block_pattern)} letters for "
@@ -55,7 +69,7 @@ def blocks(cfg):
         if letter not in KINDS:
             raise ValueError(
                 f"block_pattern letter {letter!r}: one of {sorted(KINDS)} "
-                "(M Mamba-2, E experts, * attention)")
+                "(M Mamba-2, G Gated DeltaNet, E experts, * attention)")
         kind = KINDS[letter]
         out.append((kind, seen.get(kind, 0)))
         seen[kind] = seen.get(kind, 0) + 1
@@ -77,12 +91,15 @@ def init_params(key, cfg):
     [time_step_min, time_step_max]), ``D``, the convolution and its bias,
     and the router's correction bias (std 0.02 against a 6th-to-7th score
     gap of ~0.01: it moves choices without starving experts; at 0.1 the
-    fullest expert of a step took 7 x the mean load)."""
+    fullest expert of a step took 7 x the mean load). The ``G`` blocks take
+    Mamba-2's draw of ``A_log`` and ``dt_bias`` (decays of 0.001 to 1.6 a
+    position before the data-dependent part), a convolution without bias and
+    an output-norm scale ``w_n`` in U(0.5, 1.5); a gated shared expert's
+    gate vector is drawn like a router column."""
     H, V, dt = cfg.hidden_size, cfg.vocab_size, cfg.param_dtype
     std = 0.02
     out_scale = std / math.sqrt(2 * cfg.num_layers)
-    nh, hd, G, N, d_inner, conv_dim, K = mamba.dims(cfg)
-    Lm, Le, La = (count(cfg, k) for k in ("mamba", "moe", "attn"))
+    Lm, Lg, Le, La = (count(cfg, k) for k in ("mamba", "gdn", "moe", "attn"))
     keys = iter(jax.random.split(key, 32))
 
     def normal(shape, scale=std):
@@ -91,21 +108,40 @@ def init_params(key, cfg):
     def uniform(shape, lo, hi):
         return jax.random.uniform(next(keys), shape, jnp.float32, lo, hi)
 
-    layers = {}
-    if Lm:
-        step = jnp.exp(uniform((Lm, nh), math.log(cfg.time_step_min),
+    def dt_bias(shape):
+        """The inverse softplus of a step drawn log-uniformly in
+        [time_step_min, time_step_max]."""
+        step = jnp.exp(uniform(shape, math.log(cfg.time_step_min),
                                math.log(cfg.time_step_max)))
         step = jnp.maximum(step, cfg.time_step_floor)
+        return (step + jnp.log(-jnp.expm1(-step))).astype(dt)
+
+    layers = {}
+    if Lm:
+        nh, hd, G, N, d_inner, conv_dim, K = mamba.dims(cfg)
+        bias = dt_bias((Lm, nh))              # drawn first, as since PR 32
         layers["mamba"] = {
             "ln_scale": jnp.ones((Lm, H), dt),
             "in_proj": normal((Lm, H, d_inner + conv_dim + nh)),
             "conv_w": uniform((Lm, K, conv_dim), -0.5, 0.5).astype(dt),
             "conv_b": uniform((Lm, conv_dim), -0.5, 0.5).astype(dt),
-            "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dt),
+            "dt_bias": bias,
             "A_log": jnp.log(uniform((Lm, nh), 1.0, 16.0)).astype(dt),
             "D": uniform((Lm, nh), 0.5, 1.5).astype(dt),
             "gate_norm": jnp.ones((Lm, d_inner), dt),
             "out_proj": normal((Lm, d_inner, H), out_scale),
+        }
+    if Lg:
+        Hk, Hv, dk, dv, conv_dim, K = gdn.dims(cfg)
+        layers["gdn"] = {
+            "ln_scale": jnp.ones((Lg, H), dt),
+            "in_qkvz": normal((Lg, H, conv_dim + Hv * dv)),
+            "in_ba": normal((Lg, H, 2 * Hv)),
+            "conv_w": uniform((Lg, K, conv_dim), -0.5, 0.5).astype(dt),
+            "dt_bias": dt_bias((Lg, Hv)),
+            "A_log": jnp.log(uniform((Lg, Hv), 1.0, 16.0)).astype(dt),
+            "gate_norm": uniform((Lg, dv), 0.5, 1.5).astype(dt),
+            "out_proj": normal((Lg, Hv * dv, H), out_scale),
         }
     if Le:
         E, F, Fs = cfg.num_experts, cfg.ffn_dim, cfg.moe_shared_size
@@ -121,7 +157,9 @@ def init_params(key, cfg):
 
         layers["moe"] = {
             "ln_scale": jnp.ones((Le, H), dt),
-            "wg": normal((Le, H, E)),
+            # the router scores ALL the model's experts; the stacks hold
+            # the E this chip holds (`moe_router_width` = E by default)
+            "wg": normal((Le, H, cfg.moe_router_width)),
             # the up projection, each matrix stored [F, H]: a width off
             # the 128 grid (1856) is read in place only with H last
             # (ops/grouped_matmul.grouped_matmul, `transposed`)
@@ -141,15 +179,23 @@ def init_params(key, cfg):
         if Fs:
             layers["moe"]["shared_w_in"] = normal((Le, H, Fs))
             layers["moe"]["shared_w_out"] = normal((Le, Fs, H), out_scale)
+            if "glu" in cfg.activation:
+                layers["moe"]["shared_w_gate"] = normal((Le, H, Fs))
+            if cfg.moe_shared_gate:
+                layers["moe"]["shared_gate"] = normal((Le, H))
     if La:
         nq, nkv, ahd = cfg.num_heads, cfg.kv_heads, cfg.dim_per_head
         layers["attn"] = {
             "ln_scale": jnp.ones((La, H), dt),
-            "wq": normal((La, H, nq * ahd)),
+            # with an output gate, a head's columns are [q | gate]
+            "wq": normal((La, H, nq * ahd * (2 if cfg.attn_out_gate else 1))),
             "wk": normal((La, H, nkv * ahd)),
             "wv": normal((La, H, nkv * ahd)),
             "wo": normal((La, nq * ahd, H), out_scale),
         }
+        if cfg.qk_norm_per_head:
+            layers["attn"]["q_norm"] = jnp.ones((La, ahd), dt)
+            layers["attn"]["k_norm"] = jnp.ones((La, ahd), dt)
     params = {"tok_embed": normal((V, H)), "layers": layers,
               "final_norm_scale": jnp.ones((H,), dt)}
     if not cfg.tie_embeddings:
@@ -158,10 +204,19 @@ def init_params(key, cfg):
 
 
 def logical_axes(cfg):
-    """Same tree as ``init_params``. The Mamba leaves carry no model-parallel
-    axis: the recurrent state is not split over ``tensor`` (the serving
-    engine refuses that degree for a model with ``M`` blocks)."""
+    """Same tree as ``init_params``. The recurrent mixers' leaves carry no
+    model-parallel axis: the recurrent state is not split over ``tensor``
+    (the serving engine refuses that degree for a model with ``M`` or ``G``
+    blocks)."""
     layers = {}
+    if count(cfg, "gdn"):
+        layers["gdn"] = {
+            "ln_scale": ("layers", "unmodeled"),
+            "in_qkvz": ("layers", "embed", None),
+            "in_ba": ("layers", "embed", None),
+            "conv_w": ("layers", None, None), "dt_bias": ("layers", None),
+            "A_log": ("layers", None), "gate_norm": ("layers", None),
+            "out_proj": ("layers", None, "embed")}
     if count(cfg, "mamba"):
         layers["mamba"] = {
             "ln_scale": ("layers", "unmodeled"),
@@ -183,11 +238,18 @@ def logical_axes(cfg):
         if cfg.moe_shared_size:
             layers["moe"]["shared_w_in"] = ("layers", "embed", "mlp")
             layers["moe"]["shared_w_out"] = ("layers", "mlp", "embed")
+            if "glu" in cfg.activation:
+                layers["moe"]["shared_w_gate"] = ("layers", "embed", "mlp")
+            if cfg.moe_shared_gate:
+                layers["moe"]["shared_gate"] = ("layers", "embed")
     if count(cfg, "attn"):
         layers["attn"] = {
             "ln_scale": ("layers", "unmodeled"),
             "wq": ("layers", "embed", "qkv"), "wk": ("layers", "embed", "qkv"),
             "wv": ("layers", "embed", "qkv"), "wo": ("layers", "heads", "embed")}
+        if cfg.qk_norm_per_head:
+            layers["attn"]["q_norm"] = ("layers", None)
+            layers["attn"]["k_norm"] = ("layers", None)
     axes = {"tok_embed": ("vocab", "embed"), "layers": layers,
             "final_norm_scale": ("unmodeled",)}
     if not cfg.tie_embeddings:
@@ -216,33 +278,63 @@ def _moe_mixer(p, h, cfg, train: bool = False, rng=None):
                   "w_out": p["moe_w_out"]}
     for ours, theirs in (("moe_w_gate", "w_gate"), ("e_bias", "e_bias"),
                          ("shared_w_in", "shared_w_in"),
-                         ("shared_w_out", "shared_w_out")):
+                         ("shared_w_out", "shared_w_out"),
+                         ("shared_w_gate", "shared_w_gate"),
+                         ("shared_gate", "shared_gate")):
         if ours in p:
             moe_params[theirs] = p[ours]
     with jax.named_scope("moe"):
         return _moe.moe_ffn(moe_params, h, cfg, rng=rng, train=train)
 
 
-def _qkv(p, h, cfg):
-    """h [B, T, H] -> q [B, T, nq, hd], k, v [B, T, nkv, hd]. The attention
-    blocks of a hybrid stack carry no positional embedding (``blocks``
-    refuses a config that names one)."""
-    from deepspeed_tpu.models.transformer import _wmat
+def _qkv(p, h, cfg, positions=None):
+    """h [B, T, H] -> (q [B, T, nq, hd], k, v [B, T, nkv, hd], gate [B, T,
+    nq hd] or None). What the config names is applied in HF's order: the
+    output gate's columns split off q (``attn_out_gate``: a head's columns
+    are [q | gate]), the per-head RMSNorm of q and k (``q_norm`` / ``k_norm``
+    [hd]), rotary at ``positions`` [B, T] over the first ``rotary_dim`` dims
+    (``position_type="rotary"``; "none" applies nothing)."""
+    from deepspeed_tpu.models.transformer import (_rms_whole, _wmat,
+                                                  rotary_embed)
     B, T, _ = h.shape
-    hd = cfg.dim_per_head
-    return (_wmat(h, p["wq"]).reshape(B, T, cfg.num_heads, hd),
-            _wmat(h, p["wk"]).reshape(B, T, cfg.kv_heads, hd),
-            _wmat(h, p["wv"]).reshape(B, T, cfg.kv_heads, hd))
+    hd, gate = cfg.dim_per_head, None
+    q = _wmat(h, p["wq"])
+    if cfg.attn_out_gate:
+        q = q.reshape(B, T, cfg.num_heads, 2 * hd)
+        q, gate = q[..., :hd], q[..., hd:].reshape(B, T, cfg.num_heads * hd)
+    q = q.reshape(B, T, cfg.num_heads, hd)
+    k = _wmat(h, p["wk"]).reshape(B, T, cfg.kv_heads, hd)
+    v = _wmat(h, p["wv"]).reshape(B, T, cfg.kv_heads, hd)
+    if "q_norm" in p:
+        q = _rms_whole(q, p["q_norm"], cfg.norm_eps)
+        k = _rms_whole(k, p["k_norm"], cfg.norm_eps)
+    if cfg.position_type == "rotary":
+        q = rotary_embed(q, positions, cfg.rope_theta, cfg.rotary_dim,
+                         cfg.rotary_interleaved)
+        k = rotary_embed(k, positions, cfg.rope_theta, cfg.rotary_dim,
+                         cfg.rotary_interleaved)
+    return q, k, v, gate
+
+
+def _out(p, o, gate):
+    """The attention block's output projection of o [B, T, nq hd], through
+    the sigmoid gate where the block has one."""
+    from deepspeed_tpu.models.transformer import _wrow
+    if gate is not None:
+        with jax.named_scope("attn"), jax.named_scope("out_gate"):
+            o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
+    return _wrow(o, p["wo"])
 
 
 def _attn_mixer(p, h, cfg):
     """Causal attention over whole sequences h [B, T, H] -> (out, k, v)."""
-    from deepspeed_tpu.models.transformer import _wrow, attention
+    from deepspeed_tpu.models.transformer import attention
     B, T, _ = h.shape
-    q, k, v = _qkv(p, h, cfg)
+    q, k, v, gate = _qkv(p, h, cfg,
+                         jnp.broadcast_to(jnp.arange(T)[None], (B, T)))
     with jax.named_scope("attn"):
         o = attention(q, k, v, causal=True, cfg=cfg)
-    return _wrow(o.reshape(B, T, -1), p["wo"]), k, v
+    return _out(p, o.reshape(B, T, -1), gate), k, v
 
 
 def _norm_in(p, x, cfg):
@@ -267,6 +359,82 @@ def _embed(params, ids, cfg):
 
 
 # --------------------------------------------------------------------------
+# the walk over the pattern
+# --------------------------------------------------------------------------
+
+def period(cfg):
+    """(unit, n): the pattern as ``n`` repeats of its shortest unit; a
+    pattern that does not repeat is one unit. DEFERRED: any pattern with
+    Mamba-2 blocks is one unit too, because ``%ssm_step`` takes its block's
+    index as a Python int (``ops/ssm.py``: a literal in the block index, where
+    ``%gdn_step``'s is a prefetched scalar). No pattern the benchmark runs
+    has both Mamba-2 blocks and a repeat, and the traced index would change
+    the accepted hybrid cell's step text (``tests/unit/test_program_text.
+    py``): the PR that serves a repeating Mamba-2 pattern gives the kernel
+    the scalar and drops the test on ``"M"``."""
+    pattern = cfg.block_pattern
+    if "M" not in pattern:
+        for size in range(1, len(pattern) // 2 + 1):
+            if pattern == pattern[:size] * (len(pattern) // size):
+                return pattern[:size], len(pattern) // size
+    return pattern, 1
+
+
+def _walk(params, cfg, carry, block):
+    """``block(i, kind, j, p, carry) -> (carry, out)`` over the pattern's
+    blocks in order (``j`` the block's index within its kind, ``p`` its
+    slice of the kind's stacks, ``out`` None or what an attention block
+    hands on) -> (carry, outs): the attention blocks' outs, a list where
+    the walk is unrolled and stacked arrays where it is a scan (``_stacked``
+    makes either the latter), None if there are none.
+
+    A pattern that repeats (``period``) is walked as a ``lax.scan`` over its
+    repeats with ONE unit unrolled in the body — ``j`` is then traced,
+    ``repeat x blocks of the kind a unit + index in the unit`` — so a
+    program holds one unit's blocks whatever the depth (three periods of
+    eight blocks: a third of 24 blocks' compile time and program text). A
+    pattern that does not repeat is unrolled, its indices Python ints."""
+    unit, n = period(cfg)
+    per_unit, steps = {}, []
+    for letter in unit:
+        kind = KINDS[letter]
+        steps.append((kind, per_unit.get(kind, 0)))
+        per_unit[kind] = per_unit.get(kind, 0) + 1
+
+    def one_unit(carry, r):
+        outs = []
+        for i, (kind, j) in enumerate(steps):
+            j = r * per_unit[kind] + j
+            carry, out = block(i, kind, j, _block(params, kind, j), carry)
+            if out is not None:
+                outs.append(out)
+        return carry, outs or None
+
+    if n == 1:
+        return one_unit(carry, 0)
+
+    def body(carry, r):
+        with _moe.layer_load_tap() as tap:
+            carry, outs = one_unit(carry, r)
+        return carry, (_stacked(outs),
+                       tap.stacked() if tap is not None else None)
+
+    carry, (outs, load) = lax.scan(body, carry, jnp.arange(n))
+    _moe.record_expert_load(load)
+    # [repeats, attention blocks a unit, ...] -> [attention blocks, ...]
+    return carry, (None if outs is None else jax.tree.map(
+        lambda a: a.reshape((-1,) + a.shape[2:]), outs))
+
+
+def _stacked(outs):
+    """A list of per-block pytrees -> one pytree of arrays stacked on a new
+    leading dim; what is stacked already as it is."""
+    if isinstance(outs, list):
+        return jax.tree.map(lambda *a: jnp.stack(a), *outs)
+    return outs
+
+
+# --------------------------------------------------------------------------
 # forward, no cache
 # --------------------------------------------------------------------------
 
@@ -278,21 +446,25 @@ def forward(params, input_ids, cfg, *, deterministic: bool = True,
     if extra:
         raise NotImplementedError(
             f"a hybrid (block_pattern) model's forward takes no {extra}")
-    x = _embed(params, input_ids, cfg)
-    aux_total = jnp.float32(0.0)
-    for i, (kind, j) in enumerate(blocks(cfg)):
-        p = _block(params, kind, j)
+
+    def block(i, kind, j, p, carry):
+        x, aux_total = carry
         with jax.named_scope(f"layer{i}"):
             h = _norm_in(p, x, cfg)
             if kind == "mamba":
                 y = mamba.mixer_forward(p, h, cfg)
+            elif kind == "gdn":
+                y = gdn.mixer_forward(p, h, cfg)
             elif kind == "moe":
                 y, aux = _moe_mixer(p, h, cfg, train=not deterministic,
                                     rng=dropout_rng)
                 aux_total = aux_total + aux
             else:
                 y = _attn_mixer(p, h, cfg)[0]
-            x = x + y
+            return (x + y, aux_total), None
+
+    (x, aux_total), _ = _walk(
+        params, cfg, (_embed(params, input_ids, cfg), jnp.float32(0.0)), block)
     if return_hidden:
         return _final_norm(params, x, cfg), aux_total
     logits = _head(params, x, cfg)
@@ -301,14 +473,16 @@ def forward(params, input_ids, cfg, *, deterministic: bool = True,
 
 # --------------------------------------------------------------------------
 # the paged cache: K/V blocks for the attention blocks, a state per slot for
-# the Mamba blocks
+# the recurrent blocks
 # --------------------------------------------------------------------------
 
 def init_paged_cache(cfg, num_blocks: int, block_size: int, dtype=None,
                      max_seqs: Optional[int] = None):
     """``k``, ``v`` (+ int8 scale planes) exactly as ``transformer.
     init_paged_cache`` lays them out, over the ATTENTION blocks only, and
-    ``ssm`` / ``conv`` for ``max_seqs`` slots."""
+    ``ssm`` / ``conv`` (the ``M`` blocks) and ``gdn`` / ``gdn_conv`` (the
+    ``G`` blocks) for ``max_seqs`` slots, each pair only where the pattern
+    has such blocks."""
     import dataclasses
     from deepspeed_tpu.models import transformer as tf
     if max_seqs is None:
@@ -319,11 +493,27 @@ def init_paged_cache(cfg, num_blocks: int, block_size: int, dtype=None,
         dataclasses.replace(cfg, block_pattern=None,
                             num_layers=count(cfg, "attn")),
         num_blocks, block_size, dtype=dtype)
-    nh, hd, _, N, _, conv_dim, K = mamba.dims(cfg)
-    Lm = count(cfg, "mamba")
-    pools["ssm"] = jnp.zeros((Lm, max_seqs, nh, hd, N), jnp.float32)
-    pools["conv"] = jnp.zeros((Lm, max_seqs, K - 1, conv_dim), dtype)
+    for name, shape in state_shapes(cfg, max_seqs).items():
+        pools[name] = jnp.zeros(
+            shape, dtype if name.endswith("conv") else jnp.float32)
     return pools
+
+
+def state_shapes(cfg, max_seqs: int) -> dict:
+    """{leaf: shape} of the per-slot state pool: the recurrent state
+    (float32) and the convolution tail (the pool dtype) of each recurrent
+    kind the pattern has."""
+    out = {}
+    Lm, Lg = count(cfg, "mamba"), count(cfg, "gdn")
+    if Lm:
+        nh, hd, _, N, _, conv_dim, K = mamba.dims(cfg)
+        out["ssm"] = (Lm, max_seqs, nh, hd, N)
+        out["conv"] = (Lm, max_seqs, K - 1, conv_dim)
+    if Lg:
+        _, Hv, dk, dv, conv_dim, K = gdn.dims(cfg)
+        out["gdn"] = (Lg, max_seqs, Hv, dk, dv)
+        out["gdn_conv"] = (Lg, max_seqs, K - 1, conv_dim)
+    return out
 
 
 def paged_cache_logical_axes(cfg):
@@ -331,20 +521,20 @@ def paged_cache_logical_axes(cfg):
     import dataclasses
     out = tf.paged_cache_logical_axes(
         dataclasses.replace(cfg, block_pattern=None))
-    out["ssm"] = (None,) * 5
-    out["conv"] = (None,) * 4
+    for name, shape in state_shapes(cfg, 1).items():
+        out[name] = (None,) * len(shape)
     return out
 
 
-STATE_LEAVES = ("ssm", "conv")
+STATE_LEAVES = ("ssm", "conv", "gdn", "gdn_conv")
 
 
 def prefill_paged(params, input_ids, cfg, pools, block_ids,
                   length: Optional[int] = None, slot=None):
     """Prefill ONE request into ``slot``: K/V of the attention blocks into
-    the slot's blocks, the Mamba blocks' state after the last true position
-    into the slot's rows of the state pool. input_ids [1, P], P a multiple
-    of the block size. Returns (last logits [1, V], pools)."""
+    the slot's blocks, the recurrent blocks' state after the last true
+    position into the slot's rows of the state pool. input_ids [1, P], P a
+    multiple of the block size. Returns (last logits [1, V], pools)."""
     from deepspeed_tpu.models.transformer import (_quant_kv,
                                                   _write_prefill_blocks)
     if slot is None:
@@ -353,34 +543,45 @@ def prefill_paged(params, input_ids, cfg, pools, block_ids,
     B, P = input_ids.shape
     assert B == 1, "prefill_paged serves one request"
     true_len = jnp.asarray(P if length is None else length, jnp.int32)
-    x = _embed(params, input_ids, cfg)                            # [1, P, H]
     pools = dict(pools)
-    k_seqs, v_seqs = [], []
+
+    def block(i, kind, j, p, carry):
+        x, state = carry
+        state, out = dict(state), None
+        with jax.named_scope(f"layer{i}"):
+            h = _norm_in(p, x, cfg)
+            if kind == "mamba":
+                y, s, tail = mamba.mixer_prefill(p, h[0], cfg, true_len)
+                y = y[None]
+                with jax.named_scope("ssm"), jax.named_scope("state_write"):
+                    state["ssm"] = state["ssm"].at[j, slot].set(s)
+                    state["conv"] = state["conv"].at[j, slot].set(
+                        tail.astype(state["conv"].dtype))
+            elif kind == "gdn":
+                y, s, tail = gdn.mixer_prefill(p, h[0], cfg, true_len)
+                y = y[None]
+                with jax.named_scope("gdn"), jax.named_scope("state_write"):
+                    state["gdn"] = state["gdn"].at[j, slot].set(s)
+                    state["gdn_conv"] = state["gdn_conv"].at[j, slot].set(
+                        tail.astype(state["gdn_conv"].dtype))
+            elif kind == "moe":
+                y, _ = _moe_mixer(p, h, cfg)
+            else:
+                y, k, v = _attn_mixer(p, h, cfg)
+                out = (jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2))
+            return (x + y, state), out                     # [1, nkv, P, hd]
+
+    x = _embed(params, input_ids, cfg)                            # [1, P, H]
     real = jnp.arange(P)[None] < true_len
     with _moe.counted_tokens(real):
-        for i, (kind, j) in enumerate(blocks(cfg)):
-            p = _block(params, kind, j)
-            with jax.named_scope(f"layer{i}"):
-                h = _norm_in(p, x, cfg)
-                if kind == "mamba":
-                    y, state, tail = mamba.mixer_prefill(
-                        p, h[0], cfg, true_len)
-                    y = y[None]
-                    with jax.named_scope("ssm"), jax.named_scope("state_write"):
-                        pools["ssm"] = pools["ssm"].at[j, slot].set(state)
-                        pools["conv"] = pools["conv"].at[j, slot].set(
-                            tail.astype(pools["conv"].dtype))
-                elif kind == "moe":
-                    y, _ = _moe_mixer(p, h, cfg)
-                else:
-                    y, k, v = _attn_mixer(p, h, cfg)
-                    k_seqs.append(jnp.swapaxes(k, 1, 2))   # [1, nkv, P, hd]
-                    v_seqs.append(jnp.swapaxes(v, 1, 2))
-                x = x + y
-    if k_seqs:
+        (x, state), kv = _walk(
+            params, cfg,
+            (x, {n: pools[n] for n in STATE_LEAVES if n in pools}), block)
+    pools.update(state)
+    if kv is not None:
         # the attention blocks' K/V as transformer.prefill_paged's
         # contiguous cache holds them, [La, 1, nkv, P, hd]: one writer
-        cache = {"k": jnp.stack(k_seqs), "v": jnp.stack(v_seqs)}
+        cache = dict(zip(("k", "v"), _stacked(kv)))
         if cfg.kv_cache_bits == 8:
             (cache["k"], cache["k_scale"]), (cache["v"], cache["v_scale"]) = \
                 _quant_kv(cache["k"]), _quant_kv(cache["v"])
@@ -390,6 +591,39 @@ def prefill_paged(params, input_ids, cfg, pools, block_ids,
     return _head(params, last, cfg)[:, 0], pools
 
 
+def _write_rows(pools, blk, off, rows):
+    """One K/V row per slot into every attention block's plane (``transformer.
+    _scatter_rows``'s contract), as one scatter per (plane, head), whose
+    window is a head's row alone: the pool of a model with FEW K/V heads is
+    stored by the TPU with the block's rows next to the head dim (2 heads
+    would pad to a tile of 8 otherwise), and a scatter whose window spans
+    planes or heads is answered with a relayout of the whole pool, in and
+    out, EVERY step — four copies of 0.5 GB, a fifth of this family's step
+    (PERF.md section 6, PR 40).
+
+    DEFERRED, and the selector below is not the cause: a stack with ONE
+    attention block keeps ``_scatter_rows``. One plane of 2 heads is copied
+    just the same (1.3 % of the accepted hybrid cell's device time, PERF.md
+    section 7); the branch is there because PR 40 had to leave that cell's
+    step program the parent's text (``tests/unit/test_program_text.py``) and
+    its stack is the one in the benchmark with a single attention block. The
+    PR that hands it the per-head writes measures that cell, prints the
+    golden text again and deletes the branch."""
+    from deepspeed_tpu.models.transformer import _scatter_rows
+    La, _, nkv = rows["k"].shape[:3]
+    if La == 1:
+        return _scatter_rows(pools, blk, off, rows)
+    bs, out = pools["k"].shape[2], {}
+    for name, r in rows.items():
+        pool = pools[name]
+        for j in range(La):
+            for h in range(nkv):
+                where = (j, blk, off, h) if r.ndim == 4 else (j, blk, h * bs + off)
+                pool = pool.at[where].set(r[j, :, h])
+        out[name] = pool
+    return out
+
+
 def decode_step_paged(params, tokens, cfg, pools, block_tables, seq_lens,
                       active=None, backend: str = "xla", lora=None):
     """One decode step for every slot (``transformer.decode_step_paged``'s
@@ -397,58 +631,66 @@ def decode_step_paged(params, tokens, cfg, pools, block_tables, seq_lens,
     in lockstep; their K/V rows land in the trash block and their recurrent
     state stays as it is."""
     from deepspeed_tpu.models.transformer import (
-        _block_at, _paged_attention, _quant_kv, _scatter_rows, _wrow)
+        _block_at, _paged_attention, _quant_kv)
     if lora is not None:
         raise NotImplementedError("LoRA adapters on a hybrid model")
     S = tokens.shape[0]
     seq_lens = jnp.asarray(seq_lens, jnp.int32)
     if active is None:
         active = jnp.ones((S,), jnp.bool_)
-    x = _embed(params, tokens[:, None], cfg)                      # [S, 1, H]
     int8_kv = cfg.kv_cache_bits == 8
     bs = pools["k"].shape[2]
     pools = dict(pools)
-    k_rows, v_rows = [], []
+    sc = (pools["k_scale"], pools["v_scale"]) if int8_kv else None
+
+    def block(i, kind, j, p, carry):
+        x, state = carry
+        state, out = dict(state), None
+        with jax.named_scope(f"layer{i}"):
+            h = _norm_in(p, x, cfg)
+            if kind == "mamba":
+                y, state["ssm"], state["conv"] = mamba.mixer_step(
+                    p, h[:, 0], cfg, state["ssm"], state["conv"], j, active)
+                y = y[:, None]
+            elif kind == "gdn":
+                y, state["gdn"], state["gdn_conv"] = gdn.mixer_step(
+                    p, h[:, 0], cfg, state["gdn"], state["gdn_conv"], j,
+                    active)
+                y = y[:, None]
+            elif kind == "moe":
+                y, _ = _moe_mixer(p, h, cfg)
+            else:
+                q, k, v, gate = _qkv(p, h, cfg, seq_lens[:, None])
+                row_dtype = cfg.dtype if int8_kv else pools["k"].dtype
+                k_row = jnp.swapaxes(k, 1, 2).astype(row_dtype)
+                v_row = jnp.swapaxes(v, 1, 2).astype(row_dtype)
+                with jax.named_scope("attn"):
+                    o = _paged_attention(
+                        q, pools["k"], pools["v"], block_tables, seq_lens,
+                        cfg, kv_row=(k_row, v_row), kv_scale=sc,
+                        backend=backend, window=None, layer=j)
+                y = _out(p, o.reshape(S, 1, -1), gate)
+                out = (k_row[:, :, 0], v_row[:, :, 0])
+            return (x + y, state), out
+
     with _moe.counted_tokens(active):
-        for i, (kind, j) in enumerate(blocks(cfg)):
-            p = _block(params, kind, j)
-            with jax.named_scope(f"layer{i}"):
-                h = _norm_in(p, x, cfg)
-                if kind == "mamba":
-                    y, pools["ssm"], pools["conv"] = mamba.mixer_step(
-                        p, h[:, 0], cfg, pools["ssm"], pools["conv"], j,
-                        active)
-                    y = y[:, None]
-                elif kind == "moe":
-                    y, _ = _moe_mixer(p, h, cfg)
-                else:
-                    q, k, v = _qkv(p, h, cfg)
-                    row_dtype = cfg.dtype if int8_kv else pools["k"].dtype
-                    k_row = jnp.swapaxes(k, 1, 2).astype(row_dtype)
-                    v_row = jnp.swapaxes(v, 1, 2).astype(row_dtype)
-                    sc = (pools["k_scale"], pools["v_scale"]) if int8_kv \
-                        else None
-                    with jax.named_scope("attn"):
-                        o = _paged_attention(
-                            q, pools["k"], pools["v"], block_tables, seq_lens,
-                            cfg, kv_row=(k_row, v_row), kv_scale=sc,
-                            backend=backend, window=None, layer=j)
-                    y = _wrow(o.reshape(S, 1, -1), p["wo"])
-                    k_rows.append(k_row[:, :, 0])
-                    v_rows.append(v_row[:, :, 0])
-                x = x + y
-    if k_rows:
+        (x, state), rows = _walk(
+            params, cfg,
+            (_embed(params, tokens[:, None], cfg),                # [S, 1, H]
+             {n: pools[n] for n in STATE_LEAVES if n in pools}), block)
+    pools.update(state)
+    if rows is not None:
         with jax.named_scope("attn"), jax.named_scope("kv_write"):
             blk = jnp.where(active, _block_at(block_tables, seq_lens // bs),
                             0)
             off = jnp.where(active, seq_lens % bs, 0)
-            kr, vr = jnp.stack(k_rows), jnp.stack(v_rows)  # [La, S, nkv, hd]
+            kr, vr = _stacked(rows)                        # [La, S, nkv, hd]
             if int8_kv:
                 (kq, ks), (vq, vs) = _quant_kv(kr), _quant_kv(vr)
                 rows = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
             else:
                 rows = {"k": kr.astype(pools["k"].dtype),
                         "v": vr.astype(pools["v"].dtype)}
-            pools.update(_scatter_rows(
+            pools.update(_write_rows(
                 {n: pools[n] for n in rows}, blk, off, rows))
     return _head(params, x, cfg)[:, 0], pools

@@ -78,11 +78,12 @@ def _finish(p, y, x, z, cfg):
 def _conv(p, ext, cfg):
     """ext [K - 1 + T, conv_dim]: the sequence behind its K - 1 earlier rows
     -> silu(conv) [T, conv_dim]. ``conv_w[k]`` multiplies the row K-1-k
-    positions back."""
+    positions back; ``conv_b`` where the mixer has a bias (the Gated DeltaNet
+    mixer, ``models/gated_deltanet.py``, has none)."""
     K = cfg.conv_kernel
     T = ext.shape[0] - (K - 1)
     w = p["conv_w"].astype(jnp.float32)
-    acc = p["conv_b"].astype(jnp.float32)[None, :]
+    acc = p["conv_b"].astype(jnp.float32)[None, :] if "conv_b" in p else 0.0
     for k in range(K):
         acc = acc + ext[k:k + T].astype(jnp.float32) * w[k][None, :]
     return jax.nn.silu(acc).astype(ext.dtype)

@@ -79,6 +79,13 @@ class TransformerConfig:
     # k projection (`q_norm` [nh*hd], `k_norm` [nkv*hd]), before the split
     # into heads and before RoPE
     qk_norm: bool = False
+    # ARCHITECTURE (qwen3_next, the attention blocks of a hybrid stack):
+    # RMSNorm of q and of k over each HEAD's dims (`q_norm`, `k_norm` [hd])
+    # before rotary, and a sigmoid gate on the attention output whose
+    # columns are projected beside q (`wq` [H, heads x 2 x hd], a head's
+    # columns [q | gate]): out = wo (attn . sigmoid(gate))
+    qk_norm_per_head: bool = False
+    attn_out_gate: bool = False
     tie_embeddings: bool = True
     dropout_rate: float = 0.0
     dtype: Any = jnp.bfloat16                   # activation/compute dtype
@@ -139,6 +146,18 @@ class TransformerConfig:
     moe_scoring: str = "softmax"                # softmax | sigmoid
     routed_scaling_factor: float = 1.0
     moe_shared_size: int = 0
+    # `moe_shared_gate` (qwen3_next): the shared expert's output is scaled
+    # per token by sigmoid(w_s . x) (`shared_gate` [H]).
+    moe_shared_gate: bool = False
+    # THE CHIP'S SHARE of a deployment's experts (models/hybrid.py stacks
+    # only): the router scores `moe_router_experts` experts (None: all of
+    # `num_experts`, every other configuration), the stacks hold
+    # `num_experts` of them, the experts `moe_held_first` .. + num_experts
+    # - 1. The expert layer routes over all, weighs over all the chosen and
+    # computes the held experts' part of the result; what the absent ones
+    # would add is added on no chip here (moe/sharded_moe.py moe_ffn).
+    moe_router_experts: Optional[int] = None
+    moe_held_first: int = 0
     # ARCHITECTURE (nemotron_h): a HYBRID stack. One letter a block — "M" a
     # Mamba-2 mixer, "E" an expert feed-forward, "*" attention — and every
     # block is h + mixer(norm(h)): ONE mixer, not attention + FFN. None is
@@ -147,7 +166,18 @@ class TransformerConfig:
     # width, `mamba_n_groups` B/C groups of `ssm_state_size`, a causal
     # depthwise convolution of `conv_kernel`, a chunked scan of
     # `mamba_chunk`); time_step_* shape the initialisation of dt_bias only.
+    # "G" (qwen3_next) is a Gated DeltaNet mixer, models/gated_deltanet.py:
+    # `gdn_num_k_heads` key heads of `gdn_head_k_dim` serving
+    # `gdn_num_v_heads` value heads of `gdn_head_v_dim`, a float32 matrix
+    # state [dk, dv] per value head, the same `conv_kernel`, chunks of
+    # `gdn_chunk` in prefill. A hybrid stack's attention may be rotary
+    # (`position_type`, `rotary_dim`).
     block_pattern: Optional[str] = None
+    gdn_num_k_heads: int = 0
+    gdn_num_v_heads: int = 0
+    gdn_head_k_dim: int = 0
+    gdn_head_v_dim: int = 0
+    gdn_chunk: int = 64
     mamba_num_heads: int = 0
     mamba_head_dim: int = 0
     mamba_n_groups: int = 1
@@ -227,9 +257,16 @@ class TransformerConfig:
 
     @property
     def recurrent_blocks(self) -> int:
-        """Blocks that keep a recurrent state per serving slot (the "M"
-        blocks of a hybrid stack); 0 for every homogeneous model."""
-        return (self.block_pattern or "").count("M")
+        """Blocks that keep a recurrent state per serving slot (the "M" and
+        "G" blocks of a hybrid stack); 0 for every homogeneous model."""
+        pattern = self.block_pattern or ""
+        return pattern.count("M") + pattern.count("G")
+
+    @property
+    def moe_router_width(self) -> int:
+        """Experts the router scores: the model's, of which this chip holds
+        ``num_experts``."""
+        return self.moe_router_experts or self.num_experts
 
     @property
     def attention_blocks(self) -> int:

@@ -14,12 +14,27 @@ Routing is one scoring in float32 and one ``lax.top_k`` whatever k is
 all experts (Mixtral, OLMoE), or a sigmoid per expert whose CHOICE adds a
 stored correction bias and whose WEIGHTS are the plain scores, times
 ``cfg.routed_scaling_factor`` (``nemotron_h``). The kept weights are divided
-by their sum where the model says so (``cfg.norm_topk_prob``: Mixtral and
-``nemotron_h`` do, OLMoE does not). The experts are gated (SwiGLU) when the
-stack has a ``w_gate``, plain otherwise, with the activation the model names
-(``cfg.activation``: ``relu2`` is ``relu(x)^2``), and a ``shared_w_in`` /
-``shared_w_out`` pair in the parameters is an expert of the same form that
-EVERY token passes, added unweighted (named scope ``moe/shared``).
+by their sum where the model says so (``cfg.norm_topk_prob``: Mixtral,
+``nemotron_h`` and ``qwen3_next`` do, OLMoE does not). The experts are gated
+(SwiGLU) when the stack has a ``w_gate``, plain otherwise, with the
+activation the model names (``cfg.activation``: ``relu2`` is ``relu(x)^2``),
+and a ``shared_w_in`` / ``shared_w_out`` pair in the parameters is an expert
+of the same form (gated when there is a ``shared_w_gate``) that EVERY token
+passes, added unweighted or, with a ``shared_gate`` vector, scaled per token
+by ``sigmoid(w_s . x)`` (named scope ``moe/shared``).
+
+**The chip's share.** The router's width (``wg``'s columns) and the experts
+held (the stacks' leading dim) are two numbers. Where they differ the layer
+holds experts ``cfg.moe_held_first .. + held - 1`` of a deployment that
+splits each layer's experts over chips: it scores ALL the experts, takes the
+top-k of all, normalises the weights over all the k chosen, and computes the
+part of the result that the chosen experts IT HOLDS give (plus the shared
+expert, which every chip computes alike). The other chips' parts and the
+exchange that would add them are not here: on one chip the layer returns its
+partial result. Both dispatches do this by numbering the held experts 0 ..
+held - 1 and giving an assignment elsewhere no expert (a zero one-hot row /
+a row past the last group); the load row counts the held experts and, last,
+ALL the assignments asked for.
 
 Dispatches, chosen by what the model IS (``cfg.drop_tokens``) and by the
 call's shapes, not by an option of their own:
@@ -81,8 +96,10 @@ def _capacity(num_tokens: int, num_experts: int, capacity_factor: float,
 class _LoadTap:
     """What one ``expert_load_tap`` block collected, at TRACE time: one
     int32 ``[E + 1]`` row per MoE layer called inside it — assignments KEPT
-    per expert, then the assignments ASKED for (counted tokens x k), so
-    ``1 - kept / asked`` is the dropped share."""
+    per expert (E the experts HELD), then the assignments ASKED for (counted
+    tokens x k), so ``1 - kept / asked`` is the dropped share where every
+    expert is held, and ``kept / asked`` the share of the assignments that
+    land on this chip where it holds a part."""
 
     def __init__(self):
         self.rows: List[jnp.ndarray] = []
@@ -227,7 +244,7 @@ def _switch_aux(gates, first_choice):
 def top_k_gating(logits, k: int, capacity: int, *, rng=None,
                  noise_policy: Optional[str] = None, train: bool = True,
                  renormalize: bool = True, scoring: str = "softmax",
-                 bias=None, scale: float = 1.0):
+                 bias=None, scale: float = 1.0, held=None):
     """Compute dispatch/combine tensors with capacity limits, for any k.
 
     logits: [T, E]. Returns (combine [T,E,C] f32, dispatch [T,E,C] bool,
@@ -237,6 +254,11 @@ def top_k_gating(logits, k: int, capacity: int, *, rng=None,
     over capacity dropped; aux loss = E * mean(gates_e) * mean(assignment_e)
     summed over experts (switch loss). With ``renormalize`` the weights that
     SURVIVE the capacity are divided by their sum (reference: top2 denom).
+
+    ``held`` = (first, count): the masks cover the ``count`` experts from
+    ``first`` only (``[T, count, C]``); an assignment to an expert
+    elsewhere takes no place here and is never dropped here, so its weight
+    stays in the sum the kept weights are divided by.
     """
     T, E = logits.shape
     # the division happens after the drop, over the survivors
@@ -251,7 +273,11 @@ def top_k_gating(logits, k: int, capacity: int, *, rng=None,
     # pre-drop count of the earlier ones (reference top2gating offsets
     # locations2 by sum(mask1)): choice-2 tokens must not reuse slots freed
     # by dropped choice-1 tokens, or drop statistics diverge.
-    onehot = jax.nn.one_hot(experts.T, E, dtype=jnp.float32)     # [k, T, E]
+    if held is None:
+        onehot = jax.nn.one_hot(experts.T, E, dtype=jnp.float32)  # [k, T, E]
+    else:            # an index outside 0 .. count - 1 is a row of zeros
+        first, E = held
+        onehot = jax.nn.one_hot(experts.T - first, E, dtype=jnp.float32)
     flat = onehot.reshape(k * T, E)
     pos = jnp.sum((jnp.cumsum(flat, axis=0) - 1.0) * flat, axis=-1)
     pos = pos.astype(jnp.int32).reshape(k, T)
@@ -369,15 +395,15 @@ def _use_gmm_kernel(moe_params, dtype) -> bool:
 
 
 def _one_hot_ffn(moe_params, tokens, logits, cfg, C: int, rng, train,
-                  expert_axis):
+                  expert_axis, held=None):
     """Dispatch by one-hot [T, E, C] masks; tokens over capacity dropped
-    (none at C = T)."""
+    (none at C = T). ``held``: ``top_k_gating``'s."""
     dt = tokens.dtype
     with jax.named_scope("route"):
         combine, dispatch, aux, metrics = top_k_gating(
             logits, cfg.top_k, C, rng=rng, noise_policy=cfg.noisy_gate_policy,
             train=train, renormalize=cfg.norm_topk_prob,
-            **_routing(moe_params, cfg))
+            held=held, **_routing(moe_params, cfg))
         if _STATE.taps:
             _tap_load(metrics["kept"], cfg.top_k)
     # dispatch: [T,E,C] x [T,H] -> [E,C,H]; GSPMD all-to-alls tokens to the
@@ -401,9 +427,11 @@ def _one_hot_ffn(moe_params, tokens, logits, cfg, C: int, rng, train,
     return y, aux
 
 
-def _sorted_ffn(moe_params, tokens, logits, cfg, rng, train):
+def _sorted_ffn(moe_params, tokens, logits, cfg, rng, train, held=None):
     """Dispatch by sorting the T*k (token, expert) pairs by expert: every
-    token reaches all k of its experts, and the work is T*k rows."""
+    token reaches all k of its experts, and the work is T*k rows. ``held`` =
+    (first, count): pairs whose expert is elsewhere sort behind the last
+    group, where the grouped matmul computes nothing, and add nothing."""
     T, H = tokens.shape
     E, k, dt = logits.shape[-1], cfg.top_k, tokens.dtype
     with jax.named_scope("route"):
@@ -412,6 +440,11 @@ def _sorted_ffn(moe_params, tokens, logits, cfg, rng, train):
             noise_policy=cfg.noisy_gate_policy, train=train,
             **_routing(moe_params, cfg))
         aux, _ = _switch_aux(gates, experts[:, 0])
+        if held is not None:
+            first, E = held
+            mine = (experts >= first) & (experts < first + E)
+            experts = jnp.where(mine, experts - first, E)
+            weights = jnp.where(mine, weights, 0.0)
     with jax.named_scope("dispatch"):
         flat = experts.reshape(T * k)
         onehot = jax.nn.one_hot(flat, E, dtype=jnp.int32)         # [T*k, E]
@@ -433,13 +466,18 @@ def _sorted_ffn(moe_params, tokens, logits, cfg, rng, train):
         # back to (token, choice) order, then the weighted sum over a
         # token's k rows in float32
         per_choice = jnp.take(rows_out, jnp.argsort(order), axis=0)
+        if held is not None:         # rows past the groups are undefined
+            per_choice = jnp.where(mine.reshape(T * k, 1), per_choice, 0)
         y = jnp.sum(per_choice.reshape(T, k, H).astype(jnp.float32)
                     * weights[..., None], axis=1).astype(dt)
     return y, aux
 
 
-def _one_hot_is_cheaper(T: int, E: int, k: int) -> bool:
-    """Which dropless dispatch a call of T tokens takes, from its shapes.
+def _one_hot_is_cheaper(T: int, E: int, k: float) -> bool:
+    """Which dropless dispatch a call of T tokens takes, from its shapes: E
+    the experts held, k the assignments a token is expected to have on them
+    (``top_k`` where all are held, ``top_k x held / router width`` on a
+    share).
 
     Both stream each expert's matrices; they differ in what they multiply.
     The one-hot masks with capacity = T give EVERY expert all T rows: E
@@ -456,10 +494,11 @@ def _one_hot_is_cheaper(T: int, E: int, k: int) -> bool:
     from deepspeed_tpu.ops.grouped_matmul import (WEIGHT_BOUND_ROWS, row_tile,
                                                   visit_cost)
     one_hot = E * max(1.0, T / WEIGHT_BOUND_ROWS)
-    return one_hot <= visit_cost(T * k, E, row_tile(T * k, E))
+    rows = math.ceil(T * k)
+    return one_hot <= visit_cost(rows, E, row_tile(rows, E))
 
 
-def _sorts(T: int, E: int, k: int, train: bool) -> bool:
+def _sorts(T: int, E: int, k: float, train: bool) -> bool:
     """Whether a dropless call of T tokens sorts. Only at inference on ONE
     device, and only past ``_one_hot_is_cheaper``: the sorted dispatch sets
     no sharding constraint and has never been compiled with the experts
@@ -480,35 +519,61 @@ def moe_ffn(moe_params, x, cfg, *, rng=None, train: bool = True,
     128 grid is served from: ``ops/grouped_matmul.grouped_matmul``),
     "w_out": [E, F, H], optional "w_gate": [E, H, F], "e_bias": [E] (the
     sigmoid scoring's correction bias), "shared_w_in" [H, Fs] and
-    "shared_w_out" [Fs, H] (an always-on expert)}.
+    "shared_w_out" [Fs, H] (an always-on expert; with "shared_w_gate" [H,
+    Fs] a gated one, with "shared_gate" [H] scaled per token by
+    sigmoid(shared_gate . x))}.
+
+    ``wg`` has one column per expert of the MODEL, the stacks one matrix per
+    expert HELD. Where the two differ (``wg`` [H, R], R > E) the stacks hold
+    experts ``cfg.moe_held_first .. + E - 1``: the router scores and chooses
+    over all R, the weights are normalised over all the k chosen, and y is
+    the shared expert plus the sum over the chosen experts that are HELD —
+    this chip's part of the layer's result (module docstring).
     Returns (y [B,S,H], aux_loss scalar).
     """
     B, S, H = x.shape
-    E = moe_params["wg"].shape[-1]
+    R = moe_params["wg"].shape[-1]            # experts the router scores
+    w = _w_in(moe_params)[0]
+    E = (w.stack.shape[1] if isinstance(w, LayerOf) else w.shape[0])
+    # None where every expert is held: the program of every such model is
+    # the one it was before the share existed
+    held = None if E == R else (int(cfg.moe_held_first), E)
+    if held and not 0 <= held[0] <= R - E:
+        raise ValueError(f"experts {held[0]} .. {held[0] + E - 1} held of "
+                         f"the router's {R}")
     T = B * S
     tokens = x.reshape(T, H)
     with jax.named_scope("route"):
         logits = tokens.astype(jnp.float32) @ moe_params["wg"].astype(jnp.float32)
     if cfg.drop_tokens:
         cf = cfg.capacity_factor if train else cfg.eval_capacity_factor
-        C = _capacity(T, E, cf, cfg.min_capacity)
+        C = _capacity(T, R, cf, cfg.min_capacity)
         form = "capacity"
         y, aux = _one_hot_ffn(moe_params, tokens, logits, cfg, C, rng, train,
-                               expert_axis)
-    elif _sorts(T, E, cfg.top_k, train):
+                               expert_axis, held)
+    elif _sorts(T, E, cfg.top_k if held is None else cfg.top_k * E / R,
+                train):
         form = ("sorted/moe_gmm" if _use_gmm_kernel(moe_params, tokens.dtype)
                 else "sorted/ragged_dot")
-        y, aux = _sorted_ffn(moe_params, tokens, logits, cfg, rng, train)
+        y, aux = _sorted_ffn(moe_params, tokens, logits, cfg, rng, train, held)
     else:
         # capacity = tokens: the masks drop nothing
         form = "one-hot"
         y, aux = _one_hot_ffn(moe_params, tokens, logits, cfg, T, rng, train,
-                               expert_axis)
+                               expert_axis, held)
     for tap in _STATE.taps:       # a serving engine reports it (`stats()`)
         tap.form = form
     if "shared_w_in" in moe_params:
         with jax.named_scope("shared"):
             up = tokens @ moe_params["shared_w_in"].astype(tokens.dtype)
-            y = y + _glu_or_gelu(up, None, cfg.activation) \
+            gate = (tokens @ moe_params["shared_w_gate"].astype(tokens.dtype)
+                    if "shared_w_gate" in moe_params else None)
+            out = _glu_or_gelu(up, gate, cfg.activation) \
                 @ moe_params["shared_w_out"].astype(tokens.dtype)
+            if "shared_gate" in moe_params:
+                on = jax.nn.sigmoid(
+                    tokens.astype(jnp.float32)
+                    @ moe_params["shared_gate"].astype(jnp.float32))
+                out = out * on[:, None].astype(out.dtype)
+            y = y + out
     return y.reshape(B, S, H), aux

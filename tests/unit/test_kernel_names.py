@@ -21,6 +21,7 @@ import pytest
 
 from deepspeed_tpu.ops import decode_attention as da
 from deepspeed_tpu.ops import flash_attention as fa
+from deepspeed_tpu.ops import gated_delta as gd
 from deepspeed_tpu.ops import sparse_attention as sa
 from deepspeed_tpu.ops import ssm
 
@@ -127,6 +128,49 @@ def test_the_hybrid_scopes_in_the_lowered_text():
     assert re.search(r'"jit\([^)]*\)/attn/kv_write/scatter"', prefill)
 
 
+def test_gdn_kernel_names_and_scopes_in_the_lowered_text():
+    """``%gdn_chunk`` / ``%gdn_step`` by name, and ``gdn/conv``, ``gdn/chunk``
+    | ``gdn/step``, ``gdn/state_write``, ``gdn/gate_norm``, ``attn/out_gate``
+    and ``moe/shared`` in the programs a serving engine builds for a
+    ``qwen3_next`` model."""
+    import os
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))))
+    from benchmark.families.qwen3_next import TOY
+    from deepspeed_tpu.models import make_model
+    from deepspeed_tpu.models.hf_import import hf_config_to_transformer
+    f32 = jnp.float32
+    z = lambda *s: jnp.zeros(s, f32)                          # noqa: E731
+    chunk = jax.jit(lambda *a: gd.gdn_chunk(*a, chunk=16, kernel=True)).lower(
+        z(32, 2, 16), z(32, 2, 16), z(32, 4, 16), z(32, 4), z(32, 4),
+        z(4, 16, 16)).as_text(debug_info=True)
+    assert re.search(r'"jit\([^)]*\)/gdn_chunk/pallas_call"', chunk)
+    step = jax.jit(lambda pool, *a: gd.gdn_step(pool, 1, *a, kernel=True)).lower(
+        z(2, 3, 4, 16, 16), z(3, 2, 16), z(3, 2, 16), z(3, 4, 16), z(3, 4),
+        z(3, 4)).as_text(debug_info=True)
+    assert re.search(r'"jit\([^)]*\)/gdn_step/pallas_call"', step)
+    hf = {"model_type": "qwen3_next", "max_position_embeddings": 256,
+          "num_experts_per_tok": 4, **TOY}
+    model = make_model(hf_config_to_transformer(hf, dtype=f32))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    pools = jax.eval_shape(lambda: model.init_paged_cache(
+        9, 16, dtype=f32, max_seqs=2))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)       # noqa: E731
+    step = jax.jit(model.decode_step_paged).lower(
+        params, i32(2), pools, i32(2, 4), i32(2)).as_text(debug_info=True)
+    prefill = jax.jit(model.prefill_paged).lower(
+        params, i32(1, 32), pools, i32(2), length=i32(), slot=i32()
+    ).as_text(debug_info=True)
+    for text, scopes in (
+            (step, ("gdn/conv", "gdn/step", "gdn/gate_norm", "attn/out_gate",
+                    "moe/shared")),
+            (prefill, ("gdn/conv", "gdn/chunk", "gdn/state_write",
+                       "gdn/gate_norm", "attn/out_gate", "moe/shared"))):
+        for scope in scopes:
+            assert re.search(rf'/layer\d/{scope}/', text), scope
+
+
 # ---- the instruction names the TPU's compiler gives -------------------------
 
 @pytest.fixture()
@@ -139,6 +183,7 @@ def mosaic(monkeypatch):
     monkeypatch.setattr(fa, "_interpret", lambda: False)
     monkeypatch.setattr(da, "_interpret", lambda: False)
     monkeypatch.setattr(ssm, "_interpret", lambda: False)
+    monkeypatch.setattr(gd, "_interpret", lambda: False)
     with jax.default_matmul_precision("default"):
         yield
 
@@ -212,4 +257,28 @@ def test_ssm_instruction_names_at_the_published_sizes(topo, mosaic):
     assert [c.split(".")[0] for c in _mosaic_calls(step)] == ["%ssm_step"]
     mem = step.memory_analysis()
     assert mem.alias_size_in_bytes == 4 * S * H * P * N * 4
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20
+
+
+def test_gdn_instruction_names_at_the_published_sizes(topo, mosaic):
+    """Qwen3-Next's Gated DeltaNet blocks: 16 key heads serving 32 value
+    heads of 128 x 128; a 1024-token prompt in chunks of 64, and one step
+    over 128 slots whose state pool is updated in place (the call's output
+    aliases the donated pool: no temporary of the pool's size)."""
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    bf, f32 = jnp.bfloat16, jnp.float32
+    T, Hk, Hv, dk, dv, S = 1024, 16, 32, 128, 128, 128
+    chunk = jax.jit(lambda *a: gd.gdn_chunk(*a, chunk=64, kernel=True)).lower(
+        _sds((T, Hk, dk), bf, one), _sds((T, Hk, dk), bf, one),
+        _sds((T, Hv, dv), bf, one), _sds((T, Hv), f32, one),
+        _sds((T, Hv), f32, one), _sds((Hv, dk, dv), f32, one)).compile()
+    assert [c.split(".")[0] for c in _mosaic_calls(chunk)] == ["%gdn_chunk"]
+    step = jax.jit(lambda pool, *a: gd.gdn_step(pool, 2, *a, kernel=True),
+                   donate_argnums=(0,)).lower(
+        _sds((9, S, Hv, dk, dv), f32, one), _sds((S, Hk, dk), bf, one),
+        _sds((S, Hk, dk), bf, one), _sds((S, Hv, dv), bf, one),
+        _sds((S, Hv), f32, one), _sds((S, Hv), f32, one)).compile()
+    assert [c.split(".")[0] for c in _mosaic_calls(step)] == ["%gdn_step"]
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes == 9 * S * Hv * dk * dv * 4
     assert mem.temp_size_in_bytes < 64 * 2 ** 20

@@ -1,0 +1,115 @@
+"""The serving and training programs of the families the benchmark already
+ran are, as lowered TEXT, what they were at the commit before PR 40 (the
+expert layer's held range, the hybrid walker's period scan, the K/V writer per
+(plane, head), rotary / q-k norm / output gate in a hybrid attention block
+all default to the program it was).
+
+For each family's toy widths (``benchmark/families/<model_type>.TOY``, what a
+``--rehearsal`` runs): the decode step, two prompt buckets of the prefill and
+the no-cache forward, lowered for abstract arguments (nothing is compiled or
+run) and hashed. ``GOLDEN`` was printed by THIS file run against a checkout of
+the parent commit ``bdfc2d3`` (``python tests/unit/test_program_text.py
+<checkout>``), jax 0.9.0. A PR that means to change one of these programs
+prints the table again from its own tree and says which rows moved and why; a
+PR that does not has the parent's text letter for letter.
+"""
+import functools
+import hashlib
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+CONFIGS = ("mistral-7b-serve", "mixtral-8x7b-serve", "olmoe-1b-7b-serve",
+           "nemotron-3-nano-30b-serve", "ouro-2.6b-serve")
+PROGRAMS = ("step", "prefill32", "prefill128", "forward")
+GOLDEN = {
+    "mistral-7b-serve": {
+        "step": "050edea2bb48db05", "prefill32": "40748e39cf90df13",
+        "prefill128": "e8c63a26dd58a410", "forward": "07b44c1bda28a187"},
+    "mixtral-8x7b-serve": {
+        "step": "56e1f8bb3482451e", "prefill32": "7ddfdcf887781e68",
+        "prefill128": "18ecf3f5d2a8f210", "forward": "dd490bcb5efa13b1"},
+    "olmoe-1b-7b-serve": {
+        "step": "95a0e4a3ef4f8f1d", "prefill32": "8306be4eb9138aae",
+        "prefill128": "d43a0d903a239b6a", "forward": "c545e469c97acb9b"},
+    "nemotron-3-nano-30b-serve": {
+        "step": "1acd3b9372fd7517", "prefill32": "efe837a6e02bed9a",
+        "prefill128": "8a4ca89d7fca6ca0", "forward": "cda6540c5ea4d05a"},
+    "ouro-2.6b-serve": {
+        "step": "7017150c3367e0e7", "prefill32": "6bb86f01468a6b01",
+        "prefill128": "906f6315e0dd77a9", "forward": "ac33f278ea46fd12"},
+}
+
+
+def lowered(name):
+    """{program: sha256 of its lowered text} at the configuration's toy
+    widths, from whatever tree is first on ``sys.path``."""
+    import jax
+    import jax.numpy as jnp
+    from benchmark.harness import common
+    from deepspeed_tpu.models import make_model
+    from deepspeed_tpu.models.hf_import import hf_config_to_transformer
+    from deepspeed_tpu.moe.sharded_moe import expert_load_tap
+
+    cfgf = common.load_config(name)
+    cfg = hf_config_to_transformer(common.hf_of(cfgf, rehearsal=True),
+                                   max_seq_len=256,
+                                   **cfgf["run"].get("overrides", {}))
+    model = make_model(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    slotted = {"max_seqs": 4} if cfg.block_pattern else {}
+    pools = jax.eval_shape(
+        lambda: model.init_paged_cache(33, 16, dtype=jnp.float32, **slotted))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    def step(p, tok, pools, tab, lens):
+        with expert_load_tap() as tap:
+            logits, pools = model.decode_step_paged(p, tok, pools, tab, lens)
+        return logits, pools, tap.stacked()
+
+    def prefill(p, ids, pools, blk, n, *slot):
+        with expert_load_tap() as tap:
+            logits, pools = model.prefill_paged(
+                p, ids, pools, blk, length=n,
+                **({"slot": slot[0]} if slot else {}))
+        return logits, pools, tap.stacked()
+
+    texts = {"step": jax.jit(step).lower(
+        params, i32(4), pools, i32(4, 8), i32(4)).as_text()}
+    for P in (32, 128):
+        texts[f"prefill{P}"] = jax.jit(prefill).lower(
+            params, i32(1, P), pools, i32(P // 16), i32(),
+            *((i32(),) if cfg.block_pattern else ())).as_text()
+    texts["forward"] = jax.jit(lambda p, ids: model.apply(p, ids)).lower(
+        params, i32(2, 64)).as_text()
+    return {k: hashlib.sha256(t.encode()).hexdigest()[:16]
+            for k, t in texts.items()}
+
+
+@pytest.fixture(scope="module")
+def hashes():
+    sys.path.insert(0, ROOT)
+    return functools.lru_cache(maxsize=None)(lowered)
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+@pytest.mark.parametrize("name", CONFIGS)
+def test_the_program_is_the_text_it_was(name, program, hashes):
+    assert hashes(name)[program] == GOLDEN[name][program], (
+        f"{name} {program}: the lowered text moved; if that is meant, print "
+        "GOLDEN again (this file's docstring) and say why")
+
+
+if __name__ == "__main__":
+    tree = os.path.abspath(sys.argv[1]) if len(sys.argv) > 1 else ROOT
+    sys.path[:0] = [tree, os.path.join(tree, "tests")]
+    import conftest  # noqa: F401  (the suite's devices and matmul precision)
+    import deepspeed_tpu
+    print("# from", os.path.dirname(os.path.dirname(deepspeed_tpu.__file__)))
+    for name in CONFIGS:
+        print(f'    "{name}": {lowered(name)!r},')
