@@ -452,6 +452,13 @@ def _slot_ladder(max_seqs: int) -> tuple:
     return (few, max_seqs) if 2 * few < max_seqs else (max_seqs,)
 
 
+# Prompts one prefill program takes in its row (``_get_prefill_fn``): the
+# static length of its ``starts`` / ``lengths``. A constant of the program's
+# shape, not a setting: a round admits two or three prompts where slots are
+# kept full, and the row is no longer than the longest bucket built.
+_SEGMENTS = 4
+
+
 # expert-routing counters of a stats window (ServingEngine._note_counters)
 _MOE_COUNTERS = {"kept": 0, "asked": 0, "max_over_mean": 0.0, "rounds": 0,
                  "touched": 0.0, "steps": 0, "prefill_touched": 0.0,
@@ -461,6 +468,10 @@ _MOE_COUNTERS = {"kept": 0, "asked": 0, "max_over_mean": 0.0, "rounds": 0,
 # latency-tier and round-order counters of a stats window
 _LAT_COUNTERS = {"spec_steps": 0, "spec_proposed": 0, "spec_accepted": 0,
                  "prefill_chunks": 0, "prefill_chunk_tokens": 0,
+                 # whole prompts prefilled, the programs that took them and
+                 # the prompts that shared a program's row (_pack_prefills)
+                 "prefill_prompts": 0, "prefill_programs": 0,
+                 "prefill_packed_prompts": 0,
                  "cow_forks": 0,
                  # plain decode rounds dispatched while the round before was
                  # still unfetched (of the rounds step_shape_rounds counts),
@@ -1157,28 +1168,47 @@ class ServingEngine:
 
     def _get_prefill_fn(self, P: int):
         """One compile per prompt bucket P: prefill + block scatter + first
-        sampled token, all one program (one dispatch per admission)."""
+        sampled tokens, all one program (one dispatch per row). The row
+        holds up to ``_SEGMENTS`` prompts, each from a block's edge
+        (``starts``, ``lengths``; a segment of length 0 is not there): the
+        ONE program of a bucket serves a prompt alone and the prompts of a
+        round that ``_pack_prefills`` put together, and it returns a first
+        token per segment, each an output of its own, so that nothing on
+        the device is first met when a row is shared. A model that keeps a
+        recurrent state per slot prefills one prompt, into its slot."""
         fn = self._prefill_fns.get(P)
         if fn is None:
             import jax
             from deepspeed_tpu.models.looped import exit_tap
             from deepspeed_tpu.moe.sharded_moe import expert_load_tap
 
-            def prefill(params, ids, pools, block_ids, length, key, *slot):
-                # slot: the request's slot, for a model that keeps a
-                # recurrent state per slot; nothing for every other model
+            def run(params, ids, pools, block_ids, key, **prompts):
                 with expert_load_tap() as tap, exit_tap() as gate:
                     last, pools = self.model.prefill_paged(
-                        params, ids, pools, block_ids, length=length,
-                        **({"slot": slot[0]} if slot else {}))
+                        params, ids, pools, block_ids, **prompts)
                 self._moe_forms[f"prefill_{P}"] = tap.form      # trace time
-                # the first token travels with the program's counters: the
-                # expert load [L, E + 1] of the REAL prompt tokens (None for
-                # a model without experts) and the exit distribution
-                # [passes + 1] of the position it was sampled at (None for
-                # a model that is not looped)
+                # the first tokens travel with the program's counters: the
+                # expert load [L, E + 1] of the REAL prompt tokens of every
+                # segment (None for a model without experts) and the exit
+                # distribution [passes + 1] summed over the positions they
+                # were sampled at (None for a model that is not looped)
                 return (self._sample(last, key),
-                        (tap.stacked(), gate.summed())), pools
+                        (tap.stacked(), gate.summed()), pools)
+
+            if self._recurrent:
+                def prefill(params, ids, pools, block_ids, length, key, slot):
+                    # slot: the request's slot, whose recurrent state the
+                    # prompt overwrites
+                    toks, counters, pools = run(params, ids, pools, block_ids,
+                                                key, length=length, slot=slot)
+                    return (toks, counters), pools
+            else:
+                def prefill(params, ids, pools, block_ids, starts, lengths,
+                            key):
+                    toks, counters, pools = run(params, ids, pools, block_ids,
+                                                key, segments=(starts, lengths))
+                    return (tuple(toks[k] for k in range(_SEGMENTS)),
+                            counters), pools
 
             outs = ((self._repl_sharding, self._pool_shardings)
                     if self._pool_shardings is not None else None)
@@ -1495,41 +1525,89 @@ class ServingEngine:
                 self._stats_t0 = req.submit_t
             return req.rid
 
-    def _dispatch_prefill(self, req: Request):
-        """Dispatch (no sync) the request's (re-)prefill: writes its
-        context rows into its blocks, leaves the next sampled token pending
-        in the device token vector AND as a per-request handle fetched at
-        the round boundary. Returns the padded prompt length."""
+    def _pack_prefills(self, reqs: List[Request]) -> list:
+        """A round's whole-prompt prefills as ``[(bucket, [requests])]``,
+        one prefill program each. Prompts share a program's row only where
+        that compiles nothing and hides no compile: a prompt is a candidate
+        if the program of ITS OWN bucket is built; candidates go first-fit,
+        longest first, each padded to whole blocks, into rows no longer
+        than the longest bucket built, ``_SEGMENTS`` to a row, and a shared
+        row runs at the smallest built bucket that holds it. A prompt whose
+        bucket is not built runs alone and builds it, so an engine that has
+        built none — a warm-up that sends a prompt a bucket in one round —
+        builds them all. Never for a model with a recurrent state per slot:
+        its scans run the whole row from a zero state."""
+        bs = self.config.block_size
+        built = sorted(self._prefill_fns)
+        alone, rows = [], []            # rows: [tokens of its blocks, requests]
+        for req in sorted(reqs, key=lambda req: -len(req.context)):
+            P = self._pad_prompt(len(req.context))
+            if self._recurrent or P not in self._prefill_fns:
+                alone.append((P, [req]))
+                continue
+            n = blocks_for(len(req.context), bs) * bs
+            row = next((row for row in rows if len(row[1]) < _SEGMENTS
+                        and row[0] + n <= built[-1]), None)
+            if row is None:
+                rows.append([n, [req]])
+            else:
+                row[0] += n
+                row[1].append(req)
+        # a row of one prompt is at its own bucket: the smallest that holds it
+        return alone + [(next(P for P in built if P >= n), row)
+                        for n, row in rows]
+
+    def _dispatch_prefill(self, reqs: List[Request], P: int):
+        """Dispatch (no sync) the (re-)prefill of the requests of one row
+        of ``_pack_prefills`` as the program of bucket ``P``: writes their
+        context rows into their blocks, leaves each one's next sampled
+        token pending in the device token vector AND as a per-request
+        handle fetched at the round boundary. Returns the padded length."""
         import jax.numpy as jnp
-        ctx = req.context
-        P = self._pad_prompt(ctx.size)
+        bs = self.config.block_size
         buf = np.zeros((1, P), np.int32)
-        buf[0, :ctx.size] = ctx
-        nblk = P // self.config.block_size
-        block_ids = jnp.asarray(req.block_ids[:nblk], jnp.int32)
         # a bucket's first prompt traces and lowers its program: not across
         # a chunk boundary of the interpreter's frame stack
         fn = self._get_prefill_fn(P) if P in self._prefill_fns else \
             functools.partial(_in_one_chunk, self._get_prefill_fn(P))
+        if self._recurrent:
+            req, = reqs
+            buf[0, :req.context.size] = req.context
+            block_ids = req.block_ids[:P // bs]
+            what = (jnp.int32(req.context.size), self._next_key(),
+                    np.int32(req.slot))
+        else:
+            # every prompt from a block's edge, so the row's blocks are the
+            # requests' one after the other; the rows behind the last go
+            # to the trash block
+            starts = np.zeros(_SEGMENTS, np.int32)
+            lengths = np.zeros(_SEGMENTS, np.int32)
+            block_ids = []
+            for k, req in enumerate(reqs):
+                starts[k], lengths[k] = len(block_ids) * bs, req.context.size
+                buf[0, starts[k]:starts[k] + lengths[k]] = req.context
+                block_ids += req.block_ids[:blocks_for(lengths[k], bs)]
+            block_ids += [0] * (P // bs - len(block_ids))
+            what = (starts, lengths, self._next_key())
         with self.engine.mesh:
-            first, self.pools = fn(self.engine.params, jnp.asarray(buf),
-                                   self.pools, block_ids,
-                                   jnp.int32(ctx.size), self._next_key(),
-                                   *self._state_slot(req))
-        self._tokens = self._tokens.at[req.slot].set(first[0][0])
-        req.cached_rows = ctx.size
-        req.prefill_done = True
-        # (token, the prefill's counters): fetched at round boundary
-        req._first_dev = first
-        self._publish_prefill(req, ctx)
+            (toks, counters), self.pools = fn(
+                self.engine.params, jnp.asarray(buf), self.pools,
+                jnp.asarray(block_ids, jnp.int32), *what)
+        for k, req in enumerate(reqs):
+            tok = toks[0] if self._recurrent else toks[k]
+            self._tokens = self._tokens.at[req.slot].set(tok)
+            req.cached_rows = req.context.size
+            req.prefill_done = True
+            # (token, the program's counters — with ONE of its requests, so
+            # that every sum over requests stays a sum over programs):
+            # fetched at round boundary
+            req._first_dev = (tok, counters if k == 0 else (None, None))
+            self._publish_prefill(req, req.context)
+        self._lat["prefill_prompts"] += len(reqs)
+        self._lat["prefill_programs"] += 1
+        self._lat["prefill_packed_prompts"] += len(reqs) if len(reqs) > 1 \
+            else 0
         return P
-
-    def _state_slot(self, req: Request) -> tuple:
-        """What a prefill needs beyond the blocks: nothing, or — a model
-        with recurrent blocks — the request's slot, whose recurrent state
-        the prefill overwrites (a whole prompt from a zero state, so a slot
-        given again carries nothing of the last request)."""
-        return (np.int32(req.slot),) if self._recurrent else ()
 
     def _publish_prefill(self, req: Request, ctx) -> None:
         """Index a prefill's FULL blocks in the prefix cache as soon as
@@ -1782,8 +1860,10 @@ class ServingEngine:
         ``tokens`` and ``gc_ms``, the collections inside the round) beside
         what the round was: ``index``; ``t_s``, seconds into the stats
         window at its start; ``running_before``, the requests running when
-        it began; ``prefills`` / ``prefill_tokens``, the prompts or chunks
-        dispatched in it and their padded tokens; ``shape``, the ``(slots,
+        it began; ``prefills`` / ``prefill_programs`` / ``prefill_tokens``,
+        the prompts or chunks dispatched in it, the programs that took them
+        (fewer where whole prompts shared a row: ``_pack_prefills``) and
+        their padded tokens; ``shape``, the ``(slots,
         columns)`` of the decode round it dispatched, or None;
         ``empty_before_ms``, how long the engine had held nothing when it
         began; and ``ahead_covered``, the probe (``_dispatch_round``):
@@ -1823,7 +1903,8 @@ class ServingEngine:
               "t_s": (now - self._stats_t0
                       if self._stats_t0 is not None else 0.0),
               "running_before": len(self.scheduler.running),
-              "prefills": 0, "prefill_tokens": 0, "shape": None,
+              "prefills": 0, "prefill_programs": 0, "prefill_tokens": 0,
+              "shape": None,
               "ahead_covered": None,
               "empty_before_ms": self._empty_before_s * 1e3,
               "schedule_ms": 0.0, "housekeeping_ms": 0.0, "prefill_ms": 0.0,
@@ -1899,6 +1980,7 @@ class ServingEngine:
             ph["housekeeping_ms"] = sp.seconds * 1e3
             newest = None    # the newest array on the device's queue
             with span("ds:serve.prefill_dispatch") as sp:
+                whole = []
                 for req, start, n in decisions["prefill"]:
                     if req.state != "running":
                         continue     # bounced by the adapter-slot pin above
@@ -1906,22 +1988,34 @@ class ServingEngine:
                     if start == 0 and n == len(req.context) \
                             and not self._lora:
                         # whole prompt in one go: the PR-9 program (and its
-                        # warm compiles) — chunking/prefix hits take the
-                        # span. LoRA-armed engines route ALL prefills
+                        # warm compiles), below — chunking/prefix hits take
+                        # the span. LoRA-armed engines route ALL prefills
                         # through the span program: it carries the adapter
                         # delta, and one program family keeps the compile
                         # count flat
-                        with self._rspan(req.rid, "prefill", tokens=int(n),
-                                         reprefill=req.preemptions > 0):
-                            ph["prefill_tokens"] += self._dispatch_prefill(
-                                req)
-                    else:
-                        with self._rspan(req.rid, "prefill_chunk",
-                                         start=int(start), tokens=int(n)):
-                            ph["prefill_tokens"] += self._dispatch_chunk(
-                                req, start, n)
+                        whole.append(req)
+                        continue
+                    with self._rspan(req.rid, "prefill_chunk",
+                                     start=int(start), tokens=int(n)):
+                        ph["prefill_tokens"] += self._dispatch_chunk(
+                            req, start, n)
+                    ph["prefill_programs"] += 1
                     if getattr(req, "_first_dev", None) is not None:
                         newest = req._first_dev[0]
+                # the round's whole prompts share prefill programs where
+                # the programs are there: the weights are read once a
+                # program, not once a prompt
+                for P, reqs in self._pack_prefills(whole):
+                    with contextlib.ExitStack() as spans:
+                        for req in reqs:
+                            spans.enter_context(self._rspan(
+                                req.rid, "prefill", tokens=len(req.context),
+                                reprefill=req.preemptions > 0,
+                                packed=len(reqs)))
+                        ph["prefill_tokens"] += self._dispatch_prefill(reqs,
+                                                                       P)
+                    ph["prefill_programs"] += 1
+                    newest = reqs[-1]._first_dev[0]
             ph["prefill_ms"] = sp.seconds * 1e3
             prior = self._inflight
             if prior is None and not self.scheduler.running:
@@ -2218,7 +2312,7 @@ class ServingEngine:
         for (req, _), f in zip(pending, firsts):
             # prefill's pending token: its KV row is written by the next
             # step that decodes for it, so it is part of the sequence now
-            deliver(req, [int(np.asarray(f)[0])])
+            deliver(req, [int(np.ravel(f)[0])])
             req._first_dev = None
             if req.first_token_t is None:
                 req.first_token_t = now
@@ -3025,6 +3119,12 @@ class ServingEngine:
         ``exit_step_expected`` (the mean of sum_t (t + 1) p_t, in 1 ..
         ut_steps: the passes an exit policy at that distribution would run)
         and ``exit_cdf`` (mean cumulative p after each pass).
+
+        Whole-prompt prefills (always on; ``_pack_prefills``):
+        ``prefill_prompts`` (prompts the prefill program took whole; chunks
+        and LoRA spans are ``prefill_chunks``), ``prefill_programs`` (calls
+        of it: fewer than the prompts where a round's prompts shared a row)
+        and ``prefill_packed_prompts`` (the prompts that shared one).
 
         The two kinds of state (always on): ``kv_pool_bytes`` (the K/V block
         pool's share of ``pool_bytes``) and, for a model with recurrent

@@ -695,32 +695,68 @@ def attention(q, k, v, mask=None, *, causal: bool = True, cfg: TransformerConfig
 
     window: local-attention band width (key j visible to query i iff
     i - j < window); a traced scalar — <= 0 means global. Windowed layers
-    take the XLA path (the flash/ring/sparse kernels have no band mask)."""
-    B, S, Nq, D = q.shape
-    Nkv = k.shape[2]
+    take the XLA path (the flash/ring/sparse kernels have no band mask).
+
+    segment_ids: int [B, S], several sequences packed into a row, numbered
+    from 0 — key j is visible to query i only where the two ids are equal
+    (and causality, the band and ``mask`` allow it). The ids are traced, so
+    one program serves a row of one segment and a row of several. On the
+    XLA path they are one select more on the scores; the flash kernel runs
+    once a LIVE segment (``_attention_kernel``), so a row that is all one
+    segment costs what it costs without ids; the ring and sparse kernels
+    mask no keys and leave such rows to the XLA path."""
+    kernel = _attention_kernel(q, k, v, mask, causal, cfg, segment_ids, window)
+    if kernel is not None:
+        return kernel()
+    return _xla_attention(q, k, v, mask, causal, cfg, segment_ids, window)
+
+
+def _repeat_kv(k, v, Nq: int):
+    """GQA for the paths that are not GQA-native: the kv heads repeated."""
+    rep = Nq // k.shape[2]
+    if rep == 1:
+        return k, v
+    return jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+
+
+def _attention_kernel(q, k, v, mask, causal: bool, cfg: TransformerConfig,
+                      segment_ids, window):
+    """The kernel that takes these rows in place of the XLA path, as the
+    call to make — or None where none does."""
+    if window is not None:
+        return None
+    from deepspeed_tpu.parallel.context import seq_parallel_degree, current_mesh
+    S, Nq, D = q.shape[1:]
     sm = cfg.attn_scale if cfg.attn_scale is not None else 1.0 / math.sqrt(D)
     # the Pallas flash kernel is GQA-native (K/V never repeated in HBM) and
     # handles key-padding masks in-kernel; other paths get the repeated view
-    if _use_pallas(cfg, S) and segment_ids is None and window is None \
-            and not cfg.sparse_attention:
-        from deepspeed_tpu.parallel.context import seq_parallel_degree
-        if seq_parallel_degree() <= 1:
-            return _flash_per_shard(q, k, v, mask, causal=causal,
-                                    sm_scale=sm,
+    if _use_pallas(cfg, S) and not cfg.sparse_attention \
+            and seq_parallel_degree() <= 1:
+        def flash(keys):
+            return _flash_per_shard(q, k, v, keys, causal=causal, sm_scale=sm,
                                     fused_backward=cfg.fused_backward)
-    if Nkv != Nq:  # GQA: repeat kv heads
-        rep = Nq // Nkv
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
+
+        if segment_ids is None:
+            return lambda: flash(mask)
+
+        # the kernel masks keys, not pairs: one call a live segment, given
+        # that segment's keys alone, and every row keeps the call of its
+        # own segment. Forward only: the count of live segments is data
+        def one(s, out):
+            mine = segment_ids == s
+            return jnp.where(mine[:, :, None, None],
+                             flash(mine if mask is None else mine & mask), out)
+
+        return lambda: lax.fori_loop(0, jnp.max(segment_ids) + 1, one,
+                                     jnp.zeros_like(q))
+    if segment_ids is not None:
+        return None
     # sequence parallelism: ring attention over the seq mesh axis
-    from deepspeed_tpu.parallel.context import seq_parallel_degree, current_mesh
-    if seq_parallel_degree() > 1 and mask is None and segment_ids is None \
-            and window is None:
+    if seq_parallel_degree() > 1 and mask is None:
         from deepspeed_tpu.ops.ring_attention import ring_attention
-        return ring_attention(q, k, v, current_mesh(), causal=causal,
-                              sm_scale=sm)
-    if cfg.sparse_attention and mask is None and segment_ids is None \
-            and window is None:
+        return lambda: ring_attention(q, *_repeat_kv(k, v, Nq), current_mesh(),
+                                      causal=causal, sm_scale=sm)
+    if cfg.sparse_attention and mask is None:
         if q.dtype == jnp.float16 and jax.default_backend() == "tpu":
             raise ValueError("sparse_attention kernels cannot run fp16 on "
                              "TPU (Mosaic has no f16) — use bf16")
@@ -728,8 +764,18 @@ def attention(q, k, v, mask=None, *, causal: bool = True, cfg: TransformerConfig
             get_sparsity_config, sparse_attention as _sparse_attn)
         sa = dict(cfg.sparse_attention)
         mode = sa.pop("mode", "fixed")
-        return _sparse_attn(q, k, v, get_sparsity_config(mode, **sa),
-                            causal=causal, sm_scale=sm)
+        return lambda: _sparse_attn(q, *_repeat_kv(k, v, Nq),
+                                    get_sparsity_config(mode, **sa),
+                                    causal=causal, sm_scale=sm)
+    return None
+
+
+def _xla_attention(q, k, v, mask, causal: bool, cfg: TransformerConfig,
+                   segment_ids, window):
+    """Attention through materialised scores: every mask there is."""
+    B, S, Nq, D = q.shape
+    sm = cfg.attn_scale if cfg.attn_scale is not None else 1.0 / math.sqrt(D)
+    k, v = _repeat_kv(k, v, Nq)
     scores = jnp.einsum("bsnd,btnd->bnst", q, k).astype(jnp.float32)
     scores = scores * sm
     if cfg.position_type == "alibi":
@@ -746,6 +792,9 @@ def attention(q, k, v, mask=None, *, causal: bool = True, cfg: TransformerConfig
         scores = jnp.where((w <= 0) | band[None, None], scores, -1e30)
     if mask is not None:  # [B, S] padding mask over keys
         scores = jnp.where(mask[:, None, None, :], scores, -1e30)
+    if segment_ids is not None:
+        same = segment_ids[:, :, None] == segment_ids[:, None, :]  # [B, S, S]
+        scores = jnp.where(same[:, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bnst,btnd->bsnd", probs, v)
 
@@ -1521,7 +1570,7 @@ def fused_logical_axes(cfg: TransformerConfig) -> Params:
 def transformer_layer(x, layer_params, cfg: TransformerConfig, mask=None,
                       positions=None, dropout_rng=None, deterministic=True,
                       cache=None, return_kv: bool = False, attn_window=None,
-                      paged=None, lora=None):
+                      paged=None, lora=None, segment_ids=None):
     """One pre-norm block: x + attn(ln1(x)); x + mlp(ln2(x)). With the
     after-sublayer scales in the tree (``sandwich_norm``):
     x + ln1_post(attn(ln1(x))); x + ln2_post(mlp(ln2(x))).
@@ -1547,6 +1596,9 @@ def transformer_layer(x, layer_params, cfg: TransformerConfig, mask=None,
     per-row adapter-slot index — each projection in the dict gains the
     gathered low-rank delta (``_lora_delta``), batching rows that use
     DIFFERENT adapters in the same dispatch (multi-LoRA serving).
+
+    segment_ids: int [B, S], several sequences in a row (no cache) —
+    attention stays inside each (``attention``).
     """
     p = _maybe_dequant(layer_params, cfg)
     B, S, H = x.shape
@@ -1660,7 +1712,8 @@ def transformer_layer(x, layer_params, cfg: TransformerConfig, mask=None,
         # projections outside it stay in the matmul bucket by design
         with jax.named_scope("attn"):
             attn_out = attention(q, k, v, mask=mask, causal=cfg.causal,
-                                 cfg=cfg, window=attn_window)
+                                 cfg=cfg, window=attn_window,
+                                 segment_ids=segment_ids)
     attn_flat = attn_out.reshape(B, S, nh * hd)
     attn_out = _wrow(attn_flat, p["wo"])
     if lora is not None and "o" in lora[0]:
@@ -1859,15 +1912,21 @@ def forward(params: Params, input_ids, cfg: TransformerConfig, *,
             deterministic: bool = True, layer_override=None,
             return_aux: bool = False, return_kv: bool = False,
             return_hidden: bool = False, pld_theta=None,
-            inputs_embeds=None):
+            inputs_embeds=None, segment_ids=None):
     """input_ids: [B, S] int32 -> logits [B, S, vocab] (in fp32).
 
     return_kv: also return the per-layer (post-rotary) K/V stacked on a
     leading layer dim — the prefill path's cache seed. token_type_ids:
     segment ids for encoder models (type_vocab_size > 0); None -> zeros.
     inputs_embeds: pre-computed [B, S, H] embeddings instead of a token
-    lookup (vision towers / soft prompts); positions still apply."""
+    lookup (vision towers / soft prompts); positions still apply.
+    segment_ids: int [B, S], several sequences packed into a row: attention
+    stays inside each (``attention``); the caller restarts ``positions``."""
     if cfg.block_pattern:
+        if segment_ids is not None:
+            raise NotImplementedError(
+                "segment_ids on a hybrid stack: its recurrent blocks scan "
+                "the whole row from a zero state")
         from deepspeed_tpu.models import hybrid
         return hybrid.forward(
             params, input_ids, cfg, deterministic=deterministic,
@@ -1931,7 +1990,8 @@ def forward(params: Params, input_ids, cfg: TransformerConfig, *,
             out = transformer_layer(x_c, layer_p, cfg, mask=attention_mask,
                                     positions=positions, dropout_rng=sub,
                                     deterministic=deterministic,
-                                    return_kv=return_kv, attn_window=w)
+                                    return_kv=return_kv, attn_window=w,
+                                    segment_ids=segment_ids)
         if return_kv:
             y, aux, kv = out
         else:
@@ -2227,8 +2287,28 @@ def _quant_kv(x):
     return quantize_rows(x)
 
 
+def _packed_row(starts, lengths, S: int):
+    """What a row of S tokens holding several prompts is walked with.
+    Segment k is rows ``[starts[k], starts[k] + lengths[k])`` (int32 [K],
+    both traced; a segment of length 0 is not there), and the rows behind a
+    prompt, up to the next start or the row's end, are its pad rows as a
+    bucket's are one prompt's: in its segment, behind every real row.
+    Returns (segment ids [1, S], positions [1, S] counted from each start,
+    the real rows [1, S] bool, the last real row of each segment [1, S]
+    bool, and those rows' indices [K])."""
+    rows = jnp.arange(S)[None]
+    last = starts + lengths - 1
+    begun = (lengths > 0)[:, None] & (rows >= starts[:, None])       # [K, S]
+    real = begun & (rows <= last[:, None])
+    first = jnp.max(jnp.where(begun, starts[:, None], 0), axis=0)
+    return ((jnp.sum(begun, axis=0, dtype=jnp.int32) - 1)[None],
+            rows - first, jnp.any(real, axis=0)[None],
+            jnp.any(real & (rows == last[:, None]), axis=0)[None],
+            jnp.maximum(last, 0))
+
+
 def prefill(params: Params, input_ids, cfg: TransformerConfig, cache: Params,
-            attention_mask=None, length: Optional[int] = None
+            attention_mask=None, length: Optional[int] = None, segments=None
             ) -> Tuple[jnp.ndarray, Params]:
     """Process the prompt, seed the cache, return logits at the last real
     position [B, V].
@@ -2238,19 +2318,36 @@ def prefill(params: Params, input_ids, cfg: TransformerConfig, cache: Params,
     true prompt length when input_ids is right-padded for shape bucketing:
     causality keeps logits at length-1 exact, and the cursor is set so decode
     overwrites the pad rows before they can ever be attended.
+
+    segments=(starts [K], lengths [K]) in place of ``length``: the ONE row
+    holds several prompts (``_packed_row``), each attends to itself alone
+    and counts its positions from its start, and the logits come back at
+    every segment's last real position, [K, V] (whatever, for a segment of
+    length 0).
     """
     S = input_ids.shape[1]
-    # traced length is fine: the index ops below are dynamic, so one program
-    # serves every prompt length in the same padded-shape bucket
-    true_len = jnp.asarray(S if length is None else length, jnp.int32)
+    packed = {}
+    if segments is None:
+        # traced length is fine: the index ops below are dynamic, so one
+        # program serves every prompt length in the same padded-shape bucket
+        true_len = jnp.asarray(S if length is None else length, jnp.int32)
+        real = jnp.arange(S)[None] < true_len
+        sampled = jnp.arange(S)[None] == true_len - 1
+    else:
+        if input_ids.shape[0] != 1 or length is not None:
+            raise ValueError("segments share ONE row, and take the place "
+                             "of length")
+        starts, lengths = (jnp.asarray(a, jnp.int32) for a in segments)
+        seg, pos, real, sampled, last_rows = _packed_row(starts, lengths, S)
+        packed = {"segment_ids": seg, "positions": pos}
+        true_len = jnp.max(starts + lengths)
     # an expert-load tap counts the real prompt tokens, not the bucket's pad
     # ... and an exit-gate tap the position whose logits are returned
-    with _moe.counted_tokens(jnp.broadcast_to(
-            jnp.arange(S)[None] < true_len, input_ids.shape)), \
-        _looped.counted_tokens(jnp.broadcast_to(
-            jnp.arange(S)[None] == true_len - 1, input_ids.shape)):
+    with _moe.counted_tokens(jnp.broadcast_to(real, input_ids.shape)), \
+        _looped.counted_tokens(jnp.broadcast_to(sampled, input_ids.shape)):
         logits, kv = forward(params, input_ids, cfg,
-                             attention_mask=attention_mask, return_kv=True)
+                             attention_mask=attention_mask, return_kv=True,
+                             **packed)
     k, v = kv  # [planes, B, S, nkv, hd] -> cache layout [planes, B, nkv, S, hd]
     k, v = jnp.swapaxes(k, 2, 3), jnp.swapaxes(v, 2, 3)
     if cfg.kv_cache_bits == 8:
@@ -2273,6 +2370,8 @@ def prefill(params: Params, input_ids, cfg: TransformerConfig, cache: Params,
                 cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0, 0)),
             "index": true_len,
         }
+    if segments is not None:
+        return logits[0, last_rows], new_cache
     last = lax.dynamic_index_in_dim(logits, true_len - 1, axis=1,
                                     keepdims=False)
     return last, new_cache
@@ -2811,8 +2910,8 @@ def decode_span_paged(params: Params, tokens, cfg: TransformerConfig,
 
 
 def prefill_paged(params: Params, input_ids, cfg: TransformerConfig,
-                  pools: Params, block_ids, length: Optional[int] = None
-                  ) -> Tuple[jnp.ndarray, Params]:
+                  pools: Params, block_ids, length: Optional[int] = None,
+                  segments=None) -> Tuple[jnp.ndarray, Params]:
     """Prefill ONE request and scatter its K/V into the slot's blocks.
 
     input_ids: [1, P] with P a multiple of the block size (shape-bucketed:
@@ -2820,10 +2919,17 @@ def prefill_paged(params: Params, input_ids, cfg: TransformerConfig,
     scheduler allocated; length: true prompt length (pad rows land in the
     last blocks but are masked by seq_len and overwritten as decode
     appends). Returns (last_logits [1, V], pools). The contiguous prefill
-    cache is a jit-local temporary — it never leaves the program."""
+    cache is a jit-local temporary — it never leaves the program.
+
+    segments=(starts [K], lengths [K]) in place of ``length``: SEVERAL
+    requests in the row (``prefill``), every start on a block's edge, so
+    ``block_ids`` is their blocks one request after the other (the trash
+    block behind the last) and the scatter is the same. Returns
+    (last_logits [K, V], pools)."""
     B, P = input_ids.shape
     cache = init_cache(cfg, B, P)
-    last, cache = prefill(params, input_ids, cfg, cache, length=length)
+    last, cache = prefill(params, input_ids, cfg, cache, length=length,
+                          segments=segments)
     return last, _write_prefill_blocks(pools, block_ids, cache,
                                        cfg.kv_cache_bits == 8)
 

@@ -492,8 +492,8 @@ def test_counters_ride_the_rounds_one_fetch(monkeypatch):
     load, exits = out[1][1]                  # the counters, beside the tokens
     assert (load.shape, load.dtype) == ((L, E + 1), jnp.int32) and exits is None
     out = jax.eval_shape(srv._get_prefill_fn(64).__wrapped__, srv.engine.params,
-                         i32(1, 64), srv.pools, i32(1), i32(), key)
-    load, exits = out[0][1]                  # beside the first token
+                         i32(1, 64), srv.pools, i32(1), i32(4), i32(4), key)
+    load, exits = out[0][1]                  # beside the first tokens
     assert (load.shape, load.dtype) == ((L, E + 1), jnp.int32) and exits is None
 
     gets = []

@@ -470,8 +470,9 @@ def test_exit_counters_ride_the_rounds_one_fetch_and_equal_the_reference(monkeyp
     load, exits = out[1][1]
     assert load is None and (exits.shape, exits.dtype) == ((5,), jnp.float32)
     out = jax.eval_shape(srv._get_prefill_fn(16).__wrapped__, srv.engine.params,
-                         i32(1, 16), srv.pools, i32(1), i32(), key)
+                         i32(1, 16), srv.pools, i32(1), i32(4), i32(4), key)
     assert out[0][1][0] is None and out[0][1][1].shape == (5,)
+    assert [t.shape for t in out[0][0]] == [()] * 4    # a token a segment
 
     gets = []
     real_get = jax.device_get

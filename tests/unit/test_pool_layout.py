@@ -43,6 +43,7 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu
+from deepspeed_tpu.inference.serving import _SEGMENTS
 from deepspeed_tpu.models import TransformerConfig, make_model
 
 
@@ -148,7 +149,8 @@ def _program(srv, case, kind, one_chip, shape=None):
         fn = jax.jit(srv._get_prefill_fn(256).__wrapped__,
                      donate_argnums=(2,))
         args = (params, sds((1, 256), jnp.int32), pools,
-                sds((256 // BS,), jnp.int32), sds((), jnp.int32), key)
+                sds((256 // BS,), jnp.int32), *[sds((_SEGMENTS,), jnp.int32)] * 2,
+                key)
     # the suite's "highest" matmul precision is the CPU parity tests'
     # (conftest.py); the chip runs at the default
     with jax.default_matmul_precision("default"):
@@ -405,7 +407,8 @@ def test_dropless_dispatch_is_proportional_to_the_assignments(one_chip, monkeypa
         if kind == "prefill":
             fn = jax.jit(srv._get_prefill_fn(T_).__wrapped__, donate_argnums=(2,))
             args = (params, sds((1, T_), jnp.int32), pools,
-                    sds((T_ // BS,), jnp.int32), sds((), jnp.int32), key)
+                    sds((T_ // BS,), jnp.int32),
+                    *[sds((_SEGMENTS,), jnp.int32)] * 2, key)
         else:
             fn = jax.jit(srv._quantum_step_fn().__wrapped__, donate_argnums=(1, 4))
             args = (params, pools, sds((S,), jnp.int32), _block_list(sds, S, MB, MB),
@@ -489,7 +492,7 @@ def test_the_looped_cells_programs_fit_the_chip_and_write_the_pool_in_place(
     srv = object.__new__(ServingEngine)
     srv.model, srv.decode_backend = model, "xla"
     srv.config = ServingConfig(max_seqs=serving["max_seqs"])
-    srv._moe_forms, srv._prefill_fns = {}, {}
+    srv._moe_forms, srv._prefill_fns, srv._recurrent = {}, {}, 0
     srv._repl_sharding = srv._pool_shardings = None
 
     def sds(shape, dtype):
@@ -503,7 +506,8 @@ def test_the_looped_cells_programs_fit_the_chip_and_write_the_pool_in_place(
     else:
         fn = jax.jit(srv._get_prefill_fn(width).__wrapped__, donate_argnums=(2,))
         args = (params, sds((1, width), jnp.int32), pools,
-                sds((width // BS,), jnp.int32), sds((), jnp.int32), key)
+                sds((width // BS,), jnp.int32),
+                *[sds((_SEGMENTS,), jnp.int32)] * 2, key)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     try:
         with jax.default_matmul_precision("default"):

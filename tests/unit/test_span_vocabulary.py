@@ -266,7 +266,8 @@ class TestHybridRound:
 def _record(round_ms=4.0, **over):
     """A round's record as ``step()`` hands it to ``_note_phases``."""
     e = {"index": 0, "t_s": 0.0, "running_before": 2, "prefills": 0,
-         "prefill_tokens": 0, "shape": (2, 2), "ahead_covered": True,
+         "prefill_programs": 0, "prefill_tokens": 0, "shape": (2, 2),
+         "ahead_covered": True,
          "empty_before_ms": 0.0, "gc_ms": 0.0,
          "schedule_ms": 0.1, "housekeeping_ms": 0.1, "prefill_ms": 0.2,
          "decode_ms": 0.4, "fetch_ms": 3.0, "commit_ms": 0.2,
@@ -365,10 +366,12 @@ class TestRoundRecord:
         assert [r["index"] for r in (first, plain, admit)] == [0, 1, 2]
         assert 0.0 <= first["t_s"] < plain["t_s"] < admit["t_s"]
         assert (first["running_before"], first["prefills"],
-                first["prefill_tokens"]) == (0, 1, 16)
+                first["prefill_programs"], first["prefill_tokens"]) == (
+                    0, 1, 1, 16)
         assert first["ahead_covered"] is None       # nothing was in flight
         assert (plain["running_before"], plain["prefills"],
-                plain["prefill_tokens"]) == (1, 0, 0)
+                plain["prefill_programs"], plain["prefill_tokens"]) == (
+                    1, 0, 0, 0)
         assert plain["shape"] == (2, 2) and plain["ahead_covered"] in (
             True, False)
         assert (admit["running_before"], admit["prefills"],
@@ -446,7 +449,7 @@ class TestRoundRecord:
         late = srv.scheduler.waiting[-1]
         srv.step()
         assert asked[0] is None and asked[1] is tail
-        assert asked[2].shape == (1,) and late.first_token_t is not None
+        assert asked[2].shape == () and late.first_token_t is not None
         assert hasattr(asked[1], "is_ready") and hasattr(asked[2], "is_ready")
         _drain(srv)
         srv.close()
