@@ -10,6 +10,7 @@ from deepspeed_tpu.inference.spec_decode import (NgramProposer,
                                                  greedy_accept_len)
 from deepspeed_tpu.inference.serving import (DecodeDispatchHang,
                                              RecurrentStateUnsupported,
+                                             SlotStateUnsupported,
                                              ResumeIncompatible,
                                              ServingConfig, ServingEngine,
                                              init_serving, load_drain_state)

@@ -259,20 +259,38 @@ def blocks_for(n_tokens: int, block_size: int) -> int:
 
 
 def state_pool_bytes(cfg, max_seqs: int, dtype=None) -> int:
-    """LOGICAL bytes of the per-slot recurrent state of a model with
-    recurrent blocks (``models/hybrid.py``): per block and slot a float32
-    state (Mamba-2 [heads, head dim, state size], Gated DeltaNet [value
-    heads, key dim, value dim]) and the last K - 1 rows of the convolution's
-    input in the pool dtype, every leaf of ``hybrid.state_shapes`` summed. 0
-    for every other model."""
-    if not int(getattr(cfg, "recurrent_blocks", 0) or 0):
+    """LOGICAL bytes of what a model keeps PER SERVING SLOT beside the K/V
+    block pool (``models/hybrid.py`` ``state_leaves``, every leaf summed):
+    per recurrent block a float32 state (Mamba-2 [heads, head dim, state
+    size], Gated DeltaNet [value heads, key dim, value dim]) and the last
+    K - 1 rows of the convolution's input in the pool dtype; per window
+    block the ring of the last ``window`` positions' K/V, rows and scales as
+    the pool's. 0 for every other model."""
+    if not int(getattr(cfg, "slot_state_blocks", 0) or 0):
         return 0
     import math
     import numpy as _np
-    from deepspeed_tpu.models.hybrid import state_shapes
-    itemsize = _np.dtype(dtype if dtype is not None else cfg.dtype).itemsize
-    return sum(math.prod(shape) * (itemsize if name.endswith("conv") else 4)
-               for name, shape in state_shapes(cfg, max_seqs).items())
+    from deepspeed_tpu.models.hybrid import state_leaves
+    dtype = dtype if dtype is not None else cfg.dtype
+    return sum(math.prod(shape) * _np.dtype(leaf_dtype).itemsize
+               for shape, leaf_dtype in state_leaves(cfg, max_seqs, dtype
+                                                     ).values()
+               ) + max_seqs * ring_bytes_per_slot(cfg, dtype)
+
+
+def ring_bytes_per_slot(cfg, dtype=None) -> int:
+    """Bytes ONE serving slot's window rings hold, whatever its context:
+    window blocks x window rows x (K + V rows and, for an int8 cache, their
+    scales). 0 for a model without window blocks."""
+    if not int(getattr(cfg, "window_blocks", 0) or 0):
+        return 0
+    import math
+    import numpy as _np
+    from deepspeed_tpu.models.hybrid import ring_leaves
+    return cfg.window_blocks * sum(
+        math.prod(shape) * _np.dtype(leaf_dtype).itemsize
+        for shape, leaf_dtype in ring_leaves(
+            cfg, 1, dtype if dtype is not None else cfg.dtype).values())
 
 
 def pool_bytes(cfg, num_blocks: int, block_size: int, dtype=None,
@@ -289,9 +307,10 @@ def pool_bytes(cfg, num_blocks: int, block_size: int, dtype=None,
     (``parallel.partitioning.sharded_bytes`` prices it from the committed
     shardings; the memory-law test pins per_device * tp == logical)."""
     # the planes of K/V a token keeps (``TransformerConfig.kv_planes``): one
-    # per layer of a homogeneous stack, per attention block of a hybrid one
-    # — whose per-slot recurrent state (for ``max_seqs`` slots) is counted
-    # beside them — and per pass of a looped one
+    # per layer of a homogeneous stack, per "*" attention block of a hybrid
+    # one — whose per-slot state (recurrent state, window rings; for
+    # ``max_seqs`` slots) is counted beside them — and per pass of a looped
+    # one
     L = getattr(cfg, "kv_planes", cfg.num_layers)
     nkv, hd = cfg.kv_heads, cfg.dim_per_head
     rows = L * num_blocks * nkv * block_size

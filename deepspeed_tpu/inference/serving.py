@@ -88,7 +88,8 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from deepspeed_tpu.inference.kv_cache import (BlockAllocator, blocks_for,
-                                              kv_payload_nbytes, pool_bytes)
+                                              kv_payload_nbytes, pool_bytes,
+                                              ring_bytes_per_slot)
 from deepspeed_tpu.inference.schemas import (DRAIN_STATE_VERSION,
                                              KV_PAYLOAD_SCHEMA)
 from deepspeed_tpu.inference.scheduler import (AdmissionRejected, Request,
@@ -199,22 +200,28 @@ class DecodeDispatchHang(RuntimeError):
     fetch) never came back within ``dispatch_timeout_s``."""
 
 
-class RecurrentStateUnsupported(ValueError):
-    """What a model with recurrent blocks (``block_pattern`` with "M" or
-    "G": ``nemotron_h``, ``qwen3_next``) cannot be served with, refused at ``init_serving`` or
-    at the call: a request's state there is its K/V blocks AND a recurrent
-    state per slot, and everything that shares, rolls back, resumes or ships
-    a request's state by its blocks alone — the prefix cache and its
-    copy-on-write fork, chunked prefill, speculation, K/V export / import,
-    LoRA on the projections, a pool split over ``tensor`` — would need a
-    snapshot of that state, which nothing keeps yet."""
+class SlotStateUnsupported(ValueError):
+    """What a model that keeps something PER SERVING SLOT beside its K/V
+    blocks cannot be served with — a recurrent state (``block_pattern`` with
+    "M" or "G": ``nemotron_h``, ``qwen3_next``) or a window ring ("W":
+    ``afmoe``) —, refused at ``init_serving`` or at the call: a request's
+    state there is its K/V blocks AND its slot's state, and everything that
+    shares, rolls back, resumes or ships a request's state by its blocks
+    alone — the prefix cache and its copy-on-write fork, chunked prefill,
+    speculation, K/V export / import, LoRA on the projections, a pool split
+    over ``tensor`` — would need a snapshot of that state, which nothing
+    keeps yet."""
 
     def __init__(self, what: str):
         super().__init__(
-            f"{what} is not supported on a model with recurrent blocks: a "
-            "request's state is its K/V blocks and a per-slot recurrent "
-            "state, and no snapshot of the latter is kept")
+            f"{what} is not supported on a model with recurrent or window "
+            "blocks: a request's state is its K/V blocks and a per-slot "
+            "state (recurrent state, window ring), and no snapshot of the "
+            "latter is kept")
         self.what = what
+
+
+RecurrentStateUnsupported = SlotStateUnsupported     # the name before PR 44
 
 
 class ResumeIncompatible(ValueError):
@@ -555,10 +562,10 @@ class ServingEngine:
                 f"max_model_len/model max_seq_len ({c.max_model_len} / "
                 f"{model_cap}) leaves no room for one "
                 f"{c.block_size}-token block")
-        # a model with recurrent blocks keeps a state per slot beside the
-        # K/V blocks of its attention blocks (models/hybrid.py)
-        self._recurrent = int(getattr(mcfg, "recurrent_blocks", 0) or 0)
-        if self._recurrent:
+        # a model with recurrent or window blocks keeps a state per slot
+        # beside the K/V blocks of its "*" attention blocks (models/hybrid.py)
+        self._slot_state = int(getattr(mcfg, "slot_state_blocks", 0) or 0)
+        if self._slot_state:
             for armed, what in (
                     (c.enable_prefix_cache, "the prefix cache"),
                     (c.prefill_token_budget is not None, "chunked prefill"),
@@ -566,13 +573,18 @@ class ServingEngine:
                     (c.adapter_slots > 0, "LoRA adapter serving"),
                     (self.tp > 1, "a tensor-parallel pool")):
                 if armed:
-                    raise RecurrentStateUnsupported(what)
+                    raise SlotStateUnsupported(what)
+        # rows of a window block's ring (0: the model has no such block) and
+        # what the plain rounds read of them (reset_stats windows)
+        from deepspeed_tpu.models.hybrid import window
+        self._window = window(mcfg) if getattr(mcfg, "window_blocks", 0) else 0
+        self._win = {"slot_rounds": 0, "rows_in_window": 0}
         self.max_model_len = want
         self.MB = self.max_model_len // c.block_size     # table width
         # a per-slot state pool is updated whole and in place (a Pallas
         # operand is a whole buffer: a narrower step would copy
         # ``state[:, :slots]``), so its engine keeps every round at max_seqs
-        self._slot_counts = ((c.max_seqs,) if self._recurrent
+        self._slot_counts = ((c.max_seqs,) if self._slot_state
                              else _slot_ladder(c.max_seqs))
         num_blocks = c.num_blocks or (c.max_seqs * self.MB + 1)
         if num_blocks - 1 < self.MB:
@@ -670,7 +682,7 @@ class ServingEngine:
         self._init_pools_fn = jax.jit(
             lambda: model.init_paged_cache(
                 num_blocks, c.block_size, dtype=engine.dtype,
-                **({"max_seqs": c.max_seqs} if self._recurrent else {})),
+                **({"max_seqs": c.max_seqs} if self._slot_state else {})),
             out_shardings=self._pool_shardings)
         with engine.mesh:
             self.pools = self._init_pools_fn()
@@ -1195,7 +1207,7 @@ class ServingEngine:
                 return (self._sample(last, key),
                         (tap.stacked(), gate.summed()), pools)
 
-            if self._recurrent:
+            if self._slot_state:
                 def prefill(params, ids, pools, block_ids, length, key, slot):
                     # slot: the request's slot, whose recurrent state the
                     # prompt overwrites
@@ -1542,7 +1554,7 @@ class ServingEngine:
         alone, rows = [], []            # rows: [tokens of its blocks, requests]
         for req in sorted(reqs, key=lambda req: -len(req.context)):
             P = self._pad_prompt(len(req.context))
-            if self._recurrent or P not in self._prefill_fns:
+            if self._slot_state or P not in self._prefill_fns:
                 alone.append((P, [req]))
                 continue
             n = blocks_for(len(req.context), bs) * bs
@@ -1570,7 +1582,7 @@ class ServingEngine:
         # a chunk boundary of the interpreter's frame stack
         fn = self._get_prefill_fn(P) if P in self._prefill_fns else \
             functools.partial(_in_one_chunk, self._get_prefill_fn(P))
-        if self._recurrent:
+        if self._slot_state:
             req, = reqs
             buf[0, :req.context.size] = req.context
             block_ids = req.block_ids[:P // bs]
@@ -1594,7 +1606,7 @@ class ServingEngine:
                 self.engine.params, jnp.asarray(buf), self.pools,
                 jnp.asarray(block_ids, jnp.int32), *what)
         for k, req in enumerate(reqs):
-            tok = toks[0] if self._recurrent else toks[k]
+            tok = toks[0] if self._slot_state else toks[k]
             self._tokens = self._tokens.at[req.slot].set(tok)
             req.cached_rows = req.context.size
             req.prefill_done = True
@@ -1630,8 +1642,8 @@ class ServingEngine:
         pin on the shared block is dropped. Runs BEFORE any of the
         request's own writes — full shared blocks stay referenced, the
         partial one is never written in place."""
-        if self._recurrent:
-            raise RecurrentStateUnsupported("a copy-on-write fork")
+        if self._slot_state:
+            raise SlotStateUnsupported("a copy-on-write fork")
         src, dst = req.cow_src, req.cow_dst
         with self.engine.mesh:
             self.pools = self._copy_block_fn(self.pools, np.int32(src),
@@ -1785,6 +1797,12 @@ class ServingEngine:
             # its slot but must not decode yet
             act[req.slot] = req.prefill_done
             aidx[req.slot] = req.adapter_slot or 0
+        if self._window and not full:
+            # a step reads a window plane's whole ring for every live slot;
+            # of those rows, min(length, window) are inside the slot's band
+            self._win["slot_rounds"] += int(act.sum())
+            self._win["rows_in_window"] += int(
+                np.minimum(lens[act], self._window).sum())
         return (jax.tree.map(jnp.asarray, tables), jnp.asarray(lens),
                 jnp.asarray(act), jnp.asarray(aidx)), (S, W), held
 
@@ -2513,8 +2531,8 @@ class ServingEngine:
         them in ``pool_bytes``/``kv_staging_bytes``."""
         import jax
         import jax.numpy as jnp
-        if self._recurrent:
-            raise RecurrentStateUnsupported("K/V export")
+        if self._slot_state:
+            raise SlotStateUnsupported("K/V export")
         bs = self.config.block_size
         out: Dict[int, Dict[str, Any]] = {}
         for rid in request_ids:
@@ -2606,8 +2624,8 @@ class ServingEngine:
         ``BlockAllocator`` path, the payload scatters into them before
         the 1-tail-span prefill runs, and the continuation is
         token-identical to the colocated engine."""
-        if self._recurrent:
-            raise RecurrentStateUnsupported("K/V import")
+        if self._slot_state:
+            raise SlotStateUnsupported("K/V import")
         req = self._requests.get(request_id)
         if req is None or req.state != "waiting":
             raise ResumeIncompatible(
@@ -2880,8 +2898,8 @@ class ServingEngine:
                     "on an engine at least as large as the drained one")
             payload = kv.get(req.rid)
             if payload is not None:
-                if self._recurrent:
-                    raise RecurrentStateUnsupported("K/V import")
+                if self._slot_state:
+                    raise SlotStateUnsupported("K/V import")
                 # all-or-nothing with the rest of the batch: a bad payload
                 # refuses HERE, before anything is enqueued
                 self._validate_kv_payload(req, payload, source)
@@ -3013,7 +3031,7 @@ class ServingEngine:
     def _state_bytes(self) -> int:
         """Per-device bytes of the per-slot recurrent state pool (0 for a
         model without recurrent blocks)."""
-        if not self._recurrent:
+        if not self._slot_state:
             return 0
         from deepspeed_tpu.models.hybrid import STATE_LEAVES
         from deepspeed_tpu.parallel.partitioning import sharded_bytes
@@ -3036,6 +3054,7 @@ class ServingEngine:
         self._moe = dict(_MOE_COUNTERS)
         self._exit[:] = 0.0
         self._lat = dict(_LAT_COUNTERS)
+        self._win = {"slot_rounds": 0, "rows_in_window": 0}
         self._table_rounds = self._step_shapes()
         if self._prefix_cache is not None:
             self._prefix_cache.reset_stats()
@@ -3127,11 +3146,17 @@ class ServingEngine:
         and ``prefill_packed_prompts`` (the prompts that shared one).
 
         The two kinds of state (always on): ``kv_pool_bytes`` (the K/V block
-        pool's share of ``pool_bytes``) and, for a model with recurrent
-        blocks, ``state_pool_bytes`` (the per-slot recurrent state, every
+        pool's share of ``pool_bytes``) and, for a model with recurrent or
+        window blocks, ``state_pool_bytes`` (the per-slot state, every
         state leaf of the pool summed) and
         ``state_slots_live`` (slots whose state belongs to a running
-        request);
+        request); for a model with window blocks also ``window_blocks``,
+        ``window_rows`` (rows of a ring), ``kv_bytes_per_token`` (of the
+        FULL planes, the only ones that grow with the context),
+        ``ring_bytes_per_slot`` (all its rings, whatever the context),
+        ``window_rows_read`` (rows of ONE window plane the plain rounds'
+        first steps read, a ring a live slot) and ``window_rows_in_window``
+        (of those, the rows inside the slots' bands: min(length, window));
         ``moe_dispatch`` — a dict ``{"step" | "prefill_<bucket>": form}``
         of the expert layers' dispatch form (``one-hot`` | ``sorted/moe_gmm``
         | ``sorted/ragged_dot`` | ``capacity``) in each program built so far,
@@ -3259,9 +3284,19 @@ class ServingEngine:
                 out["exit_step_expected"] = float(
                     np.dot(np.arange(1, p.size + 1), p))
                 out["exit_cdf"] = [float(x) for x in np.cumsum(p)]
-        if self._recurrent:
+        if self._slot_state:
             out["state_pool_bytes"] = float(self._state_bytes())
             out["state_slots_live"] = float(len(self.scheduler.running))
+        if self._window:
+            out["window_blocks"] = float(mcfg.window_blocks)
+            out["window_rows"] = float(self._window)
+            out["kv_bytes_per_token"] = float(pool_bytes(
+                mcfg, 1, 1, dtype=self.engine.dtype))
+            out["ring_bytes_per_slot"] = float(ring_bytes_per_slot(
+                mcfg, dtype=self.engine.dtype))
+            out["window_rows_read"] = float(
+                self._window * self._win["slot_rounds"])
+            out["window_rows_in_window"] = float(self._win["rows_in_window"])
         out.update({k: float(v) for k, v in self._lat.items()})
         out["step_shape_rounds"] = {
             f"{S}x{W}": n for (S, W), n in self._table_rounds.items()}

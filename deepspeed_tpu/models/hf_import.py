@@ -22,6 +22,7 @@ in ``deepspeed/module_inject/containers/``.
 """
 
 import json
+import math
 import os
 import re
 from typing import Any, Callable, Dict, Iterator, Optional, Tuple
@@ -1156,6 +1157,137 @@ def _qwen3_next_kwargs(get) -> dict:
         conv_kernel=get("linear_conv_kernel_dim", 4))
 
 
+def _afmoe_kwargs(get) -> dict:
+    """``afmoe`` (Arcee Trinity): every layer is an attention block then a
+    feed-forward block of a hybrid stack, both under sandwich norms (four
+    RMSNorms a layer). Attention — q/k RMSNorm per head, a sigmoid output
+    gate from a separate full-width ``gate_proj`` (stored here as a head's
+    columns [q | gate] in ``wq``) — is ``W`` on a ``sliding_attention`` layer
+    (rotary over the whole head, the last ``sliding_window`` positions) and
+    ``*`` on a ``full_attention`` one (NO positional embedding); the
+    feed-forward is ``D`` (SwiGLU of ``intermediate_size``) on the first
+    ``num_dense_layers`` layers and ``E`` after them: a sigmoid router whose
+    choice adds ``expert_bias``, ``route_norm`` / ``route_scale`` on the
+    weights, SwiGLU experts of ``moe_intermediate_size`` and an ungated
+    shared expert. ``mup_enabled`` scales the embeddings by
+    sqrt(hidden_size). Expert groups, rope scaling and biases are refused:
+    nothing here computes them.
+
+    THE CHIP'S SHARE: as ``qwen3_next`` — ``num_experts`` counts the experts
+    held, ``num_experts_router`` the router's width, ``expert_first`` the
+    first one held."""
+    L, every = get("num_hidden_layers"), get("global_attn_every_n_layers", 4)
+    for key, want in (("hidden_act", "silu"), ("rope_scaling", None),
+                      ("score_func", "sigmoid"), ("n_group", 1),
+                      ("num_expert_groups", 1), ("topk_group", 1),
+                      ("num_limited_groups", 1), ("num_shared_experts", 1)):
+        if get(key, want) != want:
+            raise ValueError(f"afmoe {key}={get(key)!r} is not supported "
+                             f"(the published config has {want!r})")
+    for key in ("attention_bias", "mlp_bias"):
+        if get(key, False):
+            raise ValueError(f"afmoe {key}=true is not supported")
+    # a config cut in depth keeps the published list: its first L entries
+    kinds = list(get("layer_types") or [
+        "full_attention" if (i + 1) % every == 0 else "sliding_attention"
+        for i in range(L)])[:L]
+    bad = sorted(set(kinds) - {"full_attention", "sliding_attention"})
+    if bad or len(kinds) != L:
+        raise ValueError(f"afmoe layer_types: >= {L} entries of "
+                         f"full_attention | sliding_attention, got "
+                         f"{len(kinds)} with {bad}")
+    dense, sw = get("num_dense_layers", 0), get("sliding_window")
+    if "sliding_attention" in kinds and not sw:
+        raise ValueError("afmoe: sliding_attention layers need sliding_window")
+    pattern = "".join(("W" if kind == "sliding_attention" else "*")
+                      + ("D" if i < dense else "E")
+                      for i, kind in enumerate(kinds))
+    held = get("num_experts")
+    width, first = get("num_experts_router", held), get("expert_first", 0)
+    if not 0 <= first <= width - held:
+        raise ValueError(f"afmoe: experts {first} .. {first + held - 1} "
+                         f"held of num_experts_router={width}")
+    H = get("hidden_size")
+    return dict(
+        vocab_size=get("vocab_size"), hidden_size=H,
+        num_layers=len(pattern), block_pattern=pattern,
+        attn_windows=tuple(int(sw) if b == "W" else 0 for b in pattern),
+        num_heads=get("num_attention_heads"),
+        num_kv_heads=get("num_key_value_heads"),
+        head_dim=get("head_dim") or H // get("num_attention_heads"),
+        max_seq_len=get("max_position_embeddings", 4096),
+        norm_eps=float(get("rms_norm_eps", 1e-5)),
+        # the rule of the "*" blocks; a "W" block is always rotary
+        position_type="none", rope_theta=float(get("rope_theta", 10000.0)),
+        norm_type="rmsnorm", activation="silu_glu", sandwich_norm=True,
+        qk_norm_per_head=True, attn_out_gate=True,
+        embed_scale=math.sqrt(H) if get("mup_enabled", False) else 1.0,
+        tie_embeddings=bool(get("tie_word_embeddings", False)),
+        # `moe_intermediate_size` is ONE expert's width and the shared
+        # expert's, `intermediate_size` a dense layer's
+        intermediate_size=get("moe_intermediate_size"),
+        dense_ffn_size=get("intermediate_size"),
+        num_experts=held, top_k=get("num_experts_per_tok"),
+        moe_router_experts=width if width != held else None,
+        moe_held_first=first, moe_scoring="sigmoid",
+        norm_topk_prob=bool(get("route_norm", True)),
+        routed_scaling_factor=float(get("route_scale", 1.0)),
+        moe_shared_size=get("moe_intermediate_size"),
+        drop_tokens=False, use_residual=False,
+        moe_aux_loss_weight=float(get("load_balance_coeff", 0.0)))
+
+
+def afmoe_weight_names(cfg) -> Dict[str, tuple]:
+    """The tensors of an HF ``afmoe`` checkpoint of ``cfg``'s shape, by the
+    names ``modeling_afmoe`` registers them under -> where each lives in the
+    hybrid tree: ``(kind, block index within its kind, leaf, part)`` —
+    ``kind`` None for the three leaves outside the layers; ``part`` the
+    expert for an expert stack (counted from ``moe_held_first``: a chip loads
+    the experts it holds), ``"q"`` / ``"gate"`` for the two projections whose
+    columns share ``wq`` head by head ([q | gate]), else None. Every matrix is
+    stored transposed ([in, out]) but the experts' up projection
+    (``moe_w_in_t`` keeps HF's [F, H]). ``load_hf_params`` stacks
+    homogeneous layers and does not read this table yet."""
+    from deepspeed_tpu.models import hybrid
+    out = {"model.embed_tokens.weight": (None, 0, "tok_embed", None),
+           "model.norm.weight": (None, 0, "final_norm_scale", None),
+           "lm_head.weight": (None, 0, "lm_head", None)}
+    blocks = hybrid.blocks(cfg)
+    for layer in range(len(blocks) // 2):
+        pre = f"model.layers.{layer}."
+        (akind, aj), (fkind, fj) = blocks[2 * layer], blocks[2 * layer + 1]
+        for name, leaf, part in (
+                ("input_layernorm", "ln_scale", None),
+                ("post_attention_layernorm", "post_ln_scale", None),
+                ("self_attn.q_proj", "wq", "q"),
+                ("self_attn.gate_proj", "wq", "gate"),
+                ("self_attn.k_proj", "wk", None),
+                ("self_attn.v_proj", "wv", None),
+                ("self_attn.o_proj", "wo", None),
+                ("self_attn.q_norm", "q_norm", None),
+                ("self_attn.k_norm", "k_norm", None)):
+            out[pre + name + ".weight"] = (akind, aj, leaf, part)
+        out[pre + "pre_mlp_layernorm.weight"] = (fkind, fj, "ln_scale", None)
+        out[pre + "post_mlp_layernorm.weight"] = (fkind, fj, "post_ln_scale",
+                                                  None)
+        if fkind == "dense":
+            for name, leaf in (("gate_proj", "w_gate"), ("up_proj", "w_in"),
+                               ("down_proj", "w_out")):
+                out[pre + f"mlp.{name}.weight"] = (fkind, fj, leaf, None)
+            continue
+        out[pre + "mlp.router.gate.weight"] = (fkind, fj, "wg", None)
+        out[pre + "mlp.expert_bias"] = (fkind, fj, "e_bias", None)
+        for name, leaf in (("gate_proj", "w_gate"), ("up_proj", "w_in"),
+                           ("down_proj", "w_out")):
+            out[pre + f"mlp.shared_experts.{name}.weight"] = (
+                fkind, fj, "shared_" + leaf, None)
+            stack = "moe_w_in_t" if leaf == "w_in" else "moe_" + leaf
+            for e in range(cfg.num_experts):
+                out[pre + f"mlp.experts.{cfg.moe_held_first + e}.{name}"
+                    ".weight"] = (fkind, fj, stack, e)
+    return out
+
+
 class EarlyExitUnsupported(NotImplementedError):
     """A looped model whose ``early_exit_threshold`` is below 1: a token
     would leave the stack at the first pass whose cumulative exit
@@ -1265,6 +1397,8 @@ def hf_config_to_transformer(hf_cfg, **overrides):
         kw = _ouro_kwargs(get)
     elif mt == "qwen3_next":
         kw = _qwen3_next_kwargs(get)
+    elif mt == "afmoe":
+        kw = _afmoe_kwargs(get)
     elif mt == "opt":
         if get("word_embed_proj_dim", get("hidden_size")) != get("hidden_size"):
             raise ValueError(

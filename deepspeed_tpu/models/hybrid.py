@@ -1,41 +1,49 @@
 """Hybrid stacks: one MIXER per block, its kind from a static pattern.
 
 ``TransformerConfig.block_pattern`` is one letter a block (``nemotron_h``'s
-``hybrid_override_pattern``; ``qwen3_next``'s layers are TWO blocks each, a
-token mixer then ``E``): ``M`` a Mamba-2 mixer (``models/mamba.py``), ``G`` a
-Gated DeltaNet mixer (``models/gated_deltanet.py``), ``E`` an expert
-feed-forward (``moe/sharded_moe.py``), ``*`` attention. Every block is
-``h <- h + mixer(RMSNorm(h))``: the walker puts no feed-forward after
-attention and no attention before an expert layer, the pattern does. The
-attention blocks carry no positional embedding (``position_type="none"``,
-``nemotron_h``) or rotary over the first ``rotary_dim`` dims of a head
-(``"rotary"``), a per-head RMSNorm of q and k (``qk_norm_per_head``) and a
-sigmoid gate on their output, projected beside q (``attn_out_gate``), as the
-config says. ``models/transformer.py``'s ``init_params``,
-``logical_axes``, ``forward``, ``init_paged_cache``, ``prefill_paged`` and
+``hybrid_override_pattern``; ``qwen3_next``'s and ``afmoe``'s layers are TWO
+blocks each, a token mixer then a feed-forward): ``M`` a Mamba-2 mixer
+(``models/mamba.py``), ``G`` a Gated DeltaNet mixer
+(``models/gated_deltanet.py``), ``E`` an expert feed-forward
+(``moe/sharded_moe.py``), ``D`` a dense feed-forward, ``*`` attention over
+the whole history, ``W`` attention over the last ``window(cfg)`` positions.
+Every block is ``h <- h + mixer(RMSNorm(h))`` — through a second RMSNorm
+after the mixer where the stack has one (``sandwich_norm``) —: the walker
+puts no feed-forward after attention and no attention before an expert layer,
+the pattern does. The rotary rule is per KIND: a ``*`` block carries no
+positional embedding (``position_type="none"``, ``nemotron_h``, ``afmoe``) or
+rotary over the first ``rotary_dim`` dims of a head (``"rotary"``), a ``W``
+block is always rotary; both take a per-head RMSNorm of q and k
+(``qk_norm_per_head``) and a sigmoid gate on their output, projected beside q
+(``attn_out_gate``), as the config says. ``embed_scale`` multiplies the
+embeddings. ``models/transformer.py``'s ``init_params``, ``logical_axes``,
+``forward``, ``init_paged_cache``, ``prefill_paged`` and
 ``decode_step_paged`` hand a config with a pattern to the functions here, so
 a hybrid model is a ``make_model`` like any other and serves through the same
 engine.
 
 Parameters are stacked PER KIND (``params["layers"]["mamba" | "gdn" | "moe"
-| "attn"]``, leading dim = blocks of that kind). The walk over a pattern that
-does not repeat is unrolled: a block's index within its kind is a Python
-int, its slice of a stack a static one. A pattern that repeats (three periods
-of ``GEGEGE*E``) is a ``lax.scan`` over its repeats with one unit unrolled in
-the body and the indices traced (``_walk``). Either way a program is shaped
-by the pool and table dims only.
+| "dense" | "attn" | "wattn"]``, leading dim = blocks of that kind). The walk
+over a pattern that does not repeat is unrolled: a block's index within its
+kind is a Python int, its slice of a stack a static one. A pattern that
+repeats (three periods of ``GEGEGE*E``) is a ``lax.scan`` over its repeats
+with one unit unrolled in the body and the indices traced (``_walk``). Either
+way a program is shaped by the pool and table dims only.
 
-The cache is two kinds of state side by side in one tree (the serving
-engine's ``srv.pools``): the K/V block pool, whose layer dim counts the
-ATTENTION blocks only, and a per-slot state pool for the recurrent blocks —
-``ssm`` float32 ``[Lm, slots, heads, P, N]`` and ``conv`` ``[Lm, slots,
-K - 1, conv_dim]`` (the last K - 1 rows of ``xBC`` before the convolution)
-for the ``M`` blocks, ``gdn`` float32 ``[Lg, slots, value heads, dk, dv]``
-and ``gdn_conv`` ``[Lg, slots, K - 1, conv_dim]`` for the ``G`` blocks.
-A prefill is a whole prompt from a zero state and overwrites the slot's rows
-with the state after the last TRUE position (so a slot given again carries
-nothing of the last request); a step advances the active slots' state in
-place and leaves the others' alone.
+The cache is three kinds of state side by side in one tree (the serving
+engine's ``srv.pools``): the K/V block pool, whose layer dim counts the ``*``
+blocks only; a per-slot state pool for the recurrent blocks — ``ssm`` float32
+``[Lm, slots, heads, P, N]`` and ``conv`` ``[Lm, slots, K - 1, conv_dim]``
+(the last K - 1 rows of ``xBC`` before the convolution) for the ``M`` blocks,
+``gdn`` float32 ``[Lg, slots, value heads, dk, dv]`` and ``gdn_conv`` ``[Lg,
+slots, K - 1, conv_dim]`` for the ``G`` blocks —; and per slot and ``W``
+block a RING of the last ``window`` positions' K/V (``ring_leaves``), its
+bytes fixed whatever the context. A prefill is a whole prompt from a zero
+state and overwrites the slot's rows with the state after the last TRUE
+position (so a slot given again carries nothing of the last request) and the
+ring's rows with the last ``window`` true positions; a step advances the
+active slots' state in place, writes their row into their rings, and leaves
+the others' alone.
 """
 import math
 from typing import Optional
@@ -48,7 +56,11 @@ from deepspeed_tpu.models import gated_deltanet as gdn
 from deepspeed_tpu.models import mamba
 from deepspeed_tpu.moe import sharded_moe as _moe
 
-KINDS = {"M": "mamba", "G": "gdn", "E": "moe", "*": "attn"}
+KINDS = {"M": "mamba", "G": "gdn", "E": "moe", "*": "attn", "W": "wattn",
+         "D": "dense"}
+# the kinds whose blocks are softmax attention: "attn" keeps every position in
+# the K/V block pool, "wattn" the last ``window(cfg)`` in a ring per slot
+ATTN_KINDS = ("attn", "wattn")
 
 
 def blocks(cfg):
@@ -69,7 +81,8 @@ def blocks(cfg):
         if letter not in KINDS:
             raise ValueError(
                 f"block_pattern letter {letter!r}: one of {sorted(KINDS)} "
-                "(M Mamba-2, G Gated DeltaNet, E experts, * attention)")
+                "(M Mamba-2, G Gated DeltaNet, E experts, D dense "
+                "feed-forward, * attention, W sliding-window attention)")
         kind = KINDS[letter]
         out.append((kind, seen.get(kind, 0)))
         seen[kind] = seen.get(kind, 0) + 1
@@ -78,6 +91,21 @@ def blocks(cfg):
 
 def count(cfg, kind: str) -> int:
     return sum(1 for k, _ in blocks(cfg) if k == kind)
+
+
+def window(cfg) -> int:
+    """Positions a "W" block sees (key j visible to query i iff 0 <= i - j <
+    window): ``attn_windows`` holds it at every "W" block of the pattern and
+    0 at every other block, so the letter and the length cannot disagree."""
+    want = tuple(letter == "W" for letter in cfg.block_pattern)
+    wins = tuple(cfg.attn_windows or (0,) * len(want))
+    sizes = {w for w in wins if w}
+    if tuple(bool(w) for w in wins) != want or len(sizes) > 1:
+        raise ValueError(
+            f"attn_windows {wins} against block_pattern "
+            f"{cfg.block_pattern!r}: ONE window length, at the W blocks and "
+            "nowhere else")
+    return sizes.pop() if sizes else 0
 
 
 # --------------------------------------------------------------------------
@@ -101,6 +129,17 @@ def init_params(key, cfg):
     out_scale = std / math.sqrt(2 * cfg.num_layers)
     Lm, Lg, Le, La = (count(cfg, k) for k in ("mamba", "gdn", "moe", "attn"))
     keys = iter(jax.random.split(key, 32))
+    # the norm scales' own stream, drawn from only where the configuration
+    # asks for a start away from 1 (`norm_init_jitter`, `post_norm_init`:
+    # transformer.init_params' rule): every other draw keeps its key
+    nkeys = iter(jax.random.split(jax.random.fold_in(key, 1), 32))
+
+    def norm_scale(shape, start=1.0):
+        j = cfg.norm_init_jitter
+        if not j:
+            return jnp.full(shape, start, dt)
+        return jax.random.uniform(next(nkeys), shape, jnp.float32,
+                                  start * (1 - j), start * (1 + j)).astype(dt)
 
     def normal(shape, scale=std):
         return (jax.random.normal(next(keys), shape) * scale).astype(dt)
@@ -173,7 +212,8 @@ def init_params(key, cfg):
             "moe_w_out": experts((E, F, H), out_scale / 4),
         }
         if cfg.moe_scoring == "sigmoid":
-            layers["moe"]["e_bias"] = normal((Le, E), 0.02)
+            # a bias per expert the router SCORES (the choice is over all)
+            layers["moe"]["e_bias"] = normal((Le, cfg.moe_router_width), 0.02)
         if "glu" in cfg.activation:
             layers["moe"]["moe_w_gate"] = experts((E, H, F))
         if Fs:
@@ -183,9 +223,12 @@ def init_params(key, cfg):
                 layers["moe"]["shared_w_gate"] = normal((Le, H, Fs))
             if cfg.moe_shared_gate:
                 layers["moe"]["shared_gate"] = normal((Le, H))
-    if La:
+    for kind in ATTN_KINDS:
+        La = count(cfg, kind)
+        if not La:
+            continue
         nq, nkv, ahd = cfg.num_heads, cfg.kv_heads, cfg.dim_per_head
-        layers["attn"] = {
+        layers[kind] = {
             "ln_scale": jnp.ones((La, H), dt),
             # with an output gate, a head's columns are [q | gate]
             "wq": normal((La, H, nq * ahd * (2 if cfg.attn_out_gate else 1))),
@@ -194,10 +237,24 @@ def init_params(key, cfg):
             "wo": normal((La, nq * ahd, H), out_scale),
         }
         if cfg.qk_norm_per_head:
-            layers["attn"]["q_norm"] = jnp.ones((La, ahd), dt)
-            layers["attn"]["k_norm"] = jnp.ones((La, ahd), dt)
+            layers[kind]["q_norm"] = norm_scale((La, ahd))
+            layers[kind]["k_norm"] = norm_scale((La, ahd))
+    Ld = count(cfg, "dense")
+    if Ld:
+        F = cfg.dense_ffn_size or cfg.ffn_dim
+        layers["dense"] = {"ln_scale": jnp.ones((Ld, H), dt),
+                           "w_in": normal((Ld, H, F)),
+                           "w_out": normal((Ld, F, H), out_scale)}
+        if "glu" in cfg.activation:
+            layers["dense"]["w_gate"] = normal((Ld, H, F))
+    for kind, stacks in layers.items():
+        n = stacks["ln_scale"].shape[0]
+        if cfg.norm_init_jitter:
+            stacks["ln_scale"] = norm_scale((n, H))
+        if cfg.sandwich_norm:
+            stacks["post_ln_scale"] = norm_scale((n, H), cfg.post_norm_init)
     params = {"tok_embed": normal((V, H)), "layers": layers,
-              "final_norm_scale": jnp.ones((H,), dt)}
+              "final_norm_scale": norm_scale((H,))}
     if not cfg.tie_embeddings:
         params["lm_head"] = normal((H, V))
     return params
@@ -242,14 +299,25 @@ def logical_axes(cfg):
                 layers["moe"]["shared_w_gate"] = ("layers", "embed", "mlp")
             if cfg.moe_shared_gate:
                 layers["moe"]["shared_gate"] = ("layers", "embed")
-    if count(cfg, "attn"):
-        layers["attn"] = {
+    for kind in ATTN_KINDS:
+        if not count(cfg, kind):
+            continue
+        layers[kind] = {
             "ln_scale": ("layers", "unmodeled"),
             "wq": ("layers", "embed", "qkv"), "wk": ("layers", "embed", "qkv"),
             "wv": ("layers", "embed", "qkv"), "wo": ("layers", "heads", "embed")}
         if cfg.qk_norm_per_head:
-            layers["attn"]["q_norm"] = ("layers", None)
-            layers["attn"]["k_norm"] = ("layers", None)
+            layers[kind]["q_norm"] = ("layers", None)
+            layers[kind]["k_norm"] = ("layers", None)
+    if count(cfg, "dense"):
+        layers["dense"] = {"ln_scale": ("layers", "unmodeled"),
+                           "w_in": ("layers", "embed", "mlp"),
+                           "w_out": ("layers", "mlp", "embed")}
+        if "glu" in cfg.activation:
+            layers["dense"]["w_gate"] = ("layers", "embed", "mlp")
+    if cfg.sandwich_norm:
+        for stacks in layers.values():
+            stacks["post_ln_scale"] = ("layers", "unmodeled")
     axes = {"tok_embed": ("vocab", "embed"), "layers": layers,
             "final_norm_scale": ("unmodeled",)}
     if not cfg.tie_embeddings:
@@ -287,15 +355,18 @@ def _moe_mixer(p, h, cfg, train: bool = False, rng=None):
         return _moe.moe_ffn(moe_params, h, cfg, rng=rng, train=train)
 
 
-def _qkv(p, h, cfg, positions=None):
+def _qkv(p, h, cfg, positions=None, rotary=None):
     """h [B, T, H] -> (q [B, T, nq, hd], k, v [B, T, nkv, hd], gate [B, T,
     nq hd] or None). What the config names is applied in HF's order: the
     output gate's columns split off q (``attn_out_gate``: a head's columns
     are [q | gate]), the per-head RMSNorm of q and k (``q_norm`` / ``k_norm``
     [hd]), rotary at ``positions`` [B, T] over the first ``rotary_dim`` dims
-    (``position_type="rotary"``; "none" applies nothing)."""
+    (``rotary``; None: what ``position_type`` says, the rule of the "*"
+    blocks — a "W" block is always rotary)."""
     from deepspeed_tpu.models.transformer import (_rms_whole, _wmat,
                                                   rotary_embed)
+    if rotary is None:
+        rotary = cfg.position_type == "rotary"
     B, T, _ = h.shape
     hd, gate = cfg.dim_per_head, None
     q = _wmat(h, p["wq"])
@@ -308,7 +379,7 @@ def _qkv(p, h, cfg, positions=None):
     if "q_norm" in p:
         q = _rms_whole(q, p["q_norm"], cfg.norm_eps)
         k = _rms_whole(k, p["k_norm"], cfg.norm_eps)
-    if cfg.position_type == "rotary":
+    if rotary:
         q = rotary_embed(q, positions, cfg.rope_theta, cfg.rotary_dim,
                          cfg.rotary_interleaved)
         k = rotary_embed(k, positions, cfg.rope_theta, cfg.rotary_dim,
@@ -326,15 +397,41 @@ def _out(p, o, gate):
     return _wrow(o, p["wo"])
 
 
-def _attn_mixer(p, h, cfg):
-    """Causal attention over whole sequences h [B, T, H] -> (out, k, v)."""
+def _attn_mixer(p, h, cfg, kind: str = "attn"):
+    """Causal attention over whole sequences h [B, T, H] -> (out, k, v); a
+    "wattn" block's is rotary and banded to the last ``window(cfg)``
+    positions (a STATIC length: the banded flash kernel where the flash
+    kernel would run)."""
     from deepspeed_tpu.models.transformer import attention
     B, T, _ = h.shape
+    local = kind == "wattn"
     q, k, v, gate = _qkv(p, h, cfg,
-                         jnp.broadcast_to(jnp.arange(T)[None], (B, T)))
-    with jax.named_scope("attn"):
-        o = attention(q, k, v, causal=True, cfg=cfg)
+                         jnp.broadcast_to(jnp.arange(T)[None], (B, T)),
+                         rotary=True if local else None)
+    if local:
+        with jax.named_scope("attn"), jax.named_scope("window"):
+            o = attention(q, k, v, causal=True, cfg=cfg, window=window(cfg))
+    else:
+        with jax.named_scope("attn"):
+            o = attention(q, k, v, causal=True, cfg=cfg)
     return _out(p, o.reshape(B, T, -1), gate), k, v
+
+
+def _dense_mixer(p, h, cfg):
+    """A dense feed-forward block h [B, T, H] -> [B, T, H]."""
+    from deepspeed_tpu.models.transformer import _activation, _wmat, _wrow
+    with jax.named_scope("mlp"):
+        gate = _wmat(h, p["w_gate"]) if "w_gate" in p else None
+        return _wrow(_activation(_wmat(h, p["w_in"]), gate, cfg), p["w_out"])
+
+
+def _residual(p, x, y, cfg):
+    """A block's output joins the stream: ``x + y``, through the block's
+    RMSNorm AFTER the mixer where the stack has one (``sandwich_norm``)."""
+    from deepspeed_tpu.models.transformer import _norm
+    if cfg.sandwich_norm:
+        y = _norm(y, p["post_ln_scale"], None, cfg)
+    return x + y
 
 
 def _norm_in(p, x, cfg):
@@ -355,7 +452,8 @@ def _head(params, x, cfg):
 
 def _embed(params, ids, cfg):
     with jax.named_scope("embed"):
-        return params["tok_embed"][ids].astype(cfg.dtype)
+        x = params["tok_embed"][ids].astype(cfg.dtype)
+        return x if cfg.embed_scale == 1.0 else x * cfg.embed_scale
 
 
 # --------------------------------------------------------------------------
@@ -371,9 +469,11 @@ def period(cfg):
     has both Mamba-2 blocks and a repeat, and the traced index would change
     the accepted hybrid cell's step text (``tests/unit/test_program_text.
     py``): the PR that serves a repeating Mamba-2 pattern gives the kernel
-    the scalar and drops the test on ``"M"``."""
+    the scalar and drops the test on ``"M"``. A pattern with "W" blocks is
+    one unit as well: their rings are one array a block (``ring_leaves``
+    says why), which a traced index cannot choose among."""
     pattern = cfg.block_pattern
-    if "M" not in pattern:
+    if "M" not in pattern and "W" not in pattern:
         for size in range(1, len(pattern) // 2 + 1):
             if pattern == pattern[:size] * (len(pattern) // size):
                 return pattern[:size], len(pattern) // size
@@ -384,9 +484,9 @@ def _walk(params, cfg, carry, block):
     """``block(i, kind, j, p, carry) -> (carry, out)`` over the pattern's
     blocks in order (``j`` the block's index within its kind, ``p`` its
     slice of the kind's stacks, ``out`` None or what an attention block
-    hands on) -> (carry, outs): the attention blocks' outs, a list where
-    the walk is unrolled and stacked arrays where it is a scan (``_stacked``
-    makes either the latter), None if there are none.
+    hands on) -> (carry, outs): {kind: its blocks' outs}, a list where the
+    walk is unrolled and stacked arrays where it is a scan (``_stacked``
+    makes either the latter), without the kinds that hand nothing on.
 
     A pattern that repeats (``period``) is walked as a ``lax.scan`` over its
     repeats with ONE unit unrolled in the body — ``j`` is then traced,
@@ -402,13 +502,13 @@ def _walk(params, cfg, carry, block):
         per_unit[kind] = per_unit.get(kind, 0) + 1
 
     def one_unit(carry, r):
-        outs = []
+        outs = {}
         for i, (kind, j) in enumerate(steps):
             j = r * per_unit[kind] + j
             carry, out = block(i, kind, j, _block(params, kind, j), carry)
             if out is not None:
-                outs.append(out)
-        return carry, outs or None
+                outs.setdefault(kind, []).append(out)
+        return carry, outs
 
     if n == 1:
         return one_unit(carry, 0)
@@ -416,13 +516,13 @@ def _walk(params, cfg, carry, block):
     def body(carry, r):
         with _moe.layer_load_tap() as tap:
             carry, outs = one_unit(carry, r)
-        return carry, (_stacked(outs),
+        return carry, ({k: _stacked(v) for k, v in outs.items()} or None,
                        tap.stacked() if tap is not None else None)
 
     carry, (outs, load) = lax.scan(body, carry, jnp.arange(n))
     _moe.record_expert_load(load)
-    # [repeats, attention blocks a unit, ...] -> [attention blocks, ...]
-    return carry, (None if outs is None else jax.tree.map(
+    # [repeats, blocks of the kind a unit, ...] -> [blocks of the kind, ...]
+    return carry, ({} if outs is None else jax.tree.map(
         lambda a: a.reshape((-1,) + a.shape[2:]), outs))
 
 
@@ -459,9 +559,11 @@ def forward(params, input_ids, cfg, *, deterministic: bool = True,
                 y, aux = _moe_mixer(p, h, cfg, train=not deterministic,
                                     rng=dropout_rng)
                 aux_total = aux_total + aux
+            elif kind == "dense":
+                y = _dense_mixer(p, h, cfg)
             else:
-                y = _attn_mixer(p, h, cfg)[0]
-            return (x + y, aux_total), None
+                y = _attn_mixer(p, h, cfg, kind)[0]
+            return (_residual(p, x, y, cfg), aux_total), None
 
     (x, aux_total), _ = _walk(
         params, cfg, (_embed(params, input_ids, cfg), jnp.float32(0.0)), block)
@@ -479,40 +581,73 @@ def forward(params, input_ids, cfg, *, deterministic: bool = True,
 def init_paged_cache(cfg, num_blocks: int, block_size: int, dtype=None,
                      max_seqs: Optional[int] = None):
     """``k``, ``v`` (+ int8 scale planes) exactly as ``transformer.
-    init_paged_cache`` lays them out, over the ATTENTION blocks only, and
-    ``ssm`` / ``conv`` (the ``M`` blocks) and ``gdn`` / ``gdn_conv`` (the
-    ``G`` blocks) for ``max_seqs`` slots, each pair only where the pattern
-    has such blocks."""
+    init_paged_cache`` lays them out, over the "*" ATTENTION blocks only,
+    and the per-slot leaves of ``state_leaves`` for ``max_seqs`` slots:
+    ``ssm`` / ``conv`` (the ``M`` blocks), ``gdn`` / ``gdn_conv`` (the ``G``
+    blocks), ``wk`` / ``wv`` (+ scales: the ``W`` blocks' rings), each only
+    where the pattern has such blocks."""
     import dataclasses
     from deepspeed_tpu.models import transformer as tf
     if max_seqs is None:
-        raise ValueError("a model with recurrent blocks keeps a state per "
-                         "serving slot: init_paged_cache needs max_seqs")
+        raise ValueError("a model with recurrent or window blocks keeps a "
+                         "state per serving slot: init_paged_cache needs "
+                         "max_seqs")
     dtype = dtype or cfg.dtype
     pools = tf.init_paged_cache(
-        dataclasses.replace(cfg, block_pattern=None,
+        dataclasses.replace(cfg, block_pattern=None, attn_windows=None,
                             num_layers=count(cfg, "attn")),
         num_blocks, block_size, dtype=dtype)
-    for name, shape in state_shapes(cfg, max_seqs).items():
-        pools[name] = jnp.zeros(
-            shape, dtype if name.endswith("conv") else jnp.float32)
+    for name, (shape, leaf_dtype) in state_leaves(cfg, max_seqs,
+                                                  dtype).items():
+        pools[name] = jnp.zeros(shape, leaf_dtype)
+    for name, (shape, leaf_dtype) in ring_leaves(cfg, max_seqs,
+                                                 dtype).items():
+        pools[name] = tuple(jnp.zeros(shape, leaf_dtype)
+                            for _ in range(count(cfg, "wattn")))
     return pools
 
 
-def state_shapes(cfg, max_seqs: int) -> dict:
-    """{leaf: shape} of the per-slot state pool: the recurrent state
-    (float32) and the convolution tail (the pool dtype) of each recurrent
-    kind the pattern has."""
+def state_leaves(cfg, max_seqs: int, dtype=None) -> dict:
+    """{leaf: (shape, dtype)} of the recurrent blocks' per-slot state: the
+    recurrent state (float32) and the convolution tail (the pool dtype) of
+    each recurrent kind the pattern has, stacked on the blocks of the kind."""
+    dtype = dtype or cfg.dtype
     out = {}
     Lm, Lg = count(cfg, "mamba"), count(cfg, "gdn")
     if Lm:
         nh, hd, _, N, _, conv_dim, K = mamba.dims(cfg)
-        out["ssm"] = (Lm, max_seqs, nh, hd, N)
-        out["conv"] = (Lm, max_seqs, K - 1, conv_dim)
+        out["ssm"] = ((Lm, max_seqs, nh, hd, N), jnp.float32)
+        out["conv"] = ((Lm, max_seqs, K - 1, conv_dim), dtype)
     if Lg:
         _, Hv, dk, dv, conv_dim, K = gdn.dims(cfg)
-        out["gdn"] = (Lg, max_seqs, Hv, dk, dv)
-        out["gdn_conv"] = (Lg, max_seqs, K - 1, conv_dim)
+        out["gdn"] = ((Lg, max_seqs, Hv, dk, dv), jnp.float32)
+        out["gdn_conv"] = ((Lg, max_seqs, K - 1, conv_dim), dtype)
+    return out
+
+
+def ring_leaves(cfg, max_seqs: int, dtype=None) -> dict:
+    """{leaf: (shape, dtype)} of ONE "W" block's rings (none without such
+    blocks): per slot the K/V of the last ``window(cfg)`` positions, position
+    ``p`` in row ``p mod window`` — ``wk``, ``wv`` [slots, window, n_kv, head
+    dim] and, for an int8 cache, ``wk_scale``, ``wv_scale`` [slots, n_kv x
+    window]: a slot is laid out as a block of the pool is (token-major rows,
+    head-major scales), so a ring row is quantised, written and read as a
+    pool row. The cache tree holds each as a TUPLE of one array a "W" block:
+    a block's step reads its own buffer and writes its row into it in place
+    (stacked on the blocks, the TPU compiler split the stack into its planes
+    and put them together again around every step: 4.3 GB of copies a step
+    at the published sizes, PERF.md section 6, PR 44). A slot's ring bytes
+    are fixed whatever its context; nothing of it is allocated or freed."""
+    if not count(cfg, "wattn"):
+        return {}
+    dtype = dtype or cfg.dtype
+    W, nkv, hd = window(cfg), cfg.kv_heads, cfg.dim_per_head
+    int8 = cfg.kv_cache_bits == 8
+    out = {}
+    for name in ("wk", "wv"):
+        out[name] = ((max_seqs, W, nkv, hd), jnp.int8 if int8 else dtype)
+        if int8:
+            out[name + "_scale"] = ((max_seqs, nkv * W), jnp.float32)
     return out
 
 
@@ -520,13 +655,155 @@ def paged_cache_logical_axes(cfg):
     from deepspeed_tpu.models import transformer as tf
     import dataclasses
     out = tf.paged_cache_logical_axes(
-        dataclasses.replace(cfg, block_pattern=None))
-    for name, shape in state_shapes(cfg, 1).items():
+        dataclasses.replace(cfg, block_pattern=None, attn_windows=None))
+    for name, (shape, _) in state_leaves(cfg, 1).items():
         out[name] = (None,) * len(shape)
+    for name, (shape, _) in ring_leaves(cfg, 1).items():
+        out[name] = ((None,) * len(shape),) * count(cfg, "wattn")
     return out
 
 
-STATE_LEAVES = ("ssm", "conv", "gdn", "gdn_conv")
+RING_LEAVES = ("wk", "wv", "wk_scale", "wv_scale")
+STATE_LEAVES = ("ssm", "conv", "gdn", "gdn_conv") + RING_LEAVES
+
+
+def _ring_rows(k, v, int8: bool, dtype):
+    """K/V rows [..., n_kv, T, hd] (head-major, as the projections give
+    them) as ring rows: {leaf: rows} token-major [..., T, n_kv, hd] and, for
+    an int8 cache, the scales [..., n_kv, T] (``_quant_kv``: a pool row's
+    quantisation)."""
+    from deepspeed_tpu.models.transformer import _quant_kv
+    out = {}
+    for name, a in (("wk", k), ("wv", v)):
+        if int8:
+            a, out[name + "_scale"] = _quant_kv(a)
+        out[name] = jnp.swapaxes(a, -3, -2).astype(jnp.int8 if int8 else dtype)
+    return out
+
+
+def _write_ring_prefill(ring, slot, k, v, true_len, cfg):
+    """The last ``window`` positions of a prompt's K/V, k / v [1, n_kv, P,
+    hd] of ONE "W" block, into ``slot``'s ring (``ring``: the block's {leaf:
+    array}): position ``p`` to row ``p mod window``. A bucket no longer than
+    the window is rows 0 .. P - 1 as they are (what lies behind them is an
+    earlier request's and is masked by position until a step overwrites it);
+    a longer one keeps the ``window`` positions that end at the last true
+    one."""
+    W = ring["wk"].shape[1]
+    P = k.shape[2]
+    if P > W:
+        start = jnp.clip(true_len - W, 0, P - W)
+        k, v = (lax.dynamic_slice_in_dim(a, start, W, axis=2) for a in (k, v))
+    out = {}
+    for name, a in _ring_rows(k[0], v[0], cfg.kv_cache_bits == 8,
+                              ring["wk"].dtype).items():
+        scale = name.endswith("_scale")
+        if P > W:            # position start + t -> row (start + t) mod W
+            a = jnp.roll(a, start % W, axis=1 if scale else 0)
+        leaf = ring[name]
+        if scale:                                       # [n_kv, T] -> lanes
+            for h in range(a.shape[0]):
+                leaf = lax.dynamic_update_slice(leaf, a[None, h],
+                                                (slot, h * W))
+        else:
+            leaf = lax.dynamic_update_slice(leaf, a[None], (slot, 0, 0, 0))
+        out[name] = leaf
+    return out
+
+
+def _ring_attention(q, ring, seq_lens, kv_row, cfg):
+    """One token per slot against ONE "W" block's rings (``ring``: the
+    block's {leaf: array}): q [S, 1, Nq, D], the token at position ``seq_lens[s]``; kv_row its fresh (k, v) [S,
+    Nkv, 1, D], folded into the same softmax and written afterwards.
+
+    Row ``r`` of a slot's ring holds the LAST position written there, ``p =
+    n - 1 - ((n - 1 - r) mod W)`` with ``n`` the slot's length; it is seen
+    iff ``p >= 0`` (the row was written by this request) and ``n - p < W``
+    (the band). What is read is the ring — W rows a slot and plane whatever
+    the context. The arithmetic is ``_paged_list_attention``'s recipe on one
+    run a slot: the query quantised per row, int8 x int8 -> int32 laid out
+    block-diagonally over the kv heads so that the rows are contracted
+    token-major as stored, probabilities x V scale requantised per row."""
+    from deepspeed_tpu.models.transformer import _quant_probs, _quant_query
+    S, _, Nq, D = q.shape
+    rk, rv = ring["wk"], ring["wv"]                      # [S, W, Nkv, D]
+    W, Nkv = rk.shape[1:3]
+    rep = Nq // Nkv
+    sm = cfg.attn_scale if cfg.attn_scale is not None else 1.0 / math.sqrt(D)
+    qg = q.reshape(S, Nkv, rep, D)
+    k_row, v_row = kv_row
+    int8 = "wk_scale" in ring
+    if int8:
+        ks, vs = (ring[n].reshape(S, Nkv, W)
+                  for n in ("wk_scale", "wv_scale"))
+        eye = jnp.eye(Nkv, dtype=jnp.int8)
+        qi, qs = _quant_query(qg.astype(jnp.float32))
+        qd = jnp.einsum("sgrd,gh->sgdhr", qi, eye)   # zeros off the diagonal
+        scores = jnp.einsum("stgd,sgdhr->shrt", rk, qd,
+                            preferred_element_type=jnp.int32
+                            ).astype(jnp.float32)
+        scores = scores * qs[..., None] * ks[:, :, None, :]
+    else:
+        scores = jnp.einsum("sgrd,stgd->sgrt", qg, rk).astype(jnp.float32)
+    scores = scores * sm
+    n = jnp.asarray(seq_lens, jnp.int32)[:, None]
+    pos = n - 1 - (n - 1 - jnp.arange(W)[None, :]) % W            # [S, W]
+    keep = (pos >= 0) & (n - pos < W)
+    scores = jnp.where(keep[:, None, None, :], scores, -1e30)
+    s_self = jnp.einsum("sgrd,sgtd->sgrt", qg,
+                        k_row.astype(qg.dtype)).astype(jnp.float32) * sm
+    probs = jax.nn.softmax(jnp.concatenate([scores, s_self], axis=-1),
+                           axis=-1)
+    pp = probs[..., :W]
+    if int8:
+        pvi, ps = _quant_probs(pp * vs[:, :, None, :])
+        pd = jnp.einsum("shrt,hg->shrtg", pvi, eye)
+        acc = jnp.einsum("shrtg,stgd->shrd", pd, rv,
+                         preferred_element_type=jnp.int32)
+        out = (acc.astype(jnp.float32) * ps[..., None]).astype(q.dtype)
+    else:
+        out = jnp.einsum("sgrt,stgd->sgrd", pp.astype(q.dtype), rv,
+                         preferred_element_type=jnp.float32).astype(q.dtype)
+    out = out + probs[..., W:].astype(q.dtype) * v_row.astype(q.dtype)
+    return out.reshape(S, 1, Nq, D)
+
+
+def _write_ring_rows(ring, seq_lens, active, k_row, v_row, cfg):
+    """A step's fresh rows, k_row / v_row [S, n_kv, hd], into every ACTIVE
+    slot's ring of ONE "W" block at row ``seq_lens mod window``, one scatter
+    per head whose window is a head's row alone (``_write_rows`` says why).
+    An inactive slot's index is out of range and its update dropped: a ring
+    has no trash row, and a slot between two requests keeps what it holds."""
+    S, nkv = k_row.shape[:2]
+    W = ring["wk"].shape[1]
+    slot = jnp.where(active, jnp.arange(S), S)
+    row = jnp.asarray(seq_lens, jnp.int32) % W
+    out = {}
+    for name, r in _ring_rows(k_row[:, :, None], v_row[:, :, None],
+                              cfg.kv_cache_bits == 8,
+                              ring["wk"].dtype).items():
+        leaf = ring[name]
+        for h in range(nkv):
+            if name.endswith("_scale"):              # r [S, n_kv, 1]
+                leaf = leaf.at[slot, h * W + row].set(r[:, h, 0], mode="drop")
+            else:                                    # r [S, 1, n_kv, hd]
+                leaf = leaf.at[slot, row, h].set(r[:, 0, h], mode="drop")
+        out[name] = leaf
+    return out
+
+
+def _rings(state):
+    """The cache tree's ring leaves, {leaf: tuple a block} -> one {leaf:
+    array} a "W" block."""
+    names = [n for n in RING_LEAVES if n in state]
+    return [dict(zip(names, leaves))
+            for leaves in zip(*(state[n] for n in names))]
+
+
+def _set_rings(state, rings):
+    for name in rings[0]:
+        state[name] = tuple(r[name] for r in rings)
+    return state
 
 
 def prefill_paged(params, input_ids, cfg, pools, block_ids,
@@ -566,10 +843,12 @@ def prefill_paged(params, input_ids, cfg, pools, block_ids,
                         tail.astype(state["gdn_conv"].dtype))
             elif kind == "moe":
                 y, _ = _moe_mixer(p, h, cfg)
+            elif kind == "dense":
+                y = _dense_mixer(p, h, cfg)
             else:
-                y, k, v = _attn_mixer(p, h, cfg)
+                y, k, v = _attn_mixer(p, h, cfg, kind)
                 out = (jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2))
-            return (x + y, state), out                     # [1, nkv, P, hd]
+            return (_residual(p, x, y, cfg), state), out   # [1, nkv, P, hd]
 
     x = _embed(params, input_ids, cfg)                            # [1, P, H]
     real = jnp.arange(P)[None] < true_len
@@ -577,11 +856,17 @@ def prefill_paged(params, input_ids, cfg, pools, block_ids,
         (x, state), kv = _walk(
             params, cfg,
             (x, {n: pools[n] for n in STATE_LEAVES if n in pools}), block)
+    if "wattn" in kv:
+        with jax.named_scope("attn"), jax.named_scope("window"), \
+                jax.named_scope("kv_write"):
+            state = _set_rings(state, [
+                _write_ring_prefill(ring, slot, k, v, true_len, cfg)
+                for ring, (k, v) in zip(_rings(state), kv["wattn"])])
     pools.update(state)
-    if kv is not None:
+    if "attn" in kv:
         # the attention blocks' K/V as transformer.prefill_paged's
         # contiguous cache holds them, [La, 1, nkv, P, hd]: one writer
-        cache = dict(zip(("k", "v"), _stacked(kv)))
+        cache = dict(zip(("k", "v"), _stacked(kv["attn"])))
         if cfg.kv_cache_bits == 8:
             (cache["k"], cache["k_scale"]), (cache["v"], cache["v_scale"]) = \
                 _quant_kv(cache["k"]), _quant_kv(cache["v"])
@@ -659,32 +944,48 @@ def decode_step_paged(params, tokens, cfg, pools, block_tables, seq_lens,
                 y = y[:, None]
             elif kind == "moe":
                 y, _ = _moe_mixer(p, h, cfg)
+            elif kind == "dense":
+                y = _dense_mixer(p, h, cfg)
             else:
-                q, k, v, gate = _qkv(p, h, cfg, seq_lens[:, None])
+                local = kind == "wattn"
+                q, k, v, gate = _qkv(p, h, cfg, seq_lens[:, None],
+                                     rotary=True if local else None)
                 row_dtype = cfg.dtype if int8_kv else pools["k"].dtype
                 k_row = jnp.swapaxes(k, 1, 2).astype(row_dtype)
                 v_row = jnp.swapaxes(v, 1, 2).astype(row_dtype)
-                with jax.named_scope("attn"):
-                    o = _paged_attention(
-                        q, pools["k"], pools["v"], block_tables, seq_lens,
-                        cfg, kv_row=(k_row, v_row), kv_scale=sc,
-                        backend=backend, window=None, layer=j)
+                if local:
+                    with jax.named_scope("attn"), jax.named_scope("window"):
+                        o = _ring_attention(q, _rings(state)[j], seq_lens,
+                                            (k_row, v_row), cfg)
+                else:
+                    with jax.named_scope("attn"):
+                        o = _paged_attention(
+                            q, pools["k"], pools["v"], block_tables,
+                            seq_lens, cfg, kv_row=(k_row, v_row),
+                            kv_scale=sc, backend=backend, window=None,
+                            layer=j)
                 y = _out(p, o.reshape(S, 1, -1), gate)
                 out = (k_row[:, :, 0], v_row[:, :, 0])
-            return (x + y, state), out
+            return (_residual(p, x, y, cfg), state), out
 
     with _moe.counted_tokens(active):
         (x, state), rows = _walk(
             params, cfg,
             (_embed(params, tokens[:, None], cfg),                # [S, 1, H]
              {n: pools[n] for n in STATE_LEAVES if n in pools}), block)
+    if "wattn" in rows:
+        with jax.named_scope("attn"), jax.named_scope("window"), \
+                jax.named_scope("kv_write"):
+            state = _set_rings(state, [
+                _write_ring_rows(ring, seq_lens, active, k, v, cfg)
+                for ring, (k, v) in zip(_rings(state), rows["wattn"])])
     pools.update(state)
-    if rows is not None:
+    if "attn" in rows:
         with jax.named_scope("attn"), jax.named_scope("kv_write"):
             blk = jnp.where(active, _block_at(block_tables, seq_lens // bs),
                             0)
             off = jnp.where(active, seq_lens % bs, 0)
-            kr, vr = _stacked(rows)                        # [La, S, nkv, hd]
+            kr, vr = _stacked(rows["attn"])                # [La, S, nkv, hd]
             if int8_kv:
                 (kq, ks), (vq, vs) = _quant_kv(kr), _quant_kv(vr)
                 rows = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}

@@ -172,7 +172,15 @@ class TransformerConfig:
     # state [dk, dv] per value head, the same `conv_kernel`, chunks of
     # `gdn_chunk` in prefill. A hybrid stack's attention may be rotary
     # (`position_type`, `rotary_dim`).
+    # "W" (afmoe) is attention over the last `attn_windows` positions,
+    # always rotary, its K/V a ring per serving slot; "*" beside it follows
+    # `position_type` (afmoe: "none"), so the rotary rule is per KIND. "D"
+    # is a dense feed-forward of width `dense_ffn_size` (0: the experts'
+    # `intermediate_size`). `embed_scale` multiplies the token embeddings
+    # (afmoe's muP factor sqrt(hidden_size); 1: every other family).
     block_pattern: Optional[str] = None
+    dense_ffn_size: int = 0
+    embed_scale: float = 1.0
     gdn_num_k_heads: int = 0
     gdn_num_v_heads: int = 0
     gdn_head_k_dim: int = 0
@@ -263,6 +271,20 @@ class TransformerConfig:
         return pattern.count("M") + pattern.count("G")
 
     @property
+    def window_blocks(self) -> int:
+        """Blocks whose K/V is a ring of the last ``attn_windows`` positions
+        per serving slot (the "W" blocks of a hybrid stack)."""
+        return (self.block_pattern or "").count("W")
+
+    @property
+    def slot_state_blocks(self) -> int:
+        """Blocks that keep something PER SERVING SLOT beside the K/V block
+        pool: a recurrent state or a window ring. What the serving engine
+        asks before it shares, rolls back or ships a request by its blocks
+        alone."""
+        return self.recurrent_blocks + self.window_blocks
+
+    @property
     def moe_router_width(self) -> int:
         """Experts the router scores: the model's, of which this chip holds
         ``num_experts``."""
@@ -270,18 +292,19 @@ class TransformerConfig:
 
     @property
     def attention_blocks(self) -> int:
-        """Blocks that own K/V: every layer of a homogeneous stack, the "*"
-        blocks of a hybrid one."""
+        """Blocks of softmax attention: every layer of a homogeneous stack,
+        the "*" and "W" blocks of a hybrid one."""
         if self.block_pattern is None:
             return self.num_layers
-        return self.block_pattern.count("*")
+        return self.block_pattern.count("*") + self.window_blocks
 
     @property
     def kv_planes(self) -> int:
-        """Planes of K/V cache a token keeps: one per pass and block that
-        owns K/V. THE number every cache, pool, byte count and handoff
-        geometry is sized by."""
-        return self.ut_steps * self.attention_blocks
+        """Planes of K/V a token keeps IN THE BLOCK POOL: one per pass and
+        block that owns K/V there (a "W" block keeps a ring per slot
+        instead: ``window_blocks``). THE number every cache, pool, byte
+        count and handoff geometry is sized by."""
+        return self.ut_steps * (self.attention_blocks - self.window_blocks)
 
     @property
     def dim_per_head(self) -> int:
@@ -694,8 +717,10 @@ def attention(q, k, v, mask=None, *, causal: bool = True, cfg: TransformerConfig
     """q: [B,S,Nq,D], k/v: [B,S,Nkv,D] -> [B,S,Nq,D].
 
     window: local-attention band width (key j visible to query i iff
-    i - j < window); a traced scalar — <= 0 means global. Windowed layers
-    take the XLA path (the flash/ring/sparse kernels have no band mask).
+    i - j < window); a traced scalar — <= 0 means global — takes the XLA
+    path (the ring and sparse kernels have no band mask); a STATIC length
+    (a Python int, causal, no mask or segments) takes the flash kernel's
+    banded forward where the flash kernel would run.
 
     segment_ids: int [B, S], several sequences packed into a row, numbered
     from 0 — key j is visible to query i only where the two ids are equal
@@ -723,11 +748,16 @@ def _attention_kernel(q, k, v, mask, causal: bool, cfg: TransformerConfig,
                       segment_ids, window):
     """The kernel that takes these rows in place of the XLA path, as the
     call to make — or None where none does."""
-    if window is not None:
-        return None
     from deepspeed_tpu.parallel.context import seq_parallel_degree, current_mesh
     S, Nq, D = q.shape[1:]
     sm = cfg.attn_scale if cfg.attn_scale is not None else 1.0 / math.sqrt(D)
+    if window is not None:
+        if (isinstance(window, int) and window > 0 and causal and mask is None
+                and segment_ids is None and _use_pallas(cfg, S)
+                and not cfg.sparse_attention and seq_parallel_degree() <= 1):
+            return lambda: _flash_per_shard(q, k, v, None, causal=True,
+                                            sm_scale=sm, window=window)
+        return None
     # the Pallas flash kernel is GQA-native (K/V never repeated in HBM) and
     # handles key-padding masks in-kernel; other paths get the repeated view
     if _use_pallas(cfg, S) and not cfg.sparse_attention \

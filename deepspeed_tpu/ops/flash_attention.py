@@ -252,6 +252,141 @@ def _fwd(q, k, v, kv_mask, sm_scale, causal, block_q, block_k):
 
 
 # --------------------------------------------------------------------------
+# forward, banded: key j visible to query i iff 0 <= i - j < window
+# --------------------------------------------------------------------------
+
+def _band_tiles(window: int, bq: int, bk: int, s: int) -> int:
+    """Key tiles the band of ONE query tile can touch: its keys span ``bq +
+    window - 1`` positions, which start anywhere in a tile."""
+    return min(s // bk, (bq + window - 2) // bk + 2)
+
+
+def _band_first(i, window, bq, bk):
+    """The first key tile that query tile ``i``'s band touches."""
+    return jax.lax.div(jnp.maximum(i * bq - (window - 1), 0), bk)
+
+
+def _band_kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *, sm_scale,
+                 window, rep, block_q, block_k):
+    """``_fwd_kernel`` over the key tiles of a query tile's band alone: step
+    ``t`` of the innermost grid dim is key tile ``first + t``. A tile that
+    lies wholly inside the band takes the body without a mask, one that the
+    band's or the diagonal's edge crosses is masked, one past the diagonal
+    is skipped (its DMA too: the index map clamps it to the diagonal's)."""
+    qi = pl.program_id(2)
+    t = pl.program_id(3)
+    d = q_ref.shape[-1]
+    rows = rep * block_q
+    kj = _band_first(qi, window, block_q, block_k) + t
+    q_lo, k_lo = qi * block_q, kj * block_k
+
+    @pl.when(t == 0)
+    def _init():
+        m_s[:] = jnp.full_like(m_s, NEG_INF)
+        l_s[:] = jnp.zeros_like(l_s)
+        acc_s[:] = jnp.zeros_like(acc_s)
+
+    def step(masked: bool):
+        q = q_ref[0, 0].astype(jnp.float32).reshape(rows, d) * sm_scale
+        k = k_ref[0, 0].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        if masked:
+            q_pos = q_lo + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, block_k), 0) % block_q
+            k_pos = k_lo + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, block_k), 1)
+            s = jnp.where((q_pos >= k_pos) & (q_pos - k_pos < window), s,
+                          NEG_INF)
+        m = m_s[:, 0:1]
+        l = l_s[:, 0:1]
+        m_new = jnp.maximum(jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True)),
+                            M_FLOOR)
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_s[:] = acc_s[:] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
+        l_s[:] = jnp.broadcast_to(l_new, l_s.shape)
+
+    # the tile's last key at or before the first query, and its first key
+    # inside the LAST query's band: every (query, key) pair is visible
+    inside = ((k_lo + block_k - 1 <= q_lo)
+              & (q_lo + block_q - 1 - k_lo < window))
+    visible = k_lo <= q_lo + block_q - 1        # not past the diagonal
+    pl.when(inside)(lambda: step(False))
+    pl.when(visible & jnp.logical_not(inside))(lambda: step(True))
+
+    @pl.when(t == pl.num_programs(3) - 1)
+    def _finalize():
+        l = l_s[:, 0:1]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0, 0] = (acc_s[:] / l_safe).reshape(rep, block_q, d).astype(
+            o_ref.dtype)
+
+
+def _fwd_band(q, k, v, sm_scale, window, block_q, block_k):
+    """q [B, N, S, D], k / v [B, Nkv, S, D] -> o, causal within the band."""
+    B, N, S, D = q.shape
+    Nkv = k.shape[1]
+    rep = N // Nkv
+    bq, bk = _pick_blocks(S, block_q, block_k, rep)
+    rows = rep * bq
+
+    def kv_index(b, g, i, t):
+        last = jax.lax.div((i + 1) * bq - 1, bk)         # the diagonal's tile
+        return (b, g, jnp.minimum(_band_first(i, window, bq, bk) + t, last), 0)
+
+    kv_spec = pl.BlockSpec((1, 1, bk, D), kv_index, memory_space=pltpu.VMEM)
+    q_spec = pl.BlockSpec((1, 1, rep, bq, D),
+                          lambda b, g, i, t: (b, g, 0, i, 0),
+                          memory_space=pltpu.VMEM)
+    o = pl.pallas_call(
+        functools.partial(_band_kernel, sm_scale=sm_scale, window=window,
+                          rep=rep, block_q=bq, block_k=bk),
+        grid=(B, Nkv, S // bq, _band_tiles(window, bq, bk, S)),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Nkv, rep, S, D), q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((rows, 128), jnp.float32),   # m (lane-padded)
+            pltpu.VMEM((rows, 128), jnp.float32),   # l
+            pltpu.VMEM((rows, D), jnp.float32),     # acc
+        ],
+        compiler_params=_compiler_params(3),
+        interpret=_interpret(),
+        name="flash_fwd_band",
+    )(q.reshape(B, Nkv, rep, S, D), k, v)
+    return o.reshape(B, N, S, D)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_band(q, k, v, sm_scale, window, block_q, block_k):
+    return _fwd_band(q, k, v, sm_scale, window, block_q, block_k)
+
+
+def _flash_band_fwd(q, k, v, sm_scale, window, block_q, block_k):
+    return _flash_band(q, k, v, sm_scale, window, block_q, block_k), (q, k, v)
+
+
+def _flash_band_bwd(sm_scale, window, block_q, block_k, residuals, g):
+    """DEFERRED: no banded backward kernel. The gradient is taken through
+    the materialised scores under the band mask (``reference_attention``),
+    O(S^2) memory, which is what a windowed layer's whole attention cost
+    before the banded forward."""
+    def ref(q, k, v):           # [B, N, S, D] -> the same, via [B, S, N, D]
+        return jnp.swapaxes(reference_attention(
+            *(jnp.swapaxes(a, 1, 2) for a in (q, k, v)), causal=True,
+            sm_scale=sm_scale, window=window), 1, 2)
+    return jax.vjp(ref, *residuals)[1](g)
+
+
+_flash_band.defvjp(_flash_band_fwd, _flash_band_bwd)
+
+
+# --------------------------------------------------------------------------
 # backward
 # --------------------------------------------------------------------------
 
@@ -514,9 +649,15 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     kv_mask=None,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K,
-                    fused_backward: bool = False):
+                    fused_backward: bool = False,
+                    window: Optional[int] = None):
     """q: [B, S, Nq, D]; k, v: [B, S, Nkv, D] (Nkv may divide Nq: GQA runs
     natively without repeating K/V) -> [B, S, Nq, D].
+
+    window: a static length W — key j is visible to query i iff 0 <= i - j <
+    W (causal within a band). The forward visits only the key tiles a query
+    tile's band touches (``flash_fwd_band``); the backward has no banded
+    kernel and goes through materialised scores. Causal, no ``kv_mask``.
 
     kv_mask: optional [B, S] bool/int padding mask over keys — masked
     positions are excluded inside the kernel (no O(S^2) fallback).
@@ -527,6 +668,16 @@ def flash_attention(q, k, v, *, causal: bool = True,
     if q.shape[2] % k.shape[2]:
         raise ValueError(f"n_q_heads {q.shape[2]} not divisible by "
                          f"n_kv_heads {k.shape[2]}")
+    if window is not None:
+        if not causal or kv_mask is not None or window < 1:
+            raise ValueError(
+                "flash_attention(window=...) is causal attention within a "
+                f"band of >= 1 positions and takes no kv_mask (causal="
+                f"{causal}, window={window}, kv_mask "
+                f"{'given' if kv_mask is not None else 'None'})")
+        o = _flash_band(*(jnp.swapaxes(a, 1, 2) for a in (q, k, v)),
+                        float(sm_scale), int(window), block_q, block_k)
+        return jnp.swapaxes(o, 1, 2)
     if kv_mask is not None and not _interpret():
         # the blocked mask spec needs block_k % 128 == 0 on TPU; _pick_blocks
         # halves from a power-of-two >= 128, so any S % 128 == 0 lands there
@@ -550,8 +701,10 @@ def flash_attention(q, k, v, *, causal: bool = True,
 
 
 def reference_attention(q, k, v, *, causal: bool = True,
-                        sm_scale: Optional[float] = None):
-    """XLA reference for parity tests (handles GQA by repeat)."""
+                        sm_scale: Optional[float] = None,
+                        window: Optional[int] = None):
+    """XLA reference for parity tests (handles GQA by repeat); ``window``:
+    key j visible to query i only where i - j < window."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     B, S, N, D = q.shape
@@ -564,5 +717,9 @@ def reference_attention(q, k, v, *, causal: bool = True,
     if causal:
         mask = jnp.tril(jnp.ones((S, S), jnp.bool_))
         s = jnp.where(mask[None, None], s, NEG_INF)
+    if window is not None:
+        pos = jnp.arange(S)
+        s = jnp.where((pos[:, None] - pos[None, :] < window)[None, None], s,
+                      NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bnst,btnd->bsnd", p, v.astype(jnp.float32)).astype(q.dtype)

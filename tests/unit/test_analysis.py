@@ -659,8 +659,9 @@ class TestDtypeAndFlash:
 
     def test_flash_survives_static_windows_unrolled(self):
         """attn_windows=(0, w): the unrolled path passes STATIC windows, so
-        the global layer keeps the flash/Pallas kernel; under scan the
-        traced window pushes every layer to the XLA path (documented cost).
+        the global layer keeps the flash/Pallas kernel and (since PR 44) the
+        windowed layer takes its banded forward; under scan the traced
+        window pushes every layer to the XLA path (documented cost).
         Confirmed at jaxpr level via the analysis census."""
         counts = {}
         for scan in (False, True):
@@ -674,7 +675,7 @@ class TestDtypeAndFlash:
             census = jaxpr_primitive_census(
                 lambda p, b: model.loss_fn(p, b, None, True), params, batch)
             counts[scan] = census.get("pallas_call", 0)
-        assert counts[False] == 1, counts  # global layer keeps flash
+        assert counts[False] == 2, counts  # flash + its banded forward
         assert counts[True] == 0, counts   # scan: traced window, XLA path
 
 

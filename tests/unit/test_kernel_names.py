@@ -282,3 +282,59 @@ def test_gdn_instruction_names_at_the_published_sizes(topo, mosaic):
     mem = step.memory_analysis()
     assert mem.alias_size_in_bytes == 9 * S * Hv * dk * dv * 4
     assert mem.temp_size_in_bytes < 64 * 2 ** 20
+
+
+# ---- the banded flash forward (afmoe's sliding layers) ---------------------
+
+def test_band_kernel_name_and_window_scopes_in_the_lowered_text():
+    """``flash_fwd_band`` by name, and the two attention kinds told apart
+    under ``attn/``: a sliding block's attention, ring read and ring write
+    lie under ``attn/window``, the full block's under ``attn`` alone."""
+    import os
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))))
+    from benchmark.families.afmoe import TOY
+    from deepspeed_tpu.models import make_model
+    from deepspeed_tpu.models.hf_import import hf_config_to_transformer
+    q = jnp.zeros((1, 128, 6, 64), jnp.float32)
+    k = jnp.zeros((1, 128, 1, 64), jnp.float32)
+    band = jax.jit(lambda q, k, v: fa.flash_attention(q, k, v, window=32)
+                   ).lower(q, k, k).as_text(debug_info=True)
+    assert re.search(r'"jit\([^)]*\)/[^"]*\bflash_fwd_band\b[^"]*/pallas_call"',
+                     band)
+    hf = {"model_type": "afmoe", "max_position_embeddings": 256,
+          "num_experts_per_tok": 4, "sliding_window": 32, **TOY}
+    model = make_model(hf_config_to_transformer(hf, dtype=jnp.float32))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    pools = jax.eval_shape(lambda: model.init_paged_cache(
+        17, 16, dtype=jnp.float32, max_seqs=2))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)       # noqa: E731
+    step = jax.jit(model.decode_step_paged).lower(
+        params, i32(2), pools, i32(2, 8), i32(2)).as_text(debug_info=True)
+    prefill = jax.jit(model.prefill_paged).lower(
+        params, i32(1, 128), pools, i32(8), length=i32(), slot=i32()
+    ).as_text(debug_info=True)
+    # layer0 is a sliding block, layer6 the full one ("WDWEWE*EWE")
+    for text in (step, prefill):
+        assert re.search(r'/layer0/attn/window/', text)
+        assert re.search(r'/layer6/attn/', text)
+        assert not re.search(r'/layer6/attn/window/', text)
+        assert re.search(r'/layer0/attn/out_gate/', text)
+        assert re.search(r'/layer1/mlp/', text)
+        assert re.search(r'"jit\([^)]*\)/attn/window/kv_write/', text)
+        assert re.search(r'"jit\([^)]*\)/attn/kv_write/scatter"', text)
+
+
+def test_band_instruction_name_at_the_published_sizes(topo, mosaic):
+    """Trinity's sliding layers at the cell's longest prompt bucket: 48
+    query heads over 8 K/V heads of 128, 9216 positions, window 4096 — ONE
+    Mosaic call, by its own name, whose grid's innermost extent is the six
+    key tiles a query tile's band can touch (of nine)."""
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    q, kv = (_sds((1, 9216, n, 128), jnp.bfloat16, one) for n in (48, 8))
+    compiled = jax.jit(lambda q, k, v: fa.flash_attention(
+        q, k, v, window=4096)).lower(q, kv, kv).compile()
+    calls = _mosaic_calls(compiled)
+    assert [c.split(".")[0] for c in calls] == ["%flash_fwd_band"], calls
+    assert fa._band_tiles(4096, *fa._pick_blocks(9216, 512, 1024, 6), 9216) == 6

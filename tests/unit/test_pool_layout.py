@@ -492,7 +492,7 @@ def test_the_looped_cells_programs_fit_the_chip_and_write_the_pool_in_place(
     srv = object.__new__(ServingEngine)
     srv.model, srv.decode_backend = model, "xla"
     srv.config = ServingConfig(max_seqs=serving["max_seqs"])
-    srv._moe_forms, srv._prefill_fns, srv._recurrent = {}, {}, 0
+    srv._moe_forms, srv._prefill_fns, srv._slot_state = {}, {}, 0
     srv._repl_sharding = srv._pool_shardings = None
 
     def sds(shape, dtype):
@@ -537,3 +537,86 @@ def test_the_looped_cells_programs_fit_the_chip_and_write_the_pool_in_place(
         # the exit gate's counter leaves with the tokens
         (_, (tokens, (load, exits)), lens) = compiled.out_info
         assert load is None and exits.shape == (cfg.ut_steps + 1,)
+
+
+# ---- window rings beside the pool (ISSUE 44) ---------------------------------
+
+@pytest.mark.parametrize("kind,width", [("step", 88), ("prefill", 9216)])
+def test_the_window_cells_programs_fit_the_chip_and_write_the_rings_in_place(
+        kind, width, one_chip, monkeypatch):
+    """Trinity-Large at the PUBLISHED widths and the cell's shapes
+    (benchmark/configs/trinity-large-serve.json: 64 slots, a ring of 4096
+    rows a slot and sliding block, one full plane of 11 265 blocks) through
+    the engine's own step (64 slots x 88 columns) and its longest prefill
+    (9216 tokens): the program compiles for the described v5e, arguments +
+    temporaries fit the chip's 15.75 GiB, the banded kernel is in the
+    prefill, and no op outside a fused scatter or block write reads and
+    writes a whole ring or pool leaf (a ring leaf is 268 MB: stacked on
+    their blocks, the rings were split and put together again around every
+    step, 4.3 GB of copies)."""
+    import json
+    import os
+    from deepspeed_tpu.inference.serving import ServingConfig, ServingEngine
+    from deepspeed_tpu.models.hf_import import hf_config_to_transformer
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs", "trinity-large-serve.json")) as f:
+        conf = json.load(f)
+    hf = {k: v for k, v in conf.items() if k not in (
+        "source", "reduced", "assumed", "deployment", "run", "correct")}
+    serving = conf["run"]["serving"]
+    S, MB = serving["max_seqs"], serving["max_model_len"] // BS
+    cfg = hf_config_to_transformer(hf, max_seq_len=serving["max_model_len"],
+                                   dtype=jnp.bfloat16, kv_cache_bits=8)
+    model = make_model(cfg)
+    params = _abstract(jax.eval_shape(lambda: jax.tree.map(
+        lambda a: a.astype(jnp.bfloat16), model.init(jax.random.PRNGKey(0)))),
+        one_chip)
+    pools = _abstract(jax.eval_shape(lambda: model.init_paged_cache(
+        S * MB + 1, BS, dtype=jnp.bfloat16, max_seqs=S)), one_chip)
+    assert pools["k"].shape == (1, S * MB + 1, BS, 8, HD)
+    assert len(pools["wk"]) == 4 and pools["wk"][0].shape == (S, 4096, 8, HD)
+    assert pools["wk"][0].dtype == jnp.int8
+    srv = object.__new__(ServingEngine)
+    srv.model, srv.decode_backend = model, "xla"
+    srv.config = ServingConfig(max_seqs=S)
+    srv._moe_forms, srv._prefill_fns, srv._slot_state = {}, {}, 4
+    srv._repl_sharding = srv._pool_shardings = None
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    key = sds((2,), jnp.uint32)
+    if kind == "step":
+        fn = jax.jit(srv._quantum_step_fn().__wrapped__, donate_argnums=(1, 4))
+        args = (params, pools, sds((S,), jnp.int32),
+                _block_list(sds, S, width, MB),
+                sds((S,), jnp.int32), sds((S,), jnp.bool_), key)
+    else:
+        fn = jax.jit(srv._get_prefill_fn(width).__wrapped__, donate_argnums=(2,))
+        args = (params, sds((1, width), jnp.int32), pools,
+                sds((width // BS,), jnp.int32), sds((), jnp.int32), key,
+                sds((), jnp.int32))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        with jax.default_matmul_precision("default"):
+            compiled = fn.lower(*args).compile()
+    finally:
+        monkeypatch.undo()
+    hlo, mem = compiled.as_text(), compiled.memory_analysis()
+    resident = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    print(f"{kind} {width}: arguments {mem.argument_size_in_bytes / 2**30:.2f} GiB, "
+          f"temporaries {mem.temp_size_in_bytes / 2**30:.2f} GiB")
+    assert resident < 15.75 * 2**30, resident
+    in_place = _dus_fusions(hlo)
+    whole = {l.strip().split(" = ")[0]: l for l in hlo.splitlines()}
+
+    def callee(line):              # `line` is cut short: find it whole
+        m = re.search(r"calls=%([\w.\-]+)", whole[line.split(" = ")[0]])
+        return m and m.group(1)
+    # (a block's scale planes, 8 MB each, ARE copied once a step into the
+    # [slots, heads, window] view the scores take them in: 0.1 GB a step)
+    leaves = {"k": pools["k"], "wk": pools["wk"][0]}
+    bad = [line for line in whole_pool_ops(hlo, leaves)
+           if callee(line) not in in_place]
+    assert not bad, "\n".join(b[:300] for b in bad)
+    if kind == "prefill":
+        assert "flash_fwd_band" in hlo and "flash_fwd" in hlo

@@ -17,6 +17,13 @@ PR 43 added ``packed128``: the 128-token bucket in the serving engine's form,
 ``prefill_paged(..., segments=(starts[4], lengths[4]))`` — a row of up to four
 prompts; how many are live is data, not text — for the four families whose
 stack keeps no recurrent state, printed from PR 43's tree. No other row moved.
+
+PR 44 added ``trinity-large-serve`` (``afmoe``: window rings beside the pool,
+a toy window of 64, so ``prefill32`` lands in a ring as it is and
+``prefill128`` keeps the last 64 positions), printed from PR 44's tree, and
+``qwen3-next-80b-a3b-serve`` (one period, unrolled), printed from a checkout
+of PR 43's tree: PR 44 changed the hybrid walker it shares. No other row
+moved.
 """
 import functools
 import hashlib
@@ -28,9 +35,14 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 CONFIGS = ("mistral-7b-serve", "mixtral-8x7b-serve", "olmoe-1b-7b-serve",
-           "nemotron-3-nano-30b-serve", "ouro-2.6b-serve")
+           "nemotron-3-nano-30b-serve", "ouro-2.6b-serve",
+           "qwen3-next-80b-a3b-serve", "trinity-large-serve")
+# a family's toy keeps the published window: this one is cut below a bucket
+WINDOW = {"trinity-large-serve": {"sliding_window": 64}}
 PROGRAMS = ("step", "prefill32", "prefill128", "forward")
-PACKED = tuple(c for c in CONFIGS if c != "nemotron-3-nano-30b-serve")
+PACKED = tuple(c for c in CONFIGS if c not in (
+    "nemotron-3-nano-30b-serve", "qwen3-next-80b-a3b-serve",
+    "trinity-large-serve"))
 GOLDEN = {
     "mistral-7b-serve": {
         "step": "050edea2bb48db05", "prefill32": "40748e39cf90df13",
@@ -51,6 +63,12 @@ GOLDEN = {
         "step": "7017150c3367e0e7", "prefill32": "6bb86f01468a6b01",
         "prefill128": "906f6315e0dd77a9", "forward": "ac33f278ea46fd12",
         "packed128": "f4ad5b70a63c4b85"},
+    "qwen3-next-80b-a3b-serve": {
+        "step": "87836351bbac143f", "prefill32": "e5ddcd5648f5e3d8",
+        "prefill128": "859d3c6691ee4065", "forward": "d8797f82f05dbbbf"},
+    "trinity-large-serve": {
+        "step": "905ed8ffb948fa92", "prefill32": "91900aa4b4516e0f",
+        "prefill128": "ad76a41a43090cac", "forward": "f9d41ce519bcc2ed"},
 }
 
 
@@ -65,7 +83,8 @@ def lowered(name):
     from deepspeed_tpu.moe.sharded_moe import expert_load_tap
 
     cfgf = common.load_config(name)
-    cfg = hf_config_to_transformer(common.hf_of(cfgf, rehearsal=True),
+    cfg = hf_config_to_transformer(dict(common.hf_of(cfgf, rehearsal=True),
+                                        **WINDOW.get(name, {})),
                                    max_seq_len=256,
                                    **cfgf["run"].get("overrides", {}))
     model = make_model(cfg)
