@@ -1882,9 +1882,10 @@ def _hold_expert_stacks(layers: Params, cfg: TransformerConfig,
     to the layer WHOLE with the layer's index (``_moe.LayerOf``), because
     the grouped-matmul kernel would otherwise be fed a fresh copy of the
     layer's experts (2.4 GB a layer at OLMoE's widths). A one-token-a-slot
-    step never reaches the kernel below 256 slots and keeps its slices; so
-    does training: a whole stack closed over by a scan body gets a
-    whole-stack cotangent per iteration."""
+    step hands them whole BESIDE its slices (``decode_step_paged``: it
+    reaches the kernel only where its rows are expected to touch few
+    experts); training keeps its slices: a whole stack closed over by a
+    scan body gets a whole-stack cotangent per iteration."""
     if not (deterministic and cfg.num_experts > 1 and not cfg.drop_tokens
             and not cfg.quantized_weights and not cfg.offload_params):
         return layers, {}
@@ -2774,8 +2775,16 @@ def decode_step_paged(params: Params, tokens, cfg: TransformerConfig,
     wins = (jnp.asarray(cfg.attn_windows, jnp.int32)
             if cfg.attn_windows else None)
 
+    _, held = _hold_expert_stacks(params["layers"], cfg)
+
     def body(x_c, i, t):
         layer_p = at_layer(params["layers"], i)
+        # the expert stacks go WHOLE beside their slices: a step whose rows
+        # are expected to reach few experts sorts (``_moe._sorts``) and its
+        # kernel reads the layer's experts in place; one that keeps the
+        # one-hot form takes the slices, as it always did
+        layer_p = {**layer_p, **{k: _moe.LayerOf(v, i, layer_p[k])
+                                 for k, v in held.items()}}
         # the WHOLE pools: the plane — the layer, and the pass of a looped
         # stack — is a coordinate of the read's gather
         sc = (pools["k_scale"], pools["v_scale"]) if int8_kv else None

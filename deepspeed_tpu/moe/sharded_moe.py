@@ -54,11 +54,15 @@ call's shapes, not by an option of their own:
   assignments; nothing has both a token and an expert-times-capacity extent
   (capacity = T computes E·T rows for T·k assignments: 8 x too many at 64
   experts top-8, and a ``[T, E, T]`` mask besides).
-- **dropless**, a call of few tokens (``_one_hot_is_cheaper``: while T rows
-  per expert hide under the expert's weight bytes), and EVERY dropless call
-  under a mesh or in training: the capacity dispatch with capacity = T,
-  which drops nothing, needs no sort and no kernel, and carries the
-  sharding constraints of the capacity path.
+  A decode step whose rows are expected to reach few of the experts held
+  (about one assignment an expert: a chip's share of a wide router) sorts
+  too: the kernel reads only the experts that got a row.
+- **dropless**, a call of few tokens that reach every expert
+  (``_one_hot_is_cheaper``: while T rows per expert hide under the expert's
+  weight bytes), and EVERY dropless call under a mesh or in training: the
+  capacity dispatch with capacity = T, which drops nothing, needs no sort
+  and no kernel, reads all E experts, and carries the sharding constraints
+  of the capacity path.
 
 ``expert_load_tap`` is how a serving step reads what routing did.
 """
@@ -336,13 +340,17 @@ class LayerOf:
     cell serves 12.7 % fewer tokens a second: PERF.md section 6, PR 26).
     The grouped-matmul kernel reads the layer's experts out of the whole
     stack instead; every other consumer calls ``whole()`` and gets the slice
-    XLA fuses into it."""
-    __slots__ = ("stack", "index")
+    XLA fuses into it — ``sliced`` where the caller has made it already (a
+    decode step, whose program is then the one it was while its rows keep
+    the one-hot form)."""
+    __slots__ = ("stack", "index", "sliced")
 
-    def __init__(self, stack, index):
-        self.stack, self.index = stack, index
+    def __init__(self, stack, index, sliced=None):
+        self.stack, self.index, self.sliced = stack, index, sliced
 
     def whole(self):
+        if self.sliced is not None:
+            return self.sliced
         return lax.dynamic_index_in_dim(self.stack, self.index, 0,
                                         keepdims=False)
 
@@ -473,32 +481,57 @@ def _sorted_ffn(moe_params, tokens, logits, cfg, rng, train, held=None):
     return y, aux
 
 
-def _one_hot_is_cheaper(T: int, E: int, k: float) -> bool:
+def _expert_bytes(moe_params) -> int:
+    """The bytes of ONE expert's matrices (two or three of them)."""
+    total = 0
+    for name in ("w_in", "w_in_t", "w_gate", "w_out"):
+        w = moe_params.get(name)
+        if w is not None:
+            w = w.stack if isinstance(w, LayerOf) else w
+            total += math.prod(w.shape[-2:]) * w.dtype.itemsize
+    return total
+
+
+def _one_hot_is_cheaper(T: int, E: int, k: float, expert_bytes: int) -> bool:
     """Which dropless dispatch a call of T tokens takes, from its shapes: E
     the experts held, k the assignments a token is expected to have on them
     (``top_k`` where all are held, ``top_k x held / router width`` on a
-    share).
+    share), ``expert_bytes`` one expert's matrices.
 
-    Both stream each expert's matrices; they differ in what they multiply.
-    The one-hot masks with capacity = T give EVERY expert all T rows: E
-    visits of T rows, nothing to sort, no kernel, and a ``[T, E, T]`` mask.
-    The sorted dispatch multiplies only the T*k assigned rows, but a row
-    tile that straddles experts is visited once per expert: ``row tiles + E
-    - 1`` visits (``ops/grouped_matmul.visit_cost``). While T rows per
-    expert still hide under the expert's weight bytes the one-hot dispatch
-    is the cheaper or equal one (Mixtral's cell, 8 experts: 8 visits against
-    8-11 at every T it runs, and its set-up does not pay for three Mosaic
-    kernels per program: PERF.md section 6, PR 26); beyond, E*T rows cost E/k
-    times the needed multiplies and the mask grows with T squared (OLMoE,
-    64 experts: 205 visit-units against 93 at a 768-token prompt)."""
-    from deepspeed_tpu.ops.grouped_matmul import (WEIGHT_BOUND_ROWS, row_tile,
+    The two differ in what they STREAM and in what they multiply. The
+    one-hot masks with capacity = T give EVERY expert all T rows: all E
+    experts' matrices are read whatever the router touched, E visits of T
+    rows, nothing to sort, no kernel, and a ``[T, E, T]`` mask. The sorted
+    dispatch multiplies only the T*k assigned rows and reads only the
+    experts that got one: a row tile that straddles experts is visited once
+    per expert, an expert with no row never — ``row tiles + experts touched
+    - 1`` visits in expectation (``ops/grouped_matmul.visit_cost``) —, and
+    it pays a layer's sort, gathers and kernel launches besides, a time that
+    does not follow the shapes: what of it exceeds the masks' own einsums is
+    ``SORTED_FIXED_BYTES``, over an expert's bytes in visits.
+
+    A prompt's rows reach every expert, so for it the expectation is the
+    worst case: while T rows per expert still hide under the expert's weight
+    bytes the one-hot dispatch is the cheaper or equal one (Mixtral's cell,
+    8 experts: 8 visits against 8-11 at every T it runs, and its set-up does
+    not pay for three Mosaic kernels per program: PERF.md section 6, PR 26);
+    beyond, E*T rows cost E/k times the needed multiplies and the mask grows
+    with T squared (OLMoE, 64 experts: 205 visit-units against 93 at a
+    768-token prompt). A decode step whose slots put about one assignment
+    on an expert (64 slots x top-4 over a 256-wide router, 32 held: 32 rows)
+    touches 20 of 32, and sorting reads a third fewer bytes (PERF.md section
+    6, PR 45); one whose rows reach nearly every expert (OLMoE: 63.9 of 64)
+    keeps the masks, by the fixed term. A tie goes to one-hot."""
+    from deepspeed_tpu.ops.grouped_matmul import (SORTED_FIXED_BYTES,
+                                                  WEIGHT_BOUND_ROWS, row_tile,
                                                   visit_cost)
     one_hot = E * max(1.0, T / WEIGHT_BOUND_ROWS)
     rows = math.ceil(T * k)
-    return one_hot <= visit_cost(rows, E, row_tile(rows, E))
+    return one_hot <= (visit_cost(rows, E, row_tile(rows, E))
+                       + SORTED_FIXED_BYTES / expert_bytes)
 
 
-def _sorts(T: int, E: int, k: float, train: bool) -> bool:
+def _sorts(T: int, E: int, k: float, train: bool, expert_bytes: int) -> bool:
     """Whether a dropless call of T tokens sorts. Only at inference on ONE
     device, and only past ``_one_hot_is_cheaper``: the sorted dispatch sets
     no sharding constraint and has never been compiled with the experts
@@ -507,7 +540,7 @@ def _sorts(T: int, E: int, k: float, train: bool) -> bool:
     training a dropless call keeps them, as before PR 26."""
     from deepspeed_tpu.parallel.context import kernel_mesh
     return (not train and kernel_mesh()[0] is None
-            and not _one_hot_is_cheaper(T, E, k))
+            and not _one_hot_is_cheaper(T, E, k, expert_bytes))
 
 
 def moe_ffn(moe_params, x, cfg, *, rng=None, train: bool = True,
@@ -552,7 +585,7 @@ def moe_ffn(moe_params, x, cfg, *, rng=None, train: bool = True,
         y, aux = _one_hot_ffn(moe_params, tokens, logits, cfg, C, rng, train,
                                expert_axis, held)
     elif _sorts(T, E, cfg.top_k if held is None else cfg.top_k * E / R,
-                train):
+                train, _expert_bytes(moe_params)):
         form = ("sorted/moe_gmm" if _use_gmm_kernel(moe_params, tokens.dtype)
                 else "sorted/ragged_dot")
         y, aux = _sorted_ffn(moe_params, tokens, logits, cfg, rng, train, held)

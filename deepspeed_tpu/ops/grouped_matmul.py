@@ -13,7 +13,8 @@ Structure (after JAX's megablox ``gmm``, which it replaced: tracing that
 one's group metadata cost 0.6 s per program on the chip's host, 3.4 s of a
 Mixtral run's set-up): the grid walks every (row tile, expert) pair that
 shares a row — a *visit* —, at most ``row tiles + E - 1`` of them and exactly
-``num_visits`` at run time (a dynamic grid bound); a visit multiplies the
+``num_visits`` at run time (a dynamic grid bound: an expert with no row is
+never visited and its matrices are never read); a visit multiplies the
 tile's ``tm`` rows by the expert's matrix, tile by tile over K, into a
 float32 accumulator, and stores the rows that belong to the expert. Visits
 of one row tile are consecutive, so its output block stays in fast memory
@@ -37,6 +38,22 @@ TK, TN = 2048, 1024
 # rows up to which streaming an expert's weight tile hides the multiply: a
 # v5e's 197 TFLOP/s over 819 GB/s
 WEIGHT_BOUND_ROWS = 240.0
+# what a layer's sorted dispatch pays whatever the shapes — two argsorts of
+# the (token, expert) pairs, two row gathers, three kernel launches — BEYOND
+# what the one-hot form pays for its masks and its dispatch / combine
+# einsums, as the bytes the chip streams in that time: 0.016 ms at 819 GB/s.
+# Measured on the chip (PERF.md section 6, PR 45: one expert layer alone at
+# five cells' decode-step shapes, the stacks read in place): the sorted layer
+# takes visited bytes / 764 GB/s + 0.09 ms (Trinity 1.496 ms for 19 visits
+# of 56.6 MB, Mixtral 3.777 ms for 8 of 352 MB; Nemotron, Qwen3-Next and
+# OLMoE within 0.04 ms of that line), the one-hot layer all E experts' bytes
+# at the same rate + 0.07 ms (Trinity, 64 slots x 32 experts) to 1.3 ms
+# (Nemotron, 128 x 128): net of it +0.016 ms at Trinity's shape, the largest
+# reading; zero or less at the other four. In visits it is
+# this over ONE expert's bytes (`moe/sharded_moe._one_hot_is_cheaper`): 0.2
+# at Trinity's widths, 1.0 at OLMoE's — what keeps a step that touches 63.9
+# of 64 experts on the masks — and 2.1 at Qwen3-Next's.
+SORTED_FIXED_BYTES = 13e6
 
 
 def _interpret() -> bool:
@@ -44,10 +61,15 @@ def _interpret() -> bool:
 
 
 def visit_cost(rows: int, experts: int, tm: int) -> float:
-    """The kernel's time in units of one weight-bound expert visit: at worst
-    ``row tiles + experts - 1`` visits, each bound by the expert's weight
-    bytes up to ``WEIGHT_BOUND_ROWS`` rows and by the multiply beyond."""
-    return (-(-rows // tm) + experts - 1) * max(1.0, tm / WEIGHT_BOUND_ROWS)
+    """The kernel's EXPECTED time in units of one weight-bound expert visit.
+    It visits every (row tile, expert) pair that shares a row and no other:
+    ``row tiles + experts TOUCHED - 1``, and ``rows`` assignments spread
+    evenly over the experts touch ``E (1 - (1 - 1/E)^rows)`` of them — all E
+    once rows >> E (a prompt), 20.4 of 32 at 32 rows (a skewed router touches
+    fewer still). Each visit is bound by the expert's weight bytes up to
+    ``WEIGHT_BOUND_ROWS`` rows and by the multiply beyond."""
+    touched = experts * (1.0 - (1.0 - 1.0 / experts) ** rows)
+    return (-(-rows // tm) + touched - 1) * max(1.0, tm / WEIGHT_BOUND_ROWS)
 
 
 def row_tile(rows: int, experts: int) -> int:

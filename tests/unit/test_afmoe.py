@@ -291,6 +291,129 @@ def test_the_eight_shares_add_up_to_the_whole_layer(toy):
     assert np.abs(whole[0] - np.asarray(want)).max() < 1e-7
 
 
+# ---- the decode step that sorts ---------------------------------------------
+
+def _sorting(monkeypatch, cfg):
+    """A model of the same config whose programs are traced with the toy's
+    experts priced at Trinity's published widths (3 x 3072 x 3072 in bf16):
+    the sorted dispatch's fixed work is a time, so against a toy expert of
+    96 KiB it is a hundred visits and the rule keeps the masks; priced as
+    the cell's it sorts the toy step from its shapes as it sorts the cell's
+    (3 slots x 4 of 16, 8 held: 6 rows are expected to touch 4.4 of 8
+    experts, 4.4 + 0.2 visits against 8)."""
+    T, E, k = SLOTS, cfg.num_experts, cfg.top_k * cfg.num_experts / 16
+    assert sm._one_hot_is_cheaper(T, E, k, 3 * 128 * 64 * 4)
+    assert not sm._one_hot_is_cheaper(T, E, k, 3 * 3072 * 3072 * 2)
+    monkeypatch.setattr(sm, "_expert_bytes", lambda p: 3 * 3072 * 3072 * 2)
+    return make_model(cfg)
+
+
+def _load_reader(model):
+    """read(params, pg, tokens) -> (form, [expert layers, E + 1] load rows)
+    of the step ``pg.step(tokens)`` would take, on ``pg``'s pools as they
+    are."""
+    form = []
+
+    def step(p, tok, pools, tab, lens, act):
+        with sm.expert_load_tap() as tap:
+            model.decode_step_paged(p, tok, pools, tab, lens, active=act)
+        form.append(tap.form)                                   # trace time
+        return tap.stacked()
+
+    step = jax.jit(step)
+
+    def read(params, pg, tokens: dict):
+        tok = np.zeros(SLOTS, np.int32)
+        act = np.zeros(SLOTS, bool)
+        for s_, t in tokens.items():
+            tok[s_], act[s_] = t, True
+        rows = step(params, jnp.asarray(tok), pg.pools, jnp.asarray(pg.tables),
+                    jnp.asarray(pg.lens), jnp.asarray(act))
+        return form[0], np.asarray(rows)
+
+    return read
+
+
+@pytest.mark.parametrize("case", ["some_experts_empty", "no_held_expert_gets_a_row",
+                                  "an_inactive_slot"])
+def test_a_step_that_sorts_gives_the_one_hot_steps_logits(toy, monkeypatch, case):
+    """The held share's decode step in the sorted form (``_sorts`` from its
+    shapes) against the one-hot form, slot by slot and step by step, within
+    TOL: (a) steps in which some held experts get no row, (b) steps in which
+    NO held expert gets a row — the bias that makes the choice sends every
+    token to the eight experts held elsewhere, the grouped matmuls have no
+    group and every routed row is masked —, (c) a slot that idles. The load
+    rows (experts touched, assignments held) are the same in both forms."""
+    cfg, model, params, _ = toy
+    if case == "no_held_expert_gets_a_row":
+        moe = dict(params["layers"]["moe"])
+        moe["e_bias"] = moe["e_bias"].at[:, :cfg.num_experts].set(-1e3)
+        params = dict(params, layers=dict(params["layers"], moe=moe))
+    prompts = {s_: _ids(9 + 4 * s_, 80 + s_) for s_ in range(SLOTS)}
+    gens = {s_: _ids(5, 90 + s_) for s_ in range(SLOTS)}
+    live = (0, 2) if case == "an_inactive_slot" else tuple(range(SLOTS))
+
+    def run(model, want):
+        """[(load rows, {slot: logits})] of five steps, traced here."""
+        pg, read, out = Paged(model, params), _load_reader(model), []
+        for s_ in range(SLOTS):
+            pg.prefill(s_, prompts[s_])
+        for i in range(5):
+            tokens = {s_: int(gens[s_][i]) for s_ in live}
+            form, loads = read(params, pg, tokens)
+            assert form == want
+            out.append((loads, pg.step(tokens)))
+        return out, pg
+
+    masks, pg_m = run(model, "one-hot")
+    sorts, pg_s = run(_sorting(monkeypatch, cfg), "sorted/ragged_dot")
+    for (loads, got), (loads_m, got_m) in zip(sorts, masks):
+        assert (loads == loads_m).all()
+        held = loads[:, :cfg.num_experts]
+        if case == "no_held_expert_gets_a_row":
+            assert held.sum() == 0 and loads[0, -1] == 4 * len(live)
+        else:
+            assert 0 < (held > 0).sum(1).min() and (held > 0).sum(1).max() < 8
+            assert held.sum(1).max() <= 4 * len(live)
+        for s_ in live:
+            assert np.abs(got[s_] - got_m[s_]).max() < TOL
+    if case == "an_inactive_slot":           # slot 1 idled: its ring is whole
+        for leaf in ("wk", "wv"):
+            assert (np.asarray(pg_s.pools[leaf][1][1])
+                    == np.asarray(pg_m.pools[leaf][1][1])).all()
+
+
+def test_a_served_step_that_sorts_counts_what_the_one_hot_step_counts(
+        toy, monkeypatch):
+    """Through ``init_serving``: the same requests under both forms give the
+    same tokens, and ``stats()`` names the form and reads the same
+    ``moe_experts_touched_per_step`` and ``moe_assignments_held``."""
+    cfg, model, params, ref = toy
+    reqs = [(_ids(n, 50 + n), m) for n, m in [(30, 9), (5, 12), (17, 10)]]
+
+    def served(model):
+        srv = _serve(model, params, max_seqs=SLOTS)
+        outs = srv.run(reqs)
+        st = srv.stats()
+        srv.close()
+        return [list(outs[r]) for r in range(len(reqs))], st
+
+    outs, st = served(model)
+    sorting = _sorting(monkeypatch, cfg)
+    # the engine of this suite spans the 8 virtual devices, and under a mesh
+    # `_sorts` keeps the masks: the rule alone here, as on one chip
+    monkeypatch.setattr(sm, "_sorts", lambda T, E, k, train, nbytes: (
+        not train and not sm._one_hot_is_cheaper(T, E, k, nbytes)))
+    outs_s, st_s = served(sorting)
+    assert outs_s == outs
+    assert st["moe_dispatch"]["step"] == "one-hot"
+    assert st_s["moe_dispatch"]["step"] == "sorted/ragged_dot"
+    for key in ("moe_experts_touched_per_step", "moe_assignments_held",
+                "moe_assignments_asked", "moe_load_max_over_mean"):
+        assert st_s[key] == st[key], key
+    assert 0 < st["moe_experts_touched_per_step"] < cfg.num_experts
+
+
 # ---- through init_serving ---------------------------------------------------
 
 def _serve(model, params, **serving):

@@ -62,7 +62,7 @@ def dispatch(request, monkeypatch):
     dispatch is forced here so that its arithmetic, and its gradient, are
     held to the reference before a later PR opens those to it)."""
     monkeypatch.setattr(sharded_moe, "_sorts",
-                        lambda T_, E, k, train: request.param == "sorted")
+                        lambda *a: request.param == "sorted")
     return request.param
 
 
@@ -591,20 +591,66 @@ def test_the_layer_runs_on_the_kernel_as_on_ragged_dot(monkeypatch):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5, rtol=0)
 
 
-def test_the_dispatch_is_chosen_by_the_calls_shapes():
+# one expert's matrices at the published widths, bf16: what the rule prices
+# the sorted dispatch's fixed work against
+_BYTES = {"mixtral": 3 * 4096 * 14336 * 2, "olmoe": 3 * 2048 * 1024 * 2,
+          "nemotron": 2 * 2688 * 1856 * 2, "qwen3-next": 3 * 2048 * 512 * 2,
+          "trinity": 3 * 3072 * 3072 * 2}
+# (experts held, assignments a token is expected to have on them)
+_HELD = {"mixtral": (8, 2), "olmoe": (64, 8), "nemotron": (128, 6),
+         "qwen3-next": (128, 10 * 128 / 512), "trinity": (32, 4 * 32 / 256)}
+
+
+def _published_widths(monkeypatch, family):
+    """The toy layer's experts priced at ``family``'s published widths: the
+    sorted dispatch's fixed work is a time, so against a toy expert of a few
+    hundred KiB it is hundreds of visits and the rule keeps the masks at
+    every length; priced at the widths the toy stands for it picks what the
+    cell's program picks."""
+    monkeypatch.setattr(sharded_moe, "_expert_bytes", lambda p: _BYTES[family])
+
+
+@pytest.mark.parametrize("family,T_,one_hot", [
+    # PR 26's rows: Mixtral's cell (prompts to 256, 32 slots) never sorts ...
+    *[("mixtral", T_, True) for T_ in (32, 64, 128, 192, 256)],
+    *[("mixtral", T_, False) for T_ in (384, 512, 2048)],
+    # ... OLMoE's sorts its prompts past 256 tokens; a decode step takes the
+    # masks up to 256 slots
+    *[("olmoe", T_, True) for T_ in (32, 48, 64, 128, 256)],
+    *[("olmoe", T_, False) for T_ in (320, 384, 512, 768, 4096)],
+    # PR 45, the five cells' DECODE STEPS (T = max_seqs). Trinity's 64 slots
+    # put 32 rows on 32 held experts and are expected to touch 20.4: sorted
+    ("trinity", 64, False),
+    # these reach (nearly) every expert: 132.7 of 128 visits, 63.9 + the
+    # fixed term of 64, 8.0 + of 8
+    ("nemotron", 128, True), ("olmoe", 32, True), ("mixtral", 32, True),
+    # Qwen3-Next, 128 slots x 10 of 512 on the 128 held: 119.6 + 2.1 of 128
+    # visits, sorted. Measured both ways on the chip before the constant was
+    # fixed (PR 45, the cell's `serve_tokens_per_s`, two seeds a side):
+    # one-hot 3318.4 / 3307.2, sorted 3766.2 / 3742.5
+    ("qwen3-next", 128, False),
+    # each cell's smallest and largest prompt bucket keep the parent's form
+    ("mixtral", 64, True), ("mixtral", 256, True),
+    ("olmoe", 64, True), ("olmoe", 768, False),
+    ("nemotron", 1024, False), ("qwen3-next", 1024, False),
+    ("trinity", 4096, False), ("trinity", 9216, False),
+    # ... but the two 64-token buckets whose rows do not reach every held
+    # expert: Nemotron's 384 rows touch 121.7 of 128 (123.7 + 0.7 visits of
+    # 128), Qwen3-Next's 160 touch 91.5 (92.5 + 2.1); both moved to sorted
+    ("nemotron", 64, False), ("qwen3-next", 64, False),
+    # and Qwen3-Next's buckets beside them: 128 is the step's shape, 192
+    # keeps the masks (128.0 + 2.1 of 128), 256 stays sorted (131.2 + 2.1 of
+    # 136.5: the tightest prompt row, which bounds the constant from above)
+    ("qwen3-next", 192, True), ("qwen3-next", 256, False),
+])
+def test_the_dispatch_is_chosen_by_the_calls_shapes(family, T_, one_hot):
     """One-hot masks while T rows per expert hide under the expert's weight
-    bytes, sorting beyond: Mixtral's cell (prompts to 256, 32 slots) never
-    sorts, OLMoE's sorts its prompts past 256 tokens."""
-    pick = sharded_moe._one_hot_is_cheaper
-    assert all(pick(T_, 8, 2) for T_ in (32, 64, 128, 192, 256))
-    assert not any(pick(T_, 8, 2) for T_ in (384, 512, 2048))
-    assert all(pick(T_, 64, 8) for T_ in (32, 64, 256))
-    assert not any(pick(T_, 64, 8) for T_ in (320, 384, 768, 4096))
-    # a decode step takes the masks up to 256 slots
-    assert all(pick(T_, 64, 8) for T_ in (48, 128)) and not pick(512, 64, 8)
-    sorts = sharded_moe._sorts
-    assert sorts(768, 64, 8, False) and not sorts(256, 64, 8, False)
-    assert not sorts(768, 64, 8, True)               # training keeps the masks
+    bytes AND the rows are expected to reach nearly every expert; sorting
+    beyond, and where they reach few."""
+    E, k = _HELD[family]
+    assert sharded_moe._one_hot_is_cheaper(T_, E, k, _BYTES[family]) == one_hot
+    assert sharded_moe._sorts(T_, E, k, False, _BYTES[family]) != one_hot
+    assert not sharded_moe._sorts(T_, E, k, True, _BYTES[family])   # training
 
 
 def _dropless_layer(tokens=512, E=8, H=128, F=256):
@@ -618,13 +664,14 @@ def _dropless_layer(tokens=512, E=8, H=128, F=256):
     return cfg, params, jax.random.normal(k[4], (1, tokens, H))
 
 
-def test_a_dropless_call_under_a_mesh_or_in_training_keeps_the_one_hot_einsums():
+def test_a_dropless_call_under_a_mesh_or_in_training_keeps_the_one_hot_einsums(monkeypatch):
     """The sorted dispatch sets no sharding constraint and nobody has
     compiled it with the experts sharded: under a mesh, and in training, a
     dropless call of many tokens lowers to what it lowered to before PR 26
     (no ragged dot; the `[E, C, H]` arrays constrained over `expert`), and
     gives what the sorted dispatch gives on one device."""
     from jax.sharding import Mesh, NamedSharding
+    _published_widths(monkeypatch, "mixtral")
     cfg, params, x = _dropless_layer()
 
     def layer(train):

@@ -391,12 +391,13 @@ def test_dropless_dispatch_is_proportional_to_the_assignments(one_chip, monkeypa
     program's temporaries stay under that buffer's bytes, the kernel reads
     the layer's experts out of the whole stack (no copy of one layer's), and
     a decode step of 32 slots — which takes the one-hot masks, [32, 64, 32]
-    — has no such array either. The control — the same prompt through the
+    — has no such array either, and a step of 8 slots, which sorts, copies
+    no layer's experts. The control — the same prompt through the
     capacity path with C = T — holds both."""
     T_, E, H = 512, 64, 256
 
-    def compiled_text(srv, kind):
-        S, MB = 32, 2          # a small pool: the largest arrays must be the layer's
+    def compiled_text(srv, kind, S=32):
+        MB = 2                 # a small pool: the largest arrays must be the layer's
 
         def sds(shape, dtype):
             return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -414,8 +415,14 @@ def test_dropless_dispatch_is_proportional_to_the_assignments(one_chip, monkeypa
             args = (params, pools, sds((S,), jnp.int32), _block_list(sds, S, MB, MB),
                     sds((S,), jnp.int32), sds((S,), jnp.bool_), key)
         # the program asks the backend which dispatch to build; the test
-        # answers for the chip it compiles for
+        # answers for the chip it compiles for — and prices the narrow
+        # experts at OLMoE's published widths, as the rule prices the cell's
+        # (against a 128 KiB expert the sort's fixed work is a hundred
+        # visits and nothing sorts: tests/unit/test_olmoe.py)
+        from deepspeed_tpu.moe import sharded_moe
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(sharded_moe, "_expert_bytes",
+                            lambda p: 3 * 2048 * 1024 * 2)
         try:
             with jax.default_matmul_precision("default"):
                 c = fn.lower(*args).compile()
@@ -434,6 +441,14 @@ def test_dropless_dispatch_is_proportional_to_the_assignments(one_chip, monkeypa
     text, temp = compiled_text(srv, "step")
     assert "%moe_gmm" not in text                            # few tokens: one-hot
     assert not _largest_arrays(text, E * T_ * H) and temp < E * T_ * H * 2
+    # a step of 8 slots puts 64 rows on 64 experts and is expected to touch
+    # 41 of them: it sorts (PR 45), and its kernel reads the layer's experts
+    # out of the whole stack too — the step hands them whole beside its
+    # slices, and no copy of one layer's is made
+    text, _ = compiled_text(srv, "step", S=8)
+    assert "%moe_gmm" in text
+    assert not [l for l in text.splitlines()
+                if f" = {per_layer}" in l and "parameter" not in l], per_layer
     srv.close()
     srv = _moe_engine(drop_tokens=True, eval_capacity_factor=float(E))
     text, _ = compiled_text(srv, "prefill")
@@ -550,7 +565,8 @@ def test_the_window_cells_programs_fit_the_chip_and_write_the_rings_in_place(
     the engine's own step (64 slots x 88 columns) and its longest prefill
     (9216 tokens): the program compiles for the described v5e, arguments +
     temporaries fit the chip's 15.75 GiB, the banded kernel is in the
-    prefill, and no op outside a fused scatter or block write reads and
+    prefill, the grouped-matmul kernel in both (it reads the expert stacks in
+    place), and no op outside a fused scatter or block write reads and
     writes a whole ring or pool leaf (a ring leaf is 268 MB: stacked on
     their blocks, the rings were split and put together again around every
     step, 4.3 GB of copies)."""
@@ -620,3 +636,12 @@ def test_the_window_cells_programs_fit_the_chip_and_write_the_rings_in_place(
     assert not bad, "\n".join(b[:300] for b in bad)
     if kind == "prefill":
         assert "flash_fwd_band" in hlo and "flash_fwd" in hlo
+    # both sort (PR 45: the step's 32 expected rows touch ~20 of the 32 held
+    # experts), and the kernel reads a layer's experts out of the whole stack:
+    # nothing makes one layer's [32, 3072, 3072] (0.6 GB)
+    assert srv._moe_forms == {"step" if kind == "step"
+                              else f"prefill_{width}": "sorted/moe_gmm"}
+    assert "%moe_gmm" in hlo
+    per_layer = f"bf16[{cfg.num_experts},3072,3072]"
+    assert not [l for l in hlo.splitlines()
+                if f" = {per_layer}" in l and "parameter" not in l], per_layer

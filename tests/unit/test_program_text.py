@@ -24,6 +24,19 @@ a toy window of 64, so ``prefill32`` lands in a ring as it is and
 ``qwen3-next-80b-a3b-serve`` (one period, unrolled), printed from a checkout
 of PR 43's tree: PR 44 changed the hybrid walker it shares. No other row
 moved.
+
+PR 45 (the dropless dispatch chosen by the sorted form's EXPECTED visits)
+printed the table again from its tree: NO row moved, Trinity's ``step``
+neither. The rule prices the sort's fixed work against one expert's bytes,
+and a toy expert is 25-400 KB where a published one is 6-350 MB: at these
+widths the fixed term is hundreds of visits and every toy program keeps the
+one-hot masks, as the parent's did (no toy row is long enough to sort).
+What the change does to the cells' own shapes is held elsewhere: the rule by
+shape at the published widths (``test_olmoe.py
+test_the_dispatch_is_chosen_by_the_calls_shapes``), the sorted step's logits
+and counters against the one-hot step's (``test_afmoe.py``), and Trinity's
+step at the published sizes compiled for the described chip with
+``%moe_gmm`` in it (``test_pool_layout.py -k window_cells``).
 """
 import functools
 import hashlib
