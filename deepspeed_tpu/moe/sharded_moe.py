@@ -46,23 +46,30 @@ call's shapes, not by an option of their own:
   capacity shapes (drop/pad exactly like the reference's capacity
   semantics).
 - **dropless** (``drop_tokens=False``: Mixtral, OLMoE as published), a call
-  of many tokens at inference on one device (``_sorts``): the T·k (token,
-  expert) pairs are sorted by expert, each projection is ONE grouped
-  matmul over the T·k rows (``_grouped_matmul``:
-  static shapes — T·k rows and E group sizes), and the rows are weighted and
-  summed back per token. Work and memory are proportional to the T·k
-  assignments; nothing has both a token and an expert-times-capacity extent
+  at inference on one device that ``_sorts``: the T·k (token, expert) pairs
+  are sorted by expert, each projection is ONE grouped matmul over the T·k
+  rows (``_grouped_matmul``: static shapes — T·k rows and E group sizes),
+  and the rows are weighted and summed back per token. Work and memory are
+  proportional to the T·k assignments, and only the experts that got a row
+  are read; nothing has both a token and an expert-times-capacity extent
   (capacity = T computes E·T rows for T·k assignments: 8 x too many at 64
-  experts top-8, and a ``[T, E, T]`` mask besides).
-  A decode step whose rows are expected to reach few of the experts held
-  (about one assignment an expert: a chip's share of a wide router) sorts
-  too: the kernel reads only the experts that got a row.
-- **dropless**, a call of few tokens that reach every expert
-  (``_one_hot_is_cheaper``: while T rows per expert hide under the expert's
-  weight bytes), and EVERY dropless call under a mesh or in training: the
-  capacity dispatch with capacity = T, which drops nothing, needs no sort
-  and no kernel, reads all E experts, and carries the sharding constraints
-  of the capacity path.
+  experts top-8, ``[E, T, H]`` rows written and read on either side of the
+  experts, and a ``[T, E, T]`` mask contracted twice besides).
+  Which calls: the two forms are PRICED from the call's shapes
+  (``_one_hot_is_cheaper``: tokens, experts held, assignments expected on
+  them, a token's row and an expert's matrices in bytes) by what each was
+  measured to cost alone on the chip, and the cheaper one is taken. Many
+  narrow experts sort at every length (Nemotron's, Qwen3-Next's and
+  Trinity's steps and prompts, OLMoE's prompts), and so does a step whose
+  rows are expected to reach few of the experts held (a chip's share of a
+  wide router).
+- **dropless**, a call whose rows reach every expert of a FEW experts of
+  large matrices (Mixtral's 8: T rows per expert hide under the expert's
+  weight bytes to 256 tokens, and the ``[E, T, H]`` rows are nothing beside
+  them), a call inside the rule's tie (OLMoE's 32-slot step), and EVERY
+  dropless call under a mesh or in training: the capacity dispatch with
+  capacity = T, which drops nothing, needs no sort and no kernel, reads all
+  E experts, and carries the sharding constraints of the capacity path.
 
 ``expert_load_tap`` is how a serving step reads what routing did.
 """
@@ -481,57 +488,68 @@ def _sorted_ffn(moe_params, tokens, logits, cfg, rng, train, held=None):
     return y, aux
 
 
-def _expert_bytes(moe_params) -> int:
-    """The bytes of ONE expert's matrices (two or three of them)."""
-    total = 0
-    for name in ("w_in", "w_in_t", "w_gate", "w_out"):
-        w = moe_params.get(name)
-        if w is not None:
-            w = w.stack if isinstance(w, LayerOf) else w
-            total += math.prod(w.shape[-2:]) * w.dtype.itemsize
-    return total
+def _expert_shapes(moe_params):
+    """(bytes of one token's row of the model's width H, bytes of ONE
+    expert's matrices, two or three of them): the two extents the dispatch
+    is priced by."""
+    mats = [w.stack if isinstance(w, LayerOf) else w
+            for w in map(moe_params.get, ("w_in", "w_in_t", "w_gate", "w_out"))
+            if w is not None]
+    w_out = mats[-1]                                      # [.., F, H]
+    return (w_out.shape[-1] * w_out.dtype.itemsize,
+            sum(math.prod(w.shape[-2:]) * w.dtype.itemsize for w in mats))
 
 
-def _one_hot_is_cheaper(T: int, E: int, k: float, expert_bytes: int) -> bool:
+def _one_hot_is_cheaper(T: int, E: int, k: float, row_bytes: int,
+                        expert_bytes: int) -> bool:
     """Which dropless dispatch a call of T tokens takes, from its shapes: E
     the experts held, k the assignments a token is expected to have on them
     (``top_k`` where all are held, ``top_k x held / router width`` on a
-    share), ``expert_bytes`` one expert's matrices.
+    share), ``row_bytes`` one token's row of H, ``expert_bytes`` one expert's
+    matrices. Both forms are priced in one unit, a weight-bound visit of one
+    expert, by what each was measured to cost alone on the chip (PERF.md
+    section 6, PR 45 and PR 46).
 
-    The two differ in what they STREAM and in what they multiply. The
-    one-hot masks with capacity = T give EVERY expert all T rows: all E
-    experts' matrices are read whatever the router touched, E visits of T
-    rows, nothing to sort, no kernel, and a ``[T, E, T]`` mask. The sorted
-    dispatch multiplies only the T*k assigned rows and reads only the
-    experts that got one: a row tile that straddles experts is visited once
-    per expert, an expert with no row never — ``row tiles + experts touched
-    - 1`` visits in expectation (``ops/grouped_matmul.visit_cost``) —, and
-    it pays a layer's sort, gathers and kernel launches besides, a time that
-    does not follow the shapes: what of it exceeds the masks' own einsums is
+    The one-hot masks with capacity = T give EVERY expert all T rows
+    (``ops/grouped_matmul.one_hot_cost``): all E experts' matrices are read
+    whatever the router touched, or E x T rows multiplied where that takes
+    longer; the ``[E, T, H]`` rows into and out of the experts are written
+    and read, E / k times the rows anyone asked for; and the ``[T, E, T]``
+    masks are contracted over T to make and to consume them. Nothing to
+    sort, no kernel. The sorted dispatch multiplies only the T*k assigned
+    rows and reads only the experts that got one: a row tile that straddles
+    experts is visited once per expert, an expert with no row never — ``row
+    tiles + experts touched - 1`` visits in expectation
+    (``ops/grouped_matmul.visit_cost``) —, and it pays a layer's sort,
+    gathers and kernel launches besides, a time that does not follow the
+    shapes: what of it exceeds the one-hot form's own fixed time is
     ``SORTED_FIXED_BYTES``, over an expert's bytes in visits.
 
-    A prompt's rows reach every expert, so for it the expectation is the
-    worst case: while T rows per expert still hide under the expert's weight
-    bytes the one-hot dispatch is the cheaper or equal one (Mixtral's cell,
-    8 experts: 8 visits against 8-11 at every T it runs, and its set-up does
-    not pay for three Mosaic kernels per program: PERF.md section 6, PR 26);
-    beyond, E*T rows cost E/k times the needed multiplies and the mask grows
-    with T squared (OLMoE, 64 experts: 205 visit-units against 93 at a
-    768-token prompt). A decode step whose slots put about one assignment
-    on an expert (64 slots x top-4 over a 256-wide router, 32 held: 32 rows)
-    touches 20 of 32, and sorting reads a third fewer bytes (PERF.md section
-    6, PR 45); one whose rows reach nearly every expert (OLMoE: 63.9 of 64)
-    keeps the masks, by the fixed term. A tie goes to one-hot."""
+    Few experts of large matrices hide T rows each under their bytes, and
+    their ``[E, T, H]`` rows are a thousandth of them: Mixtral's cell (8
+    experts: 8.0 - 8.9 against 8.0 - 9.6 at every T to 256) keeps the masks,
+    and its set-up does not pay for three Mosaic kernels per program. Many
+    narrow experts do not: at 128 slots x 128 experts x 2688 the four passes
+    and the einsums are 27 visits on top of 128, against 133 sorted
+    (Nemotron's step: 4.64 ms against 3.59 measured), and the terms grow
+    with T and T squared while the sorted side's barely move (OLMoE, 64
+    experts: 112 against 77 at a 256-token prompt). A decode step whose
+    slots put about one assignment on an expert (64 slots x top-4 over a
+    256-wide router, 32 held: 32 rows) touches 20 of 32, and sorting reads a
+    third fewer bytes (PR 45). Inside ``TIE_BYTES`` the two are one and the
+    call keeps the masks, the program it had: OLMoE's 32-slot step, 63.9 of
+    64 experts touched, 67.0 against 63.9 + 1.0, the tie 2.6."""
     from deepspeed_tpu.ops.grouped_matmul import (SORTED_FIXED_BYTES,
-                                                  WEIGHT_BOUND_ROWS, row_tile,
-                                                  visit_cost)
-    one_hot = E * max(1.0, T / WEIGHT_BOUND_ROWS)
+                                                  TIE_BYTES, one_hot_cost,
+                                                  row_tile, visit_cost)
     rows = math.ceil(T * k)
-    return one_hot <= (visit_cost(rows, E, row_tile(rows, E))
-                       + SORTED_FIXED_BYTES / expert_bytes)
+    return (one_hot_cost(T, E, row_bytes, expert_bytes)
+            <= visit_cost(rows, E, row_tile(rows, E))
+            + (SORTED_FIXED_BYTES + TIE_BYTES) / expert_bytes)
 
 
-def _sorts(T: int, E: int, k: float, train: bool, expert_bytes: int) -> bool:
+def _sorts(T: int, E: int, k: float, train: bool, row_bytes: int,
+           expert_bytes: int) -> bool:
     """Whether a dropless call of T tokens sorts. Only at inference on ONE
     device, and only past ``_one_hot_is_cheaper``: the sorted dispatch sets
     no sharding constraint and has never been compiled with the experts
@@ -540,7 +558,7 @@ def _sorts(T: int, E: int, k: float, train: bool, expert_bytes: int) -> bool:
     training a dropless call keeps them, as before PR 26."""
     from deepspeed_tpu.parallel.context import kernel_mesh
     return (not train and kernel_mesh()[0] is None
-            and not _one_hot_is_cheaper(T, E, k, expert_bytes))
+            and not _one_hot_is_cheaper(T, E, k, row_bytes, expert_bytes))
 
 
 def moe_ffn(moe_params, x, cfg, *, rng=None, train: bool = True,
@@ -585,7 +603,7 @@ def moe_ffn(moe_params, x, cfg, *, rng=None, train: bool = True,
         y, aux = _one_hot_ffn(moe_params, tokens, logits, cfg, C, rng, train,
                                expert_axis, held)
     elif _sorts(T, E, cfg.top_k if held is None else cfg.top_k * E / R,
-                train, _expert_bytes(moe_params)):
+                train, *_expert_shapes(moe_params)):
         form = ("sorted/moe_gmm" if _use_gmm_kernel(moe_params, tokens.dtype)
                 else "sorted/ragged_dot")
         y, aux = _sorted_ffn(moe_params, tokens, logits, cfg, rng, train, held)

@@ -40,20 +40,25 @@ TK, TN = 2048, 1024
 WEIGHT_BOUND_ROWS = 240.0
 # what a layer's sorted dispatch pays whatever the shapes — two argsorts of
 # the (token, expert) pairs, two row gathers, three kernel launches — BEYOND
-# what the one-hot form pays for its masks and its dispatch / combine
-# einsums, as the bytes the chip streams in that time: 0.016 ms at 819 GB/s.
-# Measured on the chip (PERF.md section 6, PR 45: one expert layer alone at
-# five cells' decode-step shapes, the stacks read in place): the sorted layer
-# takes visited bytes / 764 GB/s + 0.09 ms (Trinity 1.496 ms for 19 visits
-# of 56.6 MB, Mixtral 3.777 ms for 8 of 352 MB; Nemotron, Qwen3-Next and
-# OLMoE within 0.04 ms of that line), the one-hot layer all E experts' bytes
-# at the same rate + 0.07 ms (Trinity, 64 slots x 32 experts) to 1.3 ms
-# (Nemotron, 128 x 128): net of it +0.016 ms at Trinity's shape, the largest
-# reading; zero or less at the other four. In visits it is
-# this over ONE expert's bytes (`moe/sharded_moe._one_hot_is_cheaper`): 0.2
-# at Trinity's widths, 1.0 at OLMoE's — what keeps a step that touches 63.9
-# of 64 experts on the masks — and 2.1 at Qwen3-Next's.
+# what the one-hot form pays whatever ITS shapes, as the bytes the chip streams
+# in that time: 0.016 ms at 819 GB/s. Measured on the chip (PERF.md section 6,
+# PR 45 and PR 46: one expert layer alone, both forms, the stacks read in
+# place): the sorted layer takes visited bytes / 764 GB/s + 0.09 ms (Trinity
+# 1.496 ms for 19 visits of 56.6 MB, Mixtral 3.777 ms for 8 of 352 MB), and
+# the one-hot layer comes down to all E experts' bytes at the same rate + 0.07
+# to 0.10 ms where its rows are few (Trinity's 64 slots x 32 experts, Mixtral's
+# and OLMoE's 32): net of it +0.016 ms at Trinity's shape, the largest
+# reading. What the one-hot form pays that DOES follow the shapes is
+# `one_hot_cost`'s to price. In visits this is the constant over ONE expert's
+# bytes (`moe/sharded_moe._one_hot_is_cheaper`): 0.2 at Trinity's widths, 1.0
+# at OLMoE's, 2.1 at Qwen3-Next's.
 SORTED_FIXED_BYTES = 13e6
+# what the two forms must differ by before the difference is one: 0.04 ms at
+# 819 GB/s, as bytes. The sorted layer's time scatters by that around its line
+# with the routing of the sample (OLMoE's 32-slot step: one-hot 1.154 / 1.146
+# ms, sorted 1.117 / 1.082 in two calls), and within it the masks keep the
+# call (`_one_hot_is_cheaper`): no sort, no kernel, the program it was.
+TIE_BYTES = 33e6
 
 
 def _interpret() -> bool:
@@ -70,6 +75,36 @@ def visit_cost(rows: int, experts: int, tm: int) -> float:
     ``WEIGHT_BOUND_ROWS`` rows and by the multiply beyond."""
     touched = experts * (1.0 - (1.0 - 1.0 / experts) ** rows)
     return (-(-rows // tm) + touched - 1) * max(1.0, tm / WEIGHT_BOUND_ROWS)
+
+
+def one_hot_cost(tokens: int, experts: int, row_bytes: int,
+                 expert_bytes: int) -> float:
+    """The one-hot dispatch with capacity = tokens, in the same unit (one
+    weight-bound expert visit), from the call's shapes: ``row_bytes`` one
+    token's row of the model's width, ``expert_bytes`` one expert's matrices.
+
+    It gives EVERY expert all T rows. What that costs, as fitted to one
+    expert layer alone on the chip at 29 shapes of five families (PERF.md
+    section 6, PR 46; rms 0.24 ms over 1.1 - 8.4 ms, no term's coefficient
+    further than 6 % from the 1 it has here but the einsums'):
+
+    - all E experts' matrices streamed, or E x T rows multiplied where that
+      takes longer (``WEIGHT_BOUND_ROWS``): XLA's batched matmul overlaps the
+      two, and the ``[E, T, F]`` rows between its projections never leave
+      the fusion (coefficient 0.05);
+    - the ``[E, T, H]`` rows INTO the experts and OUT of them, each written
+      and read: four passes over E x T rows that the sorted form makes over
+      T x k — 88 MB a pass at 128 tokens x 128 experts x 2688, a quarter of
+      the experts' own bytes at Qwen3-Next's widths;
+    - the dispatch and combine einsums, which make and consume those rows by
+      contracting the ``[T, E, T]`` masks over T: E x T rows times a
+      ``[T, H]`` matrix, twice, at about half the multiplier's peak (fitted
+      1.9 x their FLOPs' time; the contraction is only T long) — T /
+      ``WEIGHT_BOUND_ROWS`` of the four passes again."""
+    rows = experts * tokens
+    return (experts * max(1.0, tokens / WEIGHT_BOUND_ROWS)
+            + 4 * rows * row_bytes * (1.0 + tokens / WEIGHT_BOUND_ROWS)
+            / expert_bytes)
 
 
 def row_tile(rows: int, experts: int) -> int:

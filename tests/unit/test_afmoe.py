@@ -302,9 +302,10 @@ def _sorting(monkeypatch, cfg):
     (3 slots x 4 of 16, 8 held: 6 rows are expected to touch 4.4 of 8
     experts, 4.4 + 0.2 visits against 8)."""
     T, E, k = SLOTS, cfg.num_experts, cfg.top_k * cfg.num_experts / 16
-    assert sm._one_hot_is_cheaper(T, E, k, 3 * 128 * 64 * 4)
-    assert not sm._one_hot_is_cheaper(T, E, k, 3 * 3072 * 3072 * 2)
-    monkeypatch.setattr(sm, "_expert_bytes", lambda p: 3 * 3072 * 3072 * 2)
+    published = (3072 * 2, 3 * 3072 * 3072 * 2)
+    assert sm._one_hot_is_cheaper(T, E, k, 128 * 4, 3 * 128 * 64 * 4)
+    assert not sm._one_hot_is_cheaper(T, E, k, *published)
+    monkeypatch.setattr(sm, "_expert_shapes", lambda p: published)
     return make_model(cfg)
 
 
@@ -402,8 +403,8 @@ def test_a_served_step_that_sorts_counts_what_the_one_hot_step_counts(
     sorting = _sorting(monkeypatch, cfg)
     # the engine of this suite spans the 8 virtual devices, and under a mesh
     # `_sorts` keeps the masks: the rule alone here, as on one chip
-    monkeypatch.setattr(sm, "_sorts", lambda T, E, k, train, nbytes: (
-        not train and not sm._one_hot_is_cheaper(T, E, k, nbytes)))
+    monkeypatch.setattr(sm, "_sorts", lambda T, E, k, train, *nbytes: (
+        not train and not sm._one_hot_is_cheaper(T, E, k, *nbytes)))
     outs_s, st_s = served(sorting)
     assert outs_s == outs
     assert st["moe_dispatch"]["step"] == "one-hot"

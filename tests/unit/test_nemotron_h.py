@@ -199,6 +199,78 @@ def test_an_inactive_slot_keeps_its_state(toy):
     assert np.abs(np.stack(got) - _ref_tail(ref, prompt, gen)).max() < TOL
 
 
+# ---- the decode step that sorts (PR 46) -------------------------------------
+
+# (one token's row, one expert's two matrices) in bytes at the published
+# widths, bf16: what the cell's layer is priced at
+PUBLISHED = (2688 * 2, 2 * 2688 * 1856 * 2)
+
+
+def _sorting(monkeypatch, cfg):
+    """A model of the same config whose programs are traced with the toy's
+    experts priced at the published widths (as ``test_afmoe.py``'s does for
+    Trinity): against a toy expert of 64 KiB the sort's fixed work is
+    hundreds of visits and the rule keeps the masks; priced as the cell's it
+    sorts the toy step from its shapes (3 slots x 2 of 8: 6 rows reach 4.4
+    of the 8 experts) as it sorts the cell's own (128 slots x 6 of 128: the
+    rows reach every expert, 132.7 visits, and the one-hot form's [E, T, H]
+    passes and einsums put 27 on top of its 128). The grouped matmuls run
+    through the Pallas kernel in interpret mode, ``w_in_t`` on its
+    ``transposed`` path, the stacks handed whole."""
+    T, E, k = SLOTS, cfg.num_experts, cfg.top_k
+    assert sm._one_hot_is_cheaper(T, E, k, 128 * 4, 2 * 128 * 64 * 4)
+    assert not sm._one_hot_is_cheaper(T, E, k, *PUBLISHED)
+    assert not sm._one_hot_is_cheaper(128, 128, 6, *PUBLISHED)
+    monkeypatch.setattr(sm, "_expert_shapes", lambda p: PUBLISHED)
+    monkeypatch.setattr(sm, "_use_gmm_kernel", lambda *a: True)
+    return make_model(cfg)
+
+
+def _step_form(model, params, pg):
+    """The dispatch ``pg``'s decode step takes, read at trace time."""
+    def step(p, pools):
+        with sm.expert_load_tap() as tap:
+            model.decode_step_paged(
+                p, jnp.zeros(SLOTS, jnp.int32), pools, jnp.asarray(pg.tables),
+                jnp.asarray(pg.lens), active=jnp.ones(SLOTS, bool))
+        return tap.form
+    form = []
+    jax.eval_shape(lambda p, pools: form.append(step(p, pools)), params,
+                   pg.pools)
+    return form[0]
+
+
+@pytest.mark.parametrize("case", ["every_slot", "an_inactive_slot"])
+def test_a_step_that_sorts_gives_the_one_hot_steps_logits(toy, monkeypatch,
+                                                          case):
+    """The toy's decode step in the sorted form, its up projection read from
+    ``w_in_t`` by the kernel, against the one-hot step slot by slot and step
+    by step, and both against the plain reference, within TOL."""
+    cfg, model, params, ref = toy
+    prompts = {s: _ids(9 + 5 * s, 60 + s) for s in range(SLOTS)}
+    gens = {s: _ids(5, 70 + s) for s in range(SLOTS)}
+    live = (0, 2) if case == "an_inactive_slot" else tuple(range(SLOTS))
+
+    def run(model, want):
+        pg, out = Paged(model, params), {s: [] for s in live}
+        for s in range(SLOTS):
+            first = pg.prefill(s, prompts[s])
+            if s in live:
+                out[s].append(first)
+        assert _step_form(model, params, pg) == want
+        for i in range(5):
+            for s, lg in pg.step({s: int(gens[s][i]) for s in live}).items():
+                out[s].append(lg)
+        return {s: np.stack(v) for s, v in out.items()}
+
+    masks = run(model, "one-hot")
+    sorts = run(_sorting(monkeypatch, cfg), "sorted/moe_gmm")
+    for s in live:
+        assert np.abs(sorts[s] - masks[s]).max() < TOL, s
+        assert np.abs(sorts[s] - _ref_tail(ref, prompts[s], gens[s])
+                      ).max() < TOL, s
+
+
 # ---- through init_serving ---------------------------------------------------
 
 def _serve(model, params, **serving):

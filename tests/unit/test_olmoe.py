@@ -21,6 +21,7 @@ within 0.15 and all within 1.0 — which still fails a dropped assignment or
 a missing norm; the finer defects are tested on the float pool.
 """
 import importlib.util
+import math
 import os
 
 import jax
@@ -591,11 +592,13 @@ def test_the_layer_runs_on_the_kernel_as_on_ragged_dot(monkeypatch):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5, rtol=0)
 
 
-# one expert's matrices at the published widths, bf16: what the rule prices
-# the sorted dispatch's fixed work against
-_BYTES = {"mixtral": 3 * 4096 * 14336 * 2, "olmoe": 3 * 2048 * 1024 * 2,
-          "nemotron": 2 * 2688 * 1856 * 2, "qwen3-next": 3 * 2048 * 512 * 2,
-          "trinity": 3 * 3072 * 3072 * 2}
+# (one token's row of H, one expert's matrices) in bytes at the published
+# widths, bf16: the two extents the rule prices a call by
+_BYTES = {"mixtral": (4096 * 2, 3 * 4096 * 14336 * 2),
+          "olmoe": (2048 * 2, 3 * 2048 * 1024 * 2),
+          "nemotron": (2688 * 2, 2 * 2688 * 1856 * 2),
+          "qwen3-next": (2048 * 2, 3 * 2048 * 512 * 2),
+          "trinity": (3072 * 2, 3 * 3072 * 3072 * 2)}
 # (experts held, assignments a token is expected to have on them)
 _HELD = {"mixtral": (8, 2), "olmoe": (64, 8), "nemotron": (128, 6),
          "qwen3-next": (128, 10 * 128 / 512), "trinity": (32, 4 * 32 / 256)}
@@ -607,50 +610,121 @@ def _published_widths(monkeypatch, family):
     hundred KiB it is hundreds of visits and the rule keeps the masks at
     every length; priced at the widths the toy stands for it picks what the
     cell's program picks."""
-    monkeypatch.setattr(sharded_moe, "_expert_bytes", lambda p: _BYTES[family])
+    monkeypatch.setattr(sharded_moe, "_expert_shapes", lambda p: _BYTES[family])
 
 
 @pytest.mark.parametrize("family,T_,one_hot", [
-    # PR 26's rows: Mixtral's cell (prompts to 256, 32 slots) never sorts ...
+    # Mixtral's cell (prompts to 256, 32 slots) never sorts: 8 experts of
+    # 352 MB hide 256 rows each, and their [E, T, H] rows are nothing beside
+    # them (8.0 - 8.9 visits against 8.0 - 9.6)
     *[("mixtral", T_, True) for T_ in (32, 64, 128, 192, 256)],
     *[("mixtral", T_, False) for T_ in (384, 512, 2048)],
-    # ... OLMoE's sorts its prompts past 256 tokens; a decode step takes the
-    # masks up to 256 slots
-    *[("olmoe", T_, True) for T_ in (32, 48, 64, 128, 256)],
-    *[("olmoe", T_, False) for T_ in (320, 384, 512, 768, 4096)],
+    # OLMoE's 32-slot step keeps the masks, inside the tie (67.0 against
+    # 63.9 + 1.0, the tie 2.6 visits; measured 1.146 / 1.154 ms against
+    # 1.082 / 1.117); every prompt bucket sorts since PR 46 (64 tokens, the
+    # nearest: 70.8 against 67.0 + 1.0 + 2.6; in the cell a lone prompt
+    # reaches half the experts)
+    *[("olmoe", T_, True) for T_ in (32, 48)],
+    *[("olmoe", T_, False) for T_ in (64, 128, 192, 256, 320, 384, 512, 768,
+                                      4096)],
     # PR 45, the five cells' DECODE STEPS (T = max_seqs). Trinity's 64 slots
     # put 32 rows on 32 held experts and are expected to touch 20.4: sorted
     ("trinity", 64, False),
-    # these reach (nearly) every expert: 132.7 of 128 visits, 63.9 + the
-    # fixed term of 64, 8.0 + of 8
-    ("nemotron", 128, True), ("olmoe", 32, True), ("mixtral", 32, True),
+    ("olmoe", 32, True), ("mixtral", 32, True),
+    # PR 46: Nemotron's 128 slots x top-6 reach every one of its 128 experts
+    # (132.7 visits sorted), and the one-hot form's [E, T, H] passes and
+    # einsums are 27 visits on top of its 128: measured alone 4.64 ms
+    # against 3.59
+    ("nemotron", 128, False),
     # Qwen3-Next, 128 slots x 10 of 512 on the 128 held: 119.6 + 2.1 of 128
     # visits, sorted. Measured both ways on the chip before the constant was
     # fixed (PR 45, the cell's `serve_tokens_per_s`, two seeds a side):
     # one-hot 3318.4 / 3307.2, sorted 3766.2 / 3742.5
     ("qwen3-next", 128, False),
-    # each cell's smallest and largest prompt bucket keep the parent's form
+    # each cell's smallest and largest prompt bucket
     ("mixtral", 64, True), ("mixtral", 256, True),
-    ("olmoe", 64, True), ("olmoe", 768, False),
+    ("olmoe", 64, False), ("olmoe", 768, False),
     ("nemotron", 1024, False), ("qwen3-next", 1024, False),
     ("trinity", 4096, False), ("trinity", 9216, False),
-    # ... but the two 64-token buckets whose rows do not reach every held
-    # expert: Nemotron's 384 rows touch 121.7 of 128 (123.7 + 0.7 visits of
-    # 128), Qwen3-Next's 160 touch 91.5 (92.5 + 2.1); both moved to sorted
     ("nemotron", 64, False), ("qwen3-next", 64, False),
-    # and Qwen3-Next's buckets beside them: 128 is the step's shape, 192
-    # keeps the masks (128.0 + 2.1 of 128), 256 stays sorted (131.2 + 2.1 of
-    # 136.5: the tightest prompt row, which bounds the constant from above)
-    ("qwen3-next", 192, True), ("qwen3-next", 256, False),
+    # Nemotron's and Qwen3-Next's buckets between: all sorted since PR 46
+    # (Qwen3-Next's 192 was the last on the masks: 128.0 + 2.1 of 128 under
+    # the old price, 243 of 130 under this one; measured 2.00 ms against
+    # 1.23)
+    ("nemotron", 192, False), ("nemotron", 256, False),
+    ("qwen3-next", 192, False), ("qwen3-next", 256, False),
 ])
 def test_the_dispatch_is_chosen_by_the_calls_shapes(family, T_, one_hot):
     """One-hot masks while T rows per expert hide under the expert's weight
-    bytes AND the rows are expected to reach nearly every expert; sorting
-    beyond, and where they reach few."""
+    bytes, the ``[E, T, H]`` rows around them are few beside those bytes AND
+    the rows are expected to reach nearly every expert; sorting beyond, and
+    where they reach few."""
     E, k = _HELD[family]
-    assert sharded_moe._one_hot_is_cheaper(T_, E, k, _BYTES[family]) == one_hot
-    assert sharded_moe._sorts(T_, E, k, False, _BYTES[family]) != one_hot
-    assert not sharded_moe._sorts(T_, E, k, True, _BYTES[family])   # training
+    assert sharded_moe._one_hot_is_cheaper(T_, E, k, *_BYTES[family]) == one_hot
+    assert sharded_moe._sorts(T_, E, k, False, *_BYTES[family]) != one_hot
+    assert not sharded_moe._sorts(T_, E, k, True, *_BYTES[family])   # training
+
+
+# one expert layer ALONE on the chip, both forms (ms a layer; PERF.md section
+# 6, PR 46: `scratch_chip/layer_forms.py`, a fresh function a form, the stacks
+# handed whole, an even router): (family, T, one-hot, sorted)
+_MEASURED = [
+    ("nemotron", 32, 3.442, 2.771), ("nemotron", 64, 3.543, 3.263),
+    ("nemotron", 128, 4.644, 3.591), ("nemotron", 192, 4.577, 3.635),
+    ("nemotron", 256, 5.609, 3.741), ("nemotron", 384, 8.392, 3.921),
+    ("nemotron", 512, 11.648, 4.300),
+    ("olmoe", 32, 1.146, 1.082), ("olmoe", 48, 1.164, 1.149),
+    ("olmoe", 64, 1.196, 1.149), ("olmoe", 128, 1.184, 1.231),
+    ("olmoe", 192, 1.294, 1.342), ("olmoe", 256, 1.579, 1.395),
+    ("olmoe", 320, 2.113, 1.446), ("olmoe", 384, 2.328, 1.503),
+    ("qwen3-next", 64, 1.215, 0.871), ("qwen3-next", 128, 1.411, 1.125),
+    ("qwen3-next", 192, 1.998, 1.225), ("qwen3-next", 256, 2.778, 1.405),
+    ("qwen3-next", 384, 4.485, 1.477),
+    ("mixtral", 32, 3.768, 3.778), ("mixtral", 64, 3.758, 3.800),
+    ("mixtral", 128, 3.778, 4.174), ("mixtral", 192, 3.808, 4.728),
+    ("mixtral", 256, 4.243, 4.734), ("mixtral", 384, 6.132, 5.294),
+    ("trinity", 64, 2.443, 1.719), ("trinity", 128, 2.469, 2.346),
+    ("trinity", 256, 2.950, 2.840),
+]
+# where the rule and the isolated layer part ways, and why it stands: at an
+# EVEN router OLMoE's 128- and 192-token calls read 0.05 ms faster on the
+# masks; the price says 0.14 / 0.30 ms slower (the one-hot side's largest
+# residuals under 256 tokens). In the cell a lone prompt's tokens reach half
+# the experts (`moe_experts_touched_per_prefill` 30-35 of 64) and the sorted
+# program reads only those: 21.7 / 22.6 ms a program on the masks against
+# 16.2 for the SORTED 320-token bucket (PERF.md section 5)
+_PARTED = {("olmoe", 128), ("olmoe", 192)}
+
+
+@pytest.mark.parametrize("family,T_,one_hot_ms,sorted_ms", _MEASURED)
+def test_the_two_prices_reproduce_the_measured_layer(family, T_, one_hot_ms,
+                                                     sorted_ms):
+    """Each measured point a case: the two prices, turned back into time (a
+    visit = an expert's bytes at the 764 GB/s both forms stream at, + the
+    0.09 ms either form pays whatever its shapes), say which form is the
+    faster one wherever the measurement can tell (more than the tie's 0.04 ms
+    apart) and, under 256 tokens — where the choice is made —, land within
+    0.55 ms of each reading (rms 0.19 one-hot, 0.08 sorted); beyond, within
+    two fifths of it (the one-hot price runs high there: OLMoE's 384 tokens
+    3.15 ms for 2.33 measured, against 1.50 sorted)."""
+    from deepspeed_tpu.ops import grouped_matmul as G
+    E, k = _HELD[family]
+    row_bytes, expert_bytes = _BYTES[family]
+    visit_ms = expert_bytes / 764e9 * 1e3
+    rows = math.ceil(T_ * k)
+    one_hot = G.one_hot_cost(T_, E, row_bytes, expert_bytes) * visit_ms + 0.09
+    sorts = (G.visit_cost(rows, E, G.row_tile(rows, E)) * visit_ms + 0.09
+             + G.SORTED_FIXED_BYTES / 819e9 * 1e3)
+    for price, read in ((one_hot, one_hot_ms), (sorts, sorted_ms)):
+        assert abs(price - read) < (0.55 if T_ < 256 else 0.4 * read)
+    gap = one_hot_ms - sorted_ms
+    takes_masks = sharded_moe._one_hot_is_cheaper(T_, E, k, *_BYTES[family])
+    if (family, T_) in _PARTED:
+        assert -0.06 < gap < 0 and not takes_masks
+    elif abs(gap) > 0.07:              # clear of the tie and of its scatter
+        assert takes_masks == (gap < 0)
+    else:                              # a tie either way: the rule may keep
+        assert abs((one_hot - sorts) - gap) < 0.06      # the masks or not
 
 
 def _dropless_layer(tokens=512, E=8, H=128, F=256):

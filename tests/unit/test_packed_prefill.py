@@ -68,9 +68,10 @@ CASES = {
         "mistral-7b-serve", attention_impl="pallas", **kw), (20, 37, 5), 128),
     "experts-one-hot": (lambda **kw: _family("mixtral-8x7b-serve", **kw),
                         (20, 37, 5), 128),
-    # 64 experts, 8 a token, q/k norms: one-hot under ~300 tokens, sorted
-    # beyond (sharded_moe._sorts) — each prompt alone on one side, the row
-    # on the other
+    # 64 experts, 8 a token, q/k norms, priced at widths whose 192-token
+    # call keeps the one-hot masks and whose 384-token call sorts
+    # (sharded_moe._sorts) — each prompt alone on one side, the row on the
+    # other
     "experts-across-the-sort": (lambda **kw: _family("olmoe-1b-7b-serve", **kw),
                                 (150, 100, 70), 384),
     "looped": (lambda **kw: _family("ouro-2.6b-serve", **kw), (20, 37, 5), 128),
@@ -152,12 +153,14 @@ def test_a_packed_row_gives_every_prompt_what_it_gets_alone(case, pool,
                                                             monkeypatch):
     build, lengths, P = CASES[case]
     if case == "experts-across-the-sort":
-        # the toy's experts priced at OLMoE's published widths, as the rule
-        # prices the cell's: against a toy expert the sort's fixed work is
-        # hundreds of visits and nothing sorts (tests/unit/test_olmoe.py)
+        # the toy's experts priced at published widths, as the rule prices
+        # a cell's: against a toy expert the sort's fixed work is hundreds of
+        # visits and nothing sorts. Mixtral's, not OLMoE's: since PR 46
+        # every OLMoE bucket sorts, and this case wants a row ACROSS the
+        # two forms (tests/unit/test_olmoe.py has both tables)
         from deepspeed_tpu.moe import sharded_moe
-        monkeypatch.setattr(sharded_moe, "_expert_bytes",
-                            lambda p: 3 * 2048 * 1024 * 2)
+        monkeypatch.setattr(sharded_moe, "_expert_shapes",
+                            lambda p: (4096 * 2, 3 * 4096 * 14336 * 2))
     cfg = build(kv_cache_bits=8 if pool == "int8-pool" else 0)
     model = make_model(cfg)
     params = _loud(model.init(jax.random.PRNGKey(0)))

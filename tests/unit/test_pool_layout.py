@@ -421,8 +421,8 @@ def test_dropless_dispatch_is_proportional_to_the_assignments(one_chip, monkeypa
         # visits and nothing sorts: tests/unit/test_olmoe.py)
         from deepspeed_tpu.moe import sharded_moe
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        monkeypatch.setattr(sharded_moe, "_expert_bytes",
-                            lambda p: 3 * 2048 * 1024 * 2)
+        monkeypatch.setattr(sharded_moe, "_expert_shapes",
+                            lambda p: (2048 * 2, 3 * 2048 * 1024 * 2))
         try:
             with jax.default_matmul_precision("default"):
                 c = fn.lower(*args).compile()
@@ -556,6 +556,53 @@ def test_the_looped_cells_programs_fit_the_chip_and_write_the_pool_in_place(
 
 # ---- window rings beside the pool (ISSUE 44) ---------------------------------
 
+def _hybrid_cell(name, one_chip):
+    """A hybrid serve cell's engine at the PUBLISHED widths and the cell's
+    own shapes (benchmark/configs/<name>.json), as shapes: -> (cfg, params,
+    pools, the engine's jitted functions without an engine — nothing of this
+    size is ever placed here —, slots, table columns, sds)."""
+    import json
+    import os
+    from deepspeed_tpu.inference.serving import ServingConfig, ServingEngine
+    from deepspeed_tpu.models.hf_import import hf_config_to_transformer
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs", name + ".json")) as f:
+        conf = json.load(f)
+    hf = {k: v for k, v in conf.items() if k not in (
+        "source", "reduced", "assumed", "deployment", "run", "correct")}
+    serving = conf["run"]["serving"]
+    S, MB = serving["max_seqs"], serving["max_model_len"] // BS
+    cfg = hf_config_to_transformer(hf, max_seq_len=serving["max_model_len"],
+                                   dtype=jnp.bfloat16, kv_cache_bits=8)
+    model = make_model(cfg)
+    params = _abstract(jax.eval_shape(lambda: jax.tree.map(
+        lambda a: a.astype(jnp.bfloat16), model.init(jax.random.PRNGKey(0)))),
+        one_chip)
+    pools = _abstract(jax.eval_shape(lambda: model.init_paged_cache(
+        S * MB + 1, BS, dtype=jnp.bfloat16, max_seqs=S)), one_chip)
+    srv = object.__new__(ServingEngine)
+    srv.model, srv.decode_backend = model, "xla"
+    srv.config = ServingConfig(max_seqs=S)
+    srv._moe_forms, srv._prefill_fns = {}, {}
+    srv._slot_state = int(cfg.slot_state_blocks)
+    srv._repl_sharding = srv._pool_shardings = None
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return cfg, params, pools, srv, S, MB, sds
+
+
+def _compiled_for_the_chip(fn, args, monkeypatch):
+    """``fn`` compiled for the described v5e, its backend branches taken as
+    on the chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        with jax.default_matmul_precision("default"):
+            return fn.lower(*args).compile()
+    finally:
+        monkeypatch.undo()
+
+
 @pytest.mark.parametrize("kind,width", [("step", 88), ("prefill", 9216)])
 def test_the_window_cells_programs_fit_the_chip_and_write_the_rings_in_place(
         kind, width, one_chip, monkeypatch):
@@ -570,36 +617,12 @@ def test_the_window_cells_programs_fit_the_chip_and_write_the_rings_in_place(
     writes a whole ring or pool leaf (a ring leaf is 268 MB: stacked on
     their blocks, the rings were split and put together again around every
     step, 4.3 GB of copies)."""
-    import json
-    import os
-    from deepspeed_tpu.inference.serving import ServingConfig, ServingEngine
-    from deepspeed_tpu.models.hf_import import hf_config_to_transformer
-    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    with open(os.path.join(root, "benchmark", "configs", "trinity-large-serve.json")) as f:
-        conf = json.load(f)
-    hf = {k: v for k, v in conf.items() if k not in (
-        "source", "reduced", "assumed", "deployment", "run", "correct")}
-    serving = conf["run"]["serving"]
-    S, MB = serving["max_seqs"], serving["max_model_len"] // BS
-    cfg = hf_config_to_transformer(hf, max_seq_len=serving["max_model_len"],
-                                   dtype=jnp.bfloat16, kv_cache_bits=8)
-    model = make_model(cfg)
-    params = _abstract(jax.eval_shape(lambda: jax.tree.map(
-        lambda a: a.astype(jnp.bfloat16), model.init(jax.random.PRNGKey(0)))),
-        one_chip)
-    pools = _abstract(jax.eval_shape(lambda: model.init_paged_cache(
-        S * MB + 1, BS, dtype=jnp.bfloat16, max_seqs=S)), one_chip)
+    cfg, params, pools, srv, S, MB, sds = _hybrid_cell("trinity-large-serve",
+                                                       one_chip)
     assert pools["k"].shape == (1, S * MB + 1, BS, 8, HD)
     assert len(pools["wk"]) == 4 and pools["wk"][0].shape == (S, 4096, 8, HD)
     assert pools["wk"][0].dtype == jnp.int8
-    srv = object.__new__(ServingEngine)
-    srv.model, srv.decode_backend = model, "xla"
-    srv.config = ServingConfig(max_seqs=S)
-    srv._moe_forms, srv._prefill_fns, srv._slot_state = {}, {}, 4
-    srv._repl_sharding = srv._pool_shardings = None
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    assert srv._slot_state == 4
     key = sds((2,), jnp.uint32)
     if kind == "step":
         fn = jax.jit(srv._quantum_step_fn().__wrapped__, donate_argnums=(1, 4))
@@ -611,12 +634,7 @@ def test_the_window_cells_programs_fit_the_chip_and_write_the_rings_in_place(
         args = (params, sds((1, width), jnp.int32), pools,
                 sds((width // BS,), jnp.int32), sds((), jnp.int32), key,
                 sds((), jnp.int32))
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    try:
-        with jax.default_matmul_precision("default"):
-            compiled = fn.lower(*args).compile()
-    finally:
-        monkeypatch.undo()
+    compiled = _compiled_for_the_chip(fn, args, monkeypatch)
     hlo, mem = compiled.as_text(), compiled.memory_analysis()
     resident = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     print(f"{kind} {width}: arguments {mem.argument_size_in_bytes / 2**30:.2f} GiB, "
@@ -645,3 +663,34 @@ def test_the_window_cells_programs_fit_the_chip_and_write_the_rings_in_place(
     per_layer = f"bf16[{cfg.num_experts},3072,3072]"
     assert not [l for l in hlo.splitlines()
                 if f" = {per_layer}" in l and "parameter" not in l], per_layer
+
+
+# ---- a hybrid stack's step that sorts (ISSUE 46) -----------------------------
+
+def test_nemotrons_step_sorts_and_reads_its_experts_in_place(one_chip,
+                                                             monkeypatch):
+    """Nemotron-3-Nano at the PUBLISHED widths and the cell's shapes
+    (benchmark/configs/nemotron-3-nano-30b-serve.json: 128 slots x top-6
+    over 128 experts of [2688, 1856], nine blocks) through the engine's own
+    step: since PR 46 the rule sorts it from its shapes, the program compiles
+    for the described v5e and fits it, the grouped-matmul kernel is in it
+    (eight calls: four expert blocks x two projections, the up projection on
+    the kernel's ``transposed`` path — ``w_in_t``, F = 1856 is off the 128
+    grid), and nothing makes a copy of one block's experts ([128, 1856, 2688]
+    or its transpose, 1.28 GB a stack: PR 26's finding, 30 % of a step)."""
+    cfg, params, pools, srv, S, MB, sds = _hybrid_cell(
+        "nemotron-3-nano-30b-serve", one_chip)
+    fn = jax.jit(srv._quantum_step_fn().__wrapped__, donate_argnums=(1, 4))
+    args = (params, pools, sds((S,), jnp.int32), _block_list(sds, S, MB, MB),
+            sds((S,), jnp.int32), sds((S,), jnp.bool_), sds((2,), jnp.uint32))
+    compiled = _compiled_for_the_chip(fn, args, monkeypatch)
+    hlo, mem = compiled.as_text(), compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
+    assert srv._moe_forms == {"step": "sorted/moe_gmm"}
+    calls = [l for l in hlo.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in l and "%moe_gmm" in l]
+    assert len(calls) == 8, len(calls)
+    E, H, F = cfg.num_experts, cfg.hidden_size, 1856
+    for per_block in (f"bf16[{E},{F},{H}]", f"bf16[{E},{H},{F}]"):
+        assert not [l for l in hlo.splitlines()
+                    if f" = {per_block}" in l and "parameter" not in l], per_block

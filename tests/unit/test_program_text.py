@@ -37,6 +37,21 @@ test_the_dispatch_is_chosen_by_the_calls_shapes``), the sorted step's logits
 and counters against the one-hot step's (``test_afmoe.py``), and Trinity's
 step at the published sizes compiled for the described chip with
 ``%moe_gmm`` in it (``test_pool_layout.py -k window_cells``).
+
+PR 46 (the one-hot form priced by what it costs: its ``[E, T, H]`` rows in
+and out and the einsums around them, not its streaming alone) printed the
+table again from its tree: THREE rows moved, all OLMoE's and all of 128
+tokens — ``prefill128``, ``packed128`` and ``forward`` (2 x 64 tokens at
+inference) now take the sorted dispatch (``ragged_dot`` on the CPU). The new
+terms do not shrink with a toy expert as its bytes do: 64 experts x 128
+tokens x 256 floats, four passes and the einsums, are 262 visits of a 196 KB
+expert on top of the 64, against 71 sorted + 234 of fixed work and tie. No
+other toy row has the experts or the tokens for it (Mixtral's toy holds 8
+experts, Nemotron's 8, Qwen3-Next's and Trinity's shares 8 and 16; 32 tokens
+and the 4-slot step are under it everywhere). What the change does at the
+PUBLISHED widths is held by shape in ``test_olmoe.py``, by logits in
+``test_nemotron_h.py`` and by the compiled step in ``test_pool_layout.py -k
+nemotrons_step``.
 """
 import functools
 import hashlib
@@ -67,8 +82,8 @@ GOLDEN = {
         "packed128": "7a87a1bf20fcb551"},
     "olmoe-1b-7b-serve": {
         "step": "95a0e4a3ef4f8f1d", "prefill32": "8306be4eb9138aae",
-        "prefill128": "d43a0d903a239b6a", "forward": "c545e469c97acb9b",
-        "packed128": "bd7dcef9713cb748"},
+        "prefill128": "323b28795b09f45a", "forward": "2580a662878249f6",
+        "packed128": "7aa493eb6b2d1c85"},
     "nemotron-3-nano-30b-serve": {
         "step": "1acd3b9372fd7517", "prefill32": "efe837a6e02bed9a",
         "prefill128": "8a4ca89d7fca6ca0", "forward": "cda6540c5ea4d05a"},
