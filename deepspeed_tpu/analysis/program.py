@@ -22,6 +22,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
+from deepspeed_tpu.utils.memory import abstractify  # noqa: F401  (its home)
+
 
 @dataclasses.dataclass
 class ProgramArtifacts:
@@ -38,17 +40,6 @@ class ProgramArtifacts:
     donation_expected: bool = True
     compute_dtype: str = "f32"         # "f32" | "bf16" | "f16"
     meta: Dict[str, Any] = dataclasses.field(default_factory=dict)
-
-
-def abstractify(tree):
-    """Concrete arrays -> ShapeDtypeStructs carrying the same shardings, so
-    `.lower()` never touches device data."""
-    def one(x):
-        if isinstance(x, jax.ShapeDtypeStruct) or x is None:
-            return x
-        sharding = getattr(x, "sharding", None)
-        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
-    return jax.tree.map(one, tree)
 
 
 def tree_leaf_paths(tree) -> Tuple[Tuple[str, ...], Tuple[int, ...]]:

@@ -9,7 +9,6 @@ from deepspeed_tpu.inference.scheduler import (AdmissionRejected, Request,
 from deepspeed_tpu.inference.spec_decode import (NgramProposer,
                                                  greedy_accept_len)
 from deepspeed_tpu.inference.serving import (DecodeDispatchHang,
-                                             RecurrentStateUnsupported,
                                              SlotStateUnsupported,
                                              ResumeIncompatible,
                                              ServingConfig, ServingEngine,

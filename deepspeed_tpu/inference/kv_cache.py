@@ -258,68 +258,59 @@ def blocks_for(n_tokens: int, block_size: int) -> int:
     return -(-n_tokens // block_size)
 
 
-def state_pool_bytes(cfg, max_seqs: int, dtype=None) -> int:
-    """LOGICAL bytes of what a model keeps PER SERVING SLOT beside the K/V
-    block pool (``models/hybrid.py`` ``state_leaves``, every leaf summed):
-    per recurrent block a float32 state (Mamba-2 [heads, head dim, state
-    size], Gated DeltaNet [value heads, key dim, value dim]) and the last
-    K - 1 rows of the convolution's input in the pool dtype; per window
-    block the ring of the last ``window`` positions' K/V, rows and scales as
-    the pool's. 0 for every other model."""
-    if not int(getattr(cfg, "slot_state_blocks", 0) or 0):
-        return 0
-    import math
-    import numpy as _np
-    from deepspeed_tpu.models.hybrid import state_leaves
-    dtype = dtype if dtype is not None else cfg.dtype
-    return sum(math.prod(shape) * _np.dtype(leaf_dtype).itemsize
-               for shape, leaf_dtype in state_leaves(cfg, max_seqs, dtype
-                                                     ).values()
-               ) + max_seqs * ring_bytes_per_slot(cfg, dtype)
+def abstract_cache(model, num_blocks: int, block_size: int, dtype=None,
+                   max_seqs: int = 0):
+    """THE description of what ``model`` (a ``ModelSpec``) keeps for serving:
+    its own ``init_paged_cache`` — the K/V block pool and, for ``max_seqs``
+    slots, whatever it keeps per slot (``model.slot_leaves``) — read
+    abstractly (``jax.eval_shape``: shapes and dtypes, nothing allocated). An
+    engine allocates by the same call, so the two cannot disagree."""
+    import jax
+    return jax.eval_shape(lambda: model.init_paged_cache(
+        num_blocks, block_size, dtype=dtype, max_seqs=max_seqs))
 
 
-def ring_bytes_per_slot(cfg, dtype=None) -> int:
-    """Bytes ONE serving slot's window rings hold, whatever its context:
-    window blocks x window rows x (K + V rows and, for an int8 cache, their
-    scales). 0 for a model without window blocks."""
-    if not int(getattr(cfg, "window_blocks", 0) or 0):
-        return 0
-    import math
-    import numpy as _np
-    from deepspeed_tpu.models.hybrid import ring_leaves
-    return cfg.window_blocks * sum(
-        math.prod(shape) * _np.dtype(leaf_dtype).itemsize
-        for shape, leaf_dtype in ring_leaves(
-            cfg, 1, dtype if dtype is not None else cfg.dtype).values())
+def ring_leaves(model, cache: Dict[str, "object"]) -> Dict[str, tuple]:
+    """The window rings of a model's paged cache ``cache`` (arrays, or the
+    shapes of ``abstract_cache``): of the leaves the model names as per slot,
+    those the tree holds as a tuple, one array a window block."""
+    return {name: cache[name] for name in model.slot_leaves
+            if isinstance(cache[name], tuple)}
+
+
+def cache_bytes(model, cache: Dict[str, "object"]) -> Dict[str, int]:
+    """LOGICAL bytes of a model's paged cache by whose they are: ``kv`` the
+    block pool (the only part that grows with a request's context), ``state``
+    the per-slot leaves that are no ring (a recurrent state, its convolution
+    tail), ``rings`` the window rings. The three add up to the tree."""
+    from deepspeed_tpu.parallel.partitioning import params_bytes
+    rings = ring_leaves(model, cache)
+    out = {"kv": 0, "state": 0, "rings": params_bytes(rings)}
+    for name, leaf in cache.items():
+        if name not in rings:
+            out["state" if name in model.slot_leaves else "kv"] += \
+                params_bytes(leaf)
+    return out
 
 
 def pool_bytes(cfg, num_blocks: int, block_size: int, dtype=None,
                max_seqs: int = 0) -> int:
-    """LOGICAL resident bytes of the block pools for a transformer config
-    — the paged-cache memory math the README documents. int8: 1 byte/elem
-    payload + 4 bytes/row/head scale x2 (k, v); float: itemsize of the
-    POOL dtype x2 — pass the engine's compute dtype (the pools are
-    allocated with it, which may differ from cfg.dtype).
+    """LOGICAL resident bytes of the cache a transformer config's model
+    keeps for serving — the paged-cache memory math the README documents —:
+    ``abstract_cache`` summed. int8: 1 byte an element and a float32 scale a
+    row and head, K and V; float: the POOL dtype's itemsize — pass the
+    engine's compute dtype (the pools are allocated with it, which may
+    differ from cfg.dtype).
 
     On a tensor-parallel serving mesh each chip holds only its kv-head
     slice: the PER-DEVICE number — what ``ServingEngine.pool_bytes`` /
     ``stats()["pool_bytes"]`` report — is this divided by the tp degree
     (``parallel.partitioning.sharded_bytes`` prices it from the committed
     shardings; the memory-law test pins per_device * tp == logical)."""
-    # the planes of K/V a token keeps (``TransformerConfig.kv_planes``): one
-    # per layer of a homogeneous stack, per "*" attention block of a hybrid
-    # one — whose per-slot state (recurrent state, window rings; for
-    # ``max_seqs`` slots) is counted beside them — and per pass of a looped
-    # one
-    L = getattr(cfg, "kv_planes", cfg.num_layers)
-    nkv, hd = cfg.kv_heads, cfg.dim_per_head
-    rows = L * num_blocks * nkv * block_size
-    state = state_pool_bytes(cfg, max_seqs, dtype)
-    if cfg.kv_cache_bits == 8:
-        return rows * hd * 2 + rows * 4 * 2 + state
-    import numpy as _np
-    itemsize = _np.dtype(dtype if dtype is not None else cfg.dtype).itemsize
-    return rows * hd * itemsize * 2 + state
+    from deepspeed_tpu.models import make_model
+    from deepspeed_tpu.parallel.partitioning import params_bytes
+    return params_bytes(abstract_cache(make_model(cfg), num_blocks,
+                                       block_size, dtype, max_seqs))
 
 
 def kv_payload_nbytes(data: Dict[str, "object"]) -> int:

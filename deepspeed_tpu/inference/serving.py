@@ -88,8 +88,8 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from deepspeed_tpu.inference.kv_cache import (BlockAllocator, blocks_for,
-                                              kv_payload_nbytes, pool_bytes,
-                                              ring_bytes_per_slot)
+                                              cache_bytes, kv_payload_nbytes,
+                                              ring_leaves)
 from deepspeed_tpu.inference.schemas import (DRAIN_STATE_VERSION,
                                              KV_PAYLOAD_SCHEMA)
 from deepspeed_tpu.inference.scheduler import (AdmissionRejected, Request,
@@ -202,9 +202,10 @@ class DecodeDispatchHang(RuntimeError):
 
 class SlotStateUnsupported(ValueError):
     """What a model that keeps something PER SERVING SLOT beside its K/V
-    blocks cannot be served with — a recurrent state (``block_pattern`` with
-    "M" or "G": ``nemotron_h``, ``qwen3_next``) or a window ring ("W":
-    ``afmoe``) —, refused at ``init_serving`` or at the call: a request's
+    blocks (``ModelSpec.slot_leaves``: a recurrent state — ``nemotron_h``,
+    ``qwen3_next`` —, a window ring — ``afmoe``) cannot be served with,
+    refused at ``init_serving`` or at the call
+    (``ServingEngine._by_blocks_alone``): a request's
     state there is its K/V blocks AND its slot's state, and everything that
     shares, rolls back, resumes or ships a request's state by its blocks
     alone — the prefix cache and its copy-on-write fork, chunked prefill,
@@ -219,9 +220,6 @@ class SlotStateUnsupported(ValueError):
             "state (recurrent state, window ring), and no snapshot of the "
             "latter is kept")
         self.what = what
-
-
-RecurrentStateUnsupported = SlotStateUnsupported     # the name before PR 44
 
 
 class ResumeIncompatible(ValueError):
@@ -340,7 +338,6 @@ class ServingConfig:
     eos_token_id: Optional[int] = None
     decode_backend: str = "auto"       # auto | xla | pallas
     prompt_bucket: int = 64            # prompt pad granularity (compile reuse)
-    backend_bench_iters: int = 10      # micro-bench timing iterations
     # --- reliability tier (all default off = pre-reliability behavior) ---
     # default per-request deadlines (ms from submit; add_request overrides
     # per request; None = unbounded). Enforced at round boundaries:
@@ -371,7 +368,6 @@ class ServingConfig:
     # pool pressure — a hit is a latency win, a miss never an admission
     # loss.
     enable_prefix_cache: bool = False
-    prefix_cache_blocks: Optional[int] = None   # cache-held block cap
     # chunked prefill: per-round token budget SHARED between prefill
     # chunks and the decode quantum's `decode_quantum * n_decoding`
     # reservation — long prompts slice across rounds instead of stalling
@@ -381,10 +377,10 @@ class ServingConfig:
     # speculative decoding: K proposed tokens verified per round in one
     # decode_span_paged pass (0 = off). Greedy-only (temperature 0.0):
     # the accept rule keeps output token-identical to K=0. Proposer
-    # defaults to self-drafting n-gram lookup; spec_proposer is the draft
-    # hook — any (context ids, k) -> <= k proposed ids callable.
+    # defaults to self-drafting n-gram lookup (n = ``_SPEC_NGRAM``);
+    # spec_proposer is the draft hook — any (context ids, k) -> <= k
+    # proposed ids callable.
     spec_tokens: int = 0
-    spec_ngram: int = 3
     spec_proposer: Optional[Any] = None
     # --- multi-tenant LoRA serving (ISSUE 17; 0 = off) ----------------
     # device adapter slot pool size INCLUDING the reserved all-zero null
@@ -404,7 +400,6 @@ class ServingConfig:
     # Arm at runtime with enable_request_trace() to A/B a warm engine.
     request_trace: bool = False
     trace_replica: str = "r0"          # process row in the merged trace
-    trace_events: int = 65536          # tracer ring bound
     # --- disaggregated serving (ISSUE 19; "both" = colocated behavior) ---
     # fleet tier this engine serves: a "prefill" engine runs prompt
     # prefills and emits each request's FIRST token but never a decode
@@ -414,6 +409,12 @@ class ServingConfig:
     # targets prefill-capable replicas first. "both" is the pre-ISSUE-19
     # colocated engine, and what role-less heartbeats interop as.
     role: str = "both"                 # prefill | decode | both
+
+
+# the n of the self-drafting proposer's n-gram lookup, and the bound of the
+# request tracer's ring of spans: one value each in every use, so constants
+_SPEC_NGRAM = 3
+_TRACE_EVENTS = 65536
 
 
 # blocks of one entry of a decode round's block list: a RUN of two columns of
@@ -562,22 +563,20 @@ class ServingEngine:
                 f"max_model_len/model max_seq_len ({c.max_model_len} / "
                 f"{model_cap}) leaves no room for one "
                 f"{c.block_size}-token block")
-        # a model with recurrent or window blocks keeps a state per slot
-        # beside the K/V blocks of its "*" attention blocks (models/hybrid.py)
-        self._slot_state = int(getattr(mcfg, "slot_state_blocks", 0) or 0)
-        if self._slot_state:
-            for armed, what in (
-                    (c.enable_prefix_cache, "the prefix cache"),
-                    (c.prefill_token_budget is not None, "chunked prefill"),
-                    (c.spec_tokens > 0, "speculative decoding"),
-                    (c.adapter_slots > 0, "LoRA adapter serving"),
-                    (self.tp > 1, "a tensor-parallel pool")):
-                if armed:
-                    raise SlotStateUnsupported(what)
-        # rows of a window block's ring (0: the model has no such block) and
-        # what the plain rounds read of them (reset_stats windows)
-        from deepspeed_tpu.models.hybrid import window
-        self._window = window(mcfg) if getattr(mcfg, "window_blocks", 0) else 0
+        # what the model keeps per serving slot beside its K/V blocks, by its
+        # own statement: the names of those leaves of the pool (none: a
+        # request's state is its blocks alone)
+        self._slot_state = tuple(model.slot_leaves)
+        for armed, what in (
+                (c.enable_prefix_cache, "the prefix cache"),
+                (c.prefill_token_budget is not None, "chunked prefill"),
+                (c.spec_tokens > 0, "speculative decoding"),
+                (c.adapter_slots > 0, "LoRA adapter serving"),
+                (self.tp > 1, "a tensor-parallel pool")):
+            if armed:
+                self._by_blocks_alone(what)
+        # what the plain rounds read of the model's window rings
+        # (``model.ring_rows`` rows each; reset_stats windows)
         self._win = {"slot_rounds": 0, "rows_in_window": 0}
         self.max_model_len = want
         self.MB = self.max_model_len // c.block_size     # table width
@@ -640,9 +639,9 @@ class ServingEngine:
         self._prefix_cache = None
         if c.enable_prefix_cache:
             from deepspeed_tpu.inference.prefix_cache import PrefixCache
-            self._prefix_cache = PrefixCache(
-                self.allocator, c.block_size,
-                max_blocks=c.prefix_cache_blocks)
+            # no cap of its own on the blocks it holds: the pool's pressure
+            # evicts them (the scheduler asks before it queues or preempts)
+            self._prefix_cache = PrefixCache(self.allocator, c.block_size)
         # the scheduler's per-round row guarantee must cover a verify
         # step's K+1 writes as well as the plain quantum's
         self._sched_quantum = max(c.decode_quantum,
@@ -655,8 +654,9 @@ class ServingEngine:
             prefix_cache=self._prefix_cache)
         self._proposer = None
         if c.spec_tokens > 0:
-            from deepspeed_tpu.inference.spec_decode import make_proposer
-            self._proposer = make_proposer(c.spec_proposer, c.spec_ngram)
+            from deepspeed_tpu.inference.spec_decode import NgramProposer
+            self._proposer = (c.spec_proposer
+                              or NgramProposer(_SPEC_NGRAM).propose)
 
         # device state -------------------------------------------------
         # Pool shardings come from the SAME col/row rules the weights use:
@@ -682,23 +682,25 @@ class ServingEngine:
         self._init_pools_fn = jax.jit(
             lambda: model.init_paged_cache(
                 num_blocks, c.block_size, dtype=engine.dtype,
-                **({"max_seqs": c.max_seqs} if self._slot_state else {})),
+                max_seqs=c.max_seqs),
             out_shardings=self._pool_shardings)
         with engine.mesh:
             self.pools = self._init_pools_fn()
-        # the per-slot recurrent state's dtype (None for every other model)
+        # the per-slot recurrent state's dtype: that of the first per-slot
+        # leaf that is no ring (None for a model without one)
+        rings = ring_leaves(model, self.pools)
         self.state_pool_dtype = next(
-            (str(self.pools[k].dtype) for k in ("ssm", "gdn")
-             if k in self.pools), None)
-        # logical pool size (the README memory math, mesh-independent) vs
+            (str(self.pools[k].dtype) for k in self._slot_state
+             if k not in rings), None)
+        # logical pool size (the README memory math, mesh-independent: the
+        # model's own tree summed, ``kv_cache.cache_bytes``) vs
         # the PER-DEVICE shard each chip actually holds: on a tp-sharded
         # engine the resident HBM is logical / tp (the kv-head slice), and
         # pool_bytes — what stats()/bench report — must price THAT, not
         # the logical array (ISSUE 15: the old single number overstated
         # HBM by the tp degree on sharded engines)
-        self.pool_bytes_logical = pool_bytes(mcfg, num_blocks, c.block_size,
-                                             dtype=engine.dtype,
-                                             max_seqs=c.max_seqs)
+        self._cache_bytes = cache_bytes(model, self.pools)
+        self.pool_bytes_logical = sum(self._cache_bytes.values())
         from deepspeed_tpu.parallel.partitioning import sharded_bytes
         self.pool_bytes = sharded_bytes(self.pools)
         # --- adapter slot pool (ISSUE 17: paged multi-LoRA) ------------
@@ -882,6 +884,12 @@ class ServingEngine:
                 "matching mesh geometry (place it on a survivor with the "
                 "same tp/ep degrees)")
 
+    def _by_blocks_alone(self, what: str) -> None:
+        """THE rule for what this engine refuses (``SlotStateUnsupported``):
+        ``what`` takes a request's K/V blocks for the whole of its state."""
+        if self._slot_state:
+            raise SlotStateUnsupported(what)
+
     # ---- fleet observability (ISSUE 18) ------------------------------
 
     def enable_request_trace(self, replica: Optional[str] = None,
@@ -894,7 +902,7 @@ class ServingEngine:
         from deepspeed_tpu.telemetry.request_trace import RequestTracer
         self._tracer = RequestTracer(
             replica=replica or self.config.trace_replica,
-            max_events=self.config.trace_events, on_span=on_span)
+            max_events=_TRACE_EVENTS, on_span=on_span)
         return self._tracer
 
     def disable_request_trace(self) -> None:
@@ -1158,7 +1166,7 @@ class ServingEngine:
             mcfg, self.pools["k"][0], self.pools["v"][0],
             max_seqs=c.max_seqs, MB=self.MB, block_size=c.block_size,
             num_blocks=self.num_blocks, dtype=self.engine.dtype,
-            iters=c.backend_bench_iters, mesh=self.engine.mesh)
+            mesh=self.engine.mesh)
         backend = "pallas" if pallas_ms < xla_ms else "xla"
         bench = {"backend": backend, "xla_ms": round(xla_ms, 3),
                  "pallas_ms": round(pallas_ms, 3),
@@ -1321,7 +1329,7 @@ class ServingEngine:
         if self._quantum_step is None:
             import jax
             import jax.numpy as jnp
-            from deepspeed_tpu.analysis.program import abstractify
+            from deepspeed_tpu.utils.memory import abstractify
 
             sds = jax.ShapeDtypeStruct
             fn = self._quantum_step_fn()
@@ -1642,8 +1650,7 @@ class ServingEngine:
         pin on the shared block is dropped. Runs BEFORE any of the
         request's own writes — full shared blocks stay referenced, the
         partial one is never written in place."""
-        if self._slot_state:
-            raise SlotStateUnsupported("a copy-on-write fork")
+        self._by_blocks_alone("a copy-on-write fork")
         src, dst = req.cow_src, req.cow_dst
         with self.engine.mesh:
             self.pools = self._copy_block_fn(self.pools, np.int32(src),
@@ -1797,12 +1804,12 @@ class ServingEngine:
             # its slot but must not decode yet
             act[req.slot] = req.prefill_done
             aidx[req.slot] = req.adapter_slot or 0
-        if self._window and not full:
+        if self.model.ring_rows and not full:
             # a step reads a window plane's whole ring for every live slot;
             # of those rows, min(length, window) are inside the slot's band
             self._win["slot_rounds"] += int(act.sum())
             self._win["rows_in_window"] += int(
-                np.minimum(lens[act], self._window).sum())
+                np.minimum(lens[act], self.model.ring_rows).sum())
         return (jax.tree.map(jnp.asarray, tables), jnp.asarray(lens),
                 jnp.asarray(act), jnp.asarray(aidx)), (S, W), held
 
@@ -2531,8 +2538,7 @@ class ServingEngine:
         them in ``pool_bytes``/``kv_staging_bytes``."""
         import jax
         import jax.numpy as jnp
-        if self._slot_state:
-            raise SlotStateUnsupported("K/V export")
+        self._by_blocks_alone("K/V export")
         bs = self.config.block_size
         out: Dict[int, Dict[str, Any]] = {}
         for rid in request_ids:
@@ -2624,8 +2630,7 @@ class ServingEngine:
         ``BlockAllocator`` path, the payload scatters into them before
         the 1-tail-span prefill runs, and the continuation is
         token-identical to the colocated engine."""
-        if self._slot_state:
-            raise SlotStateUnsupported("K/V import")
+        self._by_blocks_alone("K/V import")
         req = self._requests.get(request_id)
         if req is None or req.state != "waiting":
             raise ResumeIncompatible(
@@ -2898,8 +2903,7 @@ class ServingEngine:
                     "on an engine at least as large as the drained one")
             payload = kv.get(req.rid)
             if payload is not None:
-                if self._slot_state:
-                    raise SlotStateUnsupported("K/V import")
+                self._by_blocks_alone("K/V import")
                 # all-or-nothing with the rest of the batch: a bad payload
                 # refuses HERE, before anything is enqueued
                 self._validate_kv_payload(req, payload, source)
@@ -3027,16 +3031,6 @@ class ServingEngine:
         return {r.rid: r.output for r in self._finished if r.rid in mine}
 
     # ---- stats -------------------------------------------------------
-
-    def _state_bytes(self) -> int:
-        """Per-device bytes of the per-slot recurrent state pool (0 for a
-        model without recurrent blocks)."""
-        if not self._slot_state:
-            return 0
-        from deepspeed_tpu.models.hybrid import STATE_LEAVES
-        from deepspeed_tpu.parallel.partitioning import sharded_bytes
-        return sharded_bytes({k: self.pools[k] for k in STATE_LEAVES
-                              if k in self.pools})
 
     def reset_stats(self) -> None:
         """Start a fresh measurement window: completed-request records,
@@ -3273,29 +3267,34 @@ class ServingEngine:
         forms = {k: v for k, v in self._moe_forms.items() if v}
         if forms:
             out["moe_dispatch"] = forms
-        out["kv_pool_bytes"] = float(self.pool_bytes - self._state_bytes())
+        # what the model keeps per slot is never split over a mesh (a
+        # tensor-parallel pool is refused with it): per device = logical
+        state_bytes = self._cache_bytes["state"] + self._cache_bytes["rings"]
+        out["kv_pool_bytes"] = float(self.pool_bytes - state_bytes)
+        kv_bytes_per_token = float(self._cache_bytes["kv"] // (
+            self.num_blocks * self.config.block_size))
         if self._ut_steps > 1:
             out["ut_steps"] = float(self._ut_steps)
             out["kv_planes"] = float(mcfg.kv_planes)
-            out["kv_bytes_per_token"] = float(pool_bytes(
-                mcfg, 1, 1, dtype=self.engine.dtype))
+            out["kv_bytes_per_token"] = kv_bytes_per_token
             if self._exit[-1]:
                 p = self._exit[:-1] / self._exit[-1]
                 out["exit_step_expected"] = float(
                     np.dot(np.arange(1, p.size + 1), p))
                 out["exit_cdf"] = [float(x) for x in np.cumsum(p)]
         if self._slot_state:
-            out["state_pool_bytes"] = float(self._state_bytes())
+            out["state_pool_bytes"] = float(state_bytes)
             out["state_slots_live"] = float(len(self.scheduler.running))
-        if self._window:
-            out["window_blocks"] = float(mcfg.window_blocks)
-            out["window_rows"] = float(self._window)
-            out["kv_bytes_per_token"] = float(pool_bytes(
-                mcfg, 1, 1, dtype=self.engine.dtype))
-            out["ring_bytes_per_slot"] = float(ring_bytes_per_slot(
-                mcfg, dtype=self.engine.dtype))
+        if self.model.ring_rows:
+            # a ring leaf holds one array a window block
+            out["window_blocks"] = float(len(next(iter(
+                ring_leaves(self.model, self.pools).values()))))
+            out["window_rows"] = float(self.model.ring_rows)
+            out["kv_bytes_per_token"] = kv_bytes_per_token
+            out["ring_bytes_per_slot"] = float(
+                self._cache_bytes["rings"] // self.config.max_seqs)
             out["window_rows_read"] = float(
-                self._window * self._win["slot_rounds"])
+                self.model.ring_rows * self._win["slot_rounds"])
             out["window_rows_in_window"] = float(self._win["rows_in_window"])
         out.update({k: float(v) for k, v in self._lat.items()})
         out["step_shape_rounds"] = {

@@ -34,14 +34,9 @@ copied spans. A learned draft model drops into the same hook
 ``(context: np.ndarray, k: int) -> array of <= k token ids``.
 """
 
-from typing import Callable, Optional
-
 import numpy as np
 
 import jax.numpy as jnp
-
-# a draft hook: (host context token ids, k) -> up to k proposed ids
-Proposer = Callable[[np.ndarray, int], np.ndarray]
 
 
 class NgramProposer:
@@ -86,12 +81,3 @@ def greedy_accept_len(next_tokens, proposals):
     k = proposals.shape[-1]
     match = (next_tokens[..., :k] == proposals).astype(jnp.int32)
     return jnp.cumprod(match, axis=-1).sum(axis=-1)
-
-
-def make_proposer(spec_proposer: Optional[Proposer],
-                  ngram: int) -> Proposer:
-    """The engine's hook resolution: an explicit draft callable wins,
-    otherwise the self-drafting n-gram proposer."""
-    if spec_proposer is not None:
-        return spec_proposer
-    return NgramProposer(ngram).propose

@@ -279,9 +279,8 @@ class TransformerConfig:
     @property
     def slot_state_blocks(self) -> int:
         """Blocks that keep something PER SERVING SLOT beside the K/V block
-        pool: a recurrent state or a window ring. What the serving engine
-        asks before it shares, rolls back or ships a request by its blocks
-        alone."""
+        pool: a recurrent state or a window ring (the leaves themselves are
+        ``ModelSpec.slot_leaves``, which is what the serving engine asks)."""
         return self.recurrent_blocks + self.window_blocks
 
     @property
@@ -3106,7 +3105,8 @@ class ModelSpec:
                                                      Params]]] = None
     merge_suffix: Optional[Callable[..., Params]] = None
     # paged serving protocol (block pool + block tables; the ServingEngine
-    # consumes these): init_paged_cache(num_blocks, block_size) -> pools;
+    # consumes these): init_paged_cache(num_blocks, block_size, dtype=,
+    # max_seqs=) -> pools;
     # prefill_paged(params, ids, pools, block_ids, length) ->
     # (last_logits, pools); decode_step_paged(params, tokens, pools,
     # block_tables, seq_lens, active, backend) -> (logits, pools).
@@ -3124,6 +3124,17 @@ class ModelSpec:
     decode_span_paged: Optional[Callable[..., Tuple[jnp.ndarray,
                                                     Params]]] = None
     paged_cache_axes: Optional[Callable[[], Params]] = None
+    # what the model keeps PER SERVING SLOT beside its K/V blocks: the names
+    # of those leaves of init_paged_cache's tree, sized by its ``max_seqs``
+    # (a recurrent kind's state before its convolution tail). Empty: a
+    # request's state is its blocks alone, and only then may it be shared,
+    # rolled back, resumed or shipped by them. Everything else about the
+    # cache (shapes, dtypes, bytes) is ``jax.eval_shape`` of
+    # init_paged_cache: ``inference/kv_cache.abstract_cache``.
+    slot_leaves: Tuple[str, ...] = ()
+    # rows of a window ring (0: none). The ring leaves are the per-slot
+    # leaves the tree holds as a tuple, one array a window block.
+    ring_rows: int = 0
 
     def flops_per_token(self) -> float:
         """Approximate train FLOPs/token (6N rule + attention)."""
@@ -3173,6 +3184,9 @@ def _make_hybrid_model(cfg: TransformerConfig, name: str) -> ModelSpec:
             hybrid.decode_step_paged(params, tokens, cfg, pools,
                                      block_tables, seq_lens, **kw),
         paged_cache_axes=lambda: hybrid.paged_cache_logical_axes(cfg),
+        slot_leaves=tuple(hybrid.state_leaves(cfg, 1))
+        + tuple(hybrid.ring_leaves(cfg, 1)),
+        ring_rows=hybrid.window(cfg) if cfg.window_blocks else 0,
     )
 
 
@@ -3197,7 +3211,9 @@ def make_model(cfg: TransformerConfig, name: str = "transformer") -> ModelSpec:
         decode_step=lambda params, token, cache, **kw:
             decode_step(params, token, cfg, cache, **kw),
         cache_axes=lambda: cache_logical_axes(cfg),
-        init_paged_cache=lambda num_blocks, block_size, dtype=None:
+        # max_seqs: for what a model keeps per serving slot — nothing here
+        init_paged_cache=lambda num_blocks, block_size, dtype=None,
+            max_seqs=None:
             init_paged_cache(cfg, num_blocks, block_size, dtype=dtype),
         prefill_paged=lambda params, input_ids, pools, block_ids, **kw:
             prefill_paged(params, input_ids, cfg, pools, block_ids, **kw),

@@ -2035,7 +2035,7 @@ class Engine:
                 or self._tel_abs is not None):
             return
         try:
-            from deepspeed_tpu.analysis.program import abstractify
+            from deepspeed_tpu.utils.memory import abstractify
             self._tel_abs = (fn, abstractify(args), divisor)
         except Exception as e:  # noqa: BLE001 - telemetry never kills a run
             logger.debug(f"telemetry: static arg capture failed: {e!r}")

@@ -1,4 +1,4 @@
-"""Device/host memory reporting.
+"""Device/host memory reporting, and arrays read as shapes.
 
 Reference: ``deepspeed/runtime/utils.py:768`` (``see_memory_usage``) — reads the
 CUDA caching-allocator stats. The TPU equivalent reads per-device memory stats
@@ -41,6 +41,19 @@ def device_memory_stats(device=None) -> Dict[str, float]:
     if "bytes_limit" in stats:
         out["device_gb_limit"] = stats["bytes_limit"] / 1e9
     return out
+
+
+def abstractify(tree):
+    """Concrete arrays -> ShapeDtypeStructs carrying the same shardings, so
+    `.lower()` never touches device data."""
+    import jax
+
+    def one(x):
+        if isinstance(x, jax.ShapeDtypeStruct) or x is None:
+            return x
+        sharding = getattr(x, "sharding", None)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+    return jax.tree.map(one, tree)
 
 
 def see_memory_usage(message: str, force: bool = False, device=None) -> Optional[str]:

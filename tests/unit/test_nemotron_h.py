@@ -29,7 +29,7 @@ sys.path.insert(0, ROOT)
 
 import deepspeed_tpu  # noqa: E402
 from benchmark.families import nemotron_h as fam  # noqa: E402
-from deepspeed_tpu.inference import RecurrentStateUnsupported  # noqa: E402
+from deepspeed_tpu.inference import SlotStateUnsupported  # noqa: E402
 from deepspeed_tpu.models import hybrid, make_model  # noqa: E402
 from deepspeed_tpu.models.hf_import import hf_config_to_transformer  # noqa: E402
 from deepspeed_tpu.moe import sharded_moe as sm  # noqa: E402
@@ -348,7 +348,7 @@ def test_serving_preemption_rebuilds_the_state(toy):
 ])
 def test_refused_at_init_serving(toy, serving, what):
     _, model, params, _ = toy
-    with pytest.raises(RecurrentStateUnsupported, match=what):
+    with pytest.raises(SlotStateUnsupported, match=what):
         _serve(model, params, **serving)
 
 
@@ -357,11 +357,11 @@ def test_refused_at_the_call(toy):
     srv = _serve(model, params)
     rid = srv.add_request(_ids(5), 40)
     srv.step()
-    with pytest.raises(RecurrentStateUnsupported, match="export"):
+    with pytest.raises(SlotStateUnsupported, match="export"):
         srv.export_kv([rid])
-    with pytest.raises(RecurrentStateUnsupported, match="import"):
+    with pytest.raises(SlotStateUnsupported, match="import"):
         srv.import_kv(rid, {})
-    with pytest.raises(RecurrentStateUnsupported, match="fork"):
+    with pytest.raises(SlotStateUnsupported, match="fork"):
         srv._dispatch_fork(srv.scheduler.running[0])
     assert model.decode_span_paged is None
     srv.close()

@@ -38,7 +38,7 @@ sys.path.insert(0, ROOT)
 
 import deepspeed_tpu  # noqa: E402
 from benchmark.families import qwen3_next as fam  # noqa: E402
-from deepspeed_tpu.inference import RecurrentStateUnsupported  # noqa: E402
+from deepspeed_tpu.inference import SlotStateUnsupported  # noqa: E402
 from deepspeed_tpu.models import TransformerConfig, hybrid, make_model  # noqa: E402
 from deepspeed_tpu.models.hf_import import hf_config_to_transformer  # noqa: E402
 from deepspeed_tpu.moe import sharded_moe as sm  # noqa: E402
@@ -357,7 +357,7 @@ def test_serving_preemption_rebuilds_the_state(toy):
 ])
 def test_refused_at_init_serving(toy, serving, what):
     _, model, params, _ = toy
-    with pytest.raises(RecurrentStateUnsupported, match=what):
+    with pytest.raises(SlotStateUnsupported, match=what):
         _serve(model, params, **serving)
 
 
@@ -366,11 +366,11 @@ def test_refused_at_the_call(toy):
     srv = _serve(model, params)
     rid = srv.add_request(_ids(5), 40)
     srv.step()
-    with pytest.raises(RecurrentStateUnsupported, match="export"):
+    with pytest.raises(SlotStateUnsupported, match="export"):
         srv.export_kv([rid])
-    with pytest.raises(RecurrentStateUnsupported, match="import"):
+    with pytest.raises(SlotStateUnsupported, match="import"):
         srv.import_kv(rid, {})
-    with pytest.raises(RecurrentStateUnsupported, match="fork"):
+    with pytest.raises(SlotStateUnsupported, match="fork"):
         srv._dispatch_fork(srv.scheduler.running[0])
     assert model.decode_span_paged is None
     srv.close()
@@ -378,7 +378,7 @@ def test_refused_at_the_call(toy):
 
 def test_a_tensor_parallel_pool_is_refused(toy):
     _, model, params, _ = toy
-    with pytest.raises(RecurrentStateUnsupported, match="tensor-parallel"):
+    with pytest.raises(SlotStateUnsupported, match="tensor-parallel"):
         deepspeed_tpu.init_serving(
             model, config={"kv_cache_bits": 0, "tensor_parallel": 2},
             dtype=jnp.float32,
