@@ -1288,6 +1288,133 @@ def afmoe_weight_names(cfg) -> Dict[str, tuple]:
     return out
 
 
+def _rope_table(kind: str, params: dict):
+    """One entry of a published ``rope_parameters`` group -> a ``RopeTable``
+    (``rope_type`` ``default`` or ``yarn``; ``attention_factor`` as
+    ``transformers`` defaults it, 0.1 ln(factor) + 1)."""
+    from deepspeed_tpu.models.transformer import RopeTable
+    rope_type = params.get("rope_type", "default")
+    theta = float(params.get("rope_theta", 10000.0))
+    if rope_type == "default":
+        return RopeTable(theta)
+    if rope_type != "yarn":
+        raise ValueError(f"mellum rope_parameters[{kind!r}]: rope_type "
+                         f"{rope_type!r} is not supported (default | yarn)")
+    factor = float(params["factor"])
+    af = params.get("attention_factor")
+    return RopeTable(
+        theta, factor, int(params["original_max_position_embeddings"]),
+        float(params.get("beta_fast") or 32), float(params.get("beta_slow") or 1),
+        float(af) if af is not None else 0.1 * math.log(factor) + 1.0)
+
+
+def _mellum_kwargs(get) -> dict:
+    """``mellum`` (JetBrains Mellum 2): every layer is an attention block then
+    an expert block of a hybrid stack, pre-norm (two RMSNorms a layer).
+    Attention — q/k RMSNorm per head, no gate, no bias — is ``W`` on a
+    ``sliding_attention`` layer (the last ``sliding_window`` positions) and
+    ``*`` on a ``full_attention`` one, rotary on BOTH with the table
+    ``rope_parameters`` states for the layer's type (plain on the sliding
+    layers, YaRN on the full ones); the feed-forward is ``E`` on every layer
+    (``mlp_layer_types`` all ``sparse``): a softmax router, the top-k weights
+    renormalised (``norm_topk_prob``), SwiGLU experts of
+    ``moe_intermediate_size``, no shared expert, no auxiliary loss (the
+    config keys no coefficient). ``intermediate_size`` (a dense layer's) and
+    ``max_window_layers`` are unread; dense layers, biases and other rope
+    types are refused: nothing here computes them.
+
+    THE CHIP'S SHARE: as ``qwen3_next`` — ``num_experts`` counts the experts
+    held, ``num_experts_router`` the router's width, ``expert_first`` the
+    first one held."""
+    L = get("num_hidden_layers")
+    for key, want in (("hidden_act", "silu"), ("attention_bias", False)):
+        if get(key, want) != want:
+            raise ValueError(f"mellum {key}={get(key)!r} is not supported "
+                             f"(the published config has {want!r})")
+    if set((get("mlp_layer_types") or ["sparse"])[:L]) != {"sparse"}:
+        raise ValueError("mellum mlp_layer_types: only sparse layers are "
+                         "supported (the published config has no other)")
+    kinds = list(get("layer_types") or [])[:L]
+    bad = sorted(set(kinds) - {"full_attention", "sliding_attention"})
+    if bad or len(kinds) != L:
+        raise ValueError(f"mellum layer_types: >= {L} entries of "
+                         f"full_attention | sliding_attention, got "
+                         f"{len(kinds)} with {bad}")
+    sw = get("sliding_window")
+    if "sliding_attention" in kinds and not (
+            sw and get("use_sliding_window", True)):
+        raise ValueError("mellum: sliding_attention layers need "
+                         "sliding_window and use_sliding_window")
+    rope = get("rope_parameters") or {}
+    missing = sorted(set(kinds) - set(rope))
+    if missing:
+        raise ValueError(f"mellum rope_parameters has no group for {missing}")
+    pattern = "".join(("W" if kind == "sliding_attention" else "*") + "E"
+                      for kind in kinds)
+    held = get("num_experts")
+    width, first = get("num_experts_router", held), get("expert_first", 0)
+    if not 0 <= first <= width - held:
+        raise ValueError(f"mellum: experts {first} .. {first + held - 1} "
+                         f"held of num_experts_router={width}")
+    H = get("hidden_size")
+    tables = {"attn": rope.get("full_attention"),
+              "wattn": rope.get("sliding_attention")}
+    return dict(
+        vocab_size=get("vocab_size"), hidden_size=H,
+        num_layers=len(pattern), block_pattern=pattern,
+        attn_windows=tuple(int(sw) if b == "W" else 0 for b in pattern),
+        num_heads=get("num_attention_heads"),
+        num_kv_heads=get("num_key_value_heads"),
+        head_dim=get("head_dim") or H // get("num_attention_heads"),
+        max_seq_len=get("max_position_embeddings", 8192),
+        norm_eps=float(get("rms_norm_eps", 1e-6)),
+        position_type="rotary", norm_type="rmsnorm", activation="silu_glu",
+        rope_tables=tuple((kind, _rope_table(kind, group))
+                          for kind, group in tables.items()
+                          if group is not None),
+        qk_norm_per_head=True,
+        tie_embeddings=bool(get("tie_word_embeddings", False)),
+        # `moe_intermediate_size` is ONE expert's width
+        intermediate_size=get("moe_intermediate_size"),
+        num_experts=held, top_k=get("num_experts_per_tok"),
+        moe_router_experts=width if width != held else None,
+        moe_held_first=first,
+        norm_topk_prob=bool(get("norm_topk_prob", True)),
+        drop_tokens=False, use_residual=False, moe_aux_loss_weight=0.0)
+
+
+def mellum_weight_names(cfg) -> Dict[str, tuple]:
+    """The tensors of an HF ``mellum`` checkpoint of ``cfg``'s shape, by the
+    names its lineage (Qwen3-MoE) registers them under -> where each lives in
+    the hybrid tree, as ``afmoe_weight_names`` gives them: ``(kind, block
+    index within its kind, leaf, expert or None)``. Every matrix is stored
+    transposed ([in, out]) but the experts' up projection (``moe_w_in_t``
+    keeps HF's [F, H]). ``load_hf_params`` does not read this table yet."""
+    from deepspeed_tpu.models import hybrid
+    out = {"model.embed_tokens.weight": (None, 0, "tok_embed", None),
+           "model.norm.weight": (None, 0, "final_norm_scale", None),
+           "lm_head.weight": (None, 0, "lm_head", None)}
+    blocks = hybrid.blocks(cfg)
+    for layer in range(len(blocks) // 2):
+        pre = f"model.layers.{layer}."
+        (akind, aj), (fkind, fj) = blocks[2 * layer], blocks[2 * layer + 1]
+        out[pre + "input_layernorm.weight"] = (akind, aj, "ln_scale", None)
+        for name, leaf in (("q_proj", "wq"), ("k_proj", "wk"),
+                           ("v_proj", "wv"), ("o_proj", "wo"),
+                           ("q_norm", "q_norm"), ("k_norm", "k_norm")):
+            out[pre + f"self_attn.{name}.weight"] = (akind, aj, leaf, None)
+        out[pre + "post_attention_layernorm.weight"] = (fkind, fj, "ln_scale",
+                                                        None)
+        out[pre + "mlp.gate.weight"] = (fkind, fj, "wg", None)
+        for name, stack in (("gate_proj", "moe_w_gate"),
+                            ("up_proj", "moe_w_in_t"),
+                            ("down_proj", "moe_w_out")):
+            for e in range(cfg.num_experts):
+                out[pre + f"mlp.experts.{cfg.moe_held_first + e}.{name}"
+                    ".weight"] = (fkind, fj, stack, e)
+    return out
+
+
 class EarlyExitUnsupported(NotImplementedError):
     """A looped model whose ``early_exit_threshold`` is below 1: a token
     would leave the stack at the first pass whose cumulative exit
@@ -1399,6 +1526,8 @@ def hf_config_to_transformer(hf_cfg, **overrides):
         kw = _qwen3_next_kwargs(get)
     elif mt == "afmoe":
         kw = _afmoe_kwargs(get)
+    elif mt == "mellum":
+        kw = _mellum_kwargs(get)
     elif mt == "opt":
         if get("word_embed_proj_dim", get("hidden_size")) != get("hidden_size"):
             raise ValueError(

@@ -10,10 +10,12 @@ the whole history, ``W`` attention over the last ``window(cfg)`` positions.
 Every block is ``h <- h + mixer(RMSNorm(h))`` — through a second RMSNorm
 after the mixer where the stack has one (``sandwich_norm``) —: the walker
 puts no feed-forward after attention and no attention before an expert layer,
-the pattern does. The rotary rule is per KIND: a ``*`` block carries no
-positional embedding (``position_type="none"``, ``nemotron_h``, ``afmoe``) or
-rotary over the first ``rotary_dim`` dims of a head (``"rotary"``), a ``W``
-block is always rotary; both take a per-head RMSNorm of q and k
+the pattern does. The rotary rule is per KIND (``rope_table``): a ``*`` block
+carries no positional embedding (``position_type="none"``, ``nemotron_h``,
+``afmoe``) or rotary over the first ``rotary_dim`` dims of a head
+(``"rotary"``), a ``W`` block is always rotary, and a config that states a
+table a kind (``rope_tables``: ``mellum``'s plain table on the ``W`` blocks,
+YaRN on the ``*`` blocks) has said it all; both take a per-head RMSNorm of q and k
 (``qk_norm_per_head``) and a sigmoid gate on their output, projected beside q
 (``attn_out_gate``), as the config says. ``embed_scale`` multiplies the
 embeddings. ``models/transformer.py``'s ``init_params``, ``logical_axes``,
@@ -253,7 +255,8 @@ def init_params(key, cfg):
             stacks["ln_scale"] = norm_scale((n, H))
         if cfg.sandwich_norm:
             stacks["post_ln_scale"] = norm_scale((n, H), cfg.post_norm_init)
-    params = {"tok_embed": normal((V, H)), "layers": layers,
+    params = {"tok_embed": normal((V, H), std * cfg.embed_init_scale),
+              "layers": layers,
               "final_norm_scale": norm_scale((H,))}
     if not cfg.tie_embeddings:
         params["lm_head"] = normal((H, V))
@@ -355,18 +358,29 @@ def _moe_mixer(p, h, cfg, train: bool = False, rng=None):
         return _moe.moe_ffn(moe_params, h, cfg, rng=rng, train=train)
 
 
-def _qkv(p, h, cfg, positions=None, rotary=None):
-    """h [B, T, H] -> (q [B, T, nq, hd], k, v [B, T, nkv, hd], gate [B, T,
-    nq hd] or None). What the config names is applied in HF's order: the
-    output gate's columns split off q (``attn_out_gate``: a head's columns
-    are [q | gate]), the per-head RMSNorm of q and k (``q_norm`` / ``k_norm``
-    [hd]), rotary at ``positions`` [B, T] over the first ``rotary_dim`` dims
-    (``rotary``; None: what ``position_type`` says, the rule of the "*"
-    blocks — a "W" block is always rotary)."""
+def rope_table(cfg, kind: str):
+    """The rotary table of an attention block of ``kind``, or None where it
+    carries no positional embedding: the table the config states for the kind
+    (``rope_tables``), else plain ``rope_theta`` — on every "wattn" block, and
+    on an "attn" block where ``position_type`` says rotary."""
+    from deepspeed_tpu.models.transformer import RopeTable
+    if cfg.rope_tables is not None:
+        return dict(cfg.rope_tables)[kind]
+    if kind == "wattn" or cfg.position_type == "rotary":
+        return RopeTable(cfg.rope_theta)
+    return None
+
+
+def _qkv(p, h, cfg, positions, kind: str):
+    """h [B, T, H] of a block of ``kind`` -> (q [B, T, nq, hd], k, v [B, T,
+    nkv, hd], gate [B, T, nq hd] or None). What the config names is applied
+    in HF's order: the output gate's columns split off q (``attn_out_gate``:
+    a head's columns are [q | gate]), the per-head RMSNorm of q and k
+    (``q_norm`` / ``k_norm`` [hd]), rotary at ``positions`` [B, T] over the
+    first ``rotary_dim`` dims by the kind's table (``rope_table``)."""
     from deepspeed_tpu.models.transformer import (_rms_whole, _wmat,
                                                   rotary_embed)
-    if rotary is None:
-        rotary = cfg.position_type == "rotary"
+    table = rope_table(cfg, kind)
     B, T, _ = h.shape
     hd, gate = cfg.dim_per_head, None
     q = _wmat(h, p["wq"])
@@ -379,11 +393,9 @@ def _qkv(p, h, cfg, positions=None, rotary=None):
     if "q_norm" in p:
         q = _rms_whole(q, p["q_norm"], cfg.norm_eps)
         k = _rms_whole(k, p["k_norm"], cfg.norm_eps)
-    if rotary:
-        q = rotary_embed(q, positions, cfg.rope_theta, cfg.rotary_dim,
-                         cfg.rotary_interleaved)
-        k = rotary_embed(k, positions, cfg.rope_theta, cfg.rotary_dim,
-                         cfg.rotary_interleaved)
+    if table is not None:
+        q, k = (rotary_embed(a, positions, table.theta, cfg.rotary_dim,
+                             cfg.rotary_interleaved, table) for a in (q, k))
     return q, k, v, gate
 
 
@@ -406,8 +418,7 @@ def _attn_mixer(p, h, cfg, kind: str = "attn"):
     B, T, _ = h.shape
     local = kind == "wattn"
     q, k, v, gate = _qkv(p, h, cfg,
-                         jnp.broadcast_to(jnp.arange(T)[None], (B, T)),
-                         rotary=True if local else None)
+                         jnp.broadcast_to(jnp.arange(T)[None], (B, T)), kind)
     if local:
         with jax.named_scope("attn"), jax.named_scope("window"):
             o = attention(q, k, v, causal=True, cfg=cfg, window=window(cfg))
@@ -547,23 +558,35 @@ def forward(params, input_ids, cfg, *, deterministic: bool = True,
         raise NotImplementedError(
             f"a hybrid (block_pattern) model's forward takes no {extra}")
 
+    from deepspeed_tpu.models.transformer import _remat_policy
+    remat = cfg.remat or cfg.remat_policy not in ("none", None)
+
     def block(i, kind, j, p, carry):
-        x, aux_total = carry
-        with jax.named_scope(f"layer{i}"):
-            h = _norm_in(p, x, cfg)
-            if kind == "mamba":
-                y = mamba.mixer_forward(p, h, cfg)
-            elif kind == "gdn":
-                y = gdn.mixer_forward(p, h, cfg)
-            elif kind == "moe":
-                y, aux = _moe_mixer(p, h, cfg, train=not deterministic,
-                                    rng=dropout_rng)
-                aux_total = aux_total + aux
-            elif kind == "dense":
-                y = _dense_mixer(p, h, cfg)
-            else:
-                y = _attn_mixer(p, h, cfg, kind)[0]
-            return (_residual(p, x, y, cfg), aux_total), None
+        def run(x, aux_total):
+            # the expert load leaves a rematerialised block as an output of
+            # it, as it leaves a scan body (`_walk`)
+            with jax.named_scope(f"layer{i}"), _moe.layer_load_tap() as tap:
+                h = _norm_in(p, x, cfg)
+                if kind == "mamba":
+                    y = mamba.mixer_forward(p, h, cfg)
+                elif kind == "gdn":
+                    y = gdn.mixer_forward(p, h, cfg)
+                elif kind == "moe":
+                    y, aux = _moe_mixer(p, h, cfg, train=not deterministic,
+                                        rng=dropout_rng)
+                    aux_total = aux_total + aux
+                elif kind == "dense":
+                    y = _dense_mixer(p, h, cfg)
+                else:
+                    y = _attn_mixer(p, h, cfg, kind)[0]
+                return (_residual(p, x, y, cfg), aux_total), (
+                    tap.stacked() if tap is not None else None)
+
+        if remat:       # the block's slices are closed over: residuals
+            run = jax.checkpoint(run, policy=_remat_policy(cfg))
+        carry, load = run(*carry)
+        _moe.record_expert_load(load)
+        return carry, None
 
     (x, aux_total), _ = _walk(
         params, cfg, (_embed(params, input_ids, cfg), jnp.float32(0.0)), block)
@@ -948,8 +971,7 @@ def decode_step_paged(params, tokens, cfg, pools, block_tables, seq_lens,
                 y = _dense_mixer(p, h, cfg)
             else:
                 local = kind == "wattn"
-                q, k, v, gate = _qkv(p, h, cfg, seq_lens[:, None],
-                                     rotary=True if local else None)
+                q, k, v, gate = _qkv(p, h, cfg, seq_lens[:, None], kind)
                 row_dtype = cfg.dtype if int8_kv else pools["k"].dtype
                 k_row = jnp.swapaxes(k, 1, 2).astype(row_dtype)
                 v_row = jnp.swapaxes(v, 1, 2).astype(row_dtype)

@@ -39,6 +39,45 @@ Params = Dict[str, Any]
 
 
 @dataclasses.dataclass(frozen=True)
+class RopeTable:
+    """One rotary table: the frequencies ``theta^(-2i/d)`` and, where
+    ``factor`` > 1, YaRN's stretch of them as ``transformers`` computes it
+    (``_compute_yarn_parameters``): pair i turns ``factor`` times slower from
+    pair ``hi`` on, keeps its frequency up to pair ``lo``, a linear ramp
+    between — ``lo`` / ``hi`` the pairs that make ``beta_fast`` /
+    ``beta_slow`` turns over ``original_max_position`` positions, rounded
+    outward — and cos and sin are BOTH multiplied by ``attention_factor``
+    (so q.k carries its square)."""
+    theta: float = 10000.0
+    factor: float = 1.0
+    original_max_position: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def band(self, dim: int):
+        """(lo, hi): the first and last pair of the ramp, of ``dim // 2``."""
+        def turns(r):
+            return (dim * math.log(self.original_max_position
+                                   / (r * 2 * math.pi))
+                    / (2 * math.log(self.theta)))
+        return (max(math.floor(turns(self.beta_fast)), 0),
+                min(math.ceil(turns(self.beta_slow)), dim - 1))
+
+    def frequencies(self, dim: int):
+        """float32 [dim // 2]."""
+        half = dim // 2
+        freqs = jnp.exp(-jnp.arange(0, half, dtype=jnp.float32)
+                        * (math.log(self.theta) / half))
+        if self.factor == 1.0:
+            return freqs
+        lo, hi = self.band(dim)
+        ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - lo)
+                        / max(hi - lo, 1e-3), 0.0, 1.0)
+        return freqs / self.factor * ramp + freqs * (1.0 - ramp)
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 50257
     hidden_size: int = 768
@@ -178,7 +217,12 @@ class TransformerConfig:
     # is a dense feed-forward of width `dense_ffn_size` (0: the experts'
     # `intermediate_size`). `embed_scale` multiplies the token embeddings
     # (afmoe's muP factor sqrt(hidden_size); 1: every other family).
+    # `rope_tables` (mellum) states a rotary table PER KIND of attention
+    # block, (("attn", RopeTable), ("wattn", RopeTable)): plain on the window
+    # blocks, YaRN on the blocks that see the whole history. None: the rule
+    # above with the one `rope_theta` (models/hybrid.py rope_table).
     block_pattern: Optional[str] = None
+    rope_tables: Optional[Tuple[Tuple[str, RopeTable], ...]] = None
     dense_ffn_size: int = 0
     embed_scale: float = 1.0
     gdn_num_k_heads: int = 0
@@ -217,6 +261,14 @@ class TransformerConfig:
     # (`sandwich_norm`), the branch scale of a deep residual stack.
     norm_init_jitter: float = 0.0
     post_norm_init: float = 1.0
+    # INITIALISER of the token embeddings (`init_params` only): drawn at
+    # 0.02 x this. At 1 the first layers' stream of a seeded model is mostly
+    # what near-uniform attention adds — nearly ONE vector for a thousand
+    # neighbouring positions, 1-3 x the embedding — and a random router
+    # behind it loads its experts unevenly (load cv 0.7 at 64 experts top-8,
+    # against 0.06 over the embeddings alone; PERF.md section 6, PR 48); a
+    # trained model's early stream is its tokens' and its router balanced.
+    embed_init_scale: float = 1.0
     remat: bool = False
     # none | dots_saveable | save_nothing | dots_and_attn (dots + the flash
     # kernel's named outputs: the backward reuses O/log-sum-exp instead of
@@ -453,7 +505,8 @@ def init_params(key, cfg: TransformerConfig) -> Params:
             layers["b_out"] = jnp.zeros((L, H), dt)
 
     params: Params = {
-        "tok_embed": normal(next(k), (cfg.vocab_size, H)),
+        "tok_embed": normal(next(k), (cfg.vocab_size, H),
+                            std * cfg.embed_init_scale),
         "layers": layers,
     }
     gk = (jax.random.split(next(k), 3)
@@ -636,11 +689,12 @@ def alibi_slopes(n_heads: int) -> jnp.ndarray:
 
 
 def rotary_embed(x, positions, theta: float, rotary_dim: Optional[int] = None,
-                 interleaved: bool = False):
+                 interleaved: bool = False, table: Optional[RopeTable] = None):
     """x: [B, S, N, D]. Default: rotate pairs (d, d + D/2) — llama
     convention. rotary_dim: rotate only the first `rotary_dim` dims (GPT-J/
     GPT-NeoX partial rotary). interleaved: pair (2d, 2d+1) instead — GPT-J's
-    rotate-every-two."""
+    rotate-every-two. table: its frequencies and attention factor in place
+    of plain ``theta``'s."""
     B, S, N, D = x.shape
     rd = rotary_dim if rotary_dim else D
     if rd % 2:
@@ -648,11 +702,13 @@ def rotary_embed(x, positions, theta: float, rotary_dim: Optional[int] = None,
                          "pairs dims)")
     x_rot, x_pass = x[..., :rd], x[..., rd:]
     half = rd // 2
-    freqs = jnp.exp(-jnp.arange(0, half, dtype=jnp.float32)
-                    * (math.log(theta) / half))
+    table = table or RopeTable(theta)
+    freqs = table.frequencies(rd)
     angles = positions.astype(jnp.float32)[:, :, None] * freqs[None, None, :]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if table.attention_factor != 1.0:
+        cos, sin = cos * table.attention_factor, sin * table.attention_factor
     if interleaved:
         x1 = x_rot[..., 0::2].astype(jnp.float32)
         x2 = x_rot[..., 1::2].astype(jnp.float32)
@@ -3192,6 +3248,11 @@ def _make_hybrid_model(cfg: TransformerConfig, name: str) -> ModelSpec:
 
 def make_model(cfg: TransformerConfig, name: str = "transformer") -> ModelSpec:
     _looped.check(cfg)
+    if cfg.rope_tables is not None and not cfg.block_pattern:
+        raise NotImplementedError(
+            "rope_tables states a rotary table per KIND of attention block "
+            "of a hybrid (block_pattern) stack; a homogeneous stack has the "
+            "one rope_theta")
     if cfg.block_pattern:
         return _make_hybrid_model(cfg, name)
     # the two-level suffix decode unrolls its layers: a looped model is

@@ -46,7 +46,11 @@ call's shapes, not by an option of their own:
   capacity shapes (drop/pad exactly like the reference's capacity
   semantics).
 - **dropless** (``drop_tokens=False``: Mixtral, OLMoE as published), a call
-  at inference on one device that ``_sorts``: the T·k (token, expert) pairs
+  on one device that ``_sorts``, at inference and in training alike (the
+  grouped matmul has a backward: ``ops/grouped_matmul.py``, d rows through
+  the same kernel, d experts through ``moe_gmm_dw``; the two permutations
+  move the rows of the experts HELD alone and are differentiated as each
+  other, ``_dispatch_rows`` / ``_combine_rows``): the T·k (token, expert) pairs
   are sorted by expert, each projection is ONE grouped matmul over the T·k
   rows (``_grouped_matmul``: static shapes — T·k rows and E group sizes),
   and the rows are weighted and summed back per token. Work and memory are
@@ -67,14 +71,15 @@ call's shapes, not by an option of their own:
   large matrices (Mixtral's 8: T rows per expert hide under the expert's
   weight bytes to 256 tokens, and the ``[E, T, H]`` rows are nothing beside
   them), a call inside the rule's tie (OLMoE's 32-slot step), and EVERY
-  dropless call under a mesh or in training: the capacity dispatch with
-  capacity = T, which drops nothing, needs no sort and no kernel, reads all
-  E experts, and carries the sharding constraints of the capacity path.
+  dropless call under a mesh: the capacity dispatch with capacity = T, which
+  drops nothing, needs no sort and no kernel, reads all E experts, and
+  carries the sharding constraints of the capacity path.
 
 ``expert_load_tap`` is how a serving step reads what routing did.
 """
 
 import contextlib
+import functools
 import math
 import threading
 from typing import List, Optional
@@ -372,8 +377,8 @@ def _grouped_matmul(rows, w, group_sizes, kernel: bool,
     ``transposed``: [E, N, K]), group_sizes [E] (sum <= M) -> [M, N]: row i
     times the matrix of the expert whose group it is in.
 
-    ``kernel`` (a TPU in bf16; ``_sorts`` has already kept a mesh and
-    training away): the Pallas kernel of ``ops/grouped_matmul.py``
+    ``kernel`` (a TPU in bf16; ``_sorts`` has already kept a mesh away): the
+    Pallas kernel of ``ops/grouped_matmul.py``
     (``%moe_gmm.N`` in a trace), which reads the layer's experts out of the
     whole stack. Elsewhere — the CPU, float32, widths off the kernel's
     tiles — XLA's own ``ragged_dot`` (``%ragged-dot-none.N`` on a TPU, a
@@ -442,6 +447,84 @@ def _one_hot_ffn(moe_params, tokens, logits, cfg, C: int, rng, train,
     return y, aux
 
 
+# the rows `_gather_live` / `_scatter_live` may move, in eighths of all the
+# (token, expert) pairs: the smallest that holds the live ones is taken
+_LIVE_CAPS = (3, 4, 6, 8)
+
+
+def _capped(n_live, rows: int, upto):
+    """``upto(cap)`` for the smallest cap of the ladder that holds ``n_live``
+    rows: one program a cap (``lax.switch``), every shape static. XLA's
+    gather and scatter move row by row (4.9 ms for 131 072 rows of 2304 on a
+    v5e, three times what streaming them takes: PERF.md section 6, PR 48),
+    and on a chip's share most (token, expert) pairs are another chip's."""
+    caps = sorted({-(-rows * c // 8) for c in _LIVE_CAPS})
+    which = sum((n_live > c).astype(jnp.int32) for c in caps[:-1])
+    return lax.switch(which, [functools.partial(upto, c) for c in caps])
+
+
+def _gather_live(x, index, n_live):
+    """``out[i] = x[index[i]]`` for the first ``n_live`` entries of ``index``
+    (to the cap that holds them), zeros behind."""
+    rows = index.shape[0]
+
+    def upto(cap):
+        return jnp.pad(jnp.take(x, index[:cap], axis=0),
+                       ((0, rows - cap), (0, 0)))
+
+    return _capped(n_live, rows, upto)
+
+
+def _scatter_live(x, index, n_live):
+    """``out[index[i]] = x[i]`` for ``i < n_live``, zeros elsewhere; ``index``
+    a permutation of x's rows (no two entries alike). The transpose of
+    ``_gather_live`` over the same entries."""
+    rows = index.shape[0]
+
+    def upto(cap):
+        at = jnp.where(jnp.arange(cap) < n_live, index[:cap], rows)
+        return jnp.zeros_like(x).at[at].set(x[:cap], mode="drop",
+                                            unique_indices=True)
+
+    return _capped(n_live, rows, upto)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch_rows(tokens, order, n_live, k: int):
+    """tokens [T, H] -> the sorted rows [T k, H]: row i is the token of pair
+    ``order[i]``, for the ``n_live`` pairs that sort first (the pairs of the
+    experts held). Its gradient puts each live row back at its pair and sums
+    a token's k pairs: the live rows alone both ways, no scatter-add."""
+    return _gather_live(tokens, order // k, n_live)
+
+
+def _dispatch_rows_fwd(tokens, order, n_live, k):
+    return _dispatch_rows(tokens, order, n_live, k), (order, n_live)
+
+
+def _dispatch_rows_bwd(k, residuals, g):
+    order, n_live = residuals
+    pairs = _scatter_live(g, order, n_live)
+    return (jnp.sum(pairs.reshape(-1, k, g.shape[-1]).astype(jnp.float32),
+                    axis=1).astype(g.dtype), None, None)
+
+
+_dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine_rows(rows, order, n_live):
+    """The sorted rows [T k, H] back in (token, choice) order: the live rows
+    at their pairs, ZEROS at the pairs of experts elsewhere."""
+    return _scatter_live(rows, order, n_live)
+
+
+_combine_rows.defvjp(
+    lambda rows, order, n_live: (_combine_rows(rows, order, n_live),
+                                 (order, n_live)),
+    lambda residuals, g: (_gather_live(g, *residuals), None, None))
+
+
 def _sorted_ffn(moe_params, tokens, logits, cfg, rng, train, held=None):
     """Dispatch by sorting the T*k (token, expert) pairs by expert: every
     token reaches all k of its experts, and the work is T*k rows. ``held`` =
@@ -466,8 +549,15 @@ def _sorted_ffn(moe_params, tokens, logits, cfg, rng, train, held=None):
         group_sizes = jnp.sum(onehot, axis=0)                     # [E]
         if _STATE.taps:
             _tap_load(jnp.sum(onehot.reshape(T, k, E), axis=1), k)
-        order = jnp.argsort(flat)                # stable: token order kept
-        rows_in = jnp.take(tokens, order // k, axis=0)            # [T*k, H]
+        with jax.named_scope("sort"):
+            order = jnp.argsort(flat)            # stable: token order kept
+        # training moves the live rows alone, by ops that are each other's
+        # gradient (`_dispatch_rows`); inference keeps the program it had
+        if train:
+            n_live = jnp.sum(group_sizes)
+            rows_in = _dispatch_rows(tokens, order, n_live, k)
+        else:
+            rows_in = jnp.take(tokens, order // k, axis=0)        # [T*k, H]
     with jax.named_scope("experts"):
         kernel = _use_gmm_kernel(moe_params, dt)
         w_in, transposed = _w_in(moe_params)
@@ -480,9 +570,12 @@ def _sorted_ffn(moe_params, tokens, logits, cfg, rng, train, held=None):
     with jax.named_scope("combine"):
         # back to (token, choice) order, then the weighted sum over a
         # token's k rows in float32
-        per_choice = jnp.take(rows_out, jnp.argsort(order), axis=0)
-        if held is not None:         # rows past the groups are undefined
-            per_choice = jnp.where(mine.reshape(T * k, 1), per_choice, 0)
+        if train:
+            per_choice = _combine_rows(rows_out, order, n_live)
+        else:
+            per_choice = jnp.take(rows_out, jnp.argsort(order), axis=0)
+            if held is not None:     # rows past the groups are undefined
+                per_choice = jnp.where(mine.reshape(T * k, 1), per_choice, 0)
         y = jnp.sum(per_choice.reshape(T, k, H).astype(jnp.float32)
                     * weights[..., None], axis=1).astype(dt)
     return y, aux
@@ -548,16 +641,17 @@ def _one_hot_is_cheaper(T: int, E: int, k: float, row_bytes: int,
             + (SORTED_FIXED_BYTES + TIE_BYTES) / expert_bytes)
 
 
-def _sorts(T: int, E: int, k: float, train: bool, row_bytes: int,
+def _sorts(T: int, E: int, k: float, row_bytes: int,
            expert_bytes: int) -> bool:
-    """Whether a dropless call of T tokens sorts. Only at inference on ONE
-    device, and only past ``_one_hot_is_cheaper``: the sorted dispatch sets
-    no sharding constraint and has never been compiled with the experts
-    sharded, and the one-hot einsums are what GSPMD places the all-to-alls
-    around (``_constrain(..., P(expert_axis))``), so under a mesh and in
-    training a dropless call keeps them, as before PR 26."""
+    """Whether a dropless call of T tokens sorts. Only on ONE device, and
+    only past ``_one_hot_is_cheaper`` — the same price at inference and in
+    training, whose backward is each form's forward over again: the sorted
+    dispatch sets no sharding constraint and has never been compiled with
+    the experts sharded, and the one-hot einsums are what GSPMD places the
+    all-to-alls around (``_constrain(..., P(expert_axis))``), so under a
+    mesh a dropless call keeps them, as before PR 26."""
     from deepspeed_tpu.parallel.context import kernel_mesh
-    return (not train and kernel_mesh()[0] is None
+    return (kernel_mesh()[0] is None
             and not _one_hot_is_cheaper(T, E, k, row_bytes, expert_bytes))
 
 
@@ -603,7 +697,7 @@ def moe_ffn(moe_params, x, cfg, *, rng=None, train: bool = True,
         y, aux = _one_hot_ffn(moe_params, tokens, logits, cfg, C, rng, train,
                                expert_axis, held)
     elif _sorts(T, E, cfg.top_k if held is None else cfg.top_k * E / R,
-                train, *_expert_shapes(moe_params)):
+                *_expert_shapes(moe_params)):
         form = ("sorted/moe_gmm" if _use_gmm_kernel(moe_params, tokens.dtype)
                 else "sorted/ragged_dot")
         y, aux = _sorted_ffn(moe_params, tokens, logits, cfg, rng, train, held)

@@ -266,8 +266,16 @@ def _band_first(i, window, bq, bk):
     return jax.lax.div(jnp.maximum(i * bq - (window - 1), 0), bk)
 
 
-def _band_kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *, sm_scale,
-                 window, rep, block_q, block_k):
+def _band_inside(q_lo, k_lo, window, block_q, block_k):
+    """Every (query, key) pair of the two tiles is visible: the tile's last
+    key at or before the first query, its first key inside the LAST query's
+    band: such a tile needs no mask."""
+    return ((k_lo + block_k - 1 <= q_lo)
+            & (q_lo + block_q - 1 - k_lo < window))
+
+
+def _band_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
+                 sm_scale, window, rep, block_q, block_k):
     """``_fwd_kernel`` over the key tiles of a query tile's band alone: step
     ``t`` of the innermost grid dim is key tile ``first + t``. A tile that
     lies wholly inside the band takes the body without a mask, one that the
@@ -311,10 +319,7 @@ def _band_kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *, sm_scale,
         m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
         l_s[:] = jnp.broadcast_to(l_new, l_s.shape)
 
-    # the tile's last key at or before the first query, and its first key
-    # inside the LAST query's band: every (query, key) pair is visible
-    inside = ((k_lo + block_k - 1 <= q_lo)
-              & (q_lo + block_q - 1 - k_lo < window))
+    inside = _band_inside(q_lo, k_lo, window, block_q, block_k)
     visible = k_lo <= q_lo + block_q - 1        # not past the diagonal
     pl.when(inside)(lambda: step(False))
     pl.when(visible & jnp.logical_not(inside))(lambda: step(True))
@@ -325,10 +330,20 @@ def _band_kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *, sm_scale,
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0, 0] = (acc_s[:] / l_safe).reshape(rep, block_q, d).astype(
             o_ref.dtype)
+        if lse_ref is not None:
+            lse_ref[0, 0] = (m_s[:, 0:1] + jnp.log(l_safe)).reshape(
+                rep, block_q, 1)
 
 
-def _fwd_band(q, k, v, sm_scale, window, block_q, block_k):
-    """q [B, N, S, D], k / v [B, Nkv, S, D] -> o, causal within the band."""
+def _band_kernel_no_lse(q_ref, k_ref, v_ref, o_ref, *scratch, **kw):
+    _band_kernel(q_ref, k_ref, v_ref, o_ref, None, *scratch, **kw)
+
+
+def _fwd_band(q, k, v, sm_scale, window, block_q, block_k,
+              with_lse: bool = False):
+    """q [B, N, S, D], k / v [B, Nkv, S, D] -> o, causal within the band;
+    ``with_lse``: (o, log-sum-exp [B, N, S, 1] float32), what the backward
+    kernels take the probabilities from."""
     B, N, S, D = q.shape
     Nkv = k.shape[1]
     rep = N // Nkv
@@ -343,13 +358,20 @@ def _fwd_band(q, k, v, sm_scale, window, block_q, block_k):
     q_spec = pl.BlockSpec((1, 1, rep, bq, D),
                           lambda b, g, i, t: (b, g, 0, i, 0),
                           memory_space=pltpu.VMEM)
-    o = pl.pallas_call(
-        functools.partial(_band_kernel, sm_scale=sm_scale, window=window,
+    o_shape = jax.ShapeDtypeStruct((B, Nkv, rep, S, D), q.dtype)
+    lse_spec = pl.BlockSpec((1, 1, rep, bq, 1),
+                            lambda b, g, i, t: (b, g, 0, i, 0),
+                            memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        functools.partial(_band_kernel if with_lse else _band_kernel_no_lse,
+                          sm_scale=sm_scale, window=window,
                           rep=rep, block_q=bq, block_k=bk),
         grid=(B, Nkv, S // bq, _band_tiles(window, bq, bk, S)),
         in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Nkv, rep, S, D), q.dtype),
+        out_specs=[q_spec, lse_spec] if with_lse else q_spec,
+        out_shape=[o_shape, jax.ShapeDtypeStruct((B, Nkv, rep, S, 1),
+                                                 jnp.float32)]
+        if with_lse else o_shape,
         scratch_shapes=[
             pltpu.VMEM((rows, 128), jnp.float32),   # m (lane-padded)
             pltpu.VMEM((rows, 128), jnp.float32),   # l
@@ -359,7 +381,9 @@ def _fwd_band(q, k, v, sm_scale, window, block_q, block_k):
         interpret=_interpret(),
         name="flash_fwd_band",
     )(q.reshape(B, Nkv, rep, S, D), k, v)
-    return o.reshape(B, N, S, D)
+    if with_lse:
+        return out[0].reshape(B, N, S, D), out[1].reshape(B, N, S, 1)
+    return out.reshape(B, N, S, D)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -368,19 +392,196 @@ def _flash_band(q, k, v, sm_scale, window, block_q, block_k):
 
 
 def _flash_band_fwd(q, k, v, sm_scale, window, block_q, block_k):
-    return _flash_band(q, k, v, sm_scale, window, block_q, block_k), (q, k, v)
+    o, lse = _fwd_band(q, k, v, sm_scale, window, block_q, block_k,
+                       with_lse=True)
+    # named as the causal kernel's are: the "dots_and_attn" remat policy
+    # keeps them across the forward / backward boundary (`_flash_fwd`)
+    from jax.ad_checkpoint import checkpoint_name
+    o = checkpoint_name(o, "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
+    return o, (q, k, v, o, lse)
+
+
+def _band_step(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q_lo, k_lo,
+               masked: bool, *, sm_scale, window, rep, block_q, block_k):
+    """What both banded backward kernels compute for one (query tile, key
+    tile) pair -> (q, k, do, p, ds): the probabilities from the forward's
+    log-sum-exp, under the band mask where the tile crosses an edge of it."""
+    d = q_ref.shape[-1]
+    rows = rep * block_q
+    q = q_ref[0, 0].astype(jnp.float32).reshape(rows, d)
+    do = do_ref[0, 0].astype(jnp.float32).reshape(rows, d)
+    lse = lse_ref[0, 0].reshape(rows, 1)
+    delta = delta_ref[0, 0].reshape(rows, 1)
+    k = k_ref[0, 0].astype(jnp.float32)
+    v = v_ref[0, 0].astype(jnp.float32)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * sm_scale
+    if masked:
+        q_pos = q_lo + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, block_k), 0) % block_q
+        k_pos = k_lo + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, block_k), 1)
+        s = jnp.where((q_pos >= k_pos) & (q_pos - k_pos < window), s, NEG_INF)
+    p = jnp.exp(s - lse)
+    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    return q, k, do, p, p * (dp - delta) * sm_scale
+
+
+def _band_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                    dq_s, *, window, rep, block_q, block_k, **kw):
+    """dQ of one query tile over the key tiles of its band alone: the
+    forward's walk (step ``t`` is key tile ``first + t``)."""
+    qi, t = pl.program_id(2), pl.program_id(3)
+    kj = _band_first(qi, window, block_q, block_k) + t
+    q_lo, k_lo = qi * block_q, kj * block_k
+
+    @pl.when(t == 0)
+    def _init():
+        dq_s[:] = jnp.zeros_like(dq_s)
+
+    def step(masked: bool):
+        _, k, _, _, ds = _band_step(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q_lo, k_lo,
+            masked, window=window, rep=rep, block_q=block_q, block_k=block_k,
+            **kw)
+        dq_s[:] = dq_s[:] + jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    inside = _band_inside(q_lo, k_lo, window, block_q, block_k)
+    visible = k_lo <= q_lo + block_q - 1        # not past the diagonal
+    pl.when(inside)(lambda: step(False))
+    pl.when(visible & jnp.logical_not(inside))(lambda: step(True))
+
+    @pl.when(t == pl.num_programs(3) - 1)
+    def _finalize():
+        dq_ref[0, 0] = dq_s[:].reshape(rep, block_q, -1).astype(dq_ref.dtype)
+
+
+def _band_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+                     dv_ref, dk_s, dv_s, *, window, rep, block_q, block_k,
+                     num_q, **kw):
+    """dK and dV of one key tile over the query tiles whose band reaches it
+    alone: step ``t`` is query tile ``first + t``, from the tile that holds
+    the key tile's first position (the diagonal) to the one that holds its
+    last position + window - 1."""
+    kj, t = pl.program_id(2), pl.program_id(3)
+    qi = jax.lax.div(kj * block_k, block_q) + t
+    q_lo, k_lo = qi * block_q, kj * block_k
+
+    @pl.when(t == 0)
+    def _init():
+        dk_s[:] = jnp.zeros_like(dk_s)
+        dv_s[:] = jnp.zeros_like(dv_s)
+
+    def step(masked: bool):
+        q, _, do, p, ds = _band_step(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q_lo, k_lo,
+            masked, window=window, rep=rep, block_q=block_q, block_k=block_k,
+            **kw)
+        dv_s[:] = dv_s[:] + jax.lax.dot_general(
+            p, do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_s[:] = dk_s[:] + jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    # a query tile past the sequence's end or wholly past the band of the
+    # tile's LAST key sees none of it
+    reached = (qi < num_q) & (q_lo - (k_lo + block_k - 1) < window)
+    inside = _band_inside(q_lo, k_lo, window, block_q, block_k)
+    pl.when(reached & inside)(lambda: step(False))
+    pl.when(reached & jnp.logical_not(inside))(lambda: step(True))
+
+    @pl.when(t == pl.num_programs(3) - 1)
+    def _finalize():
+        dk_ref[0, 0] = dk_s[:].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_s[:].astype(dv_ref.dtype)
+
+
+def _bwd_band_blocks(s: int, window: int, block_q: int, block_k: int,
+                     rep: int):
+    """Tiles of the banded backward: the forward's query tile, and key tiles
+    of at most a quarter of the window (128 at the least) — a tile the band's
+    edge crosses is computed whole and masked, and at the forward's 1024 keys
+    a window of 1024 would compute two tiles for 1.1 of band."""
+    return _pick_blocks(s, block_q, min(block_k, max(128, window // 4)), rep)
 
 
 def _flash_band_bwd(sm_scale, window, block_q, block_k, residuals, g):
-    """DEFERRED: no banded backward kernel. The gradient is taken through
-    the materialised scores under the band mask (``reference_attention``),
-    O(S^2) memory, which is what a windowed layer's whole attention cost
-    before the banded forward."""
-    def ref(q, k, v):           # [B, N, S, D] -> the same, via [B, S, N, D]
-        return jnp.swapaxes(reference_attention(
-            *(jnp.swapaxes(a, 1, 2) for a in (q, k, v)), causal=True,
-            sm_scale=sm_scale, window=window), 1, 2)
-    return jax.vjp(ref, *residuals)[1](g)
+    """Two banded kernels, each over the tiles the band touches alone:
+    ``flash_bwd_band_dq`` (a query tile against its key tiles, the forward's
+    walk) and ``flash_bwd_band_dkv`` (a key tile against the query tiles that
+    see it). The probabilities come from the forward's log-sum-exp; no
+    ``[heads, S, S]`` score exists."""
+    q, k, v, o, lse = residuals
+    B, N, S, D = q.shape
+    Nkv = k.shape[1]
+    rep = N // Nkv
+    bq, bk = _bwd_band_blocks(S, window, block_q, block_k, rep)
+    rows = rep * bq
+    kw = dict(sm_scale=sm_scale, window=window, rep=rep, block_q=bq,
+              block_k=bk)
+    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                    keepdims=True)
+    qg, dog = (a.reshape(B, Nkv, rep, S, D) for a in (q, g))
+    lseg, deltag = (a.reshape(B, Nkv, rep, S, 1) for a in (lse, delta))
+
+    # ---- dQ: (B, Nkv, query tiles, key tiles of the band) ----
+    def kv_index(b, g_, i, t):
+        last = jax.lax.div((i + 1) * bq - 1, bk)         # the diagonal's tile
+        return (b, g_, jnp.minimum(_band_first(i, window, bq, bk) + t, last),
+                0)
+
+    def q_index(b, g_, i, t):
+        return (b, g_, 0, i, 0)
+
+    kv_blk = pl.BlockSpec((1, 1, bk, D), kv_index, memory_space=pltpu.VMEM)
+    grp_blk = pl.BlockSpec((1, 1, rep, bq, D), q_index,
+                           memory_space=pltpu.VMEM)
+    grp_vec = pl.BlockSpec((1, 1, rep, bq, 1), q_index,
+                           memory_space=pltpu.VMEM)
+    dq = pl.pallas_call(
+        functools.partial(_band_dq_kernel, **kw),
+        grid=(B, Nkv, S // bq, _band_tiles(window, bq, bk, S)),
+        in_specs=[grp_blk, kv_blk, kv_blk, grp_blk, grp_vec, grp_vec],
+        out_specs=grp_blk,
+        out_shape=jax.ShapeDtypeStruct((B, Nkv, rep, S, D), q.dtype),
+        scratch_shapes=[pltpu.VMEM((rows, D), jnp.float32)],
+        compiler_params=_compiler_params(3),
+        interpret=_interpret(),
+        name="flash_bwd_band_dq",
+    )(qg, k, v, dog, lseg, deltag)
+
+    # ---- dK/dV: (B, Nkv, key tiles, query tiles that see the key tile) ----
+    num_q = S // bq
+
+    def q_of_k(b, g_, j, t):
+        # past the last query tile that sees the key tile: stay on it, so
+        # that the step's DMA is elided as its compute is skipped
+        last = jnp.minimum(jax.lax.div((j + 1) * bk + window - 2, bq),
+                           num_q - 1)
+        return (b, g_, 0, jnp.minimum(jax.lax.div(j * bk, bq) + t, last), 0)
+
+    grp_q = pl.BlockSpec((1, 1, rep, bq, D), q_of_k, memory_space=pltpu.VMEM)
+    grp_q_vec = pl.BlockSpec((1, 1, rep, bq, 1), q_of_k,
+                             memory_space=pltpu.VMEM)
+    kv_out = pl.BlockSpec((1, 1, bk, D), lambda b, g_, j, t: (b, g_, j, 0),
+                          memory_space=pltpu.VMEM)
+    dk, dv = pl.pallas_call(
+        functools.partial(_band_dkv_kernel, num_q=num_q, **kw),
+        grid=(B, Nkv, S // bk, min(num_q, (bk + window - 2) // bq + 2)),
+        in_specs=[grp_q, kv_out, kv_out, grp_q, grp_q_vec, grp_q_vec],
+        out_specs=[kv_out, kv_out],
+        out_shape=[jax.ShapeDtypeStruct((B, Nkv, S, D), q.dtype)] * 2,
+        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32)] * 2,
+        compiler_params=_compiler_params(3),
+        interpret=_interpret(),
+        name="flash_bwd_band_dkv",
+    )(qg, k, v, dog, lseg, deltag)
+    return dq.reshape(B, N, S, D), dk, dv
 
 
 _flash_band.defvjp(_flash_band_fwd, _flash_band_bwd)
@@ -656,8 +857,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
 
     window: a static length W — key j is visible to query i iff 0 <= i - j <
     W (causal within a band). The forward visits only the key tiles a query
-    tile's band touches (``flash_fwd_band``); the backward has no banded
-    kernel and goes through materialised scores. Causal, no ``kv_mask``.
+    tile's band touches (``flash_fwd_band``), and so do the two backward
+    kernels (``flash_bwd_band_dq``, ``flash_bwd_band_dkv``). Causal, no
+    ``kv_mask``.
 
     kv_mask: optional [B, S] bool/int padding mask over keys — masked
     positions are excluded inside the kernel (no O(S^2) fallback).

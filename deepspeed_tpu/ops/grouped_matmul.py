@@ -184,17 +184,9 @@ def _kernel(layer_ref, offsets_ref, expert_ref, tile_ref, lhs_ref, rhs_ref,
                                  ).astype(out_ref.dtype)
 
 
-def grouped_matmul(rows, stack, layer, group_sizes, transposed: bool = False):
-    """rows [M, K] sorted by expert, stack [L, E, K, N], layer (int32
-    scalar, may be traced), group_sizes [E] int32 with sum <= M -> [M, N] in
-    ``rows.dtype``; rows past the groups' sum come back undefined.
-
-    ``transposed``: the stack holds each matrix as ``[N, K]`` (``[L, E, N,
-    K]``). An operand of a Mosaic call is read in row-major order, and the
-    TPU stores an array whose last extent is off the 128 grid (an expert
-    width of 1856) with that extent second-to-last, so a ``[.., 2688, 1856]``
-    stack handed to the kernel is first copied WHOLE into row-major order;
-    stored ``[.., 1856, 2688]`` it is read in place."""
+def _gmm_call(rows, stack, layer, group_sizes, transposed: bool):
+    """The forward kernel: rows [M, K] x the layer's experts out of the whole
+    stack -> [M, N]; ``layer`` int32 [1]."""
     M, K = rows.shape
     L, E, K2, N = stack.shape
     if transposed:
@@ -232,6 +224,149 @@ def grouped_matmul(rows, stack, layer, group_sizes, transposed: bool = False):
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=_interpret(),
         name="moe_gmm",
-    )(jnp.asarray(layer, jnp.int32).reshape(1), offsets, expert, tile,
-      rows, stack)
+    )(layer, offsets, expert, tile, rows, stack)
     return out[:M] if pad else out
+
+
+# ---- the backward: d rows is the forward kernel with the matrices read the
+# other way round; d stack is `moe_gmm_dw` ------------------------------------
+
+# the weight-gradient kernel's output block (contraction of the forward x
+# its columns): 1024 x 1024 float32 = 4 MiB of accumulator, the block itself
+# double-buffered beside it
+DW_TK, DW_TN = 1024, 1024
+# its row tile: the rows are the CONTRACTION here, and a visit's multiply
+# (tm x tk x tn) has to hide the read of its two row tiles and a grid step
+DW_TM = 512
+
+
+def _dw_kernel(offsets_ref, expert_ref, tile_ref, nv_ref, lhs_ref, rhs_ref,
+               zeros_ref, out_ref, acc_ref, *, tm: int, max_visits: int):
+    """One visit: ``lhs_tile^T @ rhs_tile`` over the rows of the tile that
+    belong to the visit's expert, added to the expert's ``[tk, tn]`` block.
+    Visits of one expert are consecutive, so its block is zeroed at its first
+    visit and stored at its last; an expert with no row is never visited and
+    keeps the zeros the output starts from (``zeros_ref`` is that buffer,
+    aliased to the output)."""
+    del zeros_ref
+    v = pl.program_id(2)
+    e = expert_ref[v]
+    first = (v == 0) | (expert_ref[jnp.maximum(v - 1, 0)] != e)
+    last = (v == nv_ref[0] - 1) \
+        | (expert_ref[jnp.minimum(v + 1, max_visits - 1)] != e)
+
+    @pl.when(first)
+    def _zero():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    row = tile_ref[v] * tm + lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+    mine = (row >= offsets_ref[e]) & (row < offsets_ref[e + 1])
+    # BOTH tiles are masked by selection: a row past the groups is whatever
+    # the forward left there, and 0 x NaN is NaN
+    lhs = jnp.where(mine, lhs_ref[...], jnp.zeros_like(lhs_ref))
+    rhs = jnp.where(mine, rhs_ref[...], jnp.zeros_like(rhs_ref))
+    acc_ref[...] += lax.dot_general(lhs, rhs, (((0,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+
+    @pl.when(last)
+    def _store():
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+def grouped_matmul_dw(lhs, rhs, group_sizes, out_dtype=None):
+    """lhs [M, K], rhs [M, N], both sorted by expert as ``group_sizes`` [E]
+    says -> [E, K, N]: per expert ``lhs_e^T @ rhs_e`` over its rows,
+    accumulated in float32; zeros for an expert with no row; rows past the
+    groups' sum are never read. The gradient of ONE layer's experts (``rows^T
+    @ dy``; for matrices stored transposed, ``dy^T @ rows``)."""
+    M, K = lhs.shape
+    N = rhs.shape[1]
+    E = group_sizes.shape[0]
+    out_dtype = out_dtype or lhs.dtype
+    tk, tn = _tile(K, DW_TK), _tile(N, DW_TN)
+    assert rhs.shape[0] == M and tk and tn, (lhs.shape, rhs.shape)
+    tm = min(DW_TM, -(-M // 128) * 128)
+    pad = -M % tm
+    if pad:
+        lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+        rhs = jnp.pad(rhs, ((0, pad), (0, 0)))
+    tiles_m = (M + pad) // tm
+    offsets, expert, tile, num_visits = visits(group_sizes, tm, tiles_m)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,          # (offsets, expert, tile, num_visits)
+        grid=(K // tk, N // tn, num_visits),
+        in_specs=[
+            pl.BlockSpec((tm, tk), lambda k, n, v, off, ex, tl, nv: (tl[v], k)),
+            pl.BlockSpec((tm, tn), lambda k, n, v, off, ex, tl, nv: (tl[v], n)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((None, tk, tn),
+                               lambda k, n, v, off, ex, tl, nv: (ex[v], k, n)),
+        scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_dw_kernel, tm=tm, max_visits=tiles_m + E - 1),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((E, K, N), out_dtype),
+        input_output_aliases={6: 0},    # the zeros the output starts from
+        compiler_params=None if _interpret() else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=_interpret(),
+        name="moe_gmm_dw",
+    )(offsets, expert, tile, num_visits.reshape(1), lhs, rhs,
+      jnp.zeros((E, K, N), out_dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _gmm(rows, w, stack, layer, group_sizes, transposed):
+    """``w`` is ``stack[layer]``, the operand the gradient is taken by: the
+    forward reads the layer's experts out of the whole ``stack`` in place and
+    never touches ``w`` (XLA drops the slice), the backward hands ``w`` an
+    ``[E, K, N]`` gradient — not the whole stack's shape once a layer."""
+    del w
+    return _gmm_call(rows, stack, layer, group_sizes, transposed)
+
+
+def _gmm_fwd(rows, w, stack, layer, group_sizes, transposed):
+    del w
+    return (_gmm_call(rows, stack, layer, group_sizes, transposed),
+            (rows, stack, layer, group_sizes))
+
+
+def _gmm_bwd(transposed, residuals, dy):
+    rows, stack, layer, group_sizes = residuals
+    # d rows: dy through the same kernel, each matrix read the other way
+    # round; a row past the groups' sum comes back undefined and its
+    # gradient is none
+    d_rows = _gmm_call(dy, stack, layer, group_sizes, not transposed)
+    live = jnp.arange(rows.shape[0])[:, None] < jnp.sum(group_sizes)
+    d_rows = jnp.where(live, d_rows, jnp.zeros_like(d_rows))
+    d_w = (grouped_matmul_dw(dy, rows, group_sizes, stack.dtype) if transposed
+           else grouped_matmul_dw(rows, dy, group_sizes, stack.dtype))
+    return d_rows, d_w, None, None, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def grouped_matmul(rows, stack, layer, group_sizes, transposed: bool = False):
+    """rows [M, K] sorted by expert, stack [L, E, K, N], layer (int32
+    scalar, may be traced), group_sizes [E] int32 with sum <= M -> [M, N] in
+    ``rows.dtype``; rows past the groups' sum come back undefined.
+
+    ``transposed``: the stack holds each matrix as ``[N, K]`` (``[L, E, N,
+    K]``). An operand of a Mosaic call is read in row-major order, and the
+    TPU stores an array whose last extent is off the 128 grid (an expert
+    width of 1856) with that extent second-to-last, so a ``[.., 2688, 1856]``
+    stack handed to the kernel is first copied WHOLE into row-major order;
+    stored ``[.., 1856, 2688]`` it is read in place.
+
+    Differentiable in ``rows`` and ``stack`` (``_gmm``): d rows is this
+    kernel with ``transposed`` flipped, d stack the ``moe_gmm_dw`` kernel's
+    ``[E, K, N]`` block of the layer, which JAX places in the stack's
+    gradient as the transpose of the slice."""
+    w = stack[layer] if isinstance(layer, int) else lax.dynamic_index_in_dim(
+        stack, layer, 0, keepdims=False)
+    return _gmm(rows, w, lax.stop_gradient(stack),
+                jnp.asarray(layer, jnp.int32).reshape(1), group_sizes,
+                transposed)

@@ -1072,17 +1072,34 @@ class Engine:
                    if a != "data"):
                 deferred_unroll = max(unroll, gas)
 
-        def micro_grads(params, mb, rng, scale, step=None, specs="grad"):
+        moe_model = getattr(getattr(model, "config", None),
+                            "num_experts", 1) > 1
+
+        def micro_grads(params, mb, rng, scale, step=None, specs="grad",
+                        load=None):
+            """``load``: a list that takes the expert layers' load rows of
+            this microbatch ([layers, E + 1], ``moe.sharded_moe._LoadTap``),
+            where the caller's trace is the one this runs in."""
+            from deepspeed_tpu.moe.sharded_moe import expert_load_tap
+
             def loss_fn(p):
                 if compression is not None:
                     p = compression.apply(p, step if step is not None else 0)
                 if moq is not None and "_moq_bits" in mb:
                     p = moq.apply(p, mb["_moq_bits"])
-                loss = model.loss_fn(p, mb, rng, False)
+                if load is None:
+                    loss, rows = model.loss_fn(p, mb, rng, False), None
+                else:
+                    with expert_load_tap() as tap:
+                        loss = model.loss_fn(p, mb, rng, False)
+                    rows = tap.stacked()
                 if fp16:
                     loss = loss * scale.astype(loss.dtype)
-                return loss
-            loss, grads = jax.value_and_grad(loss_fn)(params)
+                return loss, rows
+            (loss, rows), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params)
+            if rows is not None:
+                load.append(rows)
             grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
             if specs == "grad":
                 specs = self.grad_specs
@@ -1152,6 +1169,24 @@ class Engine:
                 metrics["loss_scale"] = state["loss_scale"]["scale"]
             return new_state, metrics
 
+        def moe_load_metrics(rows):
+            """What the expert layers did this step, from their load rows
+            ([layers, E + 1]: rows kept per expert HELD, then the assignments
+            the router made), as means over the layers: the rows the held
+            experts multiplied, the fullest expert's over the mean, and the
+            rows that found no place — counted where every expert is held
+            (elsewhere an assignment not kept may be another chip's; a
+            dropless model's are 0 by construction)."""
+            kept = rows[:, :-1].astype(jnp.float32)
+            held = jnp.sum(kept, axis=1)
+            cfg_m = model.config
+            whole = cfg_m.moe_router_width == cfg_m.num_experts
+            dropped = (jnp.mean(rows[:, -1].astype(jnp.float32) - held)
+                       if whole and cfg_m.drop_tokens else jnp.float32(0.0))
+            fullest = jnp.mean(jnp.max(kept, axis=1)
+                               / jnp.maximum(jnp.mean(kept, axis=1), 1.0))
+            return dict(zip(_MOE_METRICS, (jnp.mean(held), fullest, dropped)))
+
         def deferred_batch_grads(params, batch, rng, scale, step):
             """Deferred sync: grad accumulation runs manual over `data`
             (everything else stays auto/GSPMD). Each device accumulates the
@@ -1187,9 +1222,10 @@ class Engine:
             grads = jax.lax.with_sharding_constraint(grads, self.grad_specs)
             return grads, mean_loss
 
-        def batch_grads(state, batch, rng):
+        def batch_grads(state, batch, rng, load=None):
             """Averaged grads + mean loss over `gas` microbatches.
-            batch leaves: [global_batch, ...], sharded over (data, fsdp)."""
+            batch leaves: [global_batch, ...], sharded over (data, fsdp).
+            ``load``: ``micro_grads``'s."""
             params = state["params"]
             scale = state["loss_scale"]["scale"] if fp16 else jnp.float32(1.0)
             if deferred:
@@ -1198,7 +1234,8 @@ class Engine:
             else:
                 grads, mean_loss = self._accum_micro_grads(
                     lambda p, mb, r: micro_grads(p, mb, r, scale,
-                                                 step=state["step"]),
+                                                 step=state["step"],
+                                                 load=load),
                     params, batch, gas, rng,
                     postprocess=lambda t: jax.lax.with_sharding_constraint(
                         t, self.grad_specs),
@@ -1212,10 +1249,17 @@ class Engine:
             scopes land in the compiled program's op_name metadata — the
             perf doctor's trace join reads them to split device time into
             grad-compute vs optimizer phases."""
+            # the expert load rides out of the ONE microbatch that runs in
+            # this trace; a scan over several, or the deferred region, keeps
+            # its rows to itself
+            load = [] if moe_model and gas == 1 and not deferred else None
             with jax.named_scope("grads"):
-                mean_loss, grads = batch_grads(state, batch, rng)
+                mean_loss, grads = batch_grads(state, batch, rng, load)
             with jax.named_scope("optimizer"):
-                return apply_grads(state, grads, mean_loss)
+                new_state, metrics = apply_grads(state, grads, mean_loss)
+            if load:
+                metrics.update(moe_load_metrics(load[0]))
+            return new_state, metrics
 
         # raw (unjitted) step for the fused K-step program; recompiles
         # (Random-LTD/act-quant rebuilds) invalidate any cached fusions
@@ -1956,8 +2000,8 @@ class Engine:
         # the ONE steady-state sync point of the hot loop: every logged
         # metric AND the telemetry accumulator leaf come back in a single
         # device_get instead of one blocking float() per metric
-        extra = {k: metrics[k] for k in ("loss", "grad_norm", "loss_scale")
-                 if k in metrics}
+        extra = {k: metrics[k] for k in ("loss", "grad_norm", "loss_scale",
+                                         *_MOE_METRICS) if k in metrics}
         need_skipped = (self._schedule is not None
                         and isinstance(self.state, dict)
                         and "skipped" in self.state)
@@ -1989,6 +2033,8 @@ class Engine:
         if "loss_scale" in vals:
             events.append(("Train/loss_scale", vals["loss_scale"],
                            self.global_steps))
+        events += [(f"Train/{k}", vals[k], self.global_steps)
+                   for k in _MOE_METRICS if k in vals]
         records = []
         if self._tel_cfg is not None and tel_cur is not None:
             tel_events, records = self._drain_telemetry(tel_cur)
@@ -2631,6 +2677,10 @@ def _manual_batch_specs(batch):
                 for k, v in batch.items()}
     return jax.tree.map(
         lambda x: P("data") if getattr(x, "ndim", 0) >= 1 else P(), batch)
+
+
+# what a step over expert layers reports beside its loss (`train_step`)
+_MOE_METRICS = ("moe_held_rows", "moe_load_max_over_mean", "moe_dropped_rows")
 
 
 def _is_side_channel(key) -> bool:

@@ -403,8 +403,8 @@ def test_a_served_step_that_sorts_counts_what_the_one_hot_step_counts(
     sorting = _sorting(monkeypatch, cfg)
     # the engine of this suite spans the 8 virtual devices, and under a mesh
     # `_sorts` keeps the masks: the rule alone here, as on one chip
-    monkeypatch.setattr(sm, "_sorts", lambda T, E, k, train, *nbytes: (
-        not train and not sm._one_hot_is_cheaper(T, E, k, *nbytes)))
+    monkeypatch.setattr(sm, "_sorts", lambda T, E, k, *nbytes: (
+        not sm._one_hot_is_cheaper(T, E, k, *nbytes)))
     outs_s, st_s = served(sorting)
     assert outs_s == outs
     assert st["moe_dispatch"]["step"] == "one-hot"

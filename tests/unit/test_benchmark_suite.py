@@ -8,19 +8,21 @@ could break the instrument and stay green. This module runs that suite ONCE,
 in a process of its own (its modules put ``benchmark/tests`` on ``sys.path``
 and two of them define a fixture of the same name, so they are not imported
 into this one), and reports every one of its tests as a case here: the count
-of passes moves with the suite's.
+of passes moves with the suite's. A cell's CPU rehearsal that failed in that
+run is run once more, alone (``_REHEARSAL``), before it is reported.
 """
 import os
 import subprocess
 import sys
+import time
 import xml.etree.ElementTree as ET
 
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 SUITE = os.path.join("benchmark", "tests")
-_PYTEST = [sys.executable, "-m", "pytest", SUITE, "-q", "-p", "no:cacheprovider",
-           "-p", "no:xdist", "-p", "no:randomly"]
+_OPTIONS = ["-q", "-p", "no:cacheprovider", "-p", "no:xdist", "-p", "no:randomly"]
+_PYTEST = [sys.executable, "-m", "pytest", SUITE] + _OPTIONS
 _ENV = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
 _ENV["JAX_PLATFORMS"] = "cpu"
 
@@ -39,11 +41,10 @@ def _node_ids():
 NODE_IDS = _node_ids()
 
 
-@pytest.fixture(scope="module")
-def outcomes(tmp_path_factory):
-    """One run of the whole suite -> {test id: (outcome, detail)}."""
-    xml = tmp_path_factory.mktemp("benchmark_suite") / "junit.xml"
-    p = subprocess.run(_PYTEST + [f"--junitxml={xml}", "-o", "junit_family=xunit1"],
+def _run(command, xml):
+    """One pytest process -> ({test id: (outcome, detail)}, the end of its
+    output)."""
+    p = subprocess.run(command + [f"--junitxml={xml}", "-o", "junit_family=xunit1"],
                        cwd=ROOT, env=_ENV, capture_output=True, text=True, timeout=1200)
     out = {}
     for case in ET.parse(xml).getroot().iter("testcase"):
@@ -52,7 +53,52 @@ def outcomes(tmp_path_factory):
         out[f"{path}::{case.get('name')}"] = (
             bad[0].tag if bad else "passed",
             (bad[0].get("message") or "") + "\n" + (bad[0].text or "") if bad else "")
-    out["__log__"] = ("", p.stdout[-3000:])
+    return out, p.stdout[-3000:]
+
+
+# a cell's CPU rehearsal (`test_the_cell_rehearses_on_the_cpu` of each family)
+# is a process with a window on the HOST's clock in which a request has to
+# finish or a step to run: beside tier-1's five other workers a toy engine can
+# starve inside it (PERF.md section 7, PR 40 and PR 48: the same case passes
+# alone, in a fifth of the time). It is the load that fails, not the
+# arithmetic — so a rehearsal that failed in the suite's run is run ONCE more,
+# alone and once the machine has gone quiet (the other workers' files end
+# before this one's: the suite is tier-1's longest case), and that outcome is
+# the one reported; any other failure stands. Seen in a whole tier-1 run of
+# PR 48's tree: the retry made at once, beside the workers still busy, failed
+# as the first had (no request finished in 75 s); alone it passes in 131 s.
+_REHEARSAL = "rehears"
+_QUIET_WAIT_S, _RETRY_BUDGET_S = 120.0, 450.0
+
+
+def _wait_for_a_quiet_machine():
+    """Until the 1-minute load is under half the cores, at most
+    ``_QUIET_WAIT_S``."""
+    end = time.monotonic() + _QUIET_WAIT_S
+    while os.getloadavg()[0] > (os.cpu_count() or 2) / 2 and time.monotonic() < end:
+        time.sleep(5.0)
+
+
+@pytest.fixture(scope="module")
+def outcomes(tmp_path_factory):
+    """One run of the whole suite -> {test id: (outcome, detail)}; the
+    rehearsals that failed in it, once more and alone."""
+    tmp = tmp_path_factory.mktemp("benchmark_suite")
+    out, log = _run(_PYTEST, tmp / "junit.xml")
+    again = [n for n, (outcome, _) in out.items()
+             if outcome in ("failure", "error") and _REHEARSAL in n]
+    started = time.monotonic()
+    for i, node_id in enumerate(again):
+        if time.monotonic() - started > _RETRY_BUDGET_S:
+            break           # tier-1 has a time limit of its own
+        _wait_for_a_quiet_machine()
+        alone, _ = _run([sys.executable, "-m", "pytest", node_id] + _OPTIONS,
+                        tmp / f"alone{i}.xml")
+        if node_id in alone:
+            first = out[node_id][1][-1500:]
+            out[node_id] = (alone[node_id][0], alone[node_id][1]
+                            + "\n---- in the suite's run it had failed with:\n" + first)
+    out["__log__"] = ("", log)
     return out
 
 

@@ -1,6 +1,7 @@
 """The banded flash forward (``flash_attention(..., window=W)``, kernel
-``flash_fwd_band``) in interpret mode against ``reference_attention`` under a
-band mask, over every relation of (tile, window, length): window below, at
+``flash_fwd_band``) and its two backward kernels (``flash_bwd_band_dq``,
+``flash_bwd_band_dkv``) in interpret mode against ``reference_attention``
+under a band mask, and against its gradient, over every relation of (tile, window, length): window below, at
 and above a tile; window off the tiles; length below, at and above the window;
 length off the default tiles; the published 6 : 1 grouping. The kernel skips
 key tiles, so a wrong tile count or first tile shows as a wrong row, not as
@@ -10,6 +11,7 @@ readings are 6e-7).
 ``window=None`` is the program it was: the lowered text of the forward and of
 its gradient are pinned to what the parent commit lowers (PR 43's tree)."""
 import hashlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -77,15 +79,59 @@ def test_band_visits_only_the_bands_tiles():
     assert fa._band_tiles(1, 8, 8, 64) == 2
 
 
-def test_band_differentiates_through_the_masked_scores():
-    """No banded backward kernel: the gradient is the masked reference's."""
-    q, k, v = _qkv(32, seed=5)
-    got = jax.grad(lambda *a: fa.flash_attention(
-        *a, window=10, block_q=8, block_k=8).sum(), argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: fa.reference_attention(*a, window=10).sum(),
-                    argnums=(0, 1, 2))(q, k, v)
+def _grads(fn, q, k, v, seed=9):
+    """d (q, k, v) of ``sum(fn(q, k, v) * c)`` for a seeded cotangent ``c``
+    (a plain ``.sum()`` makes dP = 0 wherever V's rows are alike)."""
+    c = jax.random.normal(jax.random.PRNGKey(seed), q.shape)
+    return jax.grad(lambda *a: (fn(*a) * c).sum(), argnums=(0, 1, 2))(q, k, v)
+
+
+# the banded backward (``flash_bwd_band_dq`` / ``flash_bwd_band_dkv``) skips
+# tiles as the forward does, on BOTH walks, so every relation of the grid is
+# a case: float32 rounding of three contractions over <= 128 positions
+BWD_TOL = 2e-5
+
+
+@pytest.mark.parametrize("S, W, bq, bk", GRID)
+def test_band_backward_matches_the_masked_references_gradient(S, W, bq, bk):
+    q, k, v = _qkv(S, seed=5)
+    got = _grads(lambda *a: fa.flash_attention(
+        *a, window=W, block_q=bq, block_k=bk), q, k, v)
+    want = _grads(lambda *a: fa.reference_attention(*a, window=W), q, k, v)
     for g, w in zip(got, want):
-        assert float(jnp.abs(g - w).max()) < 1e-5
+        assert float(jnp.abs(g - w).max()) < BWD_TOL
+    if W < S:       # ... and not the causal triangle's gradient
+        plain = _grads(fa.reference_attention, q, k, v)
+        assert float(jnp.abs(got[1] - plain[1]).max()) > 1e-3
+
+
+@pytest.mark.parametrize("nq, nkv, B", [(6, 1, 1), (12, 2, 2), (4, 4, 1)])
+def test_band_backward_groups_and_batches(nq, nkv, B):
+    q, k, v = _qkv(64, nq, nkv, B=B, seed=3)
+    got = _grads(lambda *a: fa.flash_attention(
+        *a, window=20, block_q=16, block_k=16), q, k, v)
+    want = _grads(lambda *a: fa.reference_attention(*a, window=20), q, k, v)
+    for g, w in zip(got, want):
+        assert float(jnp.abs(g - w).max()) < BWD_TOL
+
+
+def test_band_backward_visits_only_the_bands_tiles():
+    """Both backward grids end at the band: at S = 8192, window 1024 and the
+    published 8 : 1 grouping (query tile 128) the key tile falls to a quarter
+    of the window, a query tile walks 6 key tiles of 256 and a key tile 11
+    query tiles of 128, whatever S is; and no program of the gradient holds a
+    ``[heads, S, S]`` score."""
+    bq, bk = fa._bwd_band_blocks(8192, 1024, 512, 1024, rep=8)
+    assert (bq, bk) == (128, 256)
+    assert fa._band_tiles(1024, bq, bk, 8192) == 6
+    assert fa._band_tiles(1024, bq, bk, 65536) == 6
+    q, k, v = _qkv(352, 4, 1, seed=1)
+    text = jax.jit(lambda *a: _grads(lambda *b: fa.flash_attention(
+        *b, window=32, block_q=32, block_k=32), *a)).lower(q, k, v).as_text(
+            debug_info=True)
+    assert "352x352" not in text
+    for name in ("flash_fwd_band", "flash_bwd_band_dq", "flash_bwd_band_dkv"):
+        assert re.search(rf'\b{name}\b[^"]*/pallas_call"', text), name
 
 
 @pytest.mark.parametrize("kw", [{"causal": False}, {"window": 0},

@@ -661,8 +661,8 @@ def test_the_dispatch_is_chosen_by_the_calls_shapes(family, T_, one_hot):
     where they reach few."""
     E, k = _HELD[family]
     assert sharded_moe._one_hot_is_cheaper(T_, E, k, *_BYTES[family]) == one_hot
-    assert sharded_moe._sorts(T_, E, k, False, *_BYTES[family]) != one_hot
-    assert not sharded_moe._sorts(T_, E, k, True, *_BYTES[family])   # training
+    # ... at inference and in training alike (one rule since PR 48)
+    assert sharded_moe._sorts(T_, E, k, *_BYTES[family]) != one_hot
 
 
 # one expert layer ALONE on the chip, both forms (ms a layer; PERF.md section
@@ -738,12 +738,14 @@ def _dropless_layer(tokens=512, E=8, H=128, F=256):
     return cfg, params, jax.random.normal(k[4], (1, tokens, H))
 
 
-def test_a_dropless_call_under_a_mesh_or_in_training_keeps_the_one_hot_einsums(monkeypatch):
+def test_a_dropless_call_under_a_mesh_keeps_the_one_hot_einsums(monkeypatch):
     """The sorted dispatch sets no sharding constraint and nobody has
-    compiled it with the experts sharded: under a mesh, and in training, a
-    dropless call of many tokens lowers to what it lowered to before PR 26
-    (no ragged dot; the `[E, C, H]` arrays constrained over `expert`), and
-    gives what the sorted dispatch gives on one device."""
+    compiled it with the experts sharded: under a mesh, at inference and in
+    training, a dropless call of many tokens lowers to what it lowered to
+    before PR 26 (no ragged dot; the `[E, C, H]` arrays constrained over
+    `expert`), and gives what the sorted dispatch gives on one device —
+    where training sorts as inference does (PR 48: the grouped matmul has a
+    backward)."""
     from jax.sharding import Mesh, NamedSharding
     _published_widths(monkeypatch, "mixtral")
     cfg, params, x = _dropless_layer()
@@ -755,7 +757,7 @@ def test_a_dropless_call_under_a_mesh_or_in_training_keeps_the_one_hot_einsums(m
         return "ragged_dot" in str(jax.make_jaxpr(layer(train))(p, x))
 
     assert sorts(False, params)                      # one device, inference
-    assert not sorts(True, params)
+    assert sorts(True, params)                       # ... and training
     want = layer(False)(params, x)
 
     def constraints(text):
@@ -766,7 +768,7 @@ def test_a_dropless_call_under_a_mesh_or_in_training_keeps_the_one_hot_einsums(m
     with mesh:
         placed = {n: jax.device_put(v, NamedSharding(
             mesh, P() if n == "wg" else P("expert"))) for n, v in params.items()}
-        assert not sorts(False, placed)
+        assert not sorts(False, placed) and not sorts(True, placed)
         text = layer(False).lower(placed, x).as_text()
         # expert_in and the experts' output, [E, C, H] over `expert`
         assert constraints(text) >= 2
