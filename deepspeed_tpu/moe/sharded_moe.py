@@ -48,9 +48,10 @@ call's shapes, not by an option of their own:
 - **dropless** (``drop_tokens=False``: Mixtral, OLMoE as published), a call
   on one device that ``_sorts``, at inference and in training alike (the
   grouped matmul has a backward: ``ops/grouped_matmul.py``, d rows through
-  the same kernel, d experts through ``moe_gmm_dw``; the two permutations
-  move the rows of the experts HELD alone and are differentiated as each
-  other, ``_dispatch_rows`` / ``_combine_rows``): the T·k (token, expert) pairs
+  the same kernel, d experts through ``moe_gmm_dw``; beside that kernel the
+  two permutations are kernels too, which move the rows of the experts
+  HELD alone and are each other's gradient, ``_dispatch_rows`` /
+  ``_combine_rows`` over ``ops/moe_rows.py``): the T·k (token, expert) pairs
   are sorted by expert, each projection is ONE grouped matmul over the T·k
   rows (``_grouped_matmul``: static shapes — T·k rows and E group sizes),
   and the rows are weighted and summed back per token. Work and memory are
@@ -405,12 +406,20 @@ def _w_in(moe_params):
     return moe_params["w_in"], False
 
 
-def _use_gmm_kernel(moe_params, dtype) -> bool:
+def _use_gmm_kernel(moe_params, tokens, k: int, train: bool) -> bool:
+    """Whether a sorted call of ``tokens`` [T, H] runs on the Pallas kernels
+    (``sorted/moe_gmm``) or on ``ragged_dot`` beside ``jnp.take``. TRAINING
+    takes the kernels together or not at all: the grouped matmul's leaves the
+    rows past the groups undefined, in its gradient too, and only the row
+    kernels (``ops/moe_rows.py``) never read them — so a train step off THEIR
+    tiles is ``ragged_dot``'s, each mover with its native gradient."""
+    from deepspeed_tpu.ops import moe_rows
     from deepspeed_tpu.ops.grouped_matmul import supported
     w = _w_in(moe_params)[0]
     w = w.stack if isinstance(w, LayerOf) else w
-    return (dtype == jnp.bfloat16 and w.dtype == dtype
+    return (tokens.dtype == jnp.bfloat16 and w.dtype == tokens.dtype
             and supported(*w.shape[-2:]) and supported(*w.shape[-2:][::-1])
+            and (not train or moe_rows.supported(*tokens.shape, k))
             and jax.default_backend() == "tpu")
 
 
@@ -447,89 +456,78 @@ def _one_hot_ffn(moe_params, tokens, logits, cfg, C: int, rng, train,
     return y, aux
 
 
-# the rows `_gather_live` / `_scatter_live` may move, in eighths of all the
-# (token, expert) pairs: the smallest that holds the live ones is taken
-_LIVE_CAPS = (3, 4, 6, 8)
-
-
-def _capped(n_live, rows: int, upto):
-    """``upto(cap)`` for the smallest cap of the ladder that holds ``n_live``
-    rows: one program a cap (``lax.switch``), every shape static. XLA's
-    gather and scatter move row by row (4.9 ms for 131 072 rows of 2304 on a
-    v5e, three times what streaming them takes: PERF.md section 6, PR 48),
-    and on a chip's share most (token, expert) pairs are another chip's."""
-    caps = sorted({-(-rows * c // 8) for c in _LIVE_CAPS})
-    which = sum((n_live > c).astype(jnp.int32) for c in caps[:-1])
-    return lax.switch(which, [functools.partial(upto, c) for c in caps])
-
-
-def _gather_live(x, index, n_live):
-    """``out[i] = x[index[i]]`` for the first ``n_live`` entries of ``index``
-    (to the cap that holds them), zeros behind."""
-    rows = index.shape[0]
-
-    def upto(cap):
-        return jnp.pad(jnp.take(x, index[:cap], axis=0),
-                       ((0, rows - cap), (0, 0)))
-
-    return _capped(n_live, rows, upto)
-
-
-def _scatter_live(x, index, n_live):
-    """``out[index[i]] = x[i]`` for ``i < n_live``, zeros elsewhere; ``index``
-    a permutation of x's rows (no two entries alike). The transpose of
-    ``_gather_live`` over the same entries."""
-    rows = index.shape[0]
-
-    def upto(cap):
-        at = jnp.where(jnp.arange(cap) < n_live, index[:cap], rows)
-        return jnp.zeros_like(x).at[at].set(x[:cap], mode="drop",
-                                            unique_indices=True)
-
-    return _capped(n_live, rows, upto)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch_rows(tokens, order, n_live, k: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _dispatch_rows(tokens, order, pos, n_live, k: int, readers: int):
     """tokens [T, H] -> the sorted rows [T k, H]: row i is the token of pair
     ``order[i]``, for the ``n_live`` pairs that sort first (the pairs of the
-    experts held). Its gradient puts each live row back at its pair and sums
-    a token's k pairs: the live rows alone both ways, no scatter-add."""
-    return _gather_live(tokens, order // k, n_live)
+    experts held); the rows behind them are never written
+    (``ops/moe_rows.moe_rows_gather``). The rows come back once per READER
+    (the same array: the up and the gate projection), so that the gradient
+    gets each reader's cotangent apart and adds them over the live rows
+    alone — XLA's own sum runs over all T k. The gradient sums, per token,
+    the rows of its live pairs: the combine with unit weights; ``pos``
+    [T, k] is the inverse of ``order``."""
+    from deepspeed_tpu.ops.moe_rows import moe_rows_gather
+    return (moe_rows_gather(tokens, order // k, n_live),) * readers
 
 
-def _dispatch_rows_fwd(tokens, order, n_live, k):
-    return _dispatch_rows(tokens, order, n_live, k), (order, n_live)
+def _dispatch_rows_fwd(tokens, order, pos, n_live, k, readers):
+    return (_dispatch_rows(tokens, order, pos, n_live, k, readers),
+            (pos, n_live))
 
 
-def _dispatch_rows_bwd(k, residuals, g):
-    order, n_live = residuals
-    pairs = _scatter_live(g, order, n_live)
-    return (jnp.sum(pairs.reshape(-1, k, g.shape[-1]).astype(jnp.float32),
-                    axis=1).astype(g.dtype), None, None)
+def _dispatch_rows_bwd(k, readers, residuals, gs):
+    from deepspeed_tpu.ops.moe_rows import moe_rows_combine
+    pos, n_live = residuals
+    return (moe_rows_combine(gs, pos, jnp.ones(pos.shape, jnp.float32),
+                             n_live), None, None, None)
 
 
 _dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
 
 
 @jax.custom_vjp
-def _combine_rows(rows, order, n_live):
-    """The sorted rows [T k, H] back in (token, choice) order: the live rows
-    at their pairs, ZEROS at the pairs of experts elsewhere."""
-    return _scatter_live(rows, order, n_live)
+def _combine_rows(rows, weights, order, pos, n_live):
+    """The sorted rows [T k, H] weighted and summed back per token, in
+    float32, over the token's live pairs: y [T, H]
+    (``ops/moe_rows.moe_rows_combine``). Its gradient by the rows is the
+    dispatch of d y scaled by each pair's weight, by the weights the dot of
+    d y with the pair's row — one call of ``moe_rows_gather``."""
+    from deepspeed_tpu.ops.moe_rows import moe_rows_combine
+    return moe_rows_combine(rows, pos, weights, n_live)
 
 
-_combine_rows.defvjp(
-    lambda rows, order, n_live: (_combine_rows(rows, order, n_live),
-                                 (order, n_live)),
-    lambda residuals, g: (_gather_live(g, *residuals), None, None))
+def _combine_rows_fwd(rows, weights, order, pos, n_live):
+    return (_combine_rows(rows, weights, order, pos, n_live),
+            (rows, weights, order, pos, n_live))
 
 
-def _sorted_ffn(moe_params, tokens, logits, cfg, rng, train, held=None):
+def _combine_rows_bwd(residuals, g):
+    from deepspeed_tpu.ops.moe_rows import moe_rows_gather
+    rows, weights, order, pos, n_live = residuals
+    k = pos.shape[1]
+
+    def carried(keys, values):
+        # `values` to where `keys` (a permutation) sort: XLA sorts 131 072
+        # pairs in a third of the time it gathers as many scalars
+        return lax.sort((keys, values), num_keys=1)[1]
+    d_rows, dots = moe_rows_gather(
+        g, order // k, n_live,
+        (carried(pos.reshape(-1), weights.reshape(-1)), rows))
+    d_weights = jnp.where(pos < n_live, carried(order, dots).reshape(pos.shape),
+                          0.0)
+    return d_rows, d_weights.astype(weights.dtype), None, None, None
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
+def _sorted_ffn(moe_params, tokens, logits, cfg, rng, train, held, kernel):
     """Dispatch by sorting the T*k (token, expert) pairs by expert: every
     token reaches all k of its experts, and the work is T*k rows. ``held`` =
     (first, count): pairs whose expert is elsewhere sort behind the last
-    group, where the grouped matmul computes nothing, and add nothing."""
+    group, where the grouped matmul computes nothing, and add nothing.
+    ``kernel``: ``_use_gmm_kernel``'s."""
     T, H = tokens.shape
     E, k, dt = logits.shape[-1], cfg.top_k, tokens.dtype
     with jax.named_scope("route"):
@@ -551,31 +549,32 @@ def _sorted_ffn(moe_params, tokens, logits, cfg, rng, train, held=None):
             _tap_load(jnp.sum(onehot.reshape(T, k, E), axis=1), k)
         with jax.named_scope("sort"):
             order = jnp.argsort(flat)            # stable: token order kept
-        # training moves the live rows alone, by ops that are each other's
-        # gradient (`_dispatch_rows`); inference keeps the program it had
-        if train:
+        # training on the kernel path moves the live rows alone, by two
+        # kernels that are each other's gradient (`ops/moe_rows.py`);
+        # inference keeps the program it had
+        if train and kernel:
             n_live = jnp.sum(group_sizes)
-            rows_in = _dispatch_rows(tokens, order, n_live, k)
+            pos = jnp.argsort(order).reshape(T, k)
+            rows_in = _dispatch_rows(tokens, order, pos, n_live, k,
+                                     2 if "w_gate" in moe_params else 1)
         else:
-            rows_in = jnp.take(tokens, order // k, axis=0)        # [T*k, H]
+            rows_in = (jnp.take(tokens, order // k, axis=0),)     # [T*k, H]
     with jax.named_scope("experts"):
-        kernel = _use_gmm_kernel(moe_params, dt)
         w_in, transposed = _w_in(moe_params)
-        up = _grouped_matmul(rows_in, w_in, group_sizes, kernel, transposed)
-        gate = (_grouped_matmul(rows_in, moe_params["w_gate"], group_sizes,
-                                kernel)
+        up = _grouped_matmul(rows_in[0], w_in, group_sizes, kernel, transposed)
+        gate = (_grouped_matmul(rows_in[-1], moe_params["w_gate"],
+                                group_sizes, kernel)
                 if "w_gate" in moe_params else None)
         rows_out = _grouped_matmul(_glu_or_gelu(up, gate, cfg.activation),
                                    moe_params["w_out"], group_sizes, kernel)
     with jax.named_scope("combine"):
         # back to (token, choice) order, then the weighted sum over a
         # token's k rows in float32
-        if train:
-            per_choice = _combine_rows(rows_out, order, n_live)
-        else:
-            per_choice = jnp.take(rows_out, jnp.argsort(order), axis=0)
-            if held is not None:     # rows past the groups are undefined
-                per_choice = jnp.where(mine.reshape(T * k, 1), per_choice, 0)
+        if train and kernel:
+            return _combine_rows(rows_out, weights, order, pos, n_live), aux
+        per_choice = jnp.take(rows_out, jnp.argsort(order), axis=0)
+        if held is not None:         # rows past the groups are undefined
+            per_choice = jnp.where(mine.reshape(T * k, 1), per_choice, 0)
         y = jnp.sum(per_choice.reshape(T, k, H).astype(jnp.float32)
                     * weights[..., None], axis=1).astype(dt)
     return y, aux
@@ -698,9 +697,10 @@ def moe_ffn(moe_params, x, cfg, *, rng=None, train: bool = True,
                                expert_axis, held)
     elif _sorts(T, E, cfg.top_k if held is None else cfg.top_k * E / R,
                 *_expert_shapes(moe_params)):
-        form = ("sorted/moe_gmm" if _use_gmm_kernel(moe_params, tokens.dtype)
-                else "sorted/ragged_dot")
-        y, aux = _sorted_ffn(moe_params, tokens, logits, cfg, rng, train, held)
+        kernel = _use_gmm_kernel(moe_params, tokens, cfg.top_k, train)
+        form = "sorted/moe_gmm" if kernel else "sorted/ragged_dot"
+        y, aux = _sorted_ffn(moe_params, tokens, logits, cfg, rng, train, held,
+                             kernel)
     else:
         # capacity = tokens: the masks drop nothing
         form = "one-hot"

@@ -336,11 +336,10 @@ def _gmm_fwd(rows, w, stack, layer, group_sizes, transposed):
 def _gmm_bwd(transposed, residuals, dy):
     rows, stack, layer, group_sizes = residuals
     # d rows: dy through the same kernel, each matrix read the other way
-    # round; a row past the groups' sum comes back undefined and its
-    # gradient is none
+    # round; a row past the groups' sum has no gradient and comes back
+    # undefined, as in the forward (its readers, this kernel, `moe_gmm_dw`
+    # and `ops/moe_rows.py`, never read past the groups)
     d_rows = _gmm_call(dy, stack, layer, group_sizes, not transposed)
-    live = jnp.arange(rows.shape[0])[:, None] < jnp.sum(group_sizes)
-    d_rows = jnp.where(live, d_rows, jnp.zeros_like(d_rows))
     d_w = (grouped_matmul_dw(dy, rows, group_sizes, stack.dtype) if transposed
            else grouped_matmul_dw(rows, dy, group_sizes, stack.dtype))
     return d_rows, d_w, None, None, None
@@ -362,7 +361,8 @@ def grouped_matmul(rows, stack, layer, group_sizes, transposed: bool = False):
     stored ``[.., 1856, 2688]`` it is read in place.
 
     Differentiable in ``rows`` and ``stack`` (``_gmm``): d rows is this
-    kernel with ``transposed`` flipped, d stack the ``moe_gmm_dw`` kernel's
+    kernel with ``transposed`` flipped (rows past the groups' sum undefined
+    there too), d stack the ``moe_gmm_dw`` kernel's
     ``[E, K, N]`` block of the layer, which JAX places in the stack's
     gradient as the transpose of the slice."""
     w = stack[layer] if isinstance(layer, int) else lax.dynamic_index_in_dim(

@@ -338,3 +338,58 @@ def test_band_instruction_name_at_the_published_sizes(topo, mosaic):
     calls = _mosaic_calls(compiled)
     assert [c.split(".")[0] for c in calls] == ["%flash_fwd_band"], calls
     assert fa._band_tiles(4096, *fa._pick_blocks(9216, 512, 1024, 6), 9216) == 6
+
+
+def test_row_kernel_instruction_names_at_the_published_widths(topo, monkeypatch):
+    """ONE sorted expert layer of Mellum2 at the PUBLISHED widths
+    (benchmark/configs/mellum2-12b-train.json: top-8 of a 64-wide router, 16
+    experts of [2304, 896] held; 2 x 1024 tokens, an eighth of the cell's,
+    which shortens the grids and nothing else), its TRAINING gradient
+    compiled for the described v5e with every backend branch taken as on the
+    chip: Mosaic takes the three row kernels at these widths (it refuses a
+    one-row slice of a ``[rows, H]`` array, which no CPU run shows), each
+    mover is there by its own name — two gathers (the dispatch, the
+    combine's gradient), two combines (the combine, the dispatch's gradient),
+    a pack for each — beside the grouped matmul's, and XLA neither pads,
+    selects, adds nor gathers an array of T k rows of H."""
+    import json
+    import os
+    from deepspeed_tpu.models.hf_import import hf_config_to_transformer
+    from deepspeed_tpu.moe import sharded_moe as sm
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mellum2-12b-train.json")) as f:
+        conf = json.load(f)
+    cfg = hf_config_to_transformer(
+        {k: v for k, v in conf.items() if k not in (
+            "source", "reduced", "assumed", "deployment", "run", "correct")},
+        dtype=jnp.bfloat16)
+    T, k, H, F, E, R = 2048, 8, 2304, 896, 16, 64
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    p = {"wg": _sds((H, R), jnp.float32, one),
+         "w_in_t": _sds((E, F, H), jnp.bfloat16, one),
+         "w_gate": _sds((E, H, F), jnp.bfloat16, one),
+         "w_out": _sds((E, F, H), jnp.bfloat16, one)}
+    fn = jax.jit(jax.value_and_grad(lambda p, x: jnp.sum(
+        sm.moe_ffn(p, x, cfg, train=True)[0].astype(jnp.float32)),
+        argnums=(0, 1)))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        with jax.default_matmul_precision("default"):
+            compiled = fn.lower(p, _sds((2, T // 2, H), jnp.bfloat16,
+                                        one)).compile()
+    finally:
+        monkeypatch.undo()
+    hlo = compiled.as_text()
+    calls = [l.split(" = ")[0].strip().lstrip("%").split(".")[0]
+             for l in hlo.splitlines() if "custom_call_target=\"tpu_custom_call\"" in l]
+    assert calls.count("moe_rows_gather") == 2
+    assert calls.count("moe_rows_combine") == 2
+    assert calls.count("moe_rows_pack") == 4
+    assert calls.count("moe_gmm") == 6 and calls.count("moe_gmm_dw") == 3
+    rows = f"bf16[{T * k},{H}]"
+    moved = [l for l in hlo.splitlines()
+             if f" = {rows}" in l and "tpu_custom_call" not in l
+             and " parameter(" not in l and "get-tuple-element" not in l
+             and " bitcast(" not in l]
+    assert not moved, moved[:3]

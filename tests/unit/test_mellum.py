@@ -32,6 +32,7 @@ from deepspeed_tpu.models.hf_import import hf_config_to_transformer  # noqa: E40
 from deepspeed_tpu.models.transformer import RopeTable, rotary_embed  # noqa: E402
 from deepspeed_tpu.moe import sharded_moe as sm  # noqa: E402
 from deepspeed_tpu.ops import grouped_matmul as gmm  # noqa: E402
+from deepspeed_tpu.ops import moe_rows as mr  # noqa: E402
 
 TOL = 2e-4
 WINDOW = 16
@@ -360,18 +361,20 @@ def test_grouped_matmul_vjp_is_the_dense_loops_gradient(sizes, transposed):
         return jnp.sum(_dense(r, s, 1, np.asarray(sizes), transposed) * ct)
 
     with jax.default_matmul_precision("highest"):
-        got, want = (jax.grad(f, (0, 1))(rows, stack) for f in (kernel, dense))
+        got, want = (jax.jit(jax.grad(f, (0, 1)))(rows, stack)
+                     for f in (kernel, dense))
         assert float(kernel(rows, stack)) == pytest.approx(
             float(dense(rows, stack)), rel=1e-5, abs=1e-4)
-    for g, w in zip(got, want):
+    # a row past the groups has no gradient and comes back undefined
+    for g, w in zip((got[0][:sum(sizes)], got[1]),
+                    (want[0][:sum(sizes)], want[1])):
         assert g.shape == w.shape
-        assert float(jnp.abs(g - w).max()) < 1e-3 * max(1.0, float(jnp.abs(w).max()))
-    # the other layer's experts, an empty expert's matrix and the rows past
-    # the groups get exact zeros
+        assert float(jnp.abs(g - w).max(initial=0.0)) \
+            < 1e-3 * max(1.0, float(jnp.abs(w).max(initial=0.0)))
+    # the other layer's experts and an empty expert's matrix get exact zeros
     assert not np.asarray(got[1][0]).any()
     for e, n in enumerate(sizes):
         assert bool(np.asarray(got[1][1, e]).any()) == (n > 0)
-    assert not np.asarray(got[0][sum(sizes):]).any()
 
 
 def test_the_gradient_of_a_traced_layer_index():
@@ -389,31 +392,265 @@ def test_the_gradient_of_a_traced_layer_index():
     assert not np.asarray(got[:2]).any() and np.asarray(got[2]).any()
 
 
-@pytest.mark.parametrize("n_live", [0, 1, 23, 24, 25, 32, 33, 48, 49, 64])
-def test_the_live_rows_move_alone_and_each_way_is_the_others_gradient(n_live):
-    """``_gather_live`` / ``_scatter_live`` over a permutation of 64 rows at
-    every rung of the ladder of caps (24, 32, 48, 64 rows) and on either
-    side of each: the first ``n_live`` entries move, zeros elsewhere, and the
-    two are transposes of each other."""
-    rng = np.random.default_rng(n_live)
-    perm = rng.permutation(64).astype(np.int32)
-    x = rng.normal(size=(64, 8)).astype(np.float32)
-    got = np.asarray(jax.jit(sm._gather_live)(jnp.asarray(x), jnp.asarray(perm),
-                                              jnp.int32(n_live)))
-    np.testing.assert_array_equal(got[:n_live], x[perm[:n_live]])
-    cap = next(c for c in (24, 32, 48, 64) if n_live <= c)
-    assert not got[cap:].any()              # behind the cap: zeros
-    put = np.asarray(jax.jit(sm._scatter_live)(jnp.asarray(x), jnp.asarray(perm),
-                                               jnp.int32(n_live)))
-    want = np.zeros_like(x)
-    want[perm[:n_live]] = x[:n_live]
-    np.testing.assert_array_equal(put, want)
-    # <gather(x), y> over the live rows = <x, scatter(y)>
-    y = rng.normal(size=(64, 8)).astype(np.float32)
-    y[n_live:] = 0
-    assert float((got * y).sum()) == pytest.approx(float((x * np.asarray(
-        sm._scatter_live(jnp.asarray(y), jnp.asarray(perm), jnp.int32(n_live)))
-    ).sum()), rel=1e-5, abs=1e-5)
+# ---- the row kernels of the sorted dispatch (interpret mode) -------------------
+
+ROWS_T, ROWS_K, ROWS_H = 128, 4, 256          # 512 pairs: two tiles of 256 rows
+# nobody's, one, either side of a tile's edge, every pair
+ROWS_LIVE = [0, 1, mr.ROW_TILE - 1, mr.ROW_TILE, mr.ROW_TILE + 1,
+             ROWS_T * ROWS_K]
+
+
+def _pairs(seed, whole=False):
+    """A permutation of the T k pairs as the sort leaves it (``order``, its
+    inverse ``pos`` [T, k]) and rows of small whole numbers (``whole``: sums
+    of k of them are exact in bf16) or normal ones."""
+    rng = np.random.default_rng(seed)
+    M = ROWS_T * ROWS_K
+    order = rng.permutation(M).astype(np.int32)
+    pos = np.argsort(order).astype(np.int32).reshape(ROWS_T, ROWS_K)
+
+    def draw(n):
+        a = (rng.integers(-4, 5, (n, ROWS_H)) if whole
+             else rng.normal(size=(n, ROWS_H)))
+        return np.asarray(jnp.asarray(a, jnp.bfloat16).astype(jnp.float32))
+    return rng, order, pos, draw(ROWS_T), draw(M)
+
+
+def _bf16(a):
+    return np.asarray(jnp.asarray(a).astype(jnp.bfloat16).astype(jnp.float32))
+
+
+@pytest.mark.parametrize("weighted", [False, True])
+@pytest.mark.parametrize("n_live", ROWS_LIVE)
+def test_moe_rows_gather_moves_the_live_rows_and_dots_them(n_live, weighted):
+    """``out[i] = x[index[i]]`` for ``i < n_live``; ``weighted`` (the
+    combine's gradient) ``scale[i] * x[index[i]]``, the product in float32,
+    and ``<x[index[i]], other[i]>``, before the scale."""
+    rng, order, _, x, other = _pairs(n_live + weighted)
+    index = order // ROWS_K
+    scale = rng.random(order.shape[0]).astype(np.float32)
+    args = (jnp.asarray(x, jnp.bfloat16), jnp.asarray(index), jnp.int32(n_live))
+    if not weighted:
+        out = mr.moe_rows_gather(*args)
+        np.testing.assert_array_equal(
+            np.asarray(out.astype(jnp.float32))[:n_live], x[index][:n_live])
+        return
+    out, dots = mr.moe_rows_gather(
+        *args, (jnp.asarray(scale), jnp.asarray(other, jnp.bfloat16)))
+    np.testing.assert_array_equal(
+        np.asarray(out.astype(jnp.float32))[:n_live],
+        _bf16(x[index] * scale[:, None])[:n_live])
+    np.testing.assert_allclose(np.asarray(dots)[:n_live],
+                               (x[index] * other).sum(1)[:n_live],
+                               rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("n_live", ROWS_LIVE)
+def test_moe_rows_combine_is_the_dense_float32_loop(n_live):
+    """``y[t] = sum_j w[t, j] * rows[pos[t, j]]`` over the live pairs against
+    the loop in float32; the rows past ``n_live`` hold NaN and are never
+    read; a token with no live pair gives zeros."""
+    rng, _, pos, _, rows = _pairs(100 + n_live)
+    w = rng.random(pos.shape).astype(np.float32)
+    poisoned = rows.copy()
+    poisoned[n_live:] = np.nan
+    y = np.asarray(mr.moe_rows_combine(
+        jnp.asarray(poisoned, jnp.bfloat16), jnp.asarray(pos), jnp.asarray(w),
+        jnp.int32(n_live)).astype(jnp.float32))
+    live = pos < n_live
+    want = np.zeros((ROWS_T, ROWS_H), np.float32)
+    for j in range(ROWS_K):
+        want += np.where(live[:, j, None], rows[pos[:, j]] * w[:, j, None],
+                         np.float32(0))
+    assert np.isfinite(y).all()
+    # bf16 of a float32 sum taken in the same order: at most one rounding apart
+    np.testing.assert_allclose(y, _bf16(want), rtol=2 ** -7, atol=1e-6)
+    none = ~live.any(axis=1)
+    assert none.any() == (n_live < ROWS_T * ROWS_K) and not y[none].any()
+
+
+@pytest.mark.parametrize("n_live", [1, mr.ROW_TILE - 1, mr.ROW_TILE + 1,
+                                    ROWS_T * ROWS_K])
+def test_the_two_row_kernels_are_each_others_transpose(n_live):
+    """``<gather(x), y> = <x, combine(y)>`` over the live rows (unit weights;
+    whole numbers, so every sum is exact in bf16)."""
+    _, order, pos, x, y = _pairs(200 + n_live, whole=True)
+    gathered = np.asarray(mr.moe_rows_gather(
+        jnp.asarray(x, jnp.bfloat16), jnp.asarray(order // ROWS_K),
+        jnp.int32(n_live)).astype(jnp.float32))
+    combined = np.asarray(mr.moe_rows_combine(
+        jnp.asarray(y, jnp.bfloat16), jnp.asarray(pos),
+        jnp.ones(pos.shape, jnp.float32), jnp.int32(n_live)
+    ).astype(jnp.float32))
+    assert float((gathered[:n_live] * y[:n_live]).sum()) \
+        == float((x * combined).sum()) != 0.0
+
+
+def test_the_combine_adds_its_sources_over_the_live_rows():
+    """Several row arrays: the combine of their sum as bf16 rows (XLA's
+    ``a + b``), the rows past ``n_live`` of each holding NaN."""
+    n_live = mr.ROW_TILE + 1
+    rng, _, pos, _, a = _pairs(300)
+    b = _pairs(301)[4]
+    w = jnp.asarray(rng.random(pos.shape).astype(np.float32))
+    a, b = (jnp.asarray(r, jnp.bfloat16) for r in (a, b))
+    want = mr.moe_rows_combine(a + b, jnp.asarray(pos), w,
+                                        jnp.int32(n_live))
+    dead = jnp.arange(a.shape[0])[:, None] >= n_live
+    got = mr.moe_rows_combine(
+        (jnp.where(dead, jnp.nan, a), jnp.where(dead, jnp.nan, b)),
+        jnp.asarray(pos), w, jnp.int32(n_live))
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)),
+                                  np.asarray(want.astype(jnp.float32)))
+    assert np.asarray(want.astype(jnp.float32)).any()
+
+
+def _kernel_layer(monkeypatch, T=384, H=256):
+    """One sorted expert layer in bf16 for the kernel path (the Pallas
+    kernels in interpret mode): 4 of a 16-wide router's experts held, top-4,
+    T tokens of width H — T x 4 pairs, about a quarter of them live."""
+    hf = dict(HF, hidden_size=H, moe_intermediate_size=128, num_experts=4,
+              num_experts_router=16, num_experts_per_tok=4)
+    cfg = hf_config_to_transformer(hf, dtype=jnp.bfloat16)
+    ks = jax.random.split(jax.random.PRNGKey(11), 5)
+    p = {"wg": jax.random.normal(ks[0], (H, 16)) * 0.1,
+         "w_in_t": jax.random.normal(ks[1], (4, 128, H)) * 0.06,
+         "w_gate": jax.random.normal(ks[2], (4, H, 128)) * 0.06,
+         "w_out": jax.random.normal(ks[3], (4, 128, H)) * 0.09}
+    p = {n: a.astype(jnp.float32 if n == "wg" else jnp.bfloat16)
+         for n, a in p.items()}
+    x = jax.random.normal(ks[4], (1, T, H)).astype(jnp.bfloat16)
+    monkeypatch.setattr(sm, "_sorts", lambda *a: True)
+    return cfg, p, x
+
+
+def _grads(cfg, p, x):
+    """(the dispatch form, y, the gradient by every parameter and by x) of
+    one training call of the layer, as one program."""
+    with sm.expert_load_tap() as tap:
+        (_, y), g = jax.jit(jax.value_and_grad(
+            lambda p, x: (lambda y: (jnp.sum(y.astype(jnp.float32) ** 2), y))(
+                sm.moe_ffn(p, x, cfg, train=True)[0]),
+            argnums=(0, 1), has_aux=True))(p, x)
+    return tap.form, y, g
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it, a Pallas
+    kernel's body left out (its `cond`s are `pl.when`s)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _eqns(sub)
+
+
+def test_no_row_behind_the_live_ones_is_read_by_the_layer(monkeypatch):
+    """Every row a kernel leaves undefined poisoned with NaN — what
+    ``moe_rows_gather`` does not write (the dispatch's and, in the backward,
+    the combine's gradient) and what ``moe_gmm`` leaves past the groups (each
+    projection's output and, in the backward, its d rows): the layer's result
+    and every gradient leaf stay finite and are those of the plain
+    ``jnp.take`` form beside ``ragged_dot``."""
+    cfg, p, x = _kernel_layer(monkeypatch)
+    gather, matmul = mr.moe_rows_gather, gmm._gmm_call
+
+    def dead(rows, n_live):
+        return (jnp.arange(rows.shape[0]) >= n_live).reshape(
+            (-1,) + (1,) * (rows.ndim - 1))
+
+    def poisoned_gather(x, index, n_live, weighted=None):
+        out = gather(x, index, n_live, weighted)
+        return jax.tree.map(lambda a: jnp.where(dead(a, n_live), jnp.nan, a),
+                            out)
+
+    def poisoned_matmul(rows, stack, layer, group_sizes, transposed):
+        out = matmul(rows, stack, layer, group_sizes, transposed)
+        return jnp.where(dead(out, jnp.sum(group_sizes)), jnp.nan, out)
+
+    monkeypatch.setattr(sm, "_use_gmm_kernel", lambda *a: False)
+    form, want_y, want_g = _grads(cfg, p, x)
+    assert form == "sorted/ragged_dot"
+    monkeypatch.setattr(sm, "_use_gmm_kernel", lambda *a: True)
+    monkeypatch.setattr(mr, "moe_rows_gather", poisoned_gather)
+    monkeypatch.setattr(gmm, "_gmm_call", poisoned_matmul)
+    form, y, g = _grads(cfg, p, x)
+    assert form == "sorted/moe_gmm"
+    leaves = lambda t: jax.tree.leaves(jax.tree.map(
+        lambda a: np.asarray(a.astype(jnp.float32)), t))
+    for got, want in zip(leaves((y, g)), leaves((want_y, want_g))):
+        assert np.isfinite(got).all() and np.abs(want).max() > 0
+        assert np.abs(got - want).max() < 0.02 * np.abs(want).max()
+
+
+@pytest.mark.parametrize("T,H", [(200, 256), (256, 384)])
+def test_training_off_the_row_kernels_tiles_takes_no_kernel_at_all(
+        monkeypatch, T, H):
+    """The grouped matmul's kernel pads any T and takes widths off the 256
+    grid; the row kernels do not. On a TPU such a layer (``held`` set: three
+    pairs in four are dead) TRAINS on ``ragged_dot`` beside ``jnp.take`` —
+    no Pallas kernel in its gradient program, so no undefined d row for
+    ``jnp.take``'s scatter-add to read — and still serves on ``moe_gmm``;
+    every gradient leaf is finite."""
+    cfg, p, x = _kernel_layer(monkeypatch, T, H)
+    real = sm._use_gmm_kernel
+
+    def as_on_a_tpu(*a):        # the rule itself, the kernels still interpreted
+        with monkeypatch.context() as m:
+            m.setattr(jax, "default_backend", lambda: "tpu")
+            return real(*a)
+    monkeypatch.setattr(sm, "_use_gmm_kernel", as_on_a_tpu)
+    assert not mr.supported(T, H, cfg.top_k)
+    form, y, g = _grads(cfg, p, x)
+    assert form == "sorted/ragged_dot"
+    for leaf in jax.tree.leaves((y, g)):
+        a = np.asarray(leaf.astype(jnp.float32))
+        assert np.isfinite(a).all() and a.any()
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(
+        sm.moe_ffn(p, x, cfg, train=True)[0].astype(jnp.float32)),
+        argnums=(0, 1)))(p, x)
+    assert "pallas_call" not in {e.primitive.name for e in _eqns(jaxpr.jaxpr)}
+    with sm.expert_load_tap() as tap:
+        jax.make_jaxpr(lambda p, x: sm.moe_ffn(p, x, cfg, train=False)[0])(p, x)
+    assert tap.form == "sorted/moe_gmm"
+    # on the tiles the same rule takes the kernels in training too
+    cfg, p, x = _kernel_layer(monkeypatch)
+    with sm.expert_load_tap() as tap:
+        jax.make_jaxpr(lambda p, x: sm.moe_ffn(p, x, cfg, train=True)[0])(p, x)
+    assert tap.form == "sorted/moe_gmm"
+
+
+def test_the_sorted_train_step_on_the_kernel_path_moves_rows_by_kernels_alone(
+        monkeypatch):
+    """The gradient program of one sorted expert layer on the forced kernel
+    path: no ``cond`` (a ladder of caps was a ``lax.switch`` a mover), and
+    no pad, scatter, gather, select or sum of cotangents with T k rows of H
+    out — the rows move inside ``moe_rows_gather`` / ``moe_rows_combine``,
+    which adds the up and the gate projection's cotangents itself — and no
+    array with the tokens on two axes."""
+    cfg, p, x = _kernel_layer(monkeypatch)
+    monkeypatch.setattr(sm, "_use_gmm_kernel", lambda *a: True)
+    T, k, H = 384, 4, 256
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(
+        sm.moe_ffn(p, x, cfg, train=True)[0].astype(jnp.float32)),
+        argnums=(0, 1)))(p, x)
+    eqns = list(_eqns(jaxpr.jaxpr))
+    names = [e.primitive.name for e in eqns]
+    kernels = [e.params["name"] for e in eqns
+               if e.primitive.name == "pallas_call"]
+    assert {"moe_rows_gather", "moe_rows_combine", "moe_rows_pack", "moe_gmm",
+            "moe_gmm_dw"} == set(kernels)
+    assert kernels.count("moe_rows_gather") == kernels.count(
+        "moe_rows_combine") == 2
+    assert not {"cond", "while"} & set(names)
+    shapes = [tuple(v.aval.shape) for e in eqns for v in e.outvars]
+    for e in eqns:      # (the pads left place a layer's d experts in the stack)
+        if e.primitive.name in ("pad", "gather", "scatter", "scatter-add",
+                                "scatter_add", "select_n", "add_any"):
+            assert all(v.aval.shape[-1:] != (H,) or v.aval.shape[0] != T * k
+                       for v in e.outvars), e
+    assert (T * k, H) in shapes
+    assert not [s for s in shapes if s.count(T) >= 2]
 
 
 # ---- imbalance ---------------------------------------------------------------
@@ -475,16 +712,8 @@ def test_the_sorted_train_step_builds_no_mask_over_tokens_and_experts(
         _forced(monkeypatch, dispatch)
         jaxpr = jax.make_jaxpr(jax.grad(
             lambda p: jnp.sum(sm.moe_ffn(p, x, cfg, train=True)[0])))(p)
-        found = set()
-
-        def walk(j):
-            for eqn in j.eqns:
-                for v in eqn.outvars:
-                    found.add(tuple(getattr(v.aval, "shape", ())))
-                for sub in jax.core.jaxprs_in_params(eqn.params):
-                    walk(sub)
-        walk(jaxpr.jaxpr)
-        return found
+        return {tuple(getattr(v.aval, "shape", ()))
+                for eqn in _eqns(jaxpr.jaxpr) for v in eqn.outvars}
 
     def masks(found):
         return {s for s in found if s.count(T) >= 2}
