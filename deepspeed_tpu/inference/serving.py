@@ -17,10 +17,12 @@ Replaces the one-shot ``generate()`` loop as the multi-tenant serving path
      attention read via score scaling, ops/quantizer) and int8 weights via
      the InferenceEngine's existing ``quantize_bits`` path.
 
-The decode-attention backend (paged Pallas kernel vs the XLA gather) is
-picked by a MEASURED micro-bench on the real pool shapes at engine init —
-never a config flag — and the choice is logged as a structured telemetry
-event (``decode_backend_selected``).
+The decode-attention backend (a paged Pallas kernel vs the XLA gather) is
+picked at engine init from what the engine can see — an int8 pool's read is
+PRICED from its shapes (``ops/decode_attention.paged_read_price``), a float
+pool's two reads are timed on the real pool — never by a flag the default
+leaves set, and the choice is logged with what decided it as a structured
+telemetry event (``decode_backend_selected``).
 
 Token/row bookkeeping (the invariant every path maintains):
 ``req.cached_rows`` = KV rows actually in the pool for this request. A
@@ -844,7 +846,7 @@ class ServingEngine:
             from deepspeed_tpu.monitor.monitor import JSONLMonitor
             self._jsonl = JSONLMonitor(c.telemetry_jsonl)
 
-        # backend micro-bench (one-time, on the REAL pool shapes) --------
+        # which read of the pool the decode step takes (priced or timed) --
         self.decode_backend, self.backend_bench = self._select_backend()
         # plain decode rounds dispatched per (slot count, columns a slot)
         # (reset_stats windows; _tables_device)
@@ -1108,42 +1110,67 @@ class ServingEngine:
                    min(-(-n // self._bucket) * self._bucket,
                        self.max_model_len))
 
-    # ---- backend selection (measured, not a flag) --------------------
+    # ---- backend selection (a price or a measurement, not a flag) -----
 
     def _select_backend(self):
-        """Time the paged Pallas kernel vs the XLA gather on THIS engine's
-        pool shapes and pick the winner; the decision is logged as a
-        telemetry event. Non-TPU backends and int8 pools skip straight to
-        XLA (interpret-mode Pallas is not a serving path; the int8 read
-        fuses dequant into the XLA score scaling)."""
+        """Which read of the paged pool the decode step takes, logged as a
+        telemetry event (``decode_backend_selected``) with what decided it.
+
+        An int8 pool is PRICED: ``ops/decode_attention.paged_read_price`` at
+        this engine's slots, table width, block size and heads — the bytes
+        the XLA read moves against the bytes ``paged_decode_int8`` moves and
+        its fixed costs, the constants fitted on the chip — and the kernel
+        takes the step where it is the cheaper read by more than the tie
+        band. Nothing is timed. A float pool keeps the other kernel
+        (``paged_decode_attention``) and the micro-bench that times it
+        against the XLA gather on this engine's pool shapes. Non-TPU
+        backends keep XLA (interpret-mode Pallas is not a serving path), and
+        so does every engine the kernels do not cover: a ``tensor`` mesh over
+        an int8 pool, ALiBi, a custom ``attn_scale``, per-layer windows,
+        float16."""
         import jax
         import jax.numpy as jnp
+        from deepspeed_tpu.ops.decode_attention import (int8_kernel_fits,
+                                                        paged_read_price)
         from deepspeed_tpu.robustness.events import emit
 
         c = self.config
         mcfg = self.model.config
         forced = c.decode_backend if c.decode_backend != "auto" else None
         on_tpu = jax.default_backend() == "tpu"
+        int8_pool = getattr(mcfg, "kv_cache_bits", 0) == 8
+        price = None
         # capability gate FIRST — _paged_attention would silently fall back
         # to the XLA gather for these, so selecting (or honoring a forced)
         # "pallas" here would make the telemetry event and the bench's
         # serve_decode_backend misreport what actually runs
         unavailable = None
-        if getattr(mcfg, "kv_cache_bits", 0) == 8:
-            unavailable = "int8 KV pool (fused-dequant XLA read)"
-        elif self.engine.dtype == jnp.float16:
+        if self.engine.dtype == jnp.float16:
             unavailable = "f16 compute dtype (Mosaic has no f16)"
         elif (getattr(mcfg, "position_type", None) == "alibi"
               or getattr(mcfg, "attn_scale", None) is not None
-              or getattr(mcfg, "attn_windows", None)):
+              or (getattr(mcfg, "attn_windows", None)
+                  and not getattr(mcfg, "block_pattern", None))):
             # attn_windows: decode_step_paged passes a TRACED per-layer
             # window (even all-global entries), which the kernel gate
-            # rejects
+            # rejects. Under a block pattern the windows are the ring
+            # blocks' (models/hybrid.py): no paged plane has one
             unavailable = "kernel-unsupported attention variant"
         elif mcfg.dim_per_head < 64:
             # the deleted contiguous kernel carried the same hardware
             # gate: sub-64 lanes don't lower well through Mosaic
             unavailable = f"head_dim {mcfg.dim_per_head} < 64"
+        elif int8_pool and self.tp > 1:
+            unavailable = "int8 KV pool under a tensor mesh"
+        elif int8_pool:
+            shapes = dict(MB=self.MB, block_size=c.block_size,
+                          n_kv=mcfg.kv_heads, head_dim=mcfg.dim_per_head,
+                          rep=mcfg.num_heads // mcfg.kv_heads)
+            if int8_kernel_fits(**shapes):
+                price = paged_read_price(slots=c.max_seqs,
+                                         num_blocks=self.num_blocks, **shapes)
+            else:
+                unavailable = "int8 kernel cannot be built at these shapes"
         backend = reason = None
         if unavailable is not None:
             backend = "xla"
@@ -1153,8 +1180,14 @@ class ServingEngine:
             backend, reason = forced, "forced by config"
         elif not on_tpu:
             backend, reason = "xla", "non-TPU backend"
+        elif price is not None:
+            backend, reason = price["choice"], price["why"]
         if reason is not None:
             bench = {"backend": backend, "reason": reason}
+            if price is not None:
+                bench.update(xla_bytes=price["xla_bytes"],
+                             kernel_bytes=price["kernel_bytes"],
+                             priced=price["choice"])
             emit("decode_backend_selected", **bench)
             return backend, bench
 
@@ -3299,6 +3332,10 @@ class ServingEngine:
         out.update({k: float(v) for k, v in self._lat.items()})
         out["step_shape_rounds"] = {
             f"{S}x{W}": n for (S, W), n in self._table_rounds.items()}
+        # what reads the pool in the decode step, and what decided it (the
+        # price's two sides on an int8 pool: ``_select_backend``)
+        out["decode_backend"] = self.decode_backend
+        out["decode_backend_choice"] = dict(self.backend_bench)
         out["slow_rounds"] = [[dict(rec), nxt and dict(nxt)]
                               for rec, nxt in self._slow]
         out["gc_ms_total"] = float(self._gc_ms_total)

@@ -30,7 +30,8 @@ from jax.sharding import PartitionSpec as P
 # the Pallas kernels are imported at module scope on purpose: a Pallas API
 # change must fail `import deepspeed_tpu`, not silently route every model
 # through the O(S^2) XLA attention
-from deepspeed_tpu.ops.decode_attention import paged_decode_attention
+from deepspeed_tpu.ops.decode_attention import (paged_decode_attention,
+                                                paged_decode_int8)
 from deepspeed_tpu.ops.flash_attention import flash_attention
 from deepspeed_tpu.moe import sharded_moe as _moe
 from deepspeed_tpu.models import looped as _looped
@@ -929,10 +930,11 @@ def _decode_attention(q, ck, cv, index, cfg: TransformerConfig = None,
     property, inference_context.h).
 
     This is the XLA decode path; its length-awareness comes from the decode
-    loop's static read windows. The serving tier's paged layout has its own
-    Pallas kernel (ops/decode_attention.paged_decode_attention), selected
-    by a measured micro-bench at engine init — the old contiguous-layout
-    kernel lost to this path end-to-end on v5e and was deleted.
+    loop's static read windows. The serving tier's paged layout has Pallas
+    kernels of its own (ops/decode_attention.py: one a pool dtype), chosen
+    at engine init by a price or a micro-bench (``_paged_attention``) — the
+    old contiguous-layout kernel lost to this path end-to-end on v5e and was
+    deleted.
     """
     B, _, Nq, D = q.shape
     Nkv, T = ck.shape[1], ck.shape[2]
@@ -1151,10 +1153,13 @@ def _paged_attention(q, pool_k, pool_v, tables, index, cfg: TransformerConfig,
     read as the list of all their entries; index: per-slot sequence
     length [S].
 
-    backend="pallas" (rectangular tables): the block-table gather is
-    resolved inside the kernel's index maps
-    (ops/decode_attention.paged_decode_attention) — only blocks covering
-    the valid prefix ever cross HBM->VMEM, nothing materializes.
+    backend="pallas" (rectangular tables): a kernel reads the pool — only
+    blocks covering the valid prefix ever cross HBM->VMEM, nothing
+    materializes. An int8 pool (``kv_scale`` given): the whole leaves and
+    scale planes go to ``ops/decode_attention.paged_decode_int8`` with the
+    layer as a scalar, a slot's live blocks streamed through VMEM under the
+    XLA read's int8 recipe. A float pool: the block-table gather is resolved
+    inside the index maps of ``paged_decode_attention``.
     backend="xla": ONE ``jnp.take`` per pool materializes the LISTED blocks
     as stored ([N, bs, Nkv, D]: N follows the sum of what the slots hold,
     not slots x the longest table) and ``_paged_list_attention`` contracts
@@ -1162,8 +1167,10 @@ def _paged_attention(q, pool_k, pool_v, tables, index, cfg: TransformerConfig,
     arithmetic is the ring-buffer path's (``_decode_attention`` with a
     per-slot cursor) operation for operation, which is what keeps
     paged-vs-contiguous decode bit-for-bit comparable in tests. The backend
-    is chosen by a measured micro-bench at serving-engine init, not a
-    config flag.
+    is chosen at serving-engine init (``ServingEngine._select_backend``): an
+    int8 pool's by a price computed from the engine's shapes
+    (``ops/decode_attention.paged_read_price``), a float pool's by a measured
+    micro-bench — neither by a config flag the default leaves set.
 
     Multi-token queries (q [S, T, Nq, D] with T > 1 — the speculation
     verify / chunked-prefill span path, ``decode_span_paged``) route to
@@ -1176,11 +1183,20 @@ def _paged_attention(q, pool_k, pool_v, tables, index, cfg: TransformerConfig,
         return _paged_span_attention(q, pool_k, pool_v, tables, index, cfg,
                                      kv_row, kv_scale=kv_scale,
                                      window=window, layer=layer)
-    use_pallas = (backend == "pallas" and kv_scale is None
+    use_pallas = (backend == "pallas"
                   and not isinstance(tables, BlockList)
                   and window is None and q.dtype != jnp.float16
                   and (cfg is None or (cfg.position_type != "alibi"
                                        and cfg.attn_scale is None)))
+    if use_pallas and kv_scale is not None:
+        # the int8 pool: the leaves whole, the layer a scalar of the kernel
+        # (one chip: ``ServingEngine._select_backend`` keeps a ``tensor``
+        # mesh on the XLA read)
+        if layer is None:
+            pool_k, pool_v, *kv_scale = (a[None] for a in
+                                         (pool_k, pool_v, *kv_scale))
+        return paged_decode_int8(q, pool_k, pool_v, *kv_scale, tables, index,
+                                 0 if layer is None else layer, kv_row=kv_row)
     if use_pallas:
         if layer is not None:        # a kernel's operand is a whole buffer
             pool_k, pool_v = (lax.dynamic_index_in_dim(p, layer, 0,

@@ -236,6 +236,27 @@ def test_paged_decode_instruction_name(topo, mosaic):
     assert [c.split(".")[0] for c in calls] == ["%paged_decode"], calls
 
 
+def test_paged_decode_int8_instruction_name(topo, mosaic):
+    """The int8 pool's kernel at Trinity's heads and table (48 query heads
+    over 8, 176 columns), the whole leaves and the layer a scalar: ONE Mosaic
+    call, named for ``sat_paged_read_roofline`` to find, and no other."""
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    slots, bs, MB, L = 8, 64, 176, 2
+    NB = slots * MB + 1
+    row = _sds((slots, NKV, 1, D), jnp.bfloat16, one)
+    pool = _sds((L, NB, bs, NKV, D), jnp.int8, one)
+    plane = _sds((L, NB, NKV * bs), jnp.float32, one)
+
+    def read(q, k, v, ks, vs, tables, lens, layer, kr, vr):
+        return da.paged_decode_int8(q, k, v, ks, vs, tables, lens, layer,
+                                    kv_row=(kr, vr))
+    calls = _mosaic_calls(jax.jit(read).lower(
+        _sds((slots, 1, 48, D), jnp.bfloat16, one), pool, pool, plane, plane,
+        _sds((slots, MB), jnp.int32, one), _sds((slots,), jnp.int32, one),
+        _sds((), jnp.int32, one), row, row).compile())
+    assert [c.split(".")[0] for c in calls] == ["%paged_decode_int8"], calls
+
+
 def test_ssm_instruction_names_at_the_published_sizes(topo, mosaic):
     """Nemotron-3-Nano's Mamba blocks: 64 heads of 64, 8 groups, state 128;
     a 1024-token prompt, and one step over 128 slots whose state pool is

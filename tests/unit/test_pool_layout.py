@@ -694,3 +694,95 @@ def test_nemotrons_step_sorts_and_reads_its_experts_in_place(one_chip,
     for per_block in (f"bf16[{E},{F},{H}]", f"bf16[{E},{H},{F}]"):
         assert not [l for l in hlo.splitlines()
                     if f" = {per_block}" in l and "parameter" not in l], per_block
+
+
+# ---- the int8 pool read by a kernel (ISSUE 50) -------------------------------
+
+def test_trinitys_step_reads_its_full_plane_through_the_kernel(one_chip,
+                                                               monkeypatch):
+    """Trinity-Large at the PUBLISHED widths and the cell's shapes with the
+    read its price chooses (``paged_read_price`` -> "pallas"): the step is
+    handed rectangular tables [64, 176], compiles for the described v5e and
+    fits it, holds ONE ``paged_decode_int8`` call — its one "A" block — and
+    no gather of the listed blocks (the XLA read's two ``s8[11264,64,8,128]``,
+    738 MB each), nor any other array of a whole pool leaf's bytes: the
+    kernel's view of the leaf, ``[L, NB, 512, 128]``, is a bitcast of what
+    the chip stores."""
+    from deepspeed_tpu.ops.decode_attention import paged_read_price
+    cfg, params, pools, srv, S, MB, sds = _hybrid_cell("trinity-large-serve",
+                                                       one_chip)
+    price = paged_read_price(slots=S, MB=MB, block_size=BS,
+                             n_kv=cfg.kv_heads,
+                             rep=cfg.num_heads // cfg.kv_heads,
+                             head_dim=cfg.dim_per_head)
+    assert price["choice"] == "pallas", price
+    srv.decode_backend = price["choice"]
+    fn = jax.jit(srv._quantum_step_fn().__wrapped__, donate_argnums=(1, 4))
+    args = (params, pools, sds((S,), jnp.int32), sds((S, MB), jnp.int32),
+            sds((S,), jnp.int32), sds((S,), jnp.bool_), sds((2,), jnp.uint32))
+    compiled = _compiled_for_the_chip(fn, args, monkeypatch)
+    hlo, mem = compiled.as_text(), compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
+    calls = [l for l in hlo.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in l
+             and "%paged_decode_int8" in l]
+    assert len(calls) == 1, len(calls)
+    assert f"s8[{S * MB},{BS},8,{HD}]" not in hlo
+    in_place = _dus_fusions(hlo)
+    whole = {l.strip().split(" = ")[0]: l for l in hlo.splitlines()}
+
+    def callee(line):              # `line` is cut short: find it whole
+        m = re.search(r"calls=%([\w.\-]+)", whole[line.split(" = ")[0]])
+        return m and m.group(1)
+    leaves = {"k": pools["k"], "wk": pools["wk"][0]}
+    bad = [line for line in whole_pool_ops(hlo, leaves)
+           if callee(line) not in in_place]
+    assert not bad, "\n".join(b[:300] for b in bad)
+    # the XLA read's step at the whole table holds 1.18 GiB of temporaries
+    # (the two gathers, the float32 view); this one 0.62 GiB, the rings'
+    # and the experts' own and the table's scale rows in the kernel's order
+    print(f"temporaries {mem.temp_size_in_bytes / 2**20:.0f} MiB")
+    assert mem.temp_size_in_bytes < 0.75 * 2**30, mem.temp_size_in_bytes
+
+
+# the serve cells' engines (benchmark/configs/*-serve.json: slots, table
+# columns at 64-token blocks, kv heads, query heads a kv head, head dim), the
+# read each one's rule chooses and what decides it. The three whose
+# configuration pins ``"expect": {"decode_backend": "xla"}`` keep XLA: Mixtral
+# and OLMoE by the two prices, chat — which the prices alone would hand the
+# kernel, by 0.06 ms — because a quarter of its table is under one DMA wave
+@pytest.mark.parametrize("cell,shape,choice,by", [
+    ("trinity-large-serve", (64, 176, 8, 6, 128), "pallas", "price"),
+    ("mistral-7b-serve", (48, 32, 8, 4, 128), "xla", "wave"),
+    ("mixtral-8x7b-serve", (32, 32, 8, 4, 128), "xla", "price"),
+    ("olmoe-1b-7b-serve", (32, 16, 16, 1, 128), "xla", "price"),
+    ("ouro-2.6b-serve", (16, 20, 16, 1, 128), "xla", "price"),
+    ("nemotron-3-nano-30b-serve", (128, 40, 2, 16, 128), "xla", "layout"),
+    ("qwen3-next-80b-a3b-serve", (128, 40, 2, 8, 256), "xla", "layout")])
+def test_the_price_of_the_read_at_each_serve_cell(cell, shape, choice, by):
+    """No chip and no compile: the rule is arithmetic on the engine's
+    shapes, and the shapes are the configuration files'."""
+    import json
+    import os
+    from deepspeed_tpu.ops.decode_attention import (CHUNK, PRICED_FILL,
+                                                    READ_TIE_BYTES,
+                                                    paged_read_price)
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs", cell + ".json")) as f:
+        conf = json.load(f)
+    S, MB, G, rep, D = shape
+    serving = conf["run"]["serving"]
+    assert (serving["max_seqs"], serving["max_model_len"] // BS) == (S, MB)
+    assert conf["num_key_value_heads"] == G
+    assert conf["num_attention_heads"] == G * rep
+    assert conf.get("head_dim", conf["hidden_size"] // (G * rep)) == D
+    price = paged_read_price(slots=S, MB=MB, block_size=BS, n_kv=G, rep=rep,
+                             head_dim=D, num_blocks=serving.get("num_blocks"))
+    assert price["choice"] == choice, price
+    assert conf["run"]["expect"].get("decode_backend") in (None, choice)
+    cheaper = price["kernel_bytes"] + READ_TIE_BYTES < price["xla_bytes"]
+    a_wave = PRICED_FILL * MB >= CHUNK
+    assert (choice == "pallas") == (G % 8 == 0 and a_wave and cheaper), price
+    decided_by = ("layout" if G % 8 else
+                  "wave" if cheaper and not a_wave else "price")
+    assert decided_by == by, price

@@ -867,6 +867,49 @@ def test_backend_selection_event_and_reason():
     assert srv3.backend_bench["reason"] == "non-TPU backend"
 
 
+@pytest.mark.parametrize("slots,max_model_len,n_kv,want,why", [
+    (16, 8192, 8, "pallas", "the kernel is the cheaper read"),
+    (16, 4096, 8, "xla", "the XLA read is cheaper"),  # the call's fixed cost
+    (64, 3072, 8, "xla", "under one wave"),     # a quarter table: 12 blocks
+    (16, 8192, 2, "xla", "head-major")])
+def test_an_int8_pools_read_is_priced_not_timed(monkeypatch, slots,
+                                                max_model_len, n_kv, want,
+                                                why):
+    """``decode_backend: "auto"`` on an int8 pool, asked as on the chip: the
+    choice is ``paged_read_price`` at the engine's shapes — nothing is timed
+    — and the event and ``stats()`` carry the two prices and the choice."""
+    from deepspeed_tpu.inference import serving as serving_mod
+    from deepspeed_tpu.robustness import events
+
+    def timed(*a, **k):
+        raise AssertionError("an int8 pool's read was timed")
+    monkeypatch.setattr(serving_mod, "measure_paged_backends", timed)
+    model = make_model(_cfg(hidden_size=1024, num_layers=1, num_heads=8,
+                            num_kv_heads=n_kv, head_dim=128,
+                            max_seq_len=max_model_len))
+    events.clear()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        srv = deepspeed_tpu.init_serving(
+            model, config={"kv_cache_bits": 8}, dtype=jnp.float32,
+            serving=dict(max_seqs=slots, block_size=64, num_blocks=130,
+                         max_model_len=max_model_len, prompt_bucket=64))
+    finally:
+        monkeypatch.undo()
+    assert srv.decode_backend == want, srv.backend_bench
+    ev = events.history("decode_backend_selected")[-1]
+    assert ev["backend"] == want and why in ev["reason"], ev
+    assert ev["xla_bytes"] > 0 and ev["kernel_bytes"] > 0
+    assert ev["priced"] == want
+    st = srv.stats()
+    assert st["decode_backend"] == want
+    assert st["decode_backend_choice"]["kernel_bytes"] == ev["kernel_bytes"]
+    # the kernel's step has ONE program a slot count, at the table's width
+    if want == "pallas":
+        assert list(srv._step_shapes()) == [(16, srv.MB)]
+    srv.close()
+
+
 def test_kv_cache_bits_default_is_context_aware():
     """The r5 regression fix: short-context engines keep the compute-dtype
     cache (decode there is op-latency bound; blanket int8 cost the ctx-256
