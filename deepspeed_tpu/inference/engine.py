@@ -182,12 +182,24 @@ class InferenceEngine:
         # measured crossover ~1k positions on v5e (see InferenceConfig).
         if is_tf:
             kvb = config.kv_cache_bits
+            # the long-context default is a rule about per-head K/V planes;
+            # a model whose planes are LATENT rows (a normalised latent
+            # beside a rotary key, one row for every head) keeps them in
+            # the float dtype: no int8 recipe for such a row exists here
+            latent = bool(model.config.latent_planes)
             if kvb is None:
-                kvb = 8 if int(config.max_tokens or 0) >= 1024 else 0
+                kvb = 8 if (int(config.max_tokens or 0) >= 1024
+                            and not latent) else 0
             kvb = int(kvb)
             if kvb not in (0, 8):
                 raise ValueError(f"kv_cache_bits={kvb} unsupported "
                                  "(0 = float cache, 8 = int8)")
+            if kvb and latent:
+                raise ValueError(
+                    "kv_cache_bits=8 on a model with latent attention: its "
+                    "cache is one row a token of a normalised latent and a "
+                    "rotary key, shared by every head, and it is kept in the "
+                    "pool's float dtype (leave kv_cache_bits unset, or 0)")
             if model.config.kv_cache_bits != kvb:
                 import dataclasses as _dc
                 from deepspeed_tpu.models import make_model as _mk

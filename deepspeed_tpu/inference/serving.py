@@ -577,6 +577,12 @@ class ServingEngine:
                 (self.tp > 1, "a tensor-parallel pool")):
             if armed:
                 self._by_blocks_alone(what)
+        if self.tp > 1 and getattr(mcfg, "latent_planes", 0):
+            raise ValueError(
+                f"tensor parallel degree {self.tp} over latent attention: "
+                "every head reads the one latent row a token keeps, so "
+                "neither the pool nor the absorbed read splits over heads "
+                "here yet")
         # what the plain rounds read of the model's window rings
         # (``model.ring_rows`` rows each; reset_stats windows)
         self._win = {"slot_rounds": 0, "rows_in_window": 0}
@@ -691,6 +697,9 @@ class ServingEngine:
         # the per-slot recurrent state's dtype: that of the first per-slot
         # leaf that is no ring (None for a model without one)
         rings = ring_leaves(model, self.pools)
+        # the block pool's dtype: that of its first leaf that is no slot's
+        leaf = self._block_leaf()
+        self.kv_pool_dtype = None if leaf is None else str(leaf.dtype)
         self.state_pool_dtype = next(
             (str(self.pools[k].dtype) for k in self._slot_state
              if k not in rings), None)
@@ -885,6 +894,16 @@ class ServingEngine:
                 "byte-identical continuation is only guaranteed on a "
                 "matching mesh geometry (place it on a survivor with the "
                 "same tp/ep degrees)")
+
+    @property
+    def max_seqs(self) -> int:
+        return self.config.max_seqs
+
+    def _block_leaf(self):
+        """The first leaf of the pool that belongs to no slot: a plane of
+        the block pool (None for a model that keeps no blocks)."""
+        return next((a for k, a in self.pools.items()
+                     if k not in self._slot_state), None)
 
     def _by_blocks_alone(self, what: str) -> None:
         """THE rule for what this engine refuses (``SlotStateUnsupported``):
@@ -1121,7 +1140,10 @@ class ServingEngine:
         the XLA read moves against the bytes ``paged_decode_int8`` moves and
         its fixed costs, the constants fitted on the chip — and the kernel
         takes the step where it is the cheaper read by more than the tie
-        band. Nothing is timed. A float pool keeps the other kernel
+        band. Nothing is timed. A pool of LATENT rows (one row a token for
+        every head) is priced the same way, by ``ops/latent_decode.
+        latent_read_price``, its kernel ``latent_decode``. A float pool of
+        per-head K/V keeps the other kernel
         (``paged_decode_attention``) and the micro-bench that times it
         against the XLA gather on this engine's pool shapes. Non-TPU
         backends keep XLA (interpret-mode Pallas is not a serving path), and
@@ -1132,6 +1154,8 @@ class ServingEngine:
         import jax.numpy as jnp
         from deepspeed_tpu.ops.decode_attention import (int8_kernel_fits,
                                                         paged_read_price)
+        from deepspeed_tpu.ops.latent_decode import (
+            kernel_fits as latent_kernel_fits, latent_read_price)
         from deepspeed_tpu.robustness.events import emit
 
         c = self.config
@@ -1156,6 +1180,19 @@ class ServingEngine:
             # rejects. Under a block pattern the windows are the ring
             # blocks' (models/hybrid.py): no paged plane has one
             unavailable = "kernel-unsupported attention variant"
+        elif getattr(mcfg, "latent_planes", 0):
+            # one row a token for every head, V a slice of K: neither of the
+            # per-head kernels' layout, and the float micro-bench below
+            # builds per-head rows. A latent plane's read is PRICED, like an
+            # int8 pool's: the XLA list read against ``ops/latent_decode``
+            leaf = self._block_leaf()
+            shapes = dict(block_size=c.block_size, lanes=leaf.shape[-1],
+                          rank=mcfg.kv_lora_rank, itemsize=leaf.dtype.itemsize)
+            if latent_kernel_fits(**shapes):
+                price = latent_read_price(slots=c.max_seqs, MB=self.MB,
+                                          heads=mcfg.num_heads, **shapes)
+            else:
+                unavailable = "latent kernel cannot be built at these shapes"
         elif mcfg.dim_per_head < 64:
             # the deleted contiguous kernel carried the same hardware
             # gate: sub-64 lanes don't lower well through Mosaic
@@ -2538,6 +2575,12 @@ class ServingEngine:
         assembles the full head dim, so tp2->tp2 and tp1->tp1 both ship
         the same bytes; tp CROSSING is refused by _check_geometry for the
         continuation-determinism reason, not here)."""
+        if "k" not in self.pools:
+            raise ResumeIncompatible(
+                "the KV handoff geometry describes per-head K/V planes; this "
+                f"engine's block pool holds {sorted(self.pools)} (latent "
+                "rows), which no payload carries yet — the re-prefill "
+                "migration path recomputes them")
         k = self.pools["k"]              # [planes, NB, bs, nkv, hd]
         mcfg = self.model.config
         # the planes a block carries, and what they are planes OF: a looped
@@ -2572,6 +2615,7 @@ class ServingEngine:
         import jax
         import jax.numpy as jnp
         self._by_blocks_alone("K/V export")
+        geometry = self._kv_geometry()      # refuses a pool it cannot describe
         bs = self.config.block_size
         out: Dict[int, Dict[str, Any]] = {}
         for rid in request_ids:
@@ -2590,7 +2634,7 @@ class ServingEngine:
             data = {name: np.ascontiguousarray(a[:, :n])
                     for name, a in host.items()}
             payload = {"schema": KV_PAYLOAD_SCHEMA, "rows": rows, "blocks": n,
-                       "geometry": self._kv_geometry(),
+                       "geometry": geometry,
                        "data": data, "crc": kv_payload_crc(data)}
             nbytes = kv_payload_nbytes(data)
             self._kv_staging[rid] = nbytes
@@ -3315,6 +3359,12 @@ class ServingEngine:
                 out["exit_step_expected"] = float(
                     np.dot(np.arange(1, p.size + 1), p))
                 out["exit_cdf"] = [float(x) for x in np.cumsum(p)]
+        if getattr(mcfg, "latent_planes", 0):
+            # the planes that are latent rows, and one row's bytes in the pool
+            out["latent_planes"] = float(mcfg.latent_planes)
+            out["latent_row_bytes"] = float(
+                mcfg.latent_row_width
+                * np.dtype(self.engine.dtype).itemsize)
         if self._slot_state:
             out["state_pool_bytes"] = float(state_bytes)
             out["state_slots_live"] = float(len(self.scheduler.running))

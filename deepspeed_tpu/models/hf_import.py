@@ -1288,6 +1288,120 @@ def afmoe_weight_names(cfg) -> Dict[str, tuple]:
     return out
 
 
+def _glm4_moe_lite_kwargs(get) -> dict:
+    """``glm4_moe_lite`` (Z.ai GLM-4.7-Flash; DeepSeek-V3's layer): every
+    layer is a latent-attention block (``L``, ``models/latent_attention.py``:
+    q through ``q_lora_rank`` with an RMSNorm between, K and V from ONE
+    normed row of ``kv_lora_rank`` beside a shared rotary key of
+    ``qk_rope_head_dim``) then a feed-forward block of a hybrid stack: ``D``
+    (SwiGLU of ``intermediate_size``) on the first ``first_k_dense_replace``
+    layers, ``E`` after them — ``noaux_tc``: sigmoid scores, the choice the
+    top ``num_experts_per_tok`` of score + ``e_score_correction_bias``, the
+    weights the scores, divided by their sum (``norm_topk_prob``) and times
+    ``routed_scaling_factor``; ``n_shared_experts`` shared experts as one of
+    that many times ``moe_intermediate_size``. Rotary over all the rope dims
+    in the family's interleaved pairing (2i, 2i + 1). Expert groups, rope
+    scaling, biases and a next-token-prediction module are refused: nothing
+    here computes them (the published forward does not run the module:
+    ``num_nextn_predict_layers`` 0 says it is not there)."""
+    for key, want in (("hidden_act", "silu"), ("rope_scaling", None),
+                      ("topk_method", "noaux_tc"), ("n_group", 1),
+                      ("topk_group", 1), ("partial_rotary_factor", 1),
+                      ("attention_bias", False),
+                      ("num_nextn_predict_layers", 0)):
+        if get(key, want) != want:
+            raise ValueError(f"glm4_moe_lite {key}={get(key)!r} is not "
+                             f"supported (this importer takes {want!r})")
+    for key in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                "qk_rope_head_dim", "v_head_dim"):
+        if not get(key):
+            raise ValueError(f"glm4_moe_lite needs {key}, got {get(key)!r}")
+    if get("num_experts") not in (None, get("n_routed_experts")):
+        raise ValueError(
+            f"glm4_moe_lite num_experts={get('num_experts')!r} beside "
+            f"n_routed_experts={get('n_routed_experts')!r}: the published "
+            "key is n_routed_experts, and every routed expert is held")
+    L, dense = get("num_hidden_layers"), get("first_k_dense_replace", 0)
+    pattern = "".join("L" + ("D" if i < dense else "E") for i in range(L))
+    heads = get("num_attention_heads")
+    if get("num_key_value_heads", heads) != heads:
+        raise ValueError("glm4_moe_lite: latent attention expands K and V "
+                         "for every query head (num_key_value_heads = "
+                         "num_attention_heads)")
+    return dict(
+        vocab_size=get("vocab_size"), hidden_size=get("hidden_size"),
+        num_layers=len(pattern), block_pattern=pattern, num_heads=heads,
+        head_dim=get("qk_nope_head_dim") + get("qk_rope_head_dim"),
+        q_lora_rank=get("q_lora_rank"), kv_lora_rank=get("kv_lora_rank"),
+        qk_nope_head_dim=get("qk_nope_head_dim"),
+        qk_rope_head_dim=get("qk_rope_head_dim"),
+        v_head_dim=get("v_head_dim"),
+        max_seq_len=get("max_position_embeddings", 4096),
+        norm_eps=float(get("rms_norm_eps", 1e-5)),
+        position_type="rotary", rope_theta=float(get("rope_theta", 10000.0)),
+        rotary_interleaved=True,
+        norm_type="rmsnorm", activation="silu_glu",
+        tie_embeddings=bool(get("tie_word_embeddings", False)),
+        # `moe_intermediate_size` is ONE expert's width, `intermediate_size`
+        # a dense layer's
+        intermediate_size=get("moe_intermediate_size"),
+        dense_ffn_size=get("intermediate_size"),
+        num_experts=get("n_routed_experts"), top_k=get("num_experts_per_tok"),
+        moe_scoring="sigmoid", norm_topk_prob=bool(get("norm_topk_prob", True)),
+        routed_scaling_factor=float(get("routed_scaling_factor", 1.0)),
+        moe_shared_size=get("moe_intermediate_size")
+        * get("n_shared_experts", 0),
+        drop_tokens=False, use_residual=False, moe_aux_loss_weight=0.0)
+
+
+def glm4_moe_lite_weight_names(cfg) -> Dict[str, tuple]:
+    """The tensors of an HF ``glm4_moe_lite`` checkpoint of ``cfg``'s shape,
+    by the names ``modeling_glm4_moe_lite`` registers them under -> where each
+    lives in the hybrid tree, as ``afmoe_weight_names`` gives them: ``(kind,
+    block index within its kind, leaf, part)`` — ``part`` the expert for an
+    expert stack, else None. Every matrix is stored transposed ([in, out]) but
+    the experts' up projection (``moe_w_in_t`` keeps HF's [F, H]); the
+    ``n_shared_experts`` shared experts are ONE module of their summed width.
+    The next-token-prediction layer's tensors (``model.layers.<num_hidden_
+    layers>.*``) have no place: HF's classes ignore them at load too."""
+    from deepspeed_tpu.models import hybrid
+    out = {"model.embed_tokens.weight": (None, 0, "tok_embed", None),
+           "model.norm.weight": (None, 0, "final_norm_scale", None),
+           "lm_head.weight": (None, 0, "lm_head", None)}
+    blocks = hybrid.blocks(cfg)
+    for layer in range(len(blocks) // 2):
+        pre = f"model.layers.{layer}."
+        (akind, aj), (fkind, fj) = blocks[2 * layer], blocks[2 * layer + 1]
+        for name, leaf in (("input_layernorm", "ln_scale"),
+                           ("self_attn.q_a_proj", "wq_a"),
+                           ("self_attn.q_a_layernorm", "q_a_norm"),
+                           ("self_attn.q_b_proj", "wq_b"),
+                           ("self_attn.kv_a_proj_with_mqa", "wkv_a"),
+                           ("self_attn.kv_a_layernorm", "kv_a_norm"),
+                           ("self_attn.kv_b_proj", "wkv_b"),
+                           ("self_attn.o_proj", "wo")):
+            out[pre + name + ".weight"] = (akind, aj, leaf, None)
+        out[pre + "post_attention_layernorm.weight"] = (fkind, fj, "ln_scale",
+                                                        None)
+        if fkind == "dense":
+            for name, leaf in (("gate_proj", "w_gate"), ("up_proj", "w_in"),
+                               ("down_proj", "w_out")):
+                out[pre + f"mlp.{name}.weight"] = (fkind, fj, leaf, None)
+            continue
+        out[pre + "mlp.gate.weight"] = (fkind, fj, "wg", None)
+        out[pre + "mlp.gate.e_score_correction_bias"] = (fkind, fj, "e_bias",
+                                                         None)
+        for name, leaf in (("gate_proj", "w_gate"), ("up_proj", "w_in"),
+                           ("down_proj", "w_out")):
+            out[pre + f"mlp.shared_experts.{name}.weight"] = (
+                fkind, fj, "shared_" + leaf, None)
+            stack = "moe_w_in_t" if leaf == "w_in" else "moe_" + leaf
+            for e in range(cfg.num_experts):
+                out[pre + f"mlp.experts.{e}.{name}.weight"] = (fkind, fj,
+                                                               stack, e)
+    return out
+
+
 def _rope_table(kind: str, params: dict):
     """One entry of a published ``rope_parameters`` group -> a ``RopeTable``
     (``rope_type`` ``default`` or ``yarn``; ``attention_factor`` as
@@ -1528,6 +1642,8 @@ def hf_config_to_transformer(hf_cfg, **overrides):
         kw = _afmoe_kwargs(get)
     elif mt == "mellum":
         kw = _mellum_kwargs(get)
+    elif mt == "glm4_moe_lite":
+        kw = _glm4_moe_lite_kwargs(get)
     elif mt == "opt":
         if get("word_embed_proj_dim", get("hidden_size")) != get("hidden_size"):
             raise ValueError(

@@ -6,7 +6,8 @@ blocks each, a token mixer then a feed-forward): ``M`` a Mamba-2 mixer
 (``models/mamba.py``), ``G`` a Gated DeltaNet mixer
 (``models/gated_deltanet.py``), ``E`` an expert feed-forward
 (``moe/sharded_moe.py``), ``D`` a dense feed-forward, ``*`` attention over
-the whole history, ``W`` attention over the last ``window(cfg)`` positions.
+the whole history, ``W`` attention over the last ``window(cfg)`` positions,
+``L`` multi-head latent attention (``models/latent_attention.py``).
 Every block is ``h <- h + mixer(RMSNorm(h))`` — through a second RMSNorm
 after the mixer where the stack has one (``sandwich_norm``) —: the walker
 puts no feed-forward after attention and no attention before an expert layer,
@@ -25,7 +26,8 @@ a hybrid model is a ``make_model`` like any other and serves through the same
 engine.
 
 Parameters are stacked PER KIND (``params["layers"]["mamba" | "gdn" | "moe"
-| "dense" | "attn" | "wattn"]``, leading dim = blocks of that kind). The walk
+| "dense" | "attn" | "wattn" | "latent"]``, leading dim = blocks of that
+kind). The walk
 over a pattern that does not repeat is unrolled: a block's index within its
 kind is a Python int, its slice of a stack a static one. A pattern that
 repeats (three periods of ``GEGEGE*E``) is a ``lax.scan`` over its repeats
@@ -33,8 +35,11 @@ with one unit unrolled in the body and the indices traced (``_walk``). Either
 way a program is shaped by the pool and table dims only.
 
 The cache is three kinds of state side by side in one tree (the serving
-engine's ``srv.pools``): the K/V block pool, whose layer dim counts the ``*``
-blocks only; a per-slot state pool for the recurrent blocks — ``ssm`` float32
+engine's ``srv.pools``): the block pool — ``k`` / ``v``, whose layer dim
+counts the ``*`` blocks only (absent without one), and ``latent`` [planes, NB,
+block, stored width], ONE row a token and ``L`` block that is both K and V
+(``latent_leaf``), paged by the same tables and blocks —; a per-slot state
+pool for the recurrent blocks — ``ssm`` float32
 ``[Lm, slots, heads, P, N]`` and ``conv`` ``[Lm, slots, K - 1, conv_dim]``
 (the last K - 1 rows of ``xBC`` before the convolution) for the ``M`` blocks,
 ``gdn`` float32 ``[Lg, slots, value heads, dk, dv]`` and ``gdn_conv`` ``[Lg,
@@ -55,11 +60,12 @@ import jax.numpy as jnp
 from jax import lax
 
 from deepspeed_tpu.models import gated_deltanet as gdn
+from deepspeed_tpu.models import latent_attention as latent
 from deepspeed_tpu.models import mamba
 from deepspeed_tpu.moe import sharded_moe as _moe
 
 KINDS = {"M": "mamba", "G": "gdn", "E": "moe", "*": "attn", "W": "wattn",
-         "D": "dense"}
+         "D": "dense", "L": "latent"}
 # the kinds whose blocks are softmax attention: "attn" keeps every position in
 # the K/V block pool, "wattn" the last ``window(cfg)`` in a ring per slot
 ATTN_KINDS = ("attn", "wattn")
@@ -84,7 +90,8 @@ def blocks(cfg):
             raise ValueError(
                 f"block_pattern letter {letter!r}: one of {sorted(KINDS)} "
                 "(M Mamba-2, G Gated DeltaNet, E experts, D dense "
-                "feed-forward, * attention, W sliding-window attention)")
+                "feed-forward, * attention, W sliding-window attention, L "
+                "latent attention)")
         kind = KINDS[letter]
         out.append((kind, seen.get(kind, 0)))
         seen[kind] = seen.get(kind, 0) + 1
@@ -249,6 +256,14 @@ def init_params(key, cfg):
                            "w_out": normal((Ld, F, H), out_scale)}
         if "glu" in cfg.activation:
             layers["dense"]["w_gate"] = normal((Ld, H, F))
+    Ll = count(cfg, "latent")
+    if Ll:
+        # drawn last: every other kind keeps the keys it had
+        layers["latent"] = {"ln_scale": jnp.ones((Ll, H), dt)}
+        for name, shape in latent.leaf_shapes(cfg).items():
+            layers["latent"][name] = (
+                norm_scale((Ll,) + shape) if name.endswith("_norm")
+                else normal((Ll,) + shape, out_scale if name == "wo" else std))
     for kind, stacks in layers.items():
         n = stacks["ln_scale"].shape[0]
         if cfg.norm_init_jitter:
@@ -318,6 +333,12 @@ def logical_axes(cfg):
                            "w_out": ("layers", "mlp", "embed")}
         if "glu" in cfg.activation:
             layers["dense"]["w_gate"] = ("layers", "embed", "mlp")
+    if count(cfg, "latent"):
+        # no model-parallel axis: every head reads the one latent row, and
+        # the serving engine refuses a tensor mesh over it
+        layers["latent"] = {"ln_scale": ("layers", "unmodeled")}
+        for name, shape in latent.leaf_shapes(cfg).items():
+            layers["latent"][name] = ("layers",) + (None,) * len(shape)
     if cfg.sandwich_norm:
         for stacks in layers.values():
             stacks["post_ln_scale"] = ("layers", "unmodeled")
@@ -577,6 +598,8 @@ def forward(params, input_ids, cfg, *, deterministic: bool = True,
                     aux_total = aux_total + aux
                 elif kind == "dense":
                     y = _dense_mixer(p, h, cfg)
+                elif kind == "latent":
+                    y = latent.mixer_forward(p, h, cfg)[0]
                 else:
                     y = _attn_mixer(p, h, cfg, kind)[0]
                 return (_residual(p, x, y, cfg), aux_total), (
@@ -604,22 +627,28 @@ def forward(params, input_ids, cfg, *, deterministic: bool = True,
 def init_paged_cache(cfg, num_blocks: int, block_size: int, dtype=None,
                      max_seqs: Optional[int] = None):
     """``k``, ``v`` (+ int8 scale planes) exactly as ``transformer.
-    init_paged_cache`` lays them out, over the "*" ATTENTION blocks only,
+    init_paged_cache`` lays them out, over the "*" ATTENTION blocks only
+    (none: no such leaves), ``latent`` over the "L" blocks (``latent_leaf``),
     and the per-slot leaves of ``state_leaves`` for ``max_seqs`` slots:
     ``ssm`` / ``conv`` (the ``M`` blocks), ``gdn`` / ``gdn_conv`` (the ``G``
     blocks), ``wk`` / ``wv`` (+ scales: the ``W`` blocks' rings), each only
     where the pattern has such blocks."""
     import dataclasses
     from deepspeed_tpu.models import transformer as tf
-    if max_seqs is None:
+    if max_seqs is None and (cfg.recurrent_blocks or cfg.window_blocks):
         raise ValueError("a model with recurrent or window blocks keeps a "
                          "state per serving slot: init_paged_cache needs "
                          "max_seqs")
     dtype = dtype or cfg.dtype
-    pools = tf.init_paged_cache(
-        dataclasses.replace(cfg, block_pattern=None, attn_windows=None,
-                            num_layers=count(cfg, "attn")),
-        num_blocks, block_size, dtype=dtype)
+    pools = {}
+    if count(cfg, "attn"):
+        pools = tf.init_paged_cache(
+            dataclasses.replace(cfg, block_pattern=None, attn_windows=None,
+                                num_layers=count(cfg, "attn")),
+            num_blocks, block_size, dtype=dtype)
+    if cfg.latent_planes:
+        pools["latent"] = jnp.zeros(*latent_leaf(cfg, num_blocks, block_size,
+                                                 dtype))
     for name, (shape, leaf_dtype) in state_leaves(cfg, max_seqs,
                                                   dtype).items():
         pools[name] = jnp.zeros(shape, leaf_dtype)
@@ -646,6 +675,24 @@ def state_leaves(cfg, max_seqs: int, dtype=None) -> dict:
         out["gdn"] = ((Lg, max_seqs, Hv, dk, dv), jnp.float32)
         out["gdn_conv"] = ((Lg, max_seqs, K - 1, conv_dim), dtype)
     return out
+
+
+def latent_leaf(cfg, num_blocks: int, block_size: int, dtype=None):
+    """(shape, dtype) of the "L" blocks' pool leaf: ``latent`` [planes, NB,
+    block, stored width], ONE row a token and block — the normed latent and
+    the rotary key, both K and V of every head, in whole lane tiles
+    (``latent_attention.stored_width``) — token-major like ``k`` / ``v``
+    (block 0 the trash block), so the row a step writes is scattered in
+    place. It is kept in the POOL dtype: nothing here quantises a normalised
+    latent beside a rotary key (``kv_cache_bits`` 8 is refused)."""
+    if cfg.kv_cache_bits:
+        raise NotImplementedError(
+            f"kv_cache_bits={cfg.kv_cache_bits} on a model with latent "
+            "attention: its cache row is a normalised latent beside a rotary "
+            "key, kept in the pool's float dtype; no int8 recipe for it "
+            "exists here")
+    return ((cfg.latent_planes, num_blocks, block_size,
+             latent.stored_width(cfg)), dtype or cfg.dtype)
 
 
 def ring_leaves(cfg, max_seqs: int, dtype=None) -> dict:
@@ -677,8 +724,12 @@ def ring_leaves(cfg, max_seqs: int, dtype=None) -> dict:
 def paged_cache_logical_axes(cfg):
     from deepspeed_tpu.models import transformer as tf
     import dataclasses
-    out = tf.paged_cache_logical_axes(
-        dataclasses.replace(cfg, block_pattern=None, attn_windows=None))
+    out = {}
+    if count(cfg, "attn"):
+        out = tf.paged_cache_logical_axes(
+            dataclasses.replace(cfg, block_pattern=None, attn_windows=None))
+    if cfg.latent_planes:         # one row for every head: nothing to split
+        out["latent"] = (None,) * 4
     for name, (shape, _) in state_leaves(cfg, 1).items():
         out[name] = (None,) * len(shape)
     for name, (shape, _) in ring_leaves(cfg, 1).items():
@@ -830,19 +881,38 @@ def _set_rings(state, rings):
 
 
 def prefill_paged(params, input_ids, cfg, pools, block_ids,
-                  length: Optional[int] = None, slot=None):
+                  length: Optional[int] = None, slot=None, segments=None):
     """Prefill ONE request into ``slot``: K/V of the attention blocks into
     the slot's blocks, the recurrent blocks' state after the last true
     position into the slot's rows of the state pool. input_ids [1, P], P a
-    multiple of the block size. Returns (last logits [1, V], pools)."""
-    from deepspeed_tpu.models.transformer import (_quant_kv,
+    multiple of the block size. Returns (last logits [1, V], pools).
+
+    A stack that keeps nothing per slot (its attention all "L" blocks) takes
+    no ``slot``, and ``segments=(starts [K], lengths [K])`` in place of
+    ``length`` as ``transformer.prefill_paged`` does: SEVERAL requests in the
+    row, each from a block's edge, attending to itself alone and counting its
+    positions from its start; ``block_ids`` their blocks one request after
+    the other. Returns (last logits [K, V], pools)."""
+    from deepspeed_tpu.models.transformer import (_packed_row, _quant_kv,
                                                   _write_prefill_blocks)
-    if slot is None:
+    slotted = bool(cfg.slot_state_blocks)
+    if slotted and slot is None:
         raise ValueError("a model with recurrent blocks prefills INTO a "
                          "slot: prefill_paged needs slot=")
     B, P = input_ids.shape
     assert B == 1, "prefill_paged serves one request"
-    true_len = jnp.asarray(P if length is None else length, jnp.int32)
+    packed = {}
+    if segments is None:
+        true_len = jnp.asarray(P if length is None else length, jnp.int32)
+    else:
+        if slotted or count(cfg, "attn") or length is not None:
+            raise NotImplementedError(
+                "segments share ONE row and take the place of length; a "
+                "stack with recurrent, window or per-head attention blocks "
+                "prefills one prompt a row")
+        starts, lengths = (jnp.asarray(a, jnp.int32) for a in segments)
+        seg, pos, real, _, last_rows = _packed_row(starts, lengths, P)
+        packed = {"segment_ids": seg, "positions": pos}
     pools = dict(pools)
 
     def block(i, kind, j, p, carry):
@@ -868,13 +938,16 @@ def prefill_paged(params, input_ids, cfg, pools, block_ids,
                 y, _ = _moe_mixer(p, h, cfg)
             elif kind == "dense":
                 y = _dense_mixer(p, h, cfg)
+            elif kind == "latent":
+                y, out = latent.mixer_forward(p, h, cfg, **packed)
             else:
                 y, k, v = _attn_mixer(p, h, cfg, kind)
                 out = (jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2))
             return (_residual(p, x, y, cfg), state), out   # [1, nkv, P, hd]
 
     x = _embed(params, input_ids, cfg)                            # [1, P, H]
-    real = jnp.arange(P)[None] < true_len
+    if segments is None:
+        real = jnp.arange(P)[None] < true_len
     with _moe.counted_tokens(real):
         (x, state), kv = _walk(
             params, cfg,
@@ -895,6 +968,16 @@ def prefill_paged(params, input_ids, cfg, pools, block_ids,
                 _quant_kv(cache["k"]), _quant_kv(cache["v"])
         pools.update(_write_prefill_blocks(pools, block_ids, cache,
                                            cfg.kv_cache_bits == 8))
+    if "latent" in kv:
+        # the rows of every "L" block, [planes, P, width], as whole blocks
+        with jax.named_scope("attn"), jax.named_scope("kv_write"):
+            pool = pools["latent"]
+            rows = latent.as_stored(
+                _stacked(kv["latent"])[:, 0].astype(pool.dtype), cfg)
+            pools["latent"] = pool.at[:, block_ids].set(
+                rows.reshape(rows.shape[0], -1, *pool.shape[2:]))
+    if segments is not None:
+        return _head(params, x[:, last_rows], cfg)[0], pools
     last = lax.dynamic_index_in_dim(x, true_len - 1, axis=1, keepdims=True)
     return _head(params, last, cfg)[:, 0], pools
 
@@ -947,7 +1030,7 @@ def decode_step_paged(params, tokens, cfg, pools, block_tables, seq_lens,
     if active is None:
         active = jnp.ones((S,), jnp.bool_)
     int8_kv = cfg.kv_cache_bits == 8
-    bs = pools["k"].shape[2]
+    bs = pools["latent" if "latent" in pools else "k"].shape[2]
     pools = dict(pools)
     sc = (pools["k_scale"], pools["v_scale"]) if int8_kv else None
 
@@ -969,6 +1052,9 @@ def decode_step_paged(params, tokens, cfg, pools, block_tables, seq_lens,
                 y, _ = _moe_mixer(p, h, cfg)
             elif kind == "dense":
                 y = _dense_mixer(p, h, cfg)
+            elif kind == "latent":
+                y, out = latent.mixer_step(p, h, cfg, pools["latent"],
+                                           block_tables, seq_lens, j, backend)
             else:
                 local = kind == "wattn"
                 q, k, v, gate = _qkv(p, h, cfg, seq_lens[:, None], kind)
@@ -1002,6 +1088,20 @@ def decode_step_paged(params, tokens, cfg, pools, block_tables, seq_lens,
                 _write_ring_rows(ring, seq_lens, active, k, v, cfg)
                 for ring, (k, v) in zip(_rings(state), rows["wattn"])])
     pools.update(state)
+    if "latent" in rows:
+        # every plane's fresh row [S, width] at (block, offset): a whole minor
+        # tile of the token-major leaf, written in place — one scatter a
+        # plane, whose window is a row alone (a window that spans the planes
+        # is answered with a relayout of the whole leaf, in and out, every
+        # step: ``_write_rows``)
+        with jax.named_scope("attn"), jax.named_scope("kv_write"):
+            blk = jnp.where(active, _block_at(block_tables, seq_lens // bs),
+                            0)
+            off = jnp.where(active, seq_lens % bs, 0)
+            pool = pools["latent"]
+            for j, row in enumerate(rows["latent"]):
+                pool = pool.at[j, blk, off].set(row)
+            pools["latent"] = pool
     if "attn" in rows:
         with jax.named_scope("attn"), jax.named_scope("kv_write"):
             blk = jnp.where(active, _block_at(block_tables, seq_lens // bs),

@@ -222,7 +222,21 @@ class TransformerConfig:
     # block, (("attn", RopeTable), ("wattn", RopeTable)): plain on the window
     # blocks, YaRN on the blocks that see the whole history. None: the rule
     # above with the one `rope_theta` (models/hybrid.py rope_table).
+    # "L" (glm4_moe_lite; DeepSeek-V2/V3's multi-head LATENT attention,
+    # models/latent_attention.py): q through a low-rank pair with an RMSNorm
+    # between (`q_lora_rank`), K and V both expanded from ONE normed row of
+    # `kv_lora_rank` a token, beside it one rotary key of `qk_rope_head_dim`
+    # shared by all heads; a head is `qk_nope_head_dim` + `qk_rope_head_dim`
+    # wide in q.k (the softmax scale counts both) and `v_head_dim` in the
+    # output. The CACHE is that row, [c | rope(k_r)], one plane a block that
+    # is both K and V (`latent_planes`, `latent_row_width`), in the block
+    # pool like K/V: it belongs to no slot. 0: no such block.
     block_pattern: Optional[str] = None
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     rope_tables: Optional[Tuple[Tuple[str, RopeTable], ...]] = None
     dense_ffn_size: int = 0
     embed_scale: float = 1.0
@@ -337,6 +351,17 @@ class TransformerConfig:
         return self.recurrent_blocks + self.window_blocks
 
     @property
+    def latent_planes(self) -> int:
+        """Planes of LATENT rows a token keeps in the block pool: one per "L"
+        block of a hybrid stack, each both K and V (0: none)."""
+        return (self.block_pattern or "").count("L")
+
+    @property
+    def latent_row_width(self) -> int:
+        """Values of one latent row: the normed latent and the rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
     def moe_router_width(self) -> int:
         """Experts the router scores: the model's, of which this chip holds
         ``num_experts``."""
@@ -345,18 +370,21 @@ class TransformerConfig:
     @property
     def attention_blocks(self) -> int:
         """Blocks of softmax attention: every layer of a homogeneous stack,
-        the "*" and "W" blocks of a hybrid one."""
+        the "*", "W" and "L" blocks of a hybrid one."""
         if self.block_pattern is None:
             return self.num_layers
-        return self.block_pattern.count("*") + self.window_blocks
+        return (self.block_pattern.count("*") + self.window_blocks
+                + self.latent_planes)
 
     @property
     def kv_planes(self) -> int:
         """Planes of K/V a token keeps IN THE BLOCK POOL: one per pass and
         block that owns K/V there (a "W" block keeps a ring per slot
-        instead: ``window_blocks``). THE number every cache, pool, byte
+        instead: ``window_blocks``; an "L" block a latent row:
+        ``latent_planes``). THE number every cache, pool, byte
         count and handoff geometry is sized by."""
-        return self.ut_steps * (self.attention_blocks - self.window_blocks)
+        return self.ut_steps * (self.attention_blocks - self.window_blocks
+                                - self.latent_planes)
 
     @property
     def dim_per_head(self) -> int:

@@ -257,6 +257,28 @@ def test_paged_decode_int8_instruction_name(topo, mosaic):
     assert [c.split(".")[0] for c in calls] == ["%paged_decode_int8"], calls
 
 
+def test_latent_decode_instruction_name(topo, mosaic):
+    """The latent pool's kernel at GLM-4.7-Flash's heads, row and table (20
+    heads against one 576-wide row stored in 640 lanes, 76 columns), the whole
+    leaf and the plane a scalar: ONE Mosaic call, named for
+    ``sat_mla_read_roofline`` to find, and no other."""
+    from deepspeed_tpu.ops import latent_decode as ld
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    slots, bs, MB, L, lanes = 8, 64, 76, 2, 640
+    NB = slots * MB + 1
+
+    def read(q, pool, tables, lens, layer, row):
+        return ld.latent_decode(q, pool, tables, lens, layer, row, rank=512,
+                                sm_scale=1 / 16)
+    calls = _mosaic_calls(jax.jit(read).lower(
+        _sds((slots, 20, lanes), jnp.bfloat16, one),
+        _sds((L, NB, bs, lanes), jnp.bfloat16, one),
+        _sds((slots, MB), jnp.int32, one), _sds((slots,), jnp.int32, one),
+        _sds((), jnp.int32, one), _sds((slots, lanes), jnp.bfloat16, one)
+    ).compile())
+    assert [c.split(".")[0] for c in calls] == ["%latent_decode"], calls
+
+
 def test_ssm_instruction_names_at_the_published_sizes(topo, mosaic):
     """Nemotron-3-Nano's Mamba blocks: 64 heads of 64, 8 groups, state 128;
     a 1024-token prompt, and one step over 128 slots whose state pool is

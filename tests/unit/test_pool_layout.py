@@ -556,7 +556,7 @@ def test_the_looped_cells_programs_fit_the_chip_and_write_the_pool_in_place(
 
 # ---- window rings beside the pool (ISSUE 44) ---------------------------------
 
-def _hybrid_cell(name, one_chip):
+def _hybrid_cell(name, one_chip, kv_cache_bits=8):
     """A hybrid serve cell's engine at the PUBLISHED widths and the cell's
     own shapes (benchmark/configs/<name>.json), as shapes: -> (cfg, params,
     pools, the engine's jitted functions without an engine — nothing of this
@@ -573,7 +573,8 @@ def _hybrid_cell(name, one_chip):
     serving = conf["run"]["serving"]
     S, MB = serving["max_seqs"], serving["max_model_len"] // BS
     cfg = hf_config_to_transformer(hf, max_seq_len=serving["max_model_len"],
-                                   dtype=jnp.bfloat16, kv_cache_bits=8)
+                                   dtype=jnp.bfloat16,
+                                   kv_cache_bits=kv_cache_bits)
     model = make_model(cfg)
     params = _abstract(jax.eval_shape(lambda: jax.tree.map(
         lambda a: a.astype(jnp.bfloat16), model.init(jax.random.PRNGKey(0)))),
@@ -663,6 +664,63 @@ def test_the_window_cells_programs_fit_the_chip_and_write_the_rings_in_place(
     per_layer = f"bf16[{cfg.num_experts},3072,3072]"
     assert not [l for l in hlo.splitlines()
                 if f" = {per_layer}" in l and "parameter" not in l], per_layer
+
+
+# ---- a latent pool (ISSUE 52) -------------------------------------------------
+
+@pytest.mark.parametrize("kind,backend,width", [
+    ("step", "pallas", 76), ("step", "xla", 38), ("prefill", "xla", 4096)])
+def test_the_latent_cells_programs_fit_the_chip_and_write_the_pool_in_place(
+        kind, backend, width, one_chip, monkeypatch):
+    """GLM-4.7-Flash at the PUBLISHED widths and the cell's shapes
+    (benchmark/configs/glm-4.7-flash-serve.json: 128 slots, one latent leaf
+    of 6 planes x 9 729 blocks of 64 rows stored in 640 lanes) through the
+    engine's own step — the kernel's (rectangular tables) and the list read's
+    (128 slots x 38 columns) — and its longest prefill (4096 tokens, four
+    segments): the program compiles for the described v5e, arguments +
+    temporaries fit the chip's 15.75 GiB, the kernels are in it
+    (``latent_decode`` / ``flash_fwd``, ``moe_gmm``), and NO op outside a
+    fused scatter reads and writes the whole leaf. Two faults a CPU run
+    cannot show were found this way (PR 52): a leaf whose last extent is off
+    the 128 grid (576) is stored by the TPU with the BLOCK index innermost
+    and relayouted around every read and write (4.45 GB of copies a step:
+    ``latent_attention.stored_width``), and a row scatter whose window spans
+    the planes is answered the same way (one scatter a plane)."""
+    cfg, params, pools, srv, S, MB, sds = _hybrid_cell(
+        "glm-4.7-flash-serve", one_chip, kv_cache_bits=0)
+    srv.decode_backend = backend
+    assert set(pools) == {"latent"} and srv._slot_state == 0
+    assert pools["latent"].shape == (6, S * MB + 1, BS, 640)
+    assert pools["latent"].dtype == jnp.bfloat16
+    key = sds((2,), jnp.uint32)
+    if kind == "step":
+        fn = jax.jit(srv._quantum_step_fn().__wrapped__, donate_argnums=(1, 4))
+        tables = (_block_list(sds, S, width, MB) if backend == "xla"
+                  else sds((S, MB), jnp.int32))
+        args = (params, pools, sds((S,), jnp.int32), tables,
+                sds((S,), jnp.int32), sds((S,), jnp.bool_), key)
+    else:
+        fn = jax.jit(srv._get_prefill_fn(width).__wrapped__, donate_argnums=(2,))
+        args = (params, sds((1, width), jnp.int32), pools,
+                sds((width // BS,), jnp.int32), sds((_SEGMENTS,), jnp.int32),
+                sds((_SEGMENTS,), jnp.int32), key)
+    compiled = _compiled_for_the_chip(fn, args, monkeypatch)
+    hlo, mem = compiled.as_text(), compiled.memory_analysis()
+    print(f"{kind} {backend} {width}: arguments "
+          f"{mem.argument_size_in_bytes / 2**30:.2f} GiB, temporaries "
+          f"{mem.temp_size_in_bytes / 2**30:.2f} GiB")
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
+    leaf = "bf16[" + ",".join(map(str, pools["latent"].shape)) + "]"
+    made = [l.strip() for l in hlo.splitlines()
+            if re.search(r"= \(?" + re.escape(leaf), l) and " parameter(" not in l]
+    # the leaf is produced by scatters alone (fused or not), in the layout it
+    # arrives in: no copy, no transpose, no relayout of 4.45 GB
+    assert made and all("scatter" in l or "tuple(" in l for l in made), \
+        "\n".join(l[:200] for l in made)
+    assert not [l for l in made if "{3,2,1,0" not in l], made
+    assert "%moe_gmm" in hlo
+    assert ("%latent_decode" in hlo) == (kind == "step" and backend == "pallas")
+    assert ("%flash_fwd" in hlo) == (kind == "prefill")
 
 
 # ---- a hybrid stack's step that sorts (ISSUE 46) -----------------------------
