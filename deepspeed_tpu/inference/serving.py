@@ -482,6 +482,12 @@ _LAT_COUNTERS = {"spec_steps": 0, "spec_proposed": 0, "spec_accepted": 0,
                  # the prompts that shared a program's row (_pack_prefills)
                  "prefill_prompts": 0, "prefill_programs": 0,
                  "prefill_packed_prompts": 0,
+                 # key tiles of the rows the packed flash forward took, one
+                 # kv head's pass of one layer (ops/flash_attention
+                 # .packed_walk): what its ONE call walks, and a causal
+                 # pass a prompt in the same tiles
+                 "prefill_attn_tiles_walked": 0,
+                 "prefill_attn_tiles_looped": 0,
                  "cow_forks": 0,
                  # plain decode rounds dispatched while the round before was
                  # still unfetched (of the rounds step_shape_rounds counts),
@@ -1654,6 +1660,8 @@ class ServingEngine:
         token pending in the device token vector AND as a per-request
         handle fetched at the round boundary. Returns the padded length."""
         import jax.numpy as jnp
+        from deepspeed_tpu.models.transformer import flash_takes
+        from deepspeed_tpu.ops.flash_attention import packed_walk
         bs = self.config.block_size
         buf = np.zeros((1, P), np.int32)
         # a bucket's first prompt traces and lowers its program: not across
@@ -1697,6 +1705,14 @@ class ServingEngine:
         self._lat["prefill_programs"] += 1
         self._lat["prefill_packed_prompts"] += len(reqs) if len(reqs) > 1 \
             else 0
+        mcfg = self.model.config
+        if not self._slot_state and not mcfg.attn_windows \
+                and flash_takes(mcfg, P):
+            # the row's attention was the packed forward's ONE call a layer
+            walked, causal = packed_walk(starts, lengths, P,
+                                         mcfg.num_heads // mcfg.kv_heads)
+            self._lat["prefill_attn_tiles_walked"] += walked
+            self._lat["prefill_attn_tiles_looped"] += len(reqs) * causal
         return P
 
     def _publish_prefill(self, req: Request, ctx) -> None:
@@ -3214,7 +3230,16 @@ class ServingEngine:
         ``prefill_prompts`` (prompts the prefill program took whole; chunks
         and LoRA spans are ``prefill_chunks``), ``prefill_programs`` (calls
         of it: fewer than the prompts where a round's prompts shared a row)
-        and ``prefill_packed_prompts`` (the prompts that shared one).
+        and ``prefill_packed_prompts`` (the prompts that shared one);
+        ``prefill_attn_tiles_walked`` (counted only for the rows whose
+        attention was the packed flash forward's, ``transformer
+        .flash_takes`` — 0 for an engine with a state per slot, on the XLA
+        path, at a bucket off the kernel's tiles: the key tiles one kv
+        head's pass of ONE layer walks, a shared row's one call only what
+        its segments reach) and ``prefill_attn_tiles_looped`` (a whole
+        causal pass a prompt of those rows, in the SAME tiles: what a call
+        a live segment would walk, derived and never run): their ratio is
+        how far sharing a row spares attention where the kernel runs.
 
         The two kinds of state (always on): ``kv_pool_bytes`` (the K/V block
         pool's share of ``pool_bytes``) and, for a model with recurrent or

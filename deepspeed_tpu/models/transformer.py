@@ -32,7 +32,8 @@ from jax.sharding import PartitionSpec as P
 # through the O(S^2) XLA attention
 from deepspeed_tpu.ops.decode_attention import (paged_decode_attention,
                                                 paged_decode_int8)
-from deepspeed_tpu.ops.flash_attention import flash_attention
+from deepspeed_tpu.ops.flash_attention import (flash_attention,
+                                                flash_attention_packed)
 from deepspeed_tpu.moe import sharded_moe as _moe
 from deepspeed_tpu.models import looped as _looped
 
@@ -766,11 +767,18 @@ def _use_pallas(cfg: TransformerConfig, seq_len: int) -> bool:
     return seq_len % 128 == 0 and cfg.dim_per_head >= 64
 
 
-def _flash_per_shard(q, k, v, mask, **kw):
-    """The flash kernel mapped over the ambient mesh: batch over the dp axes,
-    heads over `tensor` (the kernel is independent over both; same layout
-    as ring_attention's shard_map). See parallel.context.kernel_mesh for
-    why the call cannot stay bare under jit."""
+def flash_takes(cfg: TransformerConfig, seq_len: int) -> bool:
+    """Whether a causal row of ``seq_len`` without a band is the flash
+    kernel's (``_attention_kernel``'s rule; with segment ids it is then the
+    packed forward's, and a serving engine counts its tiles)."""
+    from deepspeed_tpu.parallel.context import seq_parallel_degree
+    return (_use_pallas(cfg, seq_len) and not cfg.sparse_attention
+            and seq_parallel_degree() <= 1)
+
+
+def _flash_shards(q, k):
+    """(mesh, its axes, the dp axes the batch splits over, the heads' axis
+    or None) for a flash call on q / k [B, S, N, D] in the ambient mesh."""
     from deepspeed_tpu.parallel.context import kernel_mesh
     from deepspeed_tpu.parallel.mesh import BATCH_AXES
     mesh, axes = kernel_mesh()
@@ -783,7 +791,15 @@ def _flash_per_shard(q, k, v, mask, **kw):
             f"flash attention under tensor parallelism {tp}: kv_heads="
             f"{k.shape[2]} must divide by it (each chip runs the kernel on "
             "a whole kv-head slice) — use attention_impl='xla'")
-    heads = "tensor" if tp > 1 else None
+    return mesh, axes, batch, "tensor" if tp > 1 else None
+
+
+def _flash_per_shard(q, k, v, mask, **kw):
+    """The flash kernel mapped over the ambient mesh: batch over the dp axes,
+    heads over `tensor` (the kernel is independent over both; same layout
+    as ring_attention's shard_map). See parallel.context.kernel_mesh for
+    why the call cannot stay bare under jit."""
+    mesh, axes, batch, heads = _flash_shards(q, k)
     if not batch and heads is None:
         return flash_attention(q, k, v, kv_mask=mask, **kw)
     from deepspeed_tpu.comm.schedule import shard_map_compat
@@ -796,6 +812,24 @@ def _flash_per_shard(q, k, v, mask, **kw):
         out_specs=spec, manual_axes=axes)(q, k, v, *masks)
 
 
+def _flash_packed_per_shard(q, k, v, mask, segment_ids, sm_scale):
+    """``_flash_per_shard`` for rows that hold several sequences: the packed
+    forward, its ``segment_ids`` [B, S] split with the batch like a mask."""
+    mesh, axes, batch, heads = _flash_shards(q, k)
+    if not batch and heads is None:
+        return flash_attention_packed(q, k, v, segment_ids, kv_mask=mask,
+                                      sm_scale=sm_scale)
+    from deepspeed_tpu.comm.schedule import shard_map_compat
+    spec = P(batch or None, None, heads, None)
+    masks = () if mask is None else (mask,)
+    return shard_map_compat(
+        lambda q, k, v, ids, *m: flash_attention_packed(
+            q, k, v, ids, kv_mask=m[0] if m else None, sm_scale=sm_scale),
+        mesh,
+        in_specs=(spec,) * 3 + (P(batch or None, None),) * (1 + len(masks)),
+        out_specs=spec, manual_axes=axes)(q, k, v, segment_ids, *masks)
+
+
 def attention(q, k, v, mask=None, *, causal: bool = True, cfg: TransformerConfig,
               segment_ids=None, window=None):
     """q: [B,S,Nq,D], k/v: [B,S,Nkv,D] -> [B,S,Nq,D].
@@ -806,14 +840,17 @@ def attention(q, k, v, mask=None, *, causal: bool = True, cfg: TransformerConfig
     (a Python int, causal, no mask or segments) takes the flash kernel's
     banded forward where the flash kernel would run.
 
-    segment_ids: int [B, S], several sequences packed into a row, numbered
-    from 0 — key j is visible to query i only where the two ids are equal
-    (and causality, the band and ``mask`` allow it). The ids are traced, so
-    one program serves a row of one segment and a row of several. On the
-    XLA path they are one select more on the scores; the flash kernel runs
-    once a LIVE segment (``_attention_kernel``), so a row that is all one
-    segment costs what it costs without ids; the ring and sparse kernels
-    mask no keys and leave such rows to the XLA path."""
+    segment_ids: int [B, S], several sequences packed into a row one after
+    the other, numbered from 0 — so the ids NEVER FALL along a row, which
+    every path may rely on (``_packed_row``'s do not) — key j is visible to
+    query i only where the two ids are equal (and causality, the band and
+    ``mask`` allow it). The ids are traced, so one program serves a row of
+    one segment and a row of several. On the XLA path they are one select
+    more on the scores; the flash kernel takes a causal row in ONE
+    forward-only call that walks the key tiles a query tile's segments
+    reach (``flash_attention_packed``), so a row that is all one segment
+    walks the causal tiles it walks without ids; the ring and sparse
+    kernels mask no keys and leave such rows to the XLA path."""
     kernel = _attention_kernel(q, k, v, mask, causal, cfg, segment_ids, window)
     if kernel is not None:
         return kernel()
@@ -837,32 +874,20 @@ def _attention_kernel(q, k, v, mask, causal: bool, cfg: TransformerConfig,
     sm = cfg.attn_scale if cfg.attn_scale is not None else 1.0 / math.sqrt(D)
     if window is not None:
         if (isinstance(window, int) and window > 0 and causal and mask is None
-                and segment_ids is None and _use_pallas(cfg, S)
-                and not cfg.sparse_attention and seq_parallel_degree() <= 1):
+                and segment_ids is None and flash_takes(cfg, S)):
             return lambda: _flash_per_shard(q, k, v, None, causal=True,
                                             sm_scale=sm, window=window)
         return None
     # the Pallas flash kernel is GQA-native (K/V never repeated in HBM) and
     # handles key-padding masks in-kernel; other paths get the repeated view
-    if _use_pallas(cfg, S) and not cfg.sparse_attention \
-            and seq_parallel_degree() <= 1:
-        def flash(keys):
-            return _flash_per_shard(q, k, v, keys, causal=causal, sm_scale=sm,
-                                    fused_backward=cfg.fused_backward)
-
+    if flash_takes(cfg, S):
         if segment_ids is None:
-            return lambda: flash(mask)
-
-        # the kernel masks keys, not pairs: one call a live segment, given
-        # that segment's keys alone, and every row keeps the call of its
-        # own segment. Forward only: the count of live segments is data
-        def one(s, out):
-            mine = segment_ids == s
-            return jnp.where(mine[:, :, None, None],
-                             flash(mine if mask is None else mine & mask), out)
-
-        return lambda: lax.fori_loop(0, jnp.max(segment_ids) + 1, one,
-                                     jnp.zeros_like(q))
+            return lambda: _flash_per_shard(
+                q, k, v, mask, causal=causal, sm_scale=sm,
+                fused_backward=cfg.fused_backward)
+        if causal:      # ONE call, walked over the tiles the segments reach
+            return lambda: _flash_packed_per_shard(q, k, v, mask,
+                                                   segment_ids, sm)
     if segment_ids is not None:
         return None
     # sequence parallelism: ring attention over the seq mesh axis

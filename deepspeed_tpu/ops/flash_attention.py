@@ -28,6 +28,12 @@ each program processes the whole query-head GROUP against one K/V stream —
 K/V are never repeated in HBM and their VMEM loads amortize over the group
 (the naive path repeats K/V n_q/n_kv times).
 
+A row that holds several sequences (a serving prefill's packed row) has a
+forward of its own, ``flash_attention_packed``: ONE call whose walk the
+segments bound — per query tile the key tiles from its first query's segment
+start to the diagonal, as prefetched scalars — so a shared row costs less
+than one causal pass. Forward only.
+
 Backward uses the standard flash decomposition (dQ kernel + joint dK/dV
 kernel) with the forward's log-sum-exp residuals; both are blocked the same
 way (dQ: KV innermost with dQ in scratch; dK/dV: Q innermost with dK/dV in
@@ -588,6 +594,216 @@ _flash_band.defvjp(_flash_band_fwd, _flash_band_bwd)
 
 
 # --------------------------------------------------------------------------
+# forward, packed: several sequences share the row, each attends to itself.
+# Forward only (no custom_vjp, no log-sum-exp) and a body of its own: a
+# serving prefill's, whose count of segments is data
+# --------------------------------------------------------------------------
+
+def _packed_blocks(s: int, rep: int = 1):
+    """The (query, key) tile of the packed forward at a row of ``s``: as
+    many query rows as the scratch holds (``MAX_ROWS`` over the stacked
+    heads) against 1024 keys. Measured on the v5e at 20 heads x 256 and
+    rows of 2048-4096 (PERF.md, PR 53): 1024 x 1024 walks the pairs of 512
+    x 1024 in half the steps, 5-11 % faster alone and shared; 512-key tiles
+    walk fewer empty pairs at the diagonal and lose 7-20 % to their steps."""
+    return _pick_blocks(s, MAX_ROWS, DEFAULT_BLOCK_K, rep)
+
+
+def _diagonal_tile(i, bq: int, bk: int):
+    """The key tile that holds the last position of query tile ``i``."""
+    return ((i + 1) * bq - 1) // bk
+
+
+def _packed_bounds(seg_start, bq: int, bk: int):
+    """What bounds the packed walk, a query tile at a time. ``seg_start``
+    [..., S]: for every position the first position of its segment (so it
+    never falls along the row) — a numpy array on the host, a jax array in
+    the program: operators only. Returns ``(lo, full)`` [..., S // bq]: the
+    first key tile any query of the tile sees, the tile of its FIRST
+    query's segment start (the last is the diagonal's), and the first key
+    position from which every query of the tile sees a key, its LAST
+    query's segment start: a key tile from there on and wholly under the
+    diagonal needs no mask."""
+    return seg_start[..., ::bq] // bk, seg_start[..., bq - 1::bq]
+
+
+def packed_walk(starts, lengths, s: int, rep: int = 1):
+    """Plain integers on the host: (the key tiles the packed forward walks
+    for ONE kv head of a row of ``s`` tokens that holds the segments
+    ``[starts[k], starts[k] + lengths[k])``, the tiles of one causal pass
+    over the whole row). A segment of length 0 is not there, and the rows
+    behind a segment up to the next start are its pad rows
+    (``transformer._packed_row``); a row of one segment walks the causal
+    pass."""
+    import numpy as np
+    bq, bk = _packed_blocks(s, rep)
+    lo, hi = _packed_tiles(starts, lengths, s, bq, bk)
+    return int(np.sum(hi - lo + 1)), int(np.sum(hi + 1))
+
+
+def _packed_tiles(starts, lengths, s: int, bq: int, bk: int):
+    """(first, last) key tile of every query tile of that row, numpy [s //
+    bq] each: what the call's prefetched bounds and its diagonal let
+    through."""
+    import numpy as np
+    seg_start = np.zeros(s, np.int64)
+    for start, n in zip(starts, lengths):
+        if n > 0:
+            seg_start[int(start):] = int(start)
+    return (_packed_bounds(seg_start, bq, bk)[0],
+            _diagonal_tile(np.arange(s // bq), bq, bk))
+
+
+def _packed_kernel(lo_ref, full_ref, q_ref, k_ref, v_ref, end_ref, o_ref,
+                   m_s, l_s, acc_s, *, sm_scale, rep, block_q, block_k):
+    """The online softmax over the key tiles ``lo[qi] .. diagonal`` of query
+    tile ``qi`` alone (the others are skipped, their DMAs too: the index
+    maps clamp them into that range). Key ``k`` is visible to the queries
+    ``k <= q < end[k]``, its segment's rows from itself on: a tile under
+    the diagonal that starts at or behind ``full[qi]`` is wholly visible
+    and takes the body without a mask; one that the diagonal or a segment's
+    edge crosses is masked pair by pair."""
+    b, qi, kj = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    d = q_ref.shape[-1]
+    rows = rep * block_q
+    q_lo, k_lo = qi * block_q, kj * block_k
+    tile = b * pl.num_programs(2) + qi
+
+    @pl.when(kj == 0)
+    def _init():
+        m_s[:] = jnp.full_like(m_s, NEG_INF)
+        l_s[:] = jnp.zeros_like(l_s)
+        acc_s[:] = jnp.zeros_like(acc_s)
+
+    def step(masked: bool):
+        q = q_ref[0, 0].astype(jnp.float32).reshape(rows, d) * sm_scale
+        k = k_ref[0, 0].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        if masked:
+            q_pos = q_lo + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, block_k), 0) % block_q
+            k_pos = k_lo + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, block_k), 1)
+            s = jnp.where((q_pos >= k_pos) & (q_pos < end_ref[0, 0:1, :]), s,
+                          NEG_INF)
+        m = m_s[:, 0:1]
+        l = l_s[:, 0:1]
+        m_new = jnp.maximum(jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True)),
+                            M_FLOOR)
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_s[:] = acc_s[:] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
+        l_s[:] = jnp.broadcast_to(l_new, l_s.shape)
+
+    # wholly under the diagonal and inside the segment of every query
+    inside = (k_lo + block_k - 1 <= q_lo) & (k_lo >= full_ref[tile])
+    visible = (kj >= lo_ref[tile]) & (k_lo <= q_lo + block_q - 1)
+    pl.when(inside)(lambda: step(False))
+    pl.when(visible & jnp.logical_not(inside))(lambda: step(True))
+
+    @pl.when(kj == pl.num_programs(3) - 1)
+    def _finalize():
+        l = l_s[:, 0:1]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0, 0] = (acc_s[:] / l_safe).reshape(rep, block_q, d).astype(
+            o_ref.dtype)
+
+
+def _fwd_packed(q, k, v, segment_ids, kv_mask, sm_scale):
+    """q [B, N, S, D], k / v [B, Nkv, S, D], segment_ids int32 [B, S] -> o,
+    causal within each segment (ids that never fall along a row: a segment
+    is one run of its id), in ONE call whose walk the segments
+    bound (``_packed_bounds``). ``kv_mask`` bool [B, S]: a masked key is
+    visible to nobody, and every tile then takes the masked body."""
+    B, N, S, D = q.shape
+    Nkv = k.shape[1]
+    rep = N // Nkv
+    bq, bk = _packed_blocks(S, rep)
+    nq = S // bq
+    rows = rep * bq
+
+    pos = jnp.arange(S, dtype=jnp.int32)[None]
+    edge = segment_ids[:, 1:] != segment_ids[:, :-1]
+    first = jnp.pad(edge, ((0, 0), (1, 0)), constant_values=True)
+    last = jnp.pad(edge, ((0, 0), (0, 1)), constant_values=True)
+    seg_start = jax.lax.cummax(jnp.where(first, pos, 0), axis=1)
+    seg_end = jax.lax.cummin(jnp.where(last, pos + 1, S), axis=1,
+                             reverse=True)
+    lo, full = _packed_bounds(seg_start, bq, bk)
+    if kv_mask is not None:
+        seg_end = jnp.where(kv_mask, seg_end, 0)
+        full = jnp.full_like(full, S)
+
+    def kv_tile(b, i, j, lo_ref):
+        return jnp.clip(j, lo_ref[b * nq + i], _diagonal_tile(i, bq, bk))
+
+    kv_spec = pl.BlockSpec(
+        (1, 1, bk, D),
+        lambda b, g, i, j, lo_ref, _: (b, g, kv_tile(b, i, j, lo_ref), 0),
+        memory_space=pltpu.VMEM)
+    q_spec = pl.BlockSpec((1, 1, rep, bq, D),
+                          lambda b, g, i, j, *_: (b, g, 0, i, 0),
+                          memory_space=pltpu.VMEM)
+    # the segments' ends [B, 8, S]: a sublane-broadcast copy blocked along
+    # the lanes with K and V, as the key-padding mask of `_fwd` is
+    end_spec = pl.BlockSpec(
+        (1, 8, bk),
+        lambda b, g, i, j, lo_ref, _: (b, 0, kv_tile(b, i, j, lo_ref)),
+        memory_space=pltpu.VMEM)
+    o = pl.pallas_call(
+        functools.partial(_packed_kernel, sm_scale=sm_scale, rep=rep,
+                          block_q=bq, block_k=bk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, Nkv, nq, S // bk),
+            in_specs=[q_spec, kv_spec, kv_spec, end_spec],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((rows, 128), jnp.float32),   # m (lane-padded)
+                pltpu.VMEM((rows, 128), jnp.float32),   # l
+                pltpu.VMEM((rows, D), jnp.float32),     # acc
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, Nkv, rep, S, D), q.dtype),
+        compiler_params=_compiler_params(3),
+        interpret=_interpret(),
+        name="flash_fwd",
+    )(lo.reshape(-1), full.reshape(-1), q.reshape(B, Nkv, rep, S, D), k, v,
+      jnp.broadcast_to(seg_end[:, None, :], (B, 8, S)))
+    return o.reshape(B, N, S, D)
+
+
+def flash_attention_packed(q, k, v, segment_ids, *,
+                           sm_scale: Optional[float] = None, kv_mask=None):
+    """Causal attention over rows that hold several sequences each, q [B, S,
+    Nq, D], k / v [B, S, Nkv, D] -> [B, S, Nq, D]: key j is visible to query
+    i iff j <= i and both carry the same id. ``segment_ids`` int [B, S],
+    traced, must NEVER FALL along a row (``transformer.attention``'s
+    precondition; ``_packed_row``'s do not): a segment is then one run of
+    its id, which is how the walk finds its edges. ONE call of the flash
+    forward, walked over the key tiles that a query tile's segments reach;
+    forward only. ``kv_mask`` [B, S]: a key-padding mask on top."""
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    if q.shape[2] % k.shape[2]:
+        raise ValueError(f"n_q_heads {q.shape[2]} not divisible by "
+                         f"n_kv_heads {k.shape[2]}")
+    if not _interpret() and q.shape[1] % 128:
+        raise ValueError("segment_ids on TPU require seq_len % 128 == 0 "
+                         f"(got {q.shape[1]}): the segments' ends are "
+                         "blocked along the lanes like K and V")
+    o = _fwd_packed(*(jnp.swapaxes(a, 1, 2) for a in (q, k, v)),
+                    jnp.asarray(segment_ids, jnp.int32),
+                    None if kv_mask is None else jnp.asarray(kv_mask, bool),
+                    float(sm_scale))
+    return jnp.swapaxes(o, 1, 2)
+
+
+# --------------------------------------------------------------------------
 # backward
 # --------------------------------------------------------------------------
 
@@ -904,9 +1120,10 @@ def flash_attention(q, k, v, *, causal: bool = True,
 
 def reference_attention(q, k, v, *, causal: bool = True,
                         sm_scale: Optional[float] = None,
-                        window: Optional[int] = None):
+                        window: Optional[int] = None, segment_ids=None):
     """XLA reference for parity tests (handles GQA by repeat); ``window``:
-    key j visible to query i only where i - j < window."""
+    key j visible to query i only where i - j < window; ``segment_ids`` [B,
+    S]: only where the two ids are equal."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     B, S, N, D = q.shape
@@ -923,5 +1140,8 @@ def reference_attention(q, k, v, *, causal: bool = True,
         pos = jnp.arange(S)
         s = jnp.where((pos[:, None] - pos[None, :] < window)[None, None], s,
                       NEG_INF)
+    if segment_ids is not None:
+        same = segment_ids[:, :, None] == segment_ids[:, None, :]
+        s = jnp.where(same[:, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bnst,btnd->bsnd", p, v.astype(jnp.float32)).astype(q.dtype)

@@ -209,6 +209,26 @@ def test_flash_instruction_names_on_one_chip(topo, mosaic):
         "%flash_dkv", "%flash_dq", "%flash_fwd"], calls
 
 
+@pytest.mark.parametrize("cell,heads,kv_heads,dim,row", [
+    ("glm", 20, 20, 256, 4096), ("glm-odd-row", 20, 20, 256, 3584),
+    ("chat", 32, 8, 128, 1024), ("olmoe", 16, 16, 128, 768),
+    ("mixtral-one-tile", 32, 8, 128, 256)])
+def test_the_packed_forward_is_one_flash_fwd_at_the_serve_cells_shapes(
+        cell, heads, kv_heads, dim, row, topo, mosaic):
+    """A shared row's attention (ISSUE 53) at the shapes the serve cells
+    hand it: Mosaic takes the prefetched bounds, the clamped index maps and
+    the segments' ends at every one, and the call keeps the name the
+    benchmark reads (``%flash_fwd``): ONE call, no loop."""
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    q, kv = (_sds((1, row, n, dim), jnp.bfloat16, one)
+             for n in (heads, kv_heads))
+    compiled = jax.jit(fa.flash_attention_packed).lower(
+        q, kv, kv, _sds((1, row), jnp.int32, one)).compile()
+    calls = _mosaic_calls(compiled)
+    assert [c.split(".")[0] for c in calls] == ["%flash_fwd"], calls
+    assert " while(" not in compiled.as_text()
+
+
 def test_flash_instruction_names_under_a_mesh(topo, mosaic):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("fsdp", "tensor"))

@@ -27,6 +27,7 @@ from deepspeed_tpu.models import TransformerConfig, make_model
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.models.looped import exit_tap
 from deepspeed_tpu.moe.sharded_moe import expert_load_tap
+from deepspeed_tpu.ops.flash_attention import packed_walk
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BS = 16
@@ -250,7 +251,8 @@ def test_attention_does_not_cross_segments(form):
     """``attention``'s XLA branch used the ids only to leave the flash path:
     two segments in a row attended across. Against each segment alone.
     ``kernel``: where the flash kernel takes a row (here in interpret mode)
-    it runs once a live segment, over that segment's keys."""
+    it is ONE call that walks the tiles the segments reach (ISSUE 53;
+    tests/unit/test_flash_packed.py), a key mask on top."""
     cfg = TransformerConfig(vocab_size=32, hidden_size=64, num_layers=1,
                             num_heads=4, num_kv_heads=2 if form == "gqa" else 4,
                             dtype=jnp.float32,
@@ -322,6 +324,10 @@ def compiles():
 
 ENGINES = {
     "gqa-dense": lambda: _family("mistral-7b-serve"),
+    # the flash kernel (interpret mode here) takes every row: the only one
+    # of these engines whose attention tiles are counted
+    "gqa-dense-kernel": lambda: _family("mistral-7b-serve",
+                                        attention_impl="pallas"),
     "experts": lambda: _family("olmoe-1b-7b-serve"),
     "looped": lambda: _family("ouro-2.6b-serve"),
 }
@@ -362,12 +368,29 @@ def test_three_admissions_of_one_round_run_as_one_program(name, compiles,
     assert tuple(int(st[k]) for k in (
         "prefill_prompts", "prefill_programs",
         "prefill_packed_prompts")) == (3, 1, 3)
+    # the row's attention, in key tiles of one kv head's pass (ISSUE 53),
+    # counted where the packed flash forward took the row and nowhere else:
+    # the ONE call walks what the three segments reach, where a causal pass
+    # a prompt walked three times the whole row
+    kernel = cfg.attention_impl == "pallas"
+    rep = cfg.num_heads // cfg.kv_heads
+    walked, causal = packed_walk([0, 48, 80, 0], [37, 20, 5, 0], 96, rep)
+    assert walked < causal
+    assert (int(st["prefill_attn_tiles_walked"]),
+            int(st["prefill_attn_tiles_looped"])) == (
+                (walked, 3 * causal) if kernel else (0, 0))
 
     monkeypatch.setattr(serving, "_SEGMENTS", 1)
     *_, record, alone, st1 = drive(_serve(cfg))
     assert (record["prefills"], record["prefill_programs"],
             record["prefill_tokens"]) == (3, 3, 64 + 32 + 32)
     assert int(st1["prefill_packed_prompts"]) == 0
+    # a prompt alone walks its bucket's causal pass, as it did
+    alone_tiles = sum(packed_walk([0], [n], P, rep)[1]
+                      for n, P in ((37, 64), (20, 32), (5, 32)))
+    assert (int(st1["prefill_attn_tiles_walked"]),
+            int(st1["prefill_attn_tiles_looped"])) == (
+                alone_tiles if kernel else 0,) * 2
     for a, b in zip(packed, alone):
         np.testing.assert_array_equal(a, b)
     # every sum over requests is still a sum over programs

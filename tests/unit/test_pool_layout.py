@@ -721,6 +721,16 @@ def test_the_latent_cells_programs_fit_the_chip_and_write_the_pool_in_place(
     assert "%moe_gmm" in hlo
     assert ("%latent_decode" in hlo) == (kind == "step" and backend == "pallas")
     assert ("%flash_fwd" in hlo) == (kind == "prefill")
+    if kind == "prefill":
+        # a row of up to four prompts is attended ONCE (ISSUE 53): one call
+        # of the flash forward a latent layer, and in no loop's body (the
+        # parent ran a `while` over the live segments around each)
+        call = re.compile(r"%flash_fwd[.\d]* = ")
+        assert len(call.findall(hlo)) == cfg.latent_planes == 6
+        holders = {name for name, lines in _computations(hlo)[0].items()
+                   if any(call.search(l) for l in lines)}
+        assert len(holders) == 1 and not holders & set(
+            re.findall(r"(?:body|condition)=%([\w.\-]+)", hlo)), holders
 
 
 # ---- a hybrid stack's step that sorts (ISSUE 46) -----------------------------
