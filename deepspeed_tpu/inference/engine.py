@@ -11,6 +11,7 @@ equivalent via logical axes), the KV cache, and a compiled decode loop.
 """
 
 import dataclasses
+import time
 from typing import Any, Optional
 
 import jax
@@ -20,6 +21,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from deepspeed_tpu.config import Config
 from deepspeed_tpu.parallel import (
     MeshPlan, build_mesh, make_rules, spec_tree)
+from deepspeed_tpu.telemetry.tracing import build_clock, span
 
 
 def init_inference(model, config=None, mesh=None, dtype=None, params=None,
@@ -98,6 +100,10 @@ class InferenceConfig:
 class InferenceEngine:
     def __init__(self, model, config: InferenceConfig, mesh: Optional[Mesh] = None,
                  params=None, rng=None):
+        # what the constructor cost (``setup``, at its end): a serving
+        # engine over this one reports it as its own set-up's first part
+        builds = build_clock()
+        t0, build_s = time.perf_counter(), builds.seconds
         self.model = model
         self.config = config
         tp = max(1, config.tensor_parallel)
@@ -256,35 +262,40 @@ class InferenceEngine:
                 return unfuse_layer_stack(p, model.config)
             return p
 
-        if self._quantized or self._weight_only:
-            from deepspeed_tpu.models.transformer import quantize_layer_stack
-            if params is None:
+        # the parameters: initialised or handed in, cast, fused, quantised
+        # and placed — the host's part of it; the device runs the program
+        # behind whatever the caller does next
+        with span("ds:setup.weights") as sp_weights:
+            if self._quantized or self._weight_only:
+                from deepspeed_tpu.models.transformer import \
+                    quantize_layer_stack
+                if params is None:
+                    rng = rng if rng is not None else jax.random.PRNGKey(0)
+                    params = model.init(rng)
+                quant_fn = jax.jit(
+                    lambda p: quantize_layer_stack(_fuse(jax.tree.map(
+                        lambda x: x.astype(self.dtype)
+                        if jnp.issubdtype(jnp.asarray(x).dtype, jnp.floating)
+                        else x, p)), bits=int(config.quantize_bits
+                                              or config.weight_bits)),
+                    out_shardings=self.param_shardings)
+                with mesh:
+                    params = quant_fn(jax.tree.map(jnp.asarray, params))
+            elif params is None:
                 rng = rng if rng is not None else jax.random.PRNGKey(0)
-                params = model.init(rng)
-            quant_fn = jax.jit(
-                lambda p: quantize_layer_stack(_fuse(jax.tree.map(
-                    lambda x: x.astype(self.dtype)
-                    if jnp.issubdtype(jnp.asarray(x).dtype, jnp.floating)
-                    else x, p)), bits=int(config.quantize_bits
-                                          or config.weight_bits)),
-                out_shardings=self.param_shardings)
-            with mesh:
-                params = quant_fn(jax.tree.map(jnp.asarray, params))
-        elif params is None:
-            rng = rng if rng is not None else jax.random.PRNGKey(0)
-            init_fn = jax.jit(
-                lambda k: _fuse(jax.tree.map(
-                    lambda p: p.astype(self.dtype), model.init(k))),
-                out_shardings=self.param_shardings)
-            with mesh:
-                params = init_fn(rng)
-        else:
-            cast_fn = jax.jit(
-                lambda p: _fuse(jax.tree.map(
-                    lambda x: jnp.asarray(x, self.dtype), p)),
-                out_shardings=self.param_shardings)
-            with mesh:
-                params = cast_fn(jax.tree.map(jnp.asarray, params))
+                init_fn = jax.jit(
+                    lambda k: _fuse(jax.tree.map(
+                        lambda p: p.astype(self.dtype), model.init(k))),
+                    out_shardings=self.param_shardings)
+                with mesh:
+                    params = init_fn(rng)
+            else:
+                cast_fn = jax.jit(
+                    lambda p: _fuse(jax.tree.map(
+                        lambda x: jnp.asarray(x, self.dtype), p)),
+                    out_shardings=self.param_shardings)
+                with mesh:
+                    params = cast_fn(jax.tree.map(jnp.asarray, params))
         self.params = params
 
         self._forward = jax.jit(lambda p, ids: model.apply(p, ids))
@@ -297,6 +308,12 @@ class InferenceEngine:
         # is a traced argument, NOT part of the compile key
         self._decode_loop_cache = {}  # (B, pad_prompt, max_len, n_steps, temp)
         self._init_cache_cache = {}   # (B, max_len)
+        # ``t0``: when the constructor began; ``init_s``: its seconds, of
+        # which ``weights_s`` under ds:setup.weights and ``build_s`` building
+        # programs (the init program, mostly: the build clock's word)
+        self.setup = {"t0": t0, "init_s": time.perf_counter() - t0,
+                      "weights_s": sp_weights.seconds,
+                      "build_s": builds.seconds - build_s}
 
     def _batch_spec(self, batch_size: int) -> P:
         """Shard batch over `data` only when it divides evenly (small ad-hoc

@@ -99,7 +99,8 @@ from deepspeed_tpu.inference.scheduler import (AdmissionRejected, Request,
 from deepspeed_tpu.robustness import events as rb_events
 from deepspeed_tpu.robustness import faults as rb_faults
 from deepspeed_tpu.robustness.preemption import Preempted
-from deepspeed_tpu.telemetry.tracing import span
+from deepspeed_tpu.telemetry.tracing import (BuildLog, build_clock,
+                                             build_log, span)
 
 
 @dataclasses.dataclass(eq=False)
@@ -517,6 +518,16 @@ class ServingEngine:
         from jax.sharding import NamedSharding, PartitionSpec as P
         from deepspeed_tpu.parallel import spec_tree
 
+        # --- set-up (ISSUE 55): what this engine built, and when, counted
+        # from where its InferenceEngine's construction began; the process's
+        # one build listener, whose running total a round diffs (build_ms)
+        self._clock = build_clock()
+        t_init, build_s = time.perf_counter(), self._clock.seconds
+        self._builds = BuildLog(
+            t0=getattr(engine, "setup", {}).get("t0", t_init))
+        # programs built by the first reset_stats() (None: not reset yet)
+        self._built_at_reset: Optional[int] = None
+        self._build_overlap_s = 0.0        # _get_quantum_step
         self.engine = engine
         self.config = config or ServingConfig()
         c = self.config
@@ -698,8 +709,9 @@ class ServingEngine:
                 num_blocks, c.block_size, dtype=engine.dtype,
                 max_seqs=c.max_seqs),
             out_shardings=self._pool_shardings)
-        with engine.mesh:
+        with span("ds:setup.pools") as sp, engine.mesh:
             self.pools = self._init_pools_fn()
+        self._pools_s = sp.seconds         # and every recovery's (_recover)
         # the per-slot recurrent state's dtype: that of the first per-slot
         # leaf that is no ring (None for a model without one)
         rings = ring_leaves(model, self.pools)
@@ -866,6 +878,10 @@ class ServingEngine:
         # plain decode rounds dispatched per (slot count, columns a slot)
         # (reset_stats windows; _tables_device)
         self._table_rounds = self._step_shapes()
+        # the constructor's seconds, and of them those spent building
+        # programs (the pool's init, the backend's micro-bench)
+        self._init_s = time.perf_counter() - t_init
+        self._init_build_s = self._clock.seconds - build_s
 
     # ---- mesh geometry -----------------------------------------------
 
@@ -1014,6 +1030,7 @@ class ServingEngine:
         self._slow: List[list] = []        # [record, follower], slowest first
         self._slow_open: Optional[list] = None   # the pair owed a follower
         self._gc_ms_total = 0.0
+        self._build_ms_total = 0.0
         self._empty_s = 0.0                # closed empty intervals
         self._rounds = 0                   # rounds in this stats window
         self._round_tokens = 0             # tokens committed this round
@@ -1050,6 +1067,7 @@ class ServingEngine:
         for key in self._phase_totals:
             self._phase_totals[key] += entry[key]
         self._gc_ms_total += entry["gc_ms"]
+        self._build_ms_total += entry["build_ms"]
         if self._slow_open is not None:
             self._slow_open[1] = entry
             self._slow_open = None
@@ -1252,6 +1270,13 @@ class ServingEngine:
 
     # ---- jitted programs ---------------------------------------------
 
+    def _first_call(self, kind: str, shape, built: bool):
+        """What a call of a jitted program runs under: its first — the one
+        that traces, lowers and compiles or loads it — under the
+        ``ds:setup.program`` of its build, every later one under nothing."""
+        return contextlib.nullcontext() if built \
+            else self._builds.program(kind, shape)
+
     def _sample(self, logits, key):
         import jax
         import jax.numpy as jnp
@@ -1414,12 +1439,18 @@ class ServingEngine:
             params, pools, apool = abstractify(
                 (self.engine.params, self.pools,
                  self.adapter_pool if self._lora else None))
+            # the round this call fell in: the worker thread is in none
+            rnd = self._clock.thread().round
+
+            def build(S, W):
+                # a shape's ONE record: its lowering there, its compile here
+                return self._builds.program("step", f"{S}x{W}", rnd)
 
             def lower(S, W):
                 tables = jax.tree.map(
                     lambda a: sds(a.shape, jnp.int32),
                     self._blank_tables(S, W))
-                with self.engine.mesh:
+                with build(S, W), self.engine.mesh:
                     return fn.lower(
                         params, pools, sds(self._tokens.shape, jnp.int32),
                         tables, sds((S,), jnp.int32),
@@ -1434,11 +1465,20 @@ class ServingEngine:
             # and the shapes have to fit a cell's set-up (why:
             # ``_in_one_chunk``). The thread sees no thread-local jax.config
             # context of the caller's; the mesh is entered there
+            t0, build_s = time.perf_counter(), self._clock.seconds
             with ThreadPoolExecutor(1) as pool:
                 lowered = {shape: pool.submit(_in_one_chunk, lower, *shape)
                            for shape in self._step_shapes()}
-                self._quantum_step = {shape: lo.result().compile()
-                                      for shape, lo in lowered.items()}
+                steps = {}
+                for shape, lo in lowered.items():
+                    lo = lo.result()
+                    with build(*shape):
+                        steps[shape] = lo.compile()
+            self._quantum_step = steps
+            # the two threads' build seconds past the wall's: a lowering
+            # that ran beside a compile is in the records twice over
+            self._build_overlap_s += max(0.0, self._clock.seconds - build_s
+                                         - (time.perf_counter() - t0))
         return self._quantum_step
 
     def _get_spec_step(self):
@@ -1665,7 +1705,9 @@ class ServingEngine:
         bs = self.config.block_size
         buf = np.zeros((1, P), np.int32)
         # a bucket's first prompt traces and lowers its program: not across
-        # a chunk boundary of the interpreter's frame stack
+        # a chunk boundary of the interpreter's frame stack, and under the
+        # span of its build
+        build = self._first_call("prefill", P, P in self._prefill_fns)
         fn = self._get_prefill_fn(P) if P in self._prefill_fns else \
             functools.partial(_in_one_chunk, self._get_prefill_fn(P))
         if self._slot_state:
@@ -1687,10 +1729,10 @@ class ServingEngine:
                 block_ids += req.block_ids[:blocks_for(lengths[k], bs)]
             block_ids += [0] * (P // bs - len(block_ids))
             what = (starts, lengths, self._next_key())
-        with self.engine.mesh:
+        ids, block_ids = jnp.asarray(buf), jnp.asarray(block_ids, jnp.int32)
+        with self.engine.mesh, build:
             (toks, counters), self.pools = fn(
-                self.engine.params, jnp.asarray(buf), self.pools,
-                jnp.asarray(block_ids, jnp.int32), *what)
+                self.engine.params, ids, self.pools, block_ids, *what)
         for k, req in enumerate(reqs):
             tok = toks[0] if self._slot_state else toks[k]
             self._tokens = self._tokens.at[req.slot].set(tok)
@@ -1791,16 +1833,17 @@ class ServingEngine:
         buf[0, :n] = ctx[start:start + n]
         tab = np.zeros((1, self.MB), np.int32)
         tab[0, :len(req.block_ids)] = req.block_ids
+        build = self._first_call("span", C, C in self._chunk_fns)
         fn = self._get_chunk_fn(C)
         lora_args = ()
         if self._lora:
             lora_args = (self.adapter_pool,
                          jnp.asarray([req.adapter_slot or 0], jnp.int32))
-        with self.engine.mesh:
-            first, self.pools = fn(self.engine.params, jnp.asarray(buf),
-                                   self.pools, jnp.asarray(tab),
-                                   jnp.int32(start), jnp.int32(n),
-                                   self._next_key(), *lora_args)
+        ids, tab = jnp.asarray(buf), jnp.asarray(tab)
+        rest = (jnp.int32(start), jnp.int32(n), self._next_key(), *lora_args)
+        with self.engine.mesh, build:
+            first, self.pools = fn(self.engine.params, ids, self.pools, tab,
+                                   *rest)
         req.cached_rows = start + n
         self._lat["prefill_chunks"] += 1
         self._lat["prefill_chunk_tokens"] += n
@@ -1923,29 +1966,38 @@ class ServingEngine:
         self._enforce_deadlines()
         finished: Optional[List[Request]] = None
         last_err: Optional[BaseException] = None
-        for _attempt in range(max(0, self.config.round_retries) + 1):
-            try:
-                gc_s = self._gc.seconds
-                with span("ds:serve.round", index=self._rounds) as rs:
-                    finished, ph = self._round()
-                    rs.note(running=len(self.scheduler.running),
-                            tokens=self._round_tokens)
-                # only a round that completed counts in the window's totals
-                self._note_phases({**ph, "round_ms": rs.seconds * 1e3,
-                                   "tokens": float(self._round_tokens),
-                                   "gc_ms": (self._gc.seconds - gc_s) * 1e3})
-                break
-            except (Preempted, KeyboardInterrupt):
-                raise
-            except rb_faults.BackendFault as e:
-                last_err = e
-                self._degrade_backend()
-                self._recover("backend_fault")
-            except Exception as e:  # noqa: BLE001 — ANY round failure
-                # (injected or real) must not kill every in-flight request:
-                # preempt-all + pool rebuild makes the retry bit-exact
-                last_err = e
-                self._recover(type(e).__name__)
+        # what is built on this thread from here on was built in this round
+        here = self._clock.thread()
+        here.round = self._rounds
+        try:
+            for _attempt in range(max(0, self.config.round_retries) + 1):
+                try:
+                    gc_s, build_s = self._gc.seconds, self._clock.seconds
+                    with span("ds:serve.round", index=self._rounds) as rs:
+                        finished, ph = self._round()
+                        rs.note(running=len(self.scheduler.running),
+                                tokens=self._round_tokens)
+                    # only a round that completed counts in the window's totals
+                    self._note_phases({
+                        **ph, "round_ms": rs.seconds * 1e3,
+                        "tokens": float(self._round_tokens),
+                        "gc_ms": (self._gc.seconds - gc_s) * 1e3,
+                        "build_ms": (self._clock.seconds - build_s) * 1e3})
+                    break
+                except (Preempted, KeyboardInterrupt):
+                    raise
+                except rb_faults.BackendFault as e:
+                    last_err = e
+                    self._degrade_backend()
+                    self._recover("backend_fault")
+                except Exception as e:  # noqa: BLE001 — ANY round failure
+                    # (injected or real) must not kill every in-flight
+                    # request: preempt-all + pool rebuild makes the retry
+                    # bit-exact
+                    last_err = e
+                    self._recover(type(e).__name__)
+        finally:
+            here.round = None
         self._drain_events()
         if finished is None:
             raise RuntimeError(
@@ -1968,8 +2020,12 @@ class ServingEngine:
         housekeeping -> prefill_dispatch -> decode_dispatch -> fetch ->
         commit, and its host milliseconds are in the record
         (``schedule_ms`` ... ``commit_ms``; ``step()`` adds ``round_ms``,
-        ``tokens`` and ``gc_ms``, the collections inside the round) beside
-        what the round was: ``index``; ``t_s``, seconds into the stats
+        ``tokens``, ``gc_ms``, the collections inside the round, and
+        ``build_ms``, the programs traced, lowered, compiled or loaded
+        inside it on any thread — the build listener's running total diffed
+        as the collector's is; 0.0 in every round of a sound window: the
+        program's own "not a compile") beside what the round was:
+        ``index``; ``t_s``, seconds into the stats
         window at its start; ``running_before``, the requests running when
         it began; ``prefills`` / ``prefill_programs`` / ``prefill_tokens``,
         the prompts or chunks dispatched in it, the programs that took them
@@ -2209,7 +2265,11 @@ class ServingEngine:
         import jax.numpy as jnp
         (tables, seq_lens, active, aidx), shape, held = \
             self._tables_device(full=spec)
-        # the plain step runs as the program of the round's shape
+        # the plain step runs as the program of the round's shape, built
+        # before any round; the verify step is built by its first call
+        build = self._first_call(
+            "spec_step", f"{shape[0]}x{self.config.spec_tokens + 1}",
+            self._spec_step is not None or not spec)
         step_fn = self._get_spec_step() if spec \
             else self._get_quantum_step()[shape]
         tok_mat = None
@@ -2244,9 +2304,10 @@ class ServingEngine:
                 if spec:
                     # ONE verify step per round: pending + K proposals
                     # scored in a single span pass
-                    p, nxt, acc, t, lens = step_fn(
-                        params, p, tok_mat, tables, lens, active, keys[0],
-                        apool, aidx)
+                    with build:
+                        p, nxt, acc, t, lens = step_fn(
+                            params, p, tok_mat, tables, lens, active, keys[0],
+                            apool, aidx)
                     return p, t, (nxt, acc), None
                 for k in keys:
                     if self._epoch != epoch:
@@ -2500,8 +2561,9 @@ class ServingEngine:
             # cache's references so the fresh pool starts fully free
             self._prefix_cache.clear()
         self._tokens = jnp.zeros((self.config.max_seqs,), jnp.int32)
-        with self.engine.mesh:
+        with span("ds:setup.pools") as sp, self.engine.mesh:
             self.pools = self._init_pools_fn()
+        self._pools_s += sp.seconds
         ms = (time.perf_counter() - t0) * 1e3
         self._counters["recoveries"] += 1
         self._counters["recovery_ms"] += ms
@@ -3129,7 +3191,12 @@ class ServingEngine:
         """Start a fresh measurement window: completed-request records,
         cancellations, reliability counters and the throughput clock reset
         (pool/scheduler state untouched — the bench warms its compiles,
-        resets, then serves the timed load)."""
+        resets, then serves the timed load). ``stats()["setup"]`` is left
+        alone: it is the ENGINE's life — what it built and what that cost —
+        not a window's, and the first reset is only marked in it
+        (``built_after_first_reset``: what a warm-up did not build)."""
+        if self._built_at_reset is None:
+            self._built_at_reset = self._setup()["programs_built"]
         self._finished = []
         self._cancelled = []
         self._stats_t0 = None
@@ -3157,6 +3224,47 @@ class ServingEngine:
         self._empty_before_s = 0.0
         if self._tracer is not None:
             self._tracer.device_syncs = 0
+
+    def _setup(self) -> Dict[str, Any]:
+        """``stats()["setup"]``: where this engine's set-up went, from its
+        ``InferenceEngine``'s construction on. ``engine_init_s``: the two
+        constructors' seconds, of which ``weights_s`` (``ds:setup.weights``),
+        ``pools_s`` (``ds:setup.pools``, a recovery's fresh pool included)
+        and ``init_build_s``, building programs — those are among
+        ``programs`` too. ``programs``: ONE record a program built
+        (``telemetry.tracing.BuildLog``), in the order built — this engine's
+        (every ``ds:setup.program``) and, ``kind`` ``other``, what the
+        process built since under no such span, by function name: the small
+        programs nobody named. Over them: ``programs_built`` (lowerings),
+        ``trace_lower_s`` (Python's part, paid warm or cold),
+        ``compile_or_load_s`` (the backend's: compiles when cold, the
+        persistent cache's key and read when warm), ``overlap_s`` (of those
+        two, the seconds a step shape's lowering on the worker thread ran
+        beside another's compile on the caller's: in the records twice, in
+        the wall once), ``cache_hits`` (equal to ``programs_built`` when
+        everything was warm) and ``built_after_first_reset`` (programs built
+        since the first ``reset_stats()``: a warm-up's omissions)."""
+        eng = getattr(self.engine, "setup", {})
+        programs = sorted(
+            self._builds.records() + build_log().records(self._builds.t0),
+            key=lambda r: r["built_at_s"])
+        built = sum(r["builds"] for r in programs)
+        return {
+            "engine_init_s": eng.get("init_s", 0.0) + self._init_s,
+            "weights_s": eng.get("weights_s", 0.0),
+            "pools_s": self._pools_s,
+            "init_build_s": eng.get("build_s", 0.0) + self._init_build_s,
+            "programs": programs,
+            "programs_built": built,
+            "trace_lower_s": sum(r["trace_s"] + r["lower_s"]
+                                 for r in programs),
+            "compile_or_load_s": sum(r["compile_or_load_s"]
+                                     for r in programs),
+            "overlap_s": self._build_overlap_s,
+            "cache_hits": sum(r["cache_hit"] for r in programs),
+            "built_after_first_reset": (
+                0 if self._built_at_reset is None
+                else built - self._built_at_reset)}
 
     def close(self, timeout: Optional[float] = None) -> bool:
         """Stop admission and join the latest watchdog round thread with
@@ -3288,10 +3396,15 @@ class ServingEngine:
         decoding), slowest first, each ``[its record, the record of the
         round that followed it]`` —, ``round_ms_max`` and ``phase_ms_max``
         (``{phase: ms}``) over those rounds, ``round_ms_median`` over the
-        ring's, ``gc_ms_total`` (garbage collection inside rounds), and the
-        empty engine: ``engine_empty_s`` (seconds of the window in which it
-        held no request, an interval still open included) of
-        ``stats_window_s`` (since the window's first request)."""
+        ring's, ``gc_ms_total`` (garbage collection inside rounds),
+        ``build_ms_total`` (programs built inside rounds: 0.0 in a window
+        that compiled nothing), and the empty engine: ``engine_empty_s``
+        (seconds of the window in which it held no request, an interval
+        still open included) of ``stats_window_s`` (since the window's first
+        request).
+
+        Set-up (always on; the engine's LIFE, which ``reset_stats`` leaves
+        alone): ``setup`` — ``_setup``."""
         done = [r for r in self._finished if r.first_token_t is not None]
         out: Dict[str, Any] = {
             "completed": float(len(self._finished)),
@@ -3414,6 +3527,8 @@ class ServingEngine:
         out["slow_rounds"] = [[dict(rec), nxt and dict(nxt)]
                               for rec, nxt in self._slow]
         out["gc_ms_total"] = float(self._gc_ms_total)
+        out["build_ms_total"] = float(self._build_ms_total)
+        out["setup"] = self._setup()
         typical = [e["round_ms"] for e in self._phases
                    if self._decode_dominated(e)]
         if typical:

@@ -20,6 +20,7 @@ API parity:
   engine.global_steps, get_lr, get_loss_scale, ...
 """
 
+import contextlib
 import dataclasses
 import json
 import math
@@ -44,6 +45,7 @@ from deepspeed_tpu.runtime import zero as zero_mod
 from deepspeed_tpu.runtime import checkpointing as ckpt_mod
 from deepspeed_tpu.runtime.lr_schedules import get_scheduler
 from deepspeed_tpu.telemetry import accumulators as tel_acc
+from deepspeed_tpu.telemetry.tracing import build_clock, build_log
 from deepspeed_tpu.telemetry.tracing import span as _span
 from deepspeed_tpu.utils import logging as log_mod
 from deepspeed_tpu.utils.timer import SynchronizedWallClockTimer, ThroughputTimer
@@ -573,7 +575,15 @@ class Engine:
             self.state = None  # streamed: the full tree never materializes
             self._infinity_exec = self._build_infinity()
         else:
-            self.state = self._init_state()
+            # set-up in the build log (telemetry.tracing.build_log): the
+            # state's initialisation and sharding here, each step program at
+            # its first call (``_building``)
+            builds = build_clock()
+            build_s = builds.seconds
+            with _span("ds:setup.state") as sp_state:
+                self.state = self._init_state()
+            self._setup_state_s = (sp_state.seconds,
+                                   builds.seconds - build_s)
             # --- jitted step functions
             self._compile_steps()
 
@@ -692,9 +702,13 @@ class Engine:
             logger.info(f"random-ltd: kept tokens "
                         f"{self._ltd.min_value} -> {self._ltd.max_value}")
         n = num_params(param_shapes)
+        state_s = getattr(self, "_setup_state_s", None)
         logger.info(f"engine ready: {model.name if hasattr(model, 'name') else 'model'} "
                     f"{n / 1e6:.1f}M params, dtype={self.compute_dtype.__name__}, "
-                    f"mesh={self.plan.describe()}")
+                    f"mesh={self.plan.describe()}"
+                    + (f", state set up in {state_s[0]:.2f} s "
+                       f"({state_s[1]:.2f} s building its programs)"
+                       if state_s else ""))
 
     # ------------------------------------------------------------------
     def _build_monitor(self):
@@ -1001,6 +1015,8 @@ class Engine:
 
     def _compile_steps(self):
         cfg = self.config
+        # (step function, batch shape) built so far: ``_building``
+        self._steps_built = set()
         # in pipeline mode grad accumulation IS the microbatch rotation inside
         # the pipelined loss; the outer step consumes the whole global batch
         gas = 1 if self._pp_mode else cfg.gradient_accumulation_steps
@@ -1543,7 +1559,7 @@ class Engine:
         batch = self._device_batch(batch)
         with self._tel_span("dispatch"):
             if self._nvme_opt:
-                with self.mesh:
+                with self.mesh, self._building(self._batch_grads, batch):
                     mean_loss, grads = self._batch_grads(self.state, batch,
                                                          sub)
                 metrics = self._nvme_apply(grads, mean_loss)
@@ -1551,7 +1567,7 @@ class Engine:
                 phase = self.optimizer.phase_for(self._onebit_applied)
                 step_fn = self._get_onebit_step(phase, batch)
                 self._capture_static_args(step_fn, (self.state, batch, sub), 1)
-                with self.mesh:
+                with self.mesh, self._building(step_fn, batch):
                     self.state, metrics = step_fn(self.state, batch, sub)
                 # EXPLICIT sync point: the warm->compressed phase switch is a
                 # host decision keyed on the applied-update count, so this
@@ -1564,7 +1580,7 @@ class Engine:
                     self.state["opt"] = self._opt_to_device(self.state["opt"])
                 self._capture_static_args(
                     self._train_step, (self.state, batch, sub), 1)
-                with self.mesh:
+                with self.mesh, self._building(self._train_step, batch):
                     self.state, metrics = self._train_step(self.state, batch,
                                                            sub)
                 if self._offload_opt:
@@ -1735,7 +1751,7 @@ class Engine:
         fused_fn = self._get_fused_step(k)
         self._capture_static_args(fused_fn, (self.state, placed, rngs), k)
         with self._tel_span("dispatch"):
-            with self.mesh:
+            with self.mesh, self._building(fused_fn, placed):
                 self.state, metrics_k = fused_fn(self.state, placed, rngs)
         self.global_steps += k
         self.micro_steps += k * self.config.gradient_accumulation_steps
@@ -2065,6 +2081,29 @@ class Engine:
         if self._tel_cfg is not None and self._tel_wall is None:
             self._tel_wall = time.perf_counter()
             self._tel_wall_steps = self.global_steps
+
+    @contextlib.contextmanager
+    def _building(self, fn, batch):
+        """Around a call of the step function ``fn``: its FIRST call at a
+        batch shape traces, lowers and compiles (or loads) the program, so
+        that call runs under ``ds:setup.program`` (``kind`` ``train_step``,
+        ``shape`` the batch's) and leaves its record in the process's build
+        log and one line in this engine's; every later call is just the
+        call."""
+        shape = np.shape(jax.tree.leaves(batch)[0])
+        if (id(fn), shape) in self._steps_built:
+            yield
+            return
+        self._steps_built.add((id(fn), shape))
+        with build_log().program("train_step",
+                                 "x".join(map(str, shape))) as sp:
+            yield
+        r = sp.record
+        logger.info(
+            f"built train step {r['shape']}: traced {r['trace_s']:.2f} s, "
+            f"lowered {r['lower_s']:.2f} s, "
+            f"{'loaded' if r['cache_hit'] else 'compiled'} "
+            f"{r['compile_or_load_s']:.2f} s, first call {sp.seconds:.2f} s")
 
     def _tel_span(self, name: str):
         """The host phase ``ds:train.<name>``, always: a profiler session

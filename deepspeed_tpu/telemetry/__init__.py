@@ -11,8 +11,10 @@ serving with zero added steady-state syncs). Four pieces:
                  ``jax.profiler.TraceAnnotation`` named ``ds:<layer>.<phase>``
                  plus the elapsed seconds), always on in both engines; the
                  span recorder around the dispatch/prefetch/block phases of
-                 ``engine.train_batches`` (Chrome-trace export) and the
-                 windowed ``jax.profiler`` capture
+                 ``engine.train_batches`` (Chrome-trace export), the
+                 windowed ``jax.profiler`` capture, and the build log: ONE
+                 record a program built (``BuildLog``, ``build_log()``) from
+                 JAX's own compile events under ``ds:setup.program`` spans
   anomaly      — structured-severity events (loss spikes, grad-norm drift,
                  overflow bursts, dispatch-stall regressions) from the
                  drained window stats
@@ -53,12 +55,15 @@ from deepspeed_tpu.telemetry.exposition import (Histogram, parse_exposition,
 from deepspeed_tpu.telemetry.join import joined_rates, static_step_cost
 from deepspeed_tpu.telemetry.request_trace import (RequestTracer,
                                                    merge_chrome_trace)
-from deepspeed_tpu.telemetry.tracing import StepTracer, span
+from deepspeed_tpu.telemetry.tracing import (BuildLog, StepTracer, build_log,
+                                             span)
 
 __all__ = [
-    "HIST_BUCKETS", "HIST_LOG2_MIN", "AnomalyDetector", "Histogram",
+    "BuildLog", "HIST_BUCKETS", "HIST_LOG2_MIN", "AnomalyDetector",
+    "Histogram",
     "HostWindow", "RequestTracer", "SEVERITY_NUM", "StepTracer", "accumulate",
-    "init_leaf", "joined_rates", "merge_chrome_trace", "parse_exposition",
+    "build_log", "init_leaf", "joined_rates", "merge_chrome_trace",
+    "parse_exposition",
     "render_prometheus", "severity_num", "span", "static_step_cost",
     "update_to_param_ratio", "window_stats",
 ]

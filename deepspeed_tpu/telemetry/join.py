@@ -32,9 +32,11 @@ def static_step_cost(jitted, abstract_args, *, mesh=None,
     K-step program back to per-step costs. Returns None when the backend
     can't answer (no cost model, lowering failure)."""
     import contextlib
+    from deepspeed_tpu.telemetry.tracing import build_log
     try:
         ctx = mesh if mesh is not None else contextlib.nullcontext()
-        with ctx:
+        # a second build of the step, off the hot path: in the build log
+        with ctx, build_log().program("other", "static_join"):
             compiled = jitted.lower(*abstract_args).compile()
         flops = 0
         bytes_accessed = 0

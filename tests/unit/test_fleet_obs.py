@@ -175,7 +175,7 @@ class TestExposition:
 def _entry(round_ms=1.0, **phases):
     e = {"index": 0, "t_s": 0.0, "running_before": 2, "prefills": 0,
          "prefill_tokens": 0, "shape": (2, 2), "ahead_covered": True,
-         "empty_before_ms": 0.0, "gc_ms": 0.0,
+         "empty_before_ms": 0.0, "gc_ms": 0.0, "build_ms": 0.0,
          "schedule_ms": 0.1, "housekeeping_ms": 0.1, "prefill_ms": 0.1,
          "decode_ms": 0.2, "fetch_ms": 0.3, "commit_ms": 0.1,
          "round_ms": round_ms, "tokens": 8.0}
@@ -381,8 +381,17 @@ class TestTracedEngineParity:
         assert meta["completed"] == len(reqs)
         assert Histogram.from_dict(meta["ttft_ms_hist"]).count == len(reqs)
 
-        # pinned reset, fleet scope: every exposed counter clears
+        # pinned reset, fleet scope: every exposed counter clears — but for
+        # exactly one key of stats(): ``setup`` is the ENGINE's life (what
+        # it built, what that cost: ISSUE 55), not a window's, and the
+        # reset that follows a warm-up must not wipe what the warm-up did
+        lived = traced.stats()["setup"]
+        assert lived["programs_built"] > 0 and lived["engine_init_s"] > 0.0
         traced.reset_stats()
+        st = traced.stats()
+        assert st["setup"] == lived
+        assert st["build_ms_total"] == st["gc_ms_total"] == 0.0
+        assert st["completed"] == 0.0 and "generated_tokens" not in st
         d = traced.phase_decomposition()
         assert d["serve_rounds"] == 0.0 and d["serve_tokens"] == 0.0
         assert d["serve_phase_stall_events"] == 0.0

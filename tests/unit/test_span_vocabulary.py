@@ -15,7 +15,12 @@ backend (the host plane works without a chip):
   - the request lifecycle (``admit_t``, ``max_gap_ms``) and the six
     ``stats()`` keys, a preempted request included, and ``admit_t``
     agreeing with ``RequestTracer``'s ``queue_wait`` span;
-  - the train loop's host phases are in the trace WITHOUT telemetry.
+  - the train loop's host phases are in the trace WITHOUT telemetry;
+  - set-up says where it went (ISSUE 55): ``ds:setup.weights|pools|state``
+    and a ``ds:setup.program`` around every build, ONE record a program in
+    ``stats()["setup"]`` from JAX's own compile events through one
+    process-wide listener, kept across ``reset_stats()``, and a round's
+    ``build_ms``.
 """
 
 import collections
@@ -268,7 +273,7 @@ def _record(round_ms=4.0, **over):
     e = {"index": 0, "t_s": 0.0, "running_before": 2, "prefills": 0,
          "prefill_programs": 0, "prefill_tokens": 0, "shape": (2, 2),
          "ahead_covered": True,
-         "empty_before_ms": 0.0, "gc_ms": 0.0,
+         "empty_before_ms": 0.0, "gc_ms": 0.0, "build_ms": 0.0,
          "schedule_ms": 0.1, "housekeeping_ms": 0.1, "prefill_ms": 0.2,
          "decode_ms": 0.4, "fetch_ms": 3.0, "commit_ms": 0.2,
          "round_ms": round_ms, "tokens": 8.0}
@@ -315,10 +320,19 @@ class TestPhaseTotals:
         srv = _serving()
         srv.run([(_prompt(9), 4)])
         assert srv.phase_decomposition()["serve_round_ms"] > 0.0
+        before = srv.stats()
+        assert before["build_ms_total"] > 0.0       # the run built programs
         srv.reset_stats()
         d = srv.phase_decomposition()
         assert d["serve_rounds"] == 0.0
         assert all(d[k] == 0.0 for k in srv._PHASE_OUT.values())
+        after = srv.stats()
+        assert after["build_ms_total"] == 0.0 == after["gc_ms_total"]
+        # exactly ONE key is exempt: ``setup`` is the engine's life — what it
+        # built and what that cost — and a window's reset must not wipe what
+        # the warm-up before it recorded (TestSetupRecord)
+        assert after["setup"] == before["setup"]
+        assert after["setup"]["programs_built"] > 0
         srv.close()
 
 
@@ -639,6 +653,275 @@ class TestEmptyEngine:
         from deepspeed_tpu.telemetry import tracing
         assert "``ds:serve.submit``" in tracing.__doc__
         assert "``ds:serve.drained``" in tracing.__doc__
+
+
+# ---------------------------------------------------------------------------
+# set-up: spans, ONE build record a program, the round's build_ms (ISSUE 55)
+# ---------------------------------------------------------------------------
+
+PROGRAM_KEYS = {"kind", "shape", "name", "trace_s", "lower_s",
+                "compile_or_load_s", "cache_load_s", "cache_hit", "builds",
+                "wall_s", "built_at_s", "round"}
+SETUP_KEYS = {"engine_init_s", "weights_s", "pools_s", "init_build_s",
+              "programs", "programs_built", "trace_lower_s",
+              "compile_or_load_s", "overlap_s", "cache_hits",
+              "built_after_first_reset"}
+
+
+def _programs(srv, kind=None):
+    return [r for r in srv.stats()["setup"]["programs"]
+            if kind is None or r["kind"] == kind]
+
+
+class TestSetupRecord:
+    def test_spans_on_the_profilers_clock_with_kind_and_shape(self, tmp_path):
+        """A whole cold start under a session: the weights, the pool, and a
+        ``ds:setup.program`` around every build — a step shape twice under
+        ONE key, its lowering on the worker thread and its compile on the
+        caller's."""
+        with _Session(tmp_path) as ses:
+            srv = _serving(_tiny_model(seq=128), max_model_len=128)
+            srv.run([(_prompt(9), 4), (_prompt(20, seed=1), 4)])
+        (w,), (p,) = ses.named("ds:setup.weights"), ses.named("ds:setup.pools")
+        assert w[2] <= p[1]                         # one clock, in order
+        progs = ses.named("ds:setup.program")
+        assert all(set(x[3]) == {"kind", "shape"} for x in progs)
+        # (the profile reads an argument that looks like a number as one)
+        by_key = collections.Counter((x[3]["kind"], str(x[3]["shape"]))
+                                     for x in progs)
+        shapes = [f"{S}x{W}" for S, W in srv._step_shapes()]
+        assert len(shapes) >= 2
+        assert by_key == {("prefill", "16"): 1, ("prefill", "32"): 1,
+                          **{("step", sh): 2 for sh in shapes}}
+        # every build of a round lies inside that round's span
+        rounds = ses.named("ds:serve.round")
+        for x in progs:
+            assert any(r[1] <= x[1] and x[2] <= r[2] for r in rounds)
+        # the record agrees with the spans: their seconds are its wall_s
+        for rec in _programs(srv, "step") + _programs(srv, "prefill"):
+            mine = [x for x in progs if (x[3]["kind"], str(x[3]["shape"]))
+                    == (rec["kind"], rec["shape"])]
+            assert sum(x[2] - x[1] for x in mine) / 1e9 == pytest.approx(
+                rec["wall_s"], rel=0.15, abs=0.03)
+        srv.close()
+
+    def test_every_program_built_is_in_the_record_once(self):
+        srv = _serving(_tiny_model(seq=128), max_model_len=128)
+        srv.run([(_prompt(9), 4), (_prompt(20, seed=1), 4)])
+        setup = srv.stats()["setup"]
+        assert set(setup) == SETUP_KEYS
+        recs = setup["programs"]
+        assert all(set(r) == PROGRAM_KEYS for r in recs)
+        named = [(r["kind"], r["shape"]) for r in recs if r["kind"] != "other"]
+        assert sorted(named) == sorted(
+            [("prefill", "16"), ("prefill", "32")]
+            + [("step", f"{S}x{W}") for S, W in srv._step_shapes()])
+        assert len(set(named)) == len(named)
+        for r in recs:
+            if r["kind"] == "other":
+                continue
+            # lowered on the worker thread, compiled here: ONE record, one
+            # build, each of JAX's three events in it
+            assert r["builds"] == 1, r
+            assert r["trace_s"] > 0.0 and r["lower_s"] > 0.0
+            assert r["compile_or_load_s"] > 0.0
+            assert r["name"] == ("step" if r["kind"] == "step" else "prefill")
+            # tracing reports the jitted functions a program calls inside
+            # the program's own time: counted once, the three fit the spans
+            assert r["trace_s"] + r["lower_s"] + r["compile_or_load_s"] \
+                <= r["wall_s"] + 1e-3
+            assert r["round"] == 0 and r["built_at_s"] > 0.0
+        # what nobody named (the pool's init, the scatter of a first token:
+        # whatever this process had not built before) goes by function name
+        assert all(r["shape"] == r["name"] for r in recs
+                   if r["kind"] == "other")
+        assert [r["built_at_s"] for r in recs] == sorted(
+            r["built_at_s"] for r in recs)
+        # the sums are over the records
+        assert setup["programs_built"] == sum(r["builds"] for r in recs)
+        assert setup["trace_lower_s"] == pytest.approx(
+            sum(r["trace_s"] + r["lower_s"] for r in recs))
+        assert setup["compile_or_load_s"] == pytest.approx(
+            sum(r["compile_or_load_s"] for r in recs))
+        assert setup["cache_hits"] == 0             # the suite has no cache
+        # a shape's lowering beside another's compile: twice in the records
+        assert 0.0 <= setup["overlap_s"] < setup["trace_lower_s"]
+        # the constructors: weights and pool inside them, and the programs
+        # they built (the init program, the pool's) counted in both
+        assert 0.0 < setup["weights_s"] + setup["pools_s"] \
+            <= setup["engine_init_s"]
+        assert 0.0 <= setup["init_build_s"] <= setup["engine_init_s"]
+        assert setup["weights_s"] == srv.engine.setup["weights_s"]
+        import json
+        json.dumps(setup)                           # rides out in a run's JSON
+        srv.close()
+
+    def test_the_record_survives_reset_stats(self):
+        srv = _serving()
+        srv.run([(_prompt(9), 4)])                  # the warm-up
+        before = srv.stats()["setup"]
+        assert before["built_after_first_reset"] == 0
+        srv.reset_stats()
+        assert srv.stats()["setup"] == before
+        srv.run([(_prompt(20, seed=1), 4)])         # a bucket it did not warm
+        srv.reset_stats()                           # only the FIRST is marked
+        after = srv.stats()["setup"]
+        new = [r for r in after["programs"] if r not in before["programs"]]
+        assert ("prefill", "32") in [(r["kind"], r["shape"]) for r in new]
+        assert after["built_after_first_reset"] == sum(
+            r["builds"] for r in after["programs"]) - before["programs_built"]
+        assert after["built_after_first_reset"] >= 1
+        for key in ("engine_init_s", "weights_s", "pools_s", "init_build_s"):
+            assert after[key] == before[key]
+        srv.close()
+
+    def test_a_bucket_first_met_in_a_round_is_in_its_build_ms(self):
+        srv = _serving(_tiny_model(seq=128), max_model_len=128)
+        srv.run([(_prompt(9), 60)])                 # bucket 16, every step
+        srv.reset_stats()
+        srv.add_request(_prompt(5), 12)             # warm: builds nothing
+        warm = _drain(srv)
+        assert warm and all(r["build_ms"] == 0.0 for r in warm)
+        assert srv.stats()["build_ms_total"] == 0.0
+        srv.add_request(_prompt(20, seed=1), 12)    # bucket 32: a build
+        recs = _drain(srv)
+        assert recs[0]["build_ms"] > 0.0 and recs[0]["prefills"] == 1
+        assert recs[0]["build_ms"] < recs[0]["round_ms"]
+        assert all(r["build_ms"] == 0.0 for r in recs[1:]) and len(recs) > 2
+        (rec,) = [r for r in _programs(srv, "prefill") if r["shape"] == "32"]
+        assert rec["round"] == recs[0]["index"]
+        assert recs[0]["build_ms"] >= 1e3 * (
+            rec["trace_s"] + rec["lower_s"] + rec["compile_or_load_s"]) - 1e-6
+        st = srv.stats()
+        assert st["build_ms_total"] == pytest.approx(recs[0]["build_ms"])
+        assert st["setup"]["built_after_first_reset"] >= 1
+        srv.close()
+
+    def test_two_engines_share_one_listener(self):
+        """As ``TestGcClock::test_one_hook_times_every_collection`` for the
+        collector's hook: the listener is the process's, each engine's
+        records are its own."""
+        from jax._src import monitoring
+        from deepspeed_tpu.telemetry import tracing
+        a, b = _serving(), _serving()
+        clock = tracing.build_clock()
+        assert a._clock is b._clock is clock
+        assert [cb for cb in monitoring.get_event_time_span_listeners()
+                if getattr(cb, "__self__", None) is clock] == [clock._on_span]
+        assert len([cb for cb in monitoring.get_event_duration_listeners()
+                    if getattr(cb, "__self__", None) is clock]) == 1
+        assert len([cb for cb in monitoring.get_event_listeners()
+                    if getattr(cb, "__self__", None) is clock]) == 1
+        before = clock.seconds
+        a.run([(_prompt(9), 4)])
+        assert clock.seconds > before
+        b.run([(_prompt(20), 4)])
+        mine = {(r["kind"], r["shape"]) for r in _programs(a, "prefill")}
+        theirs = {(r["kind"], r["shape"]) for r in _programs(b, "prefill")}
+        assert mine == {("prefill", "16")} and theirs == {("prefill", "32")}
+        assert all(r["builds"] == 1 for r in _programs(a) + _programs(b)
+                   if r["kind"] != "other")
+        a.close(), b.close()
+
+    def test_what_is_built_under_no_span_goes_by_its_name(self):
+        """... into the process's own log, and from there into the record of
+        every engine that was alive: the small programs nobody named are
+        counted, not lost."""
+        from deepspeed_tpu.telemetry import tracing
+
+        def built_before_the_engine(x):
+            return jnp.cos(x) - 3.0
+
+        def nobody_named_me(x):
+            return jnp.sin(x) * 2.0
+
+        jax.jit(built_before_the_engine)(jnp.ones((3, 5)))
+        srv = _serving()
+        srv.add_request(_prompt(5), 2)
+        real = srv._land
+
+        def land(*args):                    # built inside a round
+            jax.jit(nobody_named_me)(jnp.ones((3, 5)))
+            return real(*args)
+        srv._land = land
+        srv.step()
+        (rec,) = [r for r in tracing.build_log().records()
+                  if r["name"] == "nobody_named_me"]
+        (seen,) = [r for r in _programs(srv, "other")
+                   if r["name"] == "nobody_named_me"]
+        assert seen["round"] == rec["round"] == 0
+        assert 0.0 < seen["built_at_s"] < rec["built_at_s"]   # its own t0
+        assert {k: v for k, v in seen.items() if k != "built_at_s"} == {
+            k: v for k, v in rec.items() if k != "built_at_s"}
+        assert "built_before_the_engine" not in [
+            r["name"] for r in _programs(srv)]
+        srv.close()
+        assert (rec["kind"], rec["shape"]) == ("other", "nobody_named_me")
+        assert rec["builds"] == 1 and rec["wall_s"] == 0.0
+        assert rec["trace_s"] > 0.0 and rec["lower_s"] > 0.0
+        assert rec["compile_or_load_s"] > 0.0
+        # ... and a trace alone (nothing lowered) waits for a span's edge
+        jax.eval_shape(jax.jit(lambda x: jnp.cos(x) + 1.0), jnp.ones((7,)))
+        log = tracing.BuildLog()
+        with log.program("other", "toy"):
+            pass
+        (toy,) = log.records()
+        assert toy["trace_s"] == 0.0 and toy["builds"] == 0   # not the span's
+
+    def test_a_chunk_width_and_the_verify_step_are_builds_too(self):
+        srv = _serving(prefill_token_budget=16, max_model_len=64)
+        srv.run([(_prompt(40), 4)])                 # 16 + 16 + 8: two widths
+        assert sorted(r["shape"] for r in _programs(srv, "span")) == [
+            "16"]                                   # 8 pads to a block: 16
+        assert all(r["builds"] == 1 and r["name"] == "chunk"
+                   for r in _programs(srv, "span"))
+        srv.close()
+        srv = _serving(spec_tokens=2)
+        srv.run([(_prompt(9), 8)])
+        (rec,) = _programs(srv, "spec_step")
+        assert rec["shape"] == "2x3" and rec["builds"] == 1
+        assert rec["trace_s"] > 0.0 and rec["round"] is not None
+        assert _programs(srv, "step") == []         # it never ran a plain one
+        srv.close()
+
+    def test_a_recovery_rebuilds_the_pool_under_its_span(self, tmp_path):
+        srv = _serving()
+        srv.run([(_prompt(9), 4)])
+        first = srv.stats()["setup"]["pools_s"]
+        with _Session(tmp_path) as ses:
+            srv._recover("test")
+        assert len(ses.named("ds:setup.pools")) == 1
+        assert srv.stats()["setup"]["pools_s"] > first
+        srv.close()
+
+    def test_the_train_engines_state_and_step(self, tmp_path):
+        from deepspeed_tpu.telemetry import tracing
+        model = make_model(TransformerConfig(
+            vocab_size=64, hidden_size=32, num_layers=1, num_heads=2,
+            max_seq_len=24))
+        rng = np.random.default_rng(0)
+        batch = {"input_ids": rng.integers(0, 64, (8, 24), dtype=np.int32)}
+        with _Session(tmp_path) as ses:
+            engine, *_ = deepspeed_tpu.initialize(model=model, config={
+                "train_batch_size": 8,
+                "optimizer": {"type": "adamw", "params": {"lr": 1e-3}}})
+            engine.train_batch(batch)
+            engine.train_batch(batch)
+        assert len(ses.named("ds:setup.state")) == 1
+        (prog,) = ses.named("ds:setup.program")     # the first call alone
+        assert prog[3] == {"kind": "train_step", "shape": "8x24"}
+        (disp, _) = ses.named("ds:train.dispatch")
+        assert disp[1] <= prog[1] and prog[2] <= disp[2]
+        (rec,) = [r for r in tracing.build_log().records()
+                  if (r["kind"], r["shape"]) == ("train_step", "8x24")]
+        assert rec["builds"] >= 1 and rec["trace_s"] > 0.0
+        assert rec["lower_s"] > 0.0 and rec["compile_or_load_s"] > 0.0
+        engine.close()
+
+    def test_the_vocabulary_lists_the_four_names(self):
+        from deepspeed_tpu.telemetry import tracing
+        for name in ("weights", "pools", "state", "program"):
+            assert f"``ds:setup.{name}``" in tracing.__doc__
 
 
 # ---------------------------------------------------------------------------
