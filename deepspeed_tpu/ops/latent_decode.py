@@ -15,6 +15,21 @@ rows, so a wave is ONE matmul each way at ``heads`` rows — ``[heads, lanes] x
 [wave, lanes]^T`` and ``[heads, wave] x [wave, rank]`` — with no per-head
 layout at all. Nothing is quantised: the pool is the float dtype.
 
+The wave pipeline runs ACROSS slots. The grid's steps run in order, so under
+a slot's LAST wave the first wave of the slot after it is fetched into the
+buffer that wave leaves free (that slot's table row and length are prefetched
+scalars too), and the slot begins by WAITING for it; which buffer is next is
+carried from grid step to grid step in SMEM. A slot that reads nothing hands
+the baton on — it starts its successor's wave itself, both buffers being free
+—, the last slot starts nothing (no copy is outstanding when the kernel ends),
+and only slot 0 starts its own first wave with nothing to hide it behind. So
+EVERY live slot but a call's first finds its first wave started before its
+grid step began, by construction: a counter of that would read ``(live - 1) /
+live`` and say nothing. What says how much of the fetch the work covered is the
+kernel's share of its roofline (the benchmark's ``sat_mla_read_roofline``).
+The rows enter a slot's softmax in the order they always did, whichever
+buffer its waves start in: the result does not depend on the slots before it.
+
 The fresh row (the token's own, not in the pool yet) is folded in by the
 caller from what the kernel returns — the unnormalised accumulator, the running
 maximum and the sum — in a few small XLA ops.
@@ -50,7 +65,7 @@ def kernel_fits(*, block_size: int, lanes: int, rank: int, itemsize: int
             and block_size % ROWS == 0 and itemsize == 2)
 
 
-# ---- the price of the two reads (fitted on a v5e: PERF.md section 6, PR 52) --
+# ---- the price of the two reads (fitted on a v5e: PERF.md section 6, PR 52, 56)
 #
 # Both in BYTES at the chip's stream rate (819 GB/s: 1e6 bytes = 1.22 us), from
 # the engine's shapes alone and at ONE load, every slot at a QUARTER of its
@@ -70,12 +85,16 @@ VIEW_PASSES = 19.0
 # The kernel streams the live blocks once, at KERNEL_STREAM of the stream rate
 # (570 GB/s of stored bytes: both contractions run at `heads` rows, so the MXU
 # is bound by loading the blocks as the stationary operand), and pays
-# KERNEL_SLOT_BYTES a slot (a grid step: the first wave nothing hides, 2.6 us)
-# and KERNEL_CALL_BYTES a call (the query's padding before, the fold of the
-# fresh row after). Fitted on the same three calls (0.68 / 1.00 / 1.37 ms):
-# within 3 %.
+# KERNEL_SLOT_BYTES a slot and KERNEL_CALL_BYTES a call (the query's padding
+# before, the fold of the fresh row after). A slot's 1.5 us are what a grid step
+# costs with its first wave fetched under the slot before it: the step itself
+# and its q / out blocks, a last part rounded up to SUB blocks, and what of the
+# fetch a short last wave does not cover. (Each slot starting its own first
+# wave in the open, as PR 52 had it, paid 2.1e6 = 2.6 us: 0.68 / 1.00 / 1.37 ms
+# on the same three calls.) Fitted on them, PR 56: 0.55 / 0.87 / 1.24 ms, within
+# 2 %; the stream's rate did not move.
 KERNEL_STREAM = 0.70
-KERNEL_SLOT_BYTES = 2.1e6
+KERNEL_SLOT_BYTES = 1.2e6
 KERNEL_CALL_BYTES = 10e6
 # what the two must differ by before the difference is one (0.04 ms, as
 # ``decode_attention.READ_TIE_BYTES``)
@@ -105,48 +124,62 @@ def latent_read_price(*, slots: int, MB: int, block_size: int, heads: int,
 
 
 def _kernel(layer_ref, tab_ref, len_ref, q_ref, pool, acc_ref, m_ref, l_ref,
-            buf, sem, *, bs, MB, rank, sm):
-    s = pl.program_id(0)
+            buf, sem, turn, *, bs, MB, rank, sm):
+    s, S = pl.program_id(0), pl.num_programs(0)
     layer = layer_ref[0]
     ln = len_ref[s]
-    nb = (ln + bs - 1) // bs                       # live blocks of the slot
+    blocks = lambda rows: (rows + bs - 1) // bs    # noqa: E731
+    nb = blocks(ln)                                # live blocks of the slot
     n_chunks = (nb + CHUNK - 1) // CHUNK
+    # the slot after this one: its first wave is started HERE (none after the
+    # last slot: no copy may be outstanding when the kernel ends)
+    nxt = jnp.minimum(s + 1, S - 1)
+    nb_next = jnp.where(s + 1 < S, blocks(len_ref[nxt]), 0)
     wave_rows, part_rows = CHUNK * bs, SUB * bs
 
-    @pl.when(s == 0)
-    def _clear():
-        # what a wave's tail holds past the slot's last block is whatever the
-        # buffer held: rows of an earlier wave (finite), never uninitialised
-        # memory — their probabilities are 0, and 0 x NaN is not
-        buf[...] = jnp.zeros_like(buf)
-
-    def wave(i, slot, start: bool):
-        """Chunk i's live blocks into ``buf[slot]``: started, or waited for
-        (a wait names a copy of the same size, whatever block)."""
+    def wave(first, count, slot, start: bool):
+        """``count`` blocks from table entry ``first`` on into ``buf[slot]``:
+        started, or waited for (a wait names a copy of the same size,
+        whatever block)."""
         def one(j, carry):
-            blk = tab_ref[s * MB + i * CHUNK + j] if start else 0
+            blk = tab_ref[first + j] if start else 0
             c = pltpu.make_async_copy(
                 pool.at[layer, blk],
                 buf.at[slot, pl.ds(pl.multiple_of(j * bs, bs), bs)],
                 sem.at[slot])
             c.start() if start else c.wait()
             return carry
-        lax.fori_loop(0, jnp.minimum(CHUNK, nb - i * CHUNK), one, 0)
+        lax.fori_loop(0, jnp.minimum(CHUNK, count), one, 0)
 
-    @pl.when(nb > 0)
-    def _first():
-        wave(0, 0, True)
+    @pl.when(s == 0)
+    def _open():
+        # what a wave's tail holds past the slot's last block is whatever the
+        # buffer held: rows of an earlier wave (finite), never uninitialised
+        # memory — their probabilities are 0, and 0 x NaN is not
+        buf[...] = jnp.zeros_like(buf)
+        # the ONE first wave nothing hides
+        turn[0] = 0
+        wave(0, nb, 0, True)
+
+    first_buf = turn[0]            # where the slot before put this one's wave 0
+
+    @pl.when(n_chunks == 0)
+    def _idle():
+        # a slot that reads nothing hands the baton on: both buffers are free
+        wave(nxt * MB, nb_next, first_buf, True)
 
     q = q_ref[...]                                             # [R, lanes]
     R = q.shape[0]
 
     def chunk(i, carry):
-        slot = i % 2
-
-        @pl.when(i + 1 < n_chunks)
-        def _ahead():
-            wave(i + 1, 1 - slot, True)
-        wave(i, slot, False)
+        slot = (first_buf + i) % 2
+        # under this wave's work the next one is fetched into the other
+        # buffer: the slot's own, or — under its last — wave 0 of slot s + 1
+        more = i + 1 < n_chunks
+        wave(jnp.where(more, s * MB + (i + 1) * CHUNK, nxt * MB),
+             jnp.where(more, nb - (i + 1) * CHUNK, nb_next), 1 - slot, True)
+        live = jnp.minimum(CHUNK, nb - i * CHUNK)
+        wave(0, live, slot, False)
 
         def part(u, carry):
             # SUB blocks of the wave a trip; past the slot's last block a
@@ -168,13 +201,13 @@ def _kernel(layer_ref, tab_ref, len_ref, q_ref, pool, acc_ref, m_ref, l_ref,
                                  preferred_element_type=jnp.float32)
             return m_new, l, acc * alpha + pv
 
-        live = jnp.minimum(CHUNK, nb - i * CHUNK)
         return lax.fori_loop(0, (live + SUB - 1) // SUB, part, carry)
 
     m, l, acc = lax.fori_loop(
         0, n_chunks, chunk,
         (jnp.full((R, 1), NEG_INF, jnp.float32),
          jnp.zeros((R, 1), jnp.float32), jnp.zeros((R, rank), jnp.float32)))
+    turn[0] = (first_buf + n_chunks) % 2
     acc_ref[...] = acc
     m_ref[...] = jnp.broadcast_to(m, m_ref.shape)
     l_ref[...] = jnp.broadcast_to(l, l_ref.shape)
@@ -211,7 +244,8 @@ def latent_decode(q, pool, block_tables, seq_lens, layer, row, *, rank: int,
             out_specs=[per_slot(R, rank), per_slot(R, LANES),
                        per_slot(R, LANES)],
             scratch_shapes=[pltpu.VMEM((2, CHUNK * bs, lanes), pool.dtype),
-                            pltpu.SemaphoreType.DMA((2,))]),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((1,), jnp.int32)]),
         out_shape=[jax.ShapeDtypeStruct((S, R, rank), jnp.float32),
                    jax.ShapeDtypeStruct((S, R, LANES), jnp.float32),
                    jax.ShapeDtypeStruct((S, R, LANES), jnp.float32)],

@@ -187,33 +187,82 @@ def test_preemption_and_the_kernel_change_no_token(toy, want):
     assert same / total > 0.9, (same, total)
 
 
-def test_the_kernel_is_the_list_read(toy):
-    """``latent_decode`` (interpret mode) against the XLA list read on one
-    plane: slots of every kind of length — none, one row, a whole block, past
-    one wave of 16 blocks — to bf16 rounding."""
+_KERNEL_DIMS = dict(Nq=20, lanes=640, width=576, rank=512, bs=16, MB=40, L=2)
+# lengths a slot, at 16 rows a block so a wave is 256 rows: what walks the chain
+# of first waves (``ops/latent_decode.py``: slot s + 1's wave 0 is started
+# under slot s's last wave, into the buffer that wave leaves free)
+_CHAINS = {
+    "every-kind": [0, 1, 16, 300, 640],
+    "idle-first-between-last-but-one": [0, 0, 300, 0, 17],
+    "idle-after-the-first": [300, 0, 0, 0, 0],
+    "all-idle": [0, 0, 0, 0, 0],
+    "one-slot": [300],
+    "odd-and-even-waves": [256, 257, 512, 513],
+    "even-and-odd-waves": [513, 512, 257, 256],
+}
+
+
+def _kernel_case(lens):
+    """(q, pool, tables, lens, row) of ``_KERNEL_DIMS`` for these lengths."""
+    d = _KERNEL_DIMS
     rng = np.random.default_rng(0)
-    S, Nq, lanes, width, rank, bs, MB, L = 5, 20, 640, 576, 512, 16, 40, 2
-    NB = S * MB + 1
+    S, NB = len(lens), len(lens) * d["MB"] + 1
 
     def stored(shape):
-        x = rng.standard_normal(shape + (width,)).astype(np.float32) * 0.5
-        return jnp.asarray(np.pad(x, [(0, 0)] * len(shape) + [(0, lanes - width)]),
-                           jnp.bfloat16)
-    pool, q, row = stored((L, NB, bs)), stored((S, Nq)), stored((S,))
-    lens = np.array([0, 1, 16, 300, 640], np.int32)
-    tables = np.zeros((S, MB), np.int32)
+        x = rng.standard_normal(shape + (d["width"],)).astype(np.float32) * 0.5
+        return jnp.asarray(np.pad(x, [(0, 0)] * len(shape)
+                                  + [(0, d["lanes"] - d["width"])]), jnp.bfloat16)
+    pool, q, row = stored((d["L"], NB, d["bs"])), stored((S, d["Nq"])), stored((S,))
+    lens = np.asarray(lens, np.int32)
+    tables = np.zeros((S, d["MB"]), np.int32)
     perm, k = rng.permutation(np.arange(1, NB)), 0
     for s in range(S):
-        n = -(-int(lens[s]) // bs)
+        n = -(-int(lens[s]) // d["bs"])
         tables[s, :n] = perm[k:k + n]
         k += n
-    for layer in (0, 1):
-        a, b = (np.asarray(latent_attention.latent_read(
-            q, pool, jnp.asarray(tables), jnp.asarray(lens), row,
-            jnp.int32(layer), 1 / 16, rank, backend), np.float32)
-            for backend in ("xla", "pallas"))
-        assert a.shape == b.shape == (S, Nq, rank)
-        assert np.abs(a).max() > 0.5 and np.abs(a - b).max() < 0.02
+    return q, pool, jnp.asarray(tables), jnp.asarray(lens), row
+
+
+@pytest.mark.parametrize("layer", [0, 1])
+@pytest.mark.parametrize("chain", list(_CHAINS))
+def test_the_kernel_is_the_list_read(chain, layer):
+    """``latent_decode`` (interpret mode) against the XLA list read on one
+    plane, to bf16 rounding, over lengths that walk the chain of first waves:
+    slots of every kind of length — none, one row, a whole block, past one wave
+    of 16 blocks —; idle slots first, last and between live ones (an idle slot
+    hands the baton on); exactly one wave, one row past it, two, one row past
+    (the buffer's turn carried through odd and even wave counts, both ways).
+
+    Only slot 0 starts its own first wave, so a live slot after the first that
+    agrees with the list read FOUND its wave 0 started before its grid step
+    began and in the buffer it waited on: the chain engages for every live slot
+    but the first by construction, which is why no counter reports it."""
+    q, pool, tables, lens, row = _kernel_case(_CHAINS[chain])
+    rank = _KERNEL_DIMS["rank"]
+    a, b = (np.asarray(latent_attention.latent_read(
+        q, pool, tables, lens, row, jnp.int32(layer), 1 / 16, rank, backend),
+        np.float32) for backend in ("xla", "pallas"))
+    assert a.shape == b.shape == (len(lens), _KERNEL_DIMS["Nq"], rank)
+    # (300 rows of std 0.5 average to ~0.14 at the most; a short slot is ~2)
+    assert np.abs(a).max() > 0.1 and np.abs(a - b).max() < 0.02
+
+
+def test_the_kernel_is_the_same_from_run_to_run():
+    """The chain leaves nothing to timing: two calls on the same pool are
+    equal bit for bit, and so is a slot whichever slots stand before it (its
+    rows enter its softmax in the same order whatever buffer its waves
+    start in)."""
+    lens = _CHAINS["idle-first-between-last-but-one"]
+    q, pool, tables, ln, row = _kernel_case(lens)
+    rank = _KERNEL_DIMS["rank"]
+    read = lambda q, tables, ln, row: np.asarray(      # noqa: E731
+        latent_decode.latent_decode(q, pool, tables, ln, jnp.int32(1), row,
+                                    rank=rank, sm_scale=1 / 16), np.float32)
+    first = read(q, tables, ln, row)
+    np.testing.assert_array_equal(first, read(q, tables, ln, row))
+    # slot 2 (300 rows: two waves) alone, its waves from buffer 0 on
+    alone = read(q[2:3], tables[2:3], ln[2:3], row[2:3])
+    np.testing.assert_array_equal(first[2], alone[0])
 
 
 def test_the_price_of_the_read():
