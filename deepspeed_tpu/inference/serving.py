@@ -3325,9 +3325,8 @@ class ServingEngine:
         counters are over the experts held.
 
         A looped model (always on, ``ut_steps`` > 1 only): ``ut_steps``,
-        ``kv_planes`` (K/V planes a token keeps: passes x layers),
-        ``kv_bytes_per_token`` (logical pool bytes of one token over all
-        planes), and from the exit gate, over every position a token was
+        ``kv_planes`` (K/V planes a token keeps: passes x layers), and from
+        the exit gate, over every position a token was
         sampled at (the active slots of the plain decode steps, the last
         prompt position of a whole-prompt prefill; ``_note_counters``):
         ``exit_step_expected`` (the mean of sum_t (t + 1) p_t, in 1 ..
@@ -3350,13 +3349,18 @@ class ServingEngine:
         how far sharing a row spares attention where the kernel runs.
 
         The two kinds of state (always on): ``kv_pool_bytes`` (the K/V block
-        pool's share of ``pool_bytes``) and, for a model with recurrent or
+        pool's share of ``pool_bytes``), ``kv_bytes_per_token`` (what the
+        block pool holds a cached token: every plane, scale planes included;
+        of a model with window blocks the FULL planes, the only ones that
+        grow with the context) and ``state_bytes_per_slot`` (what the engine
+        holds a slot whatever its context: every state layer with its
+        convolution tail, every ring; 0 for a model whose request is its
+        blocks alone) — what a cost model is held to —; for a model with recurrent or
         window blocks, ``state_pool_bytes`` (the per-slot state, every
         state leaf of the pool summed) and
         ``state_slots_live`` (slots whose state belongs to a running
         request); for a model with window blocks also ``window_blocks``,
-        ``window_rows`` (rows of a ring), ``kv_bytes_per_token`` (of the
-        FULL planes, the only ones that grow with the context),
+        ``window_rows`` (rows of a ring),
         ``ring_bytes_per_slot`` (all its rings, whatever the context),
         ``window_rows_read`` (rows of ONE window plane the plain rounds'
         first steps read, a ring a live slot) and ``window_rows_in_window``
@@ -3486,12 +3490,16 @@ class ServingEngine:
         # tensor-parallel pool is refused with it): per device = logical
         state_bytes = self._cache_bytes["state"] + self._cache_bytes["rings"]
         out["kv_pool_bytes"] = float(self.pool_bytes - state_bytes)
-        kv_bytes_per_token = float(self._cache_bytes["kv"] // (
+        # what the engine ALLOCATED a cached token (all planes of the block
+        # pool, scale planes included) and a slot (all state layers, tails
+        # and rings included): a family's cost model is held to these
+        out["kv_bytes_per_token"] = float(self._cache_bytes["kv"] // (
             self.num_blocks * self.config.block_size))
+        out["state_bytes_per_slot"] = float(
+            state_bytes // self.config.max_seqs)
         if self._ut_steps > 1:
             out["ut_steps"] = float(self._ut_steps)
             out["kv_planes"] = float(mcfg.kv_planes)
-            out["kv_bytes_per_token"] = kv_bytes_per_token
             if self._exit[-1]:
                 p = self._exit[:-1] / self._exit[-1]
                 out["exit_step_expected"] = float(
@@ -3511,7 +3519,6 @@ class ServingEngine:
             out["window_blocks"] = float(len(next(iter(
                 ring_leaves(self.model, self.pools).values()))))
             out["window_rows"] = float(self.model.ring_rows)
-            out["kv_bytes_per_token"] = kv_bytes_per_token
             out["ring_bytes_per_slot"] = float(
                 self._cache_bytes["rings"] // self.config.max_seqs)
             out["window_rows_read"] = float(

@@ -1402,6 +1402,72 @@ def glm4_moe_lite_weight_names(cfg) -> Dict[str, tuple]:
     return out
 
 
+class FalconH1Unsupported(NotImplementedError):
+    """A ``falcon_h1`` config key whose value nothing here computes
+    (``.key``, ``.value``): refused at the import, not at the first step."""
+
+    def __init__(self, key: str, value, want):
+        self.key, self.value = key, value
+        super().__init__(f"falcon_h1 {key}={value!r} is not supported (this "
+                         f"importer takes {want!r})")
+
+
+def _falcon_h1_kwargs(get) -> dict:
+    """``falcon_h1`` (TII Falcon-H1): every layer is a ``P`` block — a
+    Mamba-2 mixer and rotary GQA attention side by side on ONE RMSNorm, their
+    outputs scaled and summed into one residual — then a ``D`` block (SwiGLU
+    of ``intermediate_size``). The muP multipliers stay what they are
+    published as, static scalars of the forward (``TransformerConfig``): the
+    stored tensors are the checkpoint's. Refused, typed
+    (``FalconH1Unsupported``): a Mamba mixer without its gated RMSNorm or
+    with the norm BEFORE the gate, a layer without the feed-forward, rope
+    scaling, attention on some layers only, any bias but the convolution's,
+    another activation than silu."""
+    for key, want in (("mamba_rms_norm", True),
+                      ("mamba_norm_before_gate", False),
+                      ("mamba_use_mlp", True), ("rope_scaling", None),
+                      ("attn_layer_indices", None), ("hidden_act", "silu"),
+                      ("attention_bias", False), ("mlp_bias", False),
+                      ("mamba_proj_bias", False), ("projectors_bias", False),
+                      ("mamba_conv_bias", True)):
+        if get(key, want) != want:
+            raise FalconH1Unsupported(key, get(key), want)
+    H, nh, hd = get("hidden_size"), get("mamba_n_heads"), get("mamba_d_head")
+    d_ssm = get("mamba_d_ssm") or get("mamba_expand", 2) * H
+    if d_ssm != nh * hd:
+        raise FalconH1Unsupported(
+            "mamba_d_ssm", d_ssm, f"mamba_n_heads x mamba_d_head = {nh * hd}")
+    L = get("num_hidden_layers")
+    return dict(
+        vocab_size=get("vocab_size"), hidden_size=H,
+        num_layers=2 * L, block_pattern="PD" * L,
+        num_heads=get("num_attention_heads"),
+        num_kv_heads=get("num_key_value_heads"),
+        head_dim=get("head_dim") or H // get("num_attention_heads"),
+        max_seq_len=get("max_position_embeddings", 4096),
+        norm_eps=float(get("rms_norm_eps", 1e-5)),
+        position_type="rotary", rope_theta=float(get("rope_theta", 10000.0)),
+        norm_type="rmsnorm", activation="silu_glu",
+        tie_embeddings=bool(get("tie_word_embeddings", False)),
+        intermediate_size=get("intermediate_size"),
+        mamba_num_heads=nh, mamba_head_dim=hd,
+        mamba_n_groups=get("mamba_n_groups", 1),
+        ssm_state_size=get("mamba_d_state"),
+        conv_kernel=get("mamba_d_conv", 4),
+        mamba_chunk=get("mamba_chunk_size", 128),
+        embed_scale=float(get("embedding_multiplier", 1.0)),
+        lm_head_multiplier=float(get("lm_head_multiplier", 1.0)),
+        attention_in_multiplier=float(get("attention_in_multiplier", 1.0)),
+        attention_out_multiplier=float(get("attention_out_multiplier", 1.0)),
+        key_multiplier=float(get("key_multiplier", 1.0)),
+        ssm_in_multiplier=float(get("ssm_in_multiplier", 1.0)),
+        ssm_out_multiplier=float(get("ssm_out_multiplier", 1.0)),
+        ssm_multipliers=tuple(float(m) for m in
+                              get("ssm_multipliers", (1.0,) * 5)),
+        mlp_multipliers=tuple(float(m) for m in
+                              get("mlp_multipliers", (1.0, 1.0))))
+
+
 def _rope_table(kind: str, params: dict):
     """One entry of a published ``rope_parameters`` group -> a ``RopeTable``
     (``rope_type`` ``default`` or ``yarn``; ``attention_factor`` as
@@ -1644,6 +1710,8 @@ def hf_config_to_transformer(hf_cfg, **overrides):
         kw = _mellum_kwargs(get)
     elif mt == "glm4_moe_lite":
         kw = _glm4_moe_lite_kwargs(get)
+    elif mt == "falcon_h1":
+        kw = _falcon_h1_kwargs(get)
     elif mt == "opt":
         if get("word_embed_proj_dim", get("hidden_size")) != get("hidden_size"):
             raise ValueError(

@@ -1,4 +1,5 @@
-"""Hybrid stacks: one MIXER per block, its kind from a static pattern.
+"""Hybrid stacks: one MIXER per block — two side by side in a ``P`` block —,
+its kind from a static pattern.
 
 ``TransformerConfig.block_pattern`` is one letter a block (``nemotron_h``'s
 ``hybrid_override_pattern``; ``qwen3_next``'s and ``afmoe``'s layers are TWO
@@ -7,8 +8,13 @@ blocks each, a token mixer then a feed-forward): ``M`` a Mamba-2 mixer
 (``models/gated_deltanet.py``), ``E`` an expert feed-forward
 (``moe/sharded_moe.py``), ``D`` a dense feed-forward, ``*`` attention over
 the whole history, ``W`` attention over the last ``window(cfg)`` positions,
-``L`` multi-head latent attention (``models/latent_attention.py``).
-Every block is ``h <- h + mixer(RMSNorm(h))`` — through a second RMSNorm
+``L`` multi-head latent attention (``models/latent_attention.py``), ``P``
+(``falcon_h1``) a Mamba-2 mixer AND rotary attention over the whole history on
+ONE normed input, ``h <- h + ssm_out_multiplier Mamba2(n) +
+attention_out_multiplier Attn(n attention_in_multiplier)``, ``n =
+RMSNorm(h)``: one norm, one residual, a K/V plane of the block pool and a
+layer of the ``ssm`` / ``conv`` state pool (``plane_of``, ``state_layer``).
+Every other block is ``h <- h + mixer(RMSNorm(h))`` — through a second RMSNorm
 after the mixer where the stack has one (``sandwich_norm``) —: the walker
 puts no feed-forward after attention and no attention before an expert layer,
 the pattern does. The rotary rule is per KIND (``rope_table``): a ``*`` block
@@ -19,15 +25,20 @@ table a kind (``rope_tables``: ``mellum``'s plain table on the ``W`` blocks,
 YaRN on the ``*`` blocks) has said it all; both take a per-head RMSNorm of q and k
 (``qk_norm_per_head``) and a sigmoid gate on their output, projected beside q
 (``attn_out_gate``), as the config says. ``embed_scale`` multiplies the
-embeddings. ``models/transformer.py``'s ``init_params``, ``logical_axes``,
+embeddings; the other muP multipliers of ``falcon_h1`` (``lm_head_multiplier``
+on the logits, ``key_multiplier`` on k, ``mlp_multipliers`` on a ``D`` block's
+gate and output, the Mamba mixer's in ``models/mamba.py``) are static scalars
+of the forward too, and a config that leaves one at 1 has no multiply for it
+in its programs. ``models/transformer.py``'s ``init_params``, ``logical_axes``,
 ``forward``, ``init_paged_cache``, ``prefill_paged`` and
 ``decode_step_paged`` hand a config with a pattern to the functions here, so
 a hybrid model is a ``make_model`` like any other and serves through the same
 engine.
 
 Parameters are stacked PER KIND (``params["layers"]["mamba" | "gdn" | "moe"
-| "dense" | "attn" | "wattn" | "latent"]``, leading dim = blocks of that
-kind). The walk
+| "dense" | "attn" | "wattn" | "latent" | "par"]``, leading dim = blocks of
+that kind; a ``P`` block's stack holds the leaves of both its mixers under
+the one ``ln_scale``). The walk
 over a pattern that does not repeat is unrolled: a block's index within its
 kind is a Python int, its slice of a stack a static one. A pattern that
 repeats (three periods of ``GEGEGE*E``) is a ``lax.scan`` over its repeats
@@ -36,12 +47,14 @@ way a program is shaped by the pool and table dims only.
 
 The cache is three kinds of state side by side in one tree (the serving
 engine's ``srv.pools``): the block pool — ``k`` / ``v``, whose layer dim
-counts the ``*`` blocks only (absent without one), and ``latent`` [planes, NB,
-block, stored width], ONE row a token and ``L`` block that is both K and V
+counts the ``*`` and ``P`` blocks only (absent without one), and ``latent``
+[planes, NB, block, stored width], ONE row a token and ``L`` block that is
+both K and V
 (``latent_leaf``), paged by the same tables and blocks —; a per-slot state
 pool for the recurrent blocks — ``ssm`` float32
 ``[Lm, slots, heads, P, N]`` and ``conv`` ``[Lm, slots, K - 1, conv_dim]``
-(the last K - 1 rows of ``xBC`` before the convolution) for the ``M`` blocks,
+(the last K - 1 rows of ``xBC`` before the convolution) for the ``M`` and
+``P`` blocks,
 ``gdn`` float32 ``[Lg, slots, value heads, dk, dv]`` and ``gdn_conv`` ``[Lg,
 slots, K - 1, conv_dim]`` for the ``G`` blocks —; and per slot and ``W``
 block a RING of the last ``window`` positions' K/V (``ring_leaves``), its
@@ -65,10 +78,15 @@ from deepspeed_tpu.models import mamba
 from deepspeed_tpu.moe import sharded_moe as _moe
 
 KINDS = {"M": "mamba", "G": "gdn", "E": "moe", "*": "attn", "W": "wattn",
-         "D": "dense", "L": "latent"}
+         "D": "dense", "L": "latent", "P": "par"}
 # the kinds whose blocks are softmax attention: "attn" keeps every position in
 # the K/V block pool, "wattn" the last ``window(cfg)`` in a ring per slot
 ATTN_KINDS = ("attn", "wattn")
+# the kinds that own a plane of ``k`` / ``v`` in the block pool, and those
+# that own a layer of ``ssm`` / ``conv`` in the state pool, each in the order
+# the planes / layers are stacked: a kind's blocks follow the kind before it
+PLANE_KINDS = ("attn", "par")
+SSM_KINDS = ("mamba", "par")
 
 
 def blocks(cfg):
@@ -91,7 +109,7 @@ def blocks(cfg):
                 f"block_pattern letter {letter!r}: one of {sorted(KINDS)} "
                 "(M Mamba-2, G Gated DeltaNet, E experts, D dense "
                 "feed-forward, * attention, W sliding-window attention, L "
-                "latent attention)")
+                "latent attention, P Mamba-2 and attention side by side)")
         kind = KINDS[letter]
         out.append((kind, seen.get(kind, 0)))
         seen[kind] = seen.get(kind, 0) + 1
@@ -100,6 +118,23 @@ def blocks(cfg):
 
 def count(cfg, kind: str) -> int:
     return sum(1 for k, _ in blocks(cfg) if k == kind)
+
+
+def _stacked_at(cfg, kinds, kind: str, j):
+    """Where block ``j`` of ``kind`` lies in a stack over the blocks of
+    ``kinds``, one kind after the other: ``j`` (a Python int, or traced in a
+    scanned walk) past the blocks of the kinds before it."""
+    return sum(count(cfg, k) for k in kinds[:kinds.index(kind)]) + j
+
+
+def plane_of(cfg, kind: str, j):
+    """The K/V plane of the block pool that block ``j`` of ``kind`` owns."""
+    return _stacked_at(cfg, PLANE_KINDS, kind, j)
+
+
+def state_layer(cfg, kind: str, j):
+    """The layer of ``ssm`` / ``conv`` that block ``j`` of ``kind`` owns."""
+    return _stacked_at(cfg, SSM_KINDS, kind, j)
 
 
 def window(cfg) -> int:
@@ -132,7 +167,16 @@ def init_params(key, cfg):
     Mamba-2's draw of ``A_log`` and ``dt_bias`` (decays of 0.001 to 1.6 a
     position before the data-dependent part), a convolution without bias and
     an output-norm scale ``w_n`` in U(0.5, 1.5); a gated shared expert's
-    gate vector is drawn like a router column."""
+    gate vector is drawn like a router column.
+
+    A stack with muP multipliers (``falcon_h1``: the ``P`` blocks, and the
+    ``D`` blocks and the head where ``mlp_multipliers`` /
+    ``lm_head_multiplier`` are stated) draws each projection so that WITH its
+    multipliers its output is of the order of what it joins (``_mup_std``):
+    at a flat 0.02 the keys are ``key_multiplier`` x 1.4 and every score is 0,
+    so neither rotary nor the mask would move a token. A ``P`` block's
+    Mamba leaves are drawn as an ``M`` block's, its ``gate_norm`` in
+    U(0.5, 1.5)."""
     H, V, dt = cfg.hidden_size, cfg.vocab_size, cfg.param_dtype
     std = 0.02
     out_scale = std / math.sqrt(2 * cfg.num_layers)
@@ -152,6 +196,9 @@ def init_params(key, cfg):
 
     def normal(shape, scale=std):
         return (jax.random.normal(next(keys), shape) * scale).astype(dt)
+
+    # what an output projection joins: the embeddings as the stream has them
+    stream = std * cfg.embed_init_scale * cfg.embed_scale
 
     def uniform(shape, lo, hi):
         return jax.random.uniform(next(keys), shape, jnp.float32, lo, hi)
@@ -251,11 +298,18 @@ def init_params(key, cfg):
     Ld = count(cfg, "dense")
     if Ld:
         F = cfg.dense_ffn_size or cfg.ffn_dim
+        scales = (std, out_scale, std)
+        if cfg.mlp_multipliers is not None:
+            # up and the scaled gate of order 1; up x silu(gate) then has an
+            # rms of 0.6 (E silu(g)^2 = 0.356 for a standard normal g)
+            g, d = cfg.mlp_multipliers
+            scales = (_mup_std(H), _mup_std(F, d, to=stream / 0.6),
+                      _mup_std(H, g))
         layers["dense"] = {"ln_scale": jnp.ones((Ld, H), dt),
-                           "w_in": normal((Ld, H, F)),
-                           "w_out": normal((Ld, F, H), out_scale)}
+                           "w_in": normal((Ld, H, F), scales[0]),
+                           "w_out": normal((Ld, F, H), scales[1])}
         if "glu" in cfg.activation:
-            layers["dense"]["w_gate"] = normal((Ld, H, F))
+            layers["dense"]["w_gate"] = normal((Ld, H, F), scales[2])
     Ll = count(cfg, "latent")
     if Ll:
         # drawn last: every other kind keeps the keys it had
@@ -264,6 +318,41 @@ def init_params(key, cfg):
             layers["latent"][name] = (
                 norm_scale((Ll,) + shape) if name.endswith("_norm")
                 else normal((Ll,) + shape, out_scale if name == "wo" else std))
+    Lp = count(cfg, "par")
+    if Lp:
+        # drawn after every other kind: each keeps the keys it had
+        nh, hd, G, N, d_inner, conv_dim, K = mamba.dims(cfg)
+        nq, nkv, ahd = cfg.num_heads, cfg.kv_heads, cfg.dim_per_head
+        # q and k of std sqrt(2) each: scores of std 2, a softmax that a few
+        # keys share (its output's rms ~0.25 over some hundred positions of
+        # unit-variance v); z, x, B, C and dt of order 1 under the ONE std
+        # in_proj has (the five multipliers' geometric mean: 0.18 at the
+        # published ones, which gives 1.15, 0.81, 0.57, 1.62, 1.15); the
+        # gated norm hands out_proj rows of rms 1
+        mup = cfg.ssm_multipliers or (1.0,) * 5
+        layers["par"] = {
+            "ln_scale": jnp.ones((Lp, H), dt),
+            "dt_bias": dt_bias((Lp, nh)),
+            "in_proj": normal((Lp, H, d_inner + conv_dim + nh), _mup_std(
+                H, cfg.ssm_in_multiplier,
+                math.prod(mup) ** (1 / len(mup)))),
+            "conv_w": uniform((Lp, K, conv_dim), -0.5, 0.5).astype(dt),
+            "conv_b": uniform((Lp, conv_dim), -0.5, 0.5).astype(dt),
+            "A_log": jnp.log(uniform((Lp, nh), 1.0, 16.0)).astype(dt),
+            "D": uniform((Lp, nh), 0.5, 1.5).astype(dt),
+            "gate_norm": uniform((Lp, d_inner), 0.5, 1.5).astype(dt),
+            "out_proj": normal((Lp, d_inner, H), _mup_std(
+                d_inner, cfg.ssm_out_multiplier, to=stream)),
+            "wq": normal((Lp, H, nq * ahd), _mup_std(
+                H, cfg.attention_in_multiplier, to=math.sqrt(2))),
+            "wk": normal((Lp, H, nkv * ahd), _mup_std(
+                H, cfg.attention_in_multiplier, cfg.key_multiplier,
+                to=math.sqrt(2))),
+            "wv": normal((Lp, H, nkv * ahd), _mup_std(
+                H, cfg.attention_in_multiplier)),
+            "wo": normal((Lp, nq * ahd, H), _mup_std(
+                nq * ahd, cfg.attention_out_multiplier, to=stream / 0.25)),
+        }
     for kind, stacks in layers.items():
         n = stacks["ln_scale"].shape[0]
         if cfg.norm_init_jitter:
@@ -274,8 +363,16 @@ def init_params(key, cfg):
               "layers": layers,
               "final_norm_scale": norm_scale((H,))}
     if not cfg.tie_embeddings:
-        params["lm_head"] = normal((H, V))
+        # a stated logit multiplier: logits of order 1 all the same
+        params["lm_head"] = normal((H, V), std if cfg.lm_head_multiplier == 1.0
+                                   else _mup_std(H, cfg.lm_head_multiplier))
     return params
+
+
+def _mup_std(fan_in: int, *multipliers, to: float = 1.0) -> float:
+    """The std of a projection whose output, the multipliers on its input
+    and output applied, has std ``to`` for an input of rms 1."""
+    return to / (math.sqrt(fan_in) * math.prod(multipliers))
 
 
 def logical_axes(cfg):
@@ -327,6 +424,18 @@ def logical_axes(cfg):
         if cfg.qk_norm_per_head:
             layers[kind]["q_norm"] = ("layers", None)
             layers[kind]["k_norm"] = ("layers", None)
+    if count(cfg, "par"):
+        # both mixers' leaves; the recurrent state is not split over
+        # ``tensor``, and the serving engine refuses that degree with it
+        layers["par"] = {
+            "ln_scale": ("layers", "unmodeled"),
+            "in_proj": ("layers", "embed", None),
+            "conv_w": ("layers", None, None), "conv_b": ("layers", None),
+            "dt_bias": ("layers", None), "A_log": ("layers", None),
+            "D": ("layers", None), "gate_norm": ("layers", None),
+            "out_proj": ("layers", None, "embed"),
+            "wq": ("layers", "embed", "qkv"), "wk": ("layers", "embed", "qkv"),
+            "wv": ("layers", "embed", "qkv"), "wo": ("layers", "heads", "embed")}
     if count(cfg, "dense"):
         layers["dense"] = {"ln_scale": ("layers", "unmodeled"),
                            "w_in": ("layers", "embed", "mlp"),
@@ -383,7 +492,7 @@ def rope_table(cfg, kind: str):
     """The rotary table of an attention block of ``kind``, or None where it
     carries no positional embedding: the table the config states for the kind
     (``rope_tables``), else plain ``rope_theta`` — on every "wattn" block, and
-    on an "attn" block where ``position_type`` says rotary."""
+    on an "attn" or "par" block where ``position_type`` says rotary."""
     from deepspeed_tpu.models.transformer import RopeTable
     if cfg.rope_tables is not None:
         return dict(cfg.rope_tables)[kind]
@@ -396,7 +505,8 @@ def _qkv(p, h, cfg, positions, kind: str):
     """h [B, T, H] of a block of ``kind`` -> (q [B, T, nq, hd], k, v [B, T,
     nkv, hd], gate [B, T, nq hd] or None). What the config names is applied
     in HF's order: the output gate's columns split off q (``attn_out_gate``:
-    a head's columns are [q | gate]), the per-head RMSNorm of q and k
+    a head's columns are [q | gate]), ``key_multiplier`` on k, the per-head
+    RMSNorm of q and k
     (``q_norm`` / ``k_norm`` [hd]), rotary at ``positions`` [B, T] over the
     first ``rotary_dim`` dims by the kind's table (``rope_table``)."""
     from deepspeed_tpu.models.transformer import (_rms_whole, _wmat,
@@ -410,6 +520,8 @@ def _qkv(p, h, cfg, positions, kind: str):
         q, gate = q[..., :hd], q[..., hd:].reshape(B, T, cfg.num_heads * hd)
     q = q.reshape(B, T, cfg.num_heads, hd)
     k = _wmat(h, p["wk"]).reshape(B, T, cfg.kv_heads, hd)
+    if cfg.key_multiplier != 1.0:
+        k = k * cfg.key_multiplier
     v = _wmat(h, p["wv"]).reshape(B, T, cfg.kv_heads, hd)
     if "q_norm" in p:
         q = _rms_whole(q, p["q_norm"], cfg.norm_eps)
@@ -450,11 +562,28 @@ def _attn_mixer(p, h, cfg, kind: str = "attn"):
 
 
 def _dense_mixer(p, h, cfg):
-    """A dense feed-forward block h [B, T, H] -> [B, T, H]."""
+    """A dense feed-forward block h [B, T, H] -> [B, T, H]; with
+    ``mlp_multipliers`` (g, d): ``down(up(h) act(gate(h) g)) d``."""
     from deepspeed_tpu.models.transformer import _activation, _wmat, _wrow
+    g, d = cfg.mlp_multipliers or (1.0, 1.0)
     with jax.named_scope("mlp"):
         gate = _wmat(h, p["w_gate"]) if "w_gate" in p else None
-        return _wrow(_activation(_wmat(h, p["w_in"]), gate, cfg), p["w_out"])
+        if gate is not None and g != 1.0:
+            gate = gate * g
+        y = _wrow(_activation(_wmat(h, p["w_in"]), gate, cfg), p["w_out"])
+        return y if d == 1.0 else y * d
+
+
+def _attn_in(h, cfg):
+    """The normed rows as a "P" block's attention branch takes them."""
+    m = cfg.attention_in_multiplier
+    return h if m == 1.0 else h * m
+
+
+def _par_join(m, a, cfg):
+    """A "P" block's two branches, each times its output multiplier, as the
+    ONE update its residual adds."""
+    return m * cfg.ssm_out_multiplier + a * cfg.attention_out_multiplier
 
 
 def _residual(p, x, y, cfg):
@@ -479,7 +608,9 @@ def _final_norm(params, x, cfg):
 def _head(params, x, cfg):
     from deepspeed_tpu.models.transformer import lm_head_logits
     with jax.named_scope("lm_head"):
-        return lm_head_logits(_final_norm(params, x, cfg), params)
+        logits = lm_head_logits(_final_norm(params, x, cfg), params)
+        return (logits if cfg.lm_head_multiplier == 1.0
+                else logits * cfg.lm_head_multiplier)
 
 
 def _embed(params, ids, cfg):
@@ -495,7 +626,8 @@ def _embed(params, ids, cfg):
 def period(cfg):
     """(unit, n): the pattern as ``n`` repeats of its shortest unit; a
     pattern that does not repeat is one unit. DEFERRED: any pattern with
-    Mamba-2 blocks is one unit too, because ``%ssm_step`` takes its block's
+    Mamba-2 blocks ("M", or "P" with its two mixers) is one unit too, because
+    ``%ssm_step`` takes its block's
     index as a Python int (``ops/ssm.py``: a literal in the block index, where
     ``%gdn_step``'s is a prefetched scalar). No pattern the benchmark runs
     has both Mamba-2 blocks and a repeat, and the traced index would change
@@ -505,7 +637,7 @@ def period(cfg):
     one unit as well: their rings are one array a block (``ring_leaves``
     says why), which a traced index cannot choose among."""
     pattern = cfg.block_pattern
-    if "M" not in pattern and "W" not in pattern:
+    if not set("MPW") & set(pattern):
         for size in range(1, len(pattern) // 2 + 1):
             if pattern == pattern[:size] * (len(pattern) // size):
                 return pattern[:size], len(pattern) // size
@@ -558,6 +690,15 @@ def _walk(params, cfg, carry, block):
         lambda a: a.reshape((-1,) + a.shape[2:]), outs))
 
 
+def _planes(outs):
+    """What the blocks that own a K/V plane handed on, stacked in the planes'
+    order (``PLANE_KINDS``: a kind's blocks after the kind before it)."""
+    parts = [_stacked(outs[k]) for k in PLANE_KINDS if k in outs]
+    if len(parts) == 1:
+        return parts[0]
+    return jax.tree.map(lambda *a: jnp.concatenate(a), *parts)
+
+
 def _stacked(outs):
     """A list of per-block pytrees -> one pytree of arrays stacked on a new
     leading dim; what is stacked already as it is."""
@@ -600,6 +741,12 @@ def forward(params, input_ids, cfg, *, deterministic: bool = True,
                     y = _dense_mixer(p, h, cfg)
                 elif kind == "latent":
                     y = latent.mixer_forward(p, h, cfg)[0]
+                elif kind == "par":
+                    with jax.named_scope("mix/ssm"):
+                        m = mamba.mixer_forward(p, h, cfg)
+                    with jax.named_scope("mix/attn"):
+                        a = _attn_mixer(p, _attn_in(h, cfg), cfg, kind)[0]
+                    y = _par_join(m, a, cfg)
                 else:
                     y = _attn_mixer(p, h, cfg, kind)[0]
                 return (_residual(p, x, y, cfg), aux_total), (
@@ -627,8 +774,10 @@ def forward(params, input_ids, cfg, *, deterministic: bool = True,
 def init_paged_cache(cfg, num_blocks: int, block_size: int, dtype=None,
                      max_seqs: Optional[int] = None):
     """``k``, ``v`` (+ int8 scale planes) exactly as ``transformer.
-    init_paged_cache`` lays them out, over the "*" ATTENTION blocks only
-    (none: no such leaves), ``latent`` over the "L" blocks (``latent_leaf``),
+    init_paged_cache`` lays them out, over the "*" and "P" blocks only
+    (``cfg.kv_planes``; none: no such leaves; stored head-major where
+    ``blocks_head_major`` says so), ``latent`` over the "L" blocks
+    (``latent_leaf``),
     and the per-slot leaves of ``state_leaves`` for ``max_seqs`` slots:
     ``ssm`` / ``conv`` (the ``M`` blocks), ``gdn`` / ``gdn_conv`` (the ``G``
     blocks), ``wk`` / ``wv`` (+ scales: the ``W`` blocks' rings), each only
@@ -641,14 +790,15 @@ def init_paged_cache(cfg, num_blocks: int, block_size: int, dtype=None,
                          "max_seqs")
     dtype = dtype or cfg.dtype
     pools = {}
-    if count(cfg, "attn"):
+    if cfg.kv_planes:
         pools = tf.init_paged_cache(
             dataclasses.replace(cfg, block_pattern=None, attn_windows=None,
-                                num_layers=count(cfg, "attn")),
+                                num_layers=cfg.kv_planes),
             num_blocks, block_size, dtype=dtype)
     if cfg.latent_planes:
         pools["latent"] = jnp.zeros(*latent_leaf(cfg, num_blocks, block_size,
                                                  dtype))
+    pools = _token_major(pools, cfg)            # (its own inverse)
     for name, (shape, leaf_dtype) in state_leaves(cfg, max_seqs,
                                                   dtype).items():
         pools[name] = jnp.zeros(shape, leaf_dtype)
@@ -665,7 +815,7 @@ def state_leaves(cfg, max_seqs: int, dtype=None) -> dict:
     each recurrent kind the pattern has, stacked on the blocks of the kind."""
     dtype = dtype or cfg.dtype
     out = {}
-    Lm, Lg = count(cfg, "mamba"), count(cfg, "gdn")
+    Lm, Lg = sum(count(cfg, k) for k in SSM_KINDS), count(cfg, "gdn")
     if Lm:
         nh, hd, _, N, _, conv_dim, K = mamba.dims(cfg)
         out["ssm"] = ((Lm, max_seqs, nh, hd, N), jnp.float32)
@@ -675,6 +825,56 @@ def state_leaves(cfg, max_seqs: int, dtype=None) -> dict:
         out["gdn"] = ((Lg, max_seqs, Hv, dk, dv), jnp.float32)
         out["gdn_conv"] = ((Lg, max_seqs, K - 1, conv_dim), dtype)
     return out
+
+
+def blocks_head_major(cfg) -> bool:
+    """Whether ``k`` / ``v`` are STORED [planes, NB, n_kv, block, head dim],
+    a block's rows next to the head dim, and read and written through the
+    token-major view of them (``_token_major``, a bitcast). Decided from
+    what the pool's leaves are — their dtype and their heads — and from
+    whether anything outside this module ever reads a block's bytes.
+
+    FOUR rows of int8 pack into ONE 32-bit sublane word, so an int8 pool of
+    exactly four K/V heads is dense token-major by the compiler's default
+    (``T(4,128)(4,1)``), while every op of the TPU's that reads or writes
+    whole blocks of it (the list read's gather, a prefill's block write)
+    works on the block's rows next to the head dim (``{4,2,3,1,0:T(8,128)
+    (4,1)}``): declared token-major, the compiler relayouts each whole leaf
+    once a quantum call and around every prefill's block write. A pool of 2
+    heads is stored head-major by the compiler's own default (2 rows would
+    pad the word), 8 and 16 heads fill whole words and tiles either way, and
+    a float pool packs no rows: none of them is relayouted, and they keep
+    the token-major declaration (``tests/unit/test_pool_layout.py`` holds
+    int8 pools of 8 and 16 heads free of whole-leaf copies on that side of
+    the rule, compiled for a described v5e, and one of four on both). Both
+    sides at the published sizes of
+    the one benchmark stack with four int8 heads (PERF.md section 6, PR 57,
+    the step compiled for a described v5e and both run on the chip): 1.54
+    GiB of step temporaries token-major against 0.17, a step 24.3 ms against
+    20.6, the two whole-leaf copies 10.7 % of the device, 2 321 tokens/s
+    against 2 797, and one run in six with a round stalled for 3-4 s.
+
+    Only where the blocks never leave the pool by themselves: a stack that
+    keeps a state a slot beside them (``cfg.slot_state_blocks``) is refused
+    the prefix cache, the K/V export and the swap-out (``inference/serving.
+    py`` ``_by_blocks_alone``), whose readers and writers take a block's
+    bytes token-major; there the order of the bytes is this module's own
+    business. A stack of attention planes alone keeps the declaration those
+    readers know."""
+    return cfg.kv_cache_bits == 8 and cfg.kv_heads == 4 \
+        and bool(cfg.slot_state_blocks)
+
+
+def _token_major(pools, cfg):
+    """The cache tree with ``k`` / ``v`` as every reader and writer here
+    takes them, [planes, NB, block, n_kv, head dim]: the leaves themselves,
+    or — where they are stored head-major (``blocks_head_major``) — their
+    transposed view, which the compiler lays out as the stored bytes. Its
+    own inverse."""
+    if not blocks_head_major(cfg):
+        return pools
+    return {n: (jnp.swapaxes(a, 2, 3) if n in ("k", "v") else a)
+            for n, a in pools.items()}
 
 
 def latent_leaf(cfg, num_blocks: int, block_size: int, dtype=None):
@@ -725,9 +925,13 @@ def paged_cache_logical_axes(cfg):
     from deepspeed_tpu.models import transformer as tf
     import dataclasses
     out = {}
-    if count(cfg, "attn"):
+    if cfg.kv_planes:
         out = tf.paged_cache_logical_axes(
             dataclasses.replace(cfg, block_pattern=None, attn_windows=None))
+        if blocks_head_major(cfg):
+            for name in ("k", "v"):
+                a = out[name]
+                out[name] = a[:2] + (a[3], a[2]) + a[4:]
     if cfg.latent_planes:         # one row for every head: nothing to split
         out["latent"] = (None,) * 4
     for name, (shape, _) in state_leaves(cfg, 1).items():
@@ -905,7 +1109,7 @@ def prefill_paged(params, input_ids, cfg, pools, block_ids,
     if segments is None:
         true_len = jnp.asarray(P if length is None else length, jnp.int32)
     else:
-        if slotted or count(cfg, "attn") or length is not None:
+        if slotted or cfg.kv_planes or length is not None:
             raise NotImplementedError(
                 "segments share ONE row and take the place of length; a "
                 "stack with recurrent, window or per-head attention blocks "
@@ -913,7 +1117,19 @@ def prefill_paged(params, input_ids, cfg, pools, block_ids,
         starts, lengths = (jnp.asarray(a, jnp.int32) for a in segments)
         seg, pos, real, _, last_rows = _packed_row(starts, lengths, P)
         packed = {"segment_ids": seg, "positions": pos}
-    pools = dict(pools)
+    pools = dict(_token_major(pools, cfg))
+
+    def ssm_prefill(p, h, layer, state):
+        """The Mamba-2 mixer over the prompt from a zero state; the state
+        after the last true position and the convolution tail into ``slot``'s
+        rows of ``layer`` of the state pool."""
+        y, s, tail = mamba.mixer_prefill(p, h[0], cfg, true_len)
+        y = y[None]
+        with jax.named_scope("ssm"), jax.named_scope("state_write"):
+            state["ssm"] = state["ssm"].at[layer, slot].set(s)
+            state["conv"] = state["conv"].at[layer, slot].set(
+                tail.astype(state["conv"].dtype))
+        return y, state
 
     def block(i, kind, j, p, carry):
         x, state = carry
@@ -921,12 +1137,15 @@ def prefill_paged(params, input_ids, cfg, pools, block_ids,
         with jax.named_scope(f"layer{i}"):
             h = _norm_in(p, x, cfg)
             if kind == "mamba":
-                y, s, tail = mamba.mixer_prefill(p, h[0], cfg, true_len)
-                y = y[None]
-                with jax.named_scope("ssm"), jax.named_scope("state_write"):
-                    state["ssm"] = state["ssm"].at[j, slot].set(s)
-                    state["conv"] = state["conv"].at[j, slot].set(
-                        tail.astype(state["conv"].dtype))
+                y, state = ssm_prefill(p, h, j, state)
+            elif kind == "par":
+                with jax.named_scope("mix/ssm"):
+                    m, state = ssm_prefill(p, h, state_layer(cfg, kind, j),
+                                           state)
+                with jax.named_scope("mix/attn"):
+                    a, k, v = _attn_mixer(p, _attn_in(h, cfg), cfg, kind)
+                y = _par_join(m, a, cfg)
+                out = (jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2))
             elif kind == "gdn":
                 y, s, tail = gdn.mixer_prefill(p, h[0], cfg, true_len)
                 y = y[None]
@@ -959,10 +1178,11 @@ def prefill_paged(params, input_ids, cfg, pools, block_ids,
                 _write_ring_prefill(ring, slot, k, v, true_len, cfg)
                 for ring, (k, v) in zip(_rings(state), kv["wattn"])])
     pools.update(state)
-    if "attn" in kv:
-        # the attention blocks' K/V as transformer.prefill_paged's
-        # contiguous cache holds them, [La, 1, nkv, P, hd]: one writer
-        cache = dict(zip(("k", "v"), _stacked(kv["attn"])))
+    if cfg.kv_planes:
+        # the K/V of the blocks that own a plane as transformer.
+        # prefill_paged's contiguous cache holds them, [La, 1, nkv, P, hd]:
+        # one writer
+        cache = dict(zip(("k", "v"), _planes(kv)))
         if cfg.kv_cache_bits == 8:
             (cache["k"], cache["k_scale"]), (cache["v"], cache["v_scale"]) = \
                 _quant_kv(cache["k"]), _quant_kv(cache["v"])
@@ -976,14 +1196,17 @@ def prefill_paged(params, input_ids, cfg, pools, block_ids,
                 _stacked(kv["latent"])[:, 0].astype(pool.dtype), cfg)
             pools["latent"] = pool.at[:, block_ids].set(
                 rows.reshape(rows.shape[0], -1, *pool.shape[2:]))
+    pools = _token_major(pools, cfg)
     if segments is not None:
         return _head(params, x[:, last_rows], cfg)[0], pools
     last = lax.dynamic_index_in_dim(x, true_len - 1, axis=1, keepdims=True)
     return _head(params, last, cfg)[:, 0], pools
 
 
-def _write_rows(pools, blk, off, rows):
-    """One K/V row per slot into every attention block's plane (``transformer.
+def _write_rows(pools, blk, off, rows, head_major: bool = False):
+    """One K/V row per slot into every plane of the pool — ``k`` / ``v`` [La,
+    NB, block, n_kv, hd], or [La, NB, n_kv, block, hd] with ``head_major`` —
+    (``transformer.
     _scatter_rows``'s contract), as one scatter per (plane, head), whose
     window is a head's row alone: the pool of a model with FEW K/V heads is
     stored by the TPU with the block's rows next to the head dim (2 heads
@@ -1002,14 +1225,18 @@ def _write_rows(pools, blk, off, rows):
     golden text again and deletes the branch."""
     from deepspeed_tpu.models.transformer import _scatter_rows
     La, _, nkv = rows["k"].shape[:3]
-    if La == 1:
+    if La == 1 and not head_major:
         return _scatter_rows(pools, blk, off, rows)
-    bs, out = pools["k"].shape[2], {}
+    bs, out = pools["k"].shape[3 if head_major else 2], {}
     for name, r in rows.items():
         pool = pools[name]
         for j in range(La):
             for h in range(nkv):
-                where = (j, blk, off, h) if r.ndim == 4 else (j, blk, h * bs + off)
+                if r.ndim != 4:
+                    where = (j, blk, h * bs + off)
+                else:     # ``k`` / ``v`` as STORED (``blocks_head_major``)
+                    where = ((j, blk, h, off) if head_major
+                             else (j, blk, off, h))
                 pool = pool.at[where].set(r[j, :, h])
         out[name] = pool
     return out
@@ -1030,9 +1257,32 @@ def decode_step_paged(params, tokens, cfg, pools, block_tables, seq_lens,
     if active is None:
         active = jnp.ones((S,), jnp.bool_)
     int8_kv = cfg.kv_cache_bits == 8
+    pools = dict(_token_major(pools, cfg))
     bs = pools["latent" if "latent" in pools else "k"].shape[2]
-    pools = dict(pools)
     sc = (pools["k_scale"], pools["v_scale"]) if int8_kv else None
+
+    def attend(p, h, kind, j, state):
+        """One token a slot through an attention block's projections and its
+        read — the paged planes (``plane_of``) or the block's ring — ->
+        (out [S, 1, H], the fresh K/V rows the block hands on)."""
+        local = kind == "wattn"
+        q, k, v, gate = _qkv(p, h, cfg, seq_lens[:, None], kind)
+        row_dtype = cfg.dtype if int8_kv else pools["k"].dtype
+        k_row = jnp.swapaxes(k, 1, 2).astype(row_dtype)
+        v_row = jnp.swapaxes(v, 1, 2).astype(row_dtype)
+        if local:
+            with jax.named_scope("attn"), jax.named_scope("window"):
+                o = _ring_attention(q, _rings(state)[j], seq_lens,
+                                    (k_row, v_row), cfg)
+        else:
+            with jax.named_scope("attn"):
+                o = _paged_attention(
+                    q, pools["k"], pools["v"], block_tables,
+                    seq_lens, cfg, kv_row=(k_row, v_row),
+                    kv_scale=sc, backend=backend, window=None,
+                    layer=plane_of(cfg, kind, j))
+        return (_out(p, o.reshape(S, 1, -1), gate),
+                (k_row[:, :, 0], v_row[:, :, 0]))
 
     def block(i, kind, j, p, carry):
         x, state = carry
@@ -1043,6 +1293,14 @@ def decode_step_paged(params, tokens, cfg, pools, block_tables, seq_lens,
                 y, state["ssm"], state["conv"] = mamba.mixer_step(
                     p, h[:, 0], cfg, state["ssm"], state["conv"], j, active)
                 y = y[:, None]
+            elif kind == "par":
+                with jax.named_scope("mix/ssm"):
+                    m, state["ssm"], state["conv"] = mamba.mixer_step(
+                        p, h[:, 0], cfg, state["ssm"], state["conv"],
+                        state_layer(cfg, kind, j), active)
+                with jax.named_scope("mix/attn"):
+                    a, out = attend(p, _attn_in(h, cfg), kind, j, state)
+                y = _par_join(m[:, None], a, cfg)
             elif kind == "gdn":
                 y, state["gdn"], state["gdn_conv"] = gdn.mixer_step(
                     p, h[:, 0], cfg, state["gdn"], state["gdn_conv"], j,
@@ -1056,24 +1314,7 @@ def decode_step_paged(params, tokens, cfg, pools, block_tables, seq_lens,
                 y, out = latent.mixer_step(p, h, cfg, pools["latent"],
                                            block_tables, seq_lens, j, backend)
             else:
-                local = kind == "wattn"
-                q, k, v, gate = _qkv(p, h, cfg, seq_lens[:, None], kind)
-                row_dtype = cfg.dtype if int8_kv else pools["k"].dtype
-                k_row = jnp.swapaxes(k, 1, 2).astype(row_dtype)
-                v_row = jnp.swapaxes(v, 1, 2).astype(row_dtype)
-                if local:
-                    with jax.named_scope("attn"), jax.named_scope("window"):
-                        o = _ring_attention(q, _rings(state)[j], seq_lens,
-                                            (k_row, v_row), cfg)
-                else:
-                    with jax.named_scope("attn"):
-                        o = _paged_attention(
-                            q, pools["k"], pools["v"], block_tables,
-                            seq_lens, cfg, kv_row=(k_row, v_row),
-                            kv_scale=sc, backend=backend, window=None,
-                            layer=j)
-                y = _out(p, o.reshape(S, 1, -1), gate)
-                out = (k_row[:, :, 0], v_row[:, :, 0])
+                y, out = attend(p, h, kind, j, state)
             return (_residual(p, x, y, cfg), state), out
 
     with _moe.counted_tokens(active):
@@ -1102,18 +1343,23 @@ def decode_step_paged(params, tokens, cfg, pools, block_tables, seq_lens,
             for j, row in enumerate(rows["latent"]):
                 pool = pool.at[j, blk, off].set(row)
             pools["latent"] = pool
-    if "attn" in rows:
+    if cfg.kv_planes:
         with jax.named_scope("attn"), jax.named_scope("kv_write"):
             blk = jnp.where(active, _block_at(block_tables, seq_lens // bs),
                             0)
             off = jnp.where(active, seq_lens % bs, 0)
-            kr, vr = _stacked(rows["attn"])                # [La, S, nkv, hd]
+            kr, vr = _planes(rows)                         # [La, S, nkv, hd]
             if int8_kv:
                 (kq, ks), (vq, vs) = _quant_kv(kr), _quant_kv(vr)
                 rows = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
             else:
                 rows = {"k": kr.astype(pools["k"].dtype),
                         "v": vr.astype(pools["v"].dtype)}
+            # the rows go into the leaves AS STORED: scattered through the
+            # token-major view of a head-major pool, they cost a relayout of
+            # the whole leaf there and back
+            pools = _token_major(pools, cfg)
             pools.update(_write_rows(
-                {n: pools[n] for n in rows}, blk, off, rows))
+                {n: pools[n] for n in rows}, blk, off, rows,
+                head_major=blocks_head_major(cfg)))
     return _head(params, x, cfg)[:, 0], pools

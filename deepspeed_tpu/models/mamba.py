@@ -1,4 +1,6 @@
-"""The Mamba-2 mixer of a hybrid model's ``M`` blocks (``nemotron_h``).
+"""The Mamba-2 mixer of a hybrid model's ``M`` blocks (``nemotron_h``) and of
+the ``P`` blocks' recurrent branch (``falcon_h1``, beside attention on the same
+normed input).
 
 One block is ``h + mixer(RMSNorm(h))`` (the residual and the norm are the
 walker's, ``models/hybrid.py``); the mixer, as HF's ``modeling_nemotron_h``
@@ -14,6 +16,12 @@ computes it:
 ``d_inner = heads x head dim`` (not ``expand x hidden``); head ``h`` reads
 group ``h // (heads / groups)``; the gated norm normalises each of the
 ``groups`` slices of ``d_inner`` by itself and has a weight.
+
+``falcon_h1``'s muP multipliers are static scalars of the projection, not of
+the stored tensors: ``in_proj(u * ssm_in_multiplier) * mup``, ``mup`` the five
+``ssm_multipliers`` laid over the columns z | x | B | C | dt (``mup_vector``).
+A config that states neither (``nemotron_h``) has no multiply for them in its
+programs. The mixer's OUTPUT multiplier is the block's (``models/hybrid.py``).
 
 Three entry points share ``_project`` / ``_finish``: ``mixer_forward`` (a
 whole sequence, no cache: training-shaped callers and the tests),
@@ -38,11 +46,31 @@ def dims(cfg):
     return nh, hd, G, N, nh * hd, nh * hd + 2 * G * N, cfg.conv_kernel
 
 
+def mup_vector(cfg):
+    """float32 numpy [d_inner + conv_dim + heads]: ``ssm_multipliers`` (five)
+    over the input projection's columns z | x | B | C | dt; None where the
+    config states none."""
+    import numpy as np
+    if cfg.ssm_multipliers is None:
+        return None
+    nh, _, G, N, d_inner, _, _ = dims(cfg)
+    if len(cfg.ssm_multipliers) != 5:
+        raise ValueError(f"ssm_multipliers {cfg.ssm_multipliers!r}: five, "
+                         "for z | x | B | C | dt")
+    return np.repeat(np.asarray(cfg.ssm_multipliers, np.float32),
+                     (d_inner, d_inner, G * N, G * N, nh))
+
+
 def _project(p, u, cfg):
     """u [..., H] -> (z [..., d_inner], xBC [..., conv_dim], dt [..., heads])
     of the input projection."""
     _, _, _, _, d_inner, conv_dim, _ = dims(cfg)
+    if cfg.ssm_in_multiplier != 1.0:
+        u = u * cfg.ssm_in_multiplier
     zxd = u @ p["in_proj"].astype(u.dtype)
+    mup = mup_vector(cfg)
+    if mup is not None:
+        zxd = zxd * mup.astype(zxd.dtype)
     return (zxd[..., :d_inner], zxd[..., d_inner:d_inner + conv_dim],
             zxd[..., d_inner + conv_dim:])
 

@@ -232,7 +232,27 @@ class TransformerConfig:
     # output. The CACHE is that row, [c | rope(k_r)], one plane a block that
     # is both K and V (`latent_planes`, `latent_row_width`), in the block
     # pool like K/V: it belongs to no slot. 0: no such block.
+    # "P" (falcon_h1) is a block with TWO mixers on ONE norm, side by side:
+    # h + ssm_out_multiplier Mamba2(n) + attention_out_multiplier Attn(n
+    # attention_in_multiplier), n = RMSNorm(h); it owns a K/V plane of the
+    # block pool AND a layer of the per-slot state pool. The family's muP
+    # multipliers are STATIC scalars of the forward, never folded into the
+    # stored tensors (a checkpoint loads as published): `embed_scale`,
+    # `lm_head_multiplier` on the logits, `key_multiplier` on k,
+    # `ssm_in_multiplier` on the Mamba mixer's input and `ssm_multipliers`
+    # (five: z | x | B | C | dt) on its input projection's columns,
+    # `mlp_multipliers` (two: on the gate before its activation, on the "D"
+    # block's output). 1 / None: every other family, whose programs carry
+    # no multiply for them.
     block_pattern: Optional[str] = None
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: Optional[Tuple[float, ...]] = None
+    mlp_multipliers: Optional[Tuple[float, float]] = None
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -333,10 +353,10 @@ class TransformerConfig:
 
     @property
     def recurrent_blocks(self) -> int:
-        """Blocks that keep a recurrent state per serving slot (the "M" and
-        "G" blocks of a hybrid stack); 0 for every homogeneous model."""
+        """Blocks that keep a recurrent state per serving slot (the "M", "G"
+        and "P" blocks of a hybrid stack); 0 for every homogeneous model."""
         pattern = self.block_pattern or ""
-        return pattern.count("M") + pattern.count("G")
+        return pattern.count("M") + pattern.count("G") + pattern.count("P")
 
     @property
     def window_blocks(self) -> int:
@@ -371,11 +391,12 @@ class TransformerConfig:
     @property
     def attention_blocks(self) -> int:
         """Blocks of softmax attention: every layer of a homogeneous stack,
-        the "*", "W" and "L" blocks of a hybrid one."""
+        the "*", "P", "W" and "L" blocks of a hybrid one (a "P" block counts
+        here AND among the ``recurrent_blocks``: it holds both mixers)."""
         if self.block_pattern is None:
             return self.num_layers
-        return (self.block_pattern.count("*") + self.window_blocks
-                + self.latent_planes)
+        return (self.block_pattern.count("*") + self.block_pattern.count("P")
+                + self.window_blocks + self.latent_planes)
 
     @property
     def kv_planes(self) -> int:

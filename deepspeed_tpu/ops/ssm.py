@@ -39,9 +39,24 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# heads a grid step of the step kernel updates (a multiple of the heads of a
-# group): 16 x 64 x 128 float32 = 512 KiB in and as much out
-STEP_HEADS = 16
+# float32 state a grid step of either kernel holds: the step kernel reads
+# that much and writes as much, each double-buffered (four buffers of it in
+# VMEM); the scan keeps it three times (entering, leaving, carried) beside a
+# chunk's operands. 512 KiB is 16 heads of 64 x 128 (the first shape these
+# kernels ran on the chip) and 4 heads of 128 x 256.
+STEP_STATE_BYTES = 512 * 1024
+SCAN_STATE_BYTES = 1024 * 1024
+
+
+def block_heads(H: int, per_group: int, P: int, N: int, budget: int) -> int:
+    """Heads a grid step holds: the most whose float32 state [heads, P, N]
+    fits ``budget``, as whole groups (a multiple of ``per_group`` that
+    divides ``H``) or, where one group's heads are over it, as a divisor of
+    a group — so a block never straddles a group's edge. At least 1."""
+    fit = max(1, budget // (4 * P * N))
+    unit, whole = (per_group, H) if fit >= per_group else (1, per_group)
+    return max(h for h in range(unit, whole + 1, unit)
+               if whole % h == 0 and h <= max(fit, unit))
 
 
 def _interpret() -> bool:
@@ -118,16 +133,21 @@ def _scan_kernel(x_ref, xw_ref, b_ref, c_ref, l_ref, dall_ref, s0_ref,
 def _scan_pallas(xh, xw, Bg, Cg, L, d_all, S0, Q: int, interpret: bool):
     H, T, P = xh.shape
     G, _, N = Bg.shape
-    hb = H // G                                       # the heads of a group
+    per_group = H // G
+    # a grid row is a group's heads, or a part of them where a group's state
+    # is over the budget (16 heads of 128 x 256: two rows of 8)
+    hb = min(per_group, block_heads(H, per_group, P, N, SCAN_STATE_BYTES))
+    rows = per_group // hb                            # grid rows a group
+    group = (lambda g: g) if rows == 1 else (lambda g: g // rows)
     nc = T // Q
     return pl.pallas_call(
         functools.partial(_scan_kernel, heads=hb),
-        grid=(G, nc),
+        grid=(H // hb, nc),
         in_specs=[
             pl.BlockSpec((hb, Q, P), lambda g, c: (g, c, 0)),
             pl.BlockSpec((hb, P, Q), lambda g, c: (g, 0, c)),
-            pl.BlockSpec((None, Q, N), lambda g, c: (g, c, 0)),
-            pl.BlockSpec((None, Q, N), lambda g, c: (g, c, 0)),
+            pl.BlockSpec((None, Q, N), lambda g, c: (group(g), c, 0)),
+            pl.BlockSpec((None, Q, N), lambda g, c: (group(g), c, 0)),
             pl.BlockSpec((hb, None, Q, Q), lambda g, c: (g, c, 0, 0)),
             pl.BlockSpec((hb, None, 1, 1), lambda g, c: (g, c, 0, 0)),
             pl.BlockSpec((hb, P, N), lambda g, c: (g, 0, 0)),
@@ -235,10 +255,11 @@ def _step_pallas(pool, layer: int, dtx, da, Bs, Cs, interpret: bool):
     Lm, S, H, P, N = pool.shape
     G = Bs.shape[1]
     per_group = H // G
-    hb = min(STEP_HEADS, H)
-    if hb % per_group or H % hb:
-        hb = H
-    nb, gb = H // hb, hb // per_group
+    hb = block_heads(H, per_group, P, N, STEP_STATE_BYTES)
+    # groups a block reads: its heads' whole groups, or the ONE it lies in
+    nb, gb = H // hb, max(1, hb // per_group)
+    of_group = max(1, per_group // hb)                # blocks a group
+    group = (lambda b: b) if of_group == 1 else (lambda b: b // of_group)
 
     def lanes(a):                     # [S, H, P] -> [S, nb, P, hb]
         return a.reshape(S, nb, hb, P).swapaxes(2, 3)
@@ -246,7 +267,7 @@ def _step_pallas(pool, layer: int, dtx, da, Bs, Cs, interpret: bool):
     state = pl.BlockSpec((None, None, hb, P, N),
                          lambda s, b: (layer, s, b, 0, 0))
     vec = pl.BlockSpec((None, None, P, hb), lambda s, b: (s, b, 0, 0))
-    grp = pl.BlockSpec((None, gb, 1, N), lambda s, b: (s, b, 0, 0))
+    grp = pl.BlockSpec((None, gb, 1, N), lambda s, b: (s, group(b), 0, 0))
     y, pool = pl.pallas_call(
         functools.partial(_step_kernel, heads=hb, per_group=per_group),
         grid=(S, nb),

@@ -8,8 +8,12 @@ could break the instrument and stay green. This module runs that suite ONCE,
 in a process of its own (its modules put ``benchmark/tests`` on ``sys.path``
 and two of them define a fixture of the same name, so they are not imported
 into this one), and reports every one of its tests as a case here: the count
-of passes moves with the suite's. A cell's CPU rehearsal that failed in that
-run is run once more, alone (``_REHEARSAL``), before it is reported.
+of passes moves with the suite's. That process runs the suite's files on TWO
+workers of its own (``_SUITE``): alone the suite is 530 s, 440 s of them nine
+cells' CPU rehearsals one after another, and beside tier-1's other workers it
+was tier-1's longest case by 400 s and more (979-1247 s of whole runs of
+1015-1288 s, PR 57). A cell's CPU rehearsal that failed in that run is run
+once more, alone (``_REHEARSAL``), before it is reported.
 """
 import os
 import subprocess
@@ -22,15 +26,18 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 SUITE = os.path.join("benchmark", "tests")
 _OPTIONS = ["-q", "-p", "no:cacheprovider", "-p", "no:xdist", "-p", "no:randomly"]
-_PYTEST = [sys.executable, "-m", "pytest", SUITE] + _OPTIONS
-_ENV = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+_SUITE = ["-q", "-p", "no:cacheprovider", "-p", "xdist", "-n", "2", "--dist",
+          "loadfile", "-p", "no:randomly"]
+_PYTEST = [sys.executable, "-m", "pytest", SUITE]
+_ENV = {k: v for k, v in os.environ.items()
+        if k != "XLA_FLAGS" and not k.startswith("PYTEST_XDIST")}
 _ENV["JAX_PLATFORMS"] = "cpu"
 
 
 def _node_ids():
     """The suite's test ids, by the suite's own collection (parametrised
     cases included): every worker gets the same list."""
-    p = subprocess.run(_PYTEST + ["--collect-only"], cwd=ROOT, env=_ENV,
+    p = subprocess.run(_PYTEST + _OPTIONS + ["--collect-only"], cwd=ROOT, env=_ENV,
                        capture_output=True, text=True, timeout=300)
     ids = [ln.strip() for ln in p.stdout.splitlines() if "::" in ln and " " not in ln.strip()]
     if p.returncode != 0 or not ids:
@@ -91,7 +98,7 @@ def outcomes(tmp_path_factory):
     """One run of the whole suite -> {test id: (outcome, detail)}; the
     rehearsals that failed in it, once more and alone."""
     tmp = tmp_path_factory.mktemp("benchmark_suite")
-    out, log = _run(_PYTEST, tmp / "junit.xml")
+    out, log = _run(_PYTEST + _SUITE, tmp / "junit.xml")
     again = [n for n, (outcome, _) in out.items()
              if outcome in ("failure", "error") and _REHEARSAL in n]
     started = time.monotonic()
@@ -109,7 +116,7 @@ def outcomes(tmp_path_factory):
     return out
 
 
-# FOUR assertions of the suite cannot hold once the benchmark grows, and only a
+# FIVE assertions of the suite cannot hold once the benchmark grows, and only a
 # `benchmark` PR may edit a file under benchmark/. (1) PR 32's cell test pins
 # that cell's entries as the LAST of BENCHMARK.json's lists, and a new entry
 # has to go at the end of its list (the driver reads one put first or in the
@@ -149,6 +156,21 @@ PINS = {
     "benchmark/tests/test_qwen3_next_family.py::"
     "test_benchmark_json_has_the_cell_and_its_metrics":
         ">           assert where[name] == [CELL], name",
+    # (5) PR 55's test of the five set-up metrics pins the NUMBER of serve
+    # cells (8), of cells (11) and of per-layer metrics (63): PR 57 appended a
+    # ninth serve cell to the five lists and two metrics behind them; held on
+    # the lists cut back by order by benchmark/tests/test_falcon_h1_family.py
+    # ::test_what_the_benchmark_had_before_this_cell_is_as_the_tests_before_
+    # hold_it
+    **{"benchmark/tests/test_setup_metrics.py::"
+       f"test_the_entry_is_appended_and_agrees_with_its_header[{name}]":
+           ">       assert len(serve) == 8"
+       for name in ("setup_programs_built", "setup_trace_lower_s",
+                    "setup_compile_or_load_s", "setup_engine_init_s",
+                    "setup_unattributed_share")},
+    "benchmark/tests/test_setup_metrics.py::"
+    "test_nothing_the_benchmark_had_moved":
+        ">       assert len(names) == len(set(names)) == 63",
 }
 
 

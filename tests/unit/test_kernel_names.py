@@ -299,14 +299,38 @@ def test_latent_decode_instruction_name(topo, mosaic):
     assert [c.split(".")[0] for c in calls] == ["%latent_decode"], calls
 
 
-def test_ssm_instruction_names_at_the_published_sizes(topo, mosaic):
-    """Nemotron-3-Nano's Mamba blocks: 64 heads of 64, 8 groups, state 128;
-    a 1024-token prompt, and one step over 128 slots whose state pool is
-    updated in place (the call's output aliases the donated pool: no
+@pytest.mark.parametrize("H,per_group,P,N,step,scan", [
+    (64, 8, 64, 128, 16, 8),        # Nemotron-3-Nano: what it always ran at
+    (32, 16, 128, 256, 4, 8),       # Falcon-H1-34B: a quarter / half a group
+    (8, 4, 16, 32, 8, 4),           # the toys: everything in one step
+    (24, 12, 64, 128, 12, 12),      # whole groups only, never 16 of 24
+    (6, 6, 128, 256, 3, 6)])        # a divisor of the group, not 4
+def test_heads_a_grid_step_come_from_a_byte_budget(H, per_group, P, N, step,
+                                                   scan):
+    """``ssm.block_heads``: the most heads whose float32 state fits the
+    budget (512 KiB a step of the step kernel, 1 MiB of the scan), as whole
+    groups that divide the heads, or as a divisor of one group; a scan's
+    grid row is never more than a group."""
+    assert ssm.block_heads(H, per_group, P, N, ssm.STEP_STATE_BYTES) == step
+    assert min(per_group, ssm.block_heads(H, per_group, P, N,
+                                          ssm.SCAN_STATE_BYTES)) == scan
+    hb = ssm.block_heads(H, per_group, P, N, ssm.STEP_STATE_BYTES)
+    assert H % hb == 0 and (hb % per_group == 0 or per_group % hb == 0)
+
+
+@pytest.mark.parametrize("model,T,H,P,G,N,S", [
+    ("nemotron-3-nano", 1024, 64, 64, 8, 128, 128),
+    ("falcon-h1-34b", 1024, 32, 128, 2, 256, 64)])
+def test_ssm_instruction_names_at_the_published_sizes(topo, mosaic, model, T,
+                                                      H, P, G, N, S):
+    """Nemotron-3-Nano's Mamba blocks (64 heads of 64, 8 groups, state 128)
+    and Falcon-H1-34B's (32 heads of 128, 2 groups, state 256: a head's state
+    is 128 KiB, and a grid step's heads come from a byte budget); a
+    1024-token prompt, and one step over the cell's slots whose state pool
+    is updated in place (the call's output aliases the donated pool: no
     temporary of the pool's size)."""
     one = jax.sharding.SingleDeviceSharding(topo.devices[0])
     bf, f32 = jnp.bfloat16, jnp.float32
-    T, H, P, G, N, S = 1024, 64, 64, 8, 128, 128
     scan = jax.jit(lambda *a: ssm.ssm_scan(*a, kernel=True)).lower(
         _sds((T, H, P), bf, one), _sds((T, H), f32, one), _sds((H,), f32, one),
         _sds((T, G, N), bf, one), _sds((T, G, N), bf, one),

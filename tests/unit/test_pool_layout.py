@@ -733,6 +733,93 @@ def test_the_latent_cells_programs_fit_the_chip_and_write_the_pool_in_place(
             re.findall(r"(?:body|condition)=%([\w.\-]+)", hlo)), holders
 
 
+# ---- a K/V plane AND a state layer in every layer (ISSUE 57) -----------------
+
+@pytest.mark.parametrize("kind,width,stored", [
+    ("step", 40, True), ("prefill", 1024, True), ("step", 40, False)])
+def test_the_parallel_mixer_cells_programs_fit_the_chip_and_write_both_pools_in_place(
+        kind, width, stored, one_chip, monkeypatch):
+    """Falcon-H1-34B at the PUBLISHED widths and the cell's shapes
+    (benchmark/configs/falcon-h1-34b-serve.json: 64 slots, six layers) through
+    the engine's own step (64 slots x the whole table) and its longest
+    prefill (1024 tokens): the pool holds SIX K/V planes and SIX state layers
+    for the same six layers; the program compiles for the described v5e,
+    arguments + temporaries fit the chip's 15.75 GiB; the step holds six
+    ``%ssm_step`` calls (each its layer of the state pool in place, a literal
+    in its block index), the prefill six ``%ssm_scan`` and six ``%flash_fwd``;
+    and no op outside a fused scatter, a block write or a kernel that aliases
+    its operand makes an array of a whole pool leaf's bytes. BOTH SIDES of
+    ``hybrid.blocks_head_major``'s rule (``stored`` False: the rule turned
+    off, ``k`` / ``v`` declared token-major like every other pool): FOUR K/V
+    heads of int8 pack into one sublane word, the compiler keeps that order,
+    and the step holds a relayout of each whole leaf ahead of its gathers —
+    at least two whole-leaf copies and over a GiB of temporaries (1.54 GiB
+    against 0.17; on the chip 24.3 ms a step against 20.6, PERF.md section 6,
+    PR 57). The stacks of 8 heads (Trinity's plane, above and below) and 16
+    (``olmoe-int8``, ``ouro-int8``) are held free of such copies token-major
+    by their own cases."""
+    if not stored:
+        from deepspeed_tpu.models import hybrid
+        monkeypatch.setattr(hybrid, "blocks_head_major", lambda cfg: False)
+    cfg, params, pools, srv, S, MB, sds = _hybrid_cell("falcon-h1-34b-serve",
+                                                       one_chip)
+    L = 6
+    assert (cfg.recurrent_blocks, cfg.attention_blocks, cfg.kv_planes) \
+        == (L, L, L)
+    # four K/V heads of int8: stored head-major (hybrid.blocks_head_major)
+    assert pools["k"].shape == ((L, S * MB + 1, 4, BS, HD) if stored
+                                else (L, S * MB + 1, BS, 4, HD))
+    assert pools["k_scale"].shape == (L, S * MB + 1, 4 * BS)
+    assert pools["ssm"].shape == (L, S, 32, 128, 256)
+    assert pools["ssm"].dtype == jnp.float32
+    assert pools["conv"].shape == (L, S, 3, 5120)
+    assert srv._slot_state == L
+    key = sds((2,), jnp.uint32)
+    if kind == "step":
+        fn = jax.jit(srv._quantum_step_fn().__wrapped__, donate_argnums=(1, 4))
+        args = (params, pools, sds((S,), jnp.int32),
+                _block_list(sds, S, width, MB),
+                sds((S,), jnp.int32), sds((S,), jnp.bool_), key)
+    else:
+        fn = jax.jit(srv._get_prefill_fn(width).__wrapped__, donate_argnums=(2,))
+        args = (params, sds((1, width), jnp.int32), pools,
+                sds((width // BS,), jnp.int32), sds((), jnp.int32), key,
+                sds((), jnp.int32))
+    compiled = _compiled_for_the_chip(fn, args, monkeypatch)
+    hlo, mem = compiled.as_text(), compiled.memory_analysis()
+    print(f"{kind} {width}: arguments {mem.argument_size_in_bytes / 2**30:.2f} GiB, "
+          f"temporaries {mem.temp_size_in_bytes / 2**30:.2f} GiB")
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
+
+    def calls(name):
+        return [l for l in hlo.splitlines()
+                if "custom_call_target=\"tpu_custom_call\"" in l
+                and f"%{name}" in l.split(" = ")[0]]
+    if kind == "step":
+        assert len(calls("ssm_step")) == L and not calls("paged_decode_int8")
+    else:
+        assert len(calls("ssm_scan")) == L and len(calls("flash_fwd")) == L
+    in_place = _dus_fusions(hlo)
+    whole = {l.strip().split(" = ")[0]: l for l in hlo.splitlines()}
+
+    def callee(line):              # `line` is cut short: find it whole
+        m = re.search(r"calls=%([\w.\-]+)", whole[line.split(" = ")[0]])
+        return m and m.group(1)
+    # (the convolution tails, 12 MB for all six layers, ARE relayouted around
+    # each layer's update, as the accepted Mamba-2 cell's are: 0.2 GB a step)
+    def copies(*names):
+        return [line for line in whole_pool_ops(hlo, {n: pools[n] for n in names})
+                if callee(line) not in in_place
+                and "tpu_custom_call" not in whole[line.split(" = ")[0]]]
+    if stored:
+        bad = copies("k", "ssm")
+        assert not bad, "\n".join(b[:300] for b in bad)
+        assert mem.temp_size_in_bytes < 0.6 * 2**30
+    else:
+        assert not copies("ssm") and len(copies("k")) >= 2
+        assert mem.temp_size_in_bytes > 2**30
+
+
 # ---- a hybrid stack's step that sorts (ISSUE 46) -----------------------------
 
 def test_nemotrons_step_sorts_and_reads_its_experts_in_place(one_chip,
@@ -826,7 +913,8 @@ def test_trinitys_step_reads_its_full_plane_through_the_kernel(one_chip,
     ("olmoe-1b-7b-serve", (32, 16, 16, 1, 128), "xla", "price"),
     ("ouro-2.6b-serve", (16, 20, 16, 1, 128), "xla", "price"),
     ("nemotron-3-nano-30b-serve", (128, 40, 2, 16, 128), "xla", "layout"),
-    ("qwen3-next-80b-a3b-serve", (128, 40, 2, 8, 256), "xla", "layout")])
+    ("qwen3-next-80b-a3b-serve", (128, 40, 2, 8, 256), "xla", "layout"),
+    ("falcon-h1-34b-serve", (64, 40, 4, 5, 128), "xla", "layout")])
 def test_the_price_of_the_read_at_each_serve_cell(cell, shape, choice, by):
     """No chip and no compile: the rule is arithmetic on the engine's
     shapes, and the shapes are the configuration files'."""

@@ -52,6 +52,15 @@ and the 4-slot step are under it everywhere). What the change does at the
 PUBLISHED widths is held by shape in ``test_olmoe.py``, by logits in
 ``test_nemotron_h.py`` and by the compiled step in ``test_pool_layout.py -k
 nemotrons_step``.
+
+PR 57 (a block kind with TWO mixers, ``P``; the muP multipliers; the planes and
+state layers counted over two kinds each) printed the table again from its
+tree: NO row moved — a multiplier left at 1 puts no multiply into a program,
+and the walkers' shared pieces (the state write, the attention read) are the
+ops they were, in the order they were. It added ``glm-4.7-flash-serve``,
+printed from a checkout of PR 56's tree (the rows of PR 57's tree are the
+same), and ``falcon-h1-34b-serve`` (two ``PD`` layers, unrolled), printed from
+PR 57's tree.
 """
 import functools
 import hashlib
@@ -64,13 +73,14 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 
 CONFIGS = ("mistral-7b-serve", "mixtral-8x7b-serve", "olmoe-1b-7b-serve",
            "nemotron-3-nano-30b-serve", "ouro-2.6b-serve",
-           "qwen3-next-80b-a3b-serve", "trinity-large-serve")
+           "qwen3-next-80b-a3b-serve", "trinity-large-serve",
+           "glm-4.7-flash-serve", "falcon-h1-34b-serve")
 # a family's toy keeps the published window: this one is cut below a bucket
 WINDOW = {"trinity-large-serve": {"sliding_window": 64}}
 PROGRAMS = ("step", "prefill32", "prefill128", "forward")
 PACKED = tuple(c for c in CONFIGS if c not in (
     "nemotron-3-nano-30b-serve", "qwen3-next-80b-a3b-serve",
-    "trinity-large-serve"))
+    "trinity-large-serve", "glm-4.7-flash-serve", "falcon-h1-34b-serve"))
 GOLDEN = {
     "mistral-7b-serve": {
         "step": "050edea2bb48db05", "prefill32": "40748e39cf90df13",
@@ -97,6 +107,12 @@ GOLDEN = {
     "trinity-large-serve": {
         "step": "905ed8ffb948fa92", "prefill32": "91900aa4b4516e0f",
         "prefill128": "ad76a41a43090cac", "forward": "f9d41ce519bcc2ed"},
+    "glm-4.7-flash-serve": {
+        "step": "3dfe64cce8bd75a7", "prefill32": "6c2eec876880a534",
+        "prefill128": "5a42b3340e962cd1", "forward": "41acc4a557b8572d"},
+    "falcon-h1-34b-serve": {
+        "step": "d8774e09986272f8", "prefill32": "76155bad0742ec57",
+        "prefill128": "d640f8fff4768b72", "forward": "f7cfc2fbdcfe1fd2"},
 }
 
 
