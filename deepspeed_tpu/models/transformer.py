@@ -306,10 +306,12 @@ class TransformerConfig:
     # trained model's early stream is its tokens' and its router balanced.
     embed_init_scale: float = 1.0
     remat: bool = False
-    # none | dots_saveable | save_nothing | dots_and_attn (dots + the flash
-    # kernel's named outputs: the backward reuses O/log-sum-exp instead of
-    # replaying the full online-softmax forward — jax.checkpoint treats the
-    # custom-vjp pallas outputs as recompute-always under dot-only policies)
+    # none | full | dots_saveable | save_nothing | dots_with_no_batch_dims |
+    # offload_dots: what XLA may keep of a block between its forward and its
+    # backward; any other name raises. Under EVERY one the flash kernels' O
+    # and log-sum-exp are kept besides (`_remat_policy` has the bytes):
+    # save_nothing keeps a block's input and those two. A caller who wants
+    # their bytes back has attention_impl="xla" and nothing else.
     remat_policy: str = "none"
     scan_layers: bool = True
     # fused attention backward block (ops/flash_attention fused_backward):
@@ -1993,29 +1995,57 @@ def _dropout(x, cfg, rng, deterministic, salt: int):
 # forward
 # --------------------------------------------------------------------------
 
+# what XLA may keep of a rematerialised block, by `remat_policy`'s names
+_REMAT_POLICIES = {
+    "none": jax.checkpoint_policies.nothing_saveable,   # with remat=True
+    "full": jax.checkpoint_policies.nothing_saveable,
+    "dots_saveable": jax.checkpoint_policies.dots_saveable,
+    "save_nothing": jax.checkpoint_policies.nothing_saveable,
+    "dots_with_no_batch_dims":
+        jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+    "offload_dots": jax.checkpoint_policies.offload_dot_with_no_batch_dims(
+        "device", "pinned_host"),
+}
+# what a flash kernel hands its own backward (ops/flash_attention._flash_fwd,
+# _flash_band_fwd name them)
+_KERNEL_RESIDUALS = jax.checkpoint_policies.save_only_these_names(
+    "flash_out", "flash_lse")
+
+
 def _remat_policy(cfg: TransformerConfig):
+    """The `jax.checkpoint` policy of a block, None where nothing is
+    rematerialised.
+
+    A policy speaks of what XLA can recompute from the block's input; what a
+    Pallas kernel hands its own backward is kept under every one. To
+    `jax.checkpoint` a flash forward is one more op to replay (a custom-vjp
+    call is no dot), so under the bare policies the backward ran the whole
+    online-softmax kernel a second time for O and the log-sum-exp the first
+    call had already written: 9.8 ms of Mellum's 8k-token full layer, 4.4 of
+    a banded one (PERF.md section 6, PR 58). Keeping them costs B x S x N x D
+    in the activations' type + B x N x S float32 a layer and micro-batch; the
+    replay grows with S^2 and the bytes with S, so the trade has one sign at
+    every shape a flash kernel takes and no caller chooses. With
+    attention_impl="xla", and on the ring and sparse paths, nothing is named
+    and the policy is the bare one.
+
+    The join is written out because `save_from_both_policies` refuses the
+    marks `offload_dots` returns (`Offloadable` / `Recompute`, no bools):
+    a kept name is True, everything else is the named policy's answer."""
     if cfg.remat_policy in ("none", None) and not cfg.remat:
         return None
-    policies = {
-        "none": None,
-        "full": None,
-        "dots_saveable": jax.checkpoint_policies.dots_saveable,
-        "save_nothing": jax.checkpoint_policies.nothing_saveable,
-        "dots_with_no_batch_dims": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-        "offload_dots": jax.checkpoint_policies.offload_dot_with_no_batch_dims(
-            "device", "pinned_host"),
-        # dots + the flash kernel's checkpoint_name'd outputs (O, lse):
-        # under dot-only policies jax.checkpoint recomputes custom-vjp
-        # pallas outputs, so the backward replays the whole online-softmax
-        # forward per layer — this policy pins them across the fwd/bwd
-        # boundary at ~one extra activation of HBM per layer (measured by
-        # the bench remat sweep; the winner is recorded in the bench JSON)
-        "dots_and_attn": jax.checkpoint_policies.save_from_both_policies(
-            jax.checkpoint_policies.dots_saveable,
-            jax.checkpoint_policies.save_only_these_names(
-                "flash_out", "flash_lse")),
-    }
-    return policies.get(cfg.remat_policy)
+    try:
+        base = _REMAT_POLICIES[cfg.remat_policy or "none"]
+    except KeyError:
+        raise ValueError(
+            f"unknown remat_policy {cfg.remat_policy!r}: one of "
+            f"{sorted(_REMAT_POLICIES)}") from None
+
+    def policy(prim, *args, **params):
+        return (_KERNEL_RESIDUALS(prim, *args, **params)
+                or base(prim, *args, **params))
+
+    return policy
 
 
 def _hold_expert_stacks(layers: Params, cfg: TransformerConfig,
@@ -3338,6 +3368,7 @@ def _make_hybrid_model(cfg: TransformerConfig, name: str) -> ModelSpec:
 
 def make_model(cfg: TransformerConfig, name: str = "transformer") -> ModelSpec:
     _looped.check(cfg)
+    _remat_policy(cfg)          # an unknown name raises here, not at a trace
     if cfg.rope_tables is not None and not cfg.block_pattern:
         raise NotImplementedError(
             "rope_tables states a rotary table per KIND of attention block "

@@ -48,10 +48,9 @@ next to the step's five matmuls). This removes the separate XLA delta pass
 round-trip per layer per step — so the whole attention backward is two
 Pallas grids with no XLA prologue between forward and backward. The
 forward also tags its outputs with ``checkpoint_name`` ("flash_out" /
-"flash_lse"): the ``dots_and_attn`` remat policy
-(models/transformer._remat_policy) pins them across the fwd/bwd boundary
-so the backward does not replay the full online-softmax forward kernel
-under layer-level ``jax.checkpoint``.
+"flash_lse"): every remat policy of models/transformer._remat_policy keeps
+them across the fwd/bwd boundary, so no backward replays the full
+online-softmax forward kernel under layer-level ``jax.checkpoint``.
 """
 
 import functools
@@ -400,8 +399,8 @@ def _flash_band(q, k, v, sm_scale, window, block_q, block_k):
 def _flash_band_fwd(q, k, v, sm_scale, window, block_q, block_k):
     o, lse = _fwd_band(q, k, v, sm_scale, window, block_q, block_k,
                        with_lse=True)
-    # named as the causal kernel's are: the "dots_and_attn" remat policy
-    # keeps them across the forward / backward boundary (`_flash_fwd`)
+    # named as the causal kernel's are: every remat policy keeps them
+    # across the forward / backward boundary (`_flash_fwd`)
     from jax.ad_checkpoint import checkpoint_name
     o = checkpoint_name(o, "flash_out")
     lse = checkpoint_name(lse, "flash_lse")
@@ -1038,10 +1037,10 @@ def _flash(q, k, v, kv_mask, sm_scale, causal, block_q, block_k, fused):
 def _flash_fwd(q, k, v, kv_mask, sm_scale, causal, block_q, block_k, fused):
     o, lse = _fwd(q, k, v, kv_mask, sm_scale, causal, block_q, block_k)
     # named residuals: when this call sits inside a jax.checkpoint region
-    # (the layer scan body), the "dots_and_attn" remat policy saves O and
-    # the log-sum-exp across the fwd/bwd boundary — the backward then runs
-    # straight into the two backward grids instead of replaying the full
-    # online-softmax forward kernel first
+    # (the layer scan body), every remat policy (transformer._remat_policy)
+    # saves O and the log-sum-exp across the fwd/bwd boundary — the backward
+    # then runs straight into the two backward grids instead of replaying
+    # the full online-softmax forward kernel first
     from jax.ad_checkpoint import checkpoint_name
     o = checkpoint_name(o, "flash_out")
     lse = checkpoint_name(lse, "flash_lse")

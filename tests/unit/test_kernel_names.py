@@ -12,6 +12,7 @@ of the program the TPU's compiler builds, on one chip AND mapped over a
 test loads libtpu).
 """
 
+import collections
 import re
 
 import jax
@@ -480,3 +481,91 @@ def test_row_kernel_instruction_names_at_the_published_widths(topo, monkeypatch)
              and " parameter(" not in l and "get-tuple-element" not in l
              and " bitcast(" not in l]
     assert not moved, moved[:3]
+
+
+# ---- a train cell's WHOLE step: the flash forward runs once (ISSUE 58) ------
+
+def _described_train_step(topo, monkeypatch, cell):
+    """A benchmark train cell's own engine step compiled for the described
+    v5e — the configuration's model, engine settings, token batch and
+    devices, the state abstract (nothing of its gigabytes is built; verify
+    skill, "Tier-1 and the described v5e") — as (kernel instruction names,
+    one entry a Mosaic call of the text; memory analysis)."""
+    import json
+    import os
+    import deepspeed_tpu
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from benchmark.harness import common, loadgen
+    from deepspeed_tpu.models import make_model
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        wl = next(w for w in json.load(f)["workloads"] if w["name"] == cell)
+    traffic = loadgen.load_traffic(wl["traffic"])
+    cfg = common.load_config(wl["config"])
+    seq = traffic["seq_len"]
+    rows = traffic["tokens_per_step"] // seq
+    model = make_model(common.model_config(cfg, common.hf_of(cfg, False), seq),
+                       name=wl["config"])
+    real_jit = jax.jit
+
+    def jit(fn, *a, **kw):      # the state's init program hands out shapes
+        if getattr(fn, "__name__", "") != "make_state":
+            return real_jit(fn, *a, **kw)
+        return lambda key: jax.tree.map(
+            lambda s, sh: _sds(s.shape, s.dtype, sh),
+            jax.eval_shape(fn, key), kw["out_shardings"])
+    with monkeypatch.context() as m:
+        m.setattr(jax, "jit", jit)
+        engine, *_ = deepspeed_tpu.initialize(
+            model=model, rng=jax.random.PRNGKey(0),
+            config=dict(cfg["run"]["engine"], train_batch_size=rows),
+            devices=list(topo.devices[:wl["chips"]]))
+    try:
+        batch = {"input_ids": _sds((rows, seq), jnp.int32, NamedSharding(
+            engine.mesh, P(*engine._batch_spec()[:2])))}
+        key = _sds((2,), jnp.uint32, NamedSharding(engine.mesh, P()))
+        with monkeypatch.context() as m:    # every backend branch as on the chip
+            m.setattr(jax, "default_backend", lambda: "tpu")
+            with engine.mesh, jax.default_matmul_precision("default"):
+                compiled = engine._train_step.lower(
+                    engine.state, batch, key).compile()
+    finally:
+        engine.close()
+    calls = [c.lstrip("%").split(".")[0] for c in _mosaic_calls(compiled)]
+    return calls, compiled.memory_analysis()
+
+
+HBM = 15.75 * 2 ** 30          # one v5e chip
+
+
+@pytest.mark.parametrize("cell", ["mistral-7b-train.seq2048",
+                                  "mistral-7b-zero3.seq2048"])
+def test_a_dense_train_step_holds_the_flash_forward_once(topo, monkeypatch,
+                                                         cell):
+    """Mistral's step at the cells' 4 x 2048 tokens under ``dots_saveable``,
+    on one chip and sharded fsdp 2 x tensor 2 over the described 2 x 2 host
+    (the kernel through ``_flash_per_shard``): the scanned layer's forward
+    body calls ``flash_fwd`` and the backward body only ``flash_dq`` /
+    ``flash_dkv`` — three Mosaic calls in the text where the replayed
+    forward made four — and the step fits the chip with its temporaries."""
+    calls, memory = _described_train_step(topo, monkeypatch, cell)
+    assert sorted(calls) == ["flash_dkv", "flash_dq", "flash_fwd"], calls
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes) < HBM
+
+
+def test_mellums_train_step_holds_each_flash_forward_once(topo, monkeypatch):
+    """Mellum's step at 2 x 8192 tokens under ``save_nothing`` (four
+    unrolled blocks a period, three banded and one full): ONE ``flash_fwd``
+    and THREE ``flash_fwd_band`` (two and six with the replay), each with
+    its two backward kernels, beside the expert kernels the cell's readers
+    find by name."""
+    calls, memory = _described_train_step(topo, monkeypatch,
+                                          "mellum2-12b-train.seq8192")
+    count = collections.Counter(calls)
+    assert count["flash_fwd"] == count["flash_dq"] == count["flash_dkv"] == 1
+    assert (count["flash_fwd_band"] == count["flash_bwd_band_dq"]
+            == count["flash_bwd_band_dkv"] == 3)
+    assert {"moe_gmm", "moe_gmm_dw", "moe_rows_gather", "moe_rows_combine",
+            "moe_rows_pack"} <= set(count)
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes) < HBM

@@ -10,9 +10,9 @@ Pins the tentpole contracts:
     backward Pallas grids) is BIT-FOR-BIT identical to the unfused path —
     kernel-level, and end-to-end over 20 fp16 engine steps with a forced
     overflow across ZeRO stages 1/3 (test_comm_schedule methodology);
-  * the `dots_and_attn` remat policy saves the flash kernel's named
-    outputs across the fwd/bwd boundary — the backward stops replaying the
-    online-softmax forward (pallas_call count drops);
+  * EVERY remat policy keeps the flash kernels' named outputs across the
+    fwd/bwd boundary — no backward replays the online-softmax forward
+    (three pallas_calls, never four; TestNoFlashReplay);
   * `comm.log_summary(engine=)` reports the GSPMD census of the real
     compiled train step (kinds + bytes) next to the trace-time totals.
 
@@ -185,33 +185,133 @@ class TestFusedBackwardKernel:
         for a, b in zip(g0, g1):
             assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
 
-    def test_dots_and_attn_policy_skips_flash_replay(self):
-        """Under layer-level jax.checkpoint, dot-only policies recompute
-        the flash custom-vjp outputs — the backward replays the full
-        online-softmax forward kernel. dots_and_attn pins the kernel's
-        named outputs (flash_out/flash_lse) across the boundary: the
-        backward jaxpr holds one FEWER pallas_call."""
+
+# --------------------------------------------------------------------------
+# the flash forward runs once: its O and log-sum-exp cross the remat boundary
+# --------------------------------------------------------------------------
+
+def _count(jaxpr, primitive: str) -> int:
+    """Equations of ``primitive`` in a jaxpr and everything nested in it."""
+    from jax._src import core
+    return sum((e.primitive.name == primitive)
+               + sum(_count(sub, primitive)
+                     for sub in core.jaxprs_in_params(e.params))
+               for e in jaxpr.eqns)
+
+
+class TestNoFlashReplay:
+    """To ``jax.checkpoint`` a flash forward is one more op to recompute (a
+    custom-vjp Pallas call is no dot), so under a bare policy the backward
+    ran the whole kernel a second time for the O and log-sum-exp the first
+    call had written. ``_remat_policy`` joins every policy with the kernels'
+    named outputs: a rematerialised block's gradient holds forward, dQ and
+    dK/dV — under every policy name, for the causal kernel and the banded
+    one, bare and through the mesh's ``shard_map``."""
+
+    # every name of the table; "none" is `remat=True` alone
+    POLICIES = ["none", "full", "dots_saveable", "save_nothing",
+                "dots_with_no_batch_dims", "offload_dots"]
+    B, S, N, D = 2, 128, 2, 64
+
+    @staticmethod
+    def _cfg(policy, **kw):
+        return TransformerConfig(vocab_size=8, hidden_size=128, num_layers=1,
+                                 num_heads=2, remat=True, remat_policy=policy,
+                                 **kw)
+
+    def _inputs(self):
+        ks = jax.random.split(jax.random.PRNGKey(0), 5)
+        H = self.N * self.D
+        x = jax.random.normal(ks[0], (self.B, self.S, H), jnp.float32)
+        ws = [jax.random.normal(k, (H, H), jnp.float32) * H ** -0.5
+              for k in ks[1:4]]
+        c = jax.random.normal(ks[4], (self.B, self.S, self.N, self.D))
+        return x, ws, c
+
+    def _block(self, window, sharded, c):
+        """x, (wq, wk, wv) -> a scalar: three projections (dots, for the
+        policies that speak of dots) into the flash kernel."""
+        from deepspeed_tpu.models.transformer import _flash_per_shard
+
+        def block(x, ws):
+            q, k, v = (jnp.einsum("bsh,hd->bsd", x, w).reshape(
+                self.B, self.S, self.N, self.D) for w in ws)
+            if sharded:
+                o = _flash_per_shard(q, k, v, None, causal=True,
+                                     window=window)
+            else:
+                o = flash_attention(q, k, v, causal=True, window=window)
+            return jnp.sum(o * c)
+
+        return block
+
+    @pytest.mark.parametrize("sharded", [False, True],
+                             ids=["bare", "per_shard"])
+    @pytest.mark.parametrize("window", [None, 64], ids=["causal", "band64"])
+    @pytest.mark.parametrize("policy", POLICIES)
+    def test_a_remat_block_holds_three_kernel_calls(self, policy, window,
+                                                    sharded):
+        from jax.sharding import Mesh
+        import contextlib
         from deepspeed_tpu.models.transformer import _remat_policy
 
-        ks = jax.random.split(jax.random.PRNGKey(0), 3)
-        q = jax.random.normal(ks[0], (1, 128, 2, 64), jnp.float32)
-        k = jax.random.normal(ks[1], (1, 128, 2, 64), jnp.float32)
-        v = jax.random.normal(ks[2], (1, 128, 2, 64), jnp.float32)
+        x, ws, c = self._inputs()
+        fn = jax.checkpoint(self._block(window, sharded, c),
+                            policy=_remat_policy(self._cfg(policy)))
+        mesh = (Mesh(np.array(jax.devices()[:2]), ("tensor",)) if sharded
+                else contextlib.nullcontext())
+        with mesh:
+            jaxpr = jax.make_jaxpr(jax.grad(fn, argnums=(0, 1)))(x, ws)
+        if sharded:     # ... and the kernel did go through the mesh
+            assert _count(jaxpr.jaxpr, "shard_map") >= 3
+        # forward, dQ, dK/dV — a replay would be a fourth
+        assert _count(jaxpr.jaxpr, "pallas_call") == 3
 
-        def counts(policy_name):
-            cfg = TransformerConfig(vocab_size=8, hidden_size=128,
-                                    num_layers=1, num_heads=2,
-                                    remat=True, remat_policy=policy_name)
-            fn = jax.checkpoint(
-                lambda q, k, v: jnp.sum(
-                    flash_attention(q, k, v, causal=True)),
-                policy=_remat_policy(cfg))
-            jaxpr = jax.make_jaxpr(jax.grad(fn, argnums=(0, 1, 2)))(q, k, v)
-            return str(jaxpr).count("pallas_call")
+    @pytest.mark.parametrize("window", [None, 64], ids=["causal", "band64"])
+    def test_the_bare_policy_did_replay(self, window):
+        """What the join removed: under JAX's own ``nothing_saveable`` the
+        same block's gradient holds FOUR kernel calls."""
+        x, ws, c = self._inputs()
+        fn = jax.checkpoint(self._block(window, False, c),
+                            policy=jax.checkpoint_policies.nothing_saveable)
+        jaxpr = jax.make_jaxpr(jax.grad(fn, argnums=(0, 1)))(x, ws)
+        assert _count(jaxpr.jaxpr, "pallas_call") == 4
 
-        saveable = counts("dots_saveable")
-        pinned = counts("dots_and_attn")
-        assert pinned == saveable - 1, (saveable, pinned)
+    @pytest.mark.parametrize("window", [None, 64], ids=["causal", "band64"])
+    def test_the_gradients_are_bit_identical_to_the_replays(self, window):
+        """The kept values are the same kernel's outputs: loss and every
+        gradient are what ``nothing_saveable``'s replay gave, bit for bit."""
+        from deepspeed_tpu.models.transformer import _remat_policy
+        x, ws, c = self._inputs()
+        block = self._block(window, False, c)
+
+        def run(policy):
+            return jax.jit(jax.value_and_grad(
+                jax.checkpoint(block, policy=policy), argnums=(0, 1)))(x, ws)
+
+        kept = run(_remat_policy(self._cfg("save_nothing")))
+        replayed = run(jax.checkpoint_policies.nothing_saveable)
+        for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(replayed)):
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+    def test_an_unknown_policy_name_raises(self):
+        """It fell through ``policies.get`` to None and silently saved
+        nothing; ``dots_and_attn`` (PR 8's name for what every policy now
+        does) is such a name."""
+        from deepspeed_tpu.models.transformer import _remat_policy
+        for name in ("dots_and_attn", "dots"):
+            with pytest.raises(ValueError, match="dots_saveable"):
+                _remat_policy(self._cfg(name))
+            with pytest.raises(ValueError, match="unknown remat_policy"):
+                make_model(self._cfg(name))
+            with pytest.raises(ValueError, match="unknown remat_policy"):
+                make_model(self._cfg(name, block_pattern="*"))
+
+    def test_nothing_rematerialised_has_no_policy(self):
+        from deepspeed_tpu.models.transformer import _remat_policy
+        cfg = TransformerConfig(vocab_size=8, hidden_size=128, num_layers=1,
+                                num_heads=2)
+        assert _remat_policy(cfg) is None
 
 
 # --------------------------------------------------------------------------
