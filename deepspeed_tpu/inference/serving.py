@@ -1196,7 +1196,11 @@ class ServingEngine:
         if self.engine.dtype == jnp.float16:
             unavailable = "f16 compute dtype (Mosaic has no f16)"
         elif (getattr(mcfg, "position_type", None) == "alibi"
-              or getattr(mcfg, "attn_scale", None) is not None
+              # a stated softmax scale: the per-head kernels take 1/sqrt(D);
+              # the latent read is handed its scale (``latent_attention.
+              # mixer_step``: YaRN's ``mscale^2`` rides on it)
+              or (getattr(mcfg, "attn_scale", None) is not None
+                  and not getattr(mcfg, "latent_planes", 0))
               or (getattr(mcfg, "attn_windows", None)
                   and not getattr(mcfg, "block_pattern", None))):
             # attn_windows: decode_step_paged passes a TRACED per-layer
@@ -3355,7 +3359,11 @@ class ServingEngine:
         grow with the context) and ``state_bytes_per_slot`` (what the engine
         holds a slot whatever its context: every state layer with its
         convolution tail, every ring; 0 for a model whose request is its
-        blocks alone) — what a cost model is held to —; for a model with recurrent or
+        blocks alone) — what a cost model is held to —; ``hc_mult`` and
+        ``stream_bytes_per_token`` (the rows of the residual stream a token
+        carries between blocks, 1 for every stack but a hyper-connected one,
+        and their bytes: what every block reads and writes a token); for a
+        model with recurrent or
         window blocks, ``state_pool_bytes`` (the per-slot state, every
         state leaf of the pool summed) and
         ``state_slots_live`` (slots whose state belongs to a running
@@ -3497,6 +3505,12 @@ class ServingEngine:
             self.num_blocks * self.config.block_size))
         out["state_bytes_per_slot"] = float(
             state_bytes // self.config.max_seqs)
+        # the residual stream a token carries between blocks: its rows (1:
+        # every stack but a hyper-connected one) x hidden x the itemsize
+        out["hc_mult"] = float(getattr(mcfg, "hc_mult", 1))
+        out["stream_bytes_per_token"] = float(
+            out["hc_mult"] * mcfg.hidden_size
+            * np.dtype(self.engine.dtype).itemsize)
         if self._ut_steps > 1:
             out["ut_steps"] = float(self._ut_steps)
             out["kv_planes"] = float(mcfg.kv_planes)

@@ -1321,23 +1321,32 @@ def _glm4_moe_lite_kwargs(get) -> dict:
             f"glm4_moe_lite num_experts={get('num_experts')!r} beside "
             f"n_routed_experts={get('n_routed_experts')!r}: the published "
             "key is n_routed_experts, and every routed expert is held")
-    L, dense = get("num_hidden_layers"), get("first_k_dense_replace", 0)
-    pattern = "".join("L" + ("D" if i < dense else "E") for i in range(L))
     heads = get("num_attention_heads")
     if get("num_key_value_heads", heads) != heads:
         raise ValueError("glm4_moe_lite: latent attention expands K and V "
                          "for every query head (num_key_value_heads = "
                          "num_attention_heads)")
+    return _latent_expert_stack(get, norm_eps=1e-5)
+
+
+def _latent_expert_stack(get, norm_eps: float) -> dict:
+    """What ``glm4_moe_lite`` and ``xing4_0`` share, DeepSeek-V3's layer under
+    its published keys: the ``L`` + ``D`` / ``E`` pattern, the latent ranks and
+    head widths, the sigmoid ``noaux_tc`` router with its shared expert, plain
+    interleaved rotary. ``norm_eps``: the family's default ``rms_norm_eps``."""
+    L, dense = get("num_hidden_layers"), get("first_k_dense_replace", 0)
+    pattern = "".join("L" + ("D" if i < dense else "E") for i in range(L))
     return dict(
         vocab_size=get("vocab_size"), hidden_size=get("hidden_size"),
-        num_layers=len(pattern), block_pattern=pattern, num_heads=heads,
+        num_layers=len(pattern), block_pattern=pattern,
+        num_heads=get("num_attention_heads"),
         head_dim=get("qk_nope_head_dim") + get("qk_rope_head_dim"),
         q_lora_rank=get("q_lora_rank"), kv_lora_rank=get("kv_lora_rank"),
         qk_nope_head_dim=get("qk_nope_head_dim"),
         qk_rope_head_dim=get("qk_rope_head_dim"),
         v_head_dim=get("v_head_dim"),
         max_seq_len=get("max_position_embeddings", 4096),
-        norm_eps=float(get("rms_norm_eps", 1e-5)),
+        norm_eps=float(get("rms_norm_eps", norm_eps)),
         position_type="rotary", rope_theta=float(get("rope_theta", 10000.0)),
         rotary_interleaved=True,
         norm_type="rmsnorm", activation="silu_glu",
@@ -1399,6 +1408,110 @@ def glm4_moe_lite_weight_names(cfg) -> Dict[str, tuple]:
             for e in range(cfg.num_experts):
                 out[pre + f"mlp.experts.{e}.{name}.weight"] = (fkind, fj,
                                                                stack, e)
+    return out
+
+
+class Xing40Unsupported(NotImplementedError):
+    """An ``xing4_0`` config key whose value nothing here computes (``.key``,
+    ``.value``): refused at the import, not at the first step."""
+
+    def __init__(self, key: str, value, want):
+        self.key, self.value = key, value
+        super().__init__(f"xing4_0 {key}={value!r} is not supported (this "
+                         f"importer takes {want!r})")
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """DeepSeek's ``yarn_get_mscale``: 0.1 mscale ln(factor) + 1 (1 up to a
+    factor of 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _xing4_0_kwargs(get) -> dict:
+    """``xing4_0`` (XingChen Xing4.0-29B-A4B): ``glm4_moe_lite``'s stack —
+    DeepSeek-V3's layer: a latent-attention block ``L`` then ``D`` on the
+    first ``first_k_dense_replace`` layers and ``E`` after them, the sigmoid
+    ``noaux_tc`` router, a shared expert, the interleaved rotary pairing —
+    with three things of its own. (1) The residual stream is ``hc_mult`` rows
+    wide and every block reads, writes and carries it through
+    manifold-constrained hyper-connections (``hc_sinkhorn_iters``, ``hc_eps``,
+    ``mhc_h_res_clamp_min`` / ``_max``; ``TransformerConfig.hc_mult``,
+    ``models/hybrid.py``). (2) ``v_head_dim`` may be narrower than
+    ``qk_nope_head_dim + qk_rope_head_dim``. (3) ``rope_scaling`` of type
+    ``yarn``, as DeepSeek-V3 reads it: the stretched table on the rope dims,
+    cos and sin times ``mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim)``, and the softmax scale ``(nope + rope)^-1/2`` times
+    ``mscale(factor, mscale_all_dim)^2`` where ``mscale_all_dim`` is stated
+    (``attn_scale``: both orders of latent attention read it). Refused, typed
+    (``Xing40Unsupported``): expert groups, an expert-parallel degree, a bias,
+    a next-token-prediction module, another rope scaling, another router."""
+    from deepspeed_tpu.models.transformer import RopeTable
+    for key, want in (("hidden_act", "silu"), ("topk_method", "noaux_tc"),
+                      ("scoring_func", "sigmoid"), ("n_group", 1),
+                      ("topk_group", 1), ("ep_size", 1),
+                      ("moe_layer_freq", 1), ("attention_bias", False),
+                      ("mlp_bias", False), ("num_nextn_predict_layers", 0)):
+        if get(key, want) != want:
+            raise Xing40Unsupported(key, get(key), want)
+    for key in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                "qk_rope_head_dim", "v_head_dim", "hc_mult"):
+        if not get(key):
+            raise Xing40Unsupported(key, get(key), "a positive size")
+    if get("num_experts") not in (None, get("n_routed_experts")):
+        raise Xing40Unsupported("num_experts", get("num_experts"),
+                                get("n_routed_experts"))
+    heads = get("num_attention_heads")
+    if get("num_key_value_heads", heads) != heads:
+        raise Xing40Unsupported("num_key_value_heads",
+                                get("num_key_value_heads"), heads)
+    dn, dr = get("qk_nope_head_dim"), get("qk_rope_head_dim")
+    theta = float(get("rope_theta", 10000.0))
+    table, scale = RopeTable(theta), None
+    rs = get("rope_scaling")
+    if rs is not None:
+        kind = rs.get("type", rs.get("rope_type"))
+        if kind != "yarn":
+            raise Xing40Unsupported("rope_scaling.type", kind, "yarn")
+        factor = float(rs["factor"])
+        all_dim = float(rs.get("mscale_all_dim") or 0.0)
+        table = RopeTable(
+            theta, factor, int(rs["original_max_position_embeddings"]),
+            float(rs.get("beta_fast") or 32), float(rs.get("beta_slow") or 1),
+            yarn_mscale(factor, float(rs.get("mscale") or 1.0))
+            / yarn_mscale(factor, all_dim))
+        if all_dim:
+            scale = (dn + dr) ** -0.5 * yarn_mscale(factor, all_dim) ** 2
+    return dict(
+        _latent_expert_stack(get, norm_eps=1e-6),
+        rope_tables=(("latent", table),), attn_scale=scale,
+        hc_mult=int(get("hc_mult")),
+        hc_sinkhorn_iters=int(get("hc_sinkhorn_iters", 20)),
+        hc_eps=float(get("hc_eps", 1e-6)),
+        hc_res_clamp=(float(get("mhc_h_res_clamp_min", -30.0)),
+                      float(get("mhc_h_res_clamp_max", 30.0))))
+
+
+def xing4_0_weight_names(cfg) -> Dict[str, tuple]:
+    """The tensors of an ``xing4_0`` checkpoint of ``cfg``'s shape -> where
+    each lives in the hybrid tree (``glm4_moe_lite_weight_names``' form and,
+    for everything but the stream's mappings, its names: the layer is
+    DeepSeek-V3's). The mappings' names are ASSUMED (the catalog row carries
+    the config, not the tensor index): a layer's ``hc_attn_fn`` / ``hc_ffn_fn``
+    [2n + n^2, n H] (a Linear's [out, in]: stored transposed as ``hc_phi``),
+    ``hc_attn_base`` / ``hc_ffn_base`` (``hc_b``) and ``hc_attn_scale`` /
+    ``hc_ffn_scale`` (``hc_a``: pre, post, res), and the closing read's
+    ``model.hc_head_fn`` / ``_base`` / ``_scale``."""
+    from deepspeed_tpu.models import hybrid
+    out = glm4_moe_lite_weight_names(cfg)
+    blocks = hybrid.blocks(cfg)
+    for i, (kind, j) in enumerate(blocks):
+        pre = f"model.layers.{i // 2}.hc_{'ffn' if i % 2 else 'attn'}_"
+        for name, leaf in (("fn", "hc_phi"), ("base", "hc_b"),
+                           ("scale", "hc_a")):
+            out[pre + name] = (kind, j, leaf, None)
+    for name, leaf in (("fn", "hc_out_phi"), ("base", "hc_out_b"),
+                       ("scale", "hc_out_a")):
+        out["model.hc_head_" + name] = (None, 0, leaf, None)
     return out
 
 
@@ -1712,6 +1825,8 @@ def hf_config_to_transformer(hf_cfg, **overrides):
         kw = _glm4_moe_lite_kwargs(get)
     elif mt == "falcon_h1":
         kw = _falcon_h1_kwargs(get)
+    elif mt == "xing4_0":
+        kw = _xing4_0_kwargs(get)
     elif mt == "opt":
         if get("word_embed_proj_dim", get("hidden_size")) != get("hidden_size"):
             raise ValueError(

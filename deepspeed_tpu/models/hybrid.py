@@ -35,6 +35,25 @@ in its programs. ``models/transformer.py``'s ``init_params``, ``logical_axes``,
 a hybrid model is a ``make_model`` like any other and serves through the same
 engine.
 
+THE STREAM'S SHAPE RULE. What the walkers carry from block to block is ``[..,
+H]`` — or, where ``cfg.hc_mult`` = n > 1 (``xing4_0``: manifold-constrained
+hyper-connections), ``[.., n H]``: n rows of H a token, carried flat, row i the
+columns i H .. (i + 1) H (never ``[.., n, H]``: a second-minor extent of 4 is
+padded to a sublane tile in every buffer). Four functions touch it and nothing
+else does: ``_embed`` OPENS it (every row the token's embedding), ``_norm_in``
+is a block's READ (``RMSNorm(x)``; with n rows the block's three mappings from
+the RMS-normed whole stream — sigmoid ``H_pre`` [n], ``2 sigmoid`` ``H_post``
+[n], ``H_res`` [n, n] made doubly stochastic by ``hc_sinkhorn_iters`` unrolled
+Sinkhorn rounds, all float32 — and ``RMSNorm(H_pre X)``, handing ``(H_post,
+H_res)`` on), ``_residual`` is the block's WRITE (``x + y``; with n rows
+``H_res X + H_post^T y``) and ``_final_norm`` CLOSES it (one more sigmoid read
+with leaves of its own, ``hc_out_*``) before the head. A mixer sees ``[.., H]``
+either way. The mappings are per token, so ``forward``, ``prefill_paged`` (pad
+rows included) and ``decode_step_paged`` (idle slots included) share the one
+implementation; a block's ``hc_phi`` / ``hc_b`` / ``hc_a`` are leaves of its
+kind's stack. With ``hc_mult`` 1 none of it is traced: every other family's
+programs lower to the text they had.
+
 Parameters are stacked PER KIND (``params["layers"]["mamba" | "gdn" | "moe"
 | "dense" | "attn" | "wattn" | "latent" | "par"]``, leading dim = blocks of
 that kind; a ``P`` block's stack holds the leaves of both its mixers under
@@ -362,11 +381,49 @@ def init_params(key, cfg):
     params = {"tok_embed": normal((V, H), std * cfg.embed_init_scale),
               "layers": layers,
               "final_norm_scale": norm_scale((H,))}
+    if cfg.hc_mult > 1:
+        # a stream of several rows: every block's mappings, leaves of its
+        # kind's stack, and the closing read's; their own stream of keys
+        hkeys = iter(jax.random.split(jax.random.fold_in(key, 2),
+                                      3 * (len(layers) + 1)))
+        for stacks in layers.values():
+            stacks.update(_hc_init(hkeys, cfg, stacks["ln_scale"].shape[0]))
+        out = _hc_init(hkeys, cfg, 1, closing=True)
+        params.update({"hc_out_" + k[3:]: a[0] for k, a in out.items()})
     if not cfg.tie_embeddings:
         # a stated logit multiplier: logits of order 1 all the same
         params["lm_head"] = normal((H, V), std if cfg.lm_head_multiplier == 1.0
                                    else _mup_std(H, cfg.lm_head_multiplier))
     return params
+
+
+def _hc_init(keys, cfg, blocks: int, closing: bool = False) -> dict:
+    """``hc_phi`` [blocks, n H, K], ``hc_b`` [blocks, K], ``hc_a`` [blocks,
+    3] (K = 2n + n^2: pre | post | res row-major) of ``blocks`` blocks, or
+    the closing read's (K = n, ONE scalar). ``hc_init_std`` 0: the start a
+    trained-from-scratch stack has — phi 0 and a 0.01, so the mappings are
+    their biases: H_pre = 1 / n each (the block reads the rows' mean), H_post
+    = 1, H_res within e^-8 of the identity: the plain residual on n equal
+    rows. s > 0: phi normal of std s / sqrt(n H) (the projection of a unit-RMS
+    stream is then of order s), b normal of std s, a in U(0.5, 1.5): every
+    mapping moves token by token, which is what a comparison needs to see a
+    round of Sinkhorn, the factor 2 or the closing read left out."""
+    n, dt, s = cfg.hc_mult, cfg.param_dtype, cfg.hc_init_std
+    nH = n * cfg.hidden_size
+    K, A = (n, 1) if closing else (2 * n + n * n, 3)
+    if not s:
+        pre = jnp.full((n,), -math.log(n - 1.0))
+        b = pre if closing else jnp.concatenate(
+            [pre, jnp.zeros((n,)), (8.0 * (jnp.eye(n) - 1.0)).reshape(-1)])
+        return {"hc_phi": jnp.zeros((blocks, nH, K), dt),
+                "hc_b": jnp.broadcast_to(b, (blocks, K)).astype(dt),
+                "hc_a": jnp.full((blocks, A), 0.01, dt)}
+    return {
+        "hc_phi": (jax.random.normal(next(keys), (blocks, nH, K))
+                   * (s / math.sqrt(nH))).astype(dt),
+        "hc_b": (jax.random.normal(next(keys), (blocks, K)) * s).astype(dt),
+        "hc_a": jax.random.uniform(next(keys), (blocks, A), jnp.float32,
+                                   0.5, 1.5).astype(dt)}
 
 
 def _mup_std(fan_in: int, *multipliers, to: float = 1.0) -> float:
@@ -453,6 +510,12 @@ def logical_axes(cfg):
             stacks["post_ln_scale"] = ("layers", "unmodeled")
     axes = {"tok_embed": ("vocab", "embed"), "layers": layers,
             "final_norm_scale": ("unmodeled",)}
+    if cfg.hc_mult > 1:       # the stream is whole on every chip: no split
+        for stacks in layers.values():
+            stacks.update(hc_phi=("layers", None, None),
+                          hc_b=("layers", None), hc_a=("layers", None))
+        axes.update(hc_out_phi=(None, None), hc_out_b=(None,),
+                    hc_out_a=(None,))
     if not cfg.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     return axes
@@ -586,23 +649,144 @@ def _par_join(m, a, cfg):
     return m * cfg.ssm_out_multiplier + a * cfg.attention_out_multiplier
 
 
-def _residual(p, x, y, cfg):
-    """A block's output joins the stream: ``x + y``, through the block's
-    RMSNorm AFTER the mixer where the stack has one (``sandwich_norm``)."""
+def _residual(p, x, y, cfg, hc=None):
+    """A block WRITES the stream: ``x + y``, through the block's RMSNorm
+    AFTER the mixer where the stack has one (``sandwich_norm``); with a
+    stream of several rows (``hc``: what the block's read handed on)
+    ``H_res X + H_post^T y`` (``_hc_write``)."""
     from deepspeed_tpu.models.transformer import _norm
     if cfg.sandwich_norm:
         y = _norm(y, p["post_ln_scale"], None, cfg)
-    return x + y
+    if hc is None:
+        return x + y
+    with jax.named_scope("hc/write"):
+        return _hc_write(x, y, hc, cfg)
 
 
 def _norm_in(p, x, cfg):
+    """A block READS the stream -> (its mixer's input ``RMSNorm(x)`` [..,
+    H], None); with a stream of several rows ``RMSNorm(H_pre X)`` and
+    ``(H_post, H_res)`` for the block's write (``_hc_read``)."""
     from deepspeed_tpu.models.transformer import _norm
-    return _norm(x, p["ln_scale"], None, cfg)
+    if cfg.hc_mult == 1:
+        return _norm(x, p["ln_scale"], None, cfg), None
+    with jax.named_scope("hc/read"):
+        h, hc = _hc_read(x, p["hc_phi"], p["hc_b"], p["hc_a"], cfg)
+        return _norm(h, p["ln_scale"], None, cfg).astype(x.dtype), hc
 
 
 def _final_norm(params, x, cfg):
+    """The stream CLOSES — several rows through one more sigmoid read of
+    its own (``hc_out_*``) — into the final norm."""
     from deepspeed_tpu.models.transformer import _norm
+    if cfg.hc_mult > 1:
+        with jax.named_scope("hc/close"):
+            m = _hc_project(x, params["hc_out_phi"], cfg)          # [n, N]
+            w = jax.nn.sigmoid(
+                params["hc_out_a"].astype(jnp.float32)[0] * m
+                + params["hc_out_b"].astype(jnp.float32)[:, None])
+            x = _hc_mix(w, _hc_rows(x, cfg), x.shape).astype(x.dtype)
     return _norm(x, params["final_norm_scale"], None, cfg)
+
+
+# --------------------------------------------------------------------------
+# a residual stream of several rows (manifold-constrained hyper-connections)
+# --------------------------------------------------------------------------
+# The stream of a token is n = ``hc_mult`` rows of H, carried FLAT, [.., n H],
+# row i the columns i H .. (i + 1) H: a second-minor extent of 4 would be
+# padded to a whole sublane tile by the TPU (8 rows of float32, 16 of bf16)
+# in every buffer that holds the stream, and the rows are lane-aligned slices
+# of the flat form (H a multiple of 128) that cost nothing. Everything here is
+# per token (no state across tokens): T prompt tokens or one a slot alike, pad
+# rows and idle slots included. The coefficients are float32 with the TOKENS
+# minor ([n, N], [n, n, N]): 4 x 4 matrices a token as trailing dims would
+# fill a sixty-fourth of a vector register each.
+
+def _hc_rows(x, cfg):
+    """The stream x [.., n H] as its n rows [.., H], float32. A row is
+    sliced BEFORE it is widened: widened whole, the stream has three readers
+    a block (the RMS, ``H_pre X``, the write) and the TPU compiler keeps ONE
+    float32 copy of it for them — a ``convert`` of its own, 235 MB written
+    and read three times a block of a 4096-token prompt — where each reader
+    of a slice widens it inside its own fusion and reads the bf16 stream:
+    25.7 -> 16.7 ms of a 4096-token row's 88.8 (PERF.md section 6, PR 59)."""
+    H = cfg.hidden_size
+    return [x[..., j * H:(j + 1) * H].astype(jnp.float32)
+            for j in range(cfg.hc_mult)]
+
+
+def _hc_project(x, phi, cfg):
+    """``(x / rms(x)) phi`` over the WHOLE stream of each token (all n H
+    values, no learned scale), x [.., n H], phi [n H, K] -> float32 [K, N],
+    N the tokens. The RMS is a scalar a token, so it multiplies the
+    projection's K results and not the stream's n H values; a stream and a
+    ``phi`` both stored in bf16 are multiplied as they are (their products
+    are exact in the float32 accumulator), anything wider in float32 at the
+    highest precision."""
+    x2 = x.reshape(-1, x.shape[-1])
+    x32 = x2.astype(jnp.float32)
+    r = lax.rsqrt(jnp.mean(x32 * x32, axis=-1) + cfg.norm_eps)      # [N]
+    dt = jnp.promote_types(x.dtype, phi.dtype)
+    m = jnp.dot(x2.astype(dt), phi.astype(dt),
+                precision=lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)                  # [N, K]
+    return (m * r[:, None]).T
+
+
+def _hc_mix(w, rows, shape):
+    """``sum_j w[j] rows[j]``: w [n, N] float32, one weight a token and row,
+    rows n x [.., H] -> [.., H] float32 (``shape``: the stream's)."""
+    lead = shape[:-1] + (1,)
+    out = w[0].reshape(lead) * rows[0]
+    for j in range(1, len(rows)):
+        out = out + w[j].reshape(lead) * rows[j]
+    return out
+
+
+def _sinkhorn(R, cfg):
+    """R [n, n, N] (row, column, token) -> ``hc_sinkhorn_iters`` rounds of
+    Sinkhorn-Knopp on ``exp(R - rowmax)``, a round the rows then the columns,
+    ``hc_eps`` in every denominator: doubly stochastic. Unrolled: a loop
+    would pay a launch a round for sixteen numbers a token."""
+    M = jnp.exp(R - jnp.max(R, axis=1, keepdims=True))
+    for _ in range(cfg.hc_sinkhorn_iters):
+        M = M / (jnp.sum(M, axis=1, keepdims=True) + cfg.hc_eps)
+        M = M / (jnp.sum(M, axis=0, keepdims=True) + cfg.hc_eps)
+    return M
+
+
+def _hc_read(x, phi, b, a, cfg):
+    """A block's three mappings from the whole stream x [.., n H] and the
+    block's input: -> (``H_pre X`` [.., H] float32, (H_post [n, N], H_res [n,
+    n, N])). ``m = u phi`` [2n + n^2]: ``H_pre = sigmoid(a_pre m[:n] + b)``,
+    ``H_post = 2 sigmoid(a_post m[n:2n] + b)``, ``H_res = Sinkhorn(clip(a_res
+    mat(m[2n:]) + b, hc_res_clamp))``, row-major."""
+    n = cfg.hc_mult
+    m = _hc_project(x, phi, cfg)
+    b, a = b.astype(jnp.float32)[:, None], a.astype(jnp.float32)
+    pre = jax.nn.sigmoid(a[0] * m[:n] + b[:n])
+    post = 2.0 * jax.nn.sigmoid(a[1] * m[n:2 * n] + b[n:2 * n])
+    with jax.named_scope("hc/sinkhorn"):
+        lo, hi = cfg.hc_res_clamp
+        res = _sinkhorn(jnp.clip(a[2] * m[2 * n:] + b[2 * n:], lo, hi
+                                 ).reshape(n, n, -1), cfg)
+    return _hc_mix(pre, _hc_rows(x, cfg), x.shape), (post, res)
+
+
+def _hc_write(x, y, hc, cfg):
+    """``H_res X + H_post^T y``: row i of the new stream is ``sum_j H_res[i,
+    j] X[j] + H_post[i] y``, in float32, STORED in the stream's dtype: the
+    barrier makes the rounded stream the array the next block reads. Without
+    it the TPU compiler hands the next block the float32 values (every reader
+    of the stream widens it first, so the conversion is hoisted into this
+    fusion): a 235 MB float32 stream a block of a 4096-token prompt, read
+    three times, where the stated bf16 one is 117 MB — 37.3 ms against 20.8
+    for twelve blocks' reads and writes alone (PERF.md section 6, PR 59)."""
+    post, res = hc
+    rows = _hc_rows(x, cfg) + [y.astype(jnp.float32)]
+    return lax.optimization_barrier(jnp.concatenate(
+        [_hc_mix(jnp.concatenate([res[i], post[i:i + 1]]), rows, x.shape)
+         for i in range(cfg.hc_mult)], axis=-1).astype(x.dtype))
 
 
 def _head(params, x, cfg):
@@ -616,7 +800,11 @@ def _head(params, x, cfg):
 def _embed(params, ids, cfg):
     with jax.named_scope("embed"):
         x = params["tok_embed"][ids].astype(cfg.dtype)
-        return x if cfg.embed_scale == 1.0 else x * cfg.embed_scale
+        x = x if cfg.embed_scale == 1.0 else x * cfg.embed_scale
+        if cfg.hc_mult > 1:         # the stream OPENS: every row the embedding
+            with jax.named_scope("hc/open"):
+                x = jnp.tile(x, cfg.hc_mult)
+        return x
 
 
 # --------------------------------------------------------------------------
@@ -635,9 +823,11 @@ def period(cfg):
     py``): the PR that serves a repeating Mamba-2 pattern gives the kernel
     the scalar and drops the test on ``"M"``. A pattern with "W" blocks is
     one unit as well: their rings are one array a block (``ring_leaves``
-    says why), which a traced index cannot choose among."""
+    says why), which a traced index cannot choose among. A stack whose
+    stream is several rows wide (``hc_mult`` > 1) is one unit too: no test
+    holds the scanned walk to the unrolled one with that carry yet."""
     pattern = cfg.block_pattern
-    if not set("MPW") & set(pattern):
+    if not set("MPW") & set(pattern) and cfg.hc_mult == 1:
         for size in range(1, len(pattern) // 2 + 1):
             if pattern == pattern[:size] * (len(pattern) // size):
                 return pattern[:size], len(pattern) // size
@@ -728,7 +918,7 @@ def forward(params, input_ids, cfg, *, deterministic: bool = True,
             # the expert load leaves a rematerialised block as an output of
             # it, as it leaves a scan body (`_walk`)
             with jax.named_scope(f"layer{i}"), _moe.layer_load_tap() as tap:
-                h = _norm_in(p, x, cfg)
+                h, hc = _norm_in(p, x, cfg)
                 if kind == "mamba":
                     y = mamba.mixer_forward(p, h, cfg)
                 elif kind == "gdn":
@@ -749,7 +939,7 @@ def forward(params, input_ids, cfg, *, deterministic: bool = True,
                     y = _par_join(m, a, cfg)
                 else:
                     y = _attn_mixer(p, h, cfg, kind)[0]
-                return (_residual(p, x, y, cfg), aux_total), (
+                return (_residual(p, x, y, cfg, hc), aux_total), (
                     tap.stacked() if tap is not None else None)
 
         if remat:       # the block's slices are closed over: residuals
@@ -1135,7 +1325,7 @@ def prefill_paged(params, input_ids, cfg, pools, block_ids,
         x, state = carry
         state, out = dict(state), None
         with jax.named_scope(f"layer{i}"):
-            h = _norm_in(p, x, cfg)
+            h, hc = _norm_in(p, x, cfg)
             if kind == "mamba":
                 y, state = ssm_prefill(p, h, j, state)
             elif kind == "par":
@@ -1162,7 +1352,8 @@ def prefill_paged(params, input_ids, cfg, pools, block_ids,
             else:
                 y, k, v = _attn_mixer(p, h, cfg, kind)
                 out = (jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2))
-            return (_residual(p, x, y, cfg), state), out   # [1, nkv, P, hd]
+            return (_residual(p, x, y, cfg, hc), state), out
+            # out: [1, nkv, P, hd]
 
     x = _embed(params, input_ids, cfg)                            # [1, P, H]
     if segments is None:
@@ -1288,7 +1479,7 @@ def decode_step_paged(params, tokens, cfg, pools, block_tables, seq_lens,
         x, state = carry
         state, out = dict(state), None
         with jax.named_scope(f"layer{i}"):
-            h = _norm_in(p, x, cfg)
+            h, hc = _norm_in(p, x, cfg)
             if kind == "mamba":
                 y, state["ssm"], state["conv"] = mamba.mixer_step(
                     p, h[:, 0], cfg, state["ssm"], state["conv"], j, active)
@@ -1315,7 +1506,7 @@ def decode_step_paged(params, tokens, cfg, pools, block_tables, seq_lens,
                                            block_tables, seq_lens, j, backend)
             else:
                 y, out = attend(p, h, kind, j, state)
-            return (_residual(p, x, y, cfg), state), out
+            return (_residual(p, x, y, cfg, hc), state), out
 
     with _moe.counted_tokens(active):
         (x, state), rows = _walk(

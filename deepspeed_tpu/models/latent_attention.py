@@ -1,5 +1,5 @@
-"""Multi-head LATENT attention (DeepSeek-V2/V3; ``glm4_moe_lite``): the ``L``
-mixer of a hybrid stack (``models/hybrid.py``).
+"""Multi-head LATENT attention (DeepSeek-V2/V3; ``glm4_moe_lite``,
+``xing4_0``): the ``L`` mixer of a hybrid stack (``models/hybrid.py``).
 
 One block, ``h`` its normed input [.., H]::
 
@@ -7,7 +7,16 @@ One block, ``h`` its normed input [.., H]::
     q   = c_q W_qb  -> heads x (nope | rope)   # qk_nope_head_dim | qk_rope_head_dim
     [c_kv | k_r] = h W_kva                     # kv_lora_rank | qk_rope_head_dim
     c   = RMSNorm(c_kv);  k_r: ONE rotary key a token, shared by every head
-    rotary (``rope_theta``, all qk_rope_head_dim dims) on q's rope part and k_r
+    rotary (all qk_rope_head_dim dims) on q's rope part and k_r
+
+The rotary TABLE is the kind's (``hybrid.rope_table(cfg, "latent")``): plain
+``rope_theta``, or the table the config states (``rope_tables``: YaRN's
+stretched frequencies, cos and sin times its ``attention_factor``). The softmax
+scale is ``cfg.attn_scale`` where stated — the importer of a YaRN model puts
+``(nope + rope)^-1/2 x mscale(factor, mscale_all_dim)^2`` there, DeepSeek-V3's
+rule — else ``(nope + rope)^-1/2``; BOTH orders below read the same one.
+``v_head_dim`` is V's OWN width: equal to nope + rope (``glm4_moe_lite``, 256)
+or narrower (DeepSeek-V3's and ``xing4_0``'s 128 beside keys of 192).
 
 What a token keeps is the ROW ``[c | rope(k_r)]`` (``cfg.latent_row_width``
 values, stored in whole lane tiles: ``stored_width``): one plane a block, both
@@ -17,8 +26,10 @@ orders of the same arithmetic, ``W_kvb`` [kv_lora_rank, heads x (nope | v)]:
 
 - EXPANDED (``mixer_forward``: whole sequences — the forward, a prompt's
   prefill): ``[k_nope | v] = c W_kvb`` per head, ``k = [k_nope | k_r]``,
-  ordinary causal attention at scale (nope + rope)^-1/2 over heads x that
-  width — the flash kernel where it would run — then ``W_o``.
+  ordinary causal attention at that scale over heads x (nope + rope) in q.k
+  and heads x v in P V — the flash kernel where it would run: a shared
+  prefill row's forward keeps V at its own width, the differentiable call
+  pads it (``ops/flash_attention.py``) — then ``W_o`` [heads x v, H].
 - ABSORBED (``mixer_step``: one token a slot against the pool): with ``W_kvb``
   split per head into ``W_uk`` [nope, rank] and ``W_uv`` [rank, v], ``score =
   (q_nope W_uk) . c + q_rope . k_r`` and ``o = (P c) W_uv``: every head reads
@@ -81,9 +92,11 @@ def leaf_shapes(cfg) -> dict:
 
 
 def _rotary(x, positions, cfg):
+    from deepspeed_tpu.models.hybrid import rope_table
     from deepspeed_tpu.models.transformer import rotary_embed
-    return rotary_embed(x, positions, cfg.rope_theta, None,
-                        cfg.rotary_interleaved)
+    table = rope_table(cfg, "latent")
+    return rotary_embed(x, positions, table.theta, None,
+                        cfg.rotary_interleaved, table)
 
 
 def _project(p, h, cfg, positions):
@@ -108,11 +121,6 @@ def mixer_forward(p, h, cfg, positions=None, segment_ids=None):
     (default 0..T-1) and ``segment_ids`` [B, T] are a packed row's."""
     from deepspeed_tpu.models.transformer import _wmat, _wrow, attention
     nq, dn, dr, dv, _, rkv = dims(cfg)
-    if dv != dn + dr:
-        raise NotImplementedError(
-            f"latent attention with v_head_dim {dv} != qk_nope_head_dim + "
-            f"qk_rope_head_dim {dn + dr}: the expanded path hands q, k and v "
-            "of one width to the attention kernels")
     B, T, _ = h.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(T)[None], (B, T))

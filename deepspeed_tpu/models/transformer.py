@@ -244,6 +244,29 @@ class TransformerConfig:
     # `mlp_multipliers` (two: on the gate before its activation, on the "D"
     # block's output). 1 / None: every other family, whose programs carry
     # no multiply for them.
+    # A MANY-ROW RESIDUAL STREAM (xing4_0; manifold-constrained
+    # hyper-connections, arXiv:2512.24880 over arXiv:2409.19606; hybrid
+    # stacks only): `hc_mult` n > 1 rows of `hidden_size` a token. Every
+    # block READS the stream through a sigmoid row H_pre [n] (its input is
+    # H_pre X), WRITES its output back through 2 sigmoid row H_post [n] and
+    # carries the stream through H_res [n, n], `hc_sinkhorn_iters` rounds of
+    # Sinkhorn-Knopp (rows, then columns; `hc_eps` in the denominators) on
+    # logits clipped to `hc_res_clamp`: doubly stochastic. The three come
+    # from ONE projection of the RMS-normed whole stream (a block's `hc_phi`
+    # [n H, 2n + n^2], `hc_b`, `hc_a`), in float32 whatever the stream's
+    # dtype; the stream is opened by repeating the embedding and closed by
+    # one more sigmoid read (`hc_out_*`) before the final norm
+    # (models/hybrid.py `_hc_read`, `_hc_write`). 1: every other family,
+    # whose programs hold nothing of this. `hc_init_std` is an INITIALISER
+    # (`init_params` only): 0 draws the mappings at the near-identity start
+    # (phi 0, H_post 1, H_res ~ I, H_pre summing to 1), s > 0 draws them
+    # AWAY from it (phi normal of std s / sqrt(n H), so the projection is of
+    # order s; b normal of std s; a in U(0.5, 1.5)).
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
+    hc_init_std: float = 0.0
     block_pattern: Optional[str] = None
     lm_head_multiplier: float = 1.0
     attention_in_multiplier: float = 1.0
@@ -3374,6 +3397,11 @@ def make_model(cfg: TransformerConfig, name: str = "transformer") -> ModelSpec:
             "rope_tables states a rotary table per KIND of attention block "
             "of a hybrid (block_pattern) stack; a homogeneous stack has the "
             "one rope_theta")
+    if cfg.hc_mult != 1 and not cfg.block_pattern:
+        raise NotImplementedError(
+            f"hc_mult={cfg.hc_mult}: a residual stream of several rows is "
+            "the hybrid (block_pattern) walker's (models/hybrid.py); a "
+            "homogeneous stack keeps one row a token")
     if cfg.block_pattern:
         return _make_hybrid_model(cfg, name)
     # the two-level suffix decode unrolls its layers: a looped model is

@@ -709,17 +709,22 @@ def _packed_kernel(lo_ref, full_ref, q_ref, k_ref, v_ref, end_ref, o_ref,
     def _finalize():
         l = l_s[:, 0:1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_s[:] / l_safe).reshape(rep, block_q, d).astype(
-            o_ref.dtype)
+        o_ref[0, 0] = (acc_s[:] / l_safe).reshape(
+            rep, block_q, o_ref.shape[-1]).astype(o_ref.dtype)
 
 
 def _fwd_packed(q, k, v, segment_ids, kv_mask, sm_scale):
-    """q [B, N, S, D], k / v [B, Nkv, S, D], segment_ids int32 [B, S] -> o,
+    """q [B, N, S, D], k [B, Nkv, S, D], v [B, Nkv, S, Dv], segment_ids int32
+    [B, S] -> o [B, N, S, Dv],
     causal within each segment (ids that never fall along a row: a segment
     is one run of its id), in ONE call whose walk the segments
     bound (``_packed_bounds``). ``kv_mask`` bool [B, S]: a masked key is
-    visible to nobody, and every tile then takes the masked body."""
+    visible to nobody, and every tile then takes the masked body. V may be
+    narrower than the keys (latent attention whose ``v_head_dim`` is under
+    nope + rope): its tile, the accumulator and the output are ``Dv`` wide,
+    so P V costs what V holds."""
     B, N, S, D = q.shape
+    Dv = v.shape[-1]
     Nkv = k.shape[1]
     rep = N // Nkv
     bq, bk = _packed_blocks(S, rep)
@@ -741,13 +746,16 @@ def _fwd_packed(q, k, v, segment_ids, kv_mask, sm_scale):
     def kv_tile(b, i, j, lo_ref):
         return jnp.clip(j, lo_ref[b * nq + i], _diagonal_tile(i, bq, bk))
 
-    kv_spec = pl.BlockSpec(
-        (1, 1, bk, D),
-        lambda b, g, i, j, lo_ref, _: (b, g, kv_tile(b, i, j, lo_ref), 0),
-        memory_space=pltpu.VMEM)
-    q_spec = pl.BlockSpec((1, 1, rep, bq, D),
-                          lambda b, g, i, j, *_: (b, g, 0, i, 0),
-                          memory_space=pltpu.VMEM)
+    def kv_spec(width):
+        return pl.BlockSpec(
+            (1, 1, bk, width),
+            lambda b, g, i, j, lo_ref, _: (b, g, kv_tile(b, i, j, lo_ref), 0),
+            memory_space=pltpu.VMEM)
+
+    def q_spec(width):
+        return pl.BlockSpec((1, 1, rep, bq, width),
+                            lambda b, g, i, j, *_: (b, g, 0, i, 0),
+                            memory_space=pltpu.VMEM)
     # the segments' ends [B, 8, S]: a sublane-broadcast copy blocked along
     # the lanes with K and V, as the key-padding mask of `_fwd` is
     end_spec = pl.BlockSpec(
@@ -760,26 +768,27 @@ def _fwd_packed(q, k, v, segment_ids, kv_mask, sm_scale):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, Nkv, nq, S // bk),
-            in_specs=[q_spec, kv_spec, kv_spec, end_spec],
-            out_specs=q_spec,
+            in_specs=[q_spec(D), kv_spec(D), kv_spec(Dv), end_spec],
+            out_specs=q_spec(Dv),
             scratch_shapes=[
                 pltpu.VMEM((rows, 128), jnp.float32),   # m (lane-padded)
                 pltpu.VMEM((rows, 128), jnp.float32),   # l
-                pltpu.VMEM((rows, D), jnp.float32),     # acc
+                pltpu.VMEM((rows, Dv), jnp.float32),    # acc
             ]),
-        out_shape=jax.ShapeDtypeStruct((B, Nkv, rep, S, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Nkv, rep, S, Dv), q.dtype),
         compiler_params=_compiler_params(3),
         interpret=_interpret(),
         name="flash_fwd",
     )(lo.reshape(-1), full.reshape(-1), q.reshape(B, Nkv, rep, S, D), k, v,
       jnp.broadcast_to(seg_end[:, None, :], (B, 8, S)))
-    return o.reshape(B, N, S, D)
+    return o.reshape(B, N, S, Dv)
 
 
 def flash_attention_packed(q, k, v, segment_ids, *,
                            sm_scale: Optional[float] = None, kv_mask=None):
     """Causal attention over rows that hold several sequences each, q [B, S,
-    Nq, D], k / v [B, S, Nkv, D] -> [B, S, Nq, D]: key j is visible to query
+    Nq, D], k [B, S, Nkv, D], v [B, S, Nkv, Dv] -> [B, S, Nq, Dv] (Dv = D,
+    or a V narrower than the keys: ``_fwd_packed``): key j is visible to query
     i iff j <= i and both carry the same id. ``segment_ids`` int [B, S],
     traced, must NEVER FALL along a row (``transformer.attention``'s
     precondition; ``_packed_row``'s do not): a segment is then one run of
@@ -1070,6 +1079,12 @@ def flash_attention(q, k, v, *, causal: bool = True,
     """q: [B, S, Nq, D]; k, v: [B, S, Nkv, D] (Nkv may divide Nq: GQA runs
     natively without repeating K/V) -> [B, S, Nq, D].
 
+    A V narrower than the keys (``v.shape[-1] < D``) is padded with zero
+    columns to D and the output's first ``v.shape[-1]`` columns are returned:
+    the forward and the two backward kernels share ONE width, and no serving
+    program takes this call for such a model (a shared prefill row is
+    ``flash_attention_packed``'s, whose V keeps its own width).
+
     window: a static length W — key j is visible to query i iff 0 <= i - j <
     W (causal within a band). The forward visits only the key tiles a query
     tile's band touches (``flash_fwd_band``), and so do the two backward
@@ -1085,6 +1100,13 @@ def flash_attention(q, k, v, *, causal: bool = True,
     if q.shape[2] % k.shape[2]:
         raise ValueError(f"n_q_heads {q.shape[2]} not divisible by "
                          f"n_kv_heads {k.shape[2]}")
+    dv = v.shape[-1]
+    if dv < q.shape[-1]:
+        v = jnp.pad(v, [(0, 0)] * 3 + [(0, q.shape[-1] - dv)])
+        return flash_attention(
+            q, k, v, causal=causal, sm_scale=sm_scale, kv_mask=kv_mask,
+            block_q=block_q, block_k=block_k, fused_backward=fused_backward,
+            window=window)[..., :dv]
     if window is not None:
         if not causal or kv_mask is not None or window < 1:
             raise ValueError(

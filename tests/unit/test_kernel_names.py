@@ -129,6 +129,44 @@ def test_the_hybrid_scopes_in_the_lowered_text():
     assert re.search(r'"jit\([^)]*\)/attn/kv_write/scatter"', prefill)
 
 
+def test_the_streams_scopes_in_the_lowered_text():
+    """``hc/read`` (with ``hc/sinkhorn`` inside it), ``hc/write`` under every
+    block's ``layerN`` scope, ``hc/open`` at the embedding and ``hc/close`` at
+    the head reach the lowered text of all three walkers of a stack whose
+    stream is several rows wide (ISSUE 59); there is no kernel of that name
+    today (the read and the write are XLA fusions: ``%hc_read`` /
+    ``%hc_write`` are what ``benchmark/families/xing4_0.hc_op`` would find)."""
+    import os
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))))
+    from benchmark.families.xing4_0 import TOY
+    from deepspeed_tpu.models import make_model
+    from deepspeed_tpu.models.hf_import import hf_config_to_transformer
+    hf = {"model_type": "xing4_0", "hc_mult": 4, "n_shared_experts": 1,
+          "num_experts_per_tok": 4, "max_position_embeddings": 256, **TOY,
+          "num_hidden_layers": 2, "first_k_dense_replace": 1}
+    model = make_model(hf_config_to_transformer(hf, dtype=jnp.float32))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    pools = jax.eval_shape(lambda: model.init_paged_cache(9, 16,
+                                                          dtype=jnp.float32))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)       # noqa: E731
+    texts = (
+        jax.jit(model.apply).lower(params, i32(1, 32)),
+        jax.jit(model.decode_step_paged).lower(params, i32(2), pools,
+                                               i32(2, 4), i32(2)),
+        jax.jit(lambda p, ids, pools, blocks, s, n: model.prefill_paged(
+            p, ids, pools, blocks, segments=(s, n))).lower(
+                params, i32(1, 32), pools, i32(2), i32(4), i32(4)))
+    for lowered in texts:
+        text = lowered.as_text(debug_info=True)
+        for scope in ("hc/read/", "hc/read/hc/sinkhorn/", "hc/write/"):
+            for layer in range(4):
+                assert f"/layer{layer}/{scope}" in text, (layer, scope)
+        assert "/embed/hc/open/" in text and "/hc/close/" in text
+        assert "pallas_call" not in text or "hc_read" not in text
+
+
 def test_gdn_kernel_names_and_scopes_in_the_lowered_text():
     """``%gdn_chunk`` / ``%gdn_step`` by name, and ``gdn/conv``, ``gdn/chunk``
     | ``gdn/step``, ``gdn/state_write``, ``gdn/gate_norm``, ``attn/out_gate``

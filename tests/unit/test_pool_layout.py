@@ -668,11 +668,16 @@ def test_the_window_cells_programs_fit_the_chip_and_write_the_rings_in_place(
 
 # ---- a latent pool (ISSUE 52) -------------------------------------------------
 
-@pytest.mark.parametrize("kind,backend,width", [
-    ("step", "pallas", 76), ("step", "xla", 38), ("prefill", "xla", 4096)])
+@pytest.mark.parametrize("name,kind,backend,width", [
+    ("glm-4.7-flash-serve", "step", "pallas", 76),
+    ("glm-4.7-flash-serve", "step", "xla", 38),
+    ("glm-4.7-flash-serve", "prefill", "xla", 4096),
+    ("xing4.0-29b-a4b-serve", "prefill", "xla", 4096)])
 def test_the_latent_cells_programs_fit_the_chip_and_write_the_pool_in_place(
-        kind, backend, width, one_chip, monkeypatch):
-    """GLM-4.7-Flash at the PUBLISHED widths and the cell's shapes
+        name, kind, backend, width, one_chip, monkeypatch):
+    """GLM-4.7-Flash — and Xing4.0-29B-A4B, whose stream is four rows a token,
+    whose V is 128 wide under keys of 192 and whose pool has the same shape
+    (ISSUE 59) — at the PUBLISHED widths and the cell's shapes
     (benchmark/configs/glm-4.7-flash-serve.json: 128 slots, one latent leaf
     of 6 planes x 9 729 blocks of 64 rows stored in 640 lanes) through the
     engine's own step — the kernel's (rectangular tables) and the list read's
@@ -687,7 +692,7 @@ def test_the_latent_cells_programs_fit_the_chip_and_write_the_pool_in_place(
     ``latent_attention.stored_width``), and a row scatter whose window spans
     the planes is answered the same way (one scatter a plane)."""
     cfg, params, pools, srv, S, MB, sds = _hybrid_cell(
-        "glm-4.7-flash-serve", one_chip, kv_cache_bits=0)
+        name, one_chip, kv_cache_bits=0)
     srv.decode_backend = backend
     assert set(pools) == {"latent"} and srv._slot_state == 0
     assert pools["latent"].shape == (6, S * MB + 1, BS, 640)
@@ -706,7 +711,7 @@ def test_the_latent_cells_programs_fit_the_chip_and_write_the_pool_in_place(
                 sds((_SEGMENTS,), jnp.int32), key)
     compiled = _compiled_for_the_chip(fn, args, monkeypatch)
     hlo, mem = compiled.as_text(), compiled.memory_analysis()
-    print(f"{kind} {backend} {width}: arguments "
+    print(f"{name} {kind} {backend} {width}: arguments "
           f"{mem.argument_size_in_bytes / 2**30:.2f} GiB, temporaries "
           f"{mem.temp_size_in_bytes / 2**30:.2f} GiB")
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
